@@ -1,6 +1,7 @@
 """Hybrid parallelism on a device mesh: dp x sharding(ZeRO) x mp.
-Run on CPU with a virtual mesh:
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 python distributed_hybrid.py
+Runs on a virtual 8-device CPU mesh unless JAX_PLATFORMS says otherwise:
+  python distributed_hybrid.py
+  JAX_PLATFORMS=tpu python distributed_hybrid.py   # a real 8-chip slice
 """
 import os
 import sys
@@ -9,16 +10,13 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))))
 
+# This demo needs an 8-device mesh, so it names its platform (the
+# choice must be made before the backend initializes): the virtual CPU
+# mesh by default, whatever JAX_PLATFORMS says otherwise.
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=8")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax
-
-# This demo needs an 8-device mesh.  Default to the virtual CPU mesh;
-# on a real multi-chip TPU slice run with PADDLE_TPU_REAL_MESH=1.
-# (The platform must be chosen before the backend initializes, so this
-# cannot be decided by counting devices first.)
-if os.environ.get("PADDLE_TPU_REAL_MESH") != "1":
-    jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 import paddle_tpu as paddle

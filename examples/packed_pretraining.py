@@ -6,7 +6,7 @@ GPTModel(doc_lens=...) with per-document position reset and
 block-diagonal attention (flash SegmentIds on TPU; derived mask on
 CPU).  Run:
 
-    PADDLE_TPU_PLATFORM=cpu python examples/packed_pretraining.py
+    JAX_PLATFORMS=cpu python examples/packed_pretraining.py
 """
 import os
 import sys

@@ -1,10 +1,10 @@
 """Pipeline parallelism with the 1F1B schedule (vs GPipe).
-Run on CPU with a virtual mesh:
-  XLA_FLAGS=--xla_force_host_platform_device_count=8 python pipeline_1f1b.py
+Runs on a virtual 8-device CPU mesh unless JAX_PLATFORMS says otherwise:
+  python pipeline_1f1b.py
+  JAX_PLATFORMS=tpu python pipeline_1f1b.py   # a real 8-chip slice
 
 Both schedules produce the SAME loss trajectory; 1F1B caps live
-activations at O(P) microbatches instead of GPipe's O(M) (see
-BASELINE.md for the measured 10x temp-memory reduction at M=16).
+activations at O(P) microbatches instead of GPipe's O(M).
 """
 import os
 import sys
@@ -14,10 +14,8 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
 
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=8")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax
-
-if os.environ.get("PADDLE_TPU_REAL_MESH") != "1":
-    jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 import paddle_tpu as paddle

@@ -3,7 +3,7 @@
 Reference workflow parity (fluid/contrib/slim/quantization/imperative):
 quantize -> train -> observe out-scales -> export StableHLO. Run:
 
-    PADDLE_TPU_PLATFORM=cpu PADDLE_TPU_SYNTH_N=256 \
+    JAX_PLATFORMS=cpu PADDLE_TPU_SYNTH_N=256 \
         python examples/quant_aware_training.py
 """
 import os

@@ -17,7 +17,7 @@ every shape STATIC for XLA:
 
 Compute on the flat layout does real work proportional to ``capacity``
 (total tokens), not ``B × L_max`` — the padded-dense path's cost.  At
-the skew measured in BASELINE.md round 3 (median 166 / max 2048) that
+the skew measured in round 3 (median 166 / max 2048) that
 is the difference between 17% and 85% waste.
 
 Ops are differentiable (segment_sum/scatter have VJPs); conversion

@@ -18,12 +18,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec
-try:  # jax>=0.5 moved shard_map to jax.*
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 from ..core.tensor import Tensor
 from ..core.dispatch import ensure_tensor

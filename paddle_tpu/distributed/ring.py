@@ -21,18 +21,12 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core.tensor import Tensor
 from ..core.dispatch import ensure_tensor
 from . import mesh as mesh_mod
-
-try:  # jax>=0.5 moved shard_map to jax.*
-    from jax import shard_map as _shard_map_mod
-    shard_map = _shard_map_mod
-except ImportError:
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 
 def _block_attn(q, k, v, scale, mask_mode, drop_key=None, dropout_p=0.0):

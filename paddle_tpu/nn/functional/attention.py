@@ -5,14 +5,14 @@ Reference parity: the reference only has a score-materializing
 inference-only fused kernel (operators/fused/multihead_matmul_op.cu).
 TPU-native design: one `scaled_dot_product_attention` entry point that
 dispatches to a Pallas flash-attention kernel on TPU backends (blockwise
-online-softmax so the S×S score matrix never hits HBM) with a pure-XLA
-fallback elsewhere (CPU tests, tiny shapes).  Long-context sharded variants
-(ring attention over a mesh axis) live in paddle_tpu/distributed/ring.py and
-reuse the same inner kernel.
+online-softmax so the S×S score matrix never hits HBM) at long sequences
+and to the pure-XLA form on the ``cpu`` platform and at short ones — a
+choice made from the platform and the shapes, never from a failed
+import.  Long-context sharded variants (ring attention over a mesh axis)
+live in paddle_tpu/distributed/ring.py and reuse the same inner kernel.
 """
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
@@ -44,16 +44,16 @@ def _reference_attention(q, k, v, mask=None, scale=None, is_causal=False):
     return jnp.swapaxes(out, 1, 2)
 
 
-@functools.lru_cache(maxsize=None)
 def _flash_available():
+    """The Pallas flash kernel serves every backend but ``cpu``
+    (Mosaic does not target it); the import is unguarded, so a JAX
+    without the kernel fails here instead of quietly serving the XLA
+    reference."""
     if jax.default_backend() == "cpu":
         return False
-    try:
-        from jax.experimental.pallas.ops.tpu.flash_attention import (  # noqa
-            flash_attention)
-        return True
-    except Exception:
-        return False
+    from jax.experimental.pallas.ops.tpu.flash_attention import (  # noqa
+        flash_attention)
+    return True
 
 
 # Flash engages at seq >= this (tunable; bench/perf experiments override).

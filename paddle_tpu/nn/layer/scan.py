@@ -3,8 +3,8 @@
 TPU-native alternative to unrolling a LayerList: XLA compiles the layer
 body once instead of ``num_layers`` times, collapsing compile time for
 deep models (GPT-3 1.3B full-step XLA: 18.6s scanned vs 212-460s
-unrolled on the CPU rehearsal — BASELINE.md round 3) and shrinking the
-executable.  With ``use_recompute`` the body is ``jax.checkpoint``'ed —
+unrolled on the CPU rehearsal — round 3, record deleted at bring-up)
+and shrinking the executable.  With ``use_recompute`` the body is ``jax.checkpoint``'ed —
 the canonical remat-over-scan recipe for long models.
 
 The reference has no analogue (its Program unrolls every layer's ops);

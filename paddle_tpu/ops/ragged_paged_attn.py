@@ -54,10 +54,30 @@ layout matrix (paged x plain/chunked/spec x depth 1+2 x int8 KV x
 adapter lanes; tests/test_ragged_attn.py), while seeded streams are
 asserted deterministic (same seed => same stream) and are bitwise
 arm-identical only under ``variant="gather"``.  Both variants share
-the masking rule, the int8 per-block scale operands, and the callers'
-LoRA bank plumbing; tier-1 runs both under ``interpret=True`` on CPU,
-and the compiled Mosaic lowering is the TPU tier of the ``pallas``
-marker.
+the masking rule, the int8 per-block scales, and the callers' LoRA
+bank plumbing.
+
+WHERE EACH BODY RUNS.  Interpret mode is chosen on the ``cpu``
+platform only (``_auto_interpret``); on every other backend the
+kernel goes through Mosaic and either compiles or raises — there is
+no fallback.  The STREAMING body is written for Mosaic: ``pos``,
+``width`` and the block tables are scalar-prefetched to SMEM
+(``PrefetchScalarGridSpec``), both pools stay in HBM
+(``memory_space=ANY``), and each loop step DMAs ONE K block and ONE V
+block into VMEM scratch, then runs a per-head ``[W, hd] x [hd, bs]``
+contraction with the running ``(m, l, acc)`` in VMEM scratch.  It
+needs ``head_dim % 128 == 0`` (the DMA slice must cover whole lane
+tiles); libtpu 0.0.34 compiles it for ``TPU v5 lite`` at f32 / bf16 /
+int8 pools, H in {4, 8, 16}, block sizes 8-32 and windows up to 512
+(``compile_check``; tests/test_ragged_attn.py pins it through the
+compile-only topology).  It is a correctness-level body: one page per
+step, no double buffering, f32 contractions — tuning is ROADMAP S2.
+The GATHER body still hands whole pools to each program instance as
+VMEM blocks and reads its scalars from VMEM; Mosaic refuses it
+("cannot statically prove that index in dimension 0 is a multiple of
+128") and it is not repaired — it serves the CPU A/B only, and
+``Engine(attn_impl="ragged_gather")`` raises at construction on any
+other backend with the compiler's message.
 
 K/V WRITES stay outside the kernel (the callers' width-masked scatter
 — see ``GPTAttention.ragged_window_paged``): lanes past ``width[b]``
@@ -77,10 +97,9 @@ DATA — tables carry global block ids and the wrapper localizes them
 by subtracting the shard's row offset (``axis_index('dp') *
 blocks_per_shard``), which is exact because the engine's admission
 gate only ever hands a slot blocks from its own shard's range.
-Under interpret mode on the forced CPU mesh this partitions
-identically to what a real Mosaic TPU run would lower, and it is
-asserted token-identical to the GSPMD-partitioned XLA oracle across
-the serving layout matrix (tests/test_sharded_serving.py).
+On the forced CPU mesh (interpret mode) it is asserted
+token-identical to the GSPMD-partitioned XLA oracle across the
+serving layout matrix (tests/test_sharded_serving.py).
 """
 from __future__ import annotations
 
@@ -89,12 +108,16 @@ import math
 VARIANTS = ("stream", "gather")
 
 
-def _auto_interpret():
-    """Pallas interpret mode unless we are actually on TPU — tier-1
-    (JAX_PLATFORMS=cpu) exercises the real kernel logic token-for-token
-    against the XLA oracle; compiled Mosaic lowering is the TPU tier."""
-    import jax
-    return jax.default_backend() != "tpu"
+def _auto_interpret(platform=None):
+    """Pallas interpret mode on the ``cpu`` platform only (tier-1 runs
+    the kernel logic there token-for-token against the XLA oracle).
+    Every other backend compiles through Mosaic or raises: a missing
+    chip or an unsupported shape must never turn into a silent
+    interpreted run."""
+    if platform is None:
+        import jax
+        platform = jax.default_backend()
+    return platform == "cpu"
 
 
 def kernel_working_set_bytes(*, variant, block_size, blocks_per_slot,
@@ -125,65 +148,87 @@ def kernel_working_set_bytes(*, variant, block_size, blocks_per_slot,
 def _stream_impl(q, k_flat, v_flat, block_tables, pos, width,
                  block_size, interpret, k_scale=None, v_scale=None):
     """Flash-style online-softmax streaming kernel (module docstring):
-    fori over the slot's live blocks with running (m, l, acc)."""
+    a loop over the slot's live blocks, one K and one V block DMA'd
+    HBM -> VMEM per step, running (m, l, acc) in VMEM scratch."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     B, W, H, hd = q.shape
     nb = block_tables.shape[1]
     bs = block_size
     scale = 1.0 / math.sqrt(hd)
     quant = k_scale is not None
+    # head-major query / output blocks [1, H, Wp, hd]: each head's
+    # [Wp, hd] window is then a plain 2-D tile.  Wp pads the window to
+    # whole f32 sublane tiles; the pad lanes are >= width, so they are
+    # zeroed like any other masked lane and sliced off below.
+    Wp = -(-W // 8) * 8
+    qt = jnp.swapaxes(q, 1, 2)
+    if Wp != W:
+        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, Wp - W), (0, 0)))
 
-    def kernel(tables_ref, pos_ref, width_ref, q_ref, k_ref, v_ref,
-               *rest):
+    def kernel(tables_ref, pos_ref, width_ref, *rest):
         if quant:
-            ks_ref, vs_ref, o_ref = rest
-        else:
-            (o_ref,) = rest
+            ks_ref, vs_ref, *rest = rest
+        (q_ref, k_hbm, v_hbm, o_ref,
+         k_buf, v_buf, m_ref, l_ref, acc_ref, sem) = rest
         b = pl.program_id(0)
         p = pos_ref[b]
         w = width_ref[b]
-        qa = q_ref[0].astype(jnp.float32)                # [W, H, hd]
-        s_ids = jax.lax.broadcasted_iota(jnp.int32, (W, bs), 0)
-        r_ids = jax.lax.broadcasted_iota(jnp.int32, (W, bs), 1)
-
-        def block(j, scale_ref, pool_ref):
-            # gather ONE paged block: physical block ids are runtime
-            # data; bs is the only static extent.  Quantized pools
-            # dequantize PER STREAMED BLOCK — int8 codes times that
-            # block's per-head scale row, right where the block enters
-            # the recurrence, never the whole pool.
-            idx = tables_ref[b, j]
-            blk = pool_ref[pl.ds(idx * bs, bs)]          # [bs, H, hd]
-            if scale_ref is not None:
-                s = scale_ref[pl.ds(idx, 1)][0]          # [H]
-                return blk.astype(jnp.float32) * s[None, :, None]
-            return blk.astype(jnp.float32)
+        s_ids = jax.lax.broadcasted_iota(jnp.int32, (Wp, bs), 0)
+        r_ids = jax.lax.broadcasted_iota(jnp.int32, (Wp, bs), 1)
+        m_ref[...] = jnp.full(m_ref.shape, -1e30, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
         def body(j, carry):
-            m, l, acc = carry
-            kb = block(j, ks_ref if quant else None, k_ref)
-            vb = block(j, vs_ref if quant else None, v_ref)
-            sc = jnp.einsum("qhd,khd->hqk", qa, kb) * scale
+            # fetch ONE paged block of each pool: physical block ids
+            # are runtime data read from SMEM; bs is the only static
+            # extent
+            idx = tables_ref[b, j]
+            rows = pl.ds(idx * bs, bs)
+            ck = pltpu.make_async_copy(k_hbm.at[rows], k_buf, sem.at[0])
+            cv = pltpu.make_async_copy(v_hbm.at[rows], v_buf, sem.at[1])
+            ck.start()
+            cv.start()
+            ck.wait()
+            cv.wait()
             # query lane s sees cache positions <= pos + s — the
             # slot's LENGTH does the masking, not a padded shape
-            visible = (j * bs + r_ids) <= (p + s_ids)    # [W, bs]
-            sc = jnp.where(visible[None, :, :], sc, -1e30)
-            bm = jnp.max(sc, axis=2)                     # [H, W]
-            new_m = jnp.maximum(m, bm)
-            # multiply by the mask, not just the -1e30 floor: a fully
-            # masked tile must contribute EXACTLY zero mass even while
-            # the running max is still at its -1e30 init (where
-            # exp(sc - new_m) would read exp(0) = 1)
-            pj = jnp.exp(sc - new_m[:, :, None]) \
-                * visible[None, :, :].astype(jnp.float32)
-            corr = jnp.exp(m - new_m)                    # [H, W]
-            l = l * corr + jnp.sum(pj, axis=2)
-            acc = acc * corr[:, :, None] \
-                + jnp.einsum("hqk,khd->hqd", pj, vb)
-            return new_m, l, acc
+            visible = (j * bs + r_ids) <= (p + s_ids)        # [Wp, bs]
+            vis_f = visible.astype(jnp.float32)
+            for h in range(H):
+                qh = q_ref[0, h].astype(jnp.float32)         # [Wp, hd]
+                kh = k_buf[:, h, :].astype(jnp.float32)      # [bs, hd]
+                vh = v_buf[:, h, :].astype(jnp.float32)
+                if quant:
+                    # quantized pools dequantize PER STREAMED BLOCK —
+                    # int8 codes times that block's per-head scale,
+                    # right where the block enters the recurrence
+                    kh = kh * ks_ref[b, j * H + h]
+                    vh = vh * vs_ref[b, j * H + h]
+                sc = jax.lax.dot_general(
+                    qh, kh, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                sc = jnp.where(visible, sc, -1e30)
+                m = m_ref[h]                                 # [Wp, 1]
+                new_m = jnp.maximum(
+                    m, jnp.max(sc, axis=1, keepdims=True))
+                # multiply by the mask, not just the -1e30 floor: a
+                # fully masked tile must contribute EXACTLY zero mass
+                # even while the running max is still at its -1e30
+                # init (where exp(sc - new_m) would read exp(0) = 1)
+                pj = jnp.exp(sc - new_m) * vis_f
+                corr = jnp.exp(m - new_m)
+                l_ref[h] = l_ref[h] * corr \
+                    + jnp.sum(pj, axis=1, keepdims=True)
+                acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+                    pj, vh, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m_ref[h] = new_m
+            return carry
 
         # causal horizon: the last visible position is pos + width - 1
         # (width >= 1; a parked width-0 slot still walks block 0 so
@@ -193,40 +238,49 @@ def _stream_impl(q, k_flat, v_flat, block_tables, pos, width,
         # walks O(live context), not O(table length).
         n_live = jnp.minimum(
             nb, (p + jnp.maximum(w, 1) - 1) // bs + 1)
-        m0 = jnp.full((H, W), -1e30, jnp.float32)
-        l0 = jnp.zeros((H, W), jnp.float32)
-        a0 = jnp.zeros((H, W, hd), jnp.float32)
-        m, l, acc = jax.lax.fori_loop(0, n_live, body, (m0, l0, a0))
-        ctx = jnp.transpose(acc / l[:, :, None], (1, 0, 2))
+        jax.lax.fori_loop(0, n_live, body, 0)
         # width as data: lanes past this slot's real window are zeroed
         # (parked slots — width 0 — return all-zero, never-read lanes)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (W, 1, 1), 0)
-        ctx = jnp.where(lane < w, ctx, 0.0)
-        o_ref[0] = ctx.astype(o_ref.dtype)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (Wp, 1), 0)
+        for h in range(H):
+            ctx = acc_ref[h] / l_ref[h]
+            o_ref[0, h] = jnp.where(lane < w, ctx, 0.0).astype(
+                o_ref.dtype)
 
-    in_specs = [
-        pl.BlockSpec(block_tables.shape, lambda b: (0, 0)),
-        pl.BlockSpec(pos.shape, lambda b: (0,)),
-        pl.BlockSpec(width.shape, lambda b: (0,)),
-        pl.BlockSpec((1, W, H, hd), lambda b: (b, 0, 0, 0)),
-        pl.BlockSpec(k_flat.shape, lambda b: (0, 0, 0)),
-        pl.BlockSpec(v_flat.shape, lambda b: (0, 0, 0)),
-    ]
-    operands = [block_tables, pos, width, q, k_flat, v_flat]
+    def slot_block(b, *_):
+        return (b, 0, 0, 0)
+
+    scalars = [block_tables, pos, width]
     if quant:
-        in_specs += [
-            pl.BlockSpec(k_scale.shape, lambda b: (0, 0)),
-            pl.BlockSpec(v_scale.shape, lambda b: (0, 0)),
-        ]
-        operands += [k_scale, v_scale]
-    return pl.pallas_call(
+        # per-slot scale rows, gathered through the tables out here so
+        # the kernel reads each (block, head) multiplier as an SMEM
+        # scalar: [B, nb * H]
+        scalars += [k_scale[block_tables].reshape(B, nb * H),
+                    v_scale[block_tables].reshape(B, nb * H)]
+    out = pl.pallas_call(
         kernel,
-        grid=(B,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, W, H, hd), lambda b: (b, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, W, H, hd), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(B,),
+            in_specs=[
+                pl.BlockSpec((1, H, Wp, hd), slot_block),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, H, Wp, hd), slot_block),
+            scratch_shapes=[
+                pltpu.VMEM((bs, H, hd), k_flat.dtype),
+                pltpu.VMEM((bs, H, hd), v_flat.dtype),
+                pltpu.VMEM((H, Wp, 1), jnp.float32),
+                pltpu.VMEM((H, Wp, 1), jnp.float32),
+                pltpu.VMEM((H, Wp, hd), jnp.float32),
+                pltpu.SemaphoreType.DMA((2,)),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((B, H, Wp, hd), q.dtype),
         interpret=interpret,
-    )(*operands)
+        name="ragged_paged_attn_stream",
+    )(*scalars, qt, k_flat, v_flat)
+    return jnp.swapaxes(out[:, :, :W], 1, 2)
 
 
 def _gather_impl(q, k_flat, v_flat, block_tables, pos, width,
@@ -374,6 +428,47 @@ def ragged_paged_attention(q, k_flat, v_flat, block_tables, pos, width,
         k_scale=k_scale, v_scale=v_scale)
 
 
+def compile_check(*, num_slots, window, num_heads, head_dim,
+                  block_size, blocks_per_slot, num_blocks, dtype,
+                  quant=False, variant="stream", device=None):
+    """Lower and compile the kernel through Mosaic at one shape,
+    running nothing; raises the compiler's own error when it refuses.
+
+    ``device`` defaults to ``jax.devices()[0]``.  A compile-only
+    device works too (``jax.experimental.topologies.
+    get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    .devices[0]``), which is how a CPU-only sandbox iterates on
+    Mosaic errors.  ``Engine`` calls this at construction on every
+    non-``cpu`` backend, with its per-shard shapes, so an unsupported
+    shape or body fails there with the compiler's message instead of
+    inside the first tick."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    sh = SingleDeviceSharding(device or jax.devices()[0])
+
+    def spec(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=sh)
+
+    B, H, hd = num_slots, num_heads, head_dim
+    pool = spec((num_blocks * block_size, H, hd),
+                jnp.int8 if quant else dtype)
+    args = [spec((B, window, H, hd), dtype), pool, pool,
+            spec((B, blocks_per_slot), jnp.int32),
+            spec((B,), jnp.int32), spec((B,), jnp.int32)]
+    if quant:
+        args += [spec((num_blocks, H), jnp.float32)] * 2
+
+    def run(q, k, v, tables, pos, width, *scales):
+        ks, vs = scales if scales else (None, None)
+        return ragged_paged_attention(
+            q, k, v, tables, pos, width, block_size=block_size,
+            interpret=False, k_scale=ks, v_scale=vs, variant=variant)
+
+    jax.jit(run).lower(*args).compile()
+
+
 def sharded_ragged_paged_attention(q, k_flat, v_flat, block_tables,
                                    pos, width, *, block_size,
                                    mesh=None, interpret=None,
@@ -399,20 +494,18 @@ def sharded_ragged_paged_attention(q, k_flat, v_flat, block_tables,
     * pos/width [B] shard ``P('dp')``; scales [NB, H] shard
       ``P('dp', 'mp')``.
 
-    The per-shard body is the UNchanged kernel — the partitioning
-    this wrapper hand-writes is exactly what interpret mode's HLO
-    lowering lets GSPMD derive, which is what the dp parity tests
-    pin; on TPU it is the only way to run the Mosaic kernel on a
-    mesh at all.  Output shards like q.
+    The per-shard body is the UNchanged kernel.  GSPMD cannot
+    partition a Mosaic ``pallas_call``, so on a TPU mesh this wrapper
+    is the only way to run the kernel; on the forced CPU mesh it
+    partitions the interpret-mode lowering the same way, which is
+    what the dp parity tests pin.  Output shards like q.
     """
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # newer jax: promoted out of experimental
-        from jax import shard_map
+    from jax import shard_map
+
     if mesh is None:
         from ..distributed import mesh as mesh_mod
         mesh = mesh_mod.get_mesh()
@@ -468,5 +561,5 @@ def sharded_ragged_paged_attention(q, k_flat, v_flat, block_tables,
         args += [jnp.asarray(k_scale, jnp.float32),
                  jnp.asarray(v_scale, jnp.float32)]
     fn = shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                   out_specs=qspec, check_rep=False)
+                   out_specs=qspec, check_vma=False)
     return fn(*args)

@@ -153,7 +153,7 @@ class Optimizer:
         ~150-param transformer becomes 2-3 fused update chains over
         large contiguous vectors — the per-parameter path emits hundreds
         of tiny fusions whose fixed overhead the profiler shows in the
-        dominant elementwise bucket (BASELINE.md round-3 breakdown).
+        dominant elementwise bucket (round-3 breakdown, record deleted at bring-up).
         Bitwise-equivalent math: every update rule here is per-element,
         scalar state (beta pows) follows an identical trajectory for
         every group member, and concat/split do not touch values.  Only

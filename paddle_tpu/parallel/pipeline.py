@@ -33,17 +33,12 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..core import autograd, rng as rng_mod
 from ..jit import functional_call
 from ..distributed import mesh as mesh_mod
-
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 
 def stack_block_params(blocks):

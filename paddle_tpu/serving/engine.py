@@ -305,8 +305,11 @@ class Engine:
         oracle.  ``"ragged"`` (requires the paged layout and device
         sampling) routes the decode, spec-verify, and chunked-prefill
         attention core through the Pallas RAGGED PAGED ATTENTION
-        kernel (ops/ragged_paged_attn.py; interpret mode off-TPU, so
-        tier-1 runs the real kernel logic): per-slot positions,
+        kernel (ops/ragged_paged_attn.py; interpret mode on the cpu
+        platform only, so tier-1 runs the kernel logic; on any other
+        backend construction compiles it through Mosaic at this
+        engine's shapes and raises the compiler's message if it is
+        refused — it needs head_dim % 128 == 0): per-slot positions,
         window widths, and block tables are kernel DATA, a single
         dispatch carries one-token decode lanes, k+1 verify windows,
         and budgeted prefill chunks side by side, the
@@ -327,9 +330,10 @@ class Engine:
         tests/test_ragged_attn.py.  ``"ragged_gather"`` keeps the
         original materialize-the-row kernel body — O(context) working
         set, bitwise-equal to the XLA oracle on CPU, greedy AND
-        seeded token-identical — as the A/B reference (trace span
-        ``decode.ragged``; same dispatch path and compile-matrix
-        collapse otherwise).
+        seeded token-identical — as the CPU A/B reference (trace
+        span ``decode.ragged``; same dispatch path and compile-matrix
+        collapse otherwise).  Mosaic refuses this body, so off the
+        cpu platform it raises at construction.
     mesh : TENSOR-PARALLEL SERVING over a device mesh.  ``None``
         (default) serves on one device.  An int / 1-tuple ``mp``
         degree (resolved over the first mp devices via
@@ -934,6 +938,8 @@ class Engine:
         # the streaming (online-softmax) body, "ragged_gather" the
         # materialize-the-row A/B reference (ops/ragged_paged_attn.py)
         self._ragged = attn_impl in ("ragged", "ragged_gather")
+        self._variant = ("gather" if attn_impl == "ragged_gather"
+                         else "stream")
         # the ONE ragged program's static window: wide enough for a
         # one-token decode lane, the k+1 spec-verify window, and a
         # prefill chunk — per-slot width is runtime data, so the
@@ -941,6 +947,32 @@ class Engine:
         # traffic mixes (the compile-matrix collapse)
         self._wmax = max(1, (self._spec_k + 1) if self._spec_k else 1,
                          self._chunk or 1)
+        if self._ragged:
+            import jax
+            dev = (self.mesh.devices.flat[0] if self.mesh is not None
+                   else jax.devices()[0])
+            if dev.platform != "cpu":
+                # off the cpu platform there is no interpret mode:
+                # prove NOW that Mosaic takes the kernel at this
+                # engine's per-shard shapes, so a refusal is a
+                # construction error carrying the compiler's words —
+                # not a failing first tick that step() flight-records
+                # and carries on from
+                from ..ops.ragged_paged_attn import compile_check
+                try:
+                    compile_check(
+                        num_slots=self.num_slots // self.dp,
+                        window=self._wmax,
+                        num_heads=self._nh // self.mp,
+                        head_dim=self._hd, block_size=self._bs,
+                        blocks_per_slot=self._bps,
+                        num_blocks=self._kv_managed // self.dp + 1,
+                        dtype=self._kv_dtype, quant=self._kv_quant,
+                        variant=self._variant, device=dev)
+                except Exception as e:
+                    raise ValueError(
+                        f"attn_impl={attn_impl!r} does not compile "
+                        f"for {dev.device_kind}: {e}") from e
         self._ragged_fn = None  # resolved jitted ragged-window handle
         self._zero_scale_fn = None  # jitted fresh-block scale zeroer
         #   (kv_dtype='int8'; compiled once per config — see
@@ -1355,6 +1387,15 @@ class Engine:
                         for _ in self.model.blocks]
         self.v_pools = [self._alloc_pool(shape)
                         for _ in self.model.blocks]
+        # where this engine runs, read off the pools themselves (fixed
+        # per config): /healthz and /debug/requests report it, so a
+        # caller asserts the chip through the server's own surface
+        pool0 = self.k_pools[0]
+        devs = sorted(getattr(pool0, "codes", pool0).sharding.device_set,
+                      key=lambda d: d.id)
+        self.placement = {"platform": devs[0].platform,
+                          "device_kind": devs[0].device_kind,
+                          "device_ids": [d.id for d in devs]}
         # host-side per-slot step state: in host sample_mode these ship
         # to device every tick; in device mode they are MIRRORS of the
         # device-resident cursors, re-uploaded only when an admission /
@@ -2790,6 +2831,7 @@ class Engine:
             "offload": (None if self.host_store is None
                         else self.host_store.stats()),
             "engine": {
+                **self.placement,
                 "num_slots": self.num_slots,
                 "max_seq_len": self.max_seq_len,
                 "layout": "paged" if self._paged else "contiguous",
@@ -3938,8 +3980,7 @@ class Engine:
         # itself advances them by width)
         if self._state_dirty or self._dev_state is None:
             self._push_state()
-        variant = "gather" if self.attn_impl == "ragged_gather" \
-            else "stream"
+        variant = self._variant
         # kv blocks the kernel walks this tick (computed on the
         # PRE-dispatch cursors, before the chunk lanes' mirror
         # advance): the streaming loop stops at each lane's causal

@@ -34,7 +34,9 @@ full submit -> queue -> slot -> result path over a real socket):
                    (accepting new work), "state" distinguishing
                    "draining" (finishing up — stop routing, let it
                    land its streams) from "watchdog_fired" (wedged
-                   tick — possibly dying) from "ok"
+                   tick — possibly dying) from "ok"; and WHERE the
+                   engine runs: "platform", "device_kind" and
+                   "device_ids" (the devices holding the KV pools)
   GET  /livez      200 while the process serves (liveness probe)
   GET  /readyz     200 {"ready": true} when accepting new work;
                    503 with a machine-readable "reason"
@@ -46,7 +48,8 @@ full submit -> queue -> slot -> result path over a real socket):
                         tools/trace_view.py)
   GET  /debug/requests  in-flight slot/request states (prefill
                         progress, spec lanes, KV blocks) + the queue
-                        + the recent migration log
+                        + the recent migration log; "engine" carries
+                        the same platform / device_kind / device_ids
   POST /migrate/export  KV block migration, source side.  Three body
                         shapes: {"request_id": n} exports a LIVE
                         stream; {"prompt": [...], ...generate params,
@@ -262,6 +265,9 @@ class _Handler(JsonHandler):
                 "kv_block_size": (eng._bs if getattr(eng, "_paged",
                                                      False) else None),
                 "sample_mode": getattr(eng, "sample_mode", "host"),
+                # where the engine runs: platform, device_kind and the
+                # ids of the devices holding the KV pools
+                **getattr(eng, "placement", {}),
                 # disaggregated serving: which phase this replica
                 # volunteers for (the router's pick() filters on it)
                 "role": self.role,
@@ -1033,7 +1039,9 @@ def main(argv=None):
     weights, so greedy failover across replicas is token-identical
     (the fleet tests and bench assert it).  ``--mp > 1`` needs that
     many devices — on CPU the launcher forces a virtual pool via
-    XLA_FLAGS (per-worker env propagation is its job)."""
+    XLA_FLAGS (per-worker env propagation is its job).  The platform
+    is whatever ``JAX_PLATFORMS`` (or JAX's default) selects; the
+    startup line and ``/healthz`` name it."""
     import argparse
 
     p = argparse.ArgumentParser("paddle_tpu.serving.httpd")
@@ -1100,9 +1108,11 @@ def main(argv=None):
     import signal as _signal
 
     import paddle_tpu as paddle
+    from ..core.compile_cache import enable_compile_cache
     from ..models.gpt import GPTModel
     from .engine import Engine
 
+    enable_compile_cache()
     paddle.seed(args.seed)
     model = GPTModel.from_config(args.config, dropout=0.0)
     model.eval()
@@ -1133,7 +1143,8 @@ def main(argv=None):
                        peers=args.peer,
                        drain_grace_s=args.drain_grace).start()
     print(f"serving {args.config} mp={args.mp} dp={args.dp} "
-          f"on {srv.address}", flush=True)
+          f"on {srv.address} platform={engine.placement['platform']} "
+          f"devices={engine.placement['device_ids']}", flush=True)
     try:
         while not stop_evt.wait(0.2):
             if not srv._http_thread.is_alive():

@@ -695,6 +695,8 @@ class Router:
                          ("queue_depth", "slots_free",
                           "kv_blocks_free", "drain_rate_tps",
                           "slots_total", "kv_block_size",
+                          # where the replica's engine runs
+                          "platform", "device_kind", "device_ids",
                           # mesh-sharded replicas advertise their
                           # full (mp, dp) shape: the /replicas
                           # registry rows (and timeline.py --router)
@@ -1637,6 +1639,7 @@ class InProcessReplica:
             "kv_blocks_free": (eng.block_pool.free_count()
                                if paged else None),
             "kv_block_size": (eng._bs if paged else None),
+            **getattr(eng, "placement", {}),
             "mesh_shape": getattr(eng, "mesh_axes", None),
             "mp": getattr(eng, "mp", 1),
             "dp": getattr(eng, "dp", 1),

@@ -17,7 +17,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 import jax
-jax.config.update("jax_platforms", "cpu")
 
 # The launcher (paddle_tpu.distributed.launch) initializes jax.distributed
 # BEFORE the user script imports the framework — replicate that here (the
@@ -48,7 +47,7 @@ def summed(x):
     return jax.lax.psum(x, "dp")
 
 
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 local = np.full((2, 1), float(rank + 1), np.float32)
 glob = dist.mesh.host_local_to_global(local, mesh, "dp", None)
 out = jax.jit(shard_map(summed, mesh=mesh, in_specs=P("dp"),

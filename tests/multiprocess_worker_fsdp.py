@@ -5,7 +5,7 @@ script runs at world=1 and world=N and the parent compares losses).
 Launched via paddle_tpu.distributed.launch (which wires the PADDLE_* env
 contract and jax.distributed) or directly for the single-process
 reference run.  Requires XLA_FLAGS=--xla_force_host_platform_device_count=2
-and PADDLE_TPU_PLATFORM=cpu in the environment.
+and JAX_PLATFORMS=cpu in the environment.
 """
 import os
 import sys

@@ -102,7 +102,7 @@ def test_bucketed_training_compiles_per_bucket_only():
 class TestRaggedSkewStress:
     """VERDICT round-2 missing #1: the dense+lengths reduction must hold
     at realistic length skew.  Full measured table (8192-doc lognormal,
-    wall-clock legs): BASELINE.md 'Ragged skew' section +
+    wall-clock legs): the round-3 'Ragged skew' measurement +
     tools/exp/_exp_ragged.py."""
 
     def _corpus(self, n=2048):

@@ -18,7 +18,7 @@ WORKER = os.path.join(os.path.dirname(__file__),
 
 def _run(tmp, ckpt_name, out_name, kill_after=-1):
     env = dict(os.environ,
-               PADDLE_TPU_PLATFORM="cpu",
+               JAX_PLATFORMS="cpu",
                PADDLE_RUNNING_ENV="PADDLE_EDL_AUTO_CHECKPOINT",
                PADDLE_CHECKPOINT_DIR=str(tmp / ckpt_name),
                OUT_PATH=str(tmp / out_name),

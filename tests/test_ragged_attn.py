@@ -5,8 +5,9 @@ CPU, so tier-1 exercises the REAL kernel logic token-for-token against
 the XLA oracle: per-slot pos/width/block-tables as data, width-masked
 scratch writes, and the one-program compile-matrix collapse the
 ``attn_impl="ragged"`` engine path claims.  Tests marked ``pallas``
-involve the kernel; the compiled-Mosaic variant additionally skips
-off-TPU (the marker's real-hardware tier).
+involve the kernel; ``test_kernel_compiled_lowering_on_tpu`` asks the
+real Mosaic compiler (compile-only, no chip needed) which bodies it
+takes.
 
 NUMERICS CONTRACT (two kernel bodies):
 
@@ -226,34 +227,38 @@ def test_kernel_stream_allclose_long_tables():
 
 
 @pytest.mark.pallas
-@pytest.mark.slow
-@pytest.mark.parametrize("variant", ["stream", "gather"])
-def test_kernel_compiled_lowering_on_tpu(variant):
-    """Real-TPU tier: the same kernel compiled through Mosaic (no
-    interpret) matches interpret mode — for BOTH bodies, streaming
-    online-softmax included.  Skips everywhere but TPU — the pallas
-    marker's hardware-gated variant."""
-    import jax
-    if jax.default_backend() != "tpu":
-        pytest.skip("compiled Mosaic lowering needs a TPU backend")
+def test_kernel_compiled_lowering_on_tpu():
+    """What Mosaic says about each body, settled WITHOUT a chip: the
+    installed libtpu compiles for a compile-only ``TPU v5 lite``
+    topology device (``compile_check``).  The STREAMING body lowers
+    at the shapes that matter — bf16 pools, H=16, hd=128, block 16,
+    a chunk window, the per-shard head counts of mp=2/4, f32 and int8
+    pools — and is refused below one lane tile of head_dim.  The
+    GATHER body is refused outright (scalar reads from VMEM blocks)
+    and is not repaired.  Agreement of the compiled kernel with the
+    XLA path is a chip matter: ``chip_smoke.py`` checks it there."""
     import jax.numpy as jnp
-    from paddle_tpu.ops.ragged_paged_attn import ragged_paged_attention
+    from jax.experimental import topologies
+    from paddle_tpu.ops.ragged_paged_attn import compile_check
 
-    rng = np.random.RandomState(0)
-    q = jnp.asarray(rng.randn(2, 4, 4, 128).astype(np.float32))
-    k = jnp.asarray(rng.randn(8 * 16, 4, 128).astype(np.float32))
-    v = jnp.asarray(rng.randn(8 * 16, 4, 128).astype(np.float32))
-    tables = jnp.asarray(rng.randint(1, 8, (2, 4)).astype(np.int32))
-    pos = jnp.asarray(np.array([3, 9], np.int32))
-    width = jnp.asarray(np.array([4, 1], np.int32))
-    a = ragged_paged_attention(q, k, v, tables, pos, width,
-                               block_size=16, interpret=True,
-                               variant=variant)
-    b = ragged_paged_attention(q, k, v, tables, pos, width,
-                               block_size=16, interpret=False,
-                               variant=variant)
-    np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                               rtol=2e-5, atol=2e-5)
+    dev = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0]
+    assert dev.device_kind == "TPU v5 lite"
+    c0 = dict(num_slots=8, num_heads=16, head_dim=128, block_size=16,
+              blocks_per_slot=128, num_blocks=1025, device=dev)
+    for kw in (dict(window=128, dtype=jnp.bfloat16),
+               dict(window=1, dtype=jnp.bfloat16, num_heads=8),
+               dict(window=1, dtype=jnp.bfloat16, num_heads=4),
+               dict(window=4, dtype=jnp.float32),
+               dict(window=1, dtype=jnp.bfloat16, quant=True)):
+        compile_check(**{**c0, **kw})
+    with pytest.raises(Exception, match="aligned to tiling"):
+        compile_check(**{**c0, "window": 1, "dtype": jnp.bfloat16,
+                         "head_dim": 64})
+    with pytest.raises(Exception,
+                       match="cannot statically prove that index"):
+        compile_check(**c0, window=1, dtype=jnp.bfloat16,
+                      variant="gather")
 
 
 # -- knob validation --------------------------------------------------

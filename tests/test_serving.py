@@ -1295,6 +1295,8 @@ def test_debug_endpoints_smoke(tiny_gpt):
     assert dbg["queue"][0]["queued_ms"] >= 0
     assert dbg["engine"]["spec_k"] == 2
     assert dbg["engine"]["tracing"] is True
+    assert dbg["engine"]["platform"] == "cpu"
+    assert dbg["engine"]["device_ids"] == eng.placement["device_ids"]
     eng.run_until_idle()
     r1.result(timeout=1)
     r2.result(timeout=1)
@@ -1318,6 +1320,13 @@ def test_healthz_always_reports_load_signals(tiny_gpt):
     assert health["kv_blocks_free"] > 0
     # the router's prefix-affinity hash aligns on the block size
     assert health["kv_block_size"] == 8
+    # ... and WHERE the engine runs: platform, device_kind and the ids
+    # of the devices holding the pools, as jax itself reports them
+    import jax
+    dev = jax.devices()[0]
+    assert health["platform"] == dev.platform == "cpu"
+    assert health["device_kind"] == dev.device_kind
+    assert health["device_ids"] == [dev.id]
 
 
 def test_healthz_liveness_readiness_split(tiny_gpt):

@@ -1,7 +1,7 @@
 """Fused-CE chunk-size sweep on hardware (round-3 MFU push).
 
-The chunked head+CE scan is ~18% of the GPT-2 345M step (BASELINE.md
-round-3 breakdown).  Chunk size trades scan iterations (per-iteration
+The chunked head+CE scan is ~18% of the GPT-2 345M step (round-3
+breakdown).  Chunk size trades scan iterations (per-iteration
 dW-accumulate traffic over the [H, V] head grad) against live logits
 HBM ([B, chunk, V] f32).  Sweeps chunk at b8 s1024 and prints tokens/s
 per setting; also the first data for the dynamic_slice scan rewrite

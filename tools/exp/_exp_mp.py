@@ -1,5 +1,6 @@
 """mp=2 step-time microbench on the 8-device CPU mesh (TP remat check)."""
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                            " --xla_force_host_platform_device_count=8").strip()
 import time
@@ -7,7 +8,6 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(
     os.path.abspath(__file__)), "..", ".."))
 import jax
-jax.config.update("jax_platforms", "cpu")
 import numpy as np
 import paddle_tpu as paddle
 import paddle_tpu.distributed as dist
