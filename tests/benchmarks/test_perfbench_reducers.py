@@ -1,0 +1,238 @@
+"""The reducers: each kind on a hand-made source, then every serving
+metric's own file on a recorded engine trace, and the device-trace
+reducer on a trace recorded on a v5e."""
+import json
+import os
+import statistics
+
+import pytest
+
+from bench_paths import BENCH, DATA, manifest
+from harness import reducers, xplane
+
+METRICS = reducers.load_metric_files(os.path.join(BENCH, "layer_metrics"))
+
+
+def span(name, ts, dur, **args):
+    return {"name": name, "ph": "X", "ts": ts, "dur": dur, "args": args}
+
+
+def inst(name, ts, **args):
+    return {"name": name, "ph": "i", "ts": ts, "args": args}
+
+
+SRC = {
+    "spans": [
+        span("tick", 0, 1000, batch=0), span("tick", 2000, 3000, batch=2),
+        span("tick", 6000, 5000, batch=4),
+        span("decode.dispatch", 2100, 100, batch=2),
+        span("decode.dispatch", 6100, 100, batch=4),
+        span("prefill.chunk", 100, 500_000), span("prefill.chunk", 7000,
+                                                  500_000),
+        inst("req.queued", 1000, req=1), inst("req.queued", 2000, req=2),
+        inst("req.admitted", 4000, req=1), inst("req.admitted", 9000, req=2),
+        inst("req.admitted", 9500, req=3),      # never queued here
+    ],
+    "counters": {
+        "delta": {"serving.prefix_hit_tokens": 300.0,
+                  "serving.prefill_tokens": 100.0, "x.compiles": 2.0},
+        "peak": {"serving.kv_blocks_in_use": 30.0},
+        "last": {"serving.kv_blocks_total": 40.0},
+        "profile_delta": {"serving.prefill_tokens": 0.0},
+    },
+    "client": {"late_ms": [1.0, 2.0, 3.0, 4.0, 50.0],
+               "profile_tokens": 64, "profile_live_positions": 64 * 500},
+    "device": {"busy_s": 2.0, "window_s": 5.0},
+    "ctx": {"memory_peak_bytes": 12.5e9},
+}
+
+
+@pytest.mark.parametrize("kind, params, want", [
+    ("span_percentile", {"span": "tick", "q": 50,
+                         "where": {"batch": {"min": 1}}}, 3.0),
+    ("span_percentile", {"span": "tick", "q": 100}, 5.0),
+    ("span_percentile", {"span": "absent", "q": 50}, None),
+    ("span_arg_mean", {"span": "decode.dispatch", "arg": "batch"}, 3.0),
+    ("span_rate", {"counter": "serving.prefill_tokens",
+                   "span": "prefill.chunk"}, 100.0),
+    ("lifecycle_gap", {"start": "req.queued", "end": "req.admitted",
+                       "key": "req", "q": 90}, 7.0),
+    ("lifecycle_gap", {"start": "req.queued", "end": "req.admitted",
+                       "key": "req", "q": 50}, 3.0),
+    ("counter_delta", {"counter": "x.compiles"}, 2.0),
+    ("counter_delta", {"counter": "absent"}, None),
+    ("counter_delta_ratio",
+     {"num": ["serving.prefix_hit_tokens"],
+      "den": ["serving.prefix_hit_tokens", "serving.prefill_tokens"]}, 75.0),
+    ("gauge_peak", {"gauge": "serving.kv_blocks_in_use",
+                    "over": "serving.kv_blocks_total"}, 75.0),
+    ("client_percentile", {"series": "late_ms", "q": 95}, 50.0),
+    ("client_percentile", {"series": "late_ms", "q": 50}, 3.0),
+    ("memory_peak", {}, 12.5),
+    ("xplane_idle", {}, 60.0),
+])
+def test_reducer_kinds(kind, params, want):
+    got = reducers.KINDS[kind](SRC, **params)
+    if want is None:
+        assert got is None
+    else:
+        assert got == pytest.approx(want)
+
+
+def test_roofline_is_least_time_over_busy_time():
+    from harness import counts
+    with open(os.path.join(BENCH, "configs", "gpt3-1.3b-serve.json")) as f:
+        dims = json.load(f)["dims"]
+    src = dict(SRC, ctx={"dims": dims, "dtype": "bfloat16",
+                         "peaks": counts.peaks_for("TPU v5 lite"),
+                         "num_slots": 32,
+                         "prefill_counter": "serving.prefill_tokens"})
+    # two decode steps of 32 tokens: 2 x 2.62 GB + 32,000 positions x
+    # 196,608 B = 11.5 GB at 819 GB/s = 14.1 ms, of 2 s busy
+    got = reducers.roofline(src, "serve")
+    want = (2 * counts.step_weight_bytes(dims) + 32000 * 196608) / 819e9
+    assert got == pytest.approx(100 * want / 2.0)
+    assert 0.69 < got < 0.72
+    # training: 19.9 TFLOP a step, 10 steps in 2 s busy = 50.5% of peak
+    with open(os.path.join(BENCH, "configs", "gpt2-medium-train.json")) as f:
+        tdims = json.load(f)["dims"]
+    src = dict(SRC, ctx={"dims": tdims,
+                         "peaks": counts.peaks_for("TPU v5 lite"),
+                         "batch": 8, "seq_len": 1024, "profile_steps": 10})
+    assert reducers.roofline(src, "train") == pytest.approx(
+        100 * 10 * 19.85e12 / 197e12 / 2.0, rel=0.01)
+    assert reducers.rate_over_peak(
+        {"ctx": {"peaks": counts.peaks_for("TPU v5 lite"), "rate": 30000.0,
+                 "f": 2.423e9}}, "rate", "f") == pytest.approx(36.9, abs=0.05)
+
+
+def test_a_reader_that_finds_nothing_leaves_its_metric_out():
+    out = reducers.reduce_all(
+        METRICS, ["idle_share.serve", "tick_p50_ms", "kv_used_peak"],
+        {"spans": [], "counters": {}, "device": None, "ctx": {}})
+    assert out == {}
+
+
+# -- the recorded engine trace ---------------------------------------------
+
+with open(os.path.join(DATA, "engine_sources_tiny.json")) as _f:
+    RECORDED = json.load(_f)
+
+
+def _recorded(name):
+    m = METRICS[name]
+    return reducers.KINDS[m["reducer"]](RECORDED, **m.get("params", {}))
+
+
+def _x(name):
+    return [e for e in RECORDED["spans"]
+            if e["name"] == name and e["ph"] == "X"]
+
+
+@pytest.mark.parametrize("name", sorted(
+    n for n, m in METRICS.items()
+    if m["moves"] != "train_tok_s" and m["source"] != "device_trace"))
+def test_serving_metric_files_on_the_recorded_trace(name):
+    got = _recorded(name)
+    assert got is not None, f"{name} found nothing to read"
+    delta = RECORDED["counters"]["delta"]
+    if name.endswith(".closed"):      # the same reader, another arrow
+        base = METRICS.get(name[:-len(".closed")])
+        if base is not None:
+            assert (base["reducer"], base["params"]) == \
+                (METRICS[name]["reducer"], METRICS[name]["params"])
+            assert METRICS[name]["moves"] == "out_tok_s"
+        name = name[:-len(".closed")]
+    if name == "decode_batch_mean":
+        b = [e["args"]["batch"] for e in _x("decode.dispatch")]
+        assert got == pytest.approx(sum(b) / len(b))
+    elif name == "tick_p50_ms":
+        d = sorted(e["dur"] / 1e3 for e in _x("tick")
+                   if e["args"].get("batch", 0) >= 1)
+        assert d[0] <= got <= d[-1]
+        assert got == pytest.approx(statistics.median_low(d))
+    elif name == "prefix_hit_share":
+        hit, miss = (delta["serving.prefix_hit_tokens"],
+                     delta["serving.prefill_tokens"])
+        assert got == pytest.approx(100 * hit / (hit + miss))
+    elif name == "prefix_evictions":
+        assert got == delta["serving.prefix_evictions"] >= 0
+    elif name == "queue_wait_p90_ms":
+        q = {e["args"]["req"]: e["ts"] for e in RECORDED["spans"]
+             if e["name"] == "req.queued"}
+        a = {e["args"]["req"]: e["ts"] for e in RECORDED["spans"]
+             if e["name"] == "req.admitted"}
+        waits = sorted((a[k] - q[k]) / 1e3 for k in q if k in a)
+        assert len(waits) == len(q) > 10 and waits[0] >= 0
+        assert got == pytest.approx(reducers.percentile(waits, 90))
+    elif name == "kv_used_peak":
+        assert 0 < got <= 100
+    elif name == "compiles_in_window.serve":
+        assert got == 0
+    elif name == "gen_late_p95_ms":
+        assert got == pytest.approx(
+            reducers.percentile(RECORDED["client"]["late_ms"], 95))
+    elif name == "ttft_p90_ms":
+        assert got == pytest.approx(
+            reducers.percentile(RECORDED["client"]["ttft_ms"], 90))
+    elif name == "hbm_peak.serve":
+        assert got == 0        # recorded on the CPU, which reports none
+    else:
+        pytest.fail(f"no expectation written for {name}")
+
+
+# -- the recorded device trace -------------------------------------------------
+
+TRACE = os.path.join(DATA, "mini_v5e.xplane.pb")
+
+
+def test_device_trace_recorded_on_a_v5e():
+    # three runs of one jitted program: per run a copy-start (14 ns), a
+    # copy-done (3 ns) and one fusion (11,877 ns), read off the file by
+    # hand; the union of each run's operations is 11,897 ns
+    got = xplane.reduce(TRACE, {"train.step"}, window_s=0.03)
+    assert got["devices"] == 1
+    assert got["busy_s"] == pytest.approx(3 * 11_894e-9, rel=0.002)
+    assert got["window_s"] == 0.03
+    ops = dict(got["device_ops"])
+    assert set(ops) == {"fusion bf16[]", "copy-start bf16[1024,1024]",
+                        "copy-done bf16[1024,1024]"}
+    assert ops["fusion bf16[]"] == pytest.approx(3 * 11_877e-9, rel=0.001)
+    # two gaps between three runs
+    assert sum(v for _, v in got["idle_gaps"]) == pytest.approx(
+        (68_940_258 - 45_940_931 - 2 * 11_900) * 1e-9, rel=0.01)
+    idle = reducers.xplane_idle({"device": got})
+    assert idle == pytest.approx(100 * (1 - got["busy_s"] / 0.03))
+
+
+@pytest.mark.parametrize("text, want", [
+    ("%convert.266 = f32[65536,16,128]{2,1,0:T(8,128)} convert(bf16[1]{0} %fusion.4)",
+     "convert f32[65536,16,128]"),
+    ("%copy-start.1 = (bf16[8192,2048]{1,0:T(8,128)(2,1)S(1)}, u32[]{:S(2)}) copy-start(%p)",
+     "copy-start bf16[8192,2048]"),
+    ("%fusion = bf16[]{:T(256)} fusion(bf16[1024,1024]{1,0} %x), kind=kLoop",
+     "fusion bf16[]"),
+    ("jit_pure(9165921196842725281)", "jit_pure(9165921196842725281)"),
+])
+def test_operation_names_add_up_across_layers(text, want):
+    assert xplane.short_op(text) == want
+
+
+def test_intervals_merge():
+    assert xplane.merge([(5, 7), (0, 2), (1, 3), (7, 8), (10, 11)]) == \
+        [[0, 3], [5, 8], [10, 11]]
+
+
+def test_every_metric_of_the_manifest_has_its_file_and_one_arrow():
+    man = manifest()
+    e2e = {m["name"]: m for m in man["end_to_end"]}
+    cells = {w["name"] for w in man["workloads"]}
+    for m in man["per_layer"]:
+        f = METRICS[m["name"]]
+        assert (f["layer"], f["unit"], f["moves"], f["source"]) == \
+            (m["layer"], m["unit"], m["moves"], m["source"])
+        assert f["reducer"] in reducers.KINDS
+        assert m["moves"] in e2e
+        for w in m["workloads"]:
+            assert w in cells
+            assert w in e2e[m["moves"]].get("workloads", cells)
