@@ -14,6 +14,7 @@ sharding stage2, GPT-3 1.3B hybrid) instantiate from ``GPT_CONFIGS``.
 """
 from __future__ import annotations
 
+import functools
 import math
 import threading
 import time
@@ -28,6 +29,31 @@ from ..ops import reshape, transpose, concat
 
 
 _sample_rows_jit = None  # lazily-jitted single-call sampler (below)
+
+
+def _jit_named(kind, pure, **jit_kwargs):
+    """``jax.jit(pure)`` under the name of the program's
+    ``_compile_probe`` kind, so that a device trace's ``XLA Modules``
+    line reads ``jit_gpt_fused_decode(...)`` where it read
+    ``jit_pure(...)`` for every program alike.  (The module name is
+    part of the persistent compile cache's key.)"""
+    import jax
+    pure.__name__ = pure.__qualname__ = "gpt_" + kind
+    return jax.jit(pure, **jit_kwargs)
+
+
+def _scoped(name):
+    """Run the decorated method under ``jax.named_scope(name)``: the
+    scope shows in the op metadata of a device trace, so a program's
+    time splits into attention / mlp / lm_head / sampling."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapped(*args, **kwargs):
+            import jax
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return wrapped
+    return deco
 
 
 def _is_quant_kv(pool):
@@ -220,6 +246,7 @@ class GPTAttention(nn.Layer):
         qkv = einsum("bse,ethd->btshd", x, self.qkv_weight) + self.qkv_bias
         return qkv[:, 0], qkv[:, 1], qkv[:, 2]
 
+    @_scoped("attention")
     def decode(self, x, k_buf, v_buf, pos):
         """Windowed decode against FIXED-SIZE cache buffers (compiled
         generation): writes the window's k/v at ``pos..pos+S-1`` via
@@ -324,6 +351,7 @@ class GPTAttention(nn.Layer):
             out = self._lora_out(out)
         return out
 
+    @_scoped("attention")
     def decode_slots(self, x, k_buf, v_buf, pos):
         """One-token decode with PER-SLOT positions (continuous
         batching, serving/engine.py): each batch row is an independent
@@ -350,6 +378,7 @@ class GPTAttention(nn.Layer):
         v_buf = v_buf.at[rows, pos].set(va[:, 0].astype(v_buf.dtype))
         return self._slot_attn(qa, k_buf, v_buf, pos), k_buf, v_buf
 
+    @_scoped("attention")
     def decode_slots_paged(self, x, k_pool, v_pool, block_tables, pos):
         """One-token decode reading K/V through per-slot BLOCK TABLES
         (paged KV cache — serving/kvcache.py): the physical pools hold
@@ -401,6 +430,7 @@ class GPTAttention(nn.Layer):
         return (out, flat_k.reshape(k_pool.shape),
                 flat_v.reshape(v_pool.shape))
 
+    @_scoped("attention")
     def verify_slots(self, x, k_buf, v_buf, pos):
         """SPECULATIVE VERIFY window with per-slot positions
         (serving/spec.py): score W = k+1 window tokens per slot in one
@@ -429,6 +459,7 @@ class GPTAttention(nn.Layer):
         v_buf = v_buf.at[rows, cols].set(va.astype(v_buf.dtype))
         return self._slot_attn(qa, k_buf, v_buf, pos), k_buf, v_buf
 
+    @_scoped("attention")
     def verify_slots_paged(self, x, k_pool, v_pool, block_tables, pos):
         """Block-table twin of ``verify_slots`` (paged KV cache): the
         W window tokens scatter through each slot's block table and
@@ -477,6 +508,7 @@ class GPTAttention(nn.Layer):
         return (out, flat_k.reshape(k_pool.shape),
                 flat_v.reshape(v_pool.shape))
 
+    @_scoped("attention")
     def ragged_window_paged(self, x, k_pool, v_pool, block_tables, pos,
                             width, scratch=None, sharded=False,
                             variant="stream"):
@@ -585,6 +617,7 @@ class GPTAttention(nn.Layer):
             out = self._lora_out(out)
         return out, new_k, new_v
 
+    @_scoped("attention")
     def prefill_chunk_paged(self, x, k_pool, v_pool, block_table, pos,
                             true_len, scratch=0):
         """CHUNKED prefill through ONE slot's block table (budgeted
@@ -678,6 +711,7 @@ class GPTAttention(nn.Layer):
             out = self._lora_out(out)
         return out, new_k, new_v
 
+    @_scoped("attention")
     def forward(self, x, cache=None, doc_segments=None):
         b, s, _ = x.shape
         if doc_segments is not None and self.use_sp and cache is None:
@@ -759,6 +793,7 @@ class GPTMLP(nn.Layer):
             self.fc2 = nn.Linear(ffn_hidden, hidden_size, weight_attr=init)
         self.dropout = nn.Dropout(dropout)
 
+    @_scoped("mlp")
     def forward(self, x):
         return self.dropout(self.fc2(F.gelu(self.fc1(x),
                                             approximate=True)))
@@ -899,6 +934,7 @@ class GPTLMHead(nn.Layer):
             self.lm_head = nn.Linear(hidden_size, vocab_size,
                                      weight_attr=init, bias_attr=False)
 
+    @_scoped("lm_head")
     def forward(self, x):
         return self.lm_head(self.ln_f(x))
 
@@ -1120,6 +1156,7 @@ class GPTModel(nn.Layer):
             rng_mod.request_key(lo, hi), c))(seed_lo, seed_hi, ctr)
 
     @staticmethod
+    @_scoped("sampling")
     def _sample_lanes(last, temperature, top_k, top_p, keys):
         """One token per slot row from [B, V] logits with PER-SLOT
         sampling params and keys: lanes with ``temperature == 0`` (the
@@ -1613,7 +1650,7 @@ class GPTModel(nn.Layer):
                         emit_w=emit_w, variant=variant)
             return out
 
-        fn = jax.jit(pure, donate_argnums=(2, 3))
+        fn = _jit_named("ragged_window", pure, donate_argnums=(2, 3))
         if len(cache) >= 8:  # FIFO bound, matching the other caches
             cache.pop(next(iter(cache)))
         cache[cache_key] = (self._compile_probe(
@@ -1684,6 +1721,8 @@ class GPTModel(nn.Layer):
                             pass
             return out
 
+        probed.kind = kind  # the engine labels its dev.* spans with it
+        probed.__name__ = "gpt_" + kind
         return probed
 
     def _compiled_fused_decode_fn(self, pnames, params, cache_key,
@@ -1742,7 +1781,7 @@ class GPTModel(nn.Layer):
                             top_p, seed_lo, seed_hi, ctr, eos, rem)
                 return out
 
-        fn = jax.jit(pure, donate_argnums=(2, 3))
+        fn = _jit_named("fused_decode", pure, donate_argnums=(2, 3))
         if len(cache) >= 8:  # FIFO bound, matching the other caches
             cache.pop(next(iter(cache)))
         cache[cache_key] = (self._compile_probe(
@@ -1804,7 +1843,7 @@ class GPTModel(nn.Layer):
                             rem)
                 return out
 
-        fn = jax.jit(pure, donate_argnums=(2, 3))
+        fn = _jit_named("fused_spec_verify", pure, donate_argnums=(2, 3))
         if len(cache) >= 8:  # FIFO bound, matching the other caches
             cache.pop(next(iter(cache)))
         cache[cache_key] = (self._compile_probe(
@@ -1862,7 +1901,7 @@ class GPTModel(nn.Layer):
                                 toks, k_pools, v_pools, pos)
                 return last, new_k, new_v
 
-        fn = jax.jit(pure, donate_argnums=(2, 3))
+        fn = _jit_named("spec_verify", pure, donate_argnums=(2, 3))
         if len(cache) >= 8:  # FIFO bound, matching the other caches
             cache.pop(next(iter(cache)))
         cache[cache_key] = (self._compile_probe(
@@ -1966,7 +2005,7 @@ class GPTModel(nn.Layer):
                         for vp, nv in zip(v_pools, new_v)]
             return last, k_pools, v_pools
 
-        fn = jax.jit(pure, donate_argnums=(2, 3))
+        fn = _jit_named("chunk_prefill", pure, donate_argnums=(2, 3))
         if len(cache) >= 8:  # FIFO bound, matching _prefill_fn_cache
             cache.pop(next(iter(cache)))
         cache[cache_key] = (self._compile_probe(
@@ -2012,7 +2051,7 @@ class GPTModel(nn.Layer):
                             pos, true_len, scratch=scratch)
             return last, new_k, new_v
 
-        fn = jax.jit(pure, donate_argnums=(2, 3))
+        fn = _jit_named("paged_chunk_prefill", pure, donate_argnums=(2, 3))
         if len(cache) >= 8:  # FIFO bound, matching the other caches
             cache.pop(next(iter(cache)))
         cache[cache_key] = (self._compile_probe(
@@ -2051,7 +2090,7 @@ class GPTModel(nn.Layer):
                         tok, k_pools, v_pools, block_tables, pos)
             return last, new_k, new_v
 
-        fn = jax.jit(pure, donate_argnums=(2, 3))
+        fn = _jit_named("slot_paged_decode", pure, donate_argnums=(2, 3))
         if len(cache) >= 8:  # FIFO bound, matching the other decode caches
             cache.pop(next(iter(cache)))
         cache[cache_key] = (self._compile_probe(
@@ -2138,7 +2177,7 @@ class GPTModel(nn.Layer):
                         new_v.append(_store_tail(vp, vt, tail_blocks))
             return logits._data[:, -1, :], new_k, new_v
 
-        fn = jax.jit(pure, donate_argnums=(2, 3))
+        fn = _jit_named("paged_prefill", pure, donate_argnums=(2, 3))
         if len(cache) >= 8:  # FIFO bound, matching _prefill_fn_cache
             cache.pop(next(iter(cache)))
         cache[cache_key] = (self._compile_probe(
@@ -2177,7 +2216,7 @@ class GPTModel(nn.Layer):
                         tok, k_bufs, v_bufs, pos)
             return last, new_k, new_v
 
-        fn = jax.jit(pure, donate_argnums=(2, 3))
+        fn = _jit_named("slot_decode", pure, donate_argnums=(2, 3))
         if len(cache) >= 8:  # FIFO bound, matching the other decode caches
             cache.pop(next(iter(cache)))
         cache[cache_key] = (self._compile_probe(
@@ -2257,7 +2296,7 @@ class GPTModel(nn.Layer):
         # no donate_argnums: unlike the per-token step the K/V buffers
         # are consumed by the scan but never returned, so they cannot
         # alias an output — donating them only emits a warning
-        fn = jax.jit(pure)
+        fn = _jit_named("fused_generate", pure)
         if len(cache) >= 8:  # FIFO bound on resident executables
             cache.pop(next(iter(cache)))
         cache[cache_key] = (self._compile_probe(
@@ -2423,7 +2462,7 @@ class GPTModel(nn.Layer):
                                         (B, max_new))
             return out.astype(out_dtype), n_fwd
 
-        fn = jax.jit(pure)
+        fn = _jit_named("spec_generate", pure)
         if len(cache) >= 8:  # FIFO bound, matching the other caches
             cache.pop(next(iter(cache)))
         cache[cache_key] = (self._compile_probe(
@@ -2469,7 +2508,7 @@ class GPTModel(nn.Layer):
                     v_bufs = [jnp.pad(cv._data, pad) for _, cv in caches]
             return logits._data[:, -1, :], k_bufs, v_bufs
 
-        fn = jax.jit(pure)
+        fn = _jit_named("prefill", pure)
         if len(cache) >= 8:  # FIFO bound, matching _gen_fn_cache
             cache.pop(next(iter(cache)))
         cache[cache_key] = (self._compile_probe(
@@ -2524,7 +2563,7 @@ class GPTModel(nn.Layer):
                         (b, 1, V))[:, 0]
             return last, k_bufs, v_bufs
 
-        fn = jax.jit(pure)
+        fn = _jit_named("bucket_prefill", pure)
         if len(cache) >= 8:  # FIFO bound, matching _prefill_fn_cache
             cache.pop(next(iter(cache)))
         cache[cache_key] = (self._compile_probe(
@@ -2564,7 +2603,7 @@ class GPTModel(nn.Layer):
                         tok, k_bufs, v_bufs, pos)
             return last, new_k, new_v
 
-        fn = jax.jit(pure, donate_argnums=(2, 3))
+        fn = _jit_named("decode", pure, donate_argnums=(2, 3))
         if len(cache) >= 8:  # FIFO bound, matching the other decode caches
             cache.pop(next(iter(cache)))
         cache[cache_key] = (self._compile_probe(

@@ -18,10 +18,14 @@ Design points:
   No jax import at module level — like the rest of ``monitor``, this
   is pure stdlib and safe in fork'd workers and HTTP handler threads.
 - **Thread-aware.**  Each thread appends into its own
-  ``deque(maxlen=capacity)`` ring buffer, so the engine loop, HTTP
-  handlers, and background threads never interleave events;
-  ``events()`` merges the per-thread rings into one ts-sorted
-  snapshot.
+  ``deque(maxlen=capacity)`` ring buffer, so the engine loop and
+  background threads never interleave events; ``events()`` merges the
+  rings into one ts-sorted snapshot.
+- **Shared lanes.**  Events of the categories in ``SHARED_LANES`` go
+  to one named ring per tracer whichever thread emits them: the
+  request lifecycle and the HTTP edge (``"requests"``: the events of
+  a connection outlive its handler thread, and short-lived threads
+  burn no lane) and the device-completion timeline (``"device"``).
 - **Chrome-trace native.**  Events are stored directly in Catapult
   complete-event shape (``ph="X"``, microsecond ``ts``/``dur``) plus
   instant events (``ph="i"``) for point-in-time lifecycle marks, so
@@ -43,6 +47,11 @@ from collections import deque
 # Catapult instant-event scope: "t" = thread-scoped tick mark (the
 # narrow arrow in chrome://tracing), vs "p"/"g" process/global.
 _INSTANT_SCOPE = "t"
+
+# event category -> the shared lane that holds it (every other
+# category lands on the emitting thread's own lane)
+SHARED_LANES = {"request": "requests", "http": "requests",
+                "device": "device"}
 
 
 class TraceEvent:
@@ -201,10 +210,12 @@ class NullTracer:
 
 
 class Tracer:
-    """Thread-aware span collector over bounded per-thread ring
-    buffers.
+    """Thread-aware span collector over bounded ring buffers: one per
+    thread, plus one per shared lane (``SHARED_LANES``: request
+    lifecycle + HTTP edge, device timeline), which no thread owns and
+    pruning never touches.
 
-    ``capacity`` bounds EACH thread's ring (oldest events fall off —
+    ``capacity`` bounds EACH ring (oldest events fall off —
     that is the flight-recorder property: under sustained load the
     buffer always holds the most recent ~capacity events, never grows,
     and never needs draining).  Lanes are per thread LIFETIME, not per
@@ -234,6 +245,7 @@ class Tracer:
         self._buffers = {}       # lane -> deque(maxlen=capacity)
         self._thread_names = {}  # lane -> thread name at first event
         self._thread_refs = {}   # lane -> weakref to the thread
+        self._shared = {}        # shared lane name -> (lane, deque)
         self._next_lane = 1
 
     # -- collection ----------------------------------------------------
@@ -253,16 +265,30 @@ class Tracer:
         self._local.lane_buf = (lane, buf)
         return lane, buf
 
+    def _shared_buf(self, name):
+        cached = self._shared.get(name)
+        if cached is not None:
+            return cached
+        with self._lock:
+            if name not in self._shared:
+                lane = self._next_lane
+                self._next_lane += 1
+                buf = deque(maxlen=self.capacity)
+                self._buffers[lane] = buf
+                self._thread_names[lane] = name
+                self._shared[name] = (lane, buf)
+            return self._shared[name]
+
     def _prune_dead_locked(self):
-        """Bound the lane table: once ``max_threads`` lanes exist,
-        evict DEAD threads' lanes in creation order until back under
-        the bound (short-lived HTTP handler threads each burn a lane;
-        without this a thread-per-connection server grows the table
-        forever).  Caller holds the lock."""
-        if len(self._buffers) < self.max_threads:
+        """Bound the lane table: once ``max_threads`` thread lanes
+        exist, evict DEAD threads' lanes in creation order until back
+        under the bound (a server that spawns a thread per job would
+        otherwise grow the table forever).  Shared lanes belong to no
+        thread and stay.  Caller holds the lock."""
+        if len(self._thread_refs) < self.max_threads:
             return
-        for lane in list(self._buffers):
-            if len(self._buffers) < self.max_threads:
+        for lane in list(self._thread_refs):
+            if len(self._thread_refs) < self.max_threads:
                 break
             th = self._thread_refs[lane]()
             if th is None or not th.is_alive():
@@ -273,7 +299,9 @@ class Tracer:
     def _append(self, name, ph, ts_us, dur_us, cat, args):
         if not self.enabled:
             return
-        tid, buf = self._buf()
+        shared = SHARED_LANES.get(cat)
+        tid, buf = (self._buf() if shared is None
+                    else self._shared_buf(shared))
         # the lock covers the append/snapshot race: deque.append is
         # atomic, but ``events()`` listing a ring mid-append from
         # another thread would raise "deque mutated during iteration"

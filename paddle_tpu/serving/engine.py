@@ -30,8 +30,10 @@ tokens/sec, TTFT/TPOT histograms — scrape them through
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
+import queue
 import threading
 import time
 import weakref
@@ -104,6 +106,52 @@ class _MigrateDemand:
         if self.error is not None:
             raise self.error
         return self.result
+
+
+def _spanned(name):
+    """Run the decorated engine method under a tracer span: the
+    phases of a tick that have no narrower span of their own."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapped(self, *args, **kwargs):
+            with self.tracer.span(name):
+                return fn(self, *args, **kwargs)
+        return wrapped
+    return deco
+
+
+def _watch_device(q, tracer, busy_ms):
+    """Body of an engine's device watcher thread: turn each dispatch
+    the engine thread hands over into a ``dev.*`` span on the tracer's
+    shared ``device`` lane.  One device runs the engine's programs in
+    the order they were dispatched, so a program starts when the one
+    before it completes (or when it was dispatched, if the device was
+    idle by then) and ends when its smallest output is ready; both
+    ends are read from ``time.perf_counter``, the clock of every host
+    span, so the lane lies over the host's and a hole in it is the
+    device idle.  ``busy_ms`` (``serving.dev_busy_ms``) sums the same
+    durations.  The completion is read after this thread wakes up, so
+    it is late by the wake-up (and the program after it starts as
+    late, which keeps the sum right).  The thread holds the tracer and
+    the counter but never the engine; ``None`` ends it."""
+    prev_done = 0.0
+    while True:
+        item = q.get()
+        if item is None:
+            return
+        name, t_dispatch, handle, args = item
+        try:
+            if handle is not None:
+                handle.block_until_ready()
+        except Exception:
+            # the dispatch died; step recovery rebuilt the pools
+            prev_done = time.perf_counter()
+            continue
+        done = time.perf_counter()
+        ts = max(prev_done, t_dispatch)
+        prev_done = done
+        tracer.emit(name, ts, done - ts, cat="device", args=args)
+        busy_ms.inc((done - ts) * 1e3)
 
 
 def _softmax_np(x):
@@ -412,6 +460,21 @@ class Engine:
         recorder.  Download it live via ``/debug/trace`` or
         ``Engine.chrome_trace()``; ``tracing=False`` swaps in a no-op
         tracer (the bench's A/B: overhead is asserted <= 5%).
+        Tracing also starts, at the first dispatch, one DEVICE WATCHER
+        thread (stopped by ``stop()``): it waits for the smallest
+        output of every dispatched program and records ``dev.decode``
+        / ``dev.prefill`` spans (``dev.prefill`` carried at least one
+        prompt token; args ``program``, ``tick``, ``batch``, ``n``,
+        ``req``) on a shared ``device`` lane, on the clock of the host
+        spans — a hole in that lane is the device idle, and
+        ``tools/trace_view.py --wall`` sums the holes by the host span
+        open meanwhile.  ``serving.dev_busy_ms`` sums the same
+        durations (its rate is the device's utilisation).  Every phase
+        of a tick runs under a span of its own, and the ``tick`` span
+        carries ``host_ms``: its duration less the time blocked on the
+        device.  ``http.ingest`` / ``http.first_frame`` (httpd.py)
+        and the ``req.*`` instants share one ``requests`` lane that
+        outlives the handler threads.
     trace_capacity : per-thread ring-buffer bound, in events.
     trace_annotations : also enter a ``jax.profiler.TraceAnnotation``
         per span so engine phases land in XPlane/TensorBoard captures
@@ -1018,6 +1081,12 @@ class Engine:
         self.tracer = (monitor.Tracer(capacity=trace_capacity,
                                       annotate=trace_annotations)
                        if tracing else monitor.NullTracer())
+        # device watcher (see _watch_device): its queue exists iff
+        # tracing is on, its thread starts at the first dispatch
+        self._dev_q = queue.SimpleQueue() if tracing else None
+        self._dev_thread = None
+        self._blocked_s = 0.0   # time this tick spent waiting on the
+        #   device (d2h syncs, collectives): tick less this = host_ms
         self._flight_dir = flight_dir
         self.last_flight = None        # chrome-trace dict of the most
         self.last_flight_path = None   # recent step failure (+ file)
@@ -1180,6 +1249,18 @@ class Engine:
         self._m_compile_ms = reg.histogram(
             "serving.compile_ms", "wall time of each new program's "
             "first call (jax trace + XLA compile + first run, ms)")
+        self._m_compile_wall = reg.counter(
+            "serving.compile_wall_ms", "summed wall time of every new "
+            "program's first call (the same times as "
+            "serving.compile_ms, ms): what set-up spends tracing, "
+            "compiling or loading from the cache, and first running "
+            "its programs")
+        self._m_dev_busy = reg.counter(
+            "serving.dev_busy_ms", "summed duration of the dev.* spans"
+            " (ms): device time of the engine's dispatches as the "
+            "device watcher saw them complete — its rate is the "
+            "device's utilisation, with no profiler attached (0 with "
+            "tracing=False)")
         # overload-protection surface: preemption / shedding /
         # fairness / chaos (registered always; zero when idle)
         self._m_preempt = reg.counter(
@@ -1738,6 +1819,7 @@ class Engine:
         with self._ovl_lock:
             return list(self._preempt_log)
 
+    @_spanned("post_admit")
     def _post_admit(self, admitted, timed_out, tr):
         """Shared post-admission phase of both tick paths.  Reconciles
         the admitted list against the preemption round — a handler
@@ -1833,6 +1915,7 @@ class Engine:
                    slot=i, tokens=len(req.generated),
                    priority=req.priority)
 
+    @_spanned("preempt")
     def _preempt_round(self, now, tr):
         """Admission-phase preemption loop: while the best queued
         priority outranks a running request and admission is blocked
@@ -1864,7 +1947,7 @@ class Engine:
                 # consume in-flight ticks first: the victim's device
                 # lane is NOT done, and the consume-side drift check
                 # must never see a vanished live request
-                emitted += self._drain_ring(tr)
+                emitted += self._drain_ring(tr, "preempt")
                 vr = victim.request
                 if vr is None or vr.priority >= pri \
                         or self.scheduler.free_count() > 0:
@@ -2112,7 +2195,7 @@ class Engine:
         its own failure (d.fail) so the drained-token count always
         reaches the tick accounting.  Returns tokens emitted by the
         drain."""
-        emitted = self._drain_ring(tr) if self._ring else 0
+        emitted = self._drain_ring(tr, "adapter") if self._ring else 0
         name = d.args["name"]
         try:
             with tr.span("lora.swap", cat="serving", op=d.kind,
@@ -2255,7 +2338,7 @@ class Engine:
             # host-consumed view before its rows are gathered, and the
             # consume-side drift check must never see a vanished live
             # request — same discipline as preemption
-            emitted += self._drain_ring(tr)
+            emitted += self._drain_ring(tr, "migrate")
             if req.done():
                 self._finish_out_done(d, req)
                 return "done", emitted
@@ -2275,6 +2358,16 @@ class Engine:
             "payload": payload if d.args["deliver"] == "return"
             else None})
         return "done", emitted
+
+    def _export_gather(self, blocks, req=None):
+        """``export_blocks`` on this engine's pools, shown on the
+        device lane (``dev.gather``): the gather is synchronous, so it
+        is complete when the watcher hears of it."""
+        t0 = time.perf_counter()
+        data = export_blocks(self.k_pools, self.v_pools, blocks)
+        self._dev_note("migrate_export", None, role="gather", req=req,
+                       t_dispatch=t0)
+        return data
 
     def _export_slot(self, slot, tr, deliver):
         """Freeze + gather + tear down one decoding slot (ring already
@@ -2301,8 +2394,7 @@ class Engine:
                              len(self._slot_blocks[i]))
                 blocks = self._slot_blocks[i][:n_full]
                 if n_full:
-                    data = export_blocks(self.k_pools, self.v_pools,
-                                         blocks)
+                    data = self._export_gather(blocks, req.id)
                     kv = {"block_size": self._bs,
                           "num_heads": self._nh,
                           "head_dim": self._hd,
@@ -2507,8 +2599,7 @@ class Engine:
             try:
                 with tr.span("migrate.export", cat="serving",
                              blocks=len(blocks), prefix=True):
-                    data = export_blocks(self.k_pools, self.v_pools,
-                                         blocks)
+                    data = self._export_gather(blocks)
             finally:
                 self.block_pool.decref(blocks)  # drop match's refs
             if self._kv_quant:
@@ -2605,6 +2696,7 @@ class Engine:
                             jnp.take(vp, ids, axis=0)))
                  for kp, vp in zip(self.k_pools, self.v_pools)])
             scales = None
+        self._dev_note("offload_demote", data, role="gather")
         self._offload_pending_keys.add(key)
         self._offload_pending.append((key, data, scales))
 
@@ -2621,22 +2713,23 @@ class Engine:
         self._offload_pending = []
         self._offload_pending_keys = set()
         store = self.host_store
-        for key, data, scales in pending:
-            with tr.span("offload.demote", cat="serving",
-                         key=key) as sp:
-                try:
-                    d = np.asarray(data)[:, :, 0]
-                    s = (np.asarray(scales)[:, :, 0]
-                         if scales is not None else None)
-                    ok = store.put(key, d, s)
-                except Exception:
-                    ok = False  # a dead gather (pools recovered
-                    #   mid-flight) must not fail the tick
-                if ok:
-                    self._m_offload_demotes.inc()
-                sp.args.update(stored=bool(ok))
-        self._m_kv_host_blocks.set(len(store))
-        self._m_kv_host_bytes.set(store.bytes_used)
+        with tr.span("offload.service", blocks=len(pending)):
+            for key, data, scales in pending:
+                with tr.span("offload.demote", cat="serving",
+                             key=key) as sp:
+                    try:
+                        d = np.asarray(data)[:, :, 0]
+                        s = (np.asarray(scales)[:, :, 0]
+                             if scales is not None else None)
+                        ok = store.put(key, d, s)
+                    except Exception:
+                        ok = False  # a dead gather (pools recovered
+                        #   mid-flight) must not fail the tick
+                    if ok:
+                        self._m_offload_demotes.inc()
+                    sp.args.update(stored=bool(ok))
+            self._m_kv_host_blocks.set(len(store))
+            self._m_kv_host_bytes.set(store.bytes_used)
 
     def _flush_offload(self):
         """Drain pending demotes at loop-idle boundaries
@@ -2713,6 +2806,61 @@ class Engine:
         return n
 
     # -- tracing / flight recorder / debug surface ---------------------
+    def _dev_note(self, program, handle, batch=0, n=0, req=None,
+                  role=None, t_dispatch=None):
+        """Hand the device watcher one dispatch the engine thread just
+        made.  ``program`` is the ``_compile_probe`` kind, ``handle``
+        the program's smallest output (never a pool, never donated;
+        None = already complete), ``batch`` its decode lanes, ``n``
+        the prompt tokens it carried and ``req`` the request when it
+        served one.  The span is ``dev.prefill`` when ``n`` >= 1 and
+        ``dev.decode`` when 0 — whatever the program, so the names
+        hold for the XLA paths (separate programs) and the ragged
+        window (one program) alike; ``role="gather"`` marks a KV
+        copy-out that shares the stream.  ``t_dispatch`` defaults to
+        now, the dispatch call having just returned.  A no-op with
+        ``tracing=False``."""
+        q = self._dev_q
+        if q is None:
+            return
+        if t_dispatch is None:
+            t_dispatch = time.perf_counter()
+        if self._dev_thread is None:
+            self._start_watcher()
+        if role is None:
+            role = "prefill" if n else "decode"
+        args = {"program": program, "tick": self.tick_no,
+                "batch": batch, "n": n}
+        if req is not None:
+            args["req"] = req
+        q.put(("dev." + role, t_dispatch, handle, args))
+
+    def _start_watcher(self):
+        q = self._dev_q
+        t = threading.Thread(
+            target=_watch_device, daemon=True,
+            args=(q, self.tracer, self._m_dev_busy),
+            name="paddle_tpu-serving-device")
+        t.start()
+        self._dev_thread = t
+        # an engine dropped without stop() takes its watcher with it
+        self._dev_finalizer = weakref.finalize(self, q.put, None)
+
+    def _stop_watcher(self, timeout):
+        """End the watcher after it has seen every dispatch handed to
+        it so far (``stop()``); the next dispatch starts a new one."""
+        t = self._dev_thread
+        if t is None:
+            return
+        self._dev_finalizer.detach()
+        self._dev_q.put(None)
+        t.join(timeout)
+        if t.is_alive():
+            # wedged on a dispatch that never completes: leave it its
+            # queue (the sentinel is in it) and start over
+            self._dev_q = queue.SimpleQueue()
+        self._dev_thread = None
+
     def _register_compile_listener(self):
         """Subscribe this engine to the model's compile events
         (idempotent).  ``stop()`` unsubscribes — a stopped engine must
@@ -2742,6 +2890,7 @@ class Engine:
         triggered it."""
         self._m_compiles.inc()
         self._m_compile_ms.observe(wall_s * 1e3)
+        self._m_compile_wall.inc(wall_s * 1e3)
         # keep only the scalar fields of the program cache key — it
         # embeds the full parameter-name tuple, useless in a trace
         brief = ([x for x in key
@@ -3174,22 +3323,25 @@ class Engine:
             def put(a):
                 return jnp.asarray(a.copy())
             sync = nullcontext()
-        with sync:
-            self._dev_state = dict(
-                tok=put(self._cur_tok), pos=put(self._pos),
-                ctr=put(self._sctr), temp=put(self._temp),
-                topk=put(self._topk), topp=put(self._topp),
-                slo=put(self._seed_lo), shi=put(self._seed_hi),
-                eos=put(self._eos), rem=put(self._rem))
-            if self.adapters is not None:
-                self._dev_state["aid"] = put(self._aid)
-            if self._paged:
-                self._dev_state["tables"] = put(self._block_tables)
-                # per-slot scratch block ids (constant per engine
-                # config, but rides the state dict so the ragged
-                # dispatch signature stays uniform): masked/parked
-                # lanes park in their OWN dp shard's scratch row
-                self._dev_state["scratch"] = put(self._slot_scratch)
+        mirrors = dict(
+            tok=self._cur_tok, pos=self._pos, ctr=self._sctr,
+            temp=self._temp, topk=self._topk, topp=self._topp,
+            slo=self._seed_lo, shi=self._seed_hi, eos=self._eos,
+            rem=self._rem)
+        if self.adapters is not None:
+            mirrors["aid"] = self._aid
+        if self._paged:
+            mirrors["tables"] = self._block_tables
+            # per-slot scratch block ids (constant per engine config,
+            # but rides the state dict so the ragged dispatch
+            # signature stays uniform): masked/parked lanes park in
+            # their OWN dp shard's scratch row
+            mirrors["scratch"] = self._slot_scratch
+        with self.tracer.span(
+                "state.push",
+                bytes=sum(int(a.nbytes) for a in mirrors.values())), \
+                sync:
+            self._dev_state = {k: put(a) for k, a in mirrors.items()}
         self._state_dirty = False
 
     def _prefill_paged(self, slot):
@@ -3222,14 +3374,14 @@ class Engine:
             jnp.asarray(np.asarray(ctx, np.int32)),
             jnp.asarray(np.asarray(fresh[:n_tail], np.int32)),
             *self._lora_args_slot(req))
+        self._dev_note(pf.kind, last0, n=s_tail, req=req.id)
         if self.prefix_cache is not None and not req._adapter_id:
             self.prefix_cache.insert(tokens, blocks[:s // self._bs])
         self._m_prefill_tokens.inc(s_tail)
         slot.pos = s
         slot.prefilled = s
         self._pos[i] = s
-        tok = self._pick(req, np.asarray(last0, np.float32)[0])
-        self._emit(slot, tok)
+        self._emit(slot, self._first_token(req, last0))
 
     def _prefill(self, slot):
         """Admission prefill: one jitted whole-prompt forward (shared
@@ -3268,6 +3420,7 @@ class Engine:
             last0, k_bufs, v_bufs = pf(self._p_list(), self._b_list(),
                                        tokens[None, :],
                                        *self._lora_args_slot(req))
+        self._dev_note(pf.kind, last0, n=s, req=req.id)
         i = slot.index
         if self._insert_fn is None:
             import jax
@@ -3293,8 +3446,7 @@ class Engine:
         slot.pos = s
         slot.prefilled = s
         self._pos[i] = s
-        tok = self._pick(req, np.asarray(last0, np.float32)[0])
-        self._emit(slot, tok)
+        self._emit(slot, self._first_token(req, last0))
 
     # -- budgeted chunked prefill (prefill_chunk=...) ------------------
     def _begin_chunked(self, slot):
@@ -3366,6 +3518,7 @@ class Engine:
                     jnp.asarray(p0, jnp.int32),
                     jnp.asarray(n, jnp.int32),
                     *self._lora_args_slot(req))
+            self._dev_note(fn.kind, last0, n=n, req=req.id)
         slot.prefilled = p0 + n
         slot.pos = slot.prefilled
         self._m_chunks.inc()
@@ -3385,8 +3538,7 @@ class Engine:
             self.prefix_cache.insert(tokens,
                                      self._slot_blocks[i][:s // self._bs])
         self._pos[i] = s
-        tok = self._pick(req, np.asarray(last0, np.float32)[0])
-        self._emit(slot, tok)
+        self._emit(slot, self._first_token(req, last0))
         return 1
 
     def _prefill_chunked(self, prefilling):
@@ -3420,6 +3572,16 @@ class Engine:
             else:
                 queue.append(slot)
         return emitted, newly, evicted
+
+    def _first_token(self, req, last0):
+        """Download the prefill's last-position logits and pick the
+        request's first token.  The download waits for the prefill
+        program (and whatever was queued ahead of it on the device):
+        ``prefill.d2h``, counted as time blocked on the device."""
+        with self.tracer.span("prefill.d2h", req=req.id) as sp:
+            row = np.asarray(last0, np.float32)[0]
+        self._blocked_s += sp.elapsed
+        return self._pick(req, row)
 
     def _pick(self, req, row):
         """Next token from one slot's f32 logits row: argmax (greedy —
@@ -3614,9 +3776,11 @@ class Engine:
                     self._p_list(), self._b_list(), self.k_pools,
                     self.v_pools, jnp.asarray(toks),
                     jnp.asarray(self._pos))
+        self._dev_note(fn.kind, last, batch=len(active))
         with tr.span("decode.d2h") as d2h_sp:
             rows = np.asarray(last, np.float32)       # [B, W, V]
             d2h_sp.args["bytes"] = rows.nbytes
+        self._blocked_s += d2h_sp.elapsed
         self._m_d2h.set(rows.nbytes)
         self._m_spec_windows.inc(len(active))
         t_sample = time.monotonic()
@@ -3675,6 +3839,7 @@ class Engine:
         self._m_spec_tpt.set(emitted / len(active))
         return emitted
 
+    @_spanned("dispatch")
     def _dispatch_spec(self, active, tr):
         """DISPATCH one fused speculative draft-and-verify tick
         without consuming it: the verify dispatch picks every window
@@ -3723,6 +3888,8 @@ class Engine:
             (picks, n_acc, n_emit, done, new_tok, new_pos, new_ctr,
              new_rem, self.k_pools, self.v_pools) = \
                 self._fused_spec_fn(*args)
+        self._dev_note(self._fused_spec_fn.kind, n_emit,
+                       batch=len(active))
         st["tok"], st["pos"], st["ctr"], st["rem"] = \
             new_tok, new_pos, new_ctr, new_rem
         self._m_fused_ticks.inc()
@@ -3822,6 +3989,7 @@ class Engine:
         inf = self._dispatch_spec(active, self.tracer)
         return self._consume(inf, self.tracer)
 
+    @_spanned("dispatch")
     def _dispatch_decode(self, active, tr):
         """DISPATCH one fused decode+sample tick (sample_mode=
         "device") without consuming it: the step state lives on
@@ -3860,6 +4028,7 @@ class Engine:
                 self._dequant_span(tr, len(active)):
             (ids, done, new_tok, new_pos, new_ctr, new_rem,
              self.k_pools, self.v_pools) = self._fused_fn(*args)
+        self._dev_note(self._fused_fn.kind, ids, batch=len(active))
         st["tok"], st["pos"], st["ctr"], st["rem"] = \
             new_tok, new_pos, new_ctr, new_rem
         self._m_fused_ticks.inc()
@@ -3937,6 +4106,7 @@ class Engine:
                 break
         return plan
 
+    @_spanned("dispatch")
     def _dispatch_ragged(self, active, plan, tr):
         """DISPATCH one unified RAGGED window tick without consuming
         it: decoding slots ride as mode-0 lanes (width 1, or the k+1
@@ -4043,6 +4213,10 @@ class Engine:
                 st["topk"], st["topp"], st["slo"], st["shi"],
                 st["ctr"], st["eos"], st["rem"],
                 *self._lora_args_state(st))
+        self._dev_note(
+            self._ragged_fn.kind, n_emit, batch=len(active),
+            n=chunk_toks,
+            req=plan[0][0].request.id if len(plan) == 1 else None)
         st["tok"], st["pos"], st["ctr"], st["rem"] = \
             new_tok, new_pos, new_ctr, new_rem
         self._m_fused_ticks.inc()
@@ -4134,6 +4308,7 @@ class Engine:
             self._m_spec_tpt.set(emitted_spec / n_spec)
         return emitted
 
+    @_spanned("consume")
     def _consume(self, inf, tr):
         """Materialize and emit one in-flight tick.  The blocking
         ``np.asarray`` on the ids + done mask is the async loop's ONLY
@@ -4161,14 +4336,16 @@ class Engine:
             # (tiny, unchanged-contract) host copy alone.
             with tr.span("decode.allgather", tick=inf.tick,
                          shards=self.mp * self.dp, mp=self.mp,
-                         dp=self.dp):
+                         dp=self.dp) as ag_sp:
                 for v in inf.arrays.values():
                     v.block_until_ready()
+            self._blocked_s += ag_sp.elapsed
         t0 = time.monotonic()
         with tr.span(wait_name, tick=inf.tick) as d2h_sp:
             mats = {k: np.asarray(v) for k, v in inf.arrays.items()}
             nbytes = sum(int(a.nbytes) for a in mats.values())
             d2h_sp.args["bytes"] = nbytes
+        self._blocked_s += d2h_sp.elapsed
         self._m_d2h_wait.observe((time.monotonic() - t0) * 1e3)
         self._m_d2h.set(nbytes)
         done = np.unpackbits(mats["done"],
@@ -4198,13 +4375,15 @@ class Engine:
                 (time.monotonic() - self._last_decode_end) * 1e3)
         self._m_decode_batch.set(n_active)
 
-    def _drain_ring(self, tr):
+    def _drain_ring(self, tr, why):
         """Consume every in-flight tick, oldest first (the dirty-event
         barrier: mirrors may only be re-uploaded over an empty
-        pipeline).  Returns tokens emitted."""
+        pipeline) under a ``ring.drain`` span that says ``why``.
+        Returns tokens emitted."""
         emitted = 0
-        while self._ring:
-            emitted += self._consume(self._ring.pop(0), tr)
+        with tr.span("ring.drain", why=why, ticks=len(self._ring)):
+            while self._ring:
+                emitted += self._consume(self._ring.pop(0), tr)
         return emitted
 
     def _fused_decode_tick(self, active):
@@ -4258,9 +4437,11 @@ class Engine:
                     self._p_list(), self._b_list(), self.k_pools,
                     self.v_pools, jnp.asarray(self._cur_tok),
                     jnp.asarray(self._pos))
+        self._dev_note(fn.kind, last, batch=len(active))
         with tr.span("decode.d2h") as d2h_sp:
             rows = np.asarray(last, np.float32)
             d2h_sp.args["bytes"] = rows.nbytes
+        self._blocked_s += d2h_sp.elapsed
         self._m_d2h.set(rows.nbytes)
         t_sample = time.monotonic()
         emitted = 0
@@ -4326,12 +4507,19 @@ class Engine:
         self._tick_started_at = time.monotonic()
         try:
             self._fault("host_slow")
+            self._blocked_s = 0.0
             with tr.span("tick", cat="tick",
                          tick=self.tick_no) as tick_sp:
+                t0 = time.perf_counter()
                 if self.async_depth > 1:
                     emitted = self._tick_async(tr, tick_sp)
                 else:
                     emitted = self._tick(tr, tick_sp)
+                # the tick's host share: its duration less the time
+                # it was blocked on the device (d2h syncs, collectives)
+                tick_sp.args["host_ms"] = round(
+                    (time.perf_counter() - t0 - self._blocked_s) * 1e3,
+                    3)
         finally:
             self._tick_started_at = None
         if emitted:
@@ -4398,18 +4586,21 @@ class Engine:
                     self._prefill(slot)
                 emitted += 1  # prefill samples the first token
         else:
-            for slot in admitted:
-                self._begin_chunked(slot)
-            _, _, prefilling = self.scheduler.snapshot()
-            if prefilling and not self._ragged:
-                # ragged mode: chunks ride as lanes of the unified
-                # dispatch below — and because their tokens are known
-                # up front (no data dependence on the in-flight
-                # window), chunk progress needs NO pipeline drain,
-                # unlike the XLA per-chunk programs whose cursor
-                # updates dirty the mirrors every chunk
-                n_emit, _, _ = self._prefill_chunked(prefilling)
-                emitted += n_emit
+            with tr.span("chunk.plan") as plan_sp:
+                for slot in admitted:
+                    self._begin_chunked(slot)
+                _, _, prefilling = self.scheduler.snapshot()
+                plan_sp.args["prefilling"] = len(prefilling)
+                if prefilling and not self._ragged:
+                    # ragged mode: chunks ride as lanes of the unified
+                    # dispatch below — and because their tokens are
+                    # known up front (no data dependence on the
+                    # in-flight window), chunk progress needs NO
+                    # pipeline drain, unlike the XLA per-chunk
+                    # programs whose cursor updates dirty the mirrors
+                    # every chunk
+                    n_emit, _, _ = self._prefill_chunked(prefilling)
+                    emitted += n_emit
         # -- spec barrier: drafting is data-dependent on the previous
         #    window's accepted tokens, so spec mode always consumes
         #    before the dispatch snapshot — but only HERE, after the
@@ -4417,12 +4608,12 @@ class Engine:
         #    ticks still overlap their plan work with the in-flight
         #    verify's device compute --------------------------------
         if self._spec_k is not None and self._ring:
-            emitted += self._drain_ring(tr)
+            emitted += self._drain_ring(tr, "spec")
         # -- dirty barrier: consumed evictions must not leave freed
         #    slots in the dispatch set, and _push_state may only run
         #    over an empty pipeline ---------------------------------
         if self._ring and (self._state_dirty or self._dev_state is None):
-            emitted += self._drain_ring(tr)
+            emitted += self._drain_ring(tr, "dirty")
         occ, active, prefilling = self.scheduler.snapshot()
         ragged = self._ragged
         if active and self._ring and self._spec_k is None and \
@@ -4436,11 +4627,13 @@ class Engine:
             # earlier than its budget; that case just falls through
             # to the done-mask path).  Pending ragged chunk lanes
             # veto the cutoff: their dispatch still does real work.
-            emitted += self._drain_ring(tr)
+            emitted += self._drain_ring(tr, "tail")
             occ, active, prefilling = self.scheduler.snapshot()
         n_before = self._evicted_in_tick
-        plan = (self._plan_ragged_chunks(prefilling)
-                if ragged and self._chunk is not None else [])
+        plan = []
+        if ragged and self._chunk is not None and prefilling:
+            with tr.span("chunk.plan", prefilling=len(prefilling)):
+                plan = self._plan_ragged_chunks(prefilling)
         # -- dispatch tick N+1 ---------------------------------------
         if active or plan:
             self._note_dispatch_gap(len(active))
@@ -4465,7 +4658,7 @@ class Engine:
             # every slot freed while the newest dispatch was in
             # flight: its lanes are all frozen (device-side stop), so
             # drain the tail — an idle engine must hold no futures
-            emitted += self._drain_ring(tr)
+            emitted += self._drain_ring(tr, "idle")
         self._m_queue.set(self.queue.depth())
         self._m_occ.set(occ)
         ov_ms = self._overlap_acc * 1e3
@@ -4504,6 +4697,7 @@ class Engine:
         emitted += p_emitted
         admitted = self._post_admit(admitted + p_admitted,
                                     timed_out + p_timed, tr)
+        plan = []   # ragged mode: this tick's prefill-chunk lanes
         if self._chunk is None:
             for slot in admitted:
                 # read the id up front: an EOS-on-first-token prefill
@@ -4515,21 +4709,24 @@ class Engine:
                 emitted += 1  # prefill samples the first token
             occ, active, prefilling = self.scheduler.snapshot()
         else:
-            for slot in admitted:
-                self._begin_chunked(slot)
-            occ, active, prefilling = self.scheduler.snapshot()
-            if prefilling and not self._ragged:
-                # ragged mode skips the per-chunk dispatch loop —
-                # chunks ride as window lanes of the unified dispatch
-                n_emit, newly, n_evicted = \
-                    self._prefill_chunked(prefilling)
-                emitted += n_emit
-                occ -= n_evicted
-                active = active + newly  # final-chunk slots decode in
-                #   this same tick, like monolithic emit-then-decode
+            with tr.span("chunk.plan") as plan_sp:
+                for slot in admitted:
+                    self._begin_chunked(slot)
+                occ, active, prefilling = self.scheduler.snapshot()
+                plan_sp.args["prefilling"] = len(prefilling)
+                if self._ragged:
+                    # chunks ride as window lanes of the unified
+                    # dispatch, not through the per-chunk loop
+                    plan = self._plan_ragged_chunks(prefilling)
+                elif prefilling:
+                    n_emit, newly, n_evicted = \
+                        self._prefill_chunked(prefilling)
+                    emitted += n_emit
+                    occ -= n_evicted
+                    active = active + newly  # final-chunk slots decode
+                    #   in this same tick, like monolithic
+                    #   emit-then-decode
         if self._ragged:
-            plan = (self._plan_ragged_chunks(prefilling)
-                    if self._chunk is not None else [])
             if active or plan:
                 self._note_dispatch_gap(len(active))
                 n_before = self._evicted_in_tick
@@ -4729,6 +4926,7 @@ class Engine:
         # completing inside the join window above still count.
         # start() — or a synchronous step() — re-subscribes.
         self._unregister_compile_listener()
+        self._stop_watcher(join_timeout)
         if drain:
             self._drain_on_exit = None
             self._drain()

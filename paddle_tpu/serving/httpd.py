@@ -437,60 +437,14 @@ class _Handler(JsonHandler):
             self._send_json(404, {"error": f"no route {self.path}",
                                   "reason": "not_found"})
             return
-        try:
-            n = int(self.headers.get("Content-Length", 0))
-            body = json.loads(self.rfile.read(n) or b"{}")
-            prompt = body["prompt"]
-            max_new = int(body.get("max_new_tokens", 16))
-        except (KeyError, TypeError, ValueError,
-                json.JSONDecodeError) as e:
-            self._send_json(400, {"error": f"bad request: {e}",
-                                  "reason": "bad_request"})
-            return
-        err = self._validate_prompt(prompt, max_new)
-        if err is not None:
-            self._send_json(400, {"error": err,
-                                  "reason": "bad_request"})
-            return
-        try:
-            req = self.engine.submit(
-                prompt,
-                max_new_tokens=max_new,
-                eos_token_id=body.get("eos_token_id"),
-                timeout=body.get("timeout"),
-                temperature=float(body.get("temperature", 1.0)),
-                top_k=int(body.get("top_k", 0)),
-                top_p=float(body.get("top_p", 1.0)),
-                seed=body.get("seed"),
-                priority=int(body.get("priority", 0)),
-                tenant=body.get("tenant"),
-                adapter=body.get("adapter"))
-        except UnknownAdapter as e:
-            # 404, not 400: the request is well-formed — THIS replica
-            # lacks the adapter.  The router retries elsewhere on it.
-            self._send_json(404, {"error": str(e),
-                                  "reason": "unknown_adapter"})
-            return
-        except Rejected as e:
-            # every shed (QueueFull / DeadlineShed 503, RateLimited
-            # 429) carries the engine's COMPUTED backoff: queue
-            # backlog over the measured drain rate, or the token
-            # bucket's refill time — an honest hint, not a constant
-            code = 429 if isinstance(e, RateLimited) else 503
-            self._send_json(
-                code,
-                {"error": str(e),
-                 "reason": _shed_reason(e, draining=bool(
-                     getattr(self.engine, "_draining", False)))},
-                headers=_retry_after_header(e))
-            return
-        except (TypeError, ValueError) as e:
-            # TypeError covers JSON nulls / non-numeric fields hitting
-            # the int()/float() coercions — still a 400, not a dropped
-            # connection
-            self._send_json(400, {"error": str(e),
-                                  "reason": "bad_request"})
-            return
+        tracer = self.engine.tracer
+        # the edge's share of the wait for a first token: body read,
+        # JSON decode, validation and submit (the request's id is
+        # only known at exit)
+        with tracer.span("http.ingest", cat="http") as sp:
+            req, body = self._ingest(sp)
+        if req is None:
+            return  # _ingest answered
         if body.get("stream"):
             self._stream_response(req)
             return
@@ -537,6 +491,71 @@ class _Handler(JsonHandler):
             "generated": [int(x) for x in req.generated],
             "ttft_ms": ttft,
         })
+        # not streaming: the first token reaches the client with the
+        # whole body
+        tracer.instant("http.first_frame", cat="http", req=req.id)
+
+    def _ingest(self, sp):
+        """Read, decode, validate and submit one ``/generate`` body
+        under the ``http.ingest`` span ``sp``.  Returns ``(request,
+        body)``, or ``(None, None)`` after answering the client with
+        the refusal."""
+        try:
+            n = int(self.headers.get("Content-Length", 0))
+            body = json.loads(self.rfile.read(n) or b"{}")
+            prompt = body["prompt"]
+            max_new = int(body.get("max_new_tokens", 16))
+        except (KeyError, TypeError, ValueError,
+                json.JSONDecodeError) as e:
+            self._send_json(400, {"error": f"bad request: {e}",
+                                  "reason": "bad_request"})
+            return None, None
+        err = self._validate_prompt(prompt, max_new)
+        if err is not None:
+            self._send_json(400, {"error": err,
+                                  "reason": "bad_request"})
+            return None, None
+        try:
+            req = self.engine.submit(
+                prompt,
+                max_new_tokens=max_new,
+                eos_token_id=body.get("eos_token_id"),
+                timeout=body.get("timeout"),
+                temperature=float(body.get("temperature", 1.0)),
+                top_k=int(body.get("top_k", 0)),
+                top_p=float(body.get("top_p", 1.0)),
+                seed=body.get("seed"),
+                priority=int(body.get("priority", 0)),
+                tenant=body.get("tenant"),
+                adapter=body.get("adapter"))
+        except UnknownAdapter as e:
+            # 404, not 400: the request is well-formed — THIS replica
+            # lacks the adapter.  The router retries elsewhere on it.
+            self._send_json(404, {"error": str(e),
+                                  "reason": "unknown_adapter"})
+            return None, None
+        except Rejected as e:
+            # every shed (QueueFull / DeadlineShed 503, RateLimited
+            # 429) carries the engine's COMPUTED backoff: queue
+            # backlog over the measured drain rate, or the token
+            # bucket's refill time — an honest hint, not a constant
+            code = 429 if isinstance(e, RateLimited) else 503
+            self._send_json(
+                code,
+                {"error": str(e),
+                 "reason": _shed_reason(e, draining=bool(
+                     getattr(self.engine, "_draining", False)))},
+                headers=_retry_after_header(e))
+            return None, None
+        except (TypeError, ValueError) as e:
+            # TypeError covers JSON nulls / non-numeric fields hitting
+            # the int()/float() coercions — still a 400, not a dropped
+            # connection
+            self._send_json(400, {"error": str(e),
+                                  "reason": "bad_request"})
+            return None, None
+        sp.args.update(req=req.id, bytes=n, prompt=int(len(req.prompt)))
+        return req, body
 
     # -- SSE streaming (POST /generate {"stream": true}) ---------------
     def _stream_response(self, req):
@@ -597,6 +616,9 @@ class _Handler(JsonHandler):
                     self._stream_error(req, ev.error, sent)
                     return
                 self.wfile.flush()
+                if sent == 1 and ev.kind == "token":
+                    self.engine.tracer.instant(
+                        "http.first_frame", cat="http", req=req.id)
         except (BrokenPipeError, ConnectionResetError, OSError):
             # the client vanished mid-stream: nothing to answer; the
             # engine lands the request and this sink dies with the

@@ -2282,3 +2282,291 @@ def test_queue_vfin_map_stays_bounded():
         got, _ = q.pop_ready()
         assert got is not None
     assert len(q._vfin) <= 300
+
+
+# ---------------------------------------------------------------------------
+# the device lane (dev.* spans), the tick's host spans, the HTTP edge
+# ---------------------------------------------------------------------------
+
+_DISPATCH_SPANS = {"decode.dispatch", "decode.ragged_stream",
+                   "decode.ragged", "prefill.chunk", "prefill"}
+
+
+def _spans(eng, cat=None, name=None):
+    return [e for e in eng.chrome_trace()["traceEvents"]
+            if e.get("ph") == "X"
+            and (cat is None or e.get("cat") == cat)
+            and (name is None or e["name"] == name)]
+
+
+@pytest.mark.parametrize("kw", [
+    dict(async_depth=1),
+    dict(async_depth=2),
+    dict(async_depth=1, kv_block_size=8, prefill_chunk=8),
+    dict(async_depth=2, kv_block_size=8, prefill_chunk=8),
+    dict(async_depth=1, kv_block_size=8, prefill_chunk=8,
+         attn_impl="ragged"),
+    dict(async_depth=2, kv_block_size=8, prefill_chunk=8,
+         attn_impl="ragged"),
+    dict(async_depth=2, kv_block_size=8, attn_impl="ragged"),
+    dict(sample_mode="host", kv_block_size=8),
+], ids=lambda kw: "-".join(f"{k}={v}" for k, v in kw.items()))
+def test_dev_spans_one_per_dispatch(tiny_gpt, kw):
+    """The device watcher emits one ``dev.*`` span per dispatch of a
+    model program, on the shared ``device`` lane: ordered and disjoint,
+    ending after the host span that dispatched it began, named
+    ``dev.prefill`` when it carried prompt tokens and ``dev.decode``
+    when none, with the program's probe kind, the tick that caused it
+    and what it carried; ``serving.dev_busy_ms`` sums the durations."""
+    eng = _engine(tiny_gpt, **kw)
+    busy0 = eng.registry.get("serving.dev_busy_ms").value
+    pre0 = eng.registry.get("serving.prefill_tokens").value
+    reqs = [eng.submit(p, max_new_tokens=5) for p in _prompts(3)]
+    eng.step()
+    reqs += [eng.submit(p, max_new_tokens=4) for p in _prompts(5)[3:]]
+    eng.run_until_idle()
+    eng.stop()              # joins the watcher: every span is out
+    assert all(r.done() for r in reqs)
+    dev = sorted(_spans(eng, cat="device"), key=lambda e: e["ts"])
+    host = sorted((e for e in _spans(eng)
+                   if e["name"] in _DISPATCH_SPANS),
+                  key=lambda e: e["ts"])
+    assert len(dev) == len(host) > 0
+    assert len({e["tid"] for e in dev}) == 1
+    names = {e["args"]["name"]: e["tid"]
+             for e in eng.chrome_trace()["traceEvents"]
+             if e["ph"] == "M" and e["name"] == "thread_name"}
+    assert names["device"] == dev[0]["tid"]
+    for a, b in zip(dev, dev[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 1e-3      # disjoint
+    ticks = {e["args"]["tick"]: e for e in _spans(eng, name="tick")}
+    for d, h in zip(dev, host):
+        a = d["args"]
+        assert d["dur"] >= 0
+        assert d["ts"] + d["dur"] >= h["ts"]    # ends after it began
+        assert d["ts"] + d["dur"] >= ticks[a["tick"]]["ts"]
+        assert isinstance(a["program"], str) and a["program"]
+        assert d["name"] == ("dev.prefill" if a["n"] else "dev.decode")
+        if h["name"] in ("prefill", "prefill.chunk"):
+            assert a["n"] >= 1 and a["req"] == h["args"]["req"]
+        else:
+            assert a["batch"] + a["n"] >= 1
+        if h["name"] == "decode.dispatch":
+            assert (a["batch"], a["n"]) == (h["args"]["batch"], 0)
+    # prompt tokens ride on dev.prefill spans, all of them
+    assert sum(e["args"]["n"] for e in dev) == \
+        eng.registry.get("serving.prefill_tokens").value - pre0
+    busy = eng.registry.get("serving.dev_busy_ms").value - busy0
+    assert busy == pytest.approx(sum(e["dur"] for e in dev) * 1e-3,
+                                 rel=1e-6, abs=1e-6)
+
+
+def _watchers():
+    return [t for t in threading.enumerate()
+            if t.name == "paddle_tpu-serving-device"]
+
+
+def test_device_watcher_lifecycle(tiny_gpt):
+    """One watcher thread per engine, started at the first dispatch,
+    stopped and joined by ``stop()``; 50 engines built and stopped leak
+    none, a collected engine takes its watcher with it, and
+    ``tracing=False`` starts none."""
+    import gc
+    gc.collect()
+    deadline = time.monotonic() + 10.0
+    while _watchers() and time.monotonic() < deadline:
+        time.sleep(0.05)    # watchers of engines other tests dropped
+    base = len(_watchers())
+    eng = _engine(tiny_gpt)
+    assert len(_watchers()) == base          # lazy: nothing dispatched
+    eng.submit(_prompts(1)[0], max_new_tokens=2)
+    eng.run_until_idle()
+    assert len(_watchers()) == base + 1
+    eng.stop()
+    assert len(_watchers()) == base and eng._dev_thread is None
+    # a stopped engine that ticks again starts a new one
+    eng.submit(_prompts(1)[0], max_new_tokens=2)
+    eng.run_until_idle()
+    assert len(_watchers()) == base + 1
+    eng.stop()
+    for _ in range(50):
+        e = _engine(tiny_gpt)
+        e.submit(_prompts(1)[0], max_new_tokens=2)
+        e.run_until_idle()
+        e.stop()
+    assert len(_watchers()) == base
+    # dropped without stop(): the watcher goes with the engine
+    e = _engine(tiny_gpt)
+    e.submit(_prompts(1)[0], max_new_tokens=2)
+    e.run_until_idle()
+    assert len(_watchers()) == base + 1
+    del e
+    deadline = time.monotonic() + 10.0
+    while len(_watchers()) > base and time.monotonic() < deadline:
+        gc.collect()
+        time.sleep(0.05)
+    assert len(_watchers()) == base
+    off = _engine(tiny_gpt, tracing=False)
+    off.submit(_prompts(1)[0], max_new_tokens=3)
+    off.run_until_idle()
+    assert len(_watchers()) == base and off._dev_q is None
+    assert off.registry.get("serving.dev_busy_ms").value == 0
+    assert off.chrome_trace()["traceEvents"] == []
+    off.stop()
+
+
+@pytest.mark.parametrize("kw", [
+    dict(kv_block_size=8, prefill_chunk=8),
+    dict(kv_block_size=8, prefill_chunk=8, attn_impl="ragged"),
+    dict(async_depth=1, kv_block_size=8),
+], ids=lambda kw: "-".join(f"{k}={v}" for k, v in kw.items()))
+def test_tick_host_spans_cover_host_ms(tiny_gpt, kw):
+    """Every phase of the tick runs under a span of its own: over a
+    run, the tick's direct children leave under a tenth of ``host_ms``
+    (the tick less the time blocked on the device) uncovered, and
+    ``host_ms`` never exceeds the tick."""
+    eng = _engine(tiny_gpt, **kw)
+    # warm the programs: a first call's trace and compile is host time
+    # inside the dispatch span, which would make the rest look small
+    eng.submit(_prompts(1)[0], max_new_tokens=2)
+    eng.run_until_idle()
+    eng.tracer.clear()
+    for p in _prompts(4):
+        eng.submit(p, max_new_tokens=6)
+    eng.step()
+    for p in _prompts(6)[4:]:
+        eng.submit(p, max_new_tokens=6)
+    eng.run_until_idle()
+    eng.stop()
+    ticks = _spans(eng, name="tick")
+    lane = ticks[0]["tid"]
+    inner = [e for e in _spans(eng)
+             if e["tid"] == lane and e["name"] != "tick"]
+    host_ms = uncovered_ms = 0.0
+    seen = set()
+    for t in ticks:
+        t0, t1 = t["ts"], t["ts"] + t["dur"]
+        assert 0 <= t["args"]["host_ms"] <= t["dur"] * 1e-3 + 1e-3
+        kids = sorted((e for e in inner if t0 <= e["ts"] < t1),
+                      key=lambda e: (e["ts"], -e["dur"]))
+        end = t0
+        direct = 0.0
+        for e in kids:
+            seen.add(e["name"])
+            if e["ts"] >= end:          # not nested in the one before
+                assert e["ts"] + e["dur"] <= t1 + 1e-3   # contained
+                direct += e["dur"]
+                end = e["ts"] + e["dur"]
+        host_ms += t["args"]["host_ms"]
+        uncovered_ms += (t["dur"] - direct) * 1e-3
+    assert {"admit", "preempt", "post_admit", "state.push"} <= seen
+    if "prefill_chunk" in kw:
+        assert "chunk.plan" in seen
+    if kw.get("async_depth") != 1:
+        assert "ring.drain" in seen
+        whys = {e["args"]["why"] for e in _spans(eng, name="ring.drain")}
+        assert whys <= {"dirty", "spec", "tail", "idle", "preempt",
+                        "migrate", "adapter"}
+    assert all(e["args"]["bytes"] > 0
+               for e in _spans(eng, name="state.push"))
+    assert uncovered_ms <= 0.1 * host_ms, (uncovered_ms, host_ms)
+
+
+def test_compiled_programs_carry_their_probe_kind(tiny_gpt):
+    """Each of the fourteen jitted programs of models/gpt.py is named
+    after its ``_compile_probe`` kind (``jit_gpt_<kind>`` in a device
+    trace's ``XLA Modules``), all distinct; the probed callables an
+    engine holds say the same."""
+    import inspect
+    import re
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models import gpt
+    src = inspect.getsource(gpt)
+    pairs = re.findall(
+        r'fn = _jit_named\("(\w+)", pure.*?_compile_probe\(\s*"(\w+)"',
+        src, re.S)
+    assert len(pairs) == 14
+    assert all(a == b for a, b in pairs)
+    assert len({a for a, _ in pairs}) == 14
+    assert "= jax.jit(pure" not in src
+    fn = gpt._jit_named("fused_decode", lambda x: x + 1)
+    text = fn.lower(jnp.zeros(2)).as_text()
+    assert "jit_gpt_fused_decode" in text
+    # the programs engines of this module built on the shared model
+    _engine(tiny_gpt, kv_block_size=8, prefill_chunk=8).stop()
+    eng = _engine(tiny_gpt)
+    eng.submit(_prompts(1)[0], max_new_tokens=2)
+    eng.run_until_idle()
+    eng.stop()
+    kinds = set()
+    for attr in dir(tiny_gpt):
+        if attr.endswith("_fn_cache"):
+            for probed, _, _ in getattr(tiny_gpt, attr).values():
+                assert probed.__name__ == "gpt_" + probed.kind
+                kinds.add(probed.kind)
+    assert {"prefill", "fused_decode"} <= kinds
+    assert kinds <= {a for a, _ in pairs}
+    # named scopes reach the lowered program's op metadata
+    x = paddle.to_tensor(np.zeros((1, 4), np.int32))
+    hlo = jax.jit(lambda ids: tiny_gpt(paddle.Tensor(ids))._data).lower(
+        x._data).compile().as_text()
+    for scope in ("attention", "mlp", "lm_head"):
+        assert scope in hlo, scope
+
+
+def test_http_events_and_requests_lane(tiny_gpt):
+    """``http.ingest`` (a span from the top of ``do_POST`` to the
+    return of ``submit``) and ``http.first_frame`` (after the first
+    token frame's flush, or the whole body's) carry the ``req`` of the
+    engine's own instants, in order queued < ingest's end, first_token
+    <= first_frame; and a ``/debug/trace`` taken after 200 sequential
+    connections at the default ``max_threads`` still holds the first
+    connection's ``req.queued``."""
+    from paddle_tpu.serving.stream import parse_sse
+    eng = _engine(tiny_gpt, kv_block_size=8)
+    assert eng.tracer.max_threads == 64
+    ids = []
+    with EngineServer(eng, port=0) as srv:
+        def post(body):
+            return urllib.request.urlopen(urllib.request.Request(
+                f"{srv.address}/generate",
+                data=json.dumps(body).encode(),
+                headers={"Content-Type": "application/json"}))
+        for k in range(200):
+            body = {"prompt": [1 + k % 7, 2, 3], "max_new_tokens": 2}
+            if k % 50 == 0:
+                body["stream"] = True
+                with post(body) as resp:
+                    done = [json.loads(d) for ev, d in parse_sse(resp)
+                            if ev == "done"]
+                ids.append(done[0]["id"])
+            else:
+                with post(body) as resp:
+                    ids.append(json.loads(resp.read())["id"])
+        with urllib.request.urlopen(f"{srv.address}/debug/trace") as r:
+            trace = json.loads(r.read())["traceEvents"]
+    assert len(set(ids)) == 200
+    by = {}
+    for e in trace:
+        req = (e.get("args") or {}).get("req")
+        if req is not None and e["name"] in (
+                "req.queued", "http.ingest", "req.first_token",
+                "http.first_frame"):
+            by.setdefault(e["name"], {})[req] = e
+    for name in ("req.queued", "http.ingest", "req.first_token",
+                 "http.first_frame"):
+        assert set(by[name]) >= set(ids), name      # the first too
+    for req in ids:
+        ing, q = by["http.ingest"][req], by["req.queued"][req]
+        assert ing["ph"] == "X" and ing["args"]["prompt"] == 3
+        assert ing["args"]["bytes"] > 0
+        assert ing["ts"] <= q["ts"] <= ing["ts"] + ing["dur"]
+        assert by["http.first_frame"][req]["ph"] == "i"
+        assert by["req.first_token"][req]["ts"] <= \
+            by["http.first_frame"][req]["ts"]
+    lanes = {e["args"]["name"]: e["tid"] for e in trace
+             if e["ph"] == "M" and e["name"] == "thread_name"}
+    assert {e["tid"] for n in by for e in by[n].values()} == \
+        {lanes["requests"]}
+    assert len(lanes) < 10      # handler threads burned no lanes

@@ -477,3 +477,158 @@ def test_timeline_lifecycle_counts(tmp_path, capsys):
     assert "req.preempted=1" in err
     merged = json.loads(out_path.read_text())
     assert len(merged["traceEvents"]) == 4  # 3 events + process_name
+
+
+def test_shared_lanes_outlive_their_threads():
+    """Request-lifecycle and HTTP events go to ONE shared ``requests``
+    lane per tracer whichever thread emits them: after 200 sequential
+    short-lived handler threads at the default ``max_threads`` the
+    first connection's ``req.queued`` is still there, and those
+    threads burned no lane of their own."""
+    tr = Tracer()
+    assert tr.max_threads == 64
+
+    def handler(k):
+        with tr.span("http.ingest", cat="http") as sp:
+            tr.instant("req.queued", cat="request", req=k)
+            sp.args["req"] = k
+        tr.instant("http.first_frame", cat="http", req=k)
+
+    for k in range(200):
+        t = threading.Thread(target=handler, args=(k,))
+        t.start()
+        t.join()
+    evs = tr.events()
+    queued = [e.args["req"] for e in evs if e.name == "req.queued"]
+    assert queued == list(range(200))
+    assert sum(e.name == "http.ingest" for e in evs) == 200
+    assert sum(e.name == "http.first_frame" for e in evs) == 200
+    names = tr.thread_names()
+    assert list(names.values()) == ["requests"]     # one lane, shared
+    assert len({e.tid for e in evs}) == 1
+    # the lane is bounded like any other ring
+    small = Tracer(capacity=8)
+    for k in range(50):
+        small.instant("req.queued", cat="request", req=k)
+    assert [e.args["req"] for e in small.events()] == list(range(42, 50))
+
+
+def test_shared_lane_by_category_and_never_pruned():
+    """``cat`` picks the lane: ``request``/``http`` -> ``requests``,
+    ``device`` -> ``device``, anything else the emitting thread's own;
+    pruning dead threads' lanes never touches a shared one, and
+    ``max_threads`` stays a settable attribute."""
+    tr = Tracer(max_threads=2)
+    tr.instant("req.queued", cat="request", req=1)
+    tr.emit("dev.decode", 1.0, 0.5, cat="device", args={"batch": 2})
+    tr.instant("own")
+    by_name = {e.name: e.tid for e in tr.events()}
+    names = tr.thread_names()
+    assert names[by_name["req.queued"]] == "requests"
+    assert names[by_name["dev.decode"]] == "device"
+    assert names[by_name["own"]] == "MainThread"
+    for k in range(6):
+        t = threading.Thread(target=lambda: tr.instant("w"),
+                             name=f"w{k}")
+        t.start()
+        t.join()
+    names = tr.thread_names()
+    assert {"requests", "device", "MainThread"} <= set(names.values())
+    assert sum(v.startswith("w") for v in names.values()) <= 1
+    tr.max_threads = 1 << 20        # what the benchmark's harness does
+    assert tr.max_threads == 1 << 20
+    # export: the shared lanes are labelled like thread lanes
+    meta = {e["args"]["name"] for e in tr.chrome_trace()["traceEvents"]
+            if e["ph"] == "M" and e["name"] == "thread_name"}
+    assert {"requests", "device"} <= meta
+
+
+def test_trace_view_wall_reads_the_device_lane(tmp_path, capsys):
+    """--wall leaves the ``dev.*`` spans out of the host's phase sum
+    and prints the device lane: busy by span name, and the idle holes
+    summed by the narrowest host span open over each hole's middle."""
+    tv = _load_tool("trace_view")
+    events = [
+        {"name": "tick", "ph": "X", "ts": 0.0, "dur": 10000.0,
+         "cat": "tick"},
+        {"name": "state.push", "ph": "X", "ts": 4100.0, "dur": 800.0,
+         "cat": "serving"},
+        {"name": "tick", "ph": "X", "ts": 10000.0, "dur": 10000.0,
+         "cat": "tick"},
+        {"name": "dev.decode", "ph": "X", "ts": 0.0, "dur": 4000.0,
+         "cat": "device"},
+        {"name": "dev.prefill", "ph": "X", "ts": 5000.0, "dur": 1000.0,
+         "cat": "device"},
+        {"name": "dev.decode", "ph": "X", "ts": 6000.0, "dur": 6000.0,
+         "cat": "device"},
+        {"name": "dev.decode", "ph": "X", "ts": 14000.0, "dur": 4000.0,
+         "cat": "device"},
+    ]
+    w = tv.wall_summary(events)
+    assert w["phase_ms"] == pytest.approx(0.8)   # no dev.* in it
+    d = tv.device_summary(events)
+    assert d["busy_ms"] == pytest.approx(15.0)
+    assert d["idle_ms"] == pytest.approx(3.0)
+    assert d["span_ms"] == pytest.approx(18.0)
+    assert d["busy"]["dev.decode"] == (3, pytest.approx(14.0))
+    assert d["busy"]["dev.prefill"] == (1, pytest.approx(1.0))
+    assert d["idle_by_host_span"] == [
+        ("tick", pytest.approx(2.0)), ("state.push", pytest.approx(1.0))]
+    assert tv.device_summary(events[:3]) is None
+    path = tmp_path / "t.json"
+    path.write_text(json.dumps({"traceEvents": events}))
+    assert tv.main([str(path), "--wall"]) == 0
+    out = capsys.readouterr().out
+    assert "device lane: busy 15.000 ms" in out
+    assert "device idle 3.000 ms of 18.000 ms (16.7%)" in out
+    assert "state.push" in out.split("device idle")[1]
+
+
+def test_dev_lane_check_aligns_clocks_and_pairs_programs(monkeypatch):
+    """tools/dev_lane_check.py: the profiler's clock is aligned to the
+    engine's on the tick spans both hold, each ``dev.*`` span is paired
+    with the module event of its program nearest in end time, and the
+    spans clipped to the traced interval are summed against the
+    modules'."""
+    chk = _load_tool("dev_lane_check")
+    off = 5_000_000.0                       # profiler clock runs ahead
+    ticks = [1000.0 * k + 7.0 * ((k * k) % 11) for k in range(40)]
+    decode = [(1000.0 * k + 100.0, 1000.0 * k + 900.0)
+              for k in range(10, 20)]
+    chunk = [(1000.0 * k + 900.0, 1000.0 * k + 950.0)
+             for k in range(10, 20, 2)]
+    modules = {
+        "jit_gpt_fused_decode(1)": [(s + off, e + off) for s, e in decode],
+        "jit_gpt_paged_chunk_prefill(2)": [(s + off, e + off)
+                                           for s, e in chunk]}
+    monkeypatch.setattr(chk, "read_xplane", lambda path: (
+        modules, [t + off for t in ticks[10:20]]))
+    spans = [{"name": "tick", "ph": "X", "ts": t, "dur": 990.0,
+              "cat": "tick"} for t in ticks]
+    for name, program, ivs, late in (
+            ("dev.decode", "fused_decode", decode, 30.0),
+            ("dev.prefill", "paged_chunk_prefill", chunk, 20.0)):
+        spans += [{"name": name, "ph": "X", "cat": "device",
+                   "ts": s + late, "dur": e - s + 2.0,
+                   "args": {"program": program}} for s, e in ivs[:-1]]
+    src = {"spans": spans,
+           "counters": {"profile_delta": {"serving.dev_busy_ms": 8.0}},
+           "device": {"busy_s": 0.00825, "window_s": 0.01}}
+    got = chk.check(src, "unused.xplane.pb")
+    assert got["clock_offset_spread_us"] == pytest.approx(0.0, abs=1e-6)
+    d = got["programs"]["fused_decode"]
+    assert (d["span"], d["pairs"]) == ("dev.decode", 9)
+    assert d["module"] == "jit_gpt_fused_decode(1)"
+    assert d["dev_p50_ms"] == pytest.approx(0.802)
+    assert d["module_p50_ms"] == pytest.approx(0.800)
+    assert d["abs_diff_p50_ms"] == pytest.approx(0.002)
+    assert d["offset_p50_ms"] == pytest.approx(0.032)
+    c = got["programs"]["paged_chunk_prefill"]
+    assert (c["pairs"], c["offset_p90_ms"]) == (4, pytest.approx(0.022))
+    assert got["modules"] == {"jit_gpt_fused_decode(1)": 10,
+                              "jit_gpt_paged_chunk_prefill(2)": 5}
+    b = got["busy"]
+    assert b["modules_ms"] == pytest.approx(10 * 0.8 + 5 * 0.05)
+    assert b["dev_spans_clipped_ms"] == pytest.approx(
+        9 * 0.802 + 4 * 0.052)
+    assert b["dev_busy_ms_delta"] == 8.0

@@ -94,8 +94,9 @@ def wall_summary(events):
     n_lora_swaps = n_stream_emits = 0
     n_off_demotes = n_off_promotes = 0
     for ev in events:
-        if ev.get("ph") != "X":
-            continue
+        if ev.get("ph") != "X" or ev.get("cat") == "device":
+            continue  # the device lane is not a host phase: see
+            #           device_summary
         dur = float(ev.get("dur", 0.0)) / 1e3  # us -> ms
         name = ev.get("name")
         if name == "tick":
@@ -229,6 +230,65 @@ def wall_summary(events):
     }
 
 
+def device_summary(events):
+    """The device lane against the host's: the engine's ``dev.*``
+    spans (``cat == "device"``) are its dispatches as the device
+    completed them, on the clock of every host span, so a hole between
+    two of them is the device idle — and the narrowest host span open
+    over the hole's middle says what the host was doing meanwhile
+    ("why was the device idle").  Returns None without a device lane,
+    else busy/idle totals (ms), per-span-name busy time, and the idle
+    time summed by host span, largest first."""
+    dev, host = [], []
+    for ev in events:
+        if ev.get("ph") != "X":
+            continue
+        iv = (float(ev["ts"]), float(ev["ts"]) + float(ev.get("dur", 0)),
+              ev.get("name"))
+        (dev if ev.get("cat") == "device" else host).append(iv)
+    if not dev:
+        return None
+    dev.sort()
+    host.sort()
+    busy = {}
+    for s, e, name in dev:
+        n, ms = busy.get(name, (0, 0.0))
+        busy[name] = (n + 1, ms + (e - s) / 1e3)
+    idle, open_spans, nxt = {}, [], 0
+    for (_, e0, _), (s1, _, _) in zip(dev, dev[1:]):
+        if s1 <= e0:
+            continue
+        mid = (e0 + s1) / 2.0
+        while nxt < len(host) and host[nxt][0] <= mid:
+            open_spans.append(host[nxt])
+            nxt += 1
+        open_spans = [h for h in open_spans if h[1] > mid]
+        label = (min(open_spans, key=lambda h: h[1] - h[0])[2]
+                 if open_spans else "(no host span)")
+        idle[label] = idle.get(label, 0.0) + (s1 - e0) / 1e3
+    return {
+        "span_ms": (dev[-1][1] - dev[0][0]) / 1e3,
+        "busy_ms": sum(ms for _, ms in busy.values()),
+        "idle_ms": sum(idle.values()),
+        "busy": dict(sorted(busy.items())),
+        "idle_by_host_span": sorted(idle.items(),
+                                    key=lambda kv: -kv[1]),
+    }
+
+
+def format_device(d, top=8):
+    parts = ", ".join(f"{name} {ms:.3f} ms over {n}"
+                      for name, (n, ms) in d["busy"].items())
+    share = 100.0 * d["idle_ms"] / d["span_ms"] if d["span_ms"] else 0.0
+    lines = [
+        f"device lane: busy {d['busy_ms']:.3f} ms ({parts})",
+        f"device idle {d['idle_ms']:.3f} ms of {d['span_ms']:.3f} ms "
+        f"({share:.1f}%), by the host span open meanwhile:"]
+    lines += [f"  {name:<26} {ms:>11.3f} ms"
+              for name, ms in d["idle_by_host_span"][:top]]
+    return "\n".join(lines)
+
+
 def format_wall(w):
     lines = [
         f"ticks: {w['ticks']}   wall {w['wall_ms']:.3f} ms   "
@@ -359,7 +419,11 @@ def main(argv=None):
                    help="append a per-tick wall-time vs summed-phase "
                         "summary (concurrent spans — async engine "
                         "overlap — make the two diverge; the table "
-                        "alone double-counts them)")
+                        "alone double-counts them) and, where the "
+                        "trace has the engine's device lane (dev.* "
+                        "spans), the device's busy and idle time with "
+                        "the idle summed by the host span open "
+                        "meanwhile")
     p.add_argument("--lifecycle", action="store_true",
                    help="append an instant-event count table (request "
                         "lifecycle incl. req.preempted / req.resumed "
@@ -379,6 +443,9 @@ def main(argv=None):
     if args.wall:
         print()
         print(format_wall(wall_summary(events)))
+        dev = device_summary(events)
+        if dev is not None:
+            print(format_device(dev))
     if args.lifecycle:
         life = lifecycle_summary(events)
         print()
