@@ -64,6 +64,55 @@ def _is_quant_kv(pool):
     return hasattr(pool, "codes") and hasattr(pool, "scale")
 
 
+_SLOT_ATTN_CHUNK_ROWS = 256
+
+
+def slot_attn_chunk(block_size=None):
+    """Cache rows one trip of ``GPTAttention._slot_attn``'s blocked
+    walk fetches: a whole number of KV blocks, of the order of 256
+    rows (the contiguous layout has no blocks and takes 256)."""
+    if not block_size:
+        return _SLOT_ATTN_CHUNK_ROWS
+    return block_size * max(1, _SLOT_ATTN_CHUNK_ROWS // block_size)
+
+
+def slot_attn_rows(end, table_rows, chunk):
+    """Rows of each slot's ``table_rows``-row table that
+    ``_slot_attn`` walks when the longest window ends at row ``end``
+    (exclusive, ``max(pos) + S``): the host twin of the trip count the
+    program reads from its ``pos`` lanes on the device."""
+    if table_rows <= chunk:
+        return table_rows
+    trips = min(-(-table_rows // chunk), max(1, -(-int(end) // chunk)))
+    return min(table_rows, trips * chunk)
+
+
+def _fetch_rows(buf, start, size):
+    """``_slot_attn`` fetch for the contiguous layout: ``size`` rows of
+    every slot's ``[B, L, H, hd]`` buffer from row ``start``."""
+    import jax
+    return jax.lax.dynamic_slice_in_dim(buf, start, size, axis=1)
+
+
+def _fetch_blocks(block_tables):
+    """``_slot_attn`` fetch for the paged layout: whole blocks gathered
+    through ``size // bs`` columns of the per-slot tables from logical
+    row ``start`` (a multiple of the block size).  A ``QuantKV`` pool
+    dequantizes the fetched blocks only."""
+    import jax
+
+    def fetch(pool, start, size):
+        bs = pool.shape[1]
+        cols = jax.lax.dynamic_slice_in_dim(block_tables, start // bs,
+                                            size // bs, axis=1)
+        if _is_quant_kv(pool):
+            from ..serving.quant import paged_gather
+            return paged_gather(pool, cols)
+        blocks = pool[cols]                     # [B, nb, bs, H, hd]
+        return blocks.reshape(blocks.shape[0], size, *blocks.shape[3:])
+    return fetch
+
+
 # Per-slot LoRA context (serving/lora.py).  Thread-local because jax
 # traces on the calling thread while sibling engines over ONE model
 # may trace concurrently — a plain module global would leak one
@@ -313,35 +362,98 @@ class GPTAttention(nn.Layer):
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         return q._data, k._data, v._data
 
-    def _slot_attn(self, qa, k_rows, v_rows, pos):
+    def _slot_attn(self, qa, k_src, v_src, fetch, table_rows, chunk,
+                   pos):
         """Windowed attention over each slot's cache rows: f32 scores,
         per-row causal mask (the query at window offset q of slot b
-        sees cache positions <= pos[b] + q), softmax, value
+        sees cache positions <= pos[b] + q), f32 softmax, value
         contraction, output projection.  ONE implementation shared by
         ``decode_slots`` / ``decode_slots_paged`` (S=1) and
         ``verify_slots`` / ``verify_slots_paged`` (S=k+1 speculative
         verify), so both the paged path's token-parity guarantee AND
         the speculative verify's greedy parity are structural, not
-        by-convention.  qa [B, S, H, hd]; k_rows/v_rows [B, L, H, hd];
-        pos int32 [B] (window start per slot).  Returns out Tensor
-        [B, S, E]."""
+        by-convention.
+
+        The cache is read through ``fetch(src, start, size)`` ->
+        ``[B, size, H, hd]`` logical rows ``start..start+size-1`` of
+        every slot (``_fetch_rows`` / ``_fetch_blocks``), ``chunk``
+        rows a trip, and only as far as the longest live window:
+        ``ceil((max(pos) + S) / chunk)`` trips, read on the device
+        from ``pos`` (parked lanes hold 0), so rows past every live
+        context are never fetched and the program stays one.  Rows are
+        contracted in the dtype the cache holds them in with f32
+        accumulation — a product of two bf16 values is exact in f32,
+        so an f32 copy of the rows would carry nothing they do not;
+        the probabilities stay f32 through the value contraction
+        (``HIGHEST``: no single bf16 pass over them).  One pass: each
+        trip scores its chunk, folds it into a running f32 maximum and
+        denominator per query and rescales the f32 context it has so
+        far, so the softmax is over exactly the visible positions
+        (masked ones contribute exactly 0: row 0 is visible to every
+        query, so the maximum is finite from the first trip on).  A
+        table no longer than one chunk takes one trip with nothing to
+        skip and keeps the one-shot form.
+
+        qa [B, S, H, hd]; k_src/v_src what ``fetch`` reads; pos int32
+        [B] (window start per slot).  Returns out Tensor [B, S, E]."""
         import math as _math
         import jax
         import jax.numpy as jnp
 
-        B, S = qa.shape[0], qa.shape[1]
+        B, S, H = qa.shape[0], qa.shape[1], qa.shape[2]
         scale = 1.0 / _math.sqrt(self.head_dim)
-        scores = jnp.einsum("bqhd,bkhd->bhqk",
-                            qa.astype(jnp.float32),
-                            k_rows.astype(jnp.float32)) * scale
-        L = k_rows.shape[1]
-        visible = (jnp.arange(L)[None, None, :]
-                   <= (pos[:, None] + jnp.arange(S)[None, :])[:, :, None])
-        scores = jnp.where(visible[:, None, :, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        ctx = jnp.einsum("bhqk,bkhd->bqhd", probs,
-                         v_rows.astype(jnp.float32)).astype(qa.dtype)
-        out = Tensor(ctx)
+        q_end = pos[:, None] + jnp.arange(S)[None, :]          # [B, S]
+
+        def scores_of(start, size, fresh_from=0):
+            # masked f32 scores [B, H, S, size] of rows start..; rows
+            # below fresh_from were scored by an earlier trip
+            kc = fetch(k_src, start, size)
+            dt = jnp.promote_types(qa.dtype, kc.dtype)
+            sc = jnp.einsum("bqhd,bkhd->bhqk", qa.astype(dt),
+                            kc.astype(dt),
+                            preferred_element_type=jnp.float32) * scale
+            rows = start + jnp.arange(size)
+            visible = ((rows[None, None, :] <= q_end[:, :, None])
+                       & (rows >= fresh_from)[None, None, :])
+            return jnp.where(visible[:, None, :, :], sc, -1e30)
+
+        def ctx_of(probs, start, size):
+            vc = fetch(v_src, start, size)
+            return jnp.einsum("bhqk,bkhd->bqhd", probs,
+                              vc.astype(jnp.float32),
+                              precision=jax.lax.Precision.HIGHEST)
+
+        trips = -(-table_rows // chunk)
+        if trips == 1:
+            probs = jax.nn.softmax(scores_of(0, table_rows), axis=-1)
+            ctx = ctx_of(probs, 0, table_rows)
+        else:
+            # the last trip of a table that is no whole number of
+            # chunks starts early and masks the rows it shares
+            last = table_rows - chunk
+            live = jnp.clip((jnp.max(pos) + S + chunk - 1) // chunk,
+                            1, trips)
+
+            def per_ctx(a):                  # [B, H, S] -> [B, S, H, 1]
+                return jnp.transpose(a, (0, 2, 1))[..., None]
+
+            def trip(c, carry):
+                top, den, acc = carry
+                start = jnp.minimum(c * chunk, last)
+                sc = scores_of(start, chunk, c * chunk)
+                new_top = jnp.maximum(top, jnp.max(sc, axis=-1))
+                keep = jnp.exp(top - new_top)
+                p = jnp.exp(sc - new_top[..., None])
+                return (new_top, den * keep + jnp.sum(p, axis=-1),
+                        acc * per_ctx(keep) + ctx_of(p, start, chunk))
+
+            _, den, acc = jax.lax.fori_loop(
+                0, live, trip,
+                (jnp.full((B, H, S), -1e30, jnp.float32),
+                 jnp.zeros((B, H, S), jnp.float32),
+                 jnp.zeros((B, S, H, qa.shape[3]), jnp.float32)))
+            ctx = acc / per_ctx(den)
+        out = Tensor(ctx.astype(qa.dtype))
         if self.use_mp:
             from ..ops import einsum
             out = einsum("bshd,hde->bse", out, self.out_weight) + \
@@ -376,7 +488,9 @@ class GPTAttention(nn.Layer):
         rows = jnp.arange(qa.shape[0])
         k_buf = k_buf.at[rows, pos].set(ka[:, 0].astype(k_buf.dtype))
         v_buf = v_buf.at[rows, pos].set(va[:, 0].astype(v_buf.dtype))
-        return self._slot_attn(qa, k_buf, v_buf, pos), k_buf, v_buf
+        out = self._slot_attn(qa, k_buf, v_buf, _fetch_rows,
+                              k_buf.shape[1], slot_attn_chunk(), pos)
+        return out, k_buf, v_buf
 
     @_scoped("attention")
     def decode_slots_paged(self, x, k_pool, v_pool, block_tables, pos):
@@ -385,14 +499,15 @@ class GPTAttention(nn.Layer):
         fixed-size blocks shared across slots (prefix reuse, COW
         refcounts), and each slot's logical [L] cache row is the gather
         of its table's blocks.  The write scatters into the block
-        holding ``pos[b]``; the gathered rows then go through the SAME
-        ``_slot_attn`` as the contiguous path, so slot outputs are
-        token-identical to ``decode_slots`` (and hence ``generate()``).
+        holding ``pos[b]``; the table's blocks are then fetched a chunk
+        at a time by the SAME ``_slot_attn`` as the contiguous path,
+        so slot outputs are token-identical to ``decode_slots`` (and
+        hence ``generate()``).
 
         x: Tensor [B, 1, E]; k_pool/v_pool: [NB, bs, H, hd] arrays —
         or ``QuantKV`` int8 pools (serving/quant.py), in which case
         the write goes through the touched-block requantizing insert
-        and the gather dequantizes ONLY the gathered blocks;
+        and the fetch dequantizes ONLY the live chunks' blocks;
         block_tables: int32 [B, L//bs] (physical block per logical
         block); pos: int32 [B].  Returns (out [B, 1, E], k_pool,
         v_pool).
@@ -408,27 +523,27 @@ class GPTAttention(nn.Layer):
         NB, bs = k_pool.shape[0], k_pool.shape[1]
         rows = jnp.arange(B)
         if _is_quant_kv(k_pool):
-            from ..serving.quant import paged_gather, paged_insert
+            from ..serving.quant import paged_insert
             blk = block_tables[rows, pos // bs]
             off = pos % bs
             k_pool = paged_insert(k_pool, blk, off, ka[:, 0])
             v_pool = paged_insert(v_pool, blk, off, va[:, 0])
-            out = self._slot_attn(qa, paged_gather(k_pool, block_tables),
-                                  paged_gather(v_pool, block_tables),
-                                  pos)
-            return out, k_pool, v_pool
-        flat_k = k_pool.reshape(NB * bs, self.num_heads, self.head_dim)
-        flat_v = v_pool.reshape(NB * bs, self.num_heads, self.head_dim)
-        # physical row of logical position pos[b] in slot b's table
-        widx = block_tables[rows, pos // bs] * bs + pos % bs      # [B]
-        flat_k = flat_k.at[widx].set(ka[:, 0].astype(flat_k.dtype))
-        flat_v = flat_v.at[widx].set(va[:, 0].astype(flat_v.dtype))
-        # gather each slot's logical row: [B, L] physical indices
-        gidx = ((block_tables * bs)[:, :, None]
-                + jnp.arange(bs)[None, None, :]).reshape(B, -1)
-        out = self._slot_attn(qa, flat_k[gidx], flat_v[gidx], pos)
-        return (out, flat_k.reshape(k_pool.shape),
-                flat_v.reshape(v_pool.shape))
+        else:
+            flat_k = k_pool.reshape(NB * bs, self.num_heads,
+                                    self.head_dim)
+            flat_v = v_pool.reshape(NB * bs, self.num_heads,
+                                    self.head_dim)
+            # physical row of logical position pos[b] in slot b's table
+            widx = block_tables[rows, pos // bs] * bs + pos % bs  # [B]
+            k_pool = flat_k.at[widx].set(
+                ka[:, 0].astype(flat_k.dtype)).reshape(k_pool.shape)
+            v_pool = flat_v.at[widx].set(
+                va[:, 0].astype(flat_v.dtype)).reshape(v_pool.shape)
+        out = self._slot_attn(qa, k_pool, v_pool,
+                              _fetch_blocks(block_tables),
+                              block_tables.shape[1] * bs,
+                              slot_attn_chunk(bs), pos)
+        return out, k_pool, v_pool
 
     @_scoped("attention")
     def verify_slots(self, x, k_buf, v_buf, pos):
@@ -457,14 +572,16 @@ class GPTAttention(nn.Layer):
         cols = pos[:, None] + jnp.arange(W)[None, :]        # [B, W]
         k_buf = k_buf.at[rows, cols].set(ka.astype(k_buf.dtype))
         v_buf = v_buf.at[rows, cols].set(va.astype(v_buf.dtype))
-        return self._slot_attn(qa, k_buf, v_buf, pos), k_buf, v_buf
+        out = self._slot_attn(qa, k_buf, v_buf, _fetch_rows,
+                              k_buf.shape[1], slot_attn_chunk(), pos)
+        return out, k_buf, v_buf
 
     @_scoped("attention")
     def verify_slots_paged(self, x, k_pool, v_pool, block_tables, pos):
         """Block-table twin of ``verify_slots`` (paged KV cache): the
         W window tokens scatter through each slot's block table and
-        the gathered logical rows go through the SAME ``_slot_attn``
-        as ``decode_slots_paged``.  The engine's admission gate
+        the table's blocks go through the SAME ``_slot_attn`` as
+        ``decode_slots_paged``.  The engine's admission gate
         reserves the speculative margin up front (``_kv_gate`` adds
         ``spec_k`` to the worst case), so every window position —
         rejected lanes included — lands inside the slot's own reserved
@@ -484,7 +601,7 @@ class GPTAttention(nn.Layer):
         rows = jnp.arange(B)
         offs = pos[:, None] + jnp.arange(W)[None, :]        # [B, W]
         if _is_quant_kv(k_pool):
-            from ..serving.quant import paged_gather, paged_insert
+            from ..serving.quant import paged_insert
             blk = block_tables[rows[:, None], offs // bs].reshape(-1)
             off = (offs % bs).reshape(-1)
             H, hd = self.num_heads, self.head_dim
@@ -492,21 +609,22 @@ class GPTAttention(nn.Layer):
                                   ka.reshape(B * W, H, hd))
             v_pool = paged_insert(v_pool, blk, off,
                                   va.reshape(B * W, H, hd))
-            out = self._slot_attn(qa, paged_gather(k_pool, block_tables),
-                                  paged_gather(v_pool, block_tables),
-                                  pos)
-            return out, k_pool, v_pool
-        flat_k = k_pool.reshape(NB * bs, self.num_heads, self.head_dim)
-        flat_v = v_pool.reshape(NB * bs, self.num_heads, self.head_dim)
-        widx = (block_tables[rows[:, None], offs // bs] * bs
-                + offs % bs)                                # [B, W]
-        flat_k = flat_k.at[widx].set(ka.astype(flat_k.dtype))
-        flat_v = flat_v.at[widx].set(va.astype(flat_v.dtype))
-        gidx = ((block_tables * bs)[:, :, None]
-                + jnp.arange(bs)[None, None, :]).reshape(B, -1)
-        out = self._slot_attn(qa, flat_k[gidx], flat_v[gidx], pos)
-        return (out, flat_k.reshape(k_pool.shape),
-                flat_v.reshape(v_pool.shape))
+        else:
+            flat_k = k_pool.reshape(NB * bs, self.num_heads,
+                                    self.head_dim)
+            flat_v = v_pool.reshape(NB * bs, self.num_heads,
+                                    self.head_dim)
+            widx = (block_tables[rows[:, None], offs // bs] * bs
+                    + offs % bs)                            # [B, W]
+            k_pool = flat_k.at[widx].set(
+                ka.astype(flat_k.dtype)).reshape(k_pool.shape)
+            v_pool = flat_v.at[widx].set(
+                va.astype(flat_v.dtype)).reshape(v_pool.shape)
+        out = self._slot_attn(qa, k_pool, v_pool,
+                              _fetch_blocks(block_tables),
+                              block_tables.shape[1] * bs,
+                              slot_attn_chunk(bs), pos)
+        return out, k_pool, v_pool
 
     @_scoped("attention")
     def ragged_window_paged(self, x, k_pool, v_pool, block_tables, pos,

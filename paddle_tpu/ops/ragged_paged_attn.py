@@ -43,7 +43,8 @@ GATHER (``variant="gather"``, kept behind ``attn_impl=
 "ragged_gather"`` for A/B): the original form — materialize the whole
 logical [L, H, hd] row, then one monolithic f32 score -> -1e30 mask ->
 softmax -> value contraction, BITWISE-equal to the XLA oracle
-(``GPTAttention._slot_attn``) on CPU.
+(``GPTAttention._slot_attn`` in its one-shot form, a table of at most
+one chunk) on CPU.
 
 NUMERICS CONTRACT: online softmax reorders float summation (block-
 sequential accumulation instead of one reduction over L), so the

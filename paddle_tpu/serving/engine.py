@@ -1206,6 +1206,16 @@ class Engine:
         self._m_fused_ticks = reg.counter(
             "serving.fused_sample_ticks", "decode dispatches that "
             "sampled on device (sample_mode='device')")
+        self._m_rows_walked = reg.counter(
+            "serving.decode_rows_walked", "cache rows the XLA decode / "
+            "verify dispatches walked, summed over slots: the "
+            "slot-window attention stops at the longest live window "
+            "rounded up to its chunk (from the host's position "
+            "mirror; over serving.decode_rows_table it is the share "
+            "of the table read, 1.0 = every row of every slot)")
+        self._m_rows_table = reg.counter(
+            "serving.decode_rows_table", "cache rows of every slot's "
+            "whole table, summed over the same dispatches")
         self._m_kv_blocks_walked = reg.gauge(
             "serving.kv_blocks_walked_per_tick", "KV blocks the "
             "ragged kernel walked in the latest dispatch, summed over "
@@ -3283,6 +3293,22 @@ class Engine:
         self._aid[i] = 0  # parked compute runs the base lane (zeros)
         self._state_dirty = True
 
+    def _rows_walked(self, width=1):
+        """Rows of each slot's table the XLA slot-window attention
+        walks in the dispatch about to be issued, from the position
+        mirror: the mirror trails the device by the ticks in flight,
+        each of which moved a lane by at most ``width`` rows.  Counted
+        into ``serving.decode_rows_walked`` / ``_table``; returned for
+        the ``decode.dispatch`` span."""
+        from ..models.gpt import slot_attn_chunk, slot_attn_rows
+        end = int(self._pos.max()) + width * (1 + len(self._ring))
+        rows = slot_attn_rows(
+            end, self.max_seq_len,
+            slot_attn_chunk(self._bs if self._paged else None))
+        self._m_rows_walked.inc(rows * self.num_slots)
+        self._m_rows_table.inc(self.max_seq_len * self.num_slots)
+        return rows
+
     def _push_state(self):
         """Upload the state mirrors as the device-resident step state
         (device mode): runs only when an admission / eviction / chunk
@@ -3765,7 +3791,8 @@ class Engine:
         fn = self._spec_fn
         self._fault("dispatch")
         with tr.span("decode.dispatch", batch=len(active),
-                     layout=layout, spec_w=W):
+                     layout=layout, spec_w=W,
+                     rows=self._rows_walked(W)):
             if self._paged:
                 last, self.k_pools, self.v_pools = fn(
                     self._p_list(), self._b_list(), self.k_pools,
@@ -3883,7 +3910,8 @@ class Engine:
                  *self._lora_args_state(st)]
         self._fault("dispatch")
         with tr.span("decode.dispatch", batch=len(active),
-                     layout=layout, spec_w=W, fused=True), \
+                     layout=layout, spec_w=W, fused=True,
+                     rows=self._rows_walked(W)), \
                 self._dequant_span(tr, len(active)):
             (picks, n_acc, n_emit, done, new_tok, new_pos, new_ctr,
              new_rem, self.k_pools, self.v_pools) = \
@@ -4024,7 +4052,8 @@ class Engine:
         layout = "paged" if self._paged else "contiguous"
         self._fault("dispatch")
         with tr.span("decode.dispatch", batch=len(active),
-                     layout=layout, fused=True), \
+                     layout=layout, fused=True,
+                     rows=self._rows_walked()), \
                 self._dequant_span(tr, len(active)):
             (ids, done, new_tok, new_pos, new_ctr, new_rem,
              self.k_pools, self.v_pools) = self._fused_fn(*args)
@@ -4426,7 +4455,7 @@ class Engine:
         layout = "paged" if self._paged else "contiguous"
         self._fault("dispatch")
         with tr.span("decode.dispatch", batch=len(active),
-                     layout=layout):
+                     layout=layout, rows=self._rows_walked()):
             if self._paged:
                 last, self.k_pools, self.v_pools = fn(
                     self._p_list(), self._b_list(), self.k_pools,
