@@ -5,11 +5,12 @@ from __future__ import annotations
 
 import importlib.util
 import json
+import math
 import os
 import shutil
 import sys
 
-from . import counts, reducers
+from . import counts, reducers, weights
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -90,15 +91,41 @@ def layer_metrics(cell, src, dump=None):
     return reducers.reduce_all(files, wanted, src)
 
 
+def _load_beside(name):
+    """The module ``configs/<name>.py``, loaded once a process."""
+    modname = "bench_configs_" + name
+    if modname not in sys.modules:
+        path = os.path.join(BENCH_DIR, "configs", name + ".py")
+        spec = importlib.util.spec_from_file_location(modname, path)
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[modname] = mod
+        try:
+            spec.loader.exec_module(mod)
+        except BaseException:
+            del sys.modules[modname]
+            raise
+    return sys.modules[modname]
+
+
 def load_reference(cfg):
     """The plain reference that sits beside the configuration's file."""
-    path = os.path.join(BENCH_DIR, "configs", cfg["reference"] + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_reference_" + cfg["reference"], path)
-    mod = importlib.util.module_from_spec(spec)
-    sys.modules[spec.name] = mod
-    spec.loader.exec_module(mod)
-    return mod
+    return _load_beside(cfg["reference"])
+
+
+def load_program(cfg):
+    """The program's side of the configuration, beside its file: how the
+    program's model is built (``build``), which leaves the benchmark
+    seeds (``leaf_specs``), and the work an ideal chip must do for it.
+    The only files of the benchmark that import a model class."""
+    return _load_beside(cfg["program"])
+
+
+def seeded_weights(cfg, seed, names=None):
+    """The configuration's seeded leaves ({name: array of its dtype};
+    ``names`` picks a subset), as its program file lists them."""
+    return weights.make_weights(
+        seed, load_program(cfg).leaf_specs(cfg["dims"]), cfg["dtype"],
+        names)
 
 
 # -- device, cache, scratch ------------------------------------------------
@@ -182,43 +209,19 @@ class CompileCounter:
             self.count += 1
 
 
-def build_model(cfg, seed):
-    """The program's model at the configuration's sizes, holding the
-    benchmark's seeded weights in the type it is served or trained in."""
-    import paddle_tpu as paddle
-    from paddle_tpu.models import GPTModel
-    from . import weights
-    dims = cfg["dims"]
-    if dims["ffn_hidden_size"] != 4 * dims["hidden_size"]:
-        raise ValueError("GPTModel's feed-forward is 4 x hidden")
-    paddle.seed(int(seed) & 0x7FFFFFFF)
-    model = GPTModel(num_layers=dims["num_layers"],
-                     hidden_size=dims["hidden_size"],
-                     num_heads=dims["num_heads"],
-                     vocab_size=dims["vocab_size"],
-                     max_position=dims["max_position"],
-                     **cfg.get("model_options", {}))
-    model.to(dtype=cfg["dtype"])
-    w = weights.make_weights(seed, dims, cfg["dtype"])
-    params = dict(model.named_parameters())
-    if set(params) != set(w):
-        raise RuntimeError(
-            "the program's parameters and the benchmark's weights differ: "
-            f"{sorted(set(params) ^ set(w))[:6]}")
-    for name, p in params.items():
-        p.set_value(w[name])
-    return model
-
-
 # -- correct -------------------------------------------------------------------
 
 def judge(numbers):
-    """Print each number compared beside its limit; True when every one
-    is inside (a limit of None never passes)."""
-    ok = True
+    """Print each number compared beside its limit.  Returns (correct,
+    compared): True when every one is inside (a limit of None never
+    passes), and {name: {"value", "limit"}} for the result line."""
+    ok, compared = True, {}
     for name, value, limit in numbers:
         inside = limit is not None and value == value and value <= limit
         ok = ok and inside
+        # (a value that is no number would make the line no JSON)
+        compared[name] = {"limit": limit, "value": (
+            value if math.isfinite(value) else repr(value))}
         say(f"compared {name}: {value!r} limit {limit!r} "
             f"{'ok' if inside else 'NOT OK'}")
-    return ok
+    return ok, compared

@@ -16,18 +16,23 @@ The sources a reducer sees (``src``):
 ``counters``  ``delta`` (after minus before, over the window), ``peak``
               (highest of the once-a-second samples), ``profile_delta``
               (over the profiled interval) of the program's registry
-``client``    what the load generator measured (lateness; tokens emitted
-              and live cached positions inside the profiled interval)
-``device``    the reduced device trace (``xplane.reduce``) or None
-``ctx``       sizes and peaks: ``dims``, ``peaks``, ``num_slots``,
-              ``window_s``, ``tokens_per_step`` ...
+``client``    what the load generator measured (lateness, first tokens)
+``device``    the reduced device trace (``xplane.reduce``) or None; its
+              ``by_name`` holds the summed seconds of every distinct
+              name of the ``XLA Ops`` and ``XLA Modules`` lines
+``work``      what the profiled interval asked of the chip (serving:
+              ``tokens_emitted``, ``live_positions``, ``prefill_tokens``,
+              ``num_slots``, ``counters`` = the registry's increase;
+              training: ``steps``, ``batch``, ``seq_len``); the counts
+              of the configuration's program file take it whole
+``ctx``       the configuration (``cfg``), the device's ``peaks``,
+              ``window_s``, ``memory_peak_bytes`` ...
 """
 from __future__ import annotations
 
 import json
 import os
-
-from . import counts
+import re
 
 
 def percentile(values, q):
@@ -148,33 +153,57 @@ def xplane_idle(src):
     return 100.0 * (1.0 - dev["busy_s"] / dev["window_s"])
 
 
+def _least_seconds(src, least):
+    """What the function ``least`` of the configuration's program file
+    gives for the profiled interval's work, in seconds."""
+    from . import common
+    work = src.get("work")
+    if not work:
+        return None
+    ctx = src["ctx"]
+    t = getattr(common.load_program(ctx["cfg"]), least)(
+        ctx["cfg"], ctx["peaks"], work)
+    return t[0] if isinstance(t, tuple) else t      # (seconds, bound)
+
+
 def roofline(src, work):
     """Least time the chip could take for the profiled interval's work
-    over the device's busy time in that interval, in %."""
-    dev, ctx = src.get("device"), src["ctx"]
+    (the program file's ``<work>_least_seconds``) over the device's busy
+    time in that interval, in %."""
+    dev = src.get("device")
     if not dev or dev.get("busy_s", 0) <= 0:
         return None
-    if work == "serve":
-        c = src.get("client") or {}
-        pd = src["counters"].get("profile_delta") or {}
-        if "profile_tokens" not in c:
-            return None
-        least, _ = counts.serve_least_seconds(
-            ctx["dims"], ctx["peaks"],
-            tokens_emitted=c["profile_tokens"],
-            live_positions=c["profile_live_positions"],
-            prefill_tokens=pd.get(ctx["prefill_counter"], 0),
-            num_slots=ctx["num_slots"], dtype=ctx["dtype"])
-    elif work == "train":
-        steps = ctx.get("profile_steps")
-        if not steps:
-            return None
-        least = steps * counts.train_step_flops(
-            ctx["dims"], ctx["batch"], ctx["seq_len"]) \
-            / ctx["peaks"]["bf16_flops"]
-    else:
-        raise ValueError(f"unknown work {work!r}")
-    return 100.0 * least / dev["busy_s"]
+    least = _least_seconds(src, work + "_least_seconds")
+    return None if not least else 100.0 * least / dev["busy_s"]
+
+
+def _trace_time(src, line, match):
+    """Summed device seconds of the names of ``line`` (``XLA Ops`` or
+    ``XLA Modules``) that the expression ``match`` finds; None where
+    there is no such name."""
+    names = ((src.get("device") or {}).get("by_name") or {}).get(line)
+    hit = [t for name, t in (names or {}).items() if re.search(match, name)]
+    return sum(hit) if hit else None
+
+
+def trace_time_share(src, line, match):
+    """The device time of one program (``line`` "XLA Modules") or of one
+    operation or kernel ("XLA Ops") over the device's busy time, in %."""
+    t = _trace_time(src, line, match)
+    if t is None or src["device"].get("busy_s", 0) <= 0:
+        return None
+    return 100.0 * t / src["device"]["busy_s"]
+
+
+def trace_roofline(src, line, match, least):
+    """Least time the chip could take for the profiled interval's work,
+    as the function ``least`` of the program file counts it, over the
+    device time of the program or kernel that does that work, in %."""
+    t = _trace_time(src, line, match)
+    if not t:
+        return None
+    ideal = _least_seconds(src, least)
+    return None if not ideal else 100.0 * ideal / t
 
 
 def rate_over_peak(src, rate_key, flops_per_unit_key):
@@ -191,7 +220,8 @@ def rate_over_peak(src, rate_key, flops_per_unit_key):
 KINDS = {f.__name__: f for f in (
     span_percentile, span_arg_mean, span_rate, lifecycle_gap,
     counter_delta, counter_delta_ratio, gauge_peak, client_percentile,
-    memory_peak, xplane_idle, roofline, rate_over_peak)}
+    memory_peak, xplane_idle, roofline, rate_over_peak, trace_time_share,
+    trace_roofline)}
 
 
 def load_metric_files(directory):

@@ -15,7 +15,7 @@ import urllib.request
 
 import numpy as np
 
-from . import common, reducers, weights
+from . import common, reducers
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -148,8 +148,7 @@ def check_served(cfg, seed, sample, precision="highest"):
         served[b, n - 1:n - 1 + len(r["tokens"])] = r["tokens"]
 
     def get(names):
-        return weights.make_weights(seed, dims, cfg["dtype"],
-                                    names=frozenset(names))
+        return common.seeded_weights(cfg, seed, names=frozenset(names))
     regret, valid, top = ref.served_regret(get, dims, ids, served,
                                            precision, rows_per_block=rows)
     v = regret[valid]
@@ -282,29 +281,32 @@ def traced_sources(cfg, args, dev, win, trace, counters, memory_peak):
         {e["name"] for e in spans if e["ph"] == "X"}, window_s=p1 - p0)
     p_tok, p_pos = profile_work(win["log"], p0 - win["start_at"],
                                 p1 - win["start_at"])
+    p_delta = delta(win["p_before"], win["p_after"])
     return {
         "spans": spans,
         "counters": {"delta": delta(win["before"], win["after"]),
-                     "profile_delta": delta(win["p_before"],
-                                            win["p_after"]),
+                     "profile_delta": p_delta,
                      "peak": counters.peak, "last": win["after"]},
         "client": {"late_ms": win["client"]["late_ms"],
-                   "ttft_ms": win["client"]["ttft_ms"],
-                   "profile_tokens": p_tok,
-                   "profile_live_positions": p_pos},
+                   "ttft_ms": win["client"]["ttft_ms"]},
         "device": dev_trace,
-        "ctx": {"dims": cfg["dims"], "dtype": cfg["dtype"],
+        # what the profiled interval asked of the chip; the program
+        # file's counts take it whole
+        "work": {"tokens_emitted": p_tok, "live_positions": p_pos,
+                 "prefill_tokens": p_delta.get("serving.prefill_tokens", 0),
+                 "num_slots": cfg["engine"]["num_slots"],
+                 "counters": p_delta},
+        "ctx": {"cfg": cfg,
                 "peaks": common.peaks(dev, args.rehearse),
-                "num_slots": cfg["engine"]["num_slots"],
-                "prefill_counter": "serving.prefill_tokens",
                 "window_s": args.seconds,
                 "memory_peak_bytes": memory_peak},
     }
 
 
 def check(cfg, seed, log, flight):
-    """``correct``, after the server is gone: exact counts, then the
-    reference over a sample of finished requests."""
+    """(``correct``, the numbers compared), after the server is gone:
+    exact counts, then the reference over a sample of finished
+    requests."""
     finished = [r for r in log["requests"] if r["done"] is not None]
     numbers = [
         ("finished_with_wrong_length",
@@ -332,7 +334,7 @@ def run(cell, cfg, mix_path, args, t_proc0):
     dev = common.device_info()
     compiles = common.CompileCounter()
     t_a = time.monotonic()
-    model = common.build_model(cfg, args.seed)
+    model = common.load_program(cfg).build(cfg, args.seed)
     t_b = time.monotonic()
     opts = dict(cfg["engine"])
     if args.control:
@@ -341,10 +343,6 @@ def run(cell, cfg, mix_path, args, t_proc0):
     if args.trace:
         opts.update(trace_annotations=True, trace_capacity=1 << 21)
     engine = Engine(model, **opts)
-    if args.trace:
-        # HTTP handler threads die with their request; keep their lanes
-        # (the ``req.queued`` instants) for the whole traced window
-        engine.tracer.max_threads = 1 << 20
     counters = Counters(engine.registry, compiles)
     common.say(f"set-up so far: imports and device {t_a - t_proc0:.1f}s, "
                f"model and weights {t_b - t_a:.1f}s, engine "
@@ -365,7 +363,8 @@ def run(cell, cfg, mix_path, args, t_proc0):
         engine.stop(drain=False)
     del engine, srv, model
     gc.collect()
-    correct = check(cfg, args.seed, win["log"], flight)   # not in setup_s
+    correct, compared = check(cfg, args.seed, win["log"], flight)
+    # (the reference's time is not in setup_s)
 
     cm = win["client"]
     ttft_mean = (sum(cm["ttft_ms"]) / len(cm["ttft_ms"])
@@ -402,4 +401,4 @@ def run(cell, cfg, mix_path, args, t_proc0):
                f"compiles +{d['jax.backend_compiles']}")
     return {"correct": correct, "attempted": cm["attempted"],
             "failed": cm["failed"], "metrics": metrics, "device": device,
-            "breakdown": breakdown}
+            "breakdown": breakdown, "compared": compared}

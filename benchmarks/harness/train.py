@@ -12,7 +12,7 @@ import time
 
 import numpy as np
 
-from . import common, counts, weights
+from . import common
 
 
 class Batches:
@@ -65,7 +65,7 @@ def reference_numbers(cfg, mix, seed, n_steps, precision="highest"):
     dims = cfg["dims"]
     feed = Batches(mix, dims["vocab_size"], seed)
     batches = [feed.next() for _ in range(n_steps)]
-    w = weights.make_weights(seed, dims, cfg["dtype"])
+    w = common.seeded_weights(cfg, seed)
     p0 = ref.stack_params(w, dims)
     del w
     losses, g1, p = ref.train_steps(
@@ -114,15 +114,17 @@ def run(cell, cfg, mix_path, args, t_proc0):
             cfg, mix, args.seed, n_check,
             precision=cfg["controls"][args.control]["precision"])
         want = reference_numbers(cfg, mix, args.seed, n_check)
-        return {"correct": common.judge(compare(got, want, limits)),
-                "attempted": n_check,
+        correct, compared = common.judge(compare(got, want, limits))
+        return {"correct": correct, "attempted": n_check,
                 "failed": 0, "metrics": {}, "device": dict(
-                    dev, memory_peak_bytes=common.memory_peak_bytes())}
+                    dev, memory_peak_bytes=common.memory_peak_bytes()),
+                "compared": compared}
 
     from paddle_tpu import optimizer
     from paddle_tpu.parallel.train_step import TrainStep
     compiles = common.CompileCounter()
-    model = common.build_model(cfg, args.seed)
+    program = common.load_program(cfg)
+    model = program.build(cfg, args.seed)
     model.train()
     o = cfg["optimizer"]
     opt = optimizer.AdamW(learning_rate=o["learning_rate"],
@@ -157,8 +159,7 @@ def run(cell, cfg, mix_path, args, t_proc0):
         scale=1.0 / (1.0 - o["beta1"]))
     got["losses"] += [one() for _ in range(n_check - 1)]
     got["update_norms"] = _leaf_norms(
-        dict(step.params),
-        weights.make_weights(args.seed, dims, cfg["dtype"]))
+        dict(step.params), common.seeded_weights(cfg, args.seed))
     common.say(f"first losses: {got['losses']}")
     spans.clear()
 
@@ -202,7 +203,7 @@ def run(cell, cfg, mix_path, args, t_proc0):
     common.say(f"reference losses: {want['losses']} "
                f"({time.monotonic() - t_ref:.1f}s)")
     bad = sum(not np.isfinite(x) for x in losses)
-    correct = common.judge(compare(got, want, limits) + [
+    correct, compared = common.judge(compare(got, want, limits) + [
         ("window_losses_not_finite", bad, 0),
         ("window_loss_last_minus_first", losses[-1] - losses[0], 0.0)])
     common.say(f"compiles in the window: jax backend compiles "
@@ -229,14 +230,13 @@ def run(cell, cfg, mix_path, args, t_proc0):
             "counters": {"delta": {
                 "jax.backend_compiles": compiles_in_window}},
             "device": dev_trace,
-            "ctx": {"dims": dims, "dtype": cfg["dtype"],
+            "work": {"steps": prof["steps"], "batch": int(mix["batch"]),
+                     "seq_len": int(mix["seq_len"])},
+            "ctx": {"cfg": cfg,
                     "peaks": common.peaks(dev, args.rehearse),
                     "chips": cell["chips"],
-                    "batch": int(mix["batch"]),
-                    "seq_len": int(mix["seq_len"]),
-                    "profile_steps": prof["steps"],
                     "train_tok_s": train_tok_s,
-                    "flops_per_token": counts.train_flops_per_token(
+                    "flops_per_token": program.train_flops_per_token(
                         dims, int(mix["seq_len"])),
                     "window_s": elapsed,
                     "memory_peak_bytes": memory_peak},
@@ -249,4 +249,5 @@ def run(cell, cfg, mix_path, args, t_proc0):
         common.say(f"traced run: train_tok_s={train_tok_s:.1f} outside "
                    "the profiled interval")
     return {"correct": correct, "attempted": len(losses), "failed": bad,
-            "metrics": metrics, "device": device, "breakdown": breakdown}
+            "metrics": metrics, "device": device, "breakdown": breakdown,
+            "compared": compared}

@@ -12,10 +12,12 @@ whose names are in ``span_names``.
 from __future__ import annotations
 
 import glob
+import heapq
 import os
 import re
 
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 
 
 def find_trace(directory):
@@ -55,42 +57,92 @@ def short_op(name):
     return (m.group(1) + " " + (m.group(2) or "")).strip()
 
 
-def _label(gap, host):
-    """The narrowest host span that covers the middle of ``gap``."""
-    mid = (gap[0] + gap[1]) / 2.0
-    best = None
-    for s, e, name in host:
-        if s <= mid < e and (best is None or e - s < best[0]):
-            best = (e - s, name)
-    return best[1] if best else "(no program span)"
+def op_key(name):
+    """The name under which an operation's time is summed for the
+    readers by name: a Pallas call is an instruction named after its
+    kernel (``pallas_call(..., name="k")`` traces as ``%k.1 = ...
+    custom-call(...), custom_call_target="tpu_custom_call"``) and goes
+    under the kernel's own name, ``k``, whatever its shapes; every other
+    operation under ``short_op``."""
+    if 'custom_call_target="tpu_custom_call"' in name:
+        m = re.match(r"%?([\w\-]+?)(?:\.\d+)* = ", name)
+        if m:
+            return m.group(1)
+    return short_op(name)
+
+
+def label_gaps(gaps, host):
+    """For each ``(start, end)`` of ``gaps`` (sorted, disjoint) the name
+    of the narrowest host span ``(start, end, name)`` that covers its
+    middle (the first of ``host`` among equally narrow ones), by one
+    sweep over both in time order."""
+    order = sorted(range(len(host)), key=lambda i: host[i][0])
+    open_, nxt, out = [], 0, []      # open_: heap of (width, index, end)
+    for g0, g1 in gaps:
+        mid = (g0 + g1) / 2.0
+        while nxt < len(order) and host[order[nxt]][0] <= mid:
+            i = order[nxt]
+            nxt += 1
+            heapq.heappush(open_, (host[i][1] - host[i][0], i, host[i][1]))
+        # a span that has ended stays ended for every later gap; one
+        # that hides under a narrower open span is dropped when it
+        # surfaces
+        while open_ and open_[0][2] <= mid:
+            heapq.heappop(open_)
+        out.append(host[open_[0][1]][2] if open_ else "(no program span)")
+    return out
+
+
+def _by_name(raw, key, n):
+    out = {}
+    for name, ns in raw.items():
+        k = key(name)
+        out[k] = out.get(k, 0.0) + ns
+    return {k: v * 1e-9 / n for k, v in out.items()}
 
 
 def reduce(path, span_names=(), window_s=None, top=10):
-    """{"busy_s", "window_s", "device_ops", "idle_gaps", "devices"}.
+    """{"busy_s", "window_s", "device_ops", "idle_gaps", "devices",
+    "by_name"}.
 
     ``busy_s`` is the union of the device's operation intervals,
     averaged over the device planes that ran anything; ``window_s`` is
     the traced window as the caller timed it (else the span from the
     first device event to the last).  ``device_ops`` are the ``top``
     operations by summed time, ``idle_gaps`` the idle time summed by the
-    host span that covers each gap."""
+    host span that covers each gap.  ``by_name`` holds the summed
+    seconds (averaged over the devices, as ``device_ops``) of every
+    distinct name of two lines: ``"XLA Ops"`` under ``op_key`` and
+    ``"XLA Modules"`` under the event's own name, which is the jitted
+    program's (``jit_gpt_fused_decode(<fingerprint>)``).
+
+    A name is a long string that thousands of events share, so each
+    event pays a dictionary step and the expressions run once a distinct
+    name."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
     names = set(span_names)
-    host, per_device, op_time = [], [], {}
+    host, per_device = [], []
+    raw = {OPS_LINE: {}, MODULES_LINE: {}}   # event name -> summed ns
     for plane in data.planes:
         if re.match(r"/device:[A-Za-z]+:\d+$", plane.name):
-            iv = []
+            iv, ops = [], raw[OPS_LINE]
             for line in _device_lines(plane):
                 for ev in line.events:
                     d = ev.duration_ns
                     if d <= 0:
                         continue
-                    iv.append((ev.start_ns, ev.start_ns + d))
-                    op = short_op(ev.name)
-                    op_time[op] = op_time.get(op, 0.0) + d
+                    s, name = ev.start_ns, ev.name
+                    iv.append((s, s + d))
+                    ops[name] = ops.get(name, 0.0) + d
             if iv:
                 per_device.append(merge(iv))
+            mods = raw[MODULES_LINE]
+            for line in plane.lines:
+                if line.name == MODULES_LINE:
+                    for ev in line.events:
+                        name = ev.name
+                        mods[name] = mods.get(name, 0.0) + ev.duration_ns
         elif plane.name.startswith("/host:") and names:
             for line in plane.lines:
                 for ev in line.events:
@@ -100,22 +152,26 @@ def reduce(path, span_names=(), window_s=None, top=10):
                                      ev.name))
     if not per_device:
         return {"busy_s": 0.0, "window_s": window_s or 0.0,
-                "device_ops": [], "idle_gaps": [], "devices": 0}
+                "device_ops": [], "idle_gaps": [], "devices": 0,
+                "by_name": {}}
     busy = sum(sum(e - s for s, e in m) for m in per_device) \
         / len(per_device) * 1e-9
     if window_s is None:
         window_s = (max(m[-1][1] for m in per_device)
                     - min(m[0][0] for m in per_device)) * 1e-9
-    gaps = {}
     first = per_device[0]
-    for (_, e0), (s1, _) in zip(first, first[1:]):
-        label = _label((e0, s1), host)
+    between = [(e0, s1) for (_, e0), (s1, _) in zip(first, first[1:])]
+    gaps = {}
+    for (e0, s1), label in zip(between, label_gaps(between, host)):
         gaps[label] = gaps.get(label, 0.0) + (s1 - e0) * 1e-9
     n = len(per_device)
-    ops = sorted(((k, v * 1e-9 / n) for k, v in op_time.items()),
+    ops = sorted(_by_name(raw[OPS_LINE], short_op, n).items(),
                  key=lambda kv: -kv[1])[:top]
     idle = sorted(gaps.items(), key=lambda kv: -kv[1])[:top]
     return {"busy_s": busy, "window_s": float(window_s),
             "device_ops": [[k, v] for k, v in ops],
             "idle_gaps": [[k, v] for k, v in idle],
-            "devices": n}
+            "devices": n,
+            "by_name": {
+                OPS_LINE: _by_name(raw[OPS_LINE], op_key, n),
+                MODULES_LINE: _by_name(raw[MODULES_LINE], str, n)}}

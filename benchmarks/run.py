@@ -6,8 +6,13 @@
 A cell of ``BENCHMARK.json`` names a configuration and a traffic mix; the
 files of both are found by those names (``benchmarks/configs/``,
 ``benchmarks/traffic/``), and every per-layer metric by listing
-``benchmarks/layer_metrics/``.  The mix's ``kind`` picks the runner:
-``open_loop`` and ``sessions`` serve, ``train_steps`` trains.
+``benchmarks/layer_metrics/``.  The configuration's file names two more
+that sit beside it: its plain ``reference`` and its ``program`` file
+(``configs/<name>.py``), which builds the program's model, lists the
+leaves the benchmark seeds and counts the work an ideal chip must do.
+So an architecture is a set of new files; the harness names no model.
+The mix's ``kind`` picks the runner: ``open_loop`` and ``sessions``
+serve, ``train_steps`` trains.
 
 The run needs an accelerator with as many chips as the cell asks for;
 with none it exits non-zero, names the platform and prints no result.
@@ -26,7 +31,9 @@ over the mix and over the configuration;
 (spans, counters, the client's series, the reduced device trace).
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
-``failed``, ``metrics``, ``device`` and, traced, ``breakdown``.
+``failed``, ``metrics``, ``device``, traced ``breakdown``, and last
+``compared``: each number compared with its value and its limit, which
+are also the last lines on standard error.
 """
 from __future__ import annotations
 
@@ -110,7 +117,13 @@ def main(argv=None):
         result["rehearsal_correct"] = result["correct"]
         result["correct"] = False
         result["rehearsal"] = True
+    # each number compared beside its limit: the result's last key and
+    # the last lines on standard error
+    result["compared"] = result.pop("compared")
     common.say(f"total_wall_s: {time.monotonic() - T_PROC0:.1f}")
+    for name, c in result["compared"].items():
+        print(f"compared {name}: {c['value']!r} limit {c['limit']!r}",
+              file=sys.stderr, flush=True)
     common.say(json.dumps(result))
 
 

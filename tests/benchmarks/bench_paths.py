@@ -18,6 +18,12 @@ def manifest():
         return json.load(f)
 
 
+def config(name):
+    """The configuration file ``benchmarks/configs/<name>.json``."""
+    with open(os.path.join(BENCH, "configs", name + ".json")) as f:
+        return json.load(f)
+
+
 def run_cell(workload, *extra, root=ROOT, seed=7, trace=0, timeout=600):
     """Run the benchmark's command as the driver would (plus ``extra``)
     from ``root``; returns (exit code, stdout lines, stderr)."""

@@ -1,14 +1,20 @@
 """The reducers: each kind on a hand-made source, then every serving
 metric's own file on a recorded engine trace, and the device-trace
-reducer on a trace recorded on a v5e."""
+reducer on traces recorded on a v5e.
+
+A metric file may name the recording it is checked on (its ``recorded``
+key: a dump under ``data/``, made once the engine had the spans it
+reads); ``test_perfbench_recorded.py`` checks it there, and it has no
+case on the older recording here."""
 import json
 import os
+import random
 import statistics
 
 import pytest
 
-from bench_paths import BENCH, DATA, manifest
-from harness import reducers, xplane
+from bench_paths import BENCH, DATA, config, manifest
+from harness import common, counts, reducers, xplane
 
 METRICS = reducers.load_metric_files(os.path.join(BENCH, "layer_metrics"))
 
@@ -40,9 +46,15 @@ SRC = {
         "last": {"serving.kv_blocks_total": 40.0},
         "profile_delta": {"serving.prefill_tokens": 0.0},
     },
-    "client": {"late_ms": [1.0, 2.0, 3.0, 4.0, 50.0],
-               "profile_tokens": 64, "profile_live_positions": 64 * 500},
-    "device": {"busy_s": 2.0, "window_s": 5.0},
+    "client": {"late_ms": [1.0, 2.0, 3.0, 4.0, 50.0]},
+    "device": {"busy_s": 2.0, "window_s": 5.0, "by_name": {
+        "XLA Modules": {"jit_gpt_fused_decode(11)": 1.5,
+                        "jit_gpt_fused_decode(12)": 0.1,
+                        "jit_gpt_paged_chunk_prefill(7)": 0.3},
+        "XLA Ops": {"fusion f32[32,256,16]": 0.9, "my_kernel": 0.25}}},
+    "work": {"tokens_emitted": 64, "live_positions": 64 * 500,
+             "prefill_tokens": 0.0, "num_slots": 32,
+             "counters": {"serving.prefill_tokens": 0.0}},
     "ctx": {"memory_peak_bytes": 12.5e9},
 }
 
@@ -70,6 +82,13 @@ SRC = {
     ("client_percentile", {"series": "late_ms", "q": 50}, 3.0),
     ("memory_peak", {}, 12.5),
     ("xplane_idle", {}, 60.0),
+    # two fingerprints of one program kind add up: 1.6 of 2.0 s busy
+    ("trace_time_share", {"line": "XLA Modules",
+                          "match": "^jit_gpt_fused_decode\\("}, 80.0),
+    ("trace_time_share", {"line": "XLA Ops", "match": "^my_kernel$"}, 12.5),
+    ("trace_time_share", {"line": "XLA Modules", "match": "^jit_absent"},
+     None),
+    ("trace_time_share", {"line": "No Such Line", "match": "."}, None),
 ])
 def test_reducer_kinds(kind, params, want):
     got = reducers.KINDS[kind](SRC, **params)
@@ -80,29 +99,39 @@ def test_reducer_kinds(kind, params, want):
 
 
 def test_roofline_is_least_time_over_busy_time():
-    from harness import counts
-    with open(os.path.join(BENCH, "configs", "gpt3-1.3b-serve.json")) as f:
-        dims = json.load(f)["dims"]
-    src = dict(SRC, ctx={"dims": dims, "dtype": "bfloat16",
-                         "peaks": counts.peaks_for("TPU v5 lite"),
-                         "num_slots": 32,
-                         "prefill_counter": "serving.prefill_tokens"})
+    cfg = config("gpt3-1.3b-serve")
+    prog = common.load_program(cfg)
+    peaks = counts.peaks_for("TPU v5 lite")
+    src = dict(SRC, ctx={"cfg": cfg, "peaks": peaks})
     # two decode steps of 32 tokens: 2 x 2.62 GB + 32,000 positions x
     # 196,608 B = 11.5 GB at 819 GB/s = 14.1 ms, of 2 s busy
     got = reducers.roofline(src, "serve")
-    want = (2 * counts.step_weight_bytes(dims) + 32000 * 196608) / 819e9
+    want = (2 * prog.step_weight_bytes(cfg["dims"]) + 32000 * 196608) / 819e9
     assert got == pytest.approx(100 * want / 2.0)
     assert 0.69 < got < 0.72
+    # the same 14.1 ms over the decode program's own 1.6 s
+    assert reducers.trace_roofline(
+        src, "XLA Modules", "^jit_gpt_fused_decode\\(",
+        "decode_least_seconds") == pytest.approx(100 * want / 1.6)
+    # a count that returns (seconds, bound) is read by its seconds
+    assert reducers.trace_roofline(
+        src, "XLA Modules", "^jit_gpt_fused_decode\\(",
+        "serve_least_seconds") == pytest.approx(100 * want / 1.6)
+    assert reducers.trace_roofline(
+        src, "XLA Modules", "^jit_absent", "decode_least_seconds") is None
+    assert reducers.trace_roofline(
+        dict(src, work=None), "XLA Modules", "^jit_gpt_fused_decode",
+        "decode_least_seconds") is None
     # training: 19.9 TFLOP a step, 10 steps in 2 s busy = 50.5% of peak
-    with open(os.path.join(BENCH, "configs", "gpt2-medium-train.json")) as f:
-        tdims = json.load(f)["dims"]
-    src = dict(SRC, ctx={"dims": tdims,
-                         "peaks": counts.peaks_for("TPU v5 lite"),
-                         "batch": 8, "seq_len": 1024, "profile_steps": 10})
+    src = dict(SRC, ctx={"cfg": config("gpt2-medium-train"), "peaks": peaks},
+               work={"steps": 10, "batch": 8, "seq_len": 1024})
     assert reducers.roofline(src, "train") == pytest.approx(
         100 * 10 * 19.85e12 / 197e12 / 2.0, rel=0.01)
+    assert reducers.roofline(dict(src, work={"steps": 0, "batch": 8,
+                                             "seq_len": 1024}),
+                             "train") is None
     assert reducers.rate_over_peak(
-        {"ctx": {"peaks": counts.peaks_for("TPU v5 lite"), "rate": 30000.0,
+        {"ctx": {"peaks": peaks, "rate": 30000.0,
                  "f": 2.423e9}}, "rate", "f") == pytest.approx(36.9, abs=0.05)
 
 
@@ -131,7 +160,8 @@ def _x(name):
 
 @pytest.mark.parametrize("name", sorted(
     n for n, m in METRICS.items()
-    if m["moves"] != "train_tok_s" and m["source"] != "device_trace"))
+    if m["moves"] != "train_tok_s" and m["source"] != "device_trace"
+    and "recorded" not in m))
 def test_serving_metric_files_on_the_recorded_trace(name):
     got = _recorded(name)
     assert got is not None, f"{name} found nothing to read"
@@ -203,6 +233,106 @@ def test_device_trace_recorded_on_a_v5e():
         (68_940_258 - 45_940_931 - 2 * 11_900) * 1e-9, rel=0.01)
     idle = reducers.xplane_idle({"device": got})
     assert idle == pytest.approx(100 * (1 - got["busy_s"] / 0.03))
+
+
+def test_device_trace_by_name():
+    # the same file by name.  ``XLA Modules``: three runs of the one
+    # program, 11,900 + 11,901 + 11,901 ns; ``XLA Ops``: nine events
+    # under three names (copy-start 14 + 14 + 13, copy-done 3 + 3 + 2,
+    # fusion 11,877 + 11,877 + 11,878)
+    got = xplane.reduce(TRACE, {"train.step"}, window_s=0.03)
+    by = got["by_name"]
+    assert by["XLA Modules"] == {
+        "jit__lambda(6074760096634504725)": pytest.approx(35_702e-9)}
+    assert by["XLA Ops"] == {
+        "copy-start bf16[1024,1024]": pytest.approx(41e-9),
+        "copy-done bf16[1024,1024]": pytest.approx(8e-9),
+        "fusion bf16[]": pytest.approx(35_632e-9)}
+    # device_ops is the ten longest of the same table
+    assert dict(got["device_ops"]) == by["XLA Ops"]
+    src = {"device": got}
+    # busy is the union of the operations, 3 x 11,894 - 1 = 35,681 ns
+    assert got["busy_s"] == pytest.approx(35_681e-9)
+    assert reducers.trace_time_share(src, "XLA Ops", "^fusion ") == \
+        pytest.approx(100 * 35_632 / 35_681)
+    assert reducers.trace_time_share(src, "XLA Ops", "^copy-") == \
+        pytest.approx(100 * 49 / 35_681)
+    # a program's span holds the few ns between its operations too
+    assert reducers.trace_time_share(
+        src, "XLA Modules", "^jit__lambda\\(") == \
+        pytest.approx(100 * 35_702 / 35_681)
+    # arithmetic only (a toy program against a real model's count):
+    # one decode step reads 2,623,250,432 B of weights, 3.203 ms at
+    # 819 GB/s, over the program's 35.702 us
+    cfg = config("gpt3-1.3b-serve")
+    src.update(ctx={"cfg": cfg, "peaks": counts.peaks_for("TPU v5 lite")},
+               work={"tokens_emitted": 32, "live_positions": 0,
+                     "prefill_tokens": 0, "num_slots": 32, "counters": {}})
+    assert reducers.trace_roofline(
+        src, "XLA Modules", "^jit__lambda\\(", "decode_least_seconds") == \
+        pytest.approx(100 * 2_623_250_432 / 819e9 / 35_702e-9)
+
+
+def test_a_pallas_call_goes_under_its_kernels_name():
+    # recorded on a v5e (PR 27): three runs of a jitted function that
+    # calls pl.pallas_call(..., name="probe_kernel_scale") and reduces
+    # its product; the kernel's events read "%probe_kernel_scale.1 =
+    # f32[512,512]... custom-call(...)" and took 1,937 + 1,910 + 1,903
+    # ns, the fusion 1,822 + 1,820 + 1,822, back to back within a run
+    got = xplane.reduce(os.path.join(DATA, "mini_pallas_v5e.xplane.pb"))
+    assert got["by_name"]["XLA Ops"] == {
+        "probe_kernel_scale": pytest.approx(5_750e-9),
+        "convolution_reduce_fusion f32[]": pytest.approx(5_464e-9)}
+    assert got["by_name"]["XLA Modules"] == {
+        "jit_f(2841148262588850476)": pytest.approx(11_238e-9)}
+    assert got["busy_s"] == pytest.approx(11_214e-9)
+    assert dict(got["device_ops"])["probe_kernel_scale f32[512,512]"] == \
+        pytest.approx(5_750e-9)
+    assert reducers.trace_time_share(
+        {"device": got}, "XLA Ops", "^probe_kernel_scale$") == \
+        pytest.approx(100 * 5_750 / 11_214)
+
+
+@pytest.mark.parametrize("text, want", [
+    ('%probe_kernel_scale.1 = f32[512,512]{1,0:T(8,128)S(1)} custom-call('
+     'f32[512,512]{1,0} %x.1), custom_call_target="tpu_custom_call", '
+     'operand_layout_constraints={f32[512,512]{1,0}}', "probe_kernel_scale"),
+    ('%attn_v2 = (bf16[8,128]{1,0}, f32[8]{0}) custom-call(bf16[8,128] %q), '
+     'custom_call_target="tpu_custom_call"', "attn_v2"),
+    # another custom call, and every other operation: as short_op has it
+    ('%custom-call.3 = f32[8]{0} custom-call(f32[8]{0} %x), '
+     'custom_call_target="Sharding"', "custom-call f32[8]"),
+    ("%convert.266 = f32[65536,16,128]{2,1,0:T(8,128)} convert(bf16[1]{0} %f)",
+     "convert f32[65536,16,128]"),
+])
+def test_key_of_an_operation_by_name(text, want):
+    assert xplane.op_key(text) == want
+
+
+def _label_one_by_one(gap, host):
+    """The reference: every host span tried against every gap."""
+    mid = (gap[0] + gap[1]) / 2.0
+    best = None
+    for s, e, name in host:
+        if s <= mid < e and (best is None or e - s < best[0]):
+            best = (e - s, name)
+    return best[1] if best else "(no program span)"
+
+
+@pytest.mark.parametrize("seed", [1, 2, 3])
+def test_gaps_are_labelled_as_one_by_one(seed):
+    rng = random.Random(seed)
+    # nested, overlapping and equally wide spans, in no order
+    host = []
+    for i in range(300):
+        s = rng.randrange(0, 10_000)
+        host.append((s, s + rng.choice((5, 50, 50, 400, 3000)), f"s{i % 7}"))
+    edges = sorted(rng.sample(range(0, 14_000), 400))
+    gaps = list(zip(edges[::2], edges[1::2]))
+    got = xplane.label_gaps(gaps, host)
+    assert got == [_label_one_by_one(g, host) for g in gaps]
+    assert "(no program span)" in got and len(set(got)) > 3
+    assert xplane.label_gaps(gaps, []) == ["(no program span)"] * len(gaps)
 
 
 @pytest.mark.parametrize("text, want", [

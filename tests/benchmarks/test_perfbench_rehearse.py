@@ -46,6 +46,16 @@ def test_rehearsal_end_to_end(workload):
     assert all(m["value"] > 0 for m in res["metrics"].values())
     assert any(ln.startswith("compared ") and " limit " in ln
                for ln in lines)
+    # each number compared, with its limit: the result's last key and
+    # the last lines on standard error
+    compared = res["compared"]
+    assert list(res)[-1] == "compared" and len(compared) >= 4
+    assert all(c["limit"] is not None and c["value"] <= c["limit"]
+               for c in compared.values())
+    tail = err.strip().splitlines()[-len(compared):]
+    assert [ln.split()[1].rstrip(":") for ln in tail] == list(compared)
+    assert all(ln.startswith("compared ") and " limit " in ln
+               for ln in tail)
 
 
 def test_no_accelerator_no_result():

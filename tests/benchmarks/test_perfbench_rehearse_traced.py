@@ -6,7 +6,7 @@ from bench_paths import run_cell
 from test_perfbench_rehearse import CELLS, check_line
 
 # what needs the device trace reads nothing on the CPU and is left out
-NEEDS_DEVICE = ("roofline_share.", )
+NEEDS_DEVICE = ("roofline_share.", "dev_share.")
 
 
 @pytest.mark.parametrize("workload", CELLS)
