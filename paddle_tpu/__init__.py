@@ -207,7 +207,7 @@ def flops(net, input_size, custom_ops=None, print_detail=False):
             total += 2 * n
     return total
 from .hapi.model import Model  # noqa: E402,F401
-from .nn.layer.base import Layer  # noqa: E402,F401
+from .nn.layer.base import Layer, LazyGuard  # noqa: E402,F401
 from . import framework  # noqa: E402,F401
 from .framework import random  # noqa: E402,F401
 

@@ -35,7 +35,13 @@ class Tensor:
                  name=None):
         if isinstance(data, Tensor):
             data = data._data
-        if not isinstance(data, jax.Array) and not isinstance(
+        if isinstance(data, jax.ShapeDtypeStruct):
+            # a declared value (nn.LazyGuard): shape and dtype, no
+            # storage until set_value() / Parameter.initialize()
+            if dtype is not None:
+                data = jax.ShapeDtypeStruct(data.shape,
+                                            dtypes.to_jax(dtype))
+        elif not isinstance(data, jax.Array) and not isinstance(
                 data, jax.core.Tracer):
             data = np.asarray(data)
             if dtype is None and data.dtype == np.float64:
@@ -256,8 +262,31 @@ class Parameter(Tensor):
         super().__init__(data, dtype=dtype, stop_gradient=not trainable,
                          name=name)
         self.persistable = True
+        self._lazy_init = None  # (initializer, shape, dtype) while the
+        #   value is only declared (nn.LazyGuard)
+
+    @property
+    def is_declared(self):
+        """True while the parameter has a shape and a dtype but no
+        value (created under ``nn.LazyGuard``)."""
+        return isinstance(self._data, jax.ShapeDtypeStruct)
+
+    def initialize(self):
+        """Give a declared parameter the value its initializer would
+        have given it at creation (``paddle.LazyGuard``'s
+        ``param.initialize()``); a no-op on a parameter that has a
+        value."""
+        if self.is_declared:
+            init, shape, dtype = self._lazy_init
+            self._data = Tensor(init(shape, dtype))._data.astype(
+                self._data.dtype)
+        self._lazy_init = None
+        return self
 
     def __repr__(self):
+        if self.is_declared:
+            return (f"Parameter declared: shape {list(self._data.shape)}"
+                    f" dtype {self._data.dtype}")
         return "Parameter containing:\n" + super().__repr__()
 
 

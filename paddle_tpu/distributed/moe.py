@@ -230,3 +230,131 @@ def collect_moe_aux_loss(layer: Layer):
             if a is not None:
                 total = a if total is None else total + a
     return total
+
+
+# -- dropless routing (serving) ------------------------------------------
+#
+# The capacity path above drops what overflows and moves dense
+# [E, C, D] buffers, which is what training over an 'ep' axis wants.
+# Serving computes every (token, selected expert) pair: pairs are sorted
+# by expert and each projection is ONE grouped product over the sorted
+# rows, so a step reads the weights of the experts that were hit and of
+# no other.
+
+def sigmoid_topk_routing(logits, bias, k, scale=1.0, normalize=True):
+    """Sigmoid-scored top-k with a selection-only correction bias (the
+    ``noaux_tc`` gate of DeepSeek-V3's published code, one group):
+    ``s = sigmoid(logits)`` in float32; the ``k`` experts are the top
+    ``k`` of ``s + bias``; the weights are the UNBIASED scores of the
+    selected, normalised to sum to one (``normalize``) and multiplied
+    by ``scale``.  logits [T, E] -> (choice [T, k] int32, weights
+    [T, k] float32)."""
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, choice = jax.lax.top_k(s + bias.astype(jnp.float32)[None, :], k)
+    w = jnp.take_along_axis(s, choice, axis=-1)
+    if normalize and k > 1:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return choice.astype(jnp.int32), w * scale
+
+
+def sort_pairs_by_expert(choice, live, num_experts):
+    """Sort the T x k (token, expert) pairs by expert.  ``live`` [T]
+    marks the rows that are real tokens: the pairs of the others go to
+    the end and belong to no group, so they hit no expert.  Returns
+    (order [P] pair indices in sorted order, group_sizes [E] int32);
+    the token of sorted row i is ``order[i] // k``."""
+    t, k = choice.shape
+    flat = jnp.where(live[:, None], choice, num_experts).reshape(t * k)
+    order = jnp.argsort(flat, stable=True)
+    sizes = jnp.zeros((num_experts + 1,), jnp.int32).at[flat].add(1)
+    return order.astype(jnp.int32), sizes[:num_experts]
+
+
+def _gmm_tiling(m, k, n):
+    """Tile sizes of the megablox kernel for an [m, k] x [E, k, n]
+    grouped product: all of a short m in one tile (a decode step's
+    pairs: every hit expert then costs one pass over its own weights),
+    128 rows otherwise; the whole contraction in one k tile (up to
+    2,048) and the widest n tile of those tried that divides n.  Chip
+    run, PR 28, 40 experts hit, m 192: [2,048 -> 2,816] 0.65 ms at
+    (192, 2048, 1408) against 0.72 at (192, 1024, 1408), [1,408 ->
+    2,048] 0.34 ms at (192, 1408, 1024) against 0.60 at (192, 128,
+    1024) and 0.42 at (192, 1408, 256): 87% and 83% of the weights'
+    time at 819 GB/s."""
+    tm = m if m <= 256 else 128
+    tk = k if k <= 2048 else next(
+        t for t in (2048, 1024, 512, 256, 128, k) if k % t == 0)
+    tn = next(t for t in (1408, 1024, 512, 256, 128, n) if n % t == 0)
+    return tm, tk, tn
+
+
+def grouped_matmul_impl():
+    """What ``grouped_matmul`` runs where no ``impl`` is named: the
+    megablox kernel on a TPU, ``ragged_dot`` elsewhere (the kernel's
+    interpret mode is slow and the CPU tests need the arithmetic, not
+    the schedule; on the v5e ``ragged_dot`` takes 2.2 x the kernel's
+    time, PERF.md, PR 28).  A served model reports it
+    (``ServingSpec.kernels``), so ``/healthz`` says which one a
+    replica serves on."""
+    return "gmm" if jax.default_backend() == "tpu" else "ragged_dot"
+
+
+def grouped_matmul(lhs, rhs, group_sizes, impl=None):
+    """Rows of ``lhs`` [m, k], sorted by group, each times its group's
+    matrix of ``rhs`` [E, k, n] -> float32 [m, n].  Rows past
+    ``sum(group_sizes)`` belong to no group and come back as zeros.
+
+    ``impl``: ``"gmm"`` is the megablox Pallas kernel, which visits the
+    (row tile, group) pairs that hold rows and so reads each hit
+    group's matrix once; ``"ragged_dot"`` is XLA's own.  Default:
+    ``grouped_matmul_impl()``."""
+    if impl is None:
+        impl = grouped_matmul_impl()
+    m = lhs.shape[0]
+    if impl == "ragged_dot":
+        out = jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                                 preferred_element_type=jnp.float32)
+    elif impl == "gmm":
+        # (the package's own ``gmm`` attribute is its custom_vjp
+        # wrapper, which takes no keywords: name the kernel's module)
+        import importlib
+        gmm = importlib.import_module(
+            "jax.experimental.pallas.ops.tpu.megablox.gmm").gmm
+        pad = -m % 8 if m <= 256 else -m % 128
+        if pad:
+            lhs = jnp.concatenate(
+                [lhs, jnp.zeros((pad, lhs.shape[1]), lhs.dtype)])
+        out = gmm(lhs, rhs, group_sizes,
+                  preferred_element_type=jnp.float32,
+                  tiling=_gmm_tiling(m + pad, rhs.shape[1],
+                                     rhs.shape[2]))[:m]
+    else:
+        raise ValueError(f"grouped_matmul impl {impl!r}")
+    # the kernel never visits the rows of no group: whatever memory
+    # holds there must not reach the sum over a token's pairs
+    in_group = jnp.arange(m) < jnp.sum(group_sizes)
+    return jnp.where(in_group[:, None], out, 0.0)
+
+
+def dropless_experts(x, choice, weights, live, w_in, w_out):
+    """Every (token, selected expert) pair through its expert's gated
+    feed-forward, nothing dropped: ``y_t = sum_j weights[t, j] *
+    E_choice[t, j](x_t)`` with ``E_e(x) = (silu(x W1_e) * (x W3_e))
+    W2_e``.
+
+    x [T, D]; choice / weights [T, k]; live [T] bool (rows that are
+    tokens); w_in [E, D, 2F] holds W1 | W3 side by side, w_out
+    [E, F, D].  Returns (y [T, D] float32, stats int32 [3]: pairs
+    computed, experts hit, the busiest expert's pairs)."""
+    t, k = choice.shape
+    e, f = w_in.shape[0], w_out.shape[1]
+    order, sizes = sort_pairs_by_expert(choice, live, e)
+    rows = x[order // k]                                   # [P, D]
+    a = grouped_matmul(rows, w_in, sizes)                  # [P, 2F]
+    act = (jax.nn.silu(a[:, :f]) * a[:, f:]).astype(x.dtype)
+    o = grouped_matmul(act, w_out, sizes)                  # [P, D]
+    o = o * weights.reshape(t * k)[order][:, None]
+    y = jnp.zeros((t, x.shape[1]), jnp.float32).at[order // k].add(o)
+    stats = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0),
+                       jnp.max(sizes)]).astype(jnp.int32)
+    return y, stats

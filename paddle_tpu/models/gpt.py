@@ -14,10 +14,8 @@ sharding stage2, GPT-3 1.3B hybrid) instantiate from ``GPT_CONFIGS``.
 """
 from __future__ import annotations
 
-import functools
 import math
 import threading
-import time
 from contextlib import contextmanager as _contextmanager
 
 from .. import nn
@@ -26,34 +24,13 @@ from ..nn import initializer as I
 from ..core.tensor import Tensor
 from ..nn.layer.scan import ScanLayers
 from ..ops import reshape, transpose, concat
+from .programs import (  # noqa: F401
+    KVRowSpec, ServedModel, ServingSpec, _jit_named, _scoped,
+    filter_logits_lanes, sample_lanes, slot_sample_keys,
+)
 
 
 _sample_rows_jit = None  # lazily-jitted single-call sampler (below)
-
-
-def _jit_named(kind, pure, **jit_kwargs):
-    """``jax.jit(pure)`` under the name of the program's
-    ``_compile_probe`` kind, so that a device trace's ``XLA Modules``
-    line reads ``jit_gpt_fused_decode(...)`` where it read
-    ``jit_pure(...)`` for every program alike.  (The module name is
-    part of the persistent compile cache's key.)"""
-    import jax
-    pure.__name__ = pure.__qualname__ = "gpt_" + kind
-    return jax.jit(pure, **jit_kwargs)
-
-
-def _scoped(name):
-    """Run the decorated method under ``jax.named_scope(name)``: the
-    scope shows in the op metadata of a device trace, so a program's
-    time splits into attention / mlp / lm_head / sampling."""
-    def deco(fn):
-        @functools.wraps(fn)
-        def wrapped(*args, **kwargs):
-            import jax
-            with jax.named_scope(name):
-                return fn(*args, **kwargs)
-        return wrapped
-    return deco
 
 
 def _is_quant_kv(pool):
@@ -1057,7 +1034,7 @@ class GPTLMHead(nn.Layer):
         return self.lm_head(self.ln_f(x))
 
 
-class GPTModel(nn.Layer):
+class GPTModel(ServedModel, nn.Layer):
     """Decoder-only LM returning logits [B, S, V]."""
 
     def __init__(self, num_layers=12, hidden_size=768, num_heads=12,
@@ -1228,78 +1205,11 @@ class GPTModel(nn.Layer):
             last = jnp.where(last < cutoff, -1e9, last)
         return last
 
-    @staticmethod
-    def _filter_logits_lanes(last, temperature, top_k, top_p):
-        """PER-LANE sampling filters on f32 logits [B, V]: temperature
-        / top_k / top_p are [B] arrays — one independent request per
-        batch row (the serving slot pool), every parameter traced, so
-        ONE compiled program serves any per-slot mix.  Same filter
-        sequence and masking values as ``_filter_logits`` (temperature
-        -> top-k -> top-p over the already-masked row), just with the
-        scalars lifted to lanes; ``top_k == 0`` / ``top_p == 1``
-        disable their filter lane-wise, and a ``temperature == 0``
-        greedy-sentinel lane passes through at temperature 1 (its
-        filtered row is discarded — ``_sample_lanes`` argmaxes the raw
-        logits instead)."""
-        import jax
-        import jax.numpy as jnp
-        V = last.shape[-1]
-        t_eff = jnp.where(temperature > 0, temperature, 1.0)[:, None]
-        x = last / t_eff
-        srt = jnp.sort(x, axis=-1)[:, ::-1]
-        k_eff = jnp.clip(top_k, 1, V).astype(jnp.int32)
-        kth = jnp.take_along_axis(srt, (k_eff - 1)[:, None], axis=-1)
-        x = jnp.where((top_k > 0)[:, None] & (x < kth), -1e9, x)
-        p_eff = jnp.maximum(top_p, 1e-9)[:, None]
-        srt2 = jnp.sort(x, axis=-1)[:, ::-1]
-        probs = jax.nn.softmax(srt2, axis=-1)
-        cum = jnp.cumsum(probs, axis=-1)
-        keep = (cum - probs) < p_eff
-        cutoff = jnp.min(jnp.where(keep, srt2, jnp.inf), axis=-1,
-                         keepdims=True)
-        return jnp.where((top_p < 1.0)[:, None] & (x < cutoff), -1e9, x)
-
-    @staticmethod
-    def _slot_sample_keys(seed_lo, seed_hi, ctr):
-        """Per-slot sampling keys for the fused dispatches: fold the
-        emitted-token counter into each request's seed-derived key
-        (core/rng.request_key over the uint32 seed words), so token i
-        of a request always draws from fold(request_key, i) — the same
-        stream whether it is emitted by a one-token tick, a verify-
-        window lane, or the eager first-token pick after prefill.
-        seed_lo/seed_hi uint32 [B], ctr int32 [B] -> keys [B]."""
-        import jax
-        from ..core import rng as rng_mod
-        return jax.vmap(lambda lo, hi, c: jax.random.fold_in(
-            rng_mod.request_key(lo, hi), c))(seed_lo, seed_hi, ctr)
-
-    @staticmethod
-    @_scoped("sampling")
-    def _sample_lanes(last, temperature, top_k, top_p, keys):
-        """One token per slot row from [B, V] logits with PER-SLOT
-        sampling params and keys: lanes with ``temperature == 0`` (the
-        greedy sentinel) take the raw argmax — bit-identical to the
-        host path's ``np.argmax`` on the same logits — and sampling
-        lanes draw categorically from the lane-filtered distribution.
-        The filter/draw pipeline (two [B, V] sorts + categorical) sits
-        behind a runtime ``lax.cond``: an all-greedy batch — the
-        serving default — skips it entirely instead of computing both
-        sides of a where, while staying ONE compiled program.
-        Returns int32 [B]."""
-        import jax
-        import jax.numpy as jnp
-        last = last.astype(jnp.float32)
-        greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
-
-        def draw(_):
-            filt = GPTModel._filter_logits_lanes(last, temperature,
-                                                 top_k, top_p)
-            sampled = jax.vmap(jax.random.categorical)(keys, filt)
-            return jnp.where(temperature > 0, sampled,
-                             greedy).astype(jnp.int32)
-
-        return jax.lax.cond(jnp.any(temperature > 0), draw,
-                            lambda _: greedy, None)
+    # the per-slot sampling of the fused dispatches is every served
+    # model's (models/programs.py)
+    _filter_logits_lanes = staticmethod(filter_logits_lanes)
+    _slot_sample_keys = staticmethod(slot_sample_keys)
+    _sample_lanes = staticmethod(sample_lanes)
 
     def _decode_tick(self, tok, k_bufs, v_bufs, pos):
         """One-token decode against fixed-size cache buffers: embeddings
@@ -1776,73 +1686,6 @@ class GPTModel(nn.Layer):
         return cache[cache_key]
 
     # -- compile-event hook (serving observability) --------------------
-    def add_compile_listener(self, cb):
-        """Register ``cb(kind, cache_key, wall_s)`` to fire right after
-        the FIRST call of each freshly built jitted program (the call
-        where jax traces and XLA compiles it).  Production-side
-        compile-thrash detector: the serving engine turns every event
-        into a trace span plus the ``serving.compiles_total`` counter,
-        so a traffic shape that defeats the program caches is visible
-        in /metrics instead of only as mystery latency.  A callback
-        that returns False (or raises) is deregistered — the engine
-        registers a weakref'd method so a collected engine drops off
-        this list by itself."""
-        listeners = getattr(self, "_compile_listeners", None)
-        if listeners is None:
-            listeners = self._compile_listeners = []
-        listeners.append(cb)
-        return cb
-
-    def remove_compile_listener(self, cb):
-        try:
-            getattr(self, "_compile_listeners", []).remove(cb)
-        except ValueError:
-            pass
-
-    def _compile_probe(self, kind, cache_key, fn):
-        """Wrap a freshly jitted dispatch so its first call is timed
-        and announced to ``add_compile_listener`` subscribers; later
-        calls pay one truthiness check.  The wall time covers trace +
-        XLA compile + the first execution — on a cache-warm process the
-        event simply never fires, which is exactly the signal: events
-        appearing in steady state mean the program cache is thrashing."""
-        import threading
-        done = []
-        first_lock = threading.Lock()
-        model = self
-
-        def probed(*args):
-            if done:
-                return fn(*args)
-            t0 = time.perf_counter()
-            out = fn(*args)
-            wall = time.perf_counter() - t0
-            with first_lock:
-                if done:
-                    # two threads raced the same cold program (sibling
-                    # engines over one model): exactly ONE fires the
-                    # event — the loser piggybacked on jax's compile
-                    # lock and must not double-count the compile
-                    return out
-                done.append(True)
-            listeners = getattr(model, "_compile_listeners", None)
-            if listeners:
-                for cb in list(listeners):
-                    try:
-                        alive = cb(kind, cache_key, wall)
-                    except Exception:
-                        alive = False
-                    if alive is False:
-                        try:
-                            listeners.remove(cb)
-                        except ValueError:
-                            pass
-            return out
-
-        probed.kind = kind  # the engine labels its dev.* spans with it
-        probed.__name__ = "gpt_" + kind
-        return probed
-
     def _compiled_fused_decode_fn(self, pnames, params, cache_key,
                                   paged=False):
         """Build (or fetch) the jitted FUSED decode+sample tick for
@@ -2940,6 +2783,41 @@ class GPTModel(nn.Layer):
             if was_training:
                 self.train()
         return T(jnp.concatenate(out, axis=1))
+
+    # -- the serving seam (models/programs.py) -------------------------
+    def serving_spec(self):
+        """What ``serving.Engine`` asks of this model: K and V rows of
+        ``[H, hd]`` a layer in the dtype the attention projections
+        compute in, the position table's length, and nothing it
+        cannot honour (every engine option was written against this
+        model)."""
+        attn0 = self.blocks[0].attn
+        if attn0.use_mp:
+            dtype = attn0.qkv_weight._data.dtype
+        else:
+            # compute_dtype first: a weight-only-int8 projection's
+            # .weight property would materialize the dequantized matrix
+            dtype = getattr(attn0.qkv_proj, "compute_dtype", None) \
+                or attn0.qkv_proj.weight._data.dtype
+        emb = self.embeddings
+        return ServingSpec(
+            kv=KVRowSpec.heads(len(self.blocks), attn0.num_heads,
+                               attn0.head_dim, dtype),
+            max_positions=emb.position_embeddings.weight.shape[0],
+            vocab_size=emb.word_embeddings.weight.shape[0],
+            hidden_size=emb.word_embeddings.weight.shape[1],
+            tensor_parallel=attn0.use_mp)
+
+    def serving_linear_stacks(self):
+        """The layers whose ``nn.Linear`` children weight-only int8
+        serving relayouts: the transformer blocks (embeddings and the
+        tied head stay)."""
+        return list(self.blocks)
+
+    def serving_lora_targets(self):
+        """One ``nn.Linear`` a layer that a LoRA delta folds into: the
+        attention output projection."""
+        return [blk.attn.out_proj for blk in self.blocks]
 
     def _sync_decode_twin(self):
         """Unrolled twin for KV-cache decode of a scan_layers model:

@@ -1,0 +1,676 @@
+"""Decoder with multi-head latent attention (MLA) and sigmoid-routed
+experts — the DeepSeek-V3 layer family (Kimi-VL-A3B's language decoder,
+Moonlight, DeepSeek-V2/V3), served on ``serving.Engine``'s paged path.
+
+Layer equations (pre-norm residual blocks, RMSNorm, no position table,
+untied head; d hidden, H heads, d_n / d_r the no-position and rotary
+parts of a query/key head, d_v the value head, r the latent rank):
+
+* attention, ``h = RMSNorm(x)``: ``q = h W_q`` -> per head
+  ``[q_n (d_n); q_r (d_r)]``; ``[c; k_r] = h W_kva`` (r + d_r);
+  ``c <- RMSNorm(c)``; ``k_r <- RoPE(k_r, pos)`` (ONE rotary key for
+  all heads); ``q_r <- RoPE(q_r, pos)``.  **The cached row of a
+  position is ``[c; k_r]``: r + d_r numbers a layer, no V.**
+  Expanded form: ``[k_n,i; v_i] = c W_kvb,i``; ``s_ij = (q_n,i . k_n,ij
+  + q_r,i . k_r,j) / sqrt(d_n + d_r)``; causal softmax;
+  ``o_i = sum_j p_ij v_ij``; ``y = concat(o) W_o``.
+  Absorbed form (the same numbers): ``q~_i = W_uk,i q_n,i`` (r),
+  ``s_ij = (q~_i . c_j + q_r,i . k_r,j) / sqrt(d_n + d_r)``,
+  ``o~_i = sum_j p_ij c_j``, ``o_i = o~_i W_uv,i`` with ``W_uk,i`` /
+  ``W_uv,i`` the K and V halves of ``W_kvb`` for head i: the query is
+  taken to the latent space, so a decode row never expands a cached
+  position.
+* the first ``first_k_dense`` layers: SwiGLU ``(silu(h W1) * (h W3))
+  W2``.
+* the others: ``s = sigmoid(h W_g)`` in float32; the k experts are the
+  top k of ``s + b`` (``b`` a correction bias used to select only);
+  ``w_e = scale * s_e / sum_selected s``; ``y = sum_e w_e E_e(h) +
+  S(h)``, ``E_e`` a SwiGLU of the expert width, ``S`` one SwiGLU of
+  ``n_shared`` times that width over every token.  No token is dropped
+  (``distributed/moe.py`` ``dropless_experts``).
+
+RoPE pairs dimension i with i + d_r/2 in STORED order (the published
+code first de-interleaves; that is a fixed permutation of W_q's and
+W_kva's columns).  What is not here: a vision tower (positions are
+token positions), ``q_lora_rank``, grouped top-k (one group), YaRN.
+
+Serving: ``serving_spec()`` declares one pool a layer of ``[r + d_r]``
+rows; ``serving_program`` offers the fused decode tick (absorbed form,
+walking each slot's live blocks in the pool's dtype) and the paged
+chunk prefill (absorbed or expanded by the chunk's shape), both
+returning an int32 counter vector beside their outputs.
+"""
+from __future__ import annotations
+
+import math
+
+from .. import nn
+from ..core.tensor import Tensor
+from ..nn import initializer as I
+from .programs import (
+    KVRowSpec, ServedModel, ServingSpec, _jit_named, _scoped,
+    sample_lanes, slot_sample_keys)
+
+# cache rows one trip of the blocked walk fetches (a whole number of
+# blocks): at 256 the v5e keeps a trip's rows on chip (PERF.md, PR 26)
+_WALK_ROWS = 256
+
+# counters of the routed layers, in the order of the vector the step
+# programs return; (registry name under "serving.", dev.* span arg)
+MOE_COUNTERS = (("moe_routed_pairs", "pairs"),
+                ("moe_experts_hit", "experts_hit"),
+                ("moe_expert_slots", None),
+                ("moe_load_max", None))
+
+
+def rms_norm(x, weight, eps):
+    """``weight * x / sqrt(mean(x^2) + eps)``, the mean in float32."""
+    import jax
+    import jax.numpy as jnp
+    xf = x.astype(jnp.float32)
+    xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True)
+                            + eps)
+    return xf.astype(x.dtype) * weight.astype(x.dtype)
+
+
+def rope(x, pos, theta):
+    """Rotate ``x [..., d]`` to positions ``pos`` (broadcastable to
+    ``x.shape[:-1]``): dimension i pairs with i + d/2, angle
+    ``pos * theta^(-2i/d)``, in float32."""
+    import jax.numpy as jnp
+    half = x.shape[-1] // 2
+    inv = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32)
+                  / half)
+    ang = jnp.asarray(pos, jnp.float32)[..., None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1 = x[..., :half].astype(jnp.float32)
+    x2 = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def _lin(layer, x):
+    """An ``nn.Linear`` (or its weight-only int8 form) on an array."""
+    return layer(Tensor(x))._data
+
+
+class RMSNorm(nn.Layer):
+    def __init__(self, size, eps):
+        super().__init__()
+        self.eps = float(eps)
+        self.weight = self.create_parameter(
+            [size], default_initializer=I.Constant(1.0))
+
+    def forward(self, x):
+        return rms_norm(x, self.weight._data, self.eps)
+
+
+class GatedMLP(nn.Layer):
+    """SwiGLU: ``(silu(x W1) * (x W3)) W2``; W1 | W3 side by side in
+    ``gate_up_proj``."""
+
+    def __init__(self, hidden, width):
+        super().__init__()
+        self.width = width
+        self.gate_up_proj = nn.Linear(hidden, 2 * width, bias_attr=False)
+        self.down_proj = nn.Linear(width, hidden, bias_attr=False)
+
+    def forward(self, x):
+        import jax
+        a = _lin(self.gate_up_proj, x)
+        act = jax.nn.silu(a[..., :self.width]) * a[..., self.width:]
+        return _lin(self.down_proj, act)
+
+
+class RoutedFFN(nn.Layer):
+    """Sigmoid-routed experts plus the shared expert (module
+    docstring).  ``forward`` returns ``(y, stats)``, ``stats`` int32
+    [3]: pairs computed, experts hit, the busiest expert's pairs."""
+
+    def __init__(self, hidden, width, num_experts, top_k, n_shared,
+                 scale, normalize=True):
+        super().__init__()
+        self.num_experts, self.top_k = num_experts, top_k
+        self.scale, self.normalize = float(scale), bool(normalize)
+        init = I.Normal(0.0, 0.02)
+        self.gate_weight = self.create_parameter(
+            [hidden, num_experts], default_initializer=init)
+        self.gate_bias = self.create_parameter(
+            [num_experts], is_bias=True)
+        self.experts_in = self.create_parameter(
+            [num_experts, hidden, 2 * width], default_initializer=init)
+        self.experts_out = self.create_parameter(
+            [num_experts, width, hidden], default_initializer=init)
+        self.shared = GatedMLP(hidden, n_shared * width)
+
+    @_scoped("moe.route")
+    def route(self, x):
+        import jax.numpy as jnp
+        from ..distributed.moe import sigmoid_topk_routing
+        logits = jnp.dot(x.astype(jnp.float32),
+                         self.gate_weight._data.astype(jnp.float32),
+                         precision="highest")
+        return sigmoid_topk_routing(logits, self.gate_bias._data,
+                                    self.top_k, self.scale,
+                                    self.normalize)
+
+    @_scoped("moe.experts")
+    def experts(self, x, choice, weights, live):
+        from ..distributed.moe import dropless_experts
+        return dropless_experts(x, choice, weights, live,
+                                self.experts_in._data,
+                                self.experts_out._data)
+
+    @_scoped("moe.shared")
+    def shared_expert(self, x):
+        return self.shared(x)
+
+    def forward(self, x, live):
+        """x [T, D]; live [T] bool, the rows that are tokens."""
+        choice, weights = self.route(x)
+        y, stats = self.experts(x, choice, weights, live)
+        return y.astype(x.dtype) + self.shared_expert(x), stats
+
+
+class MLAttention(nn.Layer):
+    def __init__(self, hidden, num_heads, qk_nope_head_dim,
+                 qk_rope_head_dim, v_head_dim, kv_lora_rank, rope_theta,
+                 eps):
+        super().__init__()
+        self.num_heads = num_heads
+        self.d_n, self.d_r = qk_nope_head_dim, qk_rope_head_dim
+        self.d_v, self.rank = v_head_dim, kv_lora_rank
+        self.theta = float(rope_theta)
+        self.row = kv_lora_rank + qk_rope_head_dim
+        self.q_proj = nn.Linear(hidden, num_heads * (self.d_n + self.d_r),
+                                bias_attr=False)
+        self.kv_a_proj = nn.Linear(hidden, self.row, bias_attr=False)
+        self.kv_norm = RMSNorm(kv_lora_rank, eps)
+        # [r, H * (d_n + d_v)]: a raw parameter, because the absorbed
+        # form contracts its K and V halves separately
+        self.kv_b = self.create_parameter(
+            [kv_lora_rank, num_heads * (self.d_n + self.d_v)],
+            default_initializer=I.Normal(0.0, 0.02))
+        self.o_proj = nn.Linear(num_heads * self.d_v, hidden,
+                                bias_attr=False)
+
+    def _w_kvb(self):
+        return self.kv_b._data.reshape(self.rank, self.num_heads,
+                                       self.d_n + self.d_v)
+
+    def project(self, h, pos):
+        """h [B, S, D], pos [B, S] -> q_n [B, S, H, d_n], q_r
+        [B, S, H, d_r] (rotated) and the row to cache [B, S, r + d_r]:
+        the normed latent and the rotated shared key."""
+        import jax.numpy as jnp
+        B, S = h.shape[0], h.shape[1]
+        q = _lin(self.q_proj, h).reshape(B, S, self.num_heads,
+                                         self.d_n + self.d_r)
+        q_n = q[..., :self.d_n]
+        q_r = rope(q[..., self.d_n:], pos[:, :, None], self.theta)
+        ckr = _lin(self.kv_a_proj, h)
+        c = self.kv_norm(ckr[..., :self.rank])
+        k_r = rope(ckr[..., self.rank:], pos, self.theta)
+        return q_n, q_r, jnp.concatenate([c, k_r], axis=-1)
+
+    def absorbed_wins(self, window):
+        """Which form a window of ``window`` queries attends to its
+        cached prefix in.  For every (query, cached position) pair
+        absorbed costs ``2 H (r + d_r + r)`` operations (scores over
+        the row, context over the latent); expanded costs
+        ``2 H (d_n + d_r + d_v)`` and, once for each position a trip
+        fetches, the expansion ``2 r H (d_n + d_v)``.  Absorbed wins
+        while ``window * (absorbed - expanded) < expansion``: at
+        H 16, d_n 128, d_r 64, d_v 128, r 512 that is 34.8 against
+        10.2 kFLOP a pair and 4.19 MFLOP a position, so up to 170
+        queries — a decode row (1) is absorbed, a 256-token chunk
+        expanded."""
+        H = self.num_heads
+        absorbed = 2 * H * (2 * self.rank + self.d_r)
+        expanded = 2 * H * (self.d_n + self.d_r + self.d_v)
+        expansion = 2 * self.rank * H * (self.d_n + self.d_v)
+        return window * (absorbed - expanded) < expansion
+
+    def attend(self, q_n, q_r, pool, tables, pos, absorbed=None):
+        """Causal attention of a window of queries over each slot's
+        cached rows, read through its block table ``_WALK_ROWS`` rows a
+        trip in the pool's dtype with float32 accumulation, only as far
+        as the longest live window (``ceil((max(pos) + S) / chunk)``
+        trips, read on the device), one pass with a running maximum
+        and denominator — ``GPTAttention._slot_attn``'s walk over
+        latent rows.
+
+        q_n [B, S, H, d_n], q_r [B, S, H, d_r]; pool [NB, bs, W], W >=
+        r + d_r (the row, then the spec's lane padding, never written);
+        tables int32 [B, L // bs]; pos int32 [B] (window start).  The
+        query at offset s of slot b sees rows <= pos[b] + s.  Returns
+        [B, S, H * d_v]."""
+        import jax
+        import jax.numpy as jnp
+        B, S, H = q_n.shape[0], q_n.shape[1], q_n.shape[2]
+        bs, r = pool.shape[1], self.rank
+        if absorbed is None:
+            absorbed = self.absorbed_wins(S)
+        table_rows = tables.shape[1] * bs
+        chunk = min(table_rows, bs * max(1, _WALK_ROWS // bs))
+        w_kvb = self._w_kvb()
+        scale = 1.0 / math.sqrt(self.d_n + self.d_r)
+        q_end = pos[:, None] + jnp.arange(S)[None, :]          # [B, S]
+        if absorbed:
+            with jax.named_scope("mla.absorbed"):
+                q_lat = jnp.einsum("bshn,rhn->bshr", q_n,
+                                   w_kvb[..., :self.d_n],
+                                   preferred_element_type=jnp.float32)
+                # one query over the whole cached row [c; k_r] (and
+                # zeros over the pool's lane padding)
+                qq = jnp.concatenate(
+                    [q_lat, q_r.astype(jnp.float32),
+                     jnp.zeros(q_lat.shape[:-1]
+                               + (pool.shape[2] - self.row,))],
+                    axis=-1).astype(pool.dtype)
+            width = r
+        else:
+            width = self.d_v
+
+        def trip(c, carry):
+            top, den, acc = carry
+            start = jnp.minimum(c * chunk, table_rows - chunk)
+            cols = jax.lax.dynamic_slice_in_dim(
+                tables, start // bs, chunk // bs, axis=1)
+            rows = pool[cols].reshape(B, chunk, pool.shape[2])
+            if absorbed:
+                sc = jnp.einsum("bshr,bkr->bhsk", qq, rows,
+                                preferred_element_type=jnp.float32)
+            else:
+                kv = jnp.einsum("bkr,rhe->bkhe", rows[..., :r], w_kvb,
+                                preferred_element_type=jnp.float32
+                                ).astype(pool.dtype)
+                sc = (jnp.einsum("bshn,bkhn->bhsk", q_n,
+                                 kv[..., :self.d_n],
+                                 preferred_element_type=jnp.float32)
+                      + jnp.einsum("bshr,bkr->bhsk", q_r,
+                                   rows[..., r:self.row],
+                                   preferred_element_type=jnp.float32))
+            at = start + jnp.arange(chunk)
+            # rows below c * chunk were scored by an earlier trip (the
+            # last trip of a table that is no whole number of chunks
+            # starts early)
+            visible = ((at[None, None, :] <= q_end[:, :, None])
+                       & (at >= c * chunk)[None, None, :])
+            sc = jnp.where(visible[:, None, :, :], sc * scale, -1e30)
+            new_top = jnp.maximum(top, jnp.max(sc, axis=-1))
+            keep = jnp.exp(top - new_top)
+            p = jnp.exp(sc - new_top[..., None])
+            if absorbed:
+                ctx = jnp.einsum("bhsk,bkr->bshr", p,
+                                 rows[..., :r].astype(jnp.float32),
+                                 precision=jax.lax.Precision.HIGHEST)
+            else:
+                ctx = jnp.einsum("bhsk,bkhv->bshv", p,
+                                 kv[..., self.d_n:].astype(jnp.float32),
+                                 precision=jax.lax.Precision.HIGHEST)
+            keep_c = jnp.transpose(keep, (0, 2, 1))[..., None]
+            return (new_top, den * keep + jnp.sum(p, axis=-1),
+                    acc * keep_c + ctx)
+
+        init = (jnp.full((B, H, S), -1e30, jnp.float32),
+                jnp.zeros((B, H, S), jnp.float32),
+                jnp.zeros((B, S, H, width), jnp.float32))
+        trips = -(-table_rows // chunk)
+        with jax.named_scope("mla.absorbed" if absorbed
+                             else "mla.expanded"):
+            if trips == 1:
+                _, den, acc = trip(0, init)
+            else:
+                live = jnp.clip((jnp.max(pos) + S + chunk - 1) // chunk,
+                                1, trips)
+                _, den, acc = jax.lax.fori_loop(0, live, trip, init)
+            ctx = acc / jnp.transpose(den, (0, 2, 1))[..., None]
+            if absorbed:
+                ctx = jnp.einsum("bshr,rhv->bshv",
+                                 ctx.astype(pool.dtype),
+                                 w_kvb[..., self.d_n:],
+                                 preferred_element_type=jnp.float32)
+        return ctx.astype(q_n.dtype).reshape(B, S, H * self.d_v)
+
+    @_scoped("attention")
+    def decode_slots_paged(self, h, pool, tables, pos):
+        """One token a slot: write its row into the block that holds
+        ``pos[b]``, attend in the absorbed form.  h [B, 1, D]; pool
+        [NB, bs, r + d_r]; tables [B, L // bs]; pos [B].  Returns
+        (out [B, 1, D], pool)."""
+        import jax.numpy as jnp
+        q_n, q_r, row = self.project(h, pos[:, None])
+        bs = pool.shape[1]
+        # scattered into the pool as it lies ([block, row in block]):
+        # through a flattened view the v5e compiler copies the whole
+        # pool every step (264 MB a layer; chip run, PR 28)
+        pool = pool.at[tables[jnp.arange(h.shape[0]), pos // bs],
+                       pos % bs, :self.row].set(
+            row[:, 0].astype(pool.dtype))
+        out = self.attend(q_n, q_r, pool, tables, pos, absorbed=True)
+        return _lin(self.o_proj, out), pool
+
+    @_scoped("attention")
+    def prefill_chunk_paged(self, h, pool, table, pos, true_len,
+                            scratch=0):
+        """C prompt tokens of ONE slot at positions ``pos..pos+C-1``:
+        their rows scatter through the slot's table (pad lanes, >=
+        ``true_len``, into the ``scratch`` block), then the chunk
+        attends causally over the slot's rows, the adopted prefix and
+        itself included, in the form ``absorbed_wins`` picks for C.
+        h [1, C, D]; table [L // bs]; pos / true_len / scratch traced
+        scalars.  Returns (out [1, C, D], pool)."""
+        import jax.numpy as jnp
+        C = h.shape[1]
+        offs = pos + jnp.arange(C)
+        q_n, q_r, row = self.project(h, offs[None, :])
+        bs = pool.shape[1]
+        valid = jnp.arange(C) < true_len
+        safe = jnp.where(valid, offs, 0)
+        pool = pool.at[jnp.where(valid, table[safe // bs], scratch),
+                       jnp.where(valid, safe % bs, 0), :self.row].set(
+            row[0].astype(pool.dtype))
+        out = self.attend(q_n, q_r, pool, table[None, :],
+                          jnp.reshape(pos, (1,)))
+        return _lin(self.o_proj, out), pool
+
+    def forward(self, h, absorbed=False):
+        """Uncached causal attention over a whole sequence, h
+        [B, S, D], in either form (the CPU tests hold one against the
+        other and both against the reference)."""
+        import jax
+        import jax.numpy as jnp
+        B, S = h.shape[0], h.shape[1]
+        pos = jnp.broadcast_to(jnp.arange(S)[None, :], (B, S))
+        q_n, q_r, row = self.project(h, pos)
+        c, k_r = row[..., :self.rank], row[..., self.rank:]
+        w_kvb = self._w_kvb()
+        if absorbed:
+            q_lat = jnp.einsum("bshn,rhn->bshr", q_n,
+                               w_kvb[..., :self.d_n])
+            sc = jnp.einsum("bshr,bkr->bhsk", q_lat, c)
+        else:
+            kv = jnp.einsum("bkr,rhe->bkhe", c, w_kvb)
+            sc = jnp.einsum("bshn,bkhn->bhsk", q_n, kv[..., :self.d_n])
+        sc = (sc + jnp.einsum("bshr,bkr->bhsk", q_r, k_r)).astype(
+            jnp.float32) / math.sqrt(self.d_n + self.d_r)
+        causal = jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
+        p = jax.nn.softmax(jnp.where(causal[None, None], sc, -1e30),
+                           axis=-1).astype(h.dtype)
+        if absorbed:
+            ctx = jnp.einsum("bshr,rhv->bshv",
+                             jnp.einsum("bhsk,bkr->bshr", p, c),
+                             w_kvb[..., self.d_n:])
+        else:
+            ctx = jnp.einsum("bhsk,bkhv->bshv", p, kv[..., self.d_n:])
+        return _lin(self.o_proj,
+                    ctx.reshape(B, S, self.num_heads * self.d_v))
+
+
+class MLAMoEBlock(nn.Layer):
+    """``x += Attn(RMSNorm(x)); x += FFN(RMSNorm(x))``; ``ffn`` is a
+    ``GatedMLP`` (dense layer) or a ``RoutedFFN``."""
+
+    def __init__(self, cfg, routed):
+        super().__init__()
+        d, eps = cfg["hidden_size"], cfg["rms_norm_eps"]
+        self.input_norm = RMSNorm(d, eps)
+        self.attn = MLAttention(
+            d, cfg["num_attention_heads"], cfg["qk_nope_head_dim"],
+            cfg["qk_rope_head_dim"], cfg["v_head_dim"],
+            cfg["kv_lora_rank"], cfg["rope_theta"], eps)
+        self.post_norm = RMSNorm(d, eps)
+        self.routed = routed
+        self.ffn = (RoutedFFN(d, cfg["moe_intermediate_size"],
+                              cfg["n_routed_experts"],
+                              cfg["num_experts_per_tok"],
+                              cfg["n_shared_experts"],
+                              cfg["routed_scaling_factor"],
+                              cfg.get("norm_topk_prob", True))
+                    if routed else GatedMLP(d, cfg["intermediate_size"]))
+
+    @_scoped("mlp")
+    def feed_forward(self, x, live):
+        """x [B, S, D], live [B, S] -> (x + FFN(RMSNorm(x)), stats or
+        None)."""
+        h = self.post_norm(x)
+        if not self.routed:
+            return x + self.ffn(h), None
+        y, stats = self.ffn(h.reshape(-1, h.shape[-1]),
+                            live.reshape(-1))
+        return x + y.reshape(x.shape), stats
+
+    def decode_slots_paged(self, x, pool, tables, pos, live):
+        a, pool = self.attn.decode_slots_paged(self.input_norm(x), pool,
+                                               tables, pos)
+        x, stats = self.feed_forward(x + a, live[:, None])
+        return x, pool, stats
+
+    def prefill_chunk_paged(self, x, pool, table, pos, true_len,
+                            scratch, live):
+        a, pool = self.attn.prefill_chunk_paged(
+            self.input_norm(x), pool, table, pos, true_len, scratch)
+        x, stats = self.feed_forward(x + a, live[None, :])
+        return x, pool, stats
+
+    def forward(self, x, absorbed=False):
+        import jax.numpy as jnp
+        x = x + self.attn(self.input_norm(x), absorbed)
+        return self.feed_forward(x, jnp.ones(x.shape[:2], bool))[0]
+
+
+class MLAMoEModel(ServedModel, nn.Layer):
+    """Decoder-only LM of the module's docstring.  ``config`` holds the
+    published keys (``hidden_size``, ``num_attention_heads``,
+    ``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
+    ``kv_lora_rank``, ``intermediate_size``, ``moe_intermediate_size``,
+    ``n_routed_experts``, ``num_experts_per_tok``, ``n_shared_experts``,
+    ``routed_scaling_factor``, ``first_k_dense_replace``,
+    ``num_hidden_layers``, ``vocab_size``, ``max_position_embeddings``,
+    ``rms_norm_eps``, ``rope_theta``); build it under
+    ``nn.LazyGuard()`` to declare the parameters without values."""
+
+    def __init__(self, config):
+        super().__init__()
+        cfg = dict(config)
+        if cfg.get("q_lora_rank") is not None:
+            raise ValueError("q_lora_rank: the query is not compressed "
+                             "in this decoder")
+        if cfg.get("n_group", 1) != 1 or cfg.get("topk_group", 1) != 1:
+            raise ValueError("grouped top-k routing is not written: "
+                             "n_group and topk_group have to be 1")
+        if cfg.get("scoring_func", "sigmoid") != "sigmoid":
+            raise ValueError("the gate scores with a sigmoid")
+        if cfg.get("rope_scaling") is not None:
+            raise ValueError("rope_scaling is not written")
+        self.config = cfg
+        d = cfg["hidden_size"]
+        self.embed = self.create_parameter(
+            [cfg["vocab_size"], d],
+            default_initializer=I.Normal(0.0, 0.02))
+        self.blocks = nn.LayerList([
+            MLAMoEBlock(cfg, routed=i >= cfg["first_k_dense_replace"])
+            for i in range(cfg["num_hidden_layers"])])
+        self.norm = RMSNorm(d, cfg["rms_norm_eps"])
+        self.lm_head = nn.Linear(d, cfg["vocab_size"], bias_attr=False)
+
+    @property
+    def routed_layers(self):
+        return sum(1 for b in self.blocks if b.routed)
+
+    def _counter_vector(self, stats):
+        """The int32 [4] the step programs return: pairs, experts hit,
+        expert slots (routed layers x experts, once a run), the busiest
+        expert's pairs — each summed over the routed layers."""
+        import jax.numpy as jnp
+        slots = self.routed_layers * self.config["n_routed_experts"]
+        if not stats:
+            return jnp.zeros((4,), jnp.int32)
+        s = sum(stats)
+        return jnp.stack([s[0], s[1], jnp.int32(slots), s[2]])
+
+    @_scoped("lm_head")
+    def _head(self, x):
+        import jax.numpy as jnp
+        return _lin(self.lm_head, self.norm(x)).astype(jnp.float32)
+
+    def forward(self, input_ids, absorbed=False):
+        """Uncached logits [B, S, V] (float32)."""
+        ids = input_ids._data if isinstance(input_ids, Tensor) \
+            else input_ids
+        x = self.embed._data[ids]
+        for blk in self.blocks:
+            x = blk(x, absorbed)
+        return Tensor(self._head(x))
+
+    # -- step programs -------------------------------------------------
+    def _fused_decode_tick_slots(self, tok, pools, tables, pos, temp,
+                                 top_k, top_p, seed_lo, seed_hi, ctr,
+                                 eos, rem):
+        """``GPTModel._fused_decode_tick_slots`` over latent pools:
+        one token a slot through every block, sampling and the stop
+        condition on the device, the same outputs, and the counter
+        vector last.  Only lanes with budget left (``rem > 0``) are
+        routed to experts: a parked lane's row computes garbage through
+        attention and the shared expert, as it does for GPT, but hits
+        no expert's weights."""
+        import jax.numpy as jnp
+        live = rem > 0
+        x = self.embed._data[tok[:, 0]][:, None, :]
+        new_pools, stats = [], []
+        for j, blk in enumerate(self.blocks):
+            x, pool, st = blk.decode_slots_paged(x, pools[j], tables,
+                                                 pos, live)
+            new_pools.append(pool)
+            if st is not None:
+                stats.append(st)
+        last = self._head(x)[:, -1, :]
+        L = tables.shape[1] * pools[0].shape[1]
+        keys = slot_sample_keys(seed_lo, seed_hi, ctr)
+        sampled = sample_lanes(last, temp, top_k, top_p, keys)
+        ids = jnp.where(live, sampled, tok[:, 0])
+        hit_eos = live & (eos >= 0) & (ids == eos)
+        new_rem = jnp.where(live, jnp.where(hit_eos, 0, rem - 1), rem)
+        done = jnp.packbits((new_rem <= 0).astype(jnp.uint8))
+        new_pos = jnp.where(live, jnp.minimum(pos + 1, L - 1), pos)
+        new_ctr = jnp.where(live, ctr + 1, ctr)
+        return (ids, done, ids[:, None], new_pos, new_ctr, new_rem,
+                new_pools, [], self._counter_vector(stats))
+
+    def _chunk_prefill_tick_paged(self, toks, pools, table, pos,
+                                  true_len, scratch):
+        """C prompt tokens of one slot through every block; the head
+        runs on the last REAL position only.  Returns (last logits
+        [1, V], pools, [], counters)."""
+        import jax
+        import jax.numpy as jnp
+        pos = jnp.asarray(pos, jnp.int32)
+        C = toks.shape[1]
+        live = jnp.arange(C) < true_len
+        x = self.embed._data[toks]
+        new_pools, stats = [], []
+        for j, blk in enumerate(self.blocks):
+            x, pool, st = blk.prefill_chunk_paged(
+                x, pools[j], table, pos, true_len, scratch, live)
+            new_pools.append(pool)
+            if st is not None:
+                stats.append(st)
+        last_h = jax.lax.dynamic_slice(
+            x, (0, true_len - 1, 0), (1, 1, x.shape[-1]))
+        return (self._head(last_h)[:, -1, :], new_pools, [],
+                self._counter_vector(stats))
+
+    def _program(self, kind, cache_key, params, pnames, body,
+                 donate=(2, 3)):
+        """Build once a ``cache_key`` the jitted ``body`` run with the
+        traced parameters and buffers swapped in."""
+        from ..core import autograd
+        from ..jit import _swapped
+        cache = self.__dict__.setdefault("_program_cache", {})
+        if (kind, cache_key) in cache:
+            return cache[kind, cache_key]
+        mbuffers = dict(self.named_buffers())
+        bnames = sorted(mbuffers)
+
+        def pure(p_list, b_list, *args):
+            with _swapped(params, dict(zip(pnames, p_list))), \
+                    _swapped(mbuffers, dict(zip(bnames, b_list))):
+                with autograd.no_grad():
+                    return body(*args)
+
+        fn = _jit_named(kind, pure, donate_argnums=donate)
+        if len(cache) >= 8:
+            cache.pop(next(iter(cache)))
+        cache[kind, cache_key] = (
+            self._compile_probe(kind, cache_key, fn), bnames, mbuffers)
+        return cache[kind, cache_key]
+
+    def _compiled_fused_decode_fn(self, pnames, params, cache_key,
+                                  paged=False):
+        """(p_list, b_list, pools, [], block_tables, tok, pos, temp,
+        top_k, top_p, seed_lo, seed_hi, ctr, eos, rem) -> (ids, done,
+        new_tok, new_pos, new_ctr, new_rem, pools, [], counters).
+        Pools donated."""
+        if not paged:
+            raise NotImplementedError(
+                "the latent cache is paged: no contiguous decode")
+
+        def body(pools, _v, tables, tok, pos, *lanes):
+            return self._fused_decode_tick_slots(tok, pools, tables,
+                                                 pos, *lanes)
+        return self._program("fused_decode", cache_key, params, pnames,
+                             body)
+
+    def _compiled_paged_chunk_prefill_fn(self, pnames, params,
+                                         cache_key):
+        """(p_list, b_list, pools, [], ids [1, C], block_table, pos,
+        true_len, scratch) -> (last logits [1, V], pools, [],
+        counters).  Pools donated."""
+        def body(pools, _v, ids, table, pos, true_len, scratch):
+            return self._chunk_prefill_tick_paged(
+                ids, pools, table, pos, true_len, scratch)
+        return self._program("paged_chunk_prefill", cache_key, params,
+                             pnames, body)
+
+    # -- the serving seam ----------------------------------------------
+    def serving_spec(self):
+        from ..distributed.moe import grouped_matmul_impl
+        cfg, attn0 = self.config, self.blocks[0].attn
+        dtype = getattr(attn0.kv_a_proj, "compute_dtype", None) \
+            or attn0.kv_a_proj.weight._data.dtype
+        latent = "latent rows [r + d_rope] have no head axis: "
+        return ServingSpec(
+            kv=KVRowSpec(len(self.blocks), dtype,
+                         (("latent", (attn0.row,)),)),
+            max_positions=cfg["max_position_embeddings"],
+            vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
+            counters=MOE_COUNTERS,
+            kernels={"moe.experts": grouped_matmul_impl()},
+            unsupported={
+                "contiguous": "a contiguous [slots, L] latent buffer "
+                              "and its decode / prefill programs",
+                "unchunked_prefill": "the per-length paged prefill "
+                                     "program over latent rows",
+                "host_sampling": "the unfused per-step decode program "
+                                 "that returns logits",
+                "ragged": "a ragged paged-attention kernel over latent "
+                          "rows (ops/ragged_paged_attn.py takes "
+                          "[H, hd] K and V)",
+                "spec": "the verify-window program in the absorbed "
+                        "form",
+                "kv_int8": latent + "quant.py's QuantKV scales are "
+                           "per head",
+                "mp": latent + "MLA heads over 'mp' and an expert axis "
+                      "in SERVING_SPECS are not written",
+                "lora": "LoRA banks fold into GPTAttention.out_proj; "
+                        "o_proj here has no lane-gathered form",
+                "offload": "HostBlockStore entries are (layers, 2, bs, "
+                           "H, hd): no latent form",
+                "migration": "the migration wire's (layers, K|V, "
+                             "blocks, bs, H, hd) payload: no latent "
+                             "form",
+            })
+
+    def serving_linear_stacks(self):
+        return list(self.blocks)

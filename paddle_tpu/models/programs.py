@@ -1,0 +1,364 @@
+"""What a served model offers ``serving.Engine`` — the seam between
+``models/`` and ``serving/``.
+
+The engine owns slots, blocks and ticks.  A model offers:
+
+``serving_spec()``   a ``ServingSpec``: what it keeps per cached
+                     position and layer (``KVRowSpec``), its
+                     longest sequence, its vocabulary, the integer
+                     counters its step programs return beside the
+                     sampled tokens, and which engine options it
+                     cannot honour yet (each with the missing piece,
+                     so the engine refuses them by name at
+                     construction);
+``serving_program(kind, pnames, params, cache_key, *shape, **opts)``
+                     the jitted step program of that ``kind`` over the
+                     engine's pools (``"fused_decode"``,
+                     ``"paged_chunk_prefill"``, ...), built once a
+                     ``cache_key`` and wrapped by ``_compile_probe``;
+``serving_linear_stacks()``   the layers whose ``nn.Linear`` children
+                     weight-only int8 serving relayouts.
+
+``ServedModel`` is the mixin both model files inherit: the compile
+listeners, the probe, and the default ``serving_program`` that finds
+the builder ``_compiled_<kind>_fn`` on the model.
+"""
+from __future__ import annotations
+
+import functools
+import threading
+import time
+
+
+def _jit_named(kind, pure, **jit_kwargs):
+    """``jax.jit(pure)`` under the name of the program's
+    ``_compile_probe`` kind, so that a device trace's ``XLA Modules``
+    line reads ``jit_gpt_fused_decode(...)`` where it read
+    ``jit_pure(...)`` for every program alike.  (The module name is
+    part of the persistent compile cache's key.)"""
+    import jax
+    pure.__name__ = pure.__qualname__ = "gpt_" + kind
+    return jax.jit(pure, **jit_kwargs)
+
+
+def _scoped(name):
+    """Run the decorated method under ``jax.named_scope(name)``: the
+    scope shows in the op metadata of a device trace, so a program's
+    time splits into attention / mlp / lm_head / sampling."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapped(*args, **kwargs):
+            import jax
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return wrapped
+    return deco
+
+
+def filter_logits_lanes(last, temperature, top_k, top_p):
+    """PER-LANE sampling filters on f32 logits [B, V]: temperature
+    / top_k / top_p are [B] arrays — one independent request per
+    batch row (the serving slot pool), every parameter traced, so
+    ONE compiled program serves any per-slot mix.  Same filter
+    sequence and masking values as ``GPTModel._filter_logits`` (temperature
+    -> top-k -> top-p over the already-masked row), just with the
+    scalars lifted to lanes; ``top_k == 0`` / ``top_p == 1``
+    disable their filter lane-wise, and a ``temperature == 0``
+    greedy-sentinel lane passes through at temperature 1 (its
+    filtered row is discarded — ``sample_lanes`` argmaxes the raw
+    logits instead)."""
+    import jax
+    import jax.numpy as jnp
+    V = last.shape[-1]
+    t_eff = jnp.where(temperature > 0, temperature, 1.0)[:, None]
+    x = last / t_eff
+    srt = jnp.sort(x, axis=-1)[:, ::-1]
+    k_eff = jnp.clip(top_k, 1, V).astype(jnp.int32)
+    kth = jnp.take_along_axis(srt, (k_eff - 1)[:, None], axis=-1)
+    x = jnp.where((top_k > 0)[:, None] & (x < kth), -1e9, x)
+    p_eff = jnp.maximum(top_p, 1e-9)[:, None]
+    srt2 = jnp.sort(x, axis=-1)[:, ::-1]
+    probs = jax.nn.softmax(srt2, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    keep = (cum - probs) < p_eff
+    cutoff = jnp.min(jnp.where(keep, srt2, jnp.inf), axis=-1,
+                     keepdims=True)
+    return jnp.where((top_p < 1.0)[:, None] & (x < cutoff), -1e9, x)
+
+
+def slot_sample_keys(seed_lo, seed_hi, ctr):
+    """Per-slot sampling keys for the fused dispatches: fold the
+    emitted-token counter into each request's seed-derived key
+    (core/rng.request_key over the uint32 seed words), so token i
+    of a request always draws from fold(request_key, i) — the same
+    stream whether it is emitted by a one-token tick, a verify-
+    window lane, or the eager first-token pick after prefill.
+    seed_lo/seed_hi uint32 [B], ctr int32 [B] -> keys [B]."""
+    import jax
+    from ..core import rng as rng_mod
+    return jax.vmap(lambda lo, hi, c: jax.random.fold_in(
+        rng_mod.request_key(lo, hi), c))(seed_lo, seed_hi, ctr)
+
+
+@_scoped("sampling")
+def sample_lanes(last, temperature, top_k, top_p, keys):
+    """One token per slot row from [B, V] logits with PER-SLOT
+    sampling params and keys: lanes with ``temperature == 0`` (the
+    greedy sentinel) take the raw argmax — bit-identical to the
+    host path's ``np.argmax`` on the same logits — and sampling
+    lanes draw categorically from the lane-filtered distribution.
+    The filter/draw pipeline (two [B, V] sorts + categorical) sits
+    behind a runtime ``lax.cond``: an all-greedy batch — the
+    serving default — skips it entirely instead of computing both
+    sides of a where, while staying ONE compiled program.
+    Returns int32 [B]."""
+    import jax
+    import jax.numpy as jnp
+    last = last.astype(jnp.float32)
+    greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
+
+    def draw(_):
+        filt = filter_logits_lanes(last, temperature, top_k, top_p)
+        sampled = jax.vmap(jax.random.categorical)(keys, filt)
+        return jnp.where(temperature > 0, sampled,
+                         greedy).astype(jnp.int32)
+
+    return jax.lax.cond(jnp.any(temperature > 0), draw,
+                        lambda _: greedy, None)
+
+
+class KVRowSpec:
+    """What a model keeps for one cached position in one layer — the
+    one place block bytes, pool shapes and the geometry that
+    ``/healthz``, ``/debug/requests``, the migration wire and the host
+    tier report are computed from.
+
+    ``rows``: ``((name, shape), ...)``, one pool a layer for each: two
+    of ``[H, hd]`` (``"k"``, ``"v"``) for full multi-head attention,
+    one of ``[r + d_rope]`` (``"latent"``) for latent attention, whose
+    row is the compressed KV and the shared rotary key and which keeps
+    no V.  ``dtype`` is the dtype the model computes attention in (the
+    pools hold it unless the engine quantizes them).  ``heads_axis``
+    says the first axis of every row is attention heads — what a
+    tensor-parallel mesh shards and what an int8 pool hangs its scales
+    on; a row without it can do neither.
+
+    A pool stores a row's last axis padded up to whole tiles of
+    ``LANES`` when it is wider than one tile and no multiple of it
+    (576 -> 640).  A TPU keeps the minor axis in tiles of 128 lanes
+    whatever the program says, and given a wider minor axis that is no
+    multiple of 128 its runtime picks a layout with the BLOCK axis
+    minor instead, which every step then transposes there and back (a
+    264 MB copy a layer: PERF.md, PR 28); the padding costs the same
+    bytes and keeps the pool row-major and updated in place.  Block
+    and position bytes count what is stored, padding included: they
+    are what a budget buys and what the gauges report
+    (``geometry()["rows"]`` has the row as the model wrote it)."""
+
+    LANES = 128
+
+    def __init__(self, n_layers, dtype, rows, heads_axis=False):
+        import numpy as np
+        self.n_layers = int(n_layers)
+        self.dtype = dtype
+        self.rows = tuple((str(n), tuple(int(d) for d in shape))
+                          for n, shape in rows)
+        self.heads_axis = bool(heads_axis)
+        if not self.rows:
+            raise ValueError("a KVRowSpec keeps at least one row")
+        self._row_elems = sum(int(np.prod(self._stored(shape)))
+                              for _, shape in self.rows)
+
+    @classmethod
+    def heads(cls, n_layers, num_heads, head_dim, dtype):
+        """K and V rows of ``[num_heads, head_dim]``."""
+        shape = (int(num_heads), int(head_dim))
+        return cls(n_layers, dtype, (("k", shape), ("v", shape)),
+                   heads_axis=True)
+
+    @classmethod
+    def _stored(cls, shape):
+        """``shape`` as a pool stores it (the class docstring)."""
+        minor = shape[-1]
+        if minor > cls.LANES and minor % cls.LANES:
+            minor += -minor % cls.LANES
+        return shape[:-1] + (minor,)
+
+    @property
+    def num_heads(self):
+        return self.rows[0][1][0] if self.heads_axis else None
+
+    @property
+    def head_dim(self):
+        return self.rows[0][1][1] if self.heads_axis else None
+
+    def pool_shapes(self, leading):
+        """Shape of each of a layer's pools behind the ``leading``
+        axes (``(num_blocks, block_size)`` paged, ``(num_slots,
+        max_seq_len)`` contiguous)."""
+        return [tuple(leading) + self._stored(shape)
+                for _, shape in self.rows]
+
+    def position_bytes(self, dtype=None):
+        """Bytes one cached position takes over all layers, as
+        stored."""
+        import numpy as np
+        return (self.n_layers * self._row_elems
+                * np.dtype(dtype or self.dtype).itemsize)
+
+    def block_bytes(self, block_size, mp=1, dtype=None,
+                    scale_dtype=None):
+        """PER-SHARD bytes of ONE logical block across every layer and
+        pool (see ``per_shard_block_bytes``).  ``dtype`` is the STORED
+        row dtype when it differs from the compute dtype (int8 pools);
+        ``scale_dtype`` adds a per-block per-head scale for each
+        pool."""
+        import numpy as np
+        mp = int(mp)
+        if mp != 1 or scale_dtype is not None:
+            if not self.heads_axis:
+                raise ValueError(
+                    f"rows {self.rows} have no head axis to shard "
+                    "over mp or to scale per head")
+            if mp < 1 or self.num_heads % mp:
+                raise ValueError(
+                    f"num_heads ({self.num_heads}) must divide by mp "
+                    f"({mp})")
+        total = (int(block_size) * self.position_bytes(dtype)) // mp
+        if scale_dtype is not None:
+            total += (self.n_layers * len(self.rows)
+                      * (self.num_heads // mp)
+                      * np.dtype(scale_dtype).itemsize)
+        return total
+
+    def geometry(self, block_size):
+        """The block geometry two peers must agree on before blocks
+        move between them, and what the debug surfaces report."""
+        geo = {"block_size": int(block_size)}
+        if self.heads_axis:
+            geo.update(num_heads=self.num_heads, head_dim=self.head_dim)
+        else:
+            geo["rows"] = [[n, list(shape)] for n, shape in self.rows]
+        geo["n_layers"] = self.n_layers
+        return geo
+
+
+class ServingSpec:
+    """A model's answer to the engine's questions.
+
+    ``kv``             ``KVRowSpec``: pools a layer and
+                       the row each keeps for a position
+    ``max_positions``  longest sequence the model can place
+    ``vocab_size``, ``hidden_size``
+    ``tensor_parallel``  the model is the einsum form whose parameters
+                       carry ``'mp'`` partition specs
+    ``counters``       ``((counter, span_arg | None), ...)``: names of
+                       the int32 vector the decode and chunk programs
+                       return last; the engine adds each into
+                       ``serving.<counter>`` and puts ``span_arg`` on
+                       the ``dev.*`` span
+    ``unsupported``    ``{feature: the missing piece}`` for the engine
+                       features this model cannot honour yet
+    ``kernels``        ``{scope: implementation}`` where the model
+                       picks one by the backend it finds; ``/healthz``
+                       reports it, so that a replica serving on a
+                       fallback says so
+    """
+
+    def __init__(self, kv, max_positions, vocab_size, hidden_size,
+                 tensor_parallel=False, counters=(), unsupported=None,
+                 kernels=None):
+        self.kv = kv
+        self.max_positions = int(max_positions)
+        self.vocab_size = int(vocab_size)
+        self.hidden_size = int(hidden_size)
+        self.tensor_parallel = bool(tensor_parallel)
+        self.counters = tuple(counters)
+        self.unsupported = dict(unsupported or {})
+        self.kernels = dict(kernels or {})
+
+
+class ServedModel:
+    """Mixin of a model the engine can serve (see the module's
+    docstring)."""
+
+    def serving_spec(self):
+        raise NotImplementedError
+
+    def serving_linear_stacks(self):
+        raise NotImplementedError
+
+    def serving_program(self, kind, *args, **kwargs):
+        """The jitted program of ``kind``: ``(fn, bnames, mbuffers)``
+        from the builder ``_compiled_<kind>_fn``."""
+        build = getattr(self, f"_compiled_{kind}_fn", None)
+        if build is None:
+            raise NotImplementedError(
+                f"{type(self).__name__} has no {kind!r} step program")
+        return build(*args, **kwargs)
+
+    def add_compile_listener(self, cb):
+        """Register ``cb(kind, cache_key, wall_s)`` to fire right after
+        the FIRST call of each freshly built jitted program (the call
+        where jax traces and XLA compiles it).  Production-side
+        compile-thrash detector: the serving engine turns every event
+        into a trace span plus the ``serving.compiles_total`` counter,
+        so a traffic shape that defeats the program caches is visible
+        in /metrics instead of only as mystery latency.  A callback
+        that returns False (or raises) is deregistered — the engine
+        registers a weakref'd method so a collected engine drops off
+        this list by itself."""
+        listeners = getattr(self, "_compile_listeners", None)
+        if listeners is None:
+            listeners = self._compile_listeners = []
+        listeners.append(cb)
+        return cb
+
+    def remove_compile_listener(self, cb):
+        try:
+            getattr(self, "_compile_listeners", []).remove(cb)
+        except ValueError:
+            pass
+
+    def _compile_probe(self, kind, cache_key, fn):
+        """Wrap a freshly jitted dispatch so its first call is timed
+        and announced to ``add_compile_listener`` subscribers; later
+        calls pay one truthiness check.  The wall time covers trace +
+        XLA compile + the first execution — on a cache-warm process the
+        event simply never fires, which is exactly the signal: events
+        appearing in steady state mean the program cache is thrashing."""
+        done = []
+        first_lock = threading.Lock()
+        model = self
+
+        def probed(*args):
+            if done:
+                return fn(*args)
+            t0 = time.perf_counter()
+            out = fn(*args)
+            wall = time.perf_counter() - t0
+            with first_lock:
+                if done:
+                    # two threads raced the same cold program (sibling
+                    # engines over one model): exactly ONE fires the
+                    # event — the loser piggybacked on jax's compile
+                    # lock and must not double-count the compile
+                    return out
+                done.append(True)
+            listeners = getattr(model, "_compile_listeners", None)
+            if listeners:
+                for cb in list(listeners):
+                    try:
+                        alive = cb(kind, cache_key, wall)
+                    except Exception:
+                        alive = False
+                    if alive is False:
+                        try:
+                            listeners.remove(cb)
+                        except ValueError:
+                            pass
+            return out
+
+        probed.kind = kind  # the engine labels its dev.* spans with it
+        probed.__name__ = "gpt_" + kind
+        return probed

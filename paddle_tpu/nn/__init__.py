@@ -1,6 +1,6 @@
 """paddle.nn parity surface."""
 from .layer.base import (  # noqa: F401
-    Layer, LayerList, Sequential, ParameterList,
+    Layer, LayerList, Sequential, ParameterList, LazyGuard,
 )
 from .layer.common import (  # noqa: F401
     Identity, Linear, Embedding, Dropout, Dropout2D, Dropout3D,

@@ -15,11 +15,39 @@ from collections import OrderedDict
 from typing import Callable, Iterator
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from ...core.tensor import Tensor, Parameter
 from ...core import dtype as dtypes
 from .. import initializer as I
+
+
+class LazyGuard:
+    """``paddle.LazyGuard``: parameters created inside the guard are
+    DECLARED, with their shape and dtype, and get no initial value —
+    ``p._data`` is a ``jax.ShapeDtypeStruct`` until ``p.set_value(...)``
+    (a checkpoint or seeded weights) or ``p.initialize()`` (the
+    initializer the layer asked for) gives one.  A model of billions
+    of parameters that is loaded right after construction is built so
+    without the float32 initial values it would throw away.
+
+        with nn.LazyGuard():
+            net = Net()             # no storage
+        net.to(dtype="bfloat16")    # still none
+        p.set_value(array)          # now
+
+    ``named_parameters`` / ``state_dict`` work on a declared model
+    (names, shapes, dtypes); computing with it does not."""
+
+    _depth = 0
+
+    def __enter__(self):
+        LazyGuard._depth += 1
+        return self
+
+    def __exit__(self, *exc):
+        LazyGuard._depth -= 1
 
 
 class Layer:
@@ -51,10 +79,15 @@ class Layer:
             init = default_initializer
         if init is None:
             init = I.Constant(0.0) if is_bias else I.XavierNormal()
-        data = init(shape, dtype)
+        lazy = LazyGuard._depth > 0
+        data = (jax.ShapeDtypeStruct(tuple(int(n) for n in shape),
+                                     dtypes.to_jax(dtype))
+                if lazy else init(shape, dtype))
         p = Parameter(data, dtype=dtype,
                       name=(attr.name if attr else None),
                       trainable=(attr.trainable if attr else True))
+        if lazy:
+            p._lazy_init = (init, shape, dtype)
         if attr and attr.learning_rate != 1.0:
             p.optimize_attr = {"learning_rate": attr.learning_rate}
         if attr is not None and attr.regularizer is not None:
@@ -258,7 +291,12 @@ class Layer:
         if dtype is not None:
             jdt = dtypes.to_jax(dtype)
             for p in self.parameters():
-                if jnp.issubdtype(p._data.dtype, jnp.floating):
+                if not jnp.issubdtype(p._data.dtype, jnp.floating):
+                    continue
+                if isinstance(p._data, jax.ShapeDtypeStruct):
+                    # declared under LazyGuard: nothing to cast
+                    p._data = jax.ShapeDtypeStruct(p._data.shape, jdt)
+                else:
                     p._data = p._data.astype(jdt)
             for b in self.buffers():
                 if b is not None and jnp.issubdtype(b._data.dtype,

@@ -44,8 +44,7 @@ import numpy as np
 
 from .. import monitor
 from .kvcache import (BlockPool, KVDtypeMismatch, PrefixCache,
-                      export_blocks, import_blocks,
-                      per_shard_block_bytes)
+                      export_blocks, import_blocks)
 from .lora import AdapterRegistry, LoRAAdapter, UnknownAdapter
 from .request import (MAX_SEED, DeadlineShed, QueueFull, RateLimited,
                       Request, RequestQueue, TenantPolicy, TokenBucket)
@@ -150,6 +149,11 @@ def _watch_device(q, tracer, busy_ms):
         done = time.perf_counter()
         ts = max(prev_done, t_dispatch)
         prev_done = done
+        stats = args.pop("_stats", None)
+        if stats is not None:
+            for arg, v in zip(stats[1], np.asarray(stats[0])):
+                if arg:
+                    args[arg] = int(v)
         tracer.emit(name, ts, done - ts, cat="device", args=args)
         busy_ms.inc((done - ts) * 1e3)
 
@@ -230,12 +234,21 @@ class _InflightTick:
 
 
 class Engine:
-    """In-process continuous-batching engine for a GPT-family model.
+    """In-process continuous-batching engine for a decoder-only LM.
+
+    The engine owns slots, blocks and ticks, and reaches the model only
+    through the seam of ``models/programs.py``: ``serving_spec()``
+    (the row it keeps per cached position and layer, its longest
+    sequence, its vocabulary, the features it cannot honour yet — each
+    refused here by name) and ``serving_program(kind, ...)`` (its
+    jitted step programs).
 
     Parameters
     ----------
-    model : GPTModel (eval'd; ``scan_layers`` models serve through
-        their auto-synced unrolled decode twin, like ``generate``).
+    model : a ``ServedModel`` — ``GPTModel`` (every option below) or
+        ``MLAMoEModel`` (the default paged, chunked, device-sampling
+        path); eval'd; ``scan_layers`` models serve through their
+        auto-synced unrolled decode twin, like ``generate``.
     num_slots : fixed batch-slot pool size (the compiled tick's B).
     max_seq_len : per-slot KV cache length L (prompt + generated must
         fit); defaults to the model's max_position.
@@ -548,6 +561,10 @@ class Engine:
             model = model._sync_decode_twin()
         model.eval()
         self.model = model
+        # the seam (models/programs.py): what the model keeps per
+        # cached position and layer, how far it can place a token, and
+        # which of this engine's features it cannot honour yet
+        sspec = model.serving_spec()
         # -- quantized serving (serving/quant.py) ----------------------
         # weight relayout runs HERE, before the KV-dtype resolution and
         # the parameter/buffer snapshots below, so the int8 codes +
@@ -560,7 +577,7 @@ class Engine:
                 raise ValueError(
                     f"weight_dtype must be 'int8' (or None to serve "
                     f"the checkpoint's own dtype), got {weight_dtype!r}")
-            if getattr(model.blocks[0].attn, "use_mp", False):
+            if sspec.tensor_parallel:
                 raise ValueError(
                     "weight_dtype='int8' cannot relayout the tensor-"
                     "parallel einsum form (use_mp=True): its fused "
@@ -569,24 +586,33 @@ class Engine:
                     "to_tensor_parallel(), or serve it dense")
             from .quant import relayout_weights_int8
             relayout_weights_int8(model)
+            sspec = model.serving_spec()  # the projections' compute
+            #   dtype is the quantized layers' now
         self._kv_quant = kv_dtype is not None
         if self._kv_quant and str(kv_dtype) != "int8":
             raise ValueError(
                 f"kv_dtype must be 'int8' (or None for the compute "
                 f"dtype), got {kv_dtype!r}")
-        max_position = \
-            model.embeddings.position_embeddings.weight.shape[0]
+        max_position = sspec.max_positions
         self.max_seq_len = int(max_seq_len or max_position)
         if self.max_seq_len > max_position:
             raise ValueError(
                 f"max_seq_len {self.max_seq_len} exceeds the model's "
                 f"position table ({max_position})")
         self.num_slots = int(num_slots)
-        try:  # the HTTP edge validates token ids against this
-            self.vocab_size = int(
-                model.embeddings.word_embeddings.weight.shape[0])
-        except AttributeError:
-            self.vocab_size = None
+        # the HTTP edge validates token ids against this
+        self.vocab_size = sspec.vocab_size
+        self._refuse_unsupported(sspec, dict(
+            contiguous=kv_block_size is None,
+            unchunked_prefill=prefill_chunk is None,
+            host_sampling=sample_mode == "host",
+            ragged=(attn_impl or getattr(model, "attn_impl", "xla"))
+            != "xla",
+            spec=spec_k is not None or proposer is not None,
+            kv_int8=kv_dtype is not None,
+            mp=mesh is not None,
+            lora=adapters is not None or max_adapters is not None,
+            offload=kv_host_mb is not None))
         # -- overload protection: tenants, priorities, shedding ---------
         self._tenant_policies = {}
         self._buckets = {}
@@ -632,16 +658,12 @@ class Engine:
         #   tick entry, cleared at exit
 
         import jax.numpy as jnp
-        attn0 = model.blocks[0].attn
-        self._nh, self._hd = attn0.num_heads, attn0.head_dim
-        if attn0.use_mp:
-            kv_dtype = attn0.qkv_weight._data.dtype
-        else:
-            # compute_dtype first: a weight-only-int8 projection's
-            # .weight property would materialize the dequantized matrix
-            kv_dtype = getattr(attn0.qkv_proj, "compute_dtype", None) \
-                or attn0.qkv_proj.weight._data.dtype
-        self._kv_dtype = kv_dtype
+        # the row spec: pools, block bytes, wire and debug geometry
+        # all come from it (models/programs.py KVRowSpec)
+        self._kvspec = sspec.kv
+        self._serving_spec = sspec
+        self._nh, self._hd = sspec.kv.num_heads, sspec.kv.head_dim
+        self._kv_dtype = kv_dtype = sspec.kv.dtype
         # the dtype LABEL for compiled-program cache keys, /healthz,
         # and the migration wire: a quantized pool keeps _kv_dtype as
         # its f32 COMPUTE dtype (attention math, scratch views) but
@@ -720,7 +742,7 @@ class Engine:
             self.mesh_axes = ({k: int(v) for k, v in mesh.shape.items()
                                if int(v) > 1} or {"mp": 1})
             if self.mp > 1:
-                if not attn0.use_mp:
+                if not sspec.tensor_parallel:
                     raise ValueError(
                         "mesh with mp > 1 requires the tensor-parallel"
                         " model form: build with GPTModel(use_mp=True)"
@@ -899,18 +921,16 @@ class Engine:
             # shard stores only its H/mp heads' K/V rows, so a fixed
             # per-chip HBM budget (kv_budget_mb) buys mp x the blocks
             # — sharding the model scales KV capacity, not just
-            # weights (kvcache.per_shard_block_bytes)
+            # weights (KVRowSpec.block_bytes)
             # quantized pools store int8 codes plus the parallel f32
             # scale pool; both count against the budget so capacity
             # accounting adds up (code + scale components exposed as
             # serving.kv_block_bytes / serving.kv_scale_bytes)
             store_dtype = "int8" if self._kv_quant else self._kv_dtype
-            self._kv_code_bytes_per_shard = per_shard_block_bytes(
-                bsz, self._nh, self._hd, store_dtype,
-                len(model.blocks), self.mp)
-            self._kv_block_bytes_per_shard = per_shard_block_bytes(
-                bsz, self._nh, self._hd, store_dtype,
-                len(model.blocks), self.mp,
+            self._kv_code_bytes_per_shard = self._kvspec.block_bytes(
+                bsz, self.mp, dtype=store_dtype)
+            self._kv_block_bytes_per_shard = self._kvspec.block_bytes(
+                bsz, self.mp, dtype=store_dtype,
                 scale_dtype="float32" if self._kv_quant else None)
             self._kv_scale_bytes_per_shard = (
                 self._kv_block_bytes_per_shard
@@ -975,7 +995,7 @@ class Engine:
             from .offload import HostBlockStore
             self.host_store = HostBlockStore(
                 kv_host_mb, self._bs, self._nh, self._hd,
-                len(list(model.blocks)), self._kv_dtype_str)
+                self._kvspec.n_layers, self._kv_dtype_str)
         # -- ragged paged attention (attn_impl="ragged") ----------------
         if attn_impl is None:
             attn_impl = getattr(model, "attn_impl", "xla")
@@ -1052,7 +1072,7 @@ class Engine:
                     "adapters require sample_mode='device': the host "
                     "sampling paths dispatch per-layer programs that "
                     "do not thread the per-slot LoRA lanes")
-            if self.mesh is not None or attn0.use_mp:
+            if self.mesh is not None or sspec.tensor_parallel:
                 raise ValueError(
                     "adapters cannot combine with tensor-parallel "
                     "serving (mesh=... / use_mp models): the LoRA "
@@ -1071,10 +1091,8 @@ class Engine:
                     f"{len(init)} adapters passed at construction")
             r_max = (int(max_lora_rank) if max_lora_rank is not None
                      else max([a.rank for a in init.values()] or [8]))
-            hidden = int(
-                model.embeddings.word_embeddings.weight.shape[1])
             self.adapters = AdapterRegistry(
-                len(list(model.blocks)), hidden, n_ad, r_max)
+                self._kvspec.n_layers, sspec.hidden_size, n_ad, r_max)
             for _nm in sorted(init):
                 self.adapters.load(_nm, init[_nm])
         # -- tracing / flight recorder ---------------------------------
@@ -1151,6 +1169,21 @@ class Engine:
             self._m_kv_total.set(self._kv_managed)
             self._m_kv_block_bytes.set(self._kv_code_bytes_per_shard)
             self._m_kv_scale_bytes.set(self._kv_scale_bytes_per_shard)
+        self._m_kv_row_bytes = reg.gauge(
+            "serving.kv_row_bytes", "bytes one cached position takes "
+            "over all layers as the pools store it: their dtype, and "
+            "a row padded to whole 128-lane tiles (K and V rows of "
+            "every head, or a latent row)")
+        self._m_kv_row_bytes.set(self._kvspec.position_bytes(
+            "int8" if self._kv_quant else None))
+        # counters the model's step programs feed: an int32 vector
+        # each returns beside its outputs (ServingSpec.counters),
+        # added up when the tick's ids are downloaded
+        self._m_program = [
+            reg.counter("serving." + name, "summed over the decode "
+                        "and chunk programs' runs (the model's "
+                        "ServingSpec.counters)")
+            for name, _ in sspec.counters]
         self._m_prefix_hits = reg.counter(
             "serving.prefix_hits", "admissions that adopted a cached "
             "prompt prefix")
@@ -1420,6 +1453,35 @@ class Engine:
                 zeros, out_shardings=out_sh)
         return fn()
 
+    def kv_geometry(self):
+        """The block geometry the row spec gives (what ``/healthz``,
+        ``/debug/requests`` and the migration wire report); None for
+        the contiguous layout."""
+        return self._kvspec.geometry(self._bs) if self._paged else None
+
+    def _refuse_unsupported(self, sspec, used):
+        """Raise, naming the option and the missing piece, for every
+        engine feature in use that the model's ``ServingSpec`` lists
+        as unsupported; nothing is silently ignored."""
+        option = {
+            "contiguous": "kv_block_size=None (contiguous KV)",
+            "unchunked_prefill": "prefill_chunk=None",
+            "host_sampling": "sample_mode='host'",
+            "ragged": "attn_impl='ragged' / 'ragged_gather'",
+            "spec": "spec_k / proposer (speculative verify)",
+            "kv_int8": "kv_dtype='int8'",
+            "mp": "mesh=... (mp / dp > 1)",
+            "lora": "adapters / max_adapters (LoRA banks)",
+            "offload": "kv_host_mb (host offload)",
+            "migration": "KV migration (migrate_out / import)",
+        }
+        for feature, on in used.items():
+            if on and feature in sspec.unsupported:
+                raise ValueError(
+                    f"{type(self.model).__name__} cannot be served "
+                    f"with {option[feature]} yet: it lacks "
+                    f"{sspec.unsupported[feature]}")
+
     def _slot_shard(self, i):
         """The dp mesh shard that owns batch slot ``i``: slots divide
         into ``dp`` contiguous ranges of ``num_slots/dp`` rows,
@@ -1443,8 +1505,7 @@ class Engine:
             # block some live request owns, and under shard_map a
             # slot can only address rows inside its OWN shard's range
             # (dp == 1: one scratch block, physical row 0, as before)
-            shape = (self._kv_managed + self.dp, self._bs, self._nh,
-                     self._hd)
+            leading = (self._kv_managed + self.dp, self._bs)
             self.block_pool = BlockPool(
                 self._kv_managed + self.dp, self._bs,
                 reserved_blocks=1, shards=self.dp,
@@ -1472,12 +1533,16 @@ class Engine:
                 self._slot_scratch[:, None], self._bps, axis=1).copy()
             self._slot_blocks = [[] for _ in range(self.num_slots)]
         else:
-            shape = (self.num_slots, self.max_seq_len, self._nh,
-                     self._hd)
-        self.k_pools = [self._alloc_pool(shape)
-                        for _ in self.model.blocks]
-        self.v_pools = [self._alloc_pool(shape)
-                        for _ in self.model.blocks]
+            leading = (self.num_slots, self.max_seq_len)
+        # one pool a layer for each row the model keeps: K and V for
+        # full attention; a latent cache keeps one row and no V, and
+        # its ``v_pools`` is the empty list every program passes
+        # through
+        shapes = self._kvspec.pool_shapes(leading)
+        layers = range(self._kvspec.n_layers)
+        self.k_pools = [self._alloc_pool(shapes[0]) for _ in layers]
+        self.v_pools = ([self._alloc_pool(shapes[1]) for _ in layers]
+                        if len(shapes) > 1 else [])
         # where this engine runs, read off the pools themselves (fixed
         # per config): /healthz and /debug/requests report it, so a
         # caller asserts the chip through the server's own surface
@@ -1513,6 +1578,8 @@ class Engine:
         self._aid = np.zeros(self.num_slots, np.int32)
         self._dev_state = None   # device handles of the step state
         self._state_dirty = True  # device copies stale vs the mirrors
+        self._stats_pending = []  # chunk programs' counter vectors,
+        #   not yet downloaded (they ride the next tick's download)
         self._ring = []  # dispatched-but-unconsumed ticks, oldest
         #   first (async_depth > 1); recovery and shutdown clear it —
         #   the dropped handles die with the rebuilt pools
@@ -2006,6 +2073,9 @@ class Engine:
     # ``migrate_wire`` is thrown by transports between the two.
 
     def _register_demand(self, demand):
+        if demand.kind in ("out", "in", "prefix_out", "prefix_in"):
+            self._refuse_unsupported(self._serving_spec,
+                                     {"migration": True})
         with self._mig_lock:
             self._migrate_demands.append(demand)
         self._wake.set()
@@ -2405,12 +2475,9 @@ class Engine:
                 blocks = self._slot_blocks[i][:n_full]
                 if n_full:
                     data = self._export_gather(blocks, req.id)
-                    kv = {"block_size": self._bs,
-                          "num_heads": self._nh,
-                          "head_dim": self._hd,
-                          "n_layers": len(self.k_pools),
-                          "dtype": self._kv_dtype_str,
-                          "n_blocks": n_full}
+                    kv = dict(self.kv_geometry(),
+                              dtype=self._kv_dtype_str,
+                              n_blocks=n_full)
                     if self._kv_quant:
                         # quantized export: codes + their per-block
                         # scales travel together
@@ -2493,8 +2560,7 @@ class Engine:
                 f"match this engine's {self._kv_dtype_str!r}: "
                 "adopting nothing (peers must serve the same "
                 "kv_dtype)")
-        want = {"block_size": self._bs, "num_heads": self._nh,
-                "head_dim": self._hd, "n_layers": len(self.k_pools)}
+        want = self.kv_geometry()
         got = {k: kv.get(k) for k in want}
         if got != want:
             raise ValueError(
@@ -2627,9 +2693,8 @@ class Engine:
         m_total = m + len(host_parts) * self._bs
         tier = ("mixed" if blocks and host_parts
                 else "host" if host_parts else "device")
-        kv = {"block_size": self._bs, "num_heads": self._nh,
-              "head_dim": self._hd, "n_layers": len(self.k_pools),
-              "dtype": self._kv_dtype_str, "n_blocks": n_blocks}
+        kv = dict(self.kv_geometry(), dtype=self._kv_dtype_str,
+                  n_blocks=n_blocks)
         if self._kv_quant:
             kv["data"], kv["scales"] = data, scales
         else:
@@ -2817,7 +2882,7 @@ class Engine:
 
     # -- tracing / flight recorder / debug surface ---------------------
     def _dev_note(self, program, handle, batch=0, n=0, req=None,
-                  role=None, t_dispatch=None):
+                  role=None, t_dispatch=None, stats=()):
         """Hand the device watcher one dispatch the engine thread just
         made.  ``program`` is the ``_compile_probe`` kind, ``handle``
         the program's smallest output (never a pool, never donated;
@@ -2843,6 +2908,11 @@ class Engine:
                 "batch": batch, "n": n}
         if req is not None:
             args["req"] = req
+        if stats:
+            # the program's counter vector: the watcher reads it once
+            # the program is done and names the span's arguments
+            args["_stats"] = (stats[0], [
+                a for _, a in self._serving_spec.counters])
         q.put(("dev." + role, t_dispatch, handle, args))
 
     def _start_watcher(self):
@@ -3008,6 +3078,9 @@ class Engine:
                 "kv_dtype": self._kv_dtype_str,
                 "kv_block_bytes": self._kv_code_bytes_per_shard,
                 "kv_scale_bytes": self._kv_scale_bytes_per_shard,
+                "kv_row_bytes": self._m_kv_row_bytes.value,
+                "kv_geometry": self.kv_geometry(),
+                "kernels": self._serving_spec.kernels,
                 "async_depth": self.async_depth,
                 "tracing": bool(self.tracer.enabled),
                 "preemption": self._preemption,
@@ -3387,8 +3460,8 @@ class Engine:
         n_ctx = len(ctx)
         s_tail = s - m
         n_tail = -(-s // self._bs) - n_ctx
-        pf, _, _ = self.model._compiled_paged_prefill_fn(
-            self._pnames, self._params,
+        pf, _, _ = self.model.serving_program(
+            "paged_prefill", self._pnames, self._params,
             self._lora_key(
                 (s_tail, n_ctx, n_tail, self._bs, self._kv_dtype_str,
                  tuple(self._pnames), self._bnames_all)),
@@ -3425,8 +3498,8 @@ class Engine:
         L = self.max_seq_len
         if self._prefill_buckets is not None:
             S = next(b for b in self._prefill_buckets if b >= s)
-            pf, _, _ = self.model._compiled_bucket_prefill_fn(
-                self._pnames, self._params,
+            pf, _, _ = self.model.serving_program(
+                "bucket_prefill", self._pnames, self._params,
                 self._lora_key(
                     (1, S, L, self._kv_dtype_str, tuple(self._pnames),
                      self._bnames_all)),
@@ -3437,8 +3510,8 @@ class Engine:
                                        ids, jnp.asarray(s, jnp.int32),
                                        *self._lora_args_slot(req))
         else:
-            pf, _, _ = self.model._compiled_prefill_fn(
-                self._pnames, self._params,
+            pf, _, _ = self.model.serving_program(
+                "prefill", self._pnames, self._params,
                 self._lora_key(
                     (1, s, L, self._kv_dtype_str, tuple(self._pnames),
                      self._bnames_all)),
@@ -3515,13 +3588,13 @@ class Engine:
                 "prefill.chunk", req=req.id, pos=p0, n=n,
                 layout="paged" if self._paged else "contiguous"):
             if self._paged:
-                fn, _, _ = self.model._compiled_paged_chunk_prefill_fn(
-                    self._pnames, self._params,
+                fn, _, _ = self.model.serving_program(
+                    "paged_chunk_prefill", self._pnames, self._params,
                     self._lora_key(
                         (C, self._kv_managed + self.dp, self._bs, self._bps,
                          self._kv_dtype_str, tuple(self._pnames),
                          self._bnames_all)))
-                last0, self.k_pools, self.v_pools = fn(
+                last0, self.k_pools, self.v_pools, *stats = fn(
                     self._p_list(), self._b_list(), self.k_pools,
                     self.v_pools, ids,
                     jnp.asarray(self._block_tables[i]),
@@ -3530,8 +3603,8 @@ class Engine:
                     jnp.asarray(int(self._slot_scratch[i]), jnp.int32),
                     *self._lora_args_slot(req))
             else:
-                fn, _, _ = self.model._compiled_chunk_prefill_fn(
-                    self._pnames, self._params,
+                fn, _, _ = self.model.serving_program(
+                    "chunk_prefill", self._pnames, self._params,
                     self._lora_key(
                         (C, self.num_slots, self.max_seq_len,
                          self._kv_dtype_str, tuple(self._pnames),
@@ -3544,7 +3617,11 @@ class Engine:
                     jnp.asarray(p0, jnp.int32),
                     jnp.asarray(n, jnp.int32),
                     *self._lora_args_slot(req))
-            self._dev_note(fn.kind, last0, n=n, req=req.id)
+                stats = []
+            # a chunk's counters wait for the next download (the
+            # device runs in order: they are ready by then)
+            self._stats_pending += stats
+            self._dev_note(fn.kind, last0, n=n, req=req.id, stats=stats)
         slot.prefilled = p0 + n
         slot.pos = slot.prefilled
         self._m_chunks.inc()
@@ -3781,8 +3858,8 @@ class Engine:
         with tr.span("spec.draft", batch=len(active), spec_k=W - 1):
             toks = self._draft_window(active)
         if self._spec_fn is None:
-            self._spec_fn, _, _ = self.model._compiled_spec_verify_fn(
-                self._pnames, self._params,
+            self._spec_fn, _, _ = self.model.serving_program(
+                "spec_verify", self._pnames, self._params,
                 ("paged" if self._paged else "slot", W, self.num_slots,
                  (self._kv_managed + self.dp, self._bs) if self._paged
                  else self.max_seq_len, self._kv_dtype_str,
@@ -3891,8 +3968,8 @@ class Engine:
         st = self._dev_state
         if self._fused_spec_fn is None:
             self._fused_spec_fn, _, _ = \
-                self.model._compiled_fused_spec_verify_fn(
-                    self._pnames, self._params,
+                self.model.serving_program(
+                    "fused_spec_verify", self._pnames, self._params,
                     self._lora_key(
                         ("paged" if self._paged else "slot", W,
                          self.num_slots,
@@ -4034,8 +4111,8 @@ class Engine:
             self._push_state()
         st = self._dev_state
         if self._fused_fn is None:
-            self._fused_fn, _, _ = self.model._compiled_fused_decode_fn(
-                self._pnames, self._params,
+            self._fused_fn, _, _ = self.model.serving_program(
+                "fused_decode", self._pnames, self._params,
                 self._lora_key(
                     ("paged" if self._paged else "slot", self.num_slots,
                      (self._kv_managed + self.dp, self._bs) if self._paged
@@ -4056,14 +4133,18 @@ class Engine:
                      rows=self._rows_walked()), \
                 self._dequant_span(tr, len(active)):
             (ids, done, new_tok, new_pos, new_ctr, new_rem,
-             self.k_pools, self.v_pools) = self._fused_fn(*args)
-        self._dev_note(self._fused_fn.kind, ids, batch=len(active))
+             self.k_pools, self.v_pools, *stats) = self._fused_fn(*args)
+        self._dev_note(self._fused_fn.kind, ids, batch=len(active),
+                       stats=stats)
         st["tok"], st["pos"], st["ctr"], st["rem"] = \
             new_tok, new_pos, new_ctr, new_rem
         self._m_fused_ticks.inc()
+        arrays = {"ids": ids, "done": done}
+        if stats:
+            arrays["stats"] = stats[0]  # rides the ids' download
         return _InflightTick(
             self.tick_no, "decode", list(active),
-            {"ids": ids, "done": done}, len(active), layout,
+            arrays, len(active), layout,
             {"pos": self._pos.tolist(), "rem": self._rem.tolist()})
 
     def _consume_decode(self, inf, mats, done, tr):
@@ -4215,8 +4296,8 @@ class Engine:
             # high lanes can never emit, so their picks would be
             # computed and discarded every tick
             self._ragged_fn, _, _ = \
-                self.model._compiled_ragged_window_fn(
-                    self._pnames, self._params,
+                self.model.serving_program(
+                    "ragged_window", self._pnames, self._params,
                     self._lora_key(
                         (self.num_slots, W, spec_w,
                          self._kv_managed + self.dp, self._bs,
@@ -4379,6 +4460,7 @@ class Engine:
         self._m_d2h.set(nbytes)
         done = np.unpackbits(mats["done"],
                              count=self.num_slots).astype(bool)
+        self._count_stats(mats.get("stats"))
         in_flight = bool(self._ring)
         t1 = time.monotonic()
         ov = (tr.span("host.overlap", tick=inf.tick) if in_flight
@@ -4393,6 +4475,16 @@ class Engine:
         if in_flight:
             self._overlap_acc += time.monotonic() - t1
         return emitted
+
+    def _count_stats(self, vector=None):
+        """Add a step program's counter vector, and those of the chunk
+        programs dispatched before it (complete by now: one device,
+        in order), into the model's counters."""
+        pending, self._stats_pending = self._stats_pending, []
+        for v in ([vector] if vector is not None else []) \
+                + [np.asarray(h) for h in pending]:
+            for m, n in zip(self._m_program, v):
+                m.inc(int(n))
 
     def _note_dispatch_gap(self, n_active):
         """Pre-dispatch bookkeeping shared by the sync, async, and
@@ -4439,14 +4531,14 @@ class Engine:
             # copy+hash not worth paying per generated token
             if self._paged:
                 self._tick_fn, _, _ = \
-                    self.model._compiled_slot_paged_decode_fn(
-                        self._pnames, self._params,
+                    self.model.serving_program(
+                        "slot_paged_decode", self._pnames, self._params,
                         (self.num_slots, self._kv_managed + self.dp, self._bs,
                          self._kv_dtype_str, tuple(self._pnames),
                          self._bnames_all))
             else:
-                self._tick_fn, _, _ = self.model._compiled_slot_decode_fn(
-                    self._pnames, self._params,
+                self._tick_fn, _, _ = self.model.serving_program(
+                    "slot_decode", self._pnames, self._params,
                     (self.num_slots, self.max_seq_len,
                      self._kv_dtype_str, tuple(self._pnames),
                      self._bnames_all))

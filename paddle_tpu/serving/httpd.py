@@ -315,6 +315,16 @@ class _Handler(JsonHandler):
                     eng, "_kv_code_bytes_per_shard", None),
                 "kv_scale_bytes": getattr(
                     eng, "_kv_scale_bytes_per_shard", None),
+                # what the model keeps per cached position: bytes over
+                # all layers, and the block geometry (KVRowSpec)
+                "kv_row_bytes": getattr(
+                    getattr(eng, "_m_kv_row_bytes", None), "value", None),
+                "kv_geometry": (eng.kv_geometry()
+                                if hasattr(eng, "kv_geometry") else None),
+                # where the model picks an implementation by the
+                # backend it finds (ServingSpec.kernels)
+                "kernels": getattr(getattr(eng, "_serving_spec", None),
+                                   "kernels", None),
                 # async-loop signals, next to the router-tier load
                 # signals: pipeline depth plus the mean overlapped
                 # host time and mean blocking d2h wait per tick —

@@ -113,6 +113,9 @@ tests/test_quant_serving.py (scale-pool parity + migration).
 """
 from __future__ import annotations
 
+from ..models.programs import KVRowSpec  # noqa: F401  (the row spec
+#   is the model's to give; block bytes are counted there)
+
 
 class KVDtypeMismatch(ValueError):
     """Migration payload and destination pools disagree about KV
@@ -299,18 +302,13 @@ def per_shard_block_bytes(block_size, num_heads, head_dim, dtype,
     cost is the block's TRUE footprint and the int8/f32 capacity
     ratio works out to ``4 / (1 + 4/(block_size*head_dim))`` (~3.8x
     for the small test geometries, ~4x at real ones) instead of a
-    flattering byte-only 4x."""
-    import numpy as np
-    mp = int(mp)
-    if mp < 1 or num_heads % mp:
-        raise ValueError(
-            f"num_heads ({num_heads}) must divide by mp ({mp})")
-    total = (int(n_layers) * 2 * int(block_size) * (num_heads // mp)
-             * int(head_dim) * np.dtype(dtype).itemsize)
-    if scale_dtype is not None:
-        total += (int(n_layers) * 2 * (num_heads // mp)
-                  * np.dtype(scale_dtype).itemsize)
-    return total
+    flattering byte-only 4x.
+
+    The K/V-heads case of ``KVRowSpec.block_bytes``, which is where
+    the bytes are counted."""
+    return KVRowSpec.heads(n_layers, num_heads, head_dim,
+                           dtype).block_bytes(
+        block_size, mp=mp, scale_dtype=scale_dtype)
 
 
 class NoFreeBlocks(RuntimeError):

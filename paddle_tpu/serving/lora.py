@@ -144,10 +144,10 @@ class LoRAAdapter:
         """Fold this adapter into ``model``'s attention out_proj
         weights in place — the oracle a lane-gathered engine must
         match token-for-token.  Returns the model."""
-        blocks = list(model.blocks)
-        delta = self.merged_delta(len(blocks))
-        for i, blk in enumerate(blocks):
-            w = blk.attn.out_proj.weight
+        targets = model.serving_lora_targets()
+        delta = self.merged_delta(len(targets))
+        for i, proj in enumerate(targets):
+            w = proj.weight
             w.set_value(w.numpy() + delta[i].astype(w.numpy().dtype))
         return model
 

@@ -171,7 +171,7 @@ def _iter_block_linears(model):
     and leaves the tied embedding table alone)."""
     from .. import nn
     from ..quantization.weight_only import WeightOnlyInt8Linear
-    for bi, block in enumerate(model.blocks):
+    for bi, block in enumerate(model.serving_linear_stacks()):
         stack = [(f"blocks[{bi}]", block)]
         while stack:
             prefix, layer = stack.pop()
@@ -210,11 +210,11 @@ def relayout_weights_int8(model, compute_dtype=None):
                 "quantization.int8's calibrated forms)")
     if not todo:
         raise ValueError(
-            "weight_dtype='int8' found no Linear layers in "
-            "model.blocks to relayout — the tensor-parallel einsum "
+            "weight_dtype='int8' found no Linear layers in the "
+            "model's blocks to relayout — the tensor-parallel einsum "
             "form (use_mp=True) and pre-quantized models have "
             "nothing to code")
     from ..quantization.weight_only import quantize_weights_int8
-    for block in model.blocks:
+    for block in model.serving_linear_stacks():
         quantize_weights_int8(block, compute_dtype=compute_dtype)
     return len(todo)

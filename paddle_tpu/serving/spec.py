@@ -149,10 +149,9 @@ class DraftModelProposer(Proposer):
             from .quant import relayout_weights_int8
             relayout_weights_int8(draft_model)
         self.model = draft_model
-        self.vocab_size = int(
-            draft_model.embeddings.word_embeddings.weight.shape[0])
-        self._max_position = int(
-            draft_model.embeddings.position_embeddings.weight.shape[0])
+        sspec = draft_model.serving_spec()
+        self.vocab_size = sspec.vocab_size
+        self._max_position = sspec.max_positions
 
     def propose(self, history, k):
         h = np.asarray(history, np.int32).reshape(-1)

@@ -1,0 +1,452 @@
+"""The latent-attention / routed-experts decoder (models/mla_moe.py)
+against its plain reference (benchmarks/configs/mla_moe_reference.py),
+at a tiny size on the CPU with seeded float32 weights; the engine/model
+seam (models/programs.py) with both models behind it; ``nn.LazyGuard``;
+``kvcache.KVRowSpec``."""
+import importlib.util
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu import nn
+from paddle_tpu.distributed import moe
+from paddle_tpu.models import GPTModel
+from paddle_tpu.models.mla_moe import MLAMoEModel
+from paddle_tpu.serving import Engine
+from paddle_tpu.serving.kvcache import KVRowSpec, per_shard_block_bytes
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# float32 on the CPU: the program and the reference order their sums
+# differently (absorbed against expanded attention, sorted pairs against
+# a loop over experts); the largest difference in logits of magnitude
+# ~1 measured over these cases is 6e-7
+TOL = 1e-4
+
+DIMS = dict(
+    vocab_size=128, max_position_embeddings=256, hidden_size=64,
+    intermediate_size=96, moe_intermediate_size=32, num_hidden_layers=2,
+    num_attention_heads=4, n_shared_experts=2, n_routed_experts=8,
+    routed_scaling_factor=2.446, kv_lora_rank=32, q_lora_rank=None,
+    qk_rope_head_dim=8, v_head_dim=16, qk_nope_head_dim=16,
+    num_experts_per_tok=2, first_k_dense_replace=1, norm_topk_prob=True,
+    rms_norm_eps=1e-5, rope_theta=800000)
+
+
+def _reference():
+    name = "mla_moe_reference_under_test"
+    if name not in sys.modules:
+        spec = importlib.util.spec_from_file_location(
+            name, os.path.join(ROOT, "benchmarks", "configs",
+                               "mla_moe_reference.py"))
+        mod = importlib.util.module_from_spec(spec)
+        sys.modules[name] = mod
+        spec.loader.exec_module(mod)
+    return sys.modules[name]
+
+
+def seeded(dims=DIMS, seed=0, bias=0.1):
+    """The model with every leaf drawn from ``seed`` (matrices normal
+    0.08, gains 1 + normal 0.1, the correction bias normal ``bias``),
+    and ``get(names)`` that hands the same leaves to the reference."""
+    model = MLAMoEModel(dims)
+    model.eval()
+    leaves = {}
+    for i, (name, p) in enumerate(model.named_parameters()):
+        key = jax.random.fold_in(jax.random.PRNGKey(seed), i)
+        v = jax.random.normal(key, tuple(p.shape), jnp.float32)
+        if "gate_bias" in name:
+            v = bias * v
+        elif len(p.shape) == 1:
+            v = 1.0 + 0.1 * v
+        else:
+            v = 0.08 * v
+        p.set_value(v)
+        leaves[name] = v
+    return model, leaves
+
+
+def getter(leaves, **replace):
+    return lambda names: {n: replace.get(n, leaves[n]) for n in names}
+
+
+def tokens(n, rows=1, seed=0):
+    return np.random.default_rng(seed).integers(
+        1, DIMS["vocab_size"], (rows, n))
+
+
+def paged_logits(model, ids, chunk, n_decode, bs=8, nb=12):
+    """Logits of positions ``len(ids) - n_decode - 1 ...`` through the
+    paged latent cache: chunked prefill of the first tokens, then one
+    decode step a token, each teacher-forced from ``ids``."""
+    n = len(ids) - n_decode
+    row = model.blocks[0].attn.row
+    pools = [jnp.zeros((nb, bs, row), jnp.float32) for _ in model.blocks]
+    table = jnp.arange(1, nb, dtype=jnp.int32)       # block 0: scratch
+    out, p0 = [], 0
+    while p0 < n:
+        m = min(chunk, n - p0)
+        toks = np.zeros((1, chunk), np.int32)
+        toks[0, :m] = ids[p0:p0 + m]
+        last, pools, _, stats = model._chunk_prefill_tick_paged(
+            jnp.asarray(toks), pools, table, p0, m, 0)
+        p0 += m
+    out.append(last[0])
+    for t in range(n, len(ids)):
+        x = model.embed._data[jnp.asarray([[ids[t]]])]
+        pos = jnp.asarray([t], jnp.int32)
+        new = []
+        for blk, pool in zip(model.blocks, pools):
+            x, pool, _ = blk.decode_slots_paged(
+                x, pool, table[None, :], pos, jnp.asarray([True]))
+            new.append(pool)
+        pools = new
+        out.append(model._head(x)[0, -1])
+    return np.asarray(jnp.stack(out)), stats
+
+
+@pytest.mark.parametrize("n, chunk, n_decode", [
+    (40, 16, 6),      # whole chunks and a tail, several blocks
+    (23, 8, 3),       # a chunk that ends inside a block
+    (9, 16, 5),       # one short chunk: the absorbed form (9 < 170)
+])
+def test_paged_prefill_then_decode_equals_the_reference(n, chunk,
+                                                         n_decode):
+    model, leaves = seeded()
+    ids = tokens(n)[0]
+    want = np.asarray(_reference().logits(getter(leaves), DIMS,
+                                          ids[None]))[0]
+    got, _ = paged_logits(model, ids, chunk, n_decode)
+    assert np.abs(got - want[n - n_decode - 1:]).max() < TOL
+
+
+@pytest.mark.parametrize("absorbed", [False, True])
+def test_whole_forward_equals_the_reference(absorbed):
+    model, leaves = seeded(seed=1)
+    ids = tokens(24, rows=2, seed=1)
+    want = np.asarray(_reference().logits(getter(leaves), DIMS, ids))
+    got = np.asarray(model(ids, absorbed=absorbed)._data)
+    assert np.abs(got - want).max() < TOL
+
+
+@pytest.mark.parametrize("window", [1, 5, 16])
+def test_absorbed_equals_expanded_over_the_paged_cache(window):
+    model, _ = seeded(seed=2)
+    attn = model.blocks[1].attn
+    rng = np.random.default_rng(window)
+    B, bs, nbt = 3, 8, 6
+    pool = jnp.asarray(rng.normal(size=(B * nbt + 1, bs, attn.row)),
+                       jnp.float32)
+    tables = jnp.asarray(
+        1 + rng.permutation(B * nbt).reshape(B, nbt), jnp.int32)
+    pos = jnp.asarray([0, 13, 30], jnp.int32)
+    q_n = jnp.asarray(rng.normal(size=(B, window, 4, attn.d_n)),
+                      jnp.float32)
+    q_r = jnp.asarray(rng.normal(size=(B, window, 4, attn.d_r)),
+                      jnp.float32)
+    a = attn.attend(q_n, q_r, pool, tables, pos, absorbed=True)
+    e = attn.attend(q_n, q_r, pool, tables, pos, absorbed=False)
+    assert np.abs(np.asarray(a - e)).max() < 1e-4
+
+
+def test_the_form_is_chosen_from_the_shape():
+    attn = MLAMoEModel(dict(
+        DIMS, num_attention_heads=16, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, kv_lora_rank=512,
+        hidden_size=256)).blocks[0].attn
+    # 34.8 against 10.2 kFLOP a pair, 4.19 MFLOP a position expanded
+    assert [attn.absorbed_wins(w) for w in (1, 128, 170, 171, 256)] \
+        == [True, True, True, False, False]
+
+
+@pytest.mark.parametrize("broken, change", [
+    ("the correction bias", lambda d, w: (d, {
+        n: jnp.zeros_like(v) for n, v in w.items() if "gate_bias" in n})),
+    ("the scaling factor", lambda d, w: (
+        dict(d, routed_scaling_factor=1.0), {})),
+    ("the shared expert", lambda d, w: (d, {
+        n: jnp.zeros_like(v) for n, v in w.items()
+        if "shared.down_proj" in n})),
+    ("the kv norm", lambda d, w: (d, {
+        n: jnp.ones_like(v) * jnp.sqrt(jnp.mean(v * v))
+        for n, v in w.items() if "kv_norm" in n})),
+])
+def test_a_reference_without_a_piece_differs(broken, change):
+    """Each piece of the mathematics shows in the comparison: the
+    reference computed without it is far from the program (and the
+    whole reference is near)."""
+    model, leaves = seeded(seed=3, bias=0.5)
+    ids = tokens(24, seed=3)
+    got = np.asarray(model(ids)._data)
+    whole = np.asarray(_reference().logits(getter(leaves), DIMS, ids))
+    assert np.abs(got - whole).max() < TOL
+    dims, replace = change(DIMS, leaves)
+    without = np.asarray(_reference().logits(
+        getter(leaves, **replace), dims, ids))
+    assert np.abs(got - without).max() > 100 * TOL, broken
+
+
+@pytest.mark.parametrize("skew", [0.0, 50.0])
+def test_every_pair_is_computed_and_counted(skew):
+    """Under a routing skewed onto two experts (a correction bias that
+    decides the selection) nothing is dropped: the logits still equal
+    the reference's, and the counters read what a NumPy count of the
+    routing reads."""
+    model, leaves = seeded(seed=4)
+    ffn = model.blocks[1].ffn
+    bias = np.zeros(8, np.float32)
+    bias[[2, 5]] = skew
+    ffn.gate_bias.set_value(bias)
+    leaves["blocks.1.ffn.gate_bias"] = jnp.asarray(bias)
+    n, chunk = 21, 32
+    ids = tokens(n, seed=4)[0]
+    want = np.asarray(_reference().logits(getter(leaves), DIMS,
+                                          ids[None]))[0]
+    got, stats = paged_logits(model, ids, chunk, 0)
+    assert np.abs(got[0] - want[-1]).max() < TOL
+    # the routing, counted by hand from the reference's side
+    x0 = model.blocks[0](model.embed._data[jnp.asarray(ids)][None])
+    x1 = x0 + model.blocks[1].attn(model.blocks[1].input_norm(x0))
+    h = np.asarray(model.blocks[1].post_norm(x1))[0]
+    s = 1 / (1 + np.exp(-(h @ np.asarray(leaves[
+        "blocks.1.ffn.gate_weight"]))))
+    chosen = np.argsort(-(s + bias), axis=-1, kind="stable")[:, :2]
+    load = np.bincount(chosen.reshape(-1), minlength=8)
+    assert list(np.asarray(stats)) == [2 * n, int((load > 0).sum()), 8,
+                                       int(load.max())]
+    if skew:
+        assert sorted(np.unique(chosen)) == [2, 5] and load.max() == n
+
+
+def test_dead_rows_hit_no_expert():
+    choice = jnp.asarray([[0, 1], [1, 2], [3, 4]], jnp.int32)
+    live = jnp.asarray([True, False, True])
+    order, sizes = moe.sort_pairs_by_expert(choice, live, 6)
+    assert list(np.asarray(sizes)) == [1, 1, 0, 1, 1, 0]
+    assert sorted(np.asarray(order)[:4] // 2) == [0, 0, 2, 2]
+    x = jnp.ones((3, 4))
+    w_in, w_out = jnp.ones((6, 4, 6)), jnp.ones((6, 3, 4))
+    y, stats = moe.dropless_experts(x, choice, jnp.ones((3, 2)), live,
+                                    w_in, w_out)
+    assert np.all(np.asarray(y[1]) == 0) and np.all(np.asarray(y[0]) > 0)
+    assert list(np.asarray(stats)) == [4, 4, 1]
+
+
+def test_sigmoid_topk_routing_selects_with_the_bias_and_weighs_without():
+    logits = jnp.asarray([[2.0, 1.0, 0.0, -1.0]])
+    bias = jnp.asarray([0.0, 0.0, 0.0, 5.0])
+    choice, w = moe.sigmoid_topk_routing(logits, bias, 2, scale=2.0)
+    s = 1 / (1 + np.exp(-np.asarray(logits[0])))
+    assert list(np.asarray(choice[0])) == [3, 0]
+    assert np.allclose(np.asarray(w[0]),
+                       2.0 * s[[3, 0]] / s[[3, 0]].sum(), atol=1e-6)
+
+
+# -- the seam ----------------------------------------------------------
+
+def _follows_greedily(model, prompt, generated):
+    """Every generated token is the argmax of the model's own uncached
+    logits over what came before it (one forward, teacher-forced)."""
+    ids = np.asarray([list(prompt) + list(generated)])
+    out = model(paddle.to_tensor(ids) if isinstance(model, GPTModel)
+                else ids)
+    top = np.argmax(np.asarray(out._data)[0], axis=-1)
+    return list(top[len(prompt) - 1:-1]) == list(generated)
+
+
+def _gpt():
+    paddle.seed(0)
+    model = GPTModel.from_config("tiny", max_position=128, dropout=0.0)
+    model.eval()
+    return model
+
+
+@pytest.mark.parametrize("build", [
+    pytest.param(_gpt, id="gpt"),
+    pytest.param(lambda: seeded(seed=5)[0], id="mla_moe")])
+def test_one_engine_serves_both_models(build):
+    """The same Engine call, chunked paged prefill, the fused decode
+    tick, a prefix adopted from the cache: token-identical to each
+    model's own uncached greedy decoding."""
+    model = build()
+    eng = Engine(model, num_slots=3, max_seq_len=128, kv_block_size=8,
+                 kv_blocks=48, prefill_chunk=16)
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(1, 128, n).tolist() for n in (37, 5, 50, 20)]
+    reqs = [eng.submit(p, max_new_tokens=8) for p in prompts]
+    eng.run_until_idle()
+    for p, r in zip(prompts, reqs):
+        assert _follows_greedily(model, p, list(r.result())[len(p):])
+    hits = eng.registry.get("serving.prefix_hit_tokens").value
+    again = eng.submit(prompts[0], max_new_tokens=8)   # adopted
+    eng.run_until_idle()
+    assert eng.registry.get("serving.prefix_hit_tokens").value \
+        == hits + 32
+    assert list(again.result()) == list(reqs[0].result())
+    spec = model.serving_spec()
+    assert eng.registry.get("serving.kv_row_bytes").value \
+        == spec.kv.position_bytes()
+    assert eng.registry.get("serving.kv_block_bytes").value \
+        == spec.kv.block_bytes(8)
+    pairs = eng.registry.get("serving.moe_routed_pairs")
+    assert (pairs is not None) == bool(spec.counters)
+    # a model that picks an implementation by the backend says which
+    assert eng.debug_requests()["engine"]["kernels"] == (
+        {"moe.experts": "ragged_dot"} if spec.counters else {})
+
+
+def test_sampled_decoding_and_counters_through_the_engine():
+    model, _ = seeded(seed=6)
+    eng = Engine(model, num_slots=2, max_seq_len=64, kv_block_size=8,
+                 kv_blocks=24, prefill_chunk=8,
+                 trace_capacity=4096)
+    a = eng.submit(tokens(12)[0].tolist(), max_new_tokens=10,
+                   temperature=0.8, top_k=20, seed=11)
+    eng.run_until_idle()
+    b = eng.submit(tokens(12)[0].tolist(), max_new_tokens=10,
+                   temperature=0.8, top_k=20, seed=11)
+    eng.run_until_idle()
+    assert list(a.result()) == list(b.result())
+    reg = eng.registry
+    pairs = reg.get("serving.moe_routed_pairs").value
+    # (the last chunk's vector waits for the next download)
+    assert pairs > 0 and pairs % 2 == 0
+    assert reg.get("serving.moe_experts_hit").value \
+        <= reg.get("serving.moe_expert_slots").value
+    dev = [e for e in eng.chrome_trace()["traceEvents"]
+           if e["name"] in ("dev.decode", "dev.prefill")]
+    assert dev and all({"pairs", "experts_hit"} <= set(e["args"])
+                       for e in dev)
+    assert all("_stats" not in e["args"] for e in dev)
+
+
+@pytest.mark.parametrize("options, named", [
+    (dict(kv_block_size=None), "kv_block_size=None"),
+    (dict(prefill_chunk=None), "prefill_chunk=None"),
+    (dict(sample_mode="host", async_depth=1), "sample_mode='host'"),
+    (dict(attn_impl="ragged"), "attn_impl='ragged'"),
+    (dict(spec_k=2), "spec_k"),
+    (dict(kv_dtype="int8"), "kv_dtype='int8'"),
+    (dict(mesh=1), "mesh="),
+    (dict(max_adapters=2), "adapters"),
+    (dict(kv_host_mb=1.0), "kv_host_mb"),
+])
+def test_the_engine_refuses_by_name_what_the_model_cannot_honour(
+        options, named):
+    model, _ = seeded(seed=7)
+    kw = dict(num_slots=2, max_seq_len=64, kv_block_size=8,
+              kv_blocks=24, prefill_chunk=8)
+    kw.update(options)
+    with pytest.raises(ValueError) as err:
+        Engine(model, **kw)
+    assert named in str(err.value) and "lacks" in str(err.value)
+    assert "MLAMoEModel" in str(err.value)
+
+
+def test_migration_is_refused_when_asked_for():
+    model, _ = seeded(seed=7)
+    eng = Engine(model, num_slots=2, max_seq_len=64, kv_block_size=8,
+                 kv_blocks=24, prefill_chunk=8)
+    for call in (lambda: eng.migrate_out(wait=False),
+                 lambda: eng.export_prefix([1, 2, 3], wait=False)):
+        with pytest.raises(ValueError, match="KV migration"):
+            call()
+
+
+def test_weight_only_int8_relayouts_the_linear_projections():
+    model, _ = seeded(seed=8)
+    eng = Engine(model, num_slots=2, max_seq_len=64, kv_block_size=8,
+                 kv_blocks=24, prefill_chunk=8, weight_dtype="int8")
+    r = eng.submit(tokens(12)[0].tolist(), max_new_tokens=4)
+    eng.run_until_idle()
+    assert len(r.result()) == 16
+    assert type(model.blocks[1].attn.q_proj).__name__ \
+        == "WeightOnlyInt8Linear"
+
+
+# -- the row spec --------------------------------------------------------
+
+@pytest.mark.parametrize("spec, block, want", [
+    # 9 layers x 16 rows x 640 numbers x 2 bytes: a row of 576 is
+    # stored in whole tiles of 128 lanes, and the bytes are the pool's
+    (KVRowSpec(9, "bfloat16", (("latent", (576,)),)), 16, 184_320),
+    # a row inside one tile, or of whole tiles, is stored as it is
+    (KVRowSpec(2, "float32", (("latent", (40,)),)), 8, 2_560),
+    # 24 layers x K and V x 16 rows x 16 heads x 128 x 2 bytes
+    (KVRowSpec.heads(24, 16, 128, "bfloat16"), 16, 3_145_728),
+    (KVRowSpec.heads(24, 16, 64, "bfloat16"), 16, 1_572_864),
+])
+def test_block_bytes_come_from_the_row_spec(spec, block, want):
+    assert spec.block_bytes(block) == want
+    assert spec.position_bytes() * block == want
+    # what the bytes count is what the pools hold
+    held = sum(int(np.prod(shape)) for shape in
+               spec.pool_shapes((1, block))) * spec.n_layers
+    assert held * np.dtype(spec.dtype).itemsize == want
+    if spec.heads_axis:
+        assert per_shard_block_bytes(block, 16, spec.head_dim,
+                                     "bfloat16", 24) == want
+        assert spec.block_bytes(block, mp=2) == want // 2
+        assert spec.geometry(block) == {
+            "block_size": 16, "num_heads": 16,
+            "head_dim": spec.head_dim, "n_layers": 24}
+    else:
+        # the row as the model wrote it, without the padding
+        assert spec.geometry(block)["rows"] == [
+            ["latent", list(spec.rows[0][1])]]
+        with pytest.raises(ValueError, match="no head axis"):
+            spec.block_bytes(block, mp=2)
+
+
+def test_a_budget_buys_the_blocks_the_pools_hold():
+    """A latent row wider than one tile of 128 lanes and no multiple
+    of it (160 + 8 -> 256 stored): ``kv_budget_mb`` is spent on the
+    stored bytes, and the gauges read what the pools hold."""
+    model = MLAMoEModel(dict(DIMS, kv_lora_rank=160))
+    eng = Engine(model, num_slots=2, max_seq_len=64, kv_block_size=8,
+                 kv_budget_mb=1, prefill_chunk=16)
+    block = eng.registry.get("serving.kv_block_bytes").value
+    assert block == 2 * 8 * 256 * 4
+    assert eng.registry.get("serving.kv_row_bytes").value == 2 * 256 * 4
+    managed = eng.registry.get("serving.kv_blocks_total").value
+    assert managed == 2 ** 20 // block
+    held = sum(p.nbytes for p in eng.k_pools)
+    assert tuple(eng.k_pools[0].shape[1:]) == (8, 256)
+    # the pools hold the managed blocks and the reserved scratch block
+    assert managed * block <= 2 ** 20 < held + block
+    assert eng.kv_geometry()["rows"] == [["latent", [168]]]
+    got = eng.submit(list(range(1, 20)), max_new_tokens=4)
+    eng.run_until_idle()
+    assert _follows_greedily(model, list(range(1, 20)),
+                             list(got.result())[19:])
+
+
+# -- LazyGuard -----------------------------------------------------------
+
+def test_lazy_guard_declares_without_values():
+    with nn.LazyGuard():
+        model = MLAMoEModel(DIMS)
+    params = dict(model.named_parameters())
+    assert all(p.is_declared for p in params.values())
+    assert params["embed"].shape == [128, 64]
+    model.to(dtype="bfloat16")
+    assert all(str(p._data.dtype) == "bfloat16" for p in params.values())
+    p = params["blocks.1.ffn.gate_bias"]
+    p.set_value(jnp.ones((8,)))
+    assert not p.is_declared and str(p._data.dtype) == "bfloat16"
+    q = params["norm.weight"].initialize()
+    assert not q.is_declared and np.all(np.asarray(
+        q._data, np.float32) == 1.0)
+
+
+def test_outside_the_guard_parameters_are_initialised_as_before():
+    lin = nn.Linear(4, 3)
+    assert not lin.weight.is_declared
+    assert isinstance(lin.weight._data, jax.Array)
+    with nn.LazyGuard():
+        lazy = nn.Linear(4, 3)
+    assert lazy.weight.is_declared and not nn.Linear(4, 3).bias.is_declared

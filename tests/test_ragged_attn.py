@@ -27,6 +27,7 @@ small-shape twins run in tier-1, the multi-thousand-token leg is
 additionally marked slow.
 """
 import math
+import re
 
 import numpy as np
 import pytest
@@ -259,6 +260,69 @@ def test_kernel_compiled_lowering_on_tpu():
                        match="cannot statically prove that index"):
         compile_check(**c0, window=1, dtype=jnp.bfloat16,
                       variant="gather")
+
+
+def test_latent_pool_is_updated_in_place_on_the_v5e():
+    """The latent decode attention at the published widths (16 heads
+    of 128 + 64 / 128, rank 512, 32 slots, 8,192 positions), compiled
+    for the compile-only ``TPU v5 lite`` device: with the row spec's
+    lane padding (576 -> 640) the pool is a row-major parameter that
+    the step scatters into and walks, and no instruction copies it.
+    (Unpadded, the TPU runtime lays a ``[blocks, 16, 576]`` array out
+    with the BLOCK axis minor and every step transposed it there and
+    back: 264 MB a layer; chip run, PR 28.)  The grouped expert
+    product compiles at a decode step's and a chunk's shapes."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from paddle_tpu import nn
+    from paddle_tpu.distributed.moe import grouped_matmul
+    from paddle_tpu.jit import _swapped
+    from paddle_tpu.models.mla_moe import MLAttention
+    from paddle_tpu.serving.kvcache import KVRowSpec
+
+    one = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0])
+
+    def sds(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one)
+
+    with nn.LazyGuard():
+        attn = MLAttention(2048, 16, 128, 64, 128, 512, 8e5, 1e-5)
+    attn.to(dtype="bfloat16")
+    params = dict(attn.named_parameters())
+    names = sorted(params)
+    pool, = KVRowSpec(9, "bfloat16", (("latent", (attn.row,)),)
+                      ).pool_shapes((14337, 16))
+    assert pool == (14337, 16, 640)
+
+    def step(p_list, h, pool, tables, pos):
+        with _swapped(params, dict(zip(names, p_list))):
+            return attn.decode_slots_paged(h, pool, tables, pos)
+
+    # (a compile for a described device is written to the persistent
+    # cache but cannot be read back: keep it out)
+    from jax.experimental.compilation_cache import compilation_cache
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(step, donate_argnums=(2,)).lower(
+            [sds(params[n].shape) for n in names], sds((32, 1, 2048)),
+            sds(pool), sds((32, 512), jnp.int32),
+            sds((32,), jnp.int32)).compile().as_text()
+        for m, k, n in ((192, 2048, 2816), (1536, 1408, 2048)):
+            jax.jit(lambda x, w, g: grouped_matmul(x, w, g, "gmm")).lower(
+                sds((m, k)), sds((64, k, n)),
+                sds((64,), jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+        compilation_cache.reset_cache()
+    shape = "bf16[14337,16,640]"
+    assert shape in text
+    copies = re.compile(r"= " + re.escape(shape) + r"\S* copy\(")
+    assert not [ln for ln in text.splitlines() if copies.search(ln)]
 
 
 # -- knob validation --------------------------------------------------
