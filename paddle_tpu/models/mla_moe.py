@@ -51,9 +51,13 @@ from .programs import (
     KVRowSpec, ServedModel, ServingSpec, _jit_named, _scoped,
     sample_lanes, slot_sample_keys)
 
-# cache rows one trip of the blocked walk fetches (a whole number of
-# blocks): at 256 the v5e keeps a trip's rows on chip (PERF.md, PR 26)
+# cache rows one item of the blocked walk fetches (a whole number of
+# blocks): at 256 the v5e keeps an item's rows on chip (PERF.md, PR 26)
 _WALK_ROWS = 256
+# (slot, chunk) items one trip of the decode walk takes, never more
+# than there are slots: 32 x 256 rows are what a trip over all 32 slots
+# fetched, and a trip costs the same whatever its items (PERF.md, PR 30)
+_WALK_GROUP = 32
 
 # counters of the routed layers, in the order of the vector the step
 # programs return; (registry name under "serving.", dev.* span arg)
@@ -61,6 +65,68 @@ MOE_COUNTERS = (("moe_routed_pairs", "pairs"),
                 ("moe_experts_hit", "experts_hit"),
                 ("moe_expert_slots", None),
                 ("moe_load_max", None))
+
+
+def walk_chunk(table_rows, block_size):
+    """Rows of one item of ``MLAttention.attend``'s walk: a whole
+    number of blocks, of the order of ``_WALK_ROWS``, at most the
+    table."""
+    return min(table_rows, block_size * max(1, _WALK_ROWS // block_size))
+
+
+def walk_group(slots):
+    """(slot, chunk) items one trip of the decode walk takes over
+    ``slots`` slots."""
+    return min(_WALK_GROUP, slots)
+
+
+def walk_plan(pos, window, table_rows, chunk, group):
+    """The decode walk's work list, built on the device from ``pos``
+    alone: slot b gets ``n_b = ceil((pos_b + window) / chunk)`` items,
+    one for each ``chunk`` rows its queries see (rows ``< pos_b +
+    window``), and none at position 0 (a parked lane); the items lie
+    slot by slot in chunk order, by a cumulative sum.
+
+    pos int32 [B]; the rest static.  Returns ``(slot_of, chunk_of,
+    valid, n_trips)``: three arrays of the static length ``B *
+    ceil(table_rows / chunk)`` rounded up to whole trips of ``group``
+    items (item i is chunk ``chunk_of[i]`` of slot ``slot_of[i]``;
+    items past the list's end are not ``valid``), and the data trip
+    count ``ceil(sum(n_b) / group)``.  One program for every list."""
+    import jax.numpy as jnp
+    n_chunks = -(-table_rows // chunk)
+    n = jnp.where(pos > 0, jnp.minimum(
+        (pos + window + chunk - 1) // chunk, n_chunks), 0).astype(jnp.int32)
+    ends = jnp.cumsum(n)                                          # [B]
+    size = pos.shape[0] * n_chunks
+    item = jnp.arange(size + -size % group, dtype=jnp.int32)
+    valid = item < ends[-1]
+    # the slot of item i is the first whose items end past i
+    slot_of = jnp.minimum(jnp.sum(item[:, None] >= ends[None, :], axis=1,
+                                  dtype=jnp.int32), pos.shape[0] - 1)
+    chunk_of = jnp.where(valid, item - (ends - n)[slot_of], 0)
+    return slot_of, chunk_of, valid, (ends[-1] + group - 1) // group
+
+
+def walk_rows(pos, ahead, table_rows, block_size):
+    """Host twin of ``walk_plan`` for the engine's counters
+    (``ServingSpec.decode_rows``): the cache rows one decode dispatch
+    fetches over all slots when slot b's window ends at ``pos[b] +
+    ahead`` — ``trips x group x chunk``, the last trip's padding items
+    included; a table of at most one chunk is read whole by every
+    slot."""
+    import numpy as np
+    pos = np.asarray(pos, np.int64)
+    chunk = walk_chunk(table_rows, block_size)
+    if table_rows <= chunk or len(pos) == 1:
+        # one trip over every slot; one slot walks as the chunk
+        # program does, to the end of its own window
+        end = max(1, min(int(pos.max()) + ahead, table_rows))
+        return len(pos) * min(table_rows, -(-end // chunk) * chunk)
+    group = walk_group(len(pos))
+    items = int((-(-np.minimum(pos[pos > 0] + ahead, table_rows)
+                   // chunk)).sum())
+    return -(-items // group) * group * chunk
 
 
 def rms_norm(x, weight, eps):
@@ -233,12 +299,25 @@ class MLAttention(nn.Layer):
 
     def attend(self, q_n, q_r, pool, tables, pos, absorbed=None):
         """Causal attention of a window of queries over each slot's
-        cached rows, read through its block table ``_WALK_ROWS`` rows a
-        trip in the pool's dtype with float32 accumulation, only as far
-        as the longest live window (``ceil((max(pos) + S) / chunk)``
-        trips, read on the device), one pass with a running maximum
-        and denominator — ``GPTAttention._slot_attn``'s walk over
-        latent rows.
+        cached rows, read through its block table ``_WALK_ROWS`` rows
+        at a time in the pool's dtype with float32 accumulation, one
+        pass with a running maximum and denominator.
+
+        Several slots (the decode program) are walked as a WORK LIST
+        of (slot, chunk) items, ``walk_plan``: each slot is read to its
+        OWN window's end, ``_WALK_GROUP`` items a trip whatever slots
+        they belong to, ``ceil(items / group)`` trips read on the
+        device.  An item's masked scores give a partial (maximum,
+        denominator, context); a trip folds its items' partials into
+        their slots' running state, so the softmax is over exactly the
+        visible rows (masked rows, the last trip's padding items and
+        slots without an item contribute exactly 0).  A slot at
+        position 0 has no item and returns zeros: that is where the
+        engine parks a lane, and a lane that decodes stands at 1 or
+        later.  One slot (the chunk program) walks its own
+        ``ceil((pos + S) / chunk)`` chunks in turn — ``GPTAttention
+        ._slot_attn``'s walk over latent rows.  A table of at most one
+        chunk is read whole, without a loop.
 
         q_n [B, S, H, d_n], q_r [B, S, H, d_r]; pool [NB, bs, W], W >=
         r + d_r (the row, then the spec's lane padding, never written);
@@ -252,10 +331,11 @@ class MLAttention(nn.Layer):
         if absorbed is None:
             absorbed = self.absorbed_wins(S)
         table_rows = tables.shape[1] * bs
-        chunk = min(table_rows, bs * max(1, _WALK_ROWS // bs))
+        chunk = walk_chunk(table_rows, bs)
         w_kvb = self._w_kvb()
         scale = 1.0 / math.sqrt(self.d_n + self.d_r)
         q_end = pos[:, None] + jnp.arange(S)[None, :]          # [B, S]
+        highest = jax.lax.Precision.HIGHEST
         if absorbed:
             with jax.named_scope("mla.absorbed"):
                 q_lat = jnp.einsum("bshn,rhn->bshr", q_n,
@@ -268,50 +348,110 @@ class MLAttention(nn.Layer):
                      jnp.zeros(q_lat.shape[:-1]
                                + (pool.shape[2] - self.row,))],
                     axis=-1).astype(pool.dtype)
-            width = r
+            queries, width = (qq,), r
         else:
-            width = self.d_v
+            queries, width = (q_n, q_r), self.d_v
 
-        def trip(c, carry):
-            top, den, acc = carry
-            start = jnp.minimum(c * chunk, table_rows - chunk)
-            cols = jax.lax.dynamic_slice_in_dim(
-                tables, start // bs, chunk // bs, axis=1)
-            rows = pool[cols].reshape(B, chunk, pool.shape[2])
+        def partial(qs, rows, visible):
+            """Scores of queries ``qs`` ([b, S, H, .] each) over the
+            blocks ``rows`` [b, chunk // bs, bs, W], masked by
+            ``visible`` [b, S, chunk], and ``context(p)``: the float32
+            context [b, S, H, width] of weights p [b, H, S, chunk]."""
+            rows = rows.reshape(rows.shape[0], -1, pool.shape[2])
             if absorbed:
-                sc = jnp.einsum("bshr,bkr->bhsk", qq, rows,
+                sc = jnp.einsum("bshr,bkr->bhsk", qs[0], rows,
                                 preferred_element_type=jnp.float32)
+                values, form = rows[..., :r], "bhsk,bkr->bshr"
             else:
                 kv = jnp.einsum("bkr,rhe->bkhe", rows[..., :r], w_kvb,
                                 preferred_element_type=jnp.float32
                                 ).astype(pool.dtype)
-                sc = (jnp.einsum("bshn,bkhn->bhsk", q_n,
+                sc = (jnp.einsum("bshn,bkhn->bhsk", qs[0],
                                  kv[..., :self.d_n],
                                  preferred_element_type=jnp.float32)
-                      + jnp.einsum("bshr,bkr->bhsk", q_r,
+                      + jnp.einsum("bshr,bkr->bhsk", qs[1],
                                    rows[..., r:self.row],
                                    preferred_element_type=jnp.float32))
+                values, form = kv[..., self.d_n:], "bhsk,bkhv->bshv"
+            return (jnp.where(visible[:, None, :, :], sc * scale, -1e30),
+                    lambda p: jnp.einsum(form, p,
+                                         values.astype(jnp.float32),
+                                         precision=highest))
+
+        def per_ctx(a):                  # [b, H, S] -> [b, S, H, 1]
+            return jnp.transpose(a, (0, 2, 1))[..., None]
+
+        def trip(c, carry):
+            top, den, acc = carry
+            start = jnp.minimum(c * chunk, table_rows - chunk)
             at = start + jnp.arange(chunk)
             # rows below c * chunk were scored by an earlier trip (the
             # last trip of a table that is no whole number of chunks
             # starts early)
-            visible = ((at[None, None, :] <= q_end[:, :, None])
-                       & (at >= c * chunk)[None, None, :])
-            sc = jnp.where(visible[:, None, :, :], sc * scale, -1e30)
+            sc, context = partial(
+                queries, pool[jax.lax.dynamic_slice_in_dim(
+                    tables, start // bs, chunk // bs, axis=1)],
+                (at[None, None, :] <= q_end[:, :, None])
+                & (at >= c * chunk)[None, None, :])
             new_top = jnp.maximum(top, jnp.max(sc, axis=-1))
             keep = jnp.exp(top - new_top)
             p = jnp.exp(sc - new_top[..., None])
-            if absorbed:
-                ctx = jnp.einsum("bhsk,bkr->bshr", p,
-                                 rows[..., :r].astype(jnp.float32),
-                                 precision=jax.lax.Precision.HIGHEST)
-            else:
-                ctx = jnp.einsum("bhsk,bkhv->bshv", p,
-                                 kv[..., self.d_n:].astype(jnp.float32),
-                                 precision=jax.lax.Precision.HIGHEST)
-            keep_c = jnp.transpose(keep, (0, 2, 1))[..., None]
             return (new_top, den * keep + jnp.sum(p, axis=-1),
-                    acc * keep_c + ctx)
+                    acc * per_ctx(keep) + context(p))
+
+        def walk_items(init):
+            group = walk_group(B)
+            slot_of, chunk_of, valid, n_trips = walk_plan(
+                pos, S, table_rows, chunk, group)
+            # What a trip needs of its items and no carry enters is
+            # made for the whole list before the loop and cut a trip:
+            # it depends on ``pos`` and the tables alone, so a
+            # program's layers share one copy.  (A table that is no
+            # whole number of items is filled up with block 0, whose
+            # rows lie past every window.)
+            n_chunks = -(-table_rows // chunk)
+            whole = jnp.pad(tables, ((0, 0), (
+                0, n_chunks * chunk // bs - tables.shape[1])))
+            cols = whole.reshape(B * n_chunks, chunk // bs)[
+                slot_of * n_chunks + chunk_of]           # [N, K // bs]
+            at = (chunk_of * chunk)[:, None] + jnp.arange(chunk)[None, :]
+            sees = ((at[:, None, :] <= q_end[slot_of][:, :, None])
+                    & ((at < table_rows)
+                       & valid[:, None])[:, None, :])      # [N, S, K]
+            whose = ((slot_of[None, :] == jnp.arange(B)[:, None])
+                     & valid[None, :])[..., None, None]  # [B, N, 1, 1]
+
+            def item_trip(t, carry):
+                top, den, acc = carry
+
+                def cut(a, axis=0):
+                    return jax.lax.dynamic_slice_in_dim(
+                        a, t * group, group, axis)
+                sl = cut(slot_of)
+                sc, context = partial([q[sl] for q in queries],
+                                      pool[cut(cols)], cut(sees))
+                # the items' own partials ...
+                m = jnp.max(sc, axis=-1)                  # [G, H, S]
+                p = jnp.exp(sc - m[..., None])
+                # ... folded into their slots' running state: item g
+                # weighs exp(m_g - new_top_b) in its slot b, 0 elsewhere
+                # (and exactly 0 where it saw no row: m_g = -1e30)
+                mine = cut(whose, 1)
+                new_top = jnp.maximum(top, jnp.max(
+                    jnp.where(mine, m[None], -1e30), axis=1))
+                keep = jnp.exp(top - new_top)
+                w = jnp.where(mine, jnp.exp(jnp.minimum(
+                    m[None] - new_top[:, None], 0.0)), 0.0)  # [B,G,H,S]
+                return (new_top,
+                        den * keep + jnp.einsum(
+                            "bghs,ghs->bhs", w, jnp.sum(p, axis=-1)),
+                        acc * per_ctx(keep) + jnp.einsum(
+                            "bghs,gshv->bshv", w, context(p),
+                            precision=highest))
+
+            _, den, acc = jax.lax.fori_loop(0, n_trips, item_trip, init)
+            # a slot without an item: 0 / 1
+            return jnp.where(den > 0, den, 1.0), acc
 
         init = (jnp.full((B, H, S), -1e30, jnp.float32),
                 jnp.zeros((B, H, S), jnp.float32),
@@ -321,11 +461,13 @@ class MLAttention(nn.Layer):
                              else "mla.expanded"):
             if trips == 1:
                 _, den, acc = trip(0, init)
+            elif B > 1:
+                den, acc = walk_items(init)
             else:
                 live = jnp.clip((jnp.max(pos) + S + chunk - 1) // chunk,
                                 1, trips)
                 _, den, acc = jax.lax.fori_loop(0, live, trip, init)
-            ctx = acc / jnp.transpose(den, (0, 2, 1))[..., None]
+            ctx = acc / per_ctx(den)
             if absorbed:
                 ctx = jnp.einsum("bshr,rhv->bshv",
                                  ctx.astype(pool.dtype),
@@ -339,15 +481,21 @@ class MLAttention(nn.Layer):
         ``pos[b]``, attend in the absorbed form.  h [B, 1, D]; pool
         [NB, bs, r + d_r]; tables [B, L // bs]; pos [B].  Returns
         (out [B, 1, D], pool)."""
+        import jax
         import jax.numpy as jnp
         q_n, q_r, row = self.project(h, pos[:, None])
         bs = pool.shape[1]
-        # scattered into the pool as it lies ([block, row in block]):
+        # Written into the pool as it lies ([block, row in block]:
         # through a flattened view the v5e compiler copies the whole
-        # pool every step (264 MB a layer; chip run, PR 28)
-        pool = pool.at[tables[jnp.arange(h.shape[0]), pos // bs],
-                       pos % bs, :self.row].set(
-            row[:, 0].astype(pool.dtype))
+        # pool every step, 264 MB a layer; chip run, PR 28), one
+        # in-place update a slot: the scatter that says the same is
+        # compiled to a loop of 32 trips of six operations, 1.25 ms a
+        # step over nine layers against 0.29 ms (chip run, PR 30).
+        blocks = tables[jnp.arange(h.shape[0]), pos // bs]
+        offs, new = pos % bs, row.astype(pool.dtype)
+        for b in range(h.shape[0]):
+            pool = jax.lax.dynamic_update_slice(
+                pool, new[b:b + 1], (blocks[b], offs[b], 0))
         out = self.attend(q_n, q_r, pool, tables, pos, absorbed=True)
         return _lin(self.o_proj, out), pool
 
@@ -532,9 +680,10 @@ class MLAMoEModel(ServedModel, nn.Layer):
         one token a slot through every block, sampling and the stop
         condition on the device, the same outputs, and the counter
         vector last.  Only lanes with budget left (``rem > 0``) are
-        routed to experts: a parked lane's row computes garbage through
-        attention and the shared expert, as it does for GPT, but hits
-        no expert's weights."""
+        routed to experts: a parked lane's row runs through the shared
+        expert, as it does for GPT, but walks no cached row (it stands
+        at position 0: ``MLAttention.attend``) and hits no expert's
+        weights."""
         import jax.numpy as jnp
         live = rem > 0
         x = self.embed._data[tok[:, 0]][:, None, :]
@@ -647,6 +796,7 @@ class MLAMoEModel(ServedModel, nn.Layer):
             vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
             counters=MOE_COUNTERS,
             kernels={"moe.experts": grouped_matmul_impl()},
+            decode_rows=walk_rows,
             unsupported={
                 "contiguous": "a contiguous [slots, L] latent buffer "
                               "and its decode / prefill programs",

@@ -243,6 +243,14 @@ class KVRowSpec:
         return geo
 
 
+def _rows_to_the_longest(pos, ahead, table_rows, block_size):
+    """``ServingSpec.decode_rows`` of a walk that reads every slot as
+    far as the longest live window."""
+    from .gpt import slot_attn_chunk, slot_attn_rows
+    return len(pos) * slot_attn_rows(int(max(pos)) + ahead, table_rows,
+                                     slot_attn_chunk(block_size))
+
+
 class ServingSpec:
     """A model's answer to the engine's questions.
 
@@ -263,11 +271,22 @@ class ServingSpec:
                        picks one by the backend it finds; ``/healthz``
                        reports it, so that a replica serving on a
                        fallback says so
+    ``decode_rows``    ``(pos, ahead, table_rows, block_size) -> int``:
+                       the cache rows one XLA decode / verify dispatch
+                       fetches, summed over slots, when slot b's window
+                       ends at row ``pos[b] + ahead`` of its
+                       ``table_rows``-row table (``pos`` the host's
+                       mirror, 0 a parked slot; ``block_size`` None for
+                       the contiguous layout) — the host twin of the
+                       trip count the program reads on the device,
+                       behind ``serving.decode_rows_walked``.  Left
+                       out: every slot as far as the longest window,
+                       ``GPTAttention._slot_attn``'s rule
     """
 
     def __init__(self, kv, max_positions, vocab_size, hidden_size,
                  tensor_parallel=False, counters=(), unsupported=None,
-                 kernels=None):
+                 kernels=None, decode_rows=None):
         self.kv = kv
         self.max_positions = int(max_positions)
         self.vocab_size = int(vocab_size)
@@ -276,6 +295,7 @@ class ServingSpec:
         self.counters = tuple(counters)
         self.unsupported = dict(unsupported or {})
         self.kernels = dict(kernels or {})
+        self.decode_rows = decode_rows or _rows_to_the_longest
 
 
 class ServedModel:

@@ -1241,14 +1241,22 @@ class Engine:
             "sampled on device (sample_mode='device')")
         self._m_rows_walked = reg.counter(
             "serving.decode_rows_walked", "cache rows the XLA decode / "
-            "verify dispatches walked, summed over slots: the "
-            "slot-window attention stops at the longest live window "
-            "rounded up to its chunk (from the host's position "
-            "mirror; over serving.decode_rows_table it is the share "
-            "of the table read, 1.0 = every row of every slot)")
+            "verify dispatches walked, summed over slots: how far the "
+            "slot-window attention goes is the served model's rule "
+            "(ServingSpec.decode_rows: every slot to the longest live "
+            "window rounded up to its chunk, or each to its own), "
+            "applied to the host's position mirror; over "
+            "serving.decode_rows_table it is the share of the table "
+            "read, 1.0 = every row of every slot")
         self._m_rows_table = reg.counter(
             "serving.decode_rows_table", "cache rows of every slot's "
             "whole table, summed over the same dispatches")
+        self._m_rows_live = reg.counter(
+            "serving.decode_rows_live", "cache rows some query of the "
+            "same dispatches can see, summed over the slots that hold "
+            "a position: serving.decode_rows_walked over it is what "
+            "the walk reads for every row it needs (1.0 = nothing "
+            "else)")
         self._m_kv_blocks_walked = reg.gauge(
             "serving.kv_blocks_walked_per_tick", "KV blocks the "
             "ragged kernel walked in the latest dispatch, summed over "
@@ -3367,20 +3375,23 @@ class Engine:
         self._state_dirty = True
 
     def _rows_walked(self, width=1):
-        """Rows of each slot's table the XLA slot-window attention
-        walks in the dispatch about to be issued, from the position
-        mirror: the mirror trails the device by the ticks in flight,
-        each of which moved a lane by at most ``width`` rows.  Counted
-        into ``serving.decode_rows_walked`` / ``_table``; returned for
-        the ``decode.dispatch`` span."""
-        from ..models.gpt import slot_attn_chunk, slot_attn_rows
-        end = int(self._pos.max()) + width * (1 + len(self._ring))
-        rows = slot_attn_rows(
-            end, self.max_seq_len,
-            slot_attn_chunk(self._bs if self._paged else None))
-        self._m_rows_walked.inc(rows * self.num_slots)
+        """Rows of a slot's table the XLA slot-window attention walks
+        in the dispatch about to be issued, the mean over slots, from
+        the position mirror: the mirror trails the device by the ticks
+        in flight, each of which moved a lane by at most ``width``
+        rows.  How far the walk goes is the served model's to say
+        (``ServingSpec.decode_rows``).  Counted into
+        ``serving.decode_rows_walked`` / ``_live`` / ``_table``;
+        returned for the ``decode.dispatch`` span."""
+        ahead = width * (1 + len(self._ring))
+        walked = self._serving_spec.decode_rows(
+            self._pos, ahead, self.max_seq_len,
+            self._bs if self._paged else None)
+        self._m_rows_walked.inc(walked)
+        self._m_rows_live.inc(int(np.minimum(
+            self._pos[self._pos > 0] + ahead, self.max_seq_len).sum()))
         self._m_rows_table.inc(self.max_seq_len * self.num_slots)
-        return rows
+        return walked // self.num_slots
 
     def _push_state(self):
         """Upload the state mirrors as the device-resident step state

@@ -13,9 +13,9 @@ import numpy as np
 import pytest
 
 import paddle_tpu as paddle
-from paddle_tpu import nn
+from paddle_tpu import monitor, nn
 from paddle_tpu.distributed import moe
-from paddle_tpu.models import GPTModel
+from paddle_tpu.models import GPTModel, mla_moe
 from paddle_tpu.models.mla_moe import MLAMoEModel
 from paddle_tpu.serving import Engine
 from paddle_tpu.serving.kvcache import KVRowSpec, per_shard_block_bytes
@@ -113,10 +113,38 @@ def paged_logits(model, ids, chunk, n_decode, bs=8, nb=12):
     (40, 16, 6),      # whole chunks and a tail, several blocks
     (23, 8, 3),       # a chunk that ends inside a block
     (9, 16, 5),       # one short chunk: the absorbed form (9 < 170)
+    # ragged lengths side by side through the engine's decode program,
+    # whose walk is the work list (items of 16 rows, see ``short_walk``)
+    ((70, 3, 33, 17, 100), 16, 9),    # one long lane among short ones
+    ((15, 16, 17, 31, 32, 33), 8, 6),  # lengths at an item's edge
+    ((5, 90, 5, 60), 32, 12),         # 2 slots: lanes parked between
 ])
-def test_paged_prefill_then_decode_equals_the_reference(n, chunk,
-                                                         n_decode):
+def test_paged_prefill_then_decode_equals_the_reference(
+        n, chunk, n_decode, short_walk):
     model, leaves = seeded()
+    if isinstance(n, tuple):
+        # every served token is the reference's best token over what
+        # came before it (teacher-forced, one forward a request)
+        eng = Engine(model, num_slots=2 if len(n) == 4 else 4,
+                     max_seq_len=128, kv_block_size=8, kv_blocks=72,
+                     prefill_chunk=chunk, prefix_cache=False,
+                     registry=monitor.StatRegistry())
+        prompts = [tokens(k, seed=k)[0].tolist() for k in n]
+        reqs = [eng.submit(p, max_new_tokens=n_decode) for p in prompts]
+        eng.run_until_idle()
+        for p, r in zip(prompts, reqs):
+            ids = np.asarray(list(r.result()))
+            want = np.asarray(_reference().logits(
+                getter(leaves), DIMS, ids[None]))[0]
+            assert list(np.argmax(want, axis=-1)[len(p) - 1:-1]) \
+                == list(ids[len(p):])
+        # the work list engaged: fewer rows walked than 'all slots as
+        # far as the longest' would read, never fewer than are live
+        reg = eng.registry
+        walked = reg.get("serving.decode_rows_walked").value
+        assert reg.get("serving.decode_rows_live").value <= walked \
+            < reg.get("serving.decode_rows_table").value
+        return
     ids = tokens(n)[0]
     want = np.asarray(_reference().logits(getter(leaves), DIMS,
                                           ids[None]))[0]
@@ -151,6 +179,179 @@ def test_absorbed_equals_expanded_over_the_paged_cache(window):
     a = attn.attend(q_n, q_r, pool, tables, pos, absorbed=True)
     e = attn.attend(q_n, q_r, pool, tables, pos, absorbed=False)
     assert np.abs(np.asarray(a - e)).max() < 1e-4
+
+
+@pytest.fixture
+def short_walk(monkeypatch):
+    """Items of 16 rows, 4 a trip: tables of 64 rows are then four
+    items long, and a few slots make several trips."""
+    monkeypatch.setattr(mla_moe, "_WALK_ROWS", 16)
+    monkeypatch.setattr(mla_moe, "_WALK_GROUP", 4)
+
+
+def _one_shot(attn, q_n, q_r, pool, tables, pos):
+    """float64 softmax attention of each slot's query over its own
+    rows ``<= pos``, expanded form, one shot."""
+    import math
+    w = np.asarray(attn._w_kvb(), np.float64)
+    out = np.zeros((len(pos), attn.num_heads * attn.d_v))
+    for b, last in enumerate(pos):
+        rows = np.asarray(pool, np.float64)[np.asarray(tables[b])]
+        rows = rows.reshape(-1, rows.shape[-1])[:last + 1]
+        kv = np.einsum("kr,rhe->khe", rows[:, :attn.rank], w)
+        sc = (np.einsum("hn,khn->hk", np.asarray(q_n[b, 0], np.float64),
+                        kv[..., :attn.d_n])
+              + np.einsum("hr,kr->hk", np.asarray(q_r[b, 0], np.float64),
+                          rows[:, attn.rank:attn.row])
+              ) / math.sqrt(attn.d_n + attn.d_r)
+        p = np.exp(sc - sc.max(-1, keepdims=True))
+        out[b] = np.einsum("hk,khv->hv", p / p.sum(-1, keepdims=True),
+                           kv[..., attn.d_n:]).reshape(-1)
+    return out
+
+
+# bs 8, chunk 16 rows, 4 items a trip (``short_walk``); pos 0 = parked
+RAGGED = {
+    "all 32 equal": ([37] * 32, 8),
+    "one long lane among short ones": ([3, 5, 63, 2, 9, 1, 4, 7], 8),
+    "parked lanes between live ones": ([0, 22, 0, 0, 47, 0, 13, 0], 8),
+    "lengths at an item's edge": ([14, 15, 16, 30, 31, 32], 8),
+    "every slot full": ([63] * 6, 8),
+    "a table of three items and a half": ([55, 9, 40, 17, 33], 7),
+    "16 items: whole trips": ([63, 63, 63, 63, 1, 0], 8),
+    "15 items: the last trip padded": ([63, 63, 63, 47, 0, 0], 8),
+    "one item": ([0, 0, 6, 0], 8),
+    "nothing live": ([0, 0, 0], 8),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RAGGED))
+def test_the_decode_walk_equals_one_shot_attention(case, short_walk):
+    """The decode form of ``attend`` (several slots, one query each:
+    the work list) against a one-shot softmax over each slot's own
+    rows; a parked slot returns zeros."""
+    pos, nbt = RAGGED[case]
+    model, _ = seeded(seed=2)
+    attn = model.blocks[1].attn
+    rng = np.random.default_rng(len(case))
+    B, bs = len(pos), 8
+    pool = jnp.asarray(rng.normal(size=(B * nbt + 1, bs, attn.row)),
+                       jnp.float32)
+    tables = jnp.asarray(
+        1 + rng.permutation(B * nbt).reshape(B, nbt), jnp.int32)
+    q_n = jnp.asarray(rng.normal(size=(B, 1, 4, attn.d_n)), jnp.float32)
+    q_r = jnp.asarray(rng.normal(size=(B, 1, 4, attn.d_r)), jnp.float32)
+    got = np.asarray(jax.jit(
+        lambda *a: attn.attend(*a, absorbed=True))(
+            q_n, q_r, pool, tables, jnp.asarray(pos, jnp.int32)))[:, 0]
+    live = np.asarray(pos) > 0
+    want = _one_shot(attn, q_n, q_r, pool, tables, pos)
+    assert np.abs(got - want)[live].max(initial=0) < 2e-6
+    assert np.all(got[~live] == 0)
+
+
+def test_the_decode_walk_at_the_real_item_size():
+    """256-row items: windows that end one row before, on and one row
+    after an item's edge (lengths 255, 256, 257), in both forms and for
+    a window of several queries."""
+    model, _ = seeded(seed=2)
+    attn = model.blocks[1].attn
+    rng = np.random.default_rng(9)
+    pos, B, bs, nbt = [254, 255, 256, 600, 0], 5, 16, 40
+    pool = jnp.asarray(rng.normal(size=(B * nbt + 1, bs, attn.row)),
+                       jnp.float32)
+    tables = jnp.asarray(
+        1 + rng.permutation(B * nbt).reshape(B, nbt), jnp.int32)
+    q_n = jnp.asarray(rng.normal(size=(B, 3, 4, attn.d_n)), jnp.float32)
+    q_r = jnp.asarray(rng.normal(size=(B, 3, 4, attn.d_r)), jnp.float32)
+    for absorbed in (True, False):
+        got = np.asarray(attn.attend(q_n, q_r, pool, tables,
+                                     jnp.asarray(pos, jnp.int32),
+                                     absorbed=absorbed))
+        for s in range(3):
+            want = _one_shot(attn, q_n[:, s:s + 1], q_r[:, s:s + 1],
+                             pool, tables, [p + s for p in pos])
+            assert np.abs(got[:4, s] - want[:4]).max() < 2e-6
+
+
+@pytest.mark.parametrize("pos, table_rows", [
+    ([37] * 32, 64), ([3, 5, 63, 2, 9, 1, 4, 7], 64),
+    ([0, 22, 0, 0, 47, 0, 13, 0], 64), ([15, 16, 17], 64),
+    ([63] * 6, 64), ([55, 9, 40, 17, 33], 56), ([0, 0, 0], 64),
+    ([100, 0], 64),                     # past the table: clipped
+])
+def test_the_work_list_is_the_item_rule(pos, table_rows, short_walk):
+    """``sum(ceil((pos + 1) / chunk))`` items over the slots that hold
+    a position, ``ceil(items / group)`` trips, every (slot, chunk) pair
+    once and in order; the host twin counts the same rows."""
+    chunk, group = 16, min(4, len(pos))
+    slot_of, chunk_of, valid, n_trips = (np.asarray(a) for a in
+                                         mla_moe.walk_plan(
+        jnp.asarray(pos, jnp.int32), 1, table_rows, chunk, group))
+    n = [min(-(-(p + 1) // chunk), -(-table_rows // chunk)) if p else 0
+         for p in pos]
+    assert int(valid.sum()) == sum(n)
+    assert int(n_trips) == -(-sum(n) // group)
+    assert len(valid) % group == 0 and len(valid) >= sum(n)
+    assert list(zip(slot_of[valid], chunk_of[valid])) == [
+        (b, c) for b, k in enumerate(n) for c in range(k)]
+    assert mla_moe.walk_rows(np.asarray(pos), 1, table_rows, 8) \
+        == int(n_trips) * group * chunk
+
+
+def _decode_rows(eng):
+    reg = eng.registry
+    return tuple(reg.get("serving.decode_rows_" + k).value
+                 for k in ("walked", "live", "table"))
+
+
+def test_the_rows_counters_add_up_to_the_rule(short_walk):
+    """Through a tiny engine: every decode dispatch adds the rule's
+    rows for the position mirror it was issued from."""
+    model, _ = seeded(seed=6)
+    eng = Engine(model, num_slots=4, max_seq_len=128, kv_block_size=8,
+                 kv_blocks=72, prefill_chunk=16, trace_capacity=4096,
+                 registry=monitor.StatRegistry())
+    seen = []
+    rule = eng._serving_spec.decode_rows
+
+    def spy(pos, ahead, table_rows, block_size):
+        seen.append((pos.copy(), ahead))
+        return rule(pos, ahead, table_rows, block_size)
+    eng._serving_spec.decode_rows = spy
+    for k in (70, 3, 33):
+        eng.submit(tokens(k, seed=k)[0].tolist(), max_new_tokens=7)
+    eng.run_until_idle()
+    assert seen
+    walked = sum(mla_moe.walk_rows(p, a, 128, 8) for p, a in seen)
+    live = sum(int(np.minimum(p[p > 0] + a, 128).sum()) for p, a in seen)
+    assert _decode_rows(eng) == (walked, live, len(seen) * 4 * 128)
+    assert live <= walked < len(seen) * 4 * 128
+    rows = [e["args"]["rows"] for e in eng.chrome_trace()["traceEvents"]
+            if e["name"] == "decode.dispatch"]
+    assert rows == [mla_moe.walk_rows(p, a, 128, 8) // 4 for p, a in seen]
+
+
+def test_gpt_counts_the_rows_it_counted():
+    """``GPTModel`` leaves ``decode_rows`` out: every slot as far as
+    the longest window, the numbers the parent's engine read for the
+    same requests (recorded there)."""
+    paddle.seed(0)
+    model = GPTModel.from_config("tiny", max_position=1024, dropout=0.0)
+    model.eval()
+    rule = model.serving_spec().decode_rows
+    assert rule(np.asarray([5, 0, 300]), 2, 2048, 16) == 3 * 512
+    assert rule(np.asarray([5, 0, 300]), 2, 2048, None) == 3 * 512
+    eng = Engine(model, num_slots=3, max_seq_len=1024, kv_block_size=8,
+                 kv_blocks=160, prefill_chunk=64,
+                 registry=monitor.StatRegistry())
+    rng = np.random.default_rng(7)
+    for k in (37, 300, 520, 20):
+        eng.submit(rng.integers(1, 128, k).tolist(), max_new_tokens=6)
+    eng.run_until_idle()
+    walked, live, table = _decode_rows(eng)
+    assert (walked, table) == (23808, 46080)      # the parent's run
+    assert 0 < live < walked
 
 
 def test_the_form_is_chosen_from_the_shape():

@@ -267,7 +267,9 @@ def test_latent_pool_is_updated_in_place_on_the_v5e():
     of 128 + 64 / 128, rank 512, 32 slots, 8,192 positions), compiled
     for the compile-only ``TPU v5 lite`` device: with the row spec's
     lane padding (576 -> 640) the pool is a row-major parameter that
-    the step scatters into and walks, and no instruction copies it.
+    the step scatters into and walks, no instruction copies or
+    transposes it, and no gather fetches more of it than one trip's
+    group of (slot, chunk) items.
     (Unpadded, the TPU runtime lays a ``[blocks, 16, 576]`` array out
     with the BLOCK axis minor and every step transposed it there and
     back: 264 MB a layer; chip run, PR 28.)  The grouped expert
@@ -279,7 +281,8 @@ def test_latent_pool_is_updated_in_place_on_the_v5e():
     from paddle_tpu import nn
     from paddle_tpu.distributed.moe import grouped_matmul
     from paddle_tpu.jit import _swapped
-    from paddle_tpu.models.mla_moe import MLAttention
+    from paddle_tpu.models.mla_moe import (
+        MLAttention, walk_chunk, walk_group)
     from paddle_tpu.serving.kvcache import KVRowSpec
 
     one = SingleDeviceSharding(topologies.get_topology_desc(
@@ -321,8 +324,17 @@ def test_latent_pool_is_updated_in_place_on_the_v5e():
         compilation_cache.reset_cache()
     shape = "bf16[14337,16,640]"
     assert shape in text
-    copies = re.compile(r"= " + re.escape(shape) + r"\S* copy\(")
+    copies = re.compile(r"= " + re.escape(shape)
+                        + r"\S* (copy|transpose)\(")
     assert not [ln for ln in text.splitlines() if copies.search(ln)]
+    # the walk is a work list (PR 30): no fetch of cached rows is wider
+    # than one trip's group of items, 32 x 256 rows of the pool's width
+    rows = walk_group(32) * walk_chunk(8192, 16)
+    fetched = [int(np.prod([int(d) for d in dims.split(",")]))
+               for dims in re.findall(
+                   r"= bf16\[([\d,]+)\]\S* gather\(", text)]
+    assert fetched and max(fetched) == rows * 640
+    assert " while(" in text
 
 
 # -- knob validation --------------------------------------------------
