@@ -590,24 +590,6 @@ def main(argv=None):
         say(json.dumps(rep))
 
         if not meshes:
-
-            # the gather body is refused by Mosaic: off the cpu
-            # platform the engine must say so at construction
-            if dev.platform != "cpu":
-                from paddle_tpu.serving import Engine
-                try:
-                    Engine(model, num_slots=sz["num_slots"],
-                           max_seq_len=sz["max_seq_len"],
-                           kv_block_size=sz["block"],
-                           prefill_chunk=sz["chunk"],
-                           attn_impl="ragged_gather")
-                except ValueError as e:
-                    say("[serving-ragged] ragged_gather refused at "
-                        f"construction: {str(e)[:300]}")
-                else:
-                    raise Failed("Engine(attn_impl='ragged_gather') "
-                                 "was accepted off the cpu platform")
-
             rag_ids, rep = serving_phase(
                 "serving-ragged", model, sz, prompts, cache,
                 attn_impl="ragged")

@@ -35,14 +35,13 @@ verifies all 5 positions in ONE dispatch, and keeps the matching
 prefix plus the bonus token.  The per-tick printout shows 4-5 tokens
 landing per tick instead of 1, token-identical to the plain engine.
 
-Finally it demos SAMPLING MODES: ``sample_mode="device"`` (the
-default) fuses sampling into the jitted decode dispatch — per-slot
+Finally it demos ON-DEVICE SAMPLING: sampling is fused into the jitted
+decode dispatch — per-slot
 temperature/top_k/top_p as traced lanes, rng keys derived on device
 from the request seed + emitted-token counter — so a seeded top-p
 request emits identical tokens on two fresh engine instances, and a
-steady-state tick downloads [B] ids instead of the [B, V] logits
-(compare the printed serving.d2h_bytes_per_tick against
-``sample_mode="host"``'s legacy numpy path).
+steady-state tick downloads [B] ids, never the [B, V] logits
+(the printed serving.d2h_bytes_per_tick).
 
 Finally it demos TICK-LEVEL TRACING: every engine records phase spans
 (admission / prefill chunks / decode dispatch / d2h / emit),
@@ -275,18 +274,18 @@ def main():
           f"(plain engine: {n_spec_new} ticks); "
           f"acceptance rate {rate:.2f}")
 
-    # -- sampling modes: fused on-device sampling (the default) -------
-    # sample_mode="device" fuses sampling into the jitted decode tick:
+    # -- fused on-device sampling --------------------------------------
+    # sampling runs inside the jitted decode tick:
     # per-slot temperature/top_k/top_p ride as traced lanes, the rng
     # key derives on device from the request seed + emitted-token
     # counter, and a steady-state tick downloads only the [B] sampled
-    # ids instead of the [B, V] logits.  A SEEDED request therefore
+    # ids, never the [B, V] logits.  A SEEDED request therefore
     # emits the same tokens on ANY engine instance — run it twice on
     # two fresh engines and compare
     runs, d2h_dev = [], 0
     for _ in range(2):
         reg = monitor.StatRegistry()
-        eng = Engine(model, num_slots=4, registry=reg)  # device default
+        eng = Engine(model, num_slots=4, registry=reg)
         req = eng.submit(prompts[0], max_new_tokens=12,
                          temperature=0.9, top_p=0.9, seed=1234)
         eng.run_until_idle()
@@ -294,19 +293,11 @@ def main():
         d2h_dev = int(reg.get("serving.d2h_bytes_per_tick").value)
     assert runs[0] == runs[1], \
         "seeded device sampling must reproduce across engine instances"
-    reg = monitor.StatRegistry()
-    host_eng = Engine(model, num_slots=4, registry=reg,
-                      sample_mode="host")  # legacy numpy sampling
-    host_eng.submit(prompts[0], max_new_tokens=12, temperature=0.9,
-                    top_p=0.9, seed=1234)
-    host_eng.run_until_idle()
-    d2h_host = int(reg.get("serving.d2h_bytes_per_tick").value)
-    print(f"\nfused on-device sampling (sample_mode='device', the "
-          f"default):")
+    print(f"\nfused on-device sampling:")
     print(f"  seeded top-p request on two fresh engines -> identical "
           f"tokens: {runs[0]}")
-    print(f"  d2h bytes per decode tick: host {d2h_host} "
-          f"([B, V] logits) vs device {d2h_dev} ([B] ids)")
+    print(f"  d2h bytes per decode tick: {d2h_dev} ([B] ids + the "
+          f"done mask; the [B, V] logits never leave the device)")
 
     # -- tracing + flight recorder: where did the tick's time go? -----
     # every engine keeps a bounded per-thread ring of phase spans

@@ -2,7 +2,7 @@
 
 ``serving_engine.py`` scales ONE engine up; this demo scales OUT: a
 ``serving.Router`` spreads traffic over two local engine replicas
-(``InProcessReplica`` — the same transport tier-1 tests and the bench
+(``InProcessReplica`` — the same transport the tier-1 tests
 use), probing health, routing by PREFIX AFFINITY (the first
 kv_block_size-aligned span of the prompt is rendezvous-hashed, so
 every request sharing the system prompt lands on the replica whose

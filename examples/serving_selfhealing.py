@@ -20,9 +20,7 @@ ever pays for it:
    ``tools/trace_view.py --wall`` breaks them out.
 
 The real-process twin (spawned fleet + kill storm) lives in
-``tests/test_supervisor.py`` (slow lane) and ``bench.py --only
-serving_supervisor`` (BENCH_r16.json: supervised vs unsupervised
-recovery).
+``tests/test_supervisor.py`` (slow lane).
 
 Run: python examples/serving_selfhealing.py
 """

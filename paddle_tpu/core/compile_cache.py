@@ -1,7 +1,7 @@
 """Where JAX keeps compiled programs between processes.
 
 Every entry point that compiles (``chip_smoke.py``, ``serving/httpd.py``
-``main``, ``bench.py``'s child, ``tests/conftest.py``) calls
+``main``, ``benchmarks/run.py``, ``tests/conftest.py``) calls
 ``enable_compile_cache()`` once, before its first compile.
 """
 from __future__ import annotations

@@ -213,7 +213,7 @@ class GPTAttention(nn.Layer):
             # sharded dim.  The [b,s,3E]->[b,s,3,H,hd] reshape of the fused
             # layout forced XLA SPMD into "involuntary full
             # rematerialization" (it cannot re-tile an E split into an H
-            # split without replicating); see MULTICHIP_r01.json.
+            # split without replicating).
             from jax.sharding import PartitionSpec as P
             self.qkv_weight = self.create_parameter(
                 [hidden_size, 3, num_heads, self.head_dim], attr=init)
@@ -605,8 +605,7 @@ class GPTAttention(nn.Layer):
 
     @_scoped("attention")
     def ragged_window_paged(self, x, k_pool, v_pool, block_tables, pos,
-                            width, scratch=None, sharded=False,
-                            variant="stream"):
+                            width, scratch=None, sharded=False):
         """RAGGED paged window — the Pallas-kernel twin of the three
         paged window shapes (``decode_slots_paged`` S=1,
         ``verify_slots_paged`` S=k+1, ``prefill_chunk_paged`` S=C):
@@ -626,13 +625,10 @@ class GPTAttention(nn.Layer):
         row is per-slot DATA because each dp shard reserves its own
         scratch block — a masked lane may never write another shard's
         rows.  Valid lanes write exactly what their XLA twin writes.
-        ``variant`` picks the kernel body: ``"stream"`` (default,
-        ``attn_impl="ragged"``) runs the flash-style online-softmax
-        block loop — O(block_size x W) working set, allclose to
-        ``_slot_attn`` with greedy streams token-identical
-        end-to-end; ``"gather"`` (``attn_impl="ragged_gather"``)
-        materializes the whole row and stays bitwise-equal to the XLA
-        path on CPU (asserted in tests/test_ragged_attn.py).
+        The kernel is the flash-style online-softmax block loop —
+        O(block_size x W) working set, allclose to ``_slot_attn`` with
+        greedy streams token-identical end-to-end (asserted in
+        tests/test_ragged_attn.py).
         ``sharded=True`` (a 2-D mp x dp serving mesh) routes the
         kernel through ``sharded_ragged_paged_attention`` — the
         hand-written shard_map partitioning GSPMD cannot derive for
@@ -684,8 +680,7 @@ class GPTAttention(nn.Layer):
                 qa, k_pool.codes.reshape(NB * bs, H, hd),
                 v_pool.codes.reshape(NB * bs, H, hd),
                 block_tables, pos, width, block_size=bs,
-                k_scale=k_pool.scale, v_scale=v_pool.scale,
-                variant=variant)
+                k_scale=k_pool.scale, v_scale=v_pool.scale)
             new_k, new_v = k_pool, v_pool
         else:
             flat_k = k_pool.reshape(NB * bs, H, hd)
@@ -698,8 +693,7 @@ class GPTAttention(nn.Layer):
                     else ragged_paged_attention)
             ctx = attn(qa, flat_k, flat_v,
                        block_tables, pos, width,
-                       block_size=bs,
-                       variant=variant)
+                       block_size=bs)
             new_k = flat_k.reshape(k_pool.shape)
             new_v = flat_v.reshape(v_pool.shape)
         out = Tensor(ctx)
@@ -959,12 +953,11 @@ class GPTBlock(nn.Layer):
         return x, k_pool, v_pool
 
     def ragged_window_paged(self, x, k_pool, v_pool, block_tables, pos,
-                            width, scratch=None, sharded=False,
-                            variant="stream"):
+                            width, scratch=None, sharded=False):
         """Ragged Pallas window (GPTAttention.ragged_window_paged)."""
         attn_out, k_pool, v_pool = self.attn.ragged_window_paged(
             self.ln1(x), k_pool, v_pool, block_tables, pos, width,
-            scratch=scratch, sharded=sharded, variant=variant)
+            scratch=scratch, sharded=sharded)
         x = x + attn_out
         x = x + self.mlp(self.ln2(x))
         return x, k_pool, v_pool
@@ -1044,10 +1037,10 @@ class GPTModel(ServedModel, nn.Layer):
                  use_sp=False, fused_loss_chunk=128, scan_layers=False,
                  attn_impl="xla"):
         super().__init__()
-        if attn_impl not in ("xla", "ragged", "ragged_gather"):
+        if attn_impl not in ("xla", "ragged"):
             raise ValueError(
-                f"attn_impl must be 'xla', 'ragged' or "
-                f"'ragged_gather', got {attn_impl!r}")
+                f"attn_impl must be 'xla' or 'ragged', "
+                f"got {attn_impl!r}")
         # serving-kernel selection default: 'xla' keeps the paged
         # gather/scatter dispatches (the CPU tier-1 parity oracle);
         # 'ragged' routes the paged decode / spec-verify / chunked-
@@ -1056,10 +1049,7 @@ class GPTModel(ServedModel, nn.Layer):
         # window widths as data, ONE compiled program for every paged
         # window shape — in its flash-style online-softmax STREAMING
         # form (O(block_size x window) working set, long-context
-        # first-class); 'ragged_gather' keeps the materialize-the-row
-        # kernel body (bitwise vs the XLA oracle, O(context) working
-        # set) as the A/B reference.  Engine(attn_impl=...) overrides
-        # per engine.
+        # first-class).  Engine(attn_impl=...) overrides per engine.
         self.attn_impl = attn_impl
         # decode-twin reconstruction needs the dense hyperparams
         # (scan_layers forbids mp/sp/moe, so these suffice)
@@ -1315,7 +1305,7 @@ class GPTModel(ServedModel, nn.Layer):
         step state — so a steady-state engine tick uploads nothing and
         downloads only the [B] sampled ids instead of the [B, V]
         logits matrix.  ``temperature == 0`` lanes are greedy (raw
-        argmax, bit-identical to the host path on the same logits).
+        argmax of the lane's logits).
 
         DEVICE-SIDE STOP CONDITION (the async engine loop's safety
         contract): ``eos`` [B] int32 (-1 = none) and ``rem`` [B] int32
@@ -1427,7 +1417,7 @@ class GPTModel(ServedModel, nn.Layer):
     def _ragged_window_tick_slots(self, toks, k_pools, v_pools,
                                   block_tables, pos, width,
                                   scratch=None, sharded=False,
-                                  head_lanes=None, variant="stream"):
+                                  head_lanes=None):
         """RAGGED window forward over the paged slot pool: run each
         slot's ``width[b]`` real window tokens (of the static maximum
         W) at positions ``pos[b]..`` through every block's
@@ -1455,7 +1445,7 @@ class GPTModel(ServedModel, nn.Layer):
         for j, blk in enumerate(self.blocks):
             x, kb, vb = blk.ragged_window_paged(
                 x, k_pools[j], v_pools[j], block_tables, pos, width,
-                scratch=scratch, sharded=sharded, variant=variant)
+                scratch=scratch, sharded=sharded)
             new_k.append(kb)
             new_v.append(vb)
         if head_lanes is not None:
@@ -1467,8 +1457,7 @@ class GPTModel(ServedModel, nn.Layer):
                                  block_tables, width, mode, lanes, tok,
                                  pos, temp, top_k, top_p, seed_lo,
                                  seed_hi, ctr, eos, rem, scratch=None,
-                                 sharded=False, emit_w=None,
-                                 variant="stream"):
+                                 sharded=False, emit_w=None):
         """FUSED ragged window + on-device sample / accept-scan /
         stop-condition epilogue — the ONE program that replaces the
         fused decode, fused spec-verify, AND paged chunk-prefill
@@ -1529,7 +1518,7 @@ class GPTModel(ServedModel, nn.Layer):
         logits, new_k, new_v = self._ragged_window_tick_slots(
             window, k_pools, v_pools, block_tables, pos, width,
             scratch=scratch, sharded=sharded,
-            head_lanes=head_lanes, variant=variant)    # [B, E+1, V]
+            head_lanes=head_lanes)                     # [B, E+1, V]
         L = block_tables.shape[1] * k_pools[0].shape[1]
         picks = jnp.stack(
             [self._sample_lanes(
@@ -1544,12 +1533,10 @@ class GPTModel(ServedModel, nn.Layer):
         # default PRNG a vmapped categorical's bits depend on the
         # WHOLE key batch, and the XLA oracle's first-token pick
         # (``sample_rows``) is a B=1 draw — this reproduces the draw
-        # MECHANISM bit-for-bit, which keeps seeded ragged streams
-        # token-identical to the XLA arm under variant="gather"
-        # (bitwise logits); the streaming variant's online softmax
-        # reorders float summation, so its seeded guarantee is
-        # determinism (same seed => same stream), with greedy streams
-        # still token-identical.  Behind a lax.cond: ticks
+        # MECHANISM bit-for-bit.  The kernel's online softmax
+        # reorders float summation, so the seeded guarantee against
+        # the XLA arm is determinism (same seed => same stream), with
+        # greedy streams token-identical.  Behind a lax.cond: ticks
         # without a final-chunk lane (the steady state) skip the
         # per-slot scan entirely.
         import jax
@@ -1618,8 +1605,7 @@ class GPTModel(ServedModel, nn.Layer):
                 new_rem, new_k, new_v)
 
     def _compiled_ragged_window_fn(self, pnames, params, cache_key,
-                                   emit_w=None, variant="stream",
-                                   sharded=False):
+                                   emit_w=None, sharded=False):
         """Build (or fetch) the jitted FUSED RAGGED WINDOW dispatch
         (``Engine(attn_impl="ragged")``): (p_list, b_list, k_pools,
         v_pools, block_tables [B, L//bs], scratch [B], toks [B, W],
@@ -1640,19 +1626,18 @@ class GPTModel(ServedModel, nn.Layer):
         so the (layout, chunk shape, spec_k) compile matrix collapses
         to this ONE program per engine config (compile-probe kind
         ``ragged_window``; asserted by the compile-matrix regression
-        test and the serving_ragged bench).  Pools donated."""
+        test).  Pools donated."""
         import jax
         from ..core import autograd
         from ..jit import _swapped
 
-        # emit_w, the kernel variant, and the sharded lowering are
-        # baked into the compiled program (emit_w fixes the picks
-        # lane count; variant picks the stream vs gather kernel body;
-        # sharded picks shard_map vs plain pallas_call), so they MUST
-        # distinguish cache entries — enforced here rather than
-        # trusted to every caller's key
+        # emit_w and the sharded lowering are baked into the compiled
+        # program (emit_w fixes the picks lane count; sharded picks
+        # shard_map vs plain pallas_call), so they MUST distinguish
+        # cache entries — enforced here rather than trusted to every
+        # caller's key
         cache_key = (cache_key, None if emit_w is None else int(emit_w),
-                     str(variant), bool(sharded))
+                     bool(sharded))
         cache = getattr(self, "_ragged_window_fn_cache", None)
         if cache is None:
             cache = self._ragged_window_fn_cache = {}
@@ -1675,7 +1660,7 @@ class GPTModel(ServedModel, nn.Layer):
                         mode, lanes, tok, pos, temp, top_k, top_p,
                         seed_lo, seed_hi, ctr, eos, rem,
                         scratch=scratch, sharded=sharded,
-                        emit_w=emit_w, variant=variant)
+                        emit_w=emit_w)
             return out
 
         fn = _jit_named("ragged_window", pure, donate_argnums=(2, 3))
@@ -1688,8 +1673,8 @@ class GPTModel(ServedModel, nn.Layer):
     # -- compile-event hook (serving observability) --------------------
     def _compiled_fused_decode_fn(self, pnames, params, cache_key,
                                   paged=False):
-        """Build (or fetch) the jitted FUSED decode+sample tick for
-        ``Engine(sample_mode="device")``: contiguous layout (p_list,
+        """Build (or fetch) the jitted FUSED decode+sample tick of the
+        serving engine: contiguous layout (p_list,
         b_list, k_pools, v_pools, tok [B,1], pos [B], temp [B],
         top_k [B], top_p [B], seed_lo [B], seed_hi [B], ctr [B],
         eos [B], rem [B]) or paged layout (+ block_tables [B, L//bs]
@@ -1752,19 +1737,24 @@ class GPTModel(ServedModel, nn.Layer):
     def _compiled_fused_spec_verify_fn(self, pnames, params, cache_key,
                                        paged=False):
         """Build (or fetch) the jitted FUSED speculative verify +
-        on-device sample/accept dispatch (``Engine(spec_k=...,
-        sample_mode="device")``): contiguous layout (p_list, b_list,
+        on-device sample/accept dispatch (``Engine(spec_k=...)``):
+        contiguous layout (p_list, b_list,
         k_pools, v_pools, toks [B, W], lanes [B], pos [B], temp [B],
         top_k [B], top_p [B], seed_lo [B], seed_hi [B], ctr [B],
         eos [B], rem [B]) or paged layout (+ block_tables before
         toks) -> (picks [B, W], n_acc [B], n_emit [B], done
         [ceil(B/8)] uint8, new_tok [B,1], new_pos [B], new_ctr [B],
         new_rem [B], k_pools, v_pools).  ONE XLA program per
-        (window, layout) exactly like
-        ``_compiled_spec_verify_fn`` — the draft window still uploads
-        (drafts come from the host proposer) but the [B, W, V] logits
-        download is replaced by picks + accept counts.  Pools
-        donated."""
+        (window, layout) — W and the pool shapes are static, per-slot
+        positions and block tables are runtime inputs, so a fixed
+        ``spec_k`` means exactly one compile per layout however
+        traffic varies (compile-probe asserted in
+        tests/test_serving.py).  Both layouts score the window through
+        the same ``_slot_attn`` as their one-token decode twins, which
+        is what makes speculative greedy outputs token-identical to
+        the non-speculative engine.  The draft window uploads (drafts
+        come from the host proposer); the download is picks + accept
+        counts, never the [B, W, V] logits.  Pools donated."""
         import jax
         from ..core import autograd
         from ..jit import _swapped
@@ -1809,64 +1799,6 @@ class GPTModel(ServedModel, nn.Layer):
             cache.pop(next(iter(cache)))
         cache[cache_key] = (self._compile_probe(
             "fused_spec_verify", cache_key, fn), bnames, mbuffers)
-        return cache[cache_key]
-
-    def _compiled_spec_verify_fn(self, pnames, params, cache_key,
-                                 paged=False):
-        """Build (or fetch) the jitted SPECULATIVE VERIFY dispatch for
-        the serving engine (serving/spec.py): contiguous layout
-        (p_list, b_list, k_pools, v_pools, toks [B, W], pos [B]) or
-        paged layout (p_list, b_list, k_pools, v_pools, block_tables
-        [B, L//bs], toks [B, W], pos [B]) -> (logits [B, W, V],
-        k_pools, v_pools).  ONE XLA program per (window, layout) —
-        W and the pool shapes are static, per-slot positions and block
-        tables are runtime inputs, so a fixed ``spec_k`` means exactly
-        one compile per layout however traffic varies (compile-probe
-        asserted in tests/test_serving.py, like the chunk-prefill
-        programs).  Both layouts score the window through the same
-        ``_slot_attn`` as their one-token decode twins, which is what
-        makes speculative greedy outputs token-identical to the
-        non-speculative engine.  Pools donated."""
-        import jax
-        from ..core import autograd
-        from ..jit import _swapped
-
-        cache = getattr(self, "_spec_verify_fn_cache", None)
-        if cache is None:
-            cache = self._spec_verify_fn_cache = {}
-        if cache_key in cache:
-            return cache[cache_key]
-
-        model = self
-        mbuffers = dict(self.named_buffers())
-        bnames = sorted(mbuffers)
-
-        if paged:
-            def pure(p_list, b_list, k_pools, v_pools, block_tables,
-                     toks, pos):
-                with _swapped(params, dict(zip(pnames, p_list))), \
-                        _swapped(mbuffers, dict(zip(bnames, b_list))):
-                    with autograd.no_grad():
-                        last, new_k, new_v = \
-                            model._spec_verify_tick_slots_paged(
-                                toks, k_pools, v_pools, block_tables,
-                                pos)
-                return last, new_k, new_v
-        else:
-            def pure(p_list, b_list, k_pools, v_pools, toks, pos):
-                with _swapped(params, dict(zip(pnames, p_list))), \
-                        _swapped(mbuffers, dict(zip(bnames, b_list))):
-                    with autograd.no_grad():
-                        last, new_k, new_v = \
-                            model._spec_verify_tick_slots(
-                                toks, k_pools, v_pools, pos)
-                return last, new_k, new_v
-
-        fn = _jit_named("spec_verify", pure, donate_argnums=(2, 3))
-        if len(cache) >= 8:  # FIFO bound, matching the other caches
-            cache.pop(next(iter(cache)))
-        cache[cache_key] = (self._compile_probe(
-            "spec_verify", cache_key, fn), bnames, mbuffers)
         return cache[cache_key]
 
     def _chunk_prefill_tick(self, toks, k_bufs, v_bufs, pos, true_len):
@@ -1925,12 +1857,13 @@ class GPTModel(ServedModel, nn.Layer):
         chunk runs through ``_chunk_prefill_tick``, and the updated row
         is written back — ONE program per fixed chunk shape serves
         EVERY chunk of EVERY prompt (slot_idx/pos/true_len are traced),
-        so a fixed ``prefill_chunk`` means a bounded compile set, like
-        ``prefill_buckets``.  Pad lanes of a partial final chunk write
-        garbage rows past the prompt end — parity-safe for the bucketed
-        -prefill reason (decode overwrites each before any query can
-        see it), and the engine requires C | L so the window never
-        clamps onto live rows.  Pools donated."""
+        so a fixed ``prefill_chunk`` means a bounded compile set.  Pad
+        lanes of a partial final chunk write garbage rows past the
+        prompt end — parity-safe under the causal mask (positions <
+        true_len never see the pad tail, and decode overwrites each
+        garbage row before any query can see it), and the engine
+        requires C | L so the window never clamps onto live rows.
+        Pools donated."""
         import jax
         from ..core import autograd
         from ..jit import _swapped
@@ -2019,45 +1952,6 @@ class GPTModel(ServedModel, nn.Layer):
             "paged_chunk_prefill", cache_key, fn), bnames, mbuffers)
         return cache[cache_key]
 
-    def _compiled_slot_paged_decode_fn(self, pnames, params, cache_key):
-        """Build (or fetch) the jitted PAGED slot-pool decode step:
-        (p_list, b_list, k_pools, v_pools, block_tables [B, L//bs],
-        tok [B,1], pos [B]) -> (last_logits [B,V], k_pools, v_pools).
-        The block-table twin of ``_compiled_slot_decode_fn``: the K/V
-        pools are [NB, bs, H, hd] blocks shared across slots, and ONE
-        XLA program still serves every tick — block tables are runtime
-        int32 inputs, not program constants.  Pools donated (in-place
-        update, no per-tick copy)."""
-        import jax
-        from ..core import autograd
-        from ..jit import _swapped
-
-        cache = getattr(self, "_slot_paged_decode_fn_cache", None)
-        if cache is None:
-            cache = self._slot_paged_decode_fn_cache = {}
-        if cache_key in cache:
-            return cache[cache_key]
-
-        model = self
-        mbuffers = dict(self.named_buffers())
-        bnames = sorted(mbuffers)
-
-        def pure(p_list, b_list, k_pools, v_pools, block_tables, tok,
-                 pos):
-            with _swapped(params, dict(zip(pnames, p_list))), \
-                    _swapped(mbuffers, dict(zip(bnames, b_list))):
-                with autograd.no_grad():
-                    last, new_k, new_v = model._decode_tick_slots_paged(
-                        tok, k_pools, v_pools, block_tables, pos)
-            return last, new_k, new_v
-
-        fn = _jit_named("slot_paged_decode", pure, donate_argnums=(2, 3))
-        if len(cache) >= 8:  # FIFO bound, matching the other decode caches
-            cache.pop(next(iter(cache)))
-        cache[cache_key] = (self._compile_probe(
-            "slot_paged_decode", cache_key, fn), bnames, mbuffers)
-        return cache[cache_key]
-
     def _compiled_paged_prefill_fn(self, pnames, params, cache_key,
                                    s_tail, n_ctx, n_tail, bs, nh, hd,
                                    kv_dtype):
@@ -2072,8 +1966,8 @@ class GPTModel(ServedModel, nn.Layer):
         ``n_ctx = 0`` is the miss case — then this computes exactly
         what ``_compiled_prefill_fn`` computes (same forward, empty
         context), just stored block-granular.  The pad rows of the last
-        (partial) tail block hold garbage that is parity-safe for the
-        same reason as bucketed prefill: the causal gather mask hides
+        (partial) tail block hold garbage that is parity-safe: the
+        causal gather mask hides
         positions > pos until decode overwrites them, and partial
         blocks are never registered in the prefix cache.  Pools
         donated."""
@@ -2143,45 +2037,6 @@ class GPTModel(ServedModel, nn.Layer):
             cache.pop(next(iter(cache)))
         cache[cache_key] = (self._compile_probe(
             "paged_prefill", cache_key, fn), bnames, mbuffers)
-        return cache[cache_key]
-
-    def _compiled_slot_decode_fn(self, pnames, params, cache_key):
-        """Build (or fetch) the jitted SLOT-POOL decode step: (p_list,
-        b_list, k_bufs, v_bufs, tok [B,1], pos [B]) -> (last_logits
-        [B,V], k_bufs, v_bufs).  The continuous-batching twin of
-        ``_compiled_decode_fn``: B is the fixed slot-pool size, each row
-        decodes at its own position, and ONE XLA program serves every
-        engine tick regardless of which slots are live (inactive rows
-        compute harmlessly into their own cache rows, which admission
-        prefill overwrites wholesale).  K/V pools are donated —
-        in-place update, no per-tick copy."""
-        import jax
-        from ..core import autograd
-        from ..jit import _swapped
-
-        cache = getattr(self, "_slot_decode_fn_cache", None)
-        if cache is None:
-            cache = self._slot_decode_fn_cache = {}
-        if cache_key in cache:
-            return cache[cache_key]
-
-        model = self
-        mbuffers = dict(self.named_buffers())
-        bnames = sorted(mbuffers)
-
-        def pure(p_list, b_list, k_bufs, v_bufs, tok, pos):
-            with _swapped(params, dict(zip(pnames, p_list))), \
-                    _swapped(mbuffers, dict(zip(bnames, b_list))):
-                with autograd.no_grad():
-                    last, new_k, new_v = model._decode_tick_slots(
-                        tok, k_bufs, v_bufs, pos)
-            return last, new_k, new_v
-
-        fn = _jit_named("slot_decode", pure, donate_argnums=(2, 3))
-        if len(cache) >= 8:  # FIFO bound, matching the other decode caches
-            cache.pop(next(iter(cache)))
-        cache[cache_key] = (self._compile_probe(
-            "slot_decode", cache_key, fn), bnames, mbuffers)
         return cache[cache_key]
 
     def _fused_generate_fn(self, pnames, params, cache_key, n_steps,
@@ -2474,61 +2329,6 @@ class GPTModel(ServedModel, nn.Layer):
             cache.pop(next(iter(cache)))
         cache[cache_key] = (self._compile_probe(
             "prefill", cache_key, fn), bnames, mbuffers)
-        return cache[cache_key]
-
-    def _compiled_bucket_prefill_fn(self, pnames, params, cache_key, b,
-                                    S, L, nh, hd, kv_dtype):
-        """Build (or fetch) the jitted BUCKETED prefill: (p_list,
-        b_list, ids [B, S], true_len) -> (last_logits [B, V] at
-        position true_len-1, k_bufs, v_bufs padded to L).  The serving
-        engine's compile-bound variant of ``_compiled_prefill_fn``:
-        prompts are right-padded up to bucket length S, so one XLA
-        program serves EVERY prompt length in the bucket (true_len is a
-        traced scalar).  Right padding is parity-safe under the causal
-        mask — positions < true_len never see the pad tail, and the
-        garbage cache rows past true_len are each overwritten by decode
-        before any query can attend to them."""
-        import jax
-        import jax.numpy as jnp
-        from ..core import autograd
-        from ..jit import _swapped
-
-        cache = getattr(self, "_bucket_prefill_fn_cache", None)
-        if cache is None:
-            cache = self._bucket_prefill_fn_cache = {}
-        if cache_key in cache:
-            return cache[cache_key]
-
-        model = self
-        mbuffers = dict(self.named_buffers())
-        bnames = sorted(mbuffers)
-
-        def pure(p_list, b_list, ids_arr, true_len, *lora):
-            with _swapped(params, dict(zip(pnames, p_list))), \
-                    _swapped(mbuffers, dict(zip(bnames, b_list))):
-                with autograd.no_grad(), _lora_scope(lora):
-                    empty = [(Tensor(jnp.zeros((b, 0, nh, hd),
-                                               kv_dtype)),
-                              Tensor(jnp.zeros((b, 0, nh, hd),
-                                               kv_dtype)))
-                             for _ in model.blocks]
-                    logits, caches = model.forward(Tensor(ids_arr),
-                                                   caches=empty)
-                    pad = ((0, 0), (0, L - S), (0, 0), (0, 0))
-                    k_bufs = [jnp.pad(ck._data, pad) for ck, _ in caches]
-                    v_bufs = [jnp.pad(cv._data, pad) for _, cv in caches]
-                    # the real prompt's last logits, not the pad tail's
-                    V = logits._data.shape[-1]
-                    last = jax.lax.dynamic_slice(
-                        logits._data, (0, true_len - 1, 0),
-                        (b, 1, V))[:, 0]
-            return last, k_bufs, v_bufs
-
-        fn = _jit_named("bucket_prefill", pure)
-        if len(cache) >= 8:  # FIFO bound, matching _prefill_fn_cache
-            cache.pop(next(iter(cache)))
-        cache[cache_key] = (self._compile_probe(
-            "bucket_prefill", cache_key, fn), bnames, mbuffers)
         return cache[cache_key]
 
     def _compiled_decode_fn(self, pnames, params, cache_key):

@@ -802,8 +802,6 @@ class MLAMoEModel(ServedModel, nn.Layer):
                               "and its decode / prefill programs",
                 "unchunked_prefill": "the per-length paged prefill "
                                      "program over latent rows",
-                "host_sampling": "the unfused per-step decode program "
-                                 "that returns logits",
                 "ragged": "a ragged paged-attention kernel over latent "
                           "rows (ops/ragged_paged_attn.py takes "
                           "[H, hd] K and V)",

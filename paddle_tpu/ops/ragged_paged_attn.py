@@ -22,46 +22,32 @@ block tables become kernel *data* instead of trace-time *shape* —
   never read) — so mixed prefill-chunk + decode + spec traffic shares
   ONE program whose static width is just the engine's maximum.
 
-STREAMING (``variant="stream"``, the default): a flash-style
-ONLINE-SOFTMAX loop.  K/V are consumed one paged block at a time
-inside a ``fori_loop`` over the slot's LIVE blocks (the loop stops at
-the causal horizon ``ceil((pos + width) / block_size)``, so a decode
-tick touches only the blocks that actually hold history), carrying a
-per-(head, lane) running max ``m``, normalizer ``l``, and an output
-accumulator ``acc`` rescaled by ``exp(m_old - m_new)`` per block —
-the standard flash-attention recurrence.  The per-slot working set is
-therefore **O(block_size x window)** — one K block, one V block, one
+STREAMING: the body is a flash-style ONLINE-SOFTMAX loop.  K/V are
+consumed one paged block at a time inside a ``fori_loop`` over the
+slot's LIVE blocks (the loop stops at the causal horizon
+``ceil((pos + width) / block_size)``, so a decode tick touches only
+the blocks that actually hold history), carrying a per-(head, lane)
+running max ``m``, normalizer ``l``, and an output accumulator ``acc``
+rescaled by ``exp(m_old - m_new)`` per block — the standard
+flash-attention recurrence.  The per-slot working set is therefore
+**O(block_size x window)** — one K block, one V block, one
 [H, W, block_size] score tile, and the [W, H, hd] accumulator —
-*independent of context length*, where the gather variant's is
-O(context_len): multi-thousand-token contexts stop being VMEM-bounded
-and the compiled program stays O(1) in size (the gather variant
-unrolls a Python loop over ``L // block_size`` table entries, so its
-trace/compile cost — and its concatenated [L, H, hd] row — grow
-linearly with the context ceiling).
-
-GATHER (``variant="gather"``, kept behind ``attn_impl=
-"ragged_gather"`` for A/B): the original form — materialize the whole
-logical [L, H, hd] row, then one monolithic f32 score -> -1e30 mask ->
-softmax -> value contraction, BITWISE-equal to the XLA oracle
-(``GPTAttention._slot_attn`` in its one-shot form, a table of at most
-one chunk) on CPU.
+*independent of context length*: multi-thousand-token contexts are not
+VMEM-bounded and the compiled program stays O(1) in size.
 
 NUMERICS CONTRACT: online softmax reorders float summation (block-
 sequential accumulation instead of one reduction over L), so the
-streaming kernel is **allclose** to the XLA oracle — not bitwise —
-and the engine-level guarantee shifts accordingly: greedy streams are
-asserted TOKEN-IDENTICAL to the XLA oracle end-to-end across the full
-layout matrix (paged x plain/chunked/spec x depth 1+2 x int8 KV x
+kernel is **allclose** to the XLA oracle (``GPTAttention._slot_attn``)
+— not bitwise — and the engine-level guarantee follows: greedy streams
+are asserted TOKEN-IDENTICAL to the XLA oracle end-to-end across the
+full layout matrix (paged x plain/chunked/spec x depth 1+2 x int8 KV x
 adapter lanes; tests/test_ragged_attn.py), while seeded streams are
-asserted deterministic (same seed => same stream) and are bitwise
-arm-identical only under ``variant="gather"``.  Both variants share
-the masking rule, the int8 per-block scales, and the callers' LoRA
-bank plumbing.
+asserted deterministic (same seed => same stream).
 
-WHERE EACH BODY RUNS.  Interpret mode is chosen on the ``cpu``
+WHERE IT RUNS.  Interpret mode is chosen on the ``cpu``
 platform only (``_auto_interpret``); on every other backend the
 kernel goes through Mosaic and either compiles or raises — there is
-no fallback.  The STREAMING body is written for Mosaic: ``pos``,
+no fallback.  The body is written for Mosaic: ``pos``,
 ``width`` and the block tables are scalar-prefetched to SMEM
 (``PrefetchScalarGridSpec``), both pools stay in HBM
 (``memory_space=ANY``), and each loop step DMAs ONE K block and ONE V
@@ -73,12 +59,6 @@ int8 pools, H in {4, 8, 16}, block sizes 8-32 and windows up to 512
 (``compile_check``; tests/test_ragged_attn.py pins it through the
 compile-only topology).  It is a correctness-level body: one page per
 step, no double buffering, f32 contractions — tuning is ROADMAP S2.
-The GATHER body still hands whole pools to each program instance as
-VMEM blocks and reads its scalars from VMEM; Mosaic refuses it
-("cannot statically prove that index in dimension 0 is a multiple of
-128") and it is not repaired — it serves the CPU A/B only, and
-``Engine(attn_impl="ragged_gather")`` raises at construction on any
-other backend with the compiler's message.
 
 K/V WRITES stay outside the kernel (the callers' width-masked scatter
 — see ``GPTAttention.ragged_window_paged``): lanes past ``width[b]``
@@ -106,8 +86,6 @@ from __future__ import annotations
 
 import math
 
-VARIANTS = ("stream", "gather")
-
 
 def _auto_interpret(platform=None):
     """Pallas interpret mode on the ``cpu`` platform only (tier-1 runs
@@ -119,31 +97,6 @@ def _auto_interpret(platform=None):
         import jax
         platform = jax.default_backend()
     return platform == "cpu"
-
-
-def kernel_working_set_bytes(*, variant, block_size, blocks_per_slot,
-                             width, num_heads, head_dim):
-    """Analytic per-slot VMEM working-set proxy of one kernel instance
-    (f32 compute bytes of the live K/V tiles + score tile + carry; the
-    serving_longctx bench records it against context length).  The
-    streaming variant is FLAT in ``blocks_per_slot`` — its K/V tile is
-    one block and its carry is the [W, H, hd] accumulator — while the
-    gather variant's whole logical row and [H, W, L] score matrix grow
-    linearly with the context ceiling."""
-    if variant not in VARIANTS:
-        raise ValueError(f"variant must be one of {VARIANTS}, "
-                         f"got {variant!r}")
-    bs, nb, W = int(block_size), int(blocks_per_slot), int(width)
-    H, hd = int(num_heads), int(head_dim)
-    q = W * H * hd * 4
-    if variant == "gather":
-        kv = 2 * nb * bs * H * hd * 4      # the full gathered row, x2
-        scores = H * W * nb * bs * 4       # [H, W, L] score/prob tile
-        return q + kv + scores + W * H * hd * 4
-    kv = 2 * bs * H * hd * 4               # ONE K block + ONE V block
-    scores = H * W * bs * 4                # [H, W, block_size] tile
-    carry = 2 * H * W * 4 + W * H * hd * 4  # m, l + accumulator
-    return q + kv + scores + carry
 
 
 def _stream_impl(q, k_flat, v_flat, block_tables, pos, width,
@@ -284,102 +237,9 @@ def _stream_impl(q, k_flat, v_flat, block_tables, pos, width,
     return jnp.swapaxes(out[:, :, :W], 1, 2)
 
 
-def _gather_impl(q, k_flat, v_flat, block_tables, pos, width,
-                 block_size, interpret, k_scale=None, v_scale=None):
-    """Gather-then-softmax kernel (``attn_impl="ragged_gather"``):
-    materialize the full logical row, one monolithic softmax —
-    bitwise-equal to the XLA oracle, O(context_len) working set."""
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    B, W, H, hd = q.shape
-    nb = block_tables.shape[1]
-    bs = block_size
-    L = nb * bs
-    scale = 1.0 / math.sqrt(hd)
-    quant = k_scale is not None
-
-    def kernel(tables_ref, pos_ref, width_ref, q_ref, k_ref, v_ref,
-               *rest):
-        if quant:
-            ks_ref, vs_ref, o_ref = rest
-        else:
-            (o_ref,) = rest
-        b = pl.program_id(0)
-        p = pos_ref[b]
-        w = width_ref[b]
-
-        def rows(pool_ref, scale_ref):
-            # kv-block loop: gather this slot's logical [L] row
-            # through its block table (physical block ids are runtime
-            # data; nb/bs are the only static shapes — note the
-            # UNROLLED Python loop: program size and trace time grow
-            # with nb, the gather variant's context-ceiling tax).
-            # Quantized pools dequantize PER GATHERED BLOCK.
-            parts = []
-            for j in range(nb):
-                blk = pool_ref[pl.ds(tables_ref[b, j] * bs, bs)]
-                if scale_ref is not None:
-                    s = scale_ref[pl.ds(tables_ref[b, j], 1)][0]  # [H]
-                    parts.append(blk.astype(jnp.float32)
-                                 * s[None, :, None])
-                else:
-                    parts.append(blk)
-            return jnp.concatenate(parts, axis=0)            # [L, H, hd]
-
-        k_rows = rows(k_ref, ks_ref if quant else None)
-        v_rows = rows(v_ref, vs_ref if quant else None)
-        qa = q_ref[0].astype(jnp.float32)                    # [W, H, hd]
-        # same contraction / mask / softmax as the XLA oracle
-        # (_slot_attn), per slot: scores [H, W, L] in f32
-        scores = jnp.einsum(
-            "qhd,khd->hqk", qa,
-            k_rows.astype(jnp.float32)) * scale
-        l_ids = jax.lax.broadcasted_iota(jnp.int32, (W, L), 1)
-        s_ids = jax.lax.broadcasted_iota(jnp.int32, (W, L), 0)
-        # query lane s sees cache positions <= pos + s — the slot's
-        # LENGTH does the masking, not a padded shape
-        visible = l_ids <= p + s_ids                         # [W, L]
-        scores = jnp.where(visible[None, :, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        ctx = jnp.einsum("hqk,khd->qhd", probs,
-                         v_rows.astype(jnp.float32))
-        # width as data: lanes past this slot's real window are zeroed
-        # (parked slots — width 0 — return all-zero, never-read lanes)
-        lane = jax.lax.broadcasted_iota(jnp.int32, (W, 1, 1), 0)
-        ctx = jnp.where(lane < w, ctx, 0.0)
-        o_ref[0] = ctx.astype(o_ref.dtype)
-
-    in_specs = [
-        pl.BlockSpec(block_tables.shape, lambda b: (0, 0)),
-        pl.BlockSpec(pos.shape, lambda b: (0,)),
-        pl.BlockSpec(width.shape, lambda b: (0,)),
-        pl.BlockSpec((1, W, H, hd), lambda b: (b, 0, 0, 0)),
-        pl.BlockSpec(k_flat.shape, lambda b: (0, 0, 0)),
-        pl.BlockSpec(v_flat.shape, lambda b: (0, 0, 0)),
-    ]
-    operands = [block_tables, pos, width, q, k_flat, v_flat]
-    if quant:
-        in_specs += [
-            pl.BlockSpec(k_scale.shape, lambda b: (0, 0)),
-            pl.BlockSpec(v_scale.shape, lambda b: (0, 0)),
-        ]
-        operands += [k_scale, v_scale]
-    return pl.pallas_call(
-        kernel,
-        grid=(B,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, W, H, hd), lambda b: (b, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, W, H, hd), q.dtype),
-        interpret=interpret,
-    )(*operands)
-
-
 def ragged_paged_attention(q, k_flat, v_flat, block_tables, pos, width,
                            *, block_size, interpret=None,
-                           k_scale=None, v_scale=None,
-                           variant="stream"):
+                           k_scale=None, v_scale=None):
     """Ragged paged attention over a slot pool (see module docstring).
 
     q : [B, W, H, hd] query window per slot (W = the engine's static
@@ -398,19 +258,10 @@ def ragged_paged_attention(q, k_flat, v_flat, block_tables, pos, width,
         block's scale row, adjacent to the contraction — so the
         logical K/V row never materializes outside VMEM and the whole
         pool is never dequantized.  Pass both or neither.
-    variant : ``"stream"`` (default) — flash-style online softmax,
-        O(block_size x W) working set, allclose to the oracle;
-        ``"gather"`` — materialize-the-row form, O(context_len)
-        working set, bitwise-equal to the oracle (the A/B reference
-        behind ``attn_impl="ragged_gather"``).
     Returns ctx [B, W, H, hd] in q's dtype.
     """
     import jax.numpy as jnp
 
-    if variant not in VARIANTS:
-        raise ValueError(
-            f"ragged_paged_attention: variant must be one of "
-            f"{VARIANTS}, got {variant!r}")
     if (k_scale is None) != (v_scale is None):
         raise ValueError(
             "ragged_paged_attention: pass both k_scale and v_scale "
@@ -420,8 +271,7 @@ def ragged_paged_attention(q, k_flat, v_flat, block_tables, pos, width,
     if k_scale is not None:
         k_scale = jnp.asarray(k_scale, jnp.float32)
         v_scale = jnp.asarray(v_scale, jnp.float32)
-    impl = _stream_impl if variant == "stream" else _gather_impl
-    return impl(
+    return _stream_impl(
         q, k_flat, v_flat,
         jnp.asarray(block_tables, jnp.int32),
         jnp.asarray(pos, jnp.int32), jnp.asarray(width, jnp.int32),
@@ -431,7 +281,7 @@ def ragged_paged_attention(q, k_flat, v_flat, block_tables, pos, width,
 
 def compile_check(*, num_slots, window, num_heads, head_dim,
                   block_size, blocks_per_slot, num_blocks, dtype,
-                  quant=False, variant="stream", device=None):
+                  quant=False, device=None):
     """Lower and compile the kernel through Mosaic at one shape,
     running nothing; raises the compiler's own error when it refuses.
 
@@ -441,7 +291,7 @@ def compile_check(*, num_slots, window, num_heads, head_dim,
     .devices[0]``), which is how a CPU-only sandbox iterates on
     Mosaic errors.  ``Engine`` calls this at construction on every
     non-``cpu`` backend, with its per-shard shapes, so an unsupported
-    shape or body fails there with the compiler's message instead of
+    shape fails there with the compiler's message instead of
     inside the first tick."""
     import jax
     import jax.numpy as jnp
@@ -465,7 +315,7 @@ def compile_check(*, num_slots, window, num_heads, head_dim,
         ks, vs = scales if scales else (None, None)
         return ragged_paged_attention(
             q, k, v, tables, pos, width, block_size=block_size,
-            interpret=False, k_scale=ks, v_scale=vs, variant=variant)
+            interpret=False, k_scale=ks, v_scale=vs)
 
     jax.jit(run).lower(*args).compile()
 
@@ -473,8 +323,7 @@ def compile_check(*, num_slots, window, num_heads, head_dim,
 def sharded_ragged_paged_attention(q, k_flat, v_flat, block_tables,
                                    pos, width, *, block_size,
                                    mesh=None, interpret=None,
-                                   k_scale=None, v_scale=None,
-                                   variant="stream"):
+                                   k_scale=None, v_scale=None):
     """``shard_map``-partitioned ragged paged attention over a 2-D
     ``(mp, dp)`` serving mesh (module docstring, SHARDED LOWERING).
 
@@ -546,8 +395,7 @@ def sharded_ragged_paged_attention(q, k_flat, v_flat, block_tables,
         ks, vs = scales if scales else (None, None)
         return ragged_paged_attention(
             q_l, k_l, v_l, local, pos_l, width_l, block_size=bs,
-            interpret=interpret, k_scale=ks, v_scale=vs,
-            variant=variant)
+            interpret=interpret, k_scale=ks, v_scale=vs)
 
     qspec = P("dp", None, "mp", None)
     kvspec = P("dp", "mp", None)

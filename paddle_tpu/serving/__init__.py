@@ -23,14 +23,12 @@ tokens per slot, ONE jitted verify dispatch scores all k+1 positions,
 and the engine keeps the longest argmax-matching prefix plus the
 bonus token: 1..k+1 tokens per dispatch, greedy outputs still
 token-identical to the non-speculative engine.
-``Engine(sample_mode="device")`` (the default) FUSES sampling into
-the jitted dispatches: per-slot temperature/top_k/top_p as traced
-lanes, rng keys derived on device from the request seed +
-emitted-token counter, device-resident step cursors — a steady-state
-tick uploads nothing and downloads only the sampled ids (+ accept
-counts under speculation) instead of the per-tick logits matrix;
-``sample_mode="host"`` keeps the legacy logits-download + numpy
-sampling numerics.  ``Engine(weight_dtype="int8")`` /
+Sampling is FUSED into the jitted dispatches: per-slot
+temperature/top_k/top_p as traced lanes, rng keys derived on device
+from the request seed + emitted-token counter, device-resident step
+cursors — a steady-state tick uploads nothing and downloads only the
+sampled ids (+ accept counts under speculation), never the per-tick
+logits matrix.  ``Engine(weight_dtype="int8")`` /
 ``Engine(kv_dtype="int8")`` add QUANTIZED serving (``serving.quant``):
 weight-only int8 codes ride the compiled hot paths as traced buffers,
 and the paged K/V pools store int8 codes with per-block per-head f32
@@ -41,7 +39,7 @@ migration wire (a ``kv_dtype``-mismatched peer raises
 ``KVDtypeMismatch`` instead of adopting garbage).  Metrics (queue depth, slot occupancy, tokens/sec,
 TTFT/TPOT, KV blocks in use, prefix hits/evictions, prefill chunks,
 decode stall, spec proposed/accepted/acceptance-rate/tokens-per-tick,
-d2h bytes per tick, host sample time, fused-sample ticks, compiles)
+d2h bytes per tick, fused-sample ticks, compiles)
 land in paddle_tpu.monitor and render via ``render_prometheus()``.
 Every engine also runs a tick-level span tracer (monitor/tracing.py:
 bounded per-thread rings, phase spans + request lifecycle instants +

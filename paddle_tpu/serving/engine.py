@@ -13,11 +13,9 @@ slots the moment they free up:
          one slot-batched decode dispatch          ->
          sample per live slot, evict on EOS/max_new_tokens
 
-Sampling runs ON DEVICE inside the decode dispatch by default
-(``sample_mode="device"``: traced per-slot params, seed+counter keys,
-device-resident cursors — the tick downloads [B] ids, not [B, V]
-logits); ``sample_mode="host"`` keeps the legacy per-slot numpy
-sampling on downloaded logits.
+Sampling runs ON DEVICE inside the decode dispatch (traced per-slot
+params, seed+counter keys, device-resident cursors — the tick
+downloads [B] ids, not [B, V] logits).
 
 Each slot row computes exactly what a B=1 ``GPTAttention.decode`` at
 that slot's position computes (see ``decode_slots``), so under greedy
@@ -158,32 +156,6 @@ def _watch_device(q, tracer, busy_ms):
         busy_ms.inc((done - ts) * 1e3)
 
 
-def _softmax_np(x):
-    x = x - x.max()
-    e = np.exp(x)
-    return e / e.sum()
-
-
-def _filter_logits_np(row, temperature, top_k, top_p):
-    """Host-side twin of GPTModel._filter_logits for per-slot sampling
-    (each slot needs its own rng stream; greedy slots never call this)."""
-    row = row.astype(np.float64)
-    if temperature != 1.0:
-        row = row / temperature
-    if top_k and top_k > 0:
-        kth = np.sort(row)[-min(top_k, len(row))]
-        row = np.where(row < kth, -1e9, row)
-    if top_p < 1.0:
-        p_eff = max(float(top_p), 1e-9)
-        srt = np.sort(row)[::-1]
-        probs = _softmax_np(srt)
-        cum = np.cumsum(probs)
-        keep = (cum - probs) < p_eff
-        cutoff = srt[keep].min()
-        row = np.where(row < cutoff, -1e9, row)
-    return row
-
-
 class _InflightTick:
     """One dispatched-but-not-consumed decode tick (the async engine
     loop's pipeline entry).  Holds the device handles of the arrays
@@ -246,26 +218,14 @@ class Engine:
     Parameters
     ----------
     model : a ``ServedModel`` — ``GPTModel`` (every option below) or
-        ``MLAMoEModel`` (the default paged, chunked, device-sampling
-        path); eval'd; ``scan_layers`` models serve through their
-        auto-synced unrolled decode twin, like ``generate``.
+        ``MLAMoEModel`` (the default paged, chunked path); eval'd;
+        ``scan_layers`` models serve through their auto-synced
+        unrolled decode twin, like ``generate``.
     num_slots : fixed batch-slot pool size (the compiled tick's B).
     max_seq_len : per-slot KV cache length L (prompt + generated must
         fit); defaults to the model's max_position.
     max_queue : admission queue bound (0 = unbounded); a full queue
         sheds load at ``submit`` with QueueFull.
-    prefill_buckets : bound prefill compiles under varied traffic.
-        ``None`` (default) compiles one prefill program per DISTINCT
-        prompt length — fine for tests/benchmarks with few lengths,
-        but production traffic with arbitrary lengths would thrash the
-        8-entry program cache and stall every slot on each new-length
-        compile.  ``"pow2"`` right-pads prompts up to power-of-two
-        bucket lengths (plus max_seq_len); an iterable of ints uses
-        those bucket lengths.  Right-padding is parity-safe: causal
-        attention keeps positions < s independent of the pad tail, the
-        true last-token logits are sliced at s-1, and the garbage cache
-        rows past s are each overwritten by decode before any query can
-        see them.
     kv_block_size : enable the PAGED KV cache (serving/kvcache.py).
         ``None`` (default) keeps the contiguous per-slot rows; an int
         (must divide max_seq_len) carves the pools into fixed-size
@@ -276,8 +236,8 @@ class Engine:
         (same f32 score math over the gathered rows); on TPU a
         near-tie logit may round differently between donor and adopter
         prefill shapes — the same cross-shape caveat as speculative
-        decode.  Not combinable with prefill_buckets (the paged
-        prefill compiles per (context, tail) length instead).
+        decode.  Without ``prefill_chunk`` the paged prefill compiles
+        per (context, tail) length.
     kv_blocks : physical block count of the paged pool (default:
         ``num_slots * max_seq_len / kv_block_size`` — the same HBM as
         the contiguous layout; prefix sharing then YIELDS headroom
@@ -288,14 +248,17 @@ class Engine:
     prefix_cache : keep finished prompts' full blocks resident in a
         token-trie so later requests adopt them (paged mode only;
         default True).  ``False`` pages without reuse — the A/B
-        baseline for the parity tests and bench.
+        baseline for the parity tests.
     prefill_chunk : enable BUDGETED CHUNKED PREFILL.  ``None``
         (default) prefills each admitted prompt whole, inline, before
-        the tick's decode dispatch — one long prompt then stalls token
-        emission for every decoding slot by its full prefill time.  An
+        the tick's decode dispatch, with one compiled program per
+        DISTINCT prompt length — fine for tests with few lengths, but
+        arbitrary lengths thrash the 8-entry program cache, and one
+        long prompt stalls token emission for every decoding slot by
+        its full prefill time.  An
         int (must divide max_seq_len) splits each prompt into
         fixed-size chunks run through ONE compiled chunk program
-        (bounded compiles, like prefill_buckets); each tick spends at
+        (bounded compiles); each tick spends at
         most ``tick_token_budget`` prompt tokens on chunks —
         round-robin across PREFILLING slots, resuming partially
         prefilled prompts before starting new ones — and then always
@@ -304,10 +267,9 @@ class Engine:
         Half-prefilled slots are excluded from decode and sampling
         until their final chunk emits the first token.  Greedy outputs
         stay token-identical to the unchunked engine and to
-        ``generate()`` (same caveat as bucketed prefill: on TPU a
-        near-tie logit may round differently across program shapes).
-        Works with both the contiguous and paged KV layouts; not
-        combinable with prefill_buckets.
+        ``generate()`` (caveat: on TPU a near-tie logit may round
+        differently across program shapes).
+        Works with both the contiguous and paged KV layouts.
     tick_token_budget : prompt tokens each tick may spend on prefill
         chunks (default: one ``prefill_chunk``; must be >= it so every
         tick makes progress).  Requires prefill_chunk.
@@ -315,10 +277,12 @@ class Engine:
         (default) keeps the one-token decode tick; an int k >= 1 makes
         each decode tick gather k draft tokens per slot from the
         ``proposer``, verify all k+1 window positions in ONE jitted
-        dispatch (``GPTModel._compiled_spec_verify_fn`` — one compiled
-        program per (k, layout), reusing the decode tick's
-        ``_slot_attn``), accept the longest prefix where the target's
-        argmax equals the draft plus the one bonus token, and advance
+        dispatch (``GPTModel._compiled_fused_spec_verify_fn`` — one
+        compiled program per (k, layout), reusing the decode tick's
+        ``_slot_attn``), accept ON DEVICE the longest prefix where the
+        target's pick equals the draft plus the one bonus token (the
+        tick downloads picks + accept counts, never the [B, W, V]
+        logits), and advance
         the slot's position/KV write cursor only over the accepted
         lanes — rejected lanes leave garbage rows the next window
         rewrites before any query can see them, so rollback is a pure
@@ -326,8 +290,9 @@ class Engine:
         non-speculative engine (lossless greedy acceptance); seeded
         sampling also matches, because the verify window's lane j
         logits equal the one-token tick's logits for the same prefix
-        and the per-request rng draws once per emitted token either
-        way.  Works with both KV layouts and with chunked prefill.
+        and lane j's key folds the emitted-token counter + j, one draw
+        per emitted token either way.  Works with both KV layouts and
+        with chunked prefill.
         Capacity: the verify window can write up to ``spec_k`` rows
         past a request's last needed position, so ``submit`` requires
         prompt + max_new_tokens + spec_k <= max_seq_len and the paged
@@ -337,34 +302,14 @@ class Engine:
         against the slot's own prompt + emitted history, zero extra
         model.  ``DraftModelProposer(small_gpt)`` drafts with a
         smaller model sharing the tokenizer/vocab (cross-checked).
-    sample_mode : where per-token sampling runs.  ``"device"`` (the
-        default) FUSES sampling into the jitted decode dispatch:
-        per-slot temperature/top_k/top_p ride as traced [B] lanes
-        (temperature 0 = the greedy sentinel), rng keys derive on
-        device from the request seed + emitted-token counter
-        (``core/rng.request_key`` — a given seed reproduces across
-        engine restarts), and the hot step state (current token,
-        position, rng counter) stays DEVICE-RESIDENT between ticks —
-        a steady-state tick uploads nothing and downloads only the
-        [B] sampled ids (speculative: picks + accept counts, the
-        accepted-lane count also computed on device), instead of the
-        [B, V] (or [B, W, V]) logits matrix the host path pulls every
-        tick.  Greedy outputs are token-identical to the host path on
-        every layout; SAMPLED streams differ from host mode (device
-        draws are jax categorical over fold(seed, token_index) keys,
-        host draws are numpy) but are deterministic per request seed.
-        ``"host"`` keeps the legacy exact numerics: logits download +
-        numpy per-slot sampling (``_pick``).  Watch
-        ``serving.d2h_bytes_per_tick`` / ``serving.sample_ms`` /
-        ``serving.fused_sample_ticks``.
     attn_impl : which attention implementation serves the paged
         window dispatches.  ``None`` (default) inherits the model's
         ``GPTModel(attn_impl=...)`` knob (itself defaulting to
         ``"xla"``).  ``"xla"`` keeps the pure-XLA gather/scatter
         programs — one compiled executable per (layout, chunk shape,
         spec_k) window SHAPE — and remains the CPU tier-1 parity
-        oracle.  ``"ragged"`` (requires the paged layout and device
-        sampling) routes the decode, spec-verify, and chunked-prefill
+        oracle.  ``"ragged"`` (requires the paged layout) routes the
+        decode, spec-verify, and chunked-prefill
         attention core through the Pallas RAGGED PAGED ATTENTION
         kernel (ops/ragged_paged_attn.py; interpret mode on the cpu
         platform only, so tier-1 runs the kernel logic; on any other
@@ -388,13 +333,7 @@ class Engine:
         streams are token-identical to the XLA path end-to-end across
         the full layout matrix, seeded streams are deterministic
         (same seed => same stream); both asserted in
-        tests/test_ragged_attn.py.  ``"ragged_gather"`` keeps the
-        original materialize-the-row kernel body — O(context) working
-        set, bitwise-equal to the XLA oracle on CPU, greedy AND
-        seeded token-identical — as the CPU A/B reference (trace
-        span ``decode.ragged``; same dispatch path and compile-matrix
-        collapse otherwise).  Mosaic refuses this body, so off the
-        cpu platform it raises at construction.
+        tests/test_ragged_attn.py.
     mesh : TENSOR-PARALLEL SERVING over a device mesh.  ``None``
         (default) serves on one device.  An int / 1-tuple ``mp``
         degree (resolved over the first mp devices via
@@ -428,9 +367,8 @@ class Engine:
         mesh; ``serving.kv_blocks_total`` reflects the aggregate
         logical pool).  Mutually exclusive with ``kv_blocks``;
         requires the paged layout.
-    async_depth : ASYNC ENGINE LOOP pipeline depth.  ``None`` (the
-        default) resolves to 2 in device sample mode and 1 in host
-        mode.  At depth 2 a tick DISPATCHES tick N+1's fused decode
+    async_depth : ASYNC ENGINE LOOP pipeline depth, 2 (the default)
+        or 1.  At depth 2 a tick DISPATCHES tick N+1's fused decode
         BEFORE consuming tick N's ids (jax async dispatch: the
         returned handles are futures; the only blocking sync is the
         consume-side ``np.asarray``, traced as ``decode.d2h_wait``),
@@ -454,13 +392,11 @@ class Engine:
         bit-for-bit).  Speculative mode consumes before drafting
         (draft windows are data-dependent on the previous window's
         accepted tokens), so its overlap is limited to planning.
-        Requires ``sample_mode="device"`` for depth > 1 — the host
-        sampling path needs the logits on the host every tick, so
-        there is no gap to overlap.  Watch ``serving.tick_overlap_ms``
+        Watch ``serving.tick_overlap_ms``
         / ``serving.d2h_wait_ms`` and the ``host.overlap`` spans.
     tracing : keep a per-engine span tracer (monitor/tracing.py) fed
         by every tick: admission / prefill / chunk / decode-dispatch /
-        d2h-sync / sample / emit complete-events with args (batch
+        d2h-sync / emit complete-events with args (batch
         size, layout, accepted spec lanes, KV blocks in use),
         per-request lifecycle instants (queued -> admitted ->
         prefix-adopted -> first-token -> finished/evicted), and a
@@ -472,7 +408,7 @@ class Engine:
         LAST ~capacity events are always retained — the flight
         recorder.  Download it live via ``/debug/trace`` or
         ``Engine.chrome_trace()``; ``tracing=False`` swaps in a no-op
-        tracer (the bench's A/B: overhead is asserted <= 5%).
+        tracer.
         Tracing also starts, at the first dispatch, one DEVICE WATCHER
         thread (stopped by ``stop()``): it waits for the smallest
         output of every dispatched program and records ``dev.decode``
@@ -517,9 +453,9 @@ class Engine:
         preserved, and re-admission adopts the cached span so the
         resume skips re-prefill — the resumed stream is
         token-identical (greedy AND per-seed sampled: the device key
-        folds the emitted-token counter, the host rng stream
-        survives) to an uninterrupted run.  Victims tie-break to the
-        most recently admitted (least sunk work).
+        folds the emitted-token counter) to an uninterrupted run.
+        Victims tie-break to the most recently admitted (least sunk
+        work).
     shed_deadlines : DEADLINE-AWARE LOAD SHEDDING at submit (default
         True).  Once the drain rate is measured, a request whose
         deadline (``timeout``) is already blown by the estimated
@@ -545,12 +481,12 @@ class Engine:
     """
 
     def __init__(self, model, num_slots=4, max_seq_len=None,
-                 max_queue=0, registry=None, prefill_buckets=None,
+                 max_queue=0, registry=None,
                  kv_block_size=None, kv_blocks=None, prefix_cache=True,
                  prefill_chunk=None, tick_token_budget=None,
-                 spec_k=None, proposer=None, sample_mode="device",
+                 spec_k=None, proposer=None,
                  attn_impl=None, mesh=None, kv_budget_mb=None,
-                 async_depth=None, tracing=True,
+                 async_depth=2, tracing=True,
                  trace_capacity=16384, trace_annotations=False,
                  flight_dir=None, tenants=None, preemption=True,
                  shed_deadlines=True, faults=None, watchdog_s=None,
@@ -605,7 +541,6 @@ class Engine:
         self._refuse_unsupported(sspec, dict(
             contiguous=kv_block_size is None,
             unchunked_prefill=prefill_chunk is None,
-            host_sampling=sample_mode == "host",
             ragged=(attn_impl or getattr(model, "attn_impl", "xla"))
             != "xla",
             spec=spec_k is not None or proposer is not None,
@@ -796,24 +731,6 @@ class Engine:
                 b._data = jax.device_put(b._data, self._repl_sharding)
         self._kv_budget_mb = (None if kv_budget_mb is None
                               else float(kv_budget_mb))
-        if prefill_buckets == "pow2":
-            bs, b = [], 8
-            while b < self.max_seq_len:
-                bs.append(b)
-                b *= 2
-            bs.append(self.max_seq_len)
-            self._prefill_buckets = bs
-        elif prefill_buckets:
-            bs = sorted({int(x) for x in prefill_buckets})
-            if bs[0] < 1 or bs[-1] > self.max_seq_len:
-                raise ValueError(
-                    f"prefill_buckets must lie in [1, {self.max_seq_len}]"
-                    f", got {bs}")
-            if bs[-1] < self.max_seq_len:
-                bs.append(self.max_seq_len)  # every legal prompt fits
-            self._prefill_buckets = bs
-        else:
-            self._prefill_buckets = None
         self._chunk = None
         self._tick_budget = None
         if prefill_chunk is not None:
@@ -824,11 +741,6 @@ class Engine:
                     f" ({self.max_seq_len}), got {c} — dividing keeps "
                     "the chunk window from clamping onto live cache "
                     "rows")
-            if self._prefill_buckets is not None:
-                raise ValueError(
-                    "prefill_chunk cannot combine with prefill_buckets:"
-                    " the fixed chunk shape already bounds prefill "
-                    "compiles")
             b = int(tick_token_budget) if tick_token_budget is not None \
                 else c
             if b < c:
@@ -871,23 +783,10 @@ class Engine:
             raise ValueError(
                 "proposer requires spec_k (the draft window width "
                 "fixes the compiled verify program's shape)")
-        if sample_mode not in ("device", "host"):
-            raise ValueError(
-                f"sample_mode must be 'device' or 'host', got "
-                f"{sample_mode!r}")
-        self.sample_mode = sample_mode
-        if async_depth is None:
-            async_depth = 2 if sample_mode == "device" else 1
         async_depth = int(async_depth)
         if async_depth < 1:
             raise ValueError(
                 f"async_depth must be >= 1, got {async_depth}")
-        if async_depth > 1 and sample_mode != "device":
-            raise ValueError(
-                "async_depth > 1 requires sample_mode='device': the "
-                "host sampling path downloads the logits and samples "
-                "on the host every tick, so there is no device-compute "
-                "gap to overlap")
         self.async_depth = async_depth
         self._paged = kv_block_size is not None
         if self._kv_quant:
@@ -897,24 +796,12 @@ class Engine:
                     "(kv_block_size=...): quantization is per-block — "
                     "the contiguous pools have no block granularity "
                     "to hang a scale on")
-            if sample_mode != "device":
-                raise ValueError(
-                    "kv_dtype='int8' requires sample_mode='device': "
-                    "the host sampling paths dispatch the per-layer "
-                    "fp decode programs, which have no dequantizing "
-                    "gather — only the fused device-sampling "
-                    "dispatches thread QuantKV pools")
         if self._paged:
             bsz = int(kv_block_size)
             if bsz < 1 or self.max_seq_len % bsz:
                 raise ValueError(
                     f"kv_block_size must be >= 1 and divide max_seq_len"
                     f" ({self.max_seq_len}), got {bsz}")
-            if self._prefill_buckets is not None:
-                raise ValueError(
-                    "prefill_buckets cannot combine with kv_block_size:"
-                    " the paged prefill compiles per (context, tail) "
-                    "length instead of per bucket")
             self._bs = bsz
             self._bps = self.max_seq_len // bsz  # blocks per full slot
             # per-shard footprint of ONE logical block: each mesh
@@ -999,30 +886,18 @@ class Engine:
         # -- ragged paged attention (attn_impl="ragged") ----------------
         if attn_impl is None:
             attn_impl = getattr(model, "attn_impl", "xla")
-        if attn_impl not in ("xla", "ragged", "ragged_gather"):
+        if attn_impl not in ("xla", "ragged"):
             raise ValueError(
-                f"attn_impl must be 'xla', 'ragged' or "
-                f"'ragged_gather', got {attn_impl!r}")
-        if attn_impl in ("ragged", "ragged_gather"):
-            if not self._paged:
-                raise ValueError(
-                    f"attn_impl={attn_impl!r} requires the paged KV "
-                    "layout (kv_block_size=...): the kernel reads K/V "
-                    "through per-slot block tables — the contiguous "
-                    "layout keeps the XLA path")
-            if sample_mode != "device":
-                raise ValueError(
-                    f"attn_impl={attn_impl!r} requires "
-                    "sample_mode='device': sampling, the acceptance "
-                    "scan, and the stop condition all run in the "
-                    "ragged program's epilogue")
+                f"attn_impl must be 'xla' or 'ragged', "
+                f"got {attn_impl!r}")
+        if attn_impl == "ragged" and not self._paged:
+            raise ValueError(
+                "attn_impl='ragged' requires the paged KV "
+                "layout (kv_block_size=...): the kernel reads K/V "
+                "through per-slot block tables — the contiguous "
+                "layout keeps the XLA path")
         self.attn_impl = attn_impl
-        # both ragged kernels share the dispatch path; "ragged" is
-        # the streaming (online-softmax) body, "ragged_gather" the
-        # materialize-the-row A/B reference (ops/ragged_paged_attn.py)
-        self._ragged = attn_impl in ("ragged", "ragged_gather")
-        self._variant = ("gather" if attn_impl == "ragged_gather"
-                         else "stream")
+        self._ragged = attn_impl == "ragged"
         # the ONE ragged program's static window: wide enough for a
         # one-token decode lane, the k+1 spec-verify window, and a
         # prefill chunk — per-slot width is runtime data, so the
@@ -1051,7 +926,7 @@ class Engine:
                         blocks_per_slot=self._bps,
                         num_blocks=self._kv_managed // self.dp + 1,
                         dtype=self._kv_dtype, quant=self._kv_quant,
-                        variant=self._variant, device=dev)
+                        device=dev)
                 except Exception as e:
                     raise ValueError(
                         f"attn_impl={attn_impl!r} does not compile "
@@ -1067,11 +942,6 @@ class Engine:
         # program per config.
         self.adapters = None
         if adapters is not None or max_adapters is not None:
-            if sample_mode != "device":
-                raise ValueError(
-                    "adapters require sample_mode='device': the host "
-                    "sampling paths dispatch per-layer programs that "
-                    "do not thread the per-slot LoRA lanes")
             if self.mesh is not None or sspec.tensor_parallel:
                 raise ValueError(
                     "adapters cannot combine with tensor-parallel "
@@ -1110,7 +980,6 @@ class Engine:
         self.last_flight_path = None   # recent step failure (+ file)
         self.tick_no = 0
         self._reset_pools()
-        self._rngs = {}  # request id -> np.random.Generator (sampling)
 
         params = dict(model.named_parameters())
         self._params = params
@@ -1225,20 +1094,13 @@ class Engine:
             "serving.spec_tokens_per_tick", "tokens emitted per "
             "DECODING slot by the latest speculative verify dispatch "
             "(1.0 = nothing accepted, spec_k+1 = full window)")
-        # sampling-mode surface (registered always; sample_ms stays
-        # empty in device mode, fused_sample_ticks zero in host mode)
         self._m_d2h = reg.gauge(
             "serving.d2h_bytes_per_tick", "bytes the latest decode "
-            "dispatch downloaded to the host (host mode pulls the "
-            "[B, V] logits — [B, W, V] speculative; device mode only "
-            "the sampled ids + accept counts)")
-        self._m_sample_ms = reg.histogram(
-            "serving.sample_ms", "host-side per-tick sampling + emit "
-            "loop (ms; host sample_mode only — device mode samples "
-            "inside the dispatch)")
+            "dispatch downloaded to the host (the sampled ids + "
+            "accept counts + done mask, never the logits)")
         self._m_fused_ticks = reg.counter(
             "serving.fused_sample_ticks", "decode dispatches that "
-            "sampled on device (sample_mode='device')")
+            "sampled on device")
         self._m_rows_walked = reg.counter(
             "serving.decode_rows_walked", "cache rows the XLA decode / "
             "verify dispatches walked, summed over slots: how far the "
@@ -1262,9 +1124,7 @@ class Engine:
             "ragged kernel walked in the latest dispatch, summed over "
             "lanes: the streaming kernel (attn_impl='ragged') stops "
             "at each lane's causal horizon ceil((pos + width) / "
-            "block_size), so this tracks LIVE context; the gather "
-            "variant (attn_impl='ragged_gather') always concatenates "
-            "the full per-slot table")
+            "block_size), so this tracks LIVE context")
         # max context length any request has reached on this engine
         # (slot cursor high-water: prefilled prompt + decoded tokens)
         # — surfaced in /healthz and /debug/requests so the fleet's
@@ -1291,7 +1151,7 @@ class Engine:
         # engine's model (any trigger — this engine, a sibling engine,
         # generate()) bumps the counter and lands in the trace; a
         # steady-state increase is the compile-thrash signal the
-        # bounded chunk/spec/bucket shapes exist to prevent
+        # bounded chunk/spec shapes exist to prevent
         self._m_compiles = reg.counter(
             "serving.compiles_total", "new jitted programs compiled "
             "since engine start (first-call trace + XLA compile "
@@ -1392,8 +1252,6 @@ class Engine:
         #   tick reads DELTAS to keep the occupancy gauge exact without
         #   re-locking the scheduler after the decode dispatch
         self._insert_fn = None
-        self._tick_fn = None    # resolved jitted slot-decode handle
-        self._spec_fn = None    # resolved jitted spec-verify handle
         self._fused_fn = None   # resolved fused decode+sample handle
         self._fused_spec_fn = None  # fused verify+sample/accept handle
         self._p_arrays = None   # lazy snapshots of param/buffer handles
@@ -1467,27 +1325,30 @@ class Engine:
         the contiguous layout."""
         return self._kvspec.geometry(self._bs) if self._paged else None
 
+    # every feature a ``ServingSpec.unsupported`` may name, by the
+    # option that asks for it (a key that is not here is a refusal
+    # nothing reads: tests/test_mla_moe.py holds the models to it)
+    _REFUSABLE = {
+        "contiguous": "kv_block_size=None (contiguous KV)",
+        "unchunked_prefill": "prefill_chunk=None",
+        "ragged": "attn_impl='ragged'",
+        "spec": "spec_k / proposer (speculative verify)",
+        "kv_int8": "kv_dtype='int8'",
+        "mp": "mesh=... (mp / dp > 1)",
+        "lora": "adapters / max_adapters (LoRA banks)",
+        "offload": "kv_host_mb (host offload)",
+        "migration": "KV migration (migrate_out / import)",
+    }
+
     def _refuse_unsupported(self, sspec, used):
         """Raise, naming the option and the missing piece, for every
         engine feature in use that the model's ``ServingSpec`` lists
         as unsupported; nothing is silently ignored."""
-        option = {
-            "contiguous": "kv_block_size=None (contiguous KV)",
-            "unchunked_prefill": "prefill_chunk=None",
-            "host_sampling": "sample_mode='host'",
-            "ragged": "attn_impl='ragged' / 'ragged_gather'",
-            "spec": "spec_k / proposer (speculative verify)",
-            "kv_int8": "kv_dtype='int8'",
-            "mp": "mesh=... (mp / dp > 1)",
-            "lora": "adapters / max_adapters (LoRA banks)",
-            "offload": "kv_host_mb (host offload)",
-            "migration": "KV migration (migrate_out / import)",
-        }
         for feature, on in used.items():
             if on and feature in sspec.unsupported:
                 raise ValueError(
                     f"{type(self.model).__name__} cannot be served "
-                    f"with {option[feature]} yet: it lacks "
+                    f"with {self._REFUSABLE[feature]} yet: it lacks "
                     f"{sspec.unsupported[feature]}")
 
     def _slot_shard(self, i):
@@ -1560,13 +1421,12 @@ class Engine:
         self.placement = {"platform": devs[0].platform,
                           "device_kind": devs[0].device_kind,
                           "device_ids": [d.id for d in devs]}
-        # host-side per-slot step state: in host sample_mode these ship
-        # to device every tick; in device mode they are MIRRORS of the
+        # host-side per-slot step state: MIRRORS of the
         # device-resident cursors, re-uploaded only when an admission /
         # eviction / chunk dirties them (_push_state)
         self._pos = np.zeros(self.num_slots, np.int32)
         self._cur_tok = np.zeros((self.num_slots, 1), np.int32)
-        # per-slot sampling lanes (device mode): temperature 0 is the
+        # per-slot sampling lanes: temperature 0 is the
         # greedy sentinel, seed words feed core/rng.request_key, and
         # _sctr tracks each request's emitted-token count — the rng
         # fold counter that makes a seed reproduce across restarts
@@ -1635,7 +1495,7 @@ class Engine:
             raise ValueError(
                 f"seed must be in [0, 2**63), got {seed}: the device "
                 "sampling key derivation packs the seed into two "
-                "32-bit words, and the host rng rejects negatives too")
+                "32-bit words")
         if adapter is not None:
             if self.adapters is None:
                 raise UnknownAdapter(
@@ -1761,7 +1621,7 @@ class Engine:
 
     def _b_list(self):
         """Buffer arrays sorted by name — every compiled path here
-        (prefill, bucketed prefill, slot decode) orders buffers as
+        (prefill, chunk prefill, slot decode) orders buffers as
         sorted(named_buffers()), so one snapshot serves all three."""
         if self._b_arrays is None:
             bufs = dict(self.model.named_buffers())
@@ -1932,8 +1792,6 @@ class Engine:
             self._m_timeout.inc(len(timed_out))
             self._m_done.inc(len(timed_out))
             for req in timed_out:
-                self._rngs.pop(req.id, None)  # a preempted-then-
-                #   expired request may hold a host rng stream
                 tr.instant("req.evicted", cat="request", req=req.id,
                            reason="timeout")
         return uniq
@@ -1949,9 +1807,8 @@ class Engine:
         re-admission prefills.  The resumed stream is token-identical
         to an uninterrupted run: greedy trivially, sampled because
         the device key folds the emitted-token counter (the next draw
-        is draw #len(generated) either way) and the host rng stream
-        stays alive in ``_rngs``.  Caller must have DRAINED the async
-        ring: an in-flight lane whose request vanished un-done would
+        is draw #len(generated) either way).  Caller must have
+        DRAINED the async ring: an in-flight lane whose request vanished un-done would
         otherwise raise the consume-side drift check."""
         req = slot.request
         i = slot.index
@@ -2062,7 +1919,7 @@ class Engine:
     # preemption (prefix insert, release, park) but finishes the
     # request with ``Migrated`` instead of requeueing it, and the
     # resume snapshot (prompt, emitted tokens, sampling params, the
-    # EFFECTIVE seed, host-rng state) rides alongside the bytes.  The
+    # EFFECTIVE seed) rides alongside the bytes.  The
     # destination scatters the blocks into its own pool
     # (kvcache.import_blocks), registers them under its prefix trie,
     # and queues an equivalent Request — whose normal admission
@@ -2494,11 +2351,6 @@ class Engine:
                         kv["data"] = data
                 if self.prefix_cache is not None and n_full:
                     self.prefix_cache.insert(ctx, blocks)
-            rng = self._rngs.pop(req.id, None)
-            # np.random.Generator state is a plain JSON-able dict of
-            # Python ints — the destination rebuilds the exact stream
-            rng_state = (rng.bit_generator.state
-                         if rng is not None else None)
             payload = {
                 "version": 1,
                 "request": {
@@ -2517,7 +2369,6 @@ class Engine:
                              else req.seed),
                     "priority": req.priority, "tenant": req.tenant,
                     "preemptions": req.preemptions,
-                    "rng_state": rng_state,
                 },
                 "kv": kv,
             }
@@ -2629,11 +2480,6 @@ class Engine:
         req._ctx = np.asarray(ctx, np.int32)
         req.preemptions = int(rq.get("preemptions") or 0) + 1
         #   counts the handoff; admission emits req.resumed for it
-        state = rq.get("rng_state")
-        if state is not None and self.sample_mode == "host":
-            g = np.random.default_rng(req.sample_seed)
-            g.bit_generator.state = state
-            self._rngs[req.id] = g
         self.queue.put(req)
         self._m_reqs.inc()
         with self._mig_lock:
@@ -3074,7 +2920,6 @@ class Engine:
                 "layout": "paged" if self._paged else "contiguous",
                 "prefill_chunk": self._chunk,
                 "spec_k": self._spec_k,
-                "sample_mode": self.sample_mode,
                 "attn_impl": self.attn_impl,
                 "max_context_len": self._max_context_len,
                 "mesh_shape": self.mesh_axes,
@@ -3290,7 +3135,7 @@ class Engine:
     def _dequant_span(self, tr, batch):
         """``decode.dequant``: the host-side attribution span of a
         QUANTIZED dispatch, nested inside ``decode.dispatch`` /
-        ``decode.ragged``.  The per-block dequant itself runs FUSED
+        ``decode.ragged_stream``.  The per-block dequant itself runs FUSED
         inside the compiled program (codes x scale adjacent to the
         gather), so there is no separate host phase to time — this
         wraps the same dispatch call and records the worst-case code
@@ -3305,15 +3150,14 @@ class Engine:
             code_bytes=batch * self._bps
             * (self._kv_code_bytes_per_shard or 0))
 
-    # -- per-slot sampling lanes (sample_mode="device") ----------------
+    # -- per-slot sampling lanes ---------------------------------------
     def _bind_sample_state(self, slot):
         """Install the admitted request's sampling lane into the state
         mirrors (admission): temperature 0 marks a greedy lane, the
         seed words feed the on-device key derivation, and the rng
         counter restarts at 0 — so two engines given the same seed
         emit the same sampled tokens.  Dirtying the mirrors makes the
-        next device-mode tick re-upload them (host mode ships state
-        every tick anyway and ignores the lanes).
+        next tick re-upload them.
 
         A GREEDY request's lane binds CONSTANT zero seed words, not
         its id-derived default seed: its draw is discarded (argmax),
@@ -3358,7 +3202,7 @@ class Engine:
         """Park slot i's step + sampling lanes (eviction): frozen
         zeros keep the inactive row's (discarded) compute in-bounds
         and greedy-cheap until the next admission overwrites them; the
-        dirty flag makes the next device-mode tick re-upload the
+        dirty flag makes the next tick re-upload the
         corrected cursors — a mid-window eviction may have advanced
         the device cursor further than the host consumed."""
         self._pos[i] = 0
@@ -3394,8 +3238,8 @@ class Engine:
         return walked // self.num_slots
 
     def _push_state(self):
-        """Upload the state mirrors as the device-resident step state
-        (device mode): runs only when an admission / eviction / chunk
+        """Upload the state mirrors as the device-resident step
+        state: runs only when an admission / eviction / chunk
         dirtied them — a steady-state tick reuses the handles the last
         dispatch returned and uploads NOTHING.  The pipeline must be
         drained first: the mirrors only reflect CONSUMED ticks, so
@@ -3496,10 +3340,8 @@ class Engine:
     def _prefill(self, slot):
         """Admission prefill: one jitted whole-prompt forward (shared
         with ``generate(compiled=...)`` via _compiled_prefill_fn, so the
-        math is the compiled path's bit-for-bit; or the bucketed
-        right-padded variant when prefill_buckets bounds compiles),
+        math is the compiled path's bit-for-bit),
         padded to the pool's L and written into the slot's cache rows."""
-        import jax.numpy as jnp
         self._bind_sample_state(slot)
         if self._paged:
             return self._prefill_paged(slot)
@@ -3507,29 +3349,15 @@ class Engine:
         tokens = req.context  # prompt, or the frozen resume snapshot
         s = len(tokens)
         L = self.max_seq_len
-        if self._prefill_buckets is not None:
-            S = next(b for b in self._prefill_buckets if b >= s)
-            pf, _, _ = self.model.serving_program(
-                "bucket_prefill", self._pnames, self._params,
-                self._lora_key(
-                    (1, S, L, self._kv_dtype_str, tuple(self._pnames),
-                     self._bnames_all)),
-                1, S, L, self._nh, self._hd, self._kv_dtype)
-            ids = np.zeros((1, S), np.int32)
-            ids[0, :s] = tokens
-            last0, k_bufs, v_bufs = pf(self._p_list(), self._b_list(),
-                                       ids, jnp.asarray(s, jnp.int32),
-                                       *self._lora_args_slot(req))
-        else:
-            pf, _, _ = self.model.serving_program(
-                "prefill", self._pnames, self._params,
-                self._lora_key(
-                    (1, s, L, self._kv_dtype_str, tuple(self._pnames),
-                     self._bnames_all)),
-                1, s, L, self._nh, self._hd, self._kv_dtype)
-            last0, k_bufs, v_bufs = pf(self._p_list(), self._b_list(),
-                                       tokens[None, :],
-                                       *self._lora_args_slot(req))
+        pf, _, _ = self.model.serving_program(
+            "prefill", self._pnames, self._params,
+            self._lora_key(
+                (1, s, L, self._kv_dtype_str, tuple(self._pnames),
+                 self._bnames_all)),
+            1, s, L, self._nh, self._hd, self._kv_dtype)
+        last0, k_bufs, v_bufs = pf(self._p_list(), self._b_list(),
+                                   tokens[None, :],
+                                   *self._lora_args_slot(req))
         self._dev_note(pf.kind, last0, n=s, req=req.id)
         i = slot.index
         if self._insert_fn is None:
@@ -3637,7 +3465,7 @@ class Engine:
         slot.pos = slot.prefilled
         self._m_chunks.inc()
         self._m_prefill_tokens.inc(n)
-        self._state_dirty = True  # device-mode cursors must re-park on
+        self._state_dirty = True  # the device cursors must re-park on
         #   the chunk's new start row before the next fused tick
         if slot.prefilled < s:
             # still PREFILLING: re-park the decode dispatch's garbage
@@ -3698,30 +3526,16 @@ class Engine:
         return self._pick(req, row)
 
     def _pick(self, req, row):
-        """Next token from one slot's f32 logits row: argmax (greedy —
-        identical in both sample modes), device-twin filtered sampling
-        (sample_mode="device"), or filtered numpy sampling on a
-        per-request rng stream (host mode's legacy numerics)."""
-        if not req.do_sample:
-            return int(np.argmax(row))
-        if self.sample_mode == "device":
-            return self._pick_device(req, row)
-        rng = self._rngs.get(req.id)
-        if rng is None:
-            rng = self._rngs[req.id] = np.random.default_rng(
-                req.sample_seed)
-        filt = _filter_logits_np(row, req.temperature, req.top_k,
-                                 req.top_p)
-        return int(rng.choice(len(filt), p=_softmax_np(filt)))
-
-    def _pick_device(self, req, row):
-        """Device-mode first-token pick (prefill / final chunk): the
-        SAME lane filters and key derivation as the fused dispatches
-        (``models.gpt.sample_rows`` — one process-wide compile), run
-        on the one [V] logits row prefill already returned — so token
+        """First-token pick from the one [V] logits row that prefill
+        (or the final chunk) returned: argmax for a greedy request,
+        else the SAME lane filters and key derivation as the fused
+        dispatches (``models.gpt.sample_rows`` — one process-wide
+        compile) — so token
         i of a request draws from fold(request_key, i) whether
         prefill, a one-token tick, or a verify-window lane emitted it,
         and a seed reproduces across engine restarts."""
+        if not req.do_sample:
+            return int(np.argmax(row))
         import jax.numpy as jnp
         from ..models.gpt import sample_rows
         lo, hi = req.seed_words()
@@ -3771,7 +3585,6 @@ class Engine:
             if n_after_first > 0:
                 self._m_tpot.observe(
                     (now - req.first_token_at) / n_after_first * 1e3)
-            self._rngs.pop(req.id, None)
             i = slot.index
             self.scheduler.evict(slot)
             self._evicted_in_tick += 1
@@ -3850,109 +3663,6 @@ class Engine:
             #   verify must not deflate the lifetime acceptance-rate
             #   gauge with lanes never scored.)
         return toks
-
-    def _spec_decode_tick(self, active):
-        """One speculative DRAFT-AND-VERIFY dispatch (spec_k=..., host
-        sampling): gather k draft tokens per live slot from the
-        proposer, score all k+1 window positions in one jitted verify
-        dispatch, then per slot emit the longest prefix where the
-        target's pick equals the draft plus the one bonus token —
-        1..k+1 tokens per slot per dispatch.  The write cursor
-        advances only over emitted tokens; rejected lanes leave
-        garbage K/V that the next window (which always spans the full
-        k+1 positions from the new cursor) rewrites before any query
-        can see it."""
-        import jax.numpy as jnp
-        tr = self.tracer
-        W = self._spec_k + 1
-        layout = "paged" if self._paged else "contiguous"
-        with tr.span("spec.draft", batch=len(active), spec_k=W - 1):
-            toks = self._draft_window(active)
-        if self._spec_fn is None:
-            self._spec_fn, _, _ = self.model.serving_program(
-                "spec_verify", self._pnames, self._params,
-                ("paged" if self._paged else "slot", W, self.num_slots,
-                 (self._kv_managed + self.dp, self._bs) if self._paged
-                 else self.max_seq_len, self._kv_dtype_str,
-                 tuple(self._pnames), self._bnames_all),
-                paged=self._paged)
-        fn = self._spec_fn
-        self._fault("dispatch")
-        with tr.span("decode.dispatch", batch=len(active),
-                     layout=layout, spec_w=W,
-                     rows=self._rows_walked(W)):
-            if self._paged:
-                last, self.k_pools, self.v_pools = fn(
-                    self._p_list(), self._b_list(), self.k_pools,
-                    self.v_pools, jnp.asarray(self._block_tables),
-                    jnp.asarray(toks), jnp.asarray(self._pos))
-            else:
-                last, self.k_pools, self.v_pools = fn(
-                    self._p_list(), self._b_list(), self.k_pools,
-                    self.v_pools, jnp.asarray(toks),
-                    jnp.asarray(self._pos))
-        self._dev_note(fn.kind, last, batch=len(active))
-        with tr.span("decode.d2h") as d2h_sp:
-            rows = np.asarray(last, np.float32)       # [B, W, V]
-            d2h_sp.args["bytes"] = rows.nbytes
-        self._blocked_s += d2h_sp.elapsed
-        self._m_d2h.set(rows.nbytes)
-        self._m_spec_windows.inc(len(active))
-        t_sample = time.monotonic()
-        emitted = 0
-        total_acc = 0
-        # `with`, not manual enter/exit: a _pick/_emit failure mid-loop
-        # must still record this span — it is exactly the phase the
-        # flight-recorder dump needs to show
-        with tr.span("decode.sample", batch=len(active),
-                     layout=layout) as sample_sp:
-            for slot in active:
-                i = slot.index
-                req = slot.request
-                self._m_spec_proposed.inc(slot.spec_lanes)
-                n_emit = 0
-                n_acc = 0
-                j = 0
-                while True:
-                    # lane j's logits are conditioned on exactly the
-                    # accepted tokens, so _pick here equals the
-                    # one-token tick's _pick for the same prefix
-                    # (greedy AND seeded sampling: one rng draw per
-                    # emitted token either way)
-                    tok = self._pick(req, rows[i, j])
-                    # only REAL lanes can match: a pad lane that
-                    # happens to equal the pick must not be consumed
-                    # (eviction at max_new would stop it anyway — this
-                    # makes the bound local instead of an
-                    # invariant-at-a-distance)
-                    matched = j < slot.spec_lanes \
-                        and int(toks[i, j + 1]) == tok
-                    if matched:
-                        # counted even when this very token finishes
-                        # the request (EOS proposed by a matched
-                        # lane): the draft DID predict an emitted
-                        # token, and n_emit - 1 would silently
-                        # undercount it
-                        n_acc += 1
-                    slot.pos += 1
-                    self._pos[i] = slot.pos
-                    self._emit(slot, tok)
-                    n_emit += 1
-                    if slot.request is None or not matched:
-                        break  # finished/evicted, or first mismatch
-                    j += 1     # draft j verified: consume lane j+1
-                slot.spec_lanes = 0
-                self._m_spec_accepted.inc(n_acc)
-                total_acc += n_acc
-                emitted += n_emit
-            sample_sp.args.update(emitted=emitted, accepted=total_acc)
-        self._m_sample_ms.observe((time.monotonic() - t_sample) * 1e3)
-        proposed = self._m_spec_proposed.value
-        if proposed:
-            self._m_spec_rate.set(
-                self._m_spec_accepted.value / proposed)
-        self._m_spec_tpt.set(emitted / len(active))
-        return emitted
 
     @_spanned("dispatch")
     def _dispatch_spec(self, active, tr):
@@ -4107,8 +3817,8 @@ class Engine:
 
     @_spanned("dispatch")
     def _dispatch_decode(self, active, tr):
-        """DISPATCH one fused decode+sample tick (sample_mode=
-        "device") without consuming it: the step state lives on
+        """DISPATCH one fused decode+sample tick without
+        consuming it: the step state lives on
         device between ticks (re-uploaded only when admissions /
         evictions / chunks dirtied the mirrors — which requires an
         empty pipeline, see ``_push_state``), sampling AND the stop
@@ -4271,23 +3981,17 @@ class Engine:
         # itself advances them by width)
         if self._state_dirty or self._dev_state is None:
             self._push_state()
-        variant = self._variant
         # kv blocks the kernel walks this tick (computed on the
         # PRE-dispatch cursors, before the chunk lanes' mirror
         # advance): the streaming loop stops at each lane's causal
-        # horizon ceil((pos + width) / block_size), while the gather
-        # body always concatenates the slot's FULL table — the
-        # per-tick block-walk cost the kv_blocks_walked_per_tick
-        # gauge makes attributable (and the serving_longctx bench
-        # plots flat vs context length for the streaming variant)
+        # horizon ceil((pos + width) / block_size) — the per-tick
+        # block-walk cost the kv_blocks_walked_per_tick gauge makes
+        # attributable
         walked = 0
         for s in (list(active) + [sl for sl, _, _ in plan]):
             i = s.index
-            if variant == "gather":
-                walked += self._bps
-            else:
-                live = int(self._pos[i]) + max(int(width[i]), 1)
-                walked += min(self._bps, (live - 1) // self._bs + 1)
+            live = int(self._pos[i]) + max(int(width[i]), 1)
+            walked += min(self._bps, (live - 1) // self._bs + 1)
         self._m_kv_blocks_walked.set(walked)
         for slot, n, final in plan:
             i = slot.index
@@ -4314,12 +4018,11 @@ class Engine:
                          self._kv_managed + self.dp, self._bs,
                          self._kv_dtype_str, tuple(self._pnames),
                          self._bnames_all)),
-                    emit_w=spec_w, variant=variant,
+                    emit_w=spec_w,
                     sharded=self.mp * self.dp > 1)
         self._fault("dispatch")
-        span_name = "decode.ragged_stream" if variant == "stream" \
-            else "decode.ragged"
-        with tr.span(span_name, batch=len(active) + len(plan),
+        with tr.span("decode.ragged_stream",
+                     batch=len(active) + len(plan),
                      layout="paged", w=W, chunks=len(plan),
                      chunk_tokens=chunk_toks, fused=True,
                      kv_blocks_walked=walked), \
@@ -4519,75 +4222,10 @@ class Engine:
         return emitted
 
     def _fused_decode_tick(self, active):
-        """Synchronous fused decode tick (async_depth=1 and the
-        host-driven ``_tick`` path): dispatch + immediate consume —
-        today's tick shape, bit-for-bit."""
+        """Synchronous fused decode tick (async_depth=1, the ``_tick``
+        path): dispatch + immediate consume."""
         inf = self._dispatch_decode(active, self.tracer)
         return self._consume(inf, self.tracer)
-
-    def _decode_tick(self, active):
-        """One slot-batched decode dispatch; samples and advances every
-        live slot (speculative mode verifies a whole draft window per
-        slot instead; sample_mode="device" routes both shapes to their
-        fused on-device-sampling twins)."""
-        import jax.numpy as jnp
-        if self._spec_k is not None:
-            if self.sample_mode == "device":
-                return self._fused_spec_tick(active)
-            return self._spec_decode_tick(active)
-        if self.sample_mode == "device":
-            return self._fused_decode_tick(active)
-        if self._tick_fn is None:
-            # resolve once: the key embeds tuple(pnames), an O(n_params)
-            # copy+hash not worth paying per generated token
-            if self._paged:
-                self._tick_fn, _, _ = \
-                    self.model.serving_program(
-                        "slot_paged_decode", self._pnames, self._params,
-                        (self.num_slots, self._kv_managed + self.dp, self._bs,
-                         self._kv_dtype_str, tuple(self._pnames),
-                         self._bnames_all))
-            else:
-                self._tick_fn, _, _ = self.model.serving_program(
-                    "slot_decode", self._pnames, self._params,
-                    (self.num_slots, self.max_seq_len,
-                     self._kv_dtype_str, tuple(self._pnames),
-                     self._bnames_all))
-        fn = self._tick_fn
-        tr = self.tracer
-        layout = "paged" if self._paged else "contiguous"
-        self._fault("dispatch")
-        with tr.span("decode.dispatch", batch=len(active),
-                     layout=layout, rows=self._rows_walked()):
-            if self._paged:
-                last, self.k_pools, self.v_pools = fn(
-                    self._p_list(), self._b_list(), self.k_pools,
-                    self.v_pools, jnp.asarray(self._block_tables),
-                    jnp.asarray(self._cur_tok), jnp.asarray(self._pos))
-            else:
-                last, self.k_pools, self.v_pools = fn(
-                    self._p_list(), self._b_list(), self.k_pools,
-                    self.v_pools, jnp.asarray(self._cur_tok),
-                    jnp.asarray(self._pos))
-        self._dev_note(fn.kind, last, batch=len(active))
-        with tr.span("decode.d2h") as d2h_sp:
-            rows = np.asarray(last, np.float32)
-            d2h_sp.args["bytes"] = rows.nbytes
-        self._blocked_s += d2h_sp.elapsed
-        self._m_d2h.set(rows.nbytes)
-        t_sample = time.monotonic()
-        emitted = 0
-        with tr.span("decode.sample", batch=len(active),
-                     layout=layout) as sample_sp:
-            for slot in active:
-                slot.pos += 1
-                self._pos[slot.index] = slot.pos
-                self._emit(slot, self._pick(slot.request,
-                                            rows[slot.index]))
-                emitted += 1
-            sample_sp.args["emitted"] = emitted
-        self._m_sample_ms.observe((time.monotonic() - t_sample) * 1e3)
-        return emitted
 
     def step(self):
         """One engine tick: admit -> prefill -> slot-batched decode.
@@ -4597,7 +4235,7 @@ class Engine:
         RECOVERS the engine — in-flight requests are failed loudly
         (their waiters unblock) and the donated K/V pools are rebuilt
         (a dispatch that died after consuming them leaves them deleted)
-        — then re-raises, so every driver (run_until_idle, bench, the
+        — then re-raises, so every driver (run_until_idle, the
         background loop) sees a working engine afterwards."""
         # O(1) no-op while subscribed; re-subscribes a synchronous
         # driver that keeps ticking after a stop()
@@ -4619,7 +4257,6 @@ class Engine:
                 req = self.scheduler.evict(slot, RuntimeError(
                     f"engine step failed: {e!r}"))
                 if req is not None:
-                    self._rngs.pop(req.id, None)
                     self._m_done.inc()  # terminal, like timeouts: keep
                     #   in-flight = total - completed consistent
                     self.tracer.instant("req.evicted", cat="request",
@@ -4872,7 +4509,9 @@ class Engine:
         elif active:
             self._note_dispatch_gap(len(active))
             n_before = self._evicted_in_tick
-            emitted += self._decode_tick(active)
+            emitted += (self._fused_spec_tick(active)
+                        if self._spec_k is not None
+                        else self._fused_decode_tick(active))
             occ -= self._evicted_in_tick - n_before
             self._last_decode_end = time.monotonic()
         else:
@@ -4980,19 +4619,14 @@ class Engine:
             demands, self._migrate_demands = self._migrate_demands, []
         for d in demands:
             d.fail(RuntimeError("engine stopped"))
-        for req in self.queue.drain():
-            # a preempted host-mode request waiting in queue still
-            # holds its numpy rng stream — shutdown must release it
-            self._rngs.pop(req.id, None)
-            self._m_done.inc()
+        self._m_done.inc(len(self.queue.drain()))
         for slot in self.scheduler.busy_slots():
             req = self.scheduler.evict(
                 slot, RuntimeError("engine stopped"))
             self._release_slot_kv(slot.index)
             self._park_state(slot.index)  # a later start() serves with
-            #   clean device-mode cursors
+            #   clean device cursors
             if req is not None:
-                self._rngs.pop(req.id, None)
                 self._m_done.inc()
                 self.tracer.instant("req.evicted", cat="request",
                                     req=req.id, reason="shutdown")

@@ -264,7 +264,6 @@ class _Handler(JsonHandler):
                 # the router's prefix-affinity hash aligns on this
                 "kv_block_size": (eng._bs if getattr(eng, "_paged",
                                                      False) else None),
-                "sample_mode": getattr(eng, "sample_mode", "host"),
                 # where the engine runs: platform, device_kind and the
                 # ids of the devices holding the KV pools
                 **getattr(eng, "placement", {}),
@@ -276,8 +275,7 @@ class _Handler(JsonHandler):
                 # attention kernel in its streaming online-softmax
                 # form (one program for decode / spec / chunk
                 # windows, O(block_size x window) working set),
-                # "ragged_gather" = the materialize-the-row A/B
-                # reference, "xla" = the per-shape gather/scatter
+                # "xla" = the per-shape gather/scatter
                 # programs (the CPU parity oracle); the router copies
                 # this into its registry signals like kv_dtype
                 "attn_impl": getattr(eng, "attn_impl", "xla"),
@@ -1069,7 +1067,7 @@ def main(argv=None):
 
     ``--seed`` makes every replica of a fleet initialize IDENTICAL
     weights, so greedy failover across replicas is token-identical
-    (the fleet tests and bench assert it).  ``--mp > 1`` needs that
+    (the fleet tests assert it).  ``--mp > 1`` needs that
     many devices — on CPU the launcher forces a virtual pool via
     XLA_FLAGS (per-worker env propagation is its job).  The platform
     is whatever ``JAX_PLATFORMS`` (or JAX's default) selects; the
@@ -1186,7 +1184,7 @@ def main(argv=None):
     try:
         if stop_evt.is_set():
             acct = srv.drain_to_peers()
-            # the supervisor/bench parse this accounting line from
+            # the supervisor's tests parse this accounting line from
             # the replica log: a rolling restart must report 0 lost
             print("drain: migrated={migrated} fallback={fallback} "
                   "lost_tokens={lost_tokens}".format(**acct),

@@ -57,16 +57,12 @@ lanes still land inside the reserved margin).  The pool-layer
 contract is unchanged — no live request ever reads row 0, and no
 write ever touches a block the slot does not own — it is simply
 enforced in one place (``GPTAttention.ragged_window_paged`` +
-ops/ragged_paged_attn.py) instead of three.  The rule is
-READ-SIDE-invariant across kernel bodies: the streaming
-online-softmax kernel (``attn_impl="ragged"``) walks a slot's table
-only up to the lane's causal horizon ``ceil((pos + width) /
-block_size)`` and masks per streamed block, while the gather body
-(``attn_impl="ragged_gather"``) concatenates the full table and
-masks once — but scratch-row writes, the spec margin, and block
-ownership are enforced BEFORE the kernel by the same width mask, so
-swapping kernel bodies never changes which blocks are written or
-which garbage is visible.
+ops/ragged_paged_attn.py) instead of three.  The kernel's READ side
+walks a slot's table only up to the lane's causal horizon
+``ceil((pos + width) / block_size)`` and masks per streamed block;
+scratch-row writes, the spec margin, and block ownership are enforced
+BEFORE the kernel by the width mask, so the kernel body never decides
+which blocks are written or which garbage is visible.
 
 Cross-replica block migration (PR 13): because blocks are fixed-size,
 refcounted, and layer-invariant, moving a live stream between replicas

@@ -89,7 +89,7 @@ storms replay the same routing decisions (tests assert it).
 
 Transports: ``HttpReplicaClient`` speaks to a real ``serving.httpd``
 endpoint; ``InProcessReplica`` wraps a local ``Engine`` directly (the
-tier-1 test / bench / single-host fleet transport) and threads the
+tier-1 test / single-host fleet transport) and threads the
 ``net_*`` fault sites of ``serving.faults`` through its own
 deterministic per-replica operation counter.  ``serving.routerd``
 puts an HTTP front door on the router itself.
@@ -343,7 +343,8 @@ class RouterPolicy:
         to ``hedge_floor_s`` until enough samples exist).
     breaker_threshold / breaker_cooldown_s : CircuitBreaker knobs.
     affinity : True = prefix-affinity with least-loaded fallback;
-        False = seeded RANDOM routing (the bench's baseline arm).
+        False = seeded RANDOM routing (the A/B baseline arm of
+        tests/test_router.py).
     affinity_queue_threshold : probed queue_depth beyond which the
         affinity target is considered overloaded and the pick falls
         back to least-loaded (cache locality must not create a hot
@@ -883,7 +884,7 @@ class Router:
                 + ", ".join(f"{r.name}={r.state}/{r.breaker.state}"
                             for r in reps))
         if not self.policy.affinity:
-            # seeded random (the bench's baseline arm): deterministic
+            # seeded random (the A/B baseline arm): deterministic
             # per (seed, request, attempt)
             pool = sorted(pool, key=lambda r: r.name)
             idx = int(_u01(self.policy.seed, "random", rid, attempt)
@@ -1559,7 +1560,7 @@ class Router:
 
 class InProcessReplica:
     """Replica transport wrapping a LOCAL ``Engine`` — the tier-1
-    fake-network layer: tests, benches, the example fleet, and
+    fake-network layer: tests, the example fleet, and
     single-host multi-replica serving all use it, and the ``net_*``
     fault sites of ``serving.faults`` thread through it with a
     deterministic per-replica OPERATION counter as the schedule tick

@@ -5,7 +5,8 @@ dispatch, so tokens/sec is dispatch-bound long before the hardware is.
 Speculative decoding breaks the one-dispatch-one-token coupling: a
 PROPOSER guesses ``k`` draft tokens per slot from information the
 engine already has, and ONE windowed target-model dispatch
-(``GPTModel._compiled_spec_verify_fn``) scores all k+1 positions —
+(``GPTModel._compiled_fused_spec_verify_fn``) scores all k+1
+positions —
 the engine then accepts the longest prefix where the target's argmax
 equals the draft, plus the one "bonus" token the target produced at
 the first mismatch.  Greedy acceptance is LOSSLESS: every emitted
@@ -16,13 +17,10 @@ engine (tests/test_serving.py asserts it).  Wrong drafts cost nothing
 beyond the fixed window compute — the engine's write cursor simply
 does not advance over rejected lanes.
 
-Under the engine's default ``sample_mode="device"`` the verify
-dispatch ALSO picks each lane's token and counts the accepted prefix
-on device (``GPTModel._compiled_fused_spec_verify_fn``), so a verify
-tick downloads picks ``[B, W]`` + accept counts ``[B]`` instead of
-the full ``[B, W, V]`` logits; ``sample_mode="host"`` keeps the
-legacy logits pull + host accept loop.  Proposers are mode-agnostic —
-they only ever see the host-side token history.
+The verify dispatch ALSO picks each lane's token and counts the
+accepted prefix on device, so a verify tick downloads picks
+``[B, W]`` + accept counts ``[B]``, never the full ``[B, W, V]``
+logits.  Proposers only ever see the host-side token history.
 
 Two proposers ship here:
 

@@ -233,7 +233,7 @@ class FleetSupervisor:
         return self._states[str(name)].incarnation
 
     def status(self):
-        """JSON-shaped fleet view (the bench / examples surface)."""
+        """JSON-shaped fleet view (the examples' surface)."""
         rows = {}
         for n, s in sorted(self._states.items()):
             rows[n] = {

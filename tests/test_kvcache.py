@@ -380,5 +380,3 @@ def test_engine_param_validation(tiny_gpt):
         _engine(tiny_gpt, kv_block_size=7)       # 48 % 7 != 0
     with pytest.raises(ValueError, match="max-length"):
         _engine(tiny_gpt, kv_blocks=2)           # < one full request
-    with pytest.raises(ValueError, match="prefill_buckets"):
-        _engine(tiny_gpt, prefill_buckets="pow2")

@@ -47,8 +47,7 @@ CONFIGS = {
     "paged": dict(kv_block_size=8),
     "chunked": dict(kv_block_size=8, prefill_chunk=8),
     "spec": dict(kv_block_size=8, spec_k=2),
-    "depth2": dict(kv_block_size=8, sample_mode="device",
-                   async_depth=2),
+    "depth2": dict(kv_block_size=8, async_depth=2),
     "contiguous": dict(),
 }
 
@@ -181,6 +180,29 @@ def test_migrate_deliver_error_payload_rides_waiter(tiny_gpt):
     assert r.error.payload is not None
     assert r.error.emitted == verdict["generated"]
     got = _resolve(dst, dst.migrate_in(r.error.payload, wait=False))
+    dst.run_until_idle()
+    assert got["request"].result(timeout=1).tolist() == ref
+
+
+def test_import_ignores_a_stale_rng_state(tiny_gpt):
+    """The wire is input from outside the process: a peer of an older
+    build still sends the host sampler's ``rng_state``.  The payload
+    is adopted, the field ignored, and the seeded stream continues
+    from the fold counter alone; this build's own payloads no longer
+    carry the field."""
+    ref = _oracle(tiny_gpt, CONFIGS["paged"], 1234)
+    src = _engine(tiny_gpt, kv_block_size=8)
+    dst = _engine(tiny_gpt, kv_block_size=8)
+    r = src.submit(PROMPT, max_new_tokens=MAX_NEW, **_sample_kw(1234))
+    assert _step_until(src, lambda: len(r.generated) >= 3 or r.done())
+    verdict = _resolve(src, src.migrate_out(
+        request_id=r.id, min_tokens=3, deliver="return", wait=False))
+    payload = verdict["payload"]
+    assert "rng_state" not in payload["request"]
+    payload["request"]["rng_state"] = {
+        "bit_generator": "PCG64", "has_uint32": 0, "uinteger": 0,
+        "state": {"state": 1, "inc": 3}}
+    got = _resolve(dst, dst.migrate_in(payload, wait=False))
     dst.run_until_idle()
     assert got["request"].result(timeout=1).tolist() == ref
 

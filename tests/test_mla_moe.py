@@ -528,7 +528,6 @@ def test_sampled_decoding_and_counters_through_the_engine():
 @pytest.mark.parametrize("options, named", [
     (dict(kv_block_size=None), "kv_block_size=None"),
     (dict(prefill_chunk=None), "prefill_chunk=None"),
-    (dict(sample_mode="host", async_depth=1), "sample_mode='host'"),
     (dict(attn_impl="ragged"), "attn_impl='ragged'"),
     (dict(spec_k=2), "spec_k"),
     (dict(kv_dtype="int8"), "kv_dtype='int8'"),
@@ -546,6 +545,20 @@ def test_the_engine_refuses_by_name_what_the_model_cannot_honour(
         Engine(model, **kw)
     assert named in str(err.value) and "lacks" in str(err.value)
     assert "MLAMoEModel" in str(err.value)
+
+
+@pytest.mark.parametrize("build", [
+    lambda: seeded(seed=7)[0],
+    lambda: GPTModel.from_config("tiny", dropout=0.0),
+], ids=["MLAMoEModel", "GPTModel"])
+def test_every_refusal_a_served_model_declares_is_read(build):
+    """Every key of a served model's ``ServingSpec.unsupported`` is a
+    feature the engine asks about (its construction-time table, which
+    also names ``migration`` for the migration entry points): a key
+    nothing reads is a refusal that never fires."""
+    declared = set(build().serving_spec().unsupported)
+    assert declared <= set(Engine._REFUSABLE), \
+        declared - set(Engine._REFUSABLE)
 
 
 def test_migration_is_refused_when_asked_for():

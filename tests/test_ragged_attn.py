@@ -6,20 +6,15 @@ the XLA oracle: per-slot pos/width/block-tables as data, width-masked
 scratch writes, and the one-program compile-matrix collapse the
 ``attn_impl="ragged"`` engine path claims.  Tests marked ``pallas``
 involve the kernel; ``test_kernel_compiled_lowering_on_tpu`` asks the
-real Mosaic compiler (compile-only, no chip needed) which bodies it
+real Mosaic compiler (compile-only, no chip needed) which shapes it
 takes.
 
-NUMERICS CONTRACT (two kernel bodies):
-
-* ``attn_impl="ragged"`` — the default STREAMING body, a flash-style
-  online-softmax loop over the slot's live blocks.  Online softmax
-  reorders float summation, so the kernel is ALLCLOSE to the oracle
-  (not bitwise); end-to-end, GREEDY streams are asserted
-  token-identical to the XLA arm across the full layout matrix and
-  seeded streams are asserted deterministic (same seed, same stream).
-* ``attn_impl="ragged_gather"`` — the materialize-the-row A/B
-  reference: BITWISE-equal to the oracle on CPU, greedy AND seeded
-  streams token-identical to the XLA arm.
+NUMERICS CONTRACT: ``attn_impl="ragged"`` is a flash-style
+online-softmax loop over the slot's live blocks.  Online softmax
+reorders float summation, so the kernel is ALLCLOSE to the oracle
+(not bitwise); end-to-end, GREEDY streams are asserted
+token-identical to the XLA arm across the full layout matrix and
+seeded streams are asserted deterministic (same seed, same stream).
 
 Tests marked ``longctx`` cover prompts spanning many KV blocks — the
 streaming kernel's O(block_size x window) working-set claim; the
@@ -108,14 +103,12 @@ def _kernel_oracle(q, k_flat, v_flat, tables, pos, width, bs):
 
 
 @pytest.mark.pallas
-@pytest.mark.parametrize("variant", ["stream", "gather"])
-def test_kernel_matches_oracle(variant):
+def test_kernel_matches_oracle():
     """Per slot, for real lanes, against the XLA oracle math
-    (``_slot_attn`` over the block-table gather): the GATHER body is
-    BITWISE-equal on CPU; the STREAMING body's online softmax is
-    allclose (block-sequential accumulation reorders the float sums).
-    Width-masked lanes (and whole parked width-0 slots) are zeroed
-    EXACTLY under both bodies."""
+    (``_slot_attn`` over the block-table gather): the kernel's online
+    softmax is allclose (block-sequential accumulation reorders the
+    float sums).  Width-masked lanes (and whole parked width-0 slots)
+    are zeroed EXACTLY."""
     import jax.numpy as jnp
     from paddle_tpu.ops.ragged_paged_attn import ragged_paged_attention
 
@@ -129,53 +122,15 @@ def test_kernel_matches_oracle(variant):
     pos = jnp.asarray(np.array([3, 10, 0, 30], np.int32))
     width = jnp.asarray(np.array([1, 5, 0, 3], np.int32))
     out = np.asarray(ragged_paged_attention(
-        q, k_flat, v_flat, tables, pos, width, block_size=bs,
-        variant=variant))
+        q, k_flat, v_flat, tables, pos, width, block_size=bs))
     ctx = _kernel_oracle(q, k_flat, v_flat, tables, pos, width, bs)
     for b in range(B):
         w = int(width[b])
         if w:
-            if variant == "gather":
-                np.testing.assert_array_equal(out[b, :w], ctx[b, :w])
-            else:
-                np.testing.assert_allclose(out[b, :w], ctx[b, :w],
-                                           rtol=2e-5, atol=2e-6)
+            np.testing.assert_allclose(out[b, :w], ctx[b, :w],
+                                       rtol=2e-5, atol=2e-6)
         assert np.all(out[b, w:] == 0.0), \
             "width-masked lanes must be zeroed (width is kernel data)"
-
-
-@pytest.mark.pallas
-def test_kernel_variant_validation():
-    import jax.numpy as jnp
-    from paddle_tpu.ops.ragged_paged_attn import (
-        kernel_working_set_bytes, ragged_paged_attention)
-
-    z = jnp.zeros((1, 1, 1, 4), jnp.float32)
-    with pytest.raises(ValueError, match="variant"):
-        ragged_paged_attention(
-            z, jnp.zeros((8, 1, 4)), jnp.zeros((8, 1, 4)),
-            jnp.zeros((1, 1), jnp.int32), jnp.zeros(1, jnp.int32),
-            jnp.ones(1, jnp.int32), block_size=8, variant="bogus")
-    with pytest.raises(ValueError, match="variant"):
-        kernel_working_set_bytes(variant="bogus", block_size=8,
-                                 blocks_per_slot=4, width=4,
-                                 num_heads=2, head_dim=8)
-    # the analytic VMEM proxy: streaming is FLAT in context length,
-    # gather grows linearly with it
-    args = dict(block_size=8, width=4, num_heads=2, head_dim=8)
-    s4 = kernel_working_set_bytes(variant="stream",
-                                  blocks_per_slot=4, **args)
-    s64 = kernel_working_set_bytes(variant="stream",
-                                   blocks_per_slot=64, **args)
-    g4 = kernel_working_set_bytes(variant="gather",
-                                  blocks_per_slot=4, **args)
-    g8 = kernel_working_set_bytes(variant="gather",
-                                  blocks_per_slot=8, **args)
-    g64 = kernel_working_set_bytes(variant="gather",
-                                   blocks_per_slot=64, **args)
-    assert s4 == s64, "streaming working set must not grow with blocks"
-    assert g64 - g4 == 15 * (g8 - g4), "gather grows linearly"
-    assert g64 > 10 * s64
 
 
 @pytest.mark.pallas
@@ -198,17 +153,17 @@ def test_kernel_stream_allclose_long_tables():
     pos = jnp.asarray(np.array([100, 127 - 5, 64], np.int32))
     width = jnp.asarray(np.array([1, 5, 3], np.int32))
     out = np.asarray(ragged_paged_attention(
-        q, k_flat, v_flat, tables, pos, width, block_size=bs,
-        variant="stream"))
+        q, k_flat, v_flat, tables, pos, width, block_size=bs))
     ctx = _kernel_oracle(q, k_flat, v_flat, tables, pos, width, bs)
     for b in range(B):
         w = int(width[b])
         np.testing.assert_allclose(out[b, :w], ctx[b, :w],
                                    rtol=2e-5, atol=2e-6)
         assert np.all(out[b, w:] == 0.0)
-    # int8 codes + per-block scales: stream and gather dequantize the
-    # same blocks, so they agree to float-reassociation tolerance at
-    # long context too
+    # int8 codes + per-block scales: the kernel dequantizes each
+    # streamed block, so it agrees with the oracle over the
+    # dequantized rows to float-reassociation tolerance at long
+    # context too
     ck = jnp.asarray(rng.randint(-127, 128, (NB * bs, H, hd))
                      .astype(np.int8))
     cv = jnp.asarray(rng.randint(-127, 128, (NB * bs, H, hd))
@@ -217,27 +172,31 @@ def test_kernel_stream_allclose_long_tables():
                      .astype(np.float32))
     vs = jnp.asarray(rng.uniform(0.01, 0.05, (NB, H))
                      .astype(np.float32))
-    sq = ragged_paged_attention(q, ck, cv, tables, pos, width,
-                                block_size=bs, k_scale=ks, v_scale=vs,
-                                variant="stream")
-    gq = ragged_paged_attention(q, ck, cv, tables, pos, width,
-                                block_size=bs, k_scale=ks, v_scale=vs,
-                                variant="gather")
-    np.testing.assert_allclose(np.asarray(sq), np.asarray(gq),
-                               rtol=2e-5, atol=2e-6)
+    sq = np.asarray(ragged_paged_attention(
+        q, ck, cv, tables, pos, width, block_size=bs, k_scale=ks,
+        v_scale=vs))
+
+    def deq(codes, scale):
+        rows = codes.astype(jnp.float32).reshape(NB, bs, H, hd)
+        return (rows * scale[:, None, :, None]).reshape(NB * bs, H, hd)
+
+    ctx = _kernel_oracle(q, deq(ck, ks), deq(cv, vs), tables, pos,
+                         width, bs)
+    for b in range(B):
+        w = int(width[b])
+        np.testing.assert_allclose(sq[b, :w], ctx[b, :w],
+                                   rtol=2e-5, atol=2e-5)
 
 
 @pytest.mark.pallas
 def test_kernel_compiled_lowering_on_tpu():
-    """What Mosaic says about each body, settled WITHOUT a chip: the
+    """What Mosaic says about the kernel, settled WITHOUT a chip: the
     installed libtpu compiles for a compile-only ``TPU v5 lite``
-    topology device (``compile_check``).  The STREAMING body lowers
+    topology device (``compile_check``).  The body lowers
     at the shapes that matter — bf16 pools, H=16, hd=128, block 16,
     a chunk window, the per-shard head counts of mp=2/4, f32 and int8
-    pools — and is refused below one lane tile of head_dim.  The
-    GATHER body is refused outright (scalar reads from VMEM blocks)
-    and is not repaired.  Agreement of the compiled kernel with the
-    XLA path is a chip matter: ``chip_smoke.py`` checks it there."""
+    pools — and is refused below one lane tile of head_dim.
+    Agreement of the compiled kernel with the XLA path is a chip matter: ``chip_smoke.py`` checks it there."""
     import jax.numpy as jnp
     from jax.experimental import topologies
     from paddle_tpu.ops.ragged_paged_attn import compile_check
@@ -256,10 +215,6 @@ def test_kernel_compiled_lowering_on_tpu():
     with pytest.raises(Exception, match="aligned to tiling"):
         compile_check(**{**c0, "window": 1, "dtype": jnp.bfloat16,
                          "head_dim": 64})
-    with pytest.raises(Exception,
-                       match="cannot statically prove that index"):
-        compile_check(**c0, window=1, dtype=jnp.bfloat16,
-                      variant="gather")
 
 
 def test_latent_pool_is_updated_in_place_on_the_v5e():
@@ -347,18 +302,6 @@ def test_attn_impl_validation(tiny_gpt):
         _engine(tiny_gpt, attn_impl="bogus")
     with pytest.raises(ValueError, match="paged"):
         _engine(tiny_gpt, attn_impl="ragged", kv_block_size=None)
-    with pytest.raises(ValueError, match="device"):
-        _engine(tiny_gpt, attn_impl="ragged", sample_mode="host")
-    # the gather A/B reference shares the ragged constraints
-    with pytest.raises(ValueError, match="paged"):
-        _engine(tiny_gpt, attn_impl="ragged_gather",
-                kv_block_size=None)
-    with pytest.raises(ValueError, match="device"):
-        _engine(tiny_gpt, attn_impl="ragged_gather",
-                sample_mode="host")
-    assert _engine(tiny_gpt,
-                   attn_impl="ragged_gather").attn_impl \
-        == "ragged_gather"
     # the engine inherits the model's knob when not overridden
     paddle.seed(0)
     m = GPTModel.from_config("tiny", dropout=0.0, attn_impl="ragged")
@@ -387,11 +330,9 @@ def test_ragged_parity_vs_xla_oracle(tiny_gpt, cfg):
     STREAMING kernel as the ``attn_impl="ragged"`` default: GREEDY
     streams are token-identical to the XLA oracle across paged plain
     / chunked / spec / int8-KV dispatch shapes at async depth 1 and 2
-    — and equal per-request ``generate()``.  (Seeded-stream
-    guarantees: determinism under streaming —
-    ``test_ragged_stream_seeded_deterministic`` — and bitwise arm
-    identity under the gather body —
-    ``test_ragged_gather_parity_vs_xla_oracle``.)"""
+    — and equal per-request ``generate()``.  (The seeded-stream
+    guarantee is determinism:
+    ``test_ragged_stream_seeded_deterministic``.)"""
     prompts = _prompts(4)
     xla, _ = _serve_mixed(tiny_gpt, prompts, greedy_only=True,
                           attn_impl="xla", **cfg)
@@ -411,59 +352,11 @@ def test_ragged_parity_vs_xla_oracle(tiny_gpt, cfg):
 
 
 @pytest.mark.pallas
-@pytest.mark.parametrize("cfg", [
-    dict(async_depth=2),
-    dict(prefill_chunk=8, spec_k=3, async_depth=2),
-], ids=["plain-d2", "chunked-spec-d2"])
-def test_ragged_gather_parity_vs_xla_oracle(tiny_gpt, cfg):
-    """The A/B reference keeps the ORIGINAL contract: greedy AND
-    seeded streams under ``attn_impl="ragged_gather"`` are
-    token-identical to the XLA oracle (bitwise kernel math).
-
-    Chunked configs run the concurrent mix ALL-GREEDY plus a
-    separate seeded single-request parity check: ragged chunk lanes
-    pipeline the final chunk ahead of the first decode tick, so a
-    neighbor finishes a tick later than under the XLA arm, and under
-    the repo's rbg PRNG a CONCURRENT seeded draw depends on that
-    co-scheduling (the PR10-documented property — XLA depth1 vs
-    depth2 seeded chunked streams diverge for exactly the same
-    reason).  With co-scheduling arm-stable (no chunking, or a
-    single request), seeded streams are bitwise arm-identical."""
-    prompts = _prompts(4)
-    chunked = "prefill_chunk" in cfg
-    if chunked:
-        xla, _ = _serve_mixed(tiny_gpt, prompts, greedy_only=True,
-                              attn_impl="xla", **cfg)
-        rag, eng = _serve_mixed(tiny_gpt, prompts, greedy_only=True,
-                                attn_impl="ragged_gather", **cfg)
-        seeded = {}
-        for impl in ("xla", "ragged_gather"):
-            e2 = _engine(tiny_gpt, attn_impl=impl, **cfg)
-            r = e2.submit(prompts[1], max_new_tokens=10,
-                          temperature=0.8, top_p=0.9, seed=42)
-            e2.run_until_idle()
-            seeded[impl] = r.result(timeout=2).tolist()
-        assert seeded["xla"] == seeded["ragged_gather"]
-    else:
-        xla, _ = _serve_mixed(tiny_gpt, prompts, attn_impl="xla",
-                              **cfg)
-        rag, eng = _serve_mixed(tiny_gpt, prompts,
-                                attn_impl="ragged_gather", **cfg)
-    assert xla == rag
-    greedy_lanes = range(4) if chunked else (0, 2)
-    for i in greedy_lanes:
-        assert rag[i] == _ref(tiny_gpt, prompts[i], 6).tolist()
-    if eng.prefix_cache is not None:
-        eng.prefix_cache.clear()
-    assert eng.block_pool.in_use() == 0
-
-
-@pytest.mark.pallas
 def test_ragged_stream_seeded_deterministic(tiny_gpt):
     """The streaming kernel's seeded contract: same seed => same
     stream, run-for-run (online softmax reorders float summation, so
-    bitwise-vs-XLA is the gather body's guarantee, not this one —
-    but a seeded stream must still be reproducible)."""
+    nothing is bitwise-vs-XLA — but a seeded stream must still be
+    reproducible)."""
     p = _prompts(1)[0]
     runs = []
     for _ in range(2):
@@ -535,7 +428,10 @@ def test_ragged_compile_matrix_collapse():
     programs under ``attn_impl="ragged"`` than under the XLA path —
     the (chunk shape, spec_k) matrix collapses to exactly ONE
     ``ragged_window`` program — and a second traffic wave compiles
-    NOTHING on either arm (no steady-state thrash)."""
+    NOTHING on either arm (no steady-state thrash).  The dispatch
+    count collapses with it: the XLA arm pays one program call per
+    prefill chunk beside its fused ticks, the ragged arm's chunks ride
+    inside the window dispatch."""
     prompts = _prompts(6)
 
     def wave(eng):
@@ -544,7 +440,7 @@ def test_ragged_compile_matrix_collapse():
         for r in reqs:
             r.result(timeout=2)
 
-    counts = {}
+    counts, dispatches = {}, {}
     for impl in ("xla", "ragged"):
         paddle.seed(0)
         m = GPTModel.from_config("tiny", dropout=0.0)  # fresh caches
@@ -560,12 +456,16 @@ def test_ragged_compile_matrix_collapse():
         assert c2 == c1, \
             f"{impl}: second wave recompiled ({c1} -> {c2})"
         counts[impl] = c1
+        dispatches[impl] = reg.get("serving.fused_sample_ticks").value \
+            + (reg.get("serving.prefill_chunks").value
+               if impl == "xla" else 0)
         if impl == "ragged":
             # exactly one program serves decode + spec-verify +
             # chunk-prefill — the collapse, not just a reduction
             assert c1 == 1
             assert len(m._ragged_window_fn_cache) == 1
     assert counts["ragged"] < counts["xla"]
+    assert dispatches["ragged"] < dispatches["xla"], dispatches
 
 
 @pytest.mark.pallas
@@ -608,7 +508,7 @@ def test_ragged_healthz_debug_and_trace_span(tiny_gpt):
     """/healthz and /debug/requests report the kernel selection AND
     the max observed context length, the trace carries
     ``decode.ragged_stream`` spans (never the XLA path's
-    ``decode.dispatch``, nor the gather body's ``decode.ragged``) so
+    ``decode.dispatch``) so
     traces distinguish kernel dispatches, and the per-tick block-walk
     gauge is populated."""
     from paddle_tpu.serving.httpd import _Handler
@@ -643,7 +543,6 @@ def test_ragged_healthz_debug_and_trace_span(tiny_gpt):
     names = {ev.get("name")
              for ev in eng.chrome_trace()["traceEvents"]}
     assert "decode.ragged_stream" in names
-    assert "decode.ragged" not in names
     assert "decode.dispatch" not in names
     # block-walk attribution: the last dispatch walked >= 1 block
     assert eng.registry.get(
@@ -651,31 +550,19 @@ def test_ragged_healthz_debug_and_trace_span(tiny_gpt):
 
 
 @pytest.mark.pallas
-def test_ragged_gather_trace_span_and_walk_gauge(tiny_gpt):
-    """The A/B arm keeps its own span name (``decode.ragged``) and
-    always walks the FULL per-slot table — its walk gauge reads
-    lanes x blocks_per_slot where the streaming arm's reads the live
-    horizon, which is the per-tick cost the A/B exists to show."""
-    streams = {}
-    for impl in ("ragged", "ragged_gather"):
-        eng = _engine(tiny_gpt, num_slots=2, attn_impl=impl)
-        r = eng.submit(_prompts(1)[0], max_new_tokens=4)
-        eng.run_until_idle()
-        streams[impl] = r.result(timeout=2).tolist()
-        names = {ev.get("name")
-                 for ev in eng.chrome_trace()["traceEvents"]}
-        walked = eng.registry.get(
-            "serving.kv_blocks_walked_per_tick").value
-        if impl == "ragged_gather":
-            assert "decode.ragged" in names
-            assert "decode.ragged_stream" not in names
-            # one live lane on the final tick, full table walked
-            assert walked == eng._bps
-        else:
-            assert "decode.ragged_stream" in names
-            assert walked < eng._bps  # a 5..9-token stream's horizon
-    # A/B serves the same greedy tokens
-    assert streams["ragged"] == streams["ragged_gather"]
+def test_ragged_trace_span_and_walk_gauge(tiny_gpt):
+    """The kernel's dispatches carry their own span name and the walk
+    gauge reads the live horizon, not the whole per-slot table."""
+    eng = _engine(tiny_gpt, num_slots=2, attn_impl="ragged")
+    r = eng.submit(_prompts(1)[0], max_new_tokens=4)
+    eng.run_until_idle()
+    r.result(timeout=2)
+    names = {ev.get("name")
+             for ev in eng.chrome_trace()["traceEvents"]}
+    assert "decode.ragged_stream" in names
+    # one live lane on the final tick: a 5..9-token stream's horizon
+    assert 1 <= eng.registry.get(
+        "serving.kv_blocks_walked_per_tick").value < eng._bps
 
 
 @pytest.mark.pallas
@@ -732,14 +619,14 @@ def _long_prompt(n, seed=3):
 def test_longctx_greedy_identity(long_gpt, cfg):
     """Tier-1 long-context twin: a prompt spanning MANY KV blocks
     (>= 8x block_size) decodes greedily token-identical across the
-    XLA oracle, the streaming kernel, and the gather A/B — and (fp
+    XLA oracle and the streaming kernel — and (fp
     engines) equals per-request ``generate()``.  This is the
     engine-level face of the kernel allclose test: reassociated float
     sums at 13+ blocks still never flip a greedy pick on a real
     checkpoint's logit margins."""
     p = _long_prompt(100)                       # 13 blocks of 8
     streams = {}
-    for impl in ("xla", "ragged", "ragged_gather"):
+    for impl in ("xla", "ragged"):
         eng = _engine(long_gpt, num_slots=2, max_seq_len=128,
                       attn_impl=impl, **cfg)
         r = eng.submit(p, max_new_tokens=8)
@@ -747,8 +634,13 @@ def test_longctx_greedy_identity(long_gpt, cfg):
         streams[impl] = r.result(timeout=5).tolist()
         assert eng.debug_requests()["engine"]["max_context_len"] \
             == len(p) + 8
-    assert streams["xla"] == streams["ragged"] \
-        == streams["ragged_gather"]
+    # the kernel's last dispatch walked its one live lane to the
+    # causal horizon (107 positions = 14 blocks of 8), not the
+    # 16-block table
+    assert eng.registry.get(
+        "serving.kv_blocks_walked_per_tick").value \
+        == (len(p) + 8 - 2) // 8 + 1 == 14
+    assert streams["xla"] == streams["ragged"]
     if cfg.get("kv_dtype") is None:
         assert streams["ragged"] == _ref(long_gpt, p, 8).tolist()
 

@@ -194,7 +194,7 @@ def test_pick_excludes_draining_and_dead():
 
 
 def test_random_routing_arm_is_seeded():
-    """affinity=False (the bench baseline) picks by seeded hash:
+    """affinity=False (the A/B baseline) picks by seeded hash:
     deterministic per (seed, request, attempt), spread over the
     pool."""
     def run(seed):
@@ -512,6 +512,44 @@ def test_probe_states_from_real_engine(tiny_gpt):
                          ("probe", "r0", DEGRADED),
                          ("probe", "r0", DEAD),
                          ("probe", "r0", HEALTHY)]
+
+
+def test_affinity_routing_keeps_prefix_hits_over_random(tiny_gpt):
+    """Three replicas, interleaved repeats of three system prompts:
+    the greedy ids do not depend on the routing policy, and affinity
+    routing adopts at least as many cached prompt tokens fleet-wide
+    as seeded-random routing does — every repeat of a class lands on
+    the replica whose prefix cache holds its blocks."""
+    rng = np.random.RandomState(0)
+    sys_prompts = [rng.randint(0, 128, (16,)).tolist()
+                   for _ in range(3)]
+    jobs = [sys_prompts[i % 3]
+            + rng.randint(0, 128, (1 + i % 3,)).tolist()
+            for i in range(12)]
+
+    def run(affinity):
+        engines = [_engine(tiny_gpt) for _ in range(3)]
+        r = _router({f"r{i}": InProcessReplica(f"r{i}", e)
+                     for i, e in enumerate(engines)},
+                    affinity=affinity)
+        for e in engines:
+            e.start()
+        try:
+            r.probe_once()
+            outs = [r.generate(list(p), max_new_tokens=3)["ids"]
+                    for p in jobs]
+        finally:
+            for e in engines:
+                e.stop(drain=False)
+        return outs, sum(
+            e.registry.get("serving.prefix_hit_tokens").value
+            for e in engines)
+
+    outs_aff, hits_aff = run(True)
+    outs_rand, hits_rand = run(False)
+    assert outs_aff == outs_rand
+    # 9 repeats of a 16-token (two-block) system prompt, all adopted
+    assert hits_aff == 9 * 16 >= hits_rand
 
 
 def test_unstarted_request_fails_over_off_dead_replica(tiny_gpt):

@@ -434,21 +434,16 @@ def test_sharded_ragged_kernel_matches_gspmd_oracle():
     pd = jax.device_put(pos, shards["vec"])
     wd = jax.device_put(width, shards["vec"])
 
-    for variant in ("stream", "gather"):
-        unsharded = np.asarray(ragged_paged_attention(
-            q, k, v, tables, pos, width, block_size=bs,
-            interpret=True, variant=variant))
-        oracle = np.asarray(jax.jit(
-            lambda *a: ragged_paged_attention(
-                *a, block_size=bs, interpret=True,
-                variant=variant))(qd, kd, vd, td, pd, wd))
-        got = np.asarray(sharded_ragged_paged_attention(
-            q, k, v, tables, pos, width, block_size=bs, mesh=mesh,
-            interpret=True, variant=variant))
-        np.testing.assert_allclose(got, oracle, atol=1e-5,
-                                   rtol=1e-5)
-        np.testing.assert_allclose(got, unsharded, atol=1e-5,
-                                   rtol=1e-5)
+    unsharded = np.asarray(ragged_paged_attention(
+        q, k, v, tables, pos, width, block_size=bs, interpret=True))
+    oracle = np.asarray(jax.jit(
+        lambda *a: ragged_paged_attention(
+            *a, block_size=bs, interpret=True))(qd, kd, vd, td, pd, wd))
+    got = np.asarray(sharded_ragged_paged_attention(
+        q, k, v, tables, pos, width, block_size=bs, mesh=mesh,
+        interpret=True))
+    np.testing.assert_allclose(got, oracle, atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(got, unsharded, atol=1e-5, rtol=1e-5)
 
     # int8 quantized pools thread per-block scales through the same
     # specs (P('dp', 'mp')) and dequantize in-loop per shard

@@ -312,10 +312,10 @@ def _serve(eng, prompts, max_new=6):
     {"kv_block_size": 8, "prefill_chunk": 64, "async_depth": 2},
     {"prefill_chunk": 64},
     {"kv_block_size": 8, "spec_k": 3},
-    {"spec_k": 3, "sample_mode": "host"},
+    {"spec_k": 3},
     {"kv_block_size": 8, "kv_dtype": "int8", "prefill_chunk": 64},
 ], ids=["contiguous", "paged", "paged+chunked+depth2", "chunked",
-        "paged+spec", "spec+host", "paged+int8+chunked"])
+        "paged+spec", "spec", "paged+int8+chunked"])
 def test_engine_streams_match_generate_across_chunks(long_gpt, long_refs,
                                                      cfg):
     """Greedy streams whose contexts span one to four chunks of the

@@ -312,43 +312,14 @@ def test_trace_view_wall_summary(tmp_path, capsys):
     assert "decode.ragged" not in out
 
 
-def test_trace_view_surfaces_ragged_kernel_dispatches(tmp_path,
-                                                      capsys):
-    """--wall breaks out ``decode.ragged`` spans (the GATHER-body
-    Pallas dispatches of ``Engine(attn_impl="ragged_gather")``) so
-    a trace shows at a glance whether the kernel or the per-shape XLA
-    programs (``decode.dispatch``) served the tick."""
-    tv = _load_tool("trace_view")
-    events = [
-        {"name": "tick", "ph": "X", "ts": 0.0, "dur": 10000.0,
-         "cat": "tick"},
-        {"name": "decode.ragged", "ph": "X", "ts": 500.0,
-         "dur": 6000.0, "cat": "serving",
-         "args": {"chunks": 1, "w": 8}},
-        {"name": "tick", "ph": "X", "ts": 20000.0, "dur": 10000.0,
-         "cat": "tick"},
-        {"name": "decode.ragged", "ph": "X", "ts": 20500.0,
-         "dur": 5000.0, "cat": "serving"},
-    ]
-    w = tv.wall_summary(events)
-    assert w["ragged_dispatches"] == 2
-    assert w["ragged_ms"] == pytest.approx(11.0)
-    assert w["ragged_stream_dispatches"] == 0
-    path = tmp_path / "ragged.json"
-    path.write_text(json.dumps({"traceEvents": events}))
-    assert tv.main([str(path), "--wall"]) == 0
-    out = capsys.readouterr().out
-    assert "decode.ragged 11.000 ms over 2 Pallas" in out
-    assert "decode.ragged_stream" not in out
-
-
 def test_trace_view_surfaces_ragged_stream_dispatches(tmp_path,
                                                       capsys):
-    """--wall breaks out ``decode.ragged_stream`` spans (the
-    streaming online-softmax dispatches of
-    ``Engine(attn_impl="ragged")``) SEPARATELY from the gather body's
-    ``decode.ragged``, and sums the spans' ``kv_blocks_walked`` arg —
-    per-tick block-walk cost, attributable from a trace alone."""
+    """--wall breaks out ``decode.ragged_stream`` spans (the Pallas
+    dispatches of ``Engine(attn_impl="ragged")``) so a trace shows at
+    a glance whether the kernel or the per-shape XLA programs
+    (``decode.dispatch``) served the tick, and sums the spans'
+    ``kv_blocks_walked`` arg — per-tick block-walk cost, attributable
+    from a trace alone."""
     tv = _load_tool("trace_view")
     events = [
         {"name": "tick", "ph": "X", "ts": 0.0, "dur": 10000.0,
@@ -361,21 +332,17 @@ def test_trace_view_surfaces_ragged_stream_dispatches(tmp_path,
         {"name": "decode.ragged_stream", "ph": "X", "ts": 20500.0,
          "dur": 5000.0, "cat": "serving",
          "args": {"kv_blocks_walked": 14}},
-        {"name": "decode.ragged", "ph": "X", "ts": 26000.0,
-         "dur": 2000.0, "cat": "serving"},
     ]
     w = tv.wall_summary(events)
     assert w["ragged_stream_dispatches"] == 2
     assert w["ragged_stream_ms"] == pytest.approx(11.0)
     assert w["kv_blocks_walked"] == 26
-    assert w["ragged_dispatches"] == 1      # the gather A/B line
     path = tmp_path / "ragged_stream.json"
     path.write_text(json.dumps({"traceEvents": events}))
     assert tv.main([str(path), "--wall"]) == 0
     out = capsys.readouterr().out
     assert "decode.ragged_stream 11.000 ms over 2 streaming" in out
     assert "kv blocks walked 26 (13.0/tick)" in out
-    assert "decode.ragged 2.000 ms over 1 Pallas" in out
 
 
 def test_trace_view_surfaces_offload_transfers(tmp_path, capsys):

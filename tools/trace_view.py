@@ -80,7 +80,7 @@ def wall_summary(events):
     other complete-event, ``overlap_ms``/``d2h_wait_ms`` the async
     loop's own attribution spans.  phase/wall > 1 means concurrency
     (work hidden behind device compute), not an accounting bug."""
-    wall = phase = overlap = d2h_wait = ragged = 0.0
+    wall = phase = overlap = d2h_wait = 0.0
     ragged_stream = 0.0
     kv_blocks_walked = 0
     allgather = shard_sync = 0.0
@@ -88,7 +88,7 @@ def wall_summary(events):
     sup_restart = drain_mig = dequant = 0.0
     lora_swap = stream_emit = 0.0
     off_demote = off_promote = 0.0
-    n_ticks = n_ragged = n_ragged_stream = n_allgather = 0
+    n_ticks = n_ragged_stream = n_allgather = 0
     n_migrations = 0
     n_restarts = n_drain_migs = n_dequants = 0
     n_lora_swaps = n_stream_emits = 0
@@ -121,21 +121,14 @@ def wall_summary(events):
                 mig_wire += dur
             elif name == "migrate.import":
                 mig_import += dur
-            elif name == "decode.ragged":
-                # Pallas ragged-paged-attention dispatches, GATHER
-                # body (Engine(attn_impl="ragged_gather")) — broken
-                # out so a trace shows at a glance whether the kernel
-                # or the per-shape XLA programs (decode.dispatch)
-                # served it
-                ragged += dur
-                n_ragged += 1
             elif name == "decode.ragged_stream":
-                # streaming online-softmax ragged dispatches
-                # (Engine(attn_impl="ragged"), the default ragged
-                # body) — separate from decode.ragged so an A/B trace
-                # prices the two kernel bodies side by side; the
-                # span's kv_blocks_walked arg sums each lane's causal
-                # horizon, so block-walk cost is attributable per tick
+                # Pallas ragged-paged-attention dispatches
+                # (Engine(attn_impl="ragged")) — broken out so a trace
+                # shows at a glance whether the kernel or the
+                # per-shape XLA programs (decode.dispatch) served it;
+                # the span's kv_blocks_walked arg sums each lane's
+                # causal horizon, so block-walk cost is attributable
+                # per tick
                 ragged_stream += dur
                 n_ragged_stream += 1
                 kv_blocks_walked += int(
@@ -193,7 +186,7 @@ def wall_summary(events):
                 # — gather-side dequant rides inside the compiled
                 # program, so this is the per-tick cost of serving
                 # codes+scales instead of fp blocks, nested inside
-                # decode.dispatch/decode.ragged (double-counted in
+                # decode.dispatch/decode.ragged_stream (double-counted in
                 # phase_ms like every nested span)
                 dequant += dur
                 n_dequants += 1
@@ -203,7 +196,6 @@ def wall_summary(events):
         "per_tick_phase_ms": (phase / n_ticks if n_ticks
                               else float("nan")),
         "overlap_ms": overlap, "d2h_wait_ms": d2h_wait,
-        "ragged_ms": ragged, "ragged_dispatches": n_ragged,
         "ragged_stream_ms": ragged_stream,
         "ragged_stream_dispatches": n_ragged_stream,
         "kv_blocks_walked": kv_blocks_walked,
@@ -307,11 +299,6 @@ def format_wall(w):
             "online-softmax dispatches (attn_impl='ragged')   "
             f"kv blocks walked {w['kv_blocks_walked']} "
             f"({per:.1f}/tick)")
-    if w.get("ragged_dispatches"):
-        lines.append(
-            f"decode.ragged {w['ragged_ms']:.3f} ms over "
-            f"{w['ragged_dispatches']} Pallas ragged-kernel "
-            "dispatches (gather body, attn_impl='ragged_gather')")
     if w.get("allgather_waits") or w.get("shard_sync_ms"):
         lines.append(
             f"decode.allgather {w['allgather_ms']:.3f} ms over "
