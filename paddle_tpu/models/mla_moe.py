@@ -129,6 +129,59 @@ def walk_rows(pos, ahead, table_rows, block_size):
     return -(-items // group) * group * chunk
 
 
+def write_chunk_rows(pool, rows, table, pos, true_len, scratch):
+    """One slot's chunk of cache rows into the blocks its table names:
+    row i of ``rows`` [C, w] becomes row ``pos + i`` of the slot for
+    ``i < true_len``, by one in-place update a block the chunk can
+    touch — ``ceil((C + bs - 1) / bs)`` of them wherever ``pos`` lies
+    in its block (17 for 256 rows in blocks of 16).  Each block is read
+    as it lies, the rows that land in it are laid over it and it is
+    written back, so nothing else changes: rows below ``pos`` and at or
+    past ``pos + true_len`` (the pad lanes land nowhere), and the
+    pool's lane padding (columns ``>= w``).  A block no row lands in —
+    past the last real row, where the table may hold another chunk's
+    worth of the slot's reservation, ``scratch``, or end — is taken to
+    be ``scratch`` and gets back what it held; a table index past the
+    table's end is never formed.  The blocks that are written are the
+    slot's own: a prefix adopted from the cache lies wholly below
+    ``pos``.
+
+    Why not ``pool.at[blocks, offs, :w].set(rows)``, which says the
+    same: the v5e compiler turns that scatter into a loop of one trip
+    a row, seven small operations each with the pool in the carry,
+    4.9 us a trip — 9.87 ms for the nine layers of a 256-row chunk,
+    against 0.19 ms for these updates (0.13 ms for ``C // bs`` whole
+    blocks written without being read, which holds only for a chunk
+    that starts at a block's edge), and 31.4 -> 20.1 ms a chunk
+    program in the serving cell (chip runs, PR 32;
+    ``decode_slots_paged``'s 32-trip twin, PR 30).
+
+    pool [NB, bs, W]; rows [C, w], w <= W; table int32 [L // bs]; pos,
+    true_len, scratch traced scalars.  Returns the pool."""
+    import jax
+    import jax.numpy as jnp
+    (C, w), bs = rows.shape, pool.shape[1]
+    n = (C + 2 * bs - 2) // bs
+    first, off = pos // bs, pos % bs
+    # the chunk as whole blocks: its rows start ``off`` rows into the
+    # first one
+    new = jax.lax.dynamic_update_slice(
+        jnp.zeros((n * bs, w), pool.dtype), rows.astype(pool.dtype),
+        (off, 0)).reshape(n, bs, w)
+    at = jnp.arange(n * bs) - off
+    lands = ((at >= 0) & (at < true_len)).reshape(n, bs, 1)
+    blocks = jnp.where(
+        jnp.any(lands, axis=(1, 2)),
+        table[jnp.minimum(first + jnp.arange(n), table.shape[0] - 1)],
+        scratch)
+    for j in range(n):
+        held = jax.lax.dynamic_slice(pool, (blocks[j], 0, 0), (1, bs, w))
+        pool = jax.lax.dynamic_update_slice(
+            pool, jnp.where(lands[j], new[j], held[0])[None],
+            (blocks[j], 0, 0))
+    return pool
+
+
 def rms_norm(x, weight, eps):
     """``weight * x / sqrt(mean(x^2) + eps)``, the mean in float32."""
     import jax
@@ -488,9 +541,12 @@ class MLAttention(nn.Layer):
         # Written into the pool as it lies ([block, row in block]:
         # through a flattened view the v5e compiler copies the whole
         # pool every step, 264 MB a layer; chip run, PR 28), one
-        # in-place update a slot: the scatter that says the same is
-        # compiled to a loop of 32 trips of six operations, 1.25 ms a
-        # step over nine layers against 0.29 ms (chip run, PR 30).
+        # in-place update a slot.  The scatter that says the same
+        # (``pool.at[blocks, offs, :row].set(new)``) is compiled to a
+        # loop of one trip a row, small operations with the pool in
+        # the carry: 32 trips here, 1.25 ms a step over nine layers
+        # against 0.29 ms for these updates (chip run, PR 30); for the
+        # chunk program's 256 trips see ``write_chunk_rows``.
         blocks = tables[jnp.arange(h.shape[0]), pos // bs]
         offs, new = pos % bs, row.astype(pool.dtype)
         for b in range(h.shape[0]):
@@ -503,22 +559,23 @@ class MLAttention(nn.Layer):
     def prefill_chunk_paged(self, h, pool, table, pos, true_len,
                             scratch=0):
         """C prompt tokens of ONE slot at positions ``pos..pos+C-1``:
-        their rows scatter through the slot's table (pad lanes, >=
-        ``true_len``, into the ``scratch`` block), then the chunk
-        attends causally over the slot's rows, the adopted prefix and
-        itself included, in the form ``absorbed_wins`` picks for C.
+        the rows of the first ``true_len`` go into the slot's blocks
+        (``write_chunk_rows``: the pool updated as it lies, a block at
+        a time, as ``decode_slots_paged`` updates it a row at a time,
+        and for the same reason — the scatter that says the same is
+        compiled to a loop of one trip a row; the pad lanes are
+        written nowhere), then the chunk attends causally over the
+        slot's rows, the adopted prefix and itself included, in the
+        form ``absorbed_wins`` picks for C.  ``pos`` may lie anywhere
+        in its block (the engine starts a chunk where the last one
+        ended, at a block's edge when C is a whole number of blocks).
         h [1, C, D]; table [L // bs]; pos / true_len / scratch traced
         scalars.  Returns (out [1, C, D], pool)."""
         import jax.numpy as jnp
-        C = h.shape[1]
-        offs = pos + jnp.arange(C)
-        q_n, q_r, row = self.project(h, offs[None, :])
-        bs = pool.shape[1]
-        valid = jnp.arange(C) < true_len
-        safe = jnp.where(valid, offs, 0)
-        pool = pool.at[jnp.where(valid, table[safe // bs], scratch),
-                       jnp.where(valid, safe % bs, 0), :self.row].set(
-            row[0].astype(pool.dtype))
+        q_n, q_r, row = self.project(
+            h, (pos + jnp.arange(h.shape[1]))[None, :])
+        pool = write_chunk_rows(pool, row[0], table, pos, true_len,
+                                scratch)
         out = self.attend(q_n, q_r, pool, table[None, :],
                           jnp.reshape(pos, (1,)))
         return _lin(self.o_proj, out), pool

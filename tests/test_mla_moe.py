@@ -113,11 +113,19 @@ def paged_logits(model, ids, chunk, n_decode, bs=8, nb=12):
     (40, 16, 6),      # whole chunks and a tail, several blocks
     (23, 8, 3),       # a chunk that ends inside a block
     (9, 16, 5),       # one short chunk: the absorbed form (9 < 170)
+    # 84 rows of a table of 88: the last chunk starts at 80 and its
+    # pad lanes' block lies past the table's end
+    (86, 16, 2),
+    (30, 12, 4),      # chunks of 1.5 blocks: every other starts mid-block
     # ragged lengths side by side through the engine's decode program,
     # whose walk is the work list (items of 16 rows, see ``short_walk``)
     ((70, 3, 33, 17, 100), 16, 9),    # one long lane among short ones
     ((15, 16, 17, 31, 32, 33), 8, 6),  # lengths at an item's edge
     ((5, 90, 5, 60), 32, 12),         # 2 slots: lanes parked between
+    # an engine whose chunk is no whole number of blocks: chunks start
+    # mid-block, and the longest prompt's last one (108..112 of 120)
+    # could touch a block past the table's end
+    ((30, 7, 41, 113), 12, 5),
 ])
 def test_paged_prefill_then_decode_equals_the_reference(
         n, chunk, n_decode, short_walk):
@@ -126,7 +134,8 @@ def test_paged_prefill_then_decode_equals_the_reference(
         # every served token is the reference's best token over what
         # came before it (teacher-forced, one forward a request)
         eng = Engine(model, num_slots=2 if len(n) == 4 else 4,
-                     max_seq_len=128, kv_block_size=8, kv_blocks=72,
+                     max_seq_len=128 - 128 % chunk, kv_block_size=8,
+                     kv_blocks=72,
                      prefill_chunk=chunk, prefix_cache=False,
                      registry=monitor.StatRegistry())
         prompts = [tokens(k, seed=k)[0].tolist() for k in n]
@@ -150,6 +159,102 @@ def test_paged_prefill_then_decode_equals_the_reference(
                                           ids[None]))[0]
     got, _ = paged_logits(model, ids, chunk, n_decode)
     assert np.abs(got - want[n - n_decode - 1:]).max() < TOL
+
+
+def _scattered(pool, rows, table, pos, true_len, scratch):
+    """What the chunk program did before PR 32, kept as the oracle:
+    one scatter of the chunk's rows through the table, the pad lanes
+    (``>= true_len``) into row 0 of the ``scratch`` block."""
+    bs = pool.shape[1]
+    at = pos + jnp.arange(rows.shape[0])
+    valid = jnp.arange(rows.shape[0]) < true_len
+    safe = jnp.where(valid, at, 0)
+    return pool.at[jnp.where(valid, table[safe // bs], scratch),
+                   jnp.where(valid, safe % bs, 0), :rows.shape[1]].set(rows)
+
+
+@pytest.mark.parametrize("chunk, bs, owned, pos, true_len", [
+    (32, 8, 12, 16, 32),     # a whole chunk at a block's edge
+    (32, 8, 12, 16, 13),     # a last chunk: it ends inside a block
+    (32, 8, 12, 64, 32),     # ... and ends with the table
+    (32, 8, 12, 80, 9),      # blocks of pad lanes past the table's end
+    (32, 8, 5, 24, 11),      # ... and past the slot's reservation
+    (32, 8, 12, 19, 32),     # a chunk that starts inside a block
+    (32, 8, 12, 91, 5),      # ... in the table's last block
+    (12, 8, 12, 36, 12),     # a chunk of one and a half blocks
+    (5, 8, 12, 17, 5),       # a chunk inside one block
+    (16, 16, 6, 32, 1),      # one row
+])
+def test_a_chunk_writes_the_rows_the_scatter_wrote(chunk, bs, owned, pos,
+                                                  true_len):
+    """``write_chunk_rows`` against the scatter it replaced, bit for
+    bit: every row of every block but ``scratch`` (the slot's rows
+    below its new cursor among them), the pool's lane padding, and —
+    where the scatter parked its pad lanes — the ``scratch`` block as
+    it was.  The table is 12 blocks long; the slot owns the first
+    ``owned`` and the rest are ``scratch``."""
+    rng = np.random.default_rng(pos * 100 + true_len)
+    pool = jnp.asarray(rng.normal(size=(40, bs, 24)), jnp.float32)
+    table = np.zeros(12, np.int32)
+    table[:owned] = rng.permutation(np.arange(1, 40))[:owned]
+    rows = jnp.asarray(rng.normal(size=(chunk, 20)), jnp.float32)
+    assert pos + true_len <= owned * bs
+    args = (jnp.asarray(table), jnp.int32(pos), jnp.int32(true_len),
+            jnp.int32(0))
+    got = np.asarray(jax.jit(mla_moe.write_chunk_rows)(pool, rows, *args))
+    want = np.asarray(_scattered(pool, rows, *args))
+    assert np.array_equal(got[1:], want[1:])
+    assert np.array_equal(got[0], np.asarray(pool)[0])
+    # (what the oracle itself holds: the chunk's rows where the table
+    # puts them, nothing else moved)
+    flat = want[table].reshape(-1, 24)
+    assert np.array_equal(flat[pos:pos + true_len, :20],
+                          np.asarray(rows)[:true_len])
+    moved = np.any(want != np.asarray(pool), axis=(1, 2))
+    assert set(np.flatnonzero(moved)) <= set(
+        table[pos // bs:(pos + true_len - 1) // bs + 1]) | {0}
+
+
+def test_a_chunk_after_an_adopted_prefix_leaves_the_shared_blocks():
+    """Two live requests over one cached prefix: the second adopts the
+    first's whole blocks and its chunk starts at their end.  The
+    shared blocks hold the same bytes before and after in every
+    layer's pool, and both requests are served the reference's
+    tokens."""
+    model, leaves = seeded(seed=3)
+    eng = Engine(model, num_slots=2, max_seq_len=128, kv_block_size=8,
+                 kv_blocks=40, prefill_chunk=16,
+                 registry=monitor.StatRegistry())
+    shared = tokens(37, seed=1)[0].tolist()
+    seed_req = eng.submit(shared + [5, 6, 7], max_new_tokens=2)
+    eng.run_until_idle()         # 4 whole blocks of ``shared`` cached
+    first = eng.submit(shared + tokens(9, seed=2)[0].tolist(),
+                       max_new_tokens=12)
+    while len(first.generated) < 3:
+        eng.step()
+    slot, = [i for i, blocks in enumerate(eng._slot_blocks) if blocks]
+    adopted = np.asarray(eng._slot_blocks[slot][:4])
+    before = [np.asarray(p)[adopted] for p in eng.k_pools]
+    hits = eng.registry.get("serving.prefix_hit_tokens").value
+    second = eng.submit(shared + tokens(30, seed=4)[0].tolist(),
+                        max_new_tokens=6)
+    while not second.generated:
+        eng.step()
+    assert not first.done()                 # still live beside it
+    assert eng.registry.get("serving.prefix_hit_tokens").value \
+        == hits + 32
+    other, = [i for i, blocks in enumerate(eng._slot_blocks)
+              if blocks and i != slot]
+    assert list(eng._slot_blocks[other][:4]) == list(adopted)
+    for pool, was in zip(eng.k_pools, before):
+        assert np.array_equal(np.asarray(pool)[adopted], was)
+    eng.run_until_idle()
+    for r in (seed_req, first, second):
+        ids = np.asarray(list(r.result()))
+        want = np.asarray(_reference().logits(
+            getter(leaves), DIMS, ids[None]))[0]
+        n = len(ids) - len(r.generated)
+        assert list(np.argmax(want, axis=-1)[n - 1:-1]) == list(ids[n:])
 
 
 @pytest.mark.parametrize("absorbed", [False, True])

@@ -21,6 +21,7 @@ streaming kernel's O(block_size x window) working-set claim; the
 small-shape twins run in tier-1, the multi-thousand-token leg is
 additionally marked slow.
 """
+import contextlib
 import math
 import re
 
@@ -217,6 +218,71 @@ def test_kernel_compiled_lowering_on_tpu():
                          "head_dim": 64})
 
 
+_POOL_SHAPE = (14337, 16, 640)
+_POOL = "bf16[14337,16,640]"
+
+
+@contextlib.contextmanager
+def _described_v5e():
+    """``sds(shape, dtype=bf16)``: shapes placed on the compile-only
+    ``TPU v5 lite`` device, to lower and compile with.  (A compile for
+    a described device is written to the persistent cache but cannot
+    be read back: the cache is kept out meanwhile.)"""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices[0])
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+            tuple(shape), dtype, sharding=one)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+        compilation_cache.reset_cache()
+
+
+def _latent_step_text(sds, program, *args):
+    """The compiled text of ``program(attn, *args)``, a method of the
+    latent attention at the published widths (16 heads of 128 + 64 /
+    128, rank 512, hidden 2,048, bf16); the pool, 9 layers of 14,337
+    blocks of 16 rows as the row spec stores them, comes second and is
+    donated."""
+    import jax
+    from paddle_tpu import nn
+    from paddle_tpu.jit import _swapped
+    from paddle_tpu.models.mla_moe import MLAttention
+    from paddle_tpu.serving.kvcache import KVRowSpec
+
+    with nn.LazyGuard():
+        attn = MLAttention(2048, 16, 128, 64, 128, 512, 8e5, 1e-5)
+    attn.to(dtype="bfloat16")
+    params = dict(attn.named_parameters())
+    names = sorted(params)
+    assert KVRowSpec(9, "bfloat16", (("latent", (attn.row,)),)
+                     ).pool_shapes((14337, 16)) == [_POOL_SHAPE]
+
+    def step(p_list, *args):
+        with _swapped(params, dict(zip(names, p_list))):
+            return program(attn, *args)
+
+    return jax.jit(step, donate_argnums=(2,)).lower(
+        [sds(params[n].shape) for n in names], *args).compile().as_text()
+
+
+def _pool_copies(text):
+    """Instructions that copy or transpose an array of the pool's
+    shape."""
+    copies = re.compile(r"= " + re.escape(_POOL)
+                        + r"\S* (copy|transpose)\(")
+    return [ln for ln in text.splitlines() if copies.search(ln)]
+
+
 def test_latent_pool_is_updated_in_place_on_the_v5e():
     """The latent decode attention at the published widths (16 heads
     of 128 + 64 / 128, rank 512, 32 slots, 8,192 positions), compiled
@@ -231,57 +297,21 @@ def test_latent_pool_is_updated_in_place_on_the_v5e():
     product compiles at a decode step's and a chunk's shapes."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-    from paddle_tpu import nn
     from paddle_tpu.distributed.moe import grouped_matmul
-    from paddle_tpu.jit import _swapped
     from paddle_tpu.models.mla_moe import (
         MLAttention, walk_chunk, walk_group)
-    from paddle_tpu.serving.kvcache import KVRowSpec
 
-    one = SingleDeviceSharding(topologies.get_topology_desc(
-        platform="tpu", topology_name="v5e:2x2").devices[0])
-
-    def sds(shape, dtype=jnp.bfloat16):
-        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one)
-
-    with nn.LazyGuard():
-        attn = MLAttention(2048, 16, 128, 64, 128, 512, 8e5, 1e-5)
-    attn.to(dtype="bfloat16")
-    params = dict(attn.named_parameters())
-    names = sorted(params)
-    pool, = KVRowSpec(9, "bfloat16", (("latent", (attn.row,)),)
-                      ).pool_shapes((14337, 16))
-    assert pool == (14337, 16, 640)
-
-    def step(p_list, h, pool, tables, pos):
-        with _swapped(params, dict(zip(names, p_list))):
-            return attn.decode_slots_paged(h, pool, tables, pos)
-
-    # (a compile for a described device is written to the persistent
-    # cache but cannot be read back: keep it out)
-    from jax.experimental.compilation_cache import compilation_cache
-    cache = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        text = jax.jit(step, donate_argnums=(2,)).lower(
-            [sds(params[n].shape) for n in names], sds((32, 1, 2048)),
-            sds(pool), sds((32, 512), jnp.int32),
-            sds((32,), jnp.int32)).compile().as_text()
+    with _described_v5e() as sds:
+        text = _latent_step_text(
+            sds, MLAttention.decode_slots_paged, sds((32, 1, 2048)),
+            sds(_POOL_SHAPE), sds((32, 512), jnp.int32),
+            sds((32,), jnp.int32))
         for m, k, n in ((192, 2048, 2816), (1536, 1408, 2048)):
             jax.jit(lambda x, w, g: grouped_matmul(x, w, g, "gmm")).lower(
                 sds((m, k)), sds((64, k, n)),
                 sds((64,), jnp.int32)).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cache)
-        compilation_cache.reset_cache()
-    shape = "bf16[14337,16,640]"
-    assert shape in text
-    copies = re.compile(r"= " + re.escape(shape)
-                        + r"\S* (copy|transpose)\(")
-    assert not [ln for ln in text.splitlines() if copies.search(ln)]
+    assert _POOL in text
+    assert not _pool_copies(text)
     # the walk is a work list (PR 30): no fetch of cached rows is wider
     # than one trip's group of items, 32 x 256 rows of the pool's width
     rows = walk_group(32) * walk_chunk(8192, 16)
@@ -290,6 +320,44 @@ def test_latent_pool_is_updated_in_place_on_the_v5e():
                    r"= bf16\[([\d,]+)\]\S* gather\(", text)]
     assert fetched and max(fetched) == rows * 640
     assert " while(" in text
+
+
+def test_latent_chunk_rows_are_written_in_place_on_the_v5e():
+    """The latent chunk attention at the published widths (a chunk of
+    256 rows, a table of 512 blocks), compiled for the compile-only
+    ``TPU v5 lite`` device: the one loop left is the slot's walk.  The
+    chunk's rows reach the pool by 17 in-place updates of the pool as
+    it lies, a block each; no instruction comes from a scatter (which
+    this compiler makes a loop of 256 trips with the pool in its
+    carry: the second ``while`` this test found at PR 32's parent) and
+    none copies or transposes the pool."""
+    import jax.numpy as jnp
+    from paddle_tpu.models.mla_moe import MLAttention
+
+    with _described_v5e() as sds:
+        text = _latent_step_text(
+            sds, MLAttention.prefill_chunk_paged, sds((1, 256, 2048)),
+            sds(_POOL_SHAPE), sds((512,), jnp.int32), sds((), jnp.int32),
+            sds((), jnp.int32), sds((), jnp.int32))
+    lines = text.splitlines()
+    assert sum(" while(" in ln for ln in lines) == 1
+    assert not [ln for ln in lines
+                if re.search(r" scatter\(|op_name=\"[^\"]*scatter", ln)]
+    assert not _pool_copies(text)
+    # the pool comes back through the updates: the first takes the
+    # donated parameter, each next one the pool of the one before it,
+    # the program returns the last
+    chain = [m.groups() for m in (re.search(
+        r"%(\S+) = " + re.escape(_POOL)
+        + r"\S* dynamic-update-slice\(%([^,]+), ", ln) for ln in lines) if m]
+    assert len(chain) == 17
+    assert [src for _, src in chain[1:]] == [name for name, _ in chain[:-1]]
+    assert [ln for ln in lines if re.search(
+        r"%" + re.escape(chain[0][1]) + r" = " + re.escape(_POOL)
+        + r"\S* parameter\(", ln)]
+    assert [ln for ln in lines if "ROOT" in ln
+            and "%" + chain[-1][0] + ")" in ln]
+    assert "input_output_alias" in lines[0]
 
 
 # -- knob validation --------------------------------------------------
