@@ -257,6 +257,20 @@ def sigmoid_topk_routing(logits, bias, k, scale=1.0, normalize=True):
     return choice.astype(jnp.int32), w * scale
 
 
+def softmax_topk_routing(logits, k, normalize=True):
+    """Softmax-scored top-k (the Qwen3-MoE lineage's gate, which SDAR's
+    routed layers keep): ``p = softmax(logits)`` over all experts in
+    float32; the ``k`` experts are the top ``k`` of ``p``; the weights
+    are their probabilities, renormalised over the selected to sum to
+    one where ``normalize`` (``norm_topk_prob``).  No bias, no scale.
+    logits [T, E] -> (choice [T, k] int32, weights [T, k] float32)."""
+    p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    w, choice = jax.lax.top_k(p, k)
+    if normalize:
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+    return choice.astype(jnp.int32), w
+
+
 def sort_pairs_by_expert(choice, live, num_experts):
     """Sort the T x k (token, expert) pairs by expert.  ``live`` [T]
     marks the rows that are real tokens: the pairs of the others go to
@@ -275,16 +289,26 @@ def _gmm_tiling(m, k, n):
     grouped product: all of a short m in one tile (a decode step's
     pairs: every hit expert then costs one pass over its own weights),
     128 rows otherwise; the whole contraction in one k tile (up to
-    2,048) and the widest n tile of those tried that divides n.  Chip
-    run, PR 28, 40 experts hit, m 192: [2,048 -> 2,816] 0.65 ms at
-    (192, 2048, 1408) against 0.72 at (192, 1024, 1408), [1,408 ->
-    2,048] 0.34 ms at (192, 1408, 1024) against 0.60 at (192, 128,
-    1024) and 0.42 at (192, 1408, 256): 87% and 83% of the weights'
-    time at 819 GB/s."""
+    2,048); the whole of n in one tile where the contraction is at
+    most 1,024 and n at most 2,048, else the widest n tile of those
+    tried that divides n.  Chip run, PR 28, 40 experts hit, m 192:
+    [2,048 -> 2,816] 0.65 ms at (192, 2048, 1408) against 0.72 at
+    (192, 1024, 1408), [1,408 -> 2,048] 0.34 ms at (192, 1408, 1024)
+    against 0.60 at (192, 128, 1024) and 0.42 at (192, 1408, 256): 87%
+    and 83% of the weights' time at 819 GB/s.  Chip run, PR 35
+    (``_chip/gmm_bench.py``), 128 experts hit, m 1,024 / 2,048:
+    [2,048 -> 1,536] 1.166 / 1.226 ms at (128, 2048, 1536) against
+    1.188 / 1.249 at n tile 768, 1.178 / 1.251 at 512, 1.276 / 1.359
+    at k tile 1,024, 1.234 / 1.280 at (256, 2048, 768), and no fit in
+    fast memory at (256, 2048, 1536); [768 -> 2,048] 0.619 / 0.672 ms
+    at (128, 768, 2048) against 0.660 / 0.718 at n tile 1,024, 0.691 /
+    0.756 at 512, 0.647 / 0.683 at (256, 768, 2048): 84% and 79% of the
+    weights' time; ``ragged_dot`` 2.74 and 1.65 ms."""
     tm = m if m <= 256 else 128
     tk = k if k <= 2048 else next(
         t for t in (2048, 1024, 512, 256, 128, k) if k % t == 0)
-    tn = next(t for t in (1408, 1024, 512, 256, 128, n) if n % t == 0)
+    tn = n if k <= 1024 and n <= 2048 else next(
+        t for t in (1536, 1408, 1024, 512, 256, 128, n) if n % t == 0)
     return tm, tk, tn
 
 

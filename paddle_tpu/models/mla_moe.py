@@ -48,8 +48,8 @@ from .. import nn
 from ..core.tensor import Tensor
 from ..nn import initializer as I
 from .programs import (
-    KVRowSpec, ServedModel, ServingSpec, _jit_named, _scoped,
-    sample_lanes, slot_sample_keys)
+    KVRowSpec, ServedModel, ServingSpec, _scoped, sample_lanes,
+    slot_sample_keys)
 
 # cache rows one item of the blocked walk fetches (a whole number of
 # blocks): at 256 the v5e keeps an item's rows on chip (PERF.md, PR 26)
@@ -156,29 +156,33 @@ def write_chunk_rows(pool, rows, table, pos, true_len, scratch):
     program in the serving cell (chip runs, PR 32;
     ``decode_slots_paged``'s 32-trip twin, PR 30).
 
-    pool [NB, bs, W]; rows [C, w], w <= W; table int32 [L // bs]; pos,
+    pool [NB, bs, W]; rows [C, w], w <= W (or, a row of several axes,
+    pool [NB, bs, *W] and rows [C, *W]: the K and V rows ``[heads,
+    hd]`` of ``models/sdar_moe.py``); table int32 [L // bs]; pos,
     true_len, scratch traced scalars.  Returns the pool."""
     import jax
     import jax.numpy as jnp
-    (C, w), bs = rows.shape, pool.shape[1]
+    (C, *w), bs = rows.shape, pool.shape[1]
+    zeros = (0,) * len(w)
     n = (C + 2 * bs - 2) // bs
     first, off = pos // bs, pos % bs
     # the chunk as whole blocks: its rows start ``off`` rows into the
     # first one
     new = jax.lax.dynamic_update_slice(
-        jnp.zeros((n * bs, w), pool.dtype), rows.astype(pool.dtype),
-        (off, 0)).reshape(n, bs, w)
+        jnp.zeros((n * bs, *w), pool.dtype), rows.astype(pool.dtype),
+        (off, *zeros)).reshape(n, bs, *w)
     at = jnp.arange(n * bs) - off
-    lands = ((at >= 0) & (at < true_len)).reshape(n, bs, 1)
+    lands = ((at >= 0) & (at < true_len)).reshape(n, bs, *(1 for _ in w))
     blocks = jnp.where(
-        jnp.any(lands, axis=(1, 2)),
+        jnp.any(lands, axis=tuple(range(1, lands.ndim))),
         table[jnp.minimum(first + jnp.arange(n), table.shape[0] - 1)],
         scratch)
     for j in range(n):
-        held = jax.lax.dynamic_slice(pool, (blocks[j], 0, 0), (1, bs, w))
+        held = jax.lax.dynamic_slice(pool, (blocks[j], 0, *zeros),
+                                     (1, bs, *w))
         pool = jax.lax.dynamic_update_slice(
             pool, jnp.where(lands[j], new[j], held[0])[None],
-            (blocks[j], 0, 0))
+            (blocks[j], 0, *zeros))
     return pool
 
 
@@ -242,36 +246,47 @@ class GatedMLP(nn.Layer):
 
 
 class RoutedFFN(nn.Layer):
-    """Sigmoid-routed experts plus the shared expert (module
-    docstring).  ``forward`` returns ``(y, stats)``, ``stats`` int32
-    [3]: pairs computed, experts hit, the busiest expert's pairs."""
+    """Routed experts: sigmoid-scored with a selection bias and a
+    shared expert (module docstring), or — ``gate="softmax"``,
+    ``n_shared`` 0 — the softmax top-k layer that has neither
+    (``models/sdar_moe.py``).  ``forward`` returns ``(y, stats)``,
+    ``stats`` int32 [3]: pairs computed, experts hit, the busiest
+    expert's pairs."""
 
     def __init__(self, hidden, width, num_experts, top_k, n_shared,
-                 scale, normalize=True):
+                 scale, normalize=True, gate="sigmoid"):
         super().__init__()
+        if gate not in ("sigmoid", "softmax"):
+            raise ValueError(f"gate {gate!r}: 'sigmoid' or 'softmax'")
         self.num_experts, self.top_k = num_experts, top_k
         self.scale, self.normalize = float(scale), bool(normalize)
+        self.gate = gate
         init = I.Normal(0.0, 0.02)
         self.gate_weight = self.create_parameter(
             [hidden, num_experts], default_initializer=init)
-        self.gate_bias = self.create_parameter(
-            [num_experts], is_bias=True)
+        if gate == "sigmoid":
+            self.gate_bias = self.create_parameter(
+                [num_experts], is_bias=True)
         self.experts_in = self.create_parameter(
             [num_experts, hidden, 2 * width], default_initializer=init)
         self.experts_out = self.create_parameter(
             [num_experts, width, hidden], default_initializer=init)
-        self.shared = GatedMLP(hidden, n_shared * width)
+        self.shared = (GatedMLP(hidden, n_shared * width) if n_shared
+                       else None)
 
     @_scoped("moe.route")
     def route(self, x):
         import jax.numpy as jnp
-        from ..distributed.moe import sigmoid_topk_routing
+        from ..distributed import moe
         logits = jnp.dot(x.astype(jnp.float32),
                          self.gate_weight._data.astype(jnp.float32),
                          precision="highest")
-        return sigmoid_topk_routing(logits, self.gate_bias._data,
-                                    self.top_k, self.scale,
-                                    self.normalize)
+        if self.gate == "softmax":
+            return moe.softmax_topk_routing(logits, self.top_k,
+                                            self.normalize)
+        return moe.sigmoid_topk_routing(logits, self.gate_bias._data,
+                                        self.top_k, self.scale,
+                                        self.normalize)
 
     @_scoped("moe.experts")
     def experts(self, x, choice, weights, live):
@@ -288,7 +303,10 @@ class RoutedFFN(nn.Layer):
         """x [T, D]; live [T] bool, the rows that are tokens."""
         choice, weights = self.route(x)
         y, stats = self.experts(x, choice, weights, live)
-        return y.astype(x.dtype) + self.shared_expert(x), stats
+        y = y.astype(x.dtype)
+        if self.shared is not None:
+            y = y + self.shared_expert(x)
+        return y, stats
 
 
 class MLAttention(nn.Layer):
@@ -786,31 +804,6 @@ class MLAMoEModel(ServedModel, nn.Layer):
             x, (0, true_len - 1, 0), (1, 1, x.shape[-1]))
         return (self._head(last_h)[:, -1, :], new_pools, [],
                 self._counter_vector(stats))
-
-    def _program(self, kind, cache_key, params, pnames, body,
-                 donate=(2, 3)):
-        """Build once a ``cache_key`` the jitted ``body`` run with the
-        traced parameters and buffers swapped in."""
-        from ..core import autograd
-        from ..jit import _swapped
-        cache = self.__dict__.setdefault("_program_cache", {})
-        if (kind, cache_key) in cache:
-            return cache[kind, cache_key]
-        mbuffers = dict(self.named_buffers())
-        bnames = sorted(mbuffers)
-
-        def pure(p_list, b_list, *args):
-            with _swapped(params, dict(zip(pnames, p_list))), \
-                    _swapped(mbuffers, dict(zip(bnames, b_list))):
-                with autograd.no_grad():
-                    return body(*args)
-
-        fn = _jit_named(kind, pure, donate_argnums=donate)
-        if len(cache) >= 8:
-            cache.pop(next(iter(cache)))
-        cache[kind, cache_key] = (
-            self._compile_probe(kind, cache_key, fn), bnames, mbuffers)
-        return cache[kind, cache_key]
 
     def _compiled_fused_decode_fn(self, pnames, params, cache_key,
                                   paged=False):

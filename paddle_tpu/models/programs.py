@@ -19,7 +19,7 @@ The engine owns slots, blocks and ticks.  A model offers:
 ``serving_linear_stacks()``   the layers whose ``nn.Linear`` children
                      weight-only int8 serving relayouts.
 
-``ServedModel`` is the mixin both model files inherit: the compile
+``ServedModel`` is the mixin the model files inherit: the compile
 listeners, the probe, and the default ``serving_program`` that finds
 the builder ``_compiled_<kind>_fn`` on the model.
 """
@@ -251,6 +251,45 @@ def _rows_to_the_longest(pos, ahead, table_rows, block_size):
                                      slot_attn_chunk(block_size))
 
 
+class StepSpec:
+    """What one run of a model's ``fused_decode`` program does to a
+    lane, where that is not "one row in, one token out" (a model whose
+    ``ServingSpec.step`` is None keeps that contract, and its prefill
+    picks token 0 from the last prompt position's logits).
+
+    ``rows``     rows a live lane carries through the step: the width
+                 of the device's ``tok`` state ``[slots, rows]``, and
+                 the most tokens a lane yields a step (0..rows)
+    ``align``    positions come in groups of ``align``: a prefill
+                 covers ``n // align * align`` prompt tokens and yields
+                 NO token, chunks and prefix hits start at multiples of
+                 it (``kv_block_size`` and ``prefill_chunk`` are
+                 refused otherwise), and rows below a lane's ``pos``
+                 are final, so a finished or preempted request's whole
+                 blocks below it enter the prefix cache
+    ``open``     ``(tail ids) -> (tok row [rows], flags)``: the lane's
+                 state when its first step starts at ``pos = n // align
+                 * align`` with the ``n % align`` prompt tokens the
+                 prefill left; ``flags`` is one int32 a lane that the
+                 program keeps (0 = a lane that is not stepping: parked
+                 or prefilling) and the engine mirrors without reading
+    ``report``   what ``/healthz`` says of the step
+
+    The program takes ``flags`` [slots] after ``rem`` and returns its
+    new value last.  Its first output is the lane's report, int32
+    ``[slots, rows + 4]``: the lane's ``rows`` ids after the step, then
+    ``first`` and ``count`` (ids ``[first, first + count)`` are newly
+    final in position order with every position before them: the
+    tokens to send, the device having cut them at the budget and at an
+    EOS), the lane's new ``pos`` and its new ``flags``.  ``rem`` and
+    ``ctr`` move by ``count``."""
+
+    def __init__(self, rows, align, open, report=None):
+        self.rows, self.align = int(rows), int(align)
+        self.open = open
+        self.report = dict(report or {})
+
+
 class ServingSpec:
     """A model's answer to the engine's questions.
 
@@ -282,11 +321,13 @@ class ServingSpec:
                        behind ``serving.decode_rows_walked``.  Left
                        out: every slot as far as the longest window,
                        ``GPTAttention._slot_attn``'s rule
+    ``step``           ``StepSpec`` of a model whose step is not one
+                       row and one token a lane; None for one that is
     """
 
     def __init__(self, kv, max_positions, vocab_size, hidden_size,
                  tensor_parallel=False, counters=(), unsupported=None,
-                 kernels=None, decode_rows=None):
+                 kernels=None, decode_rows=None, step=None):
         self.kv = kv
         self.max_positions = int(max_positions)
         self.vocab_size = int(vocab_size)
@@ -296,6 +337,7 @@ class ServingSpec:
         self.unsupported = dict(unsupported or {})
         self.kernels = dict(kernels or {})
         self.decode_rows = decode_rows or _rows_to_the_longest
+        self.step = step
 
 
 class ServedModel:
@@ -316,6 +358,31 @@ class ServedModel:
             raise NotImplementedError(
                 f"{type(self).__name__} has no {kind!r} step program")
         return build(*args, **kwargs)
+
+    def _program(self, kind, cache_key, params, pnames, body,
+                 donate=(2, 3)):
+        """Build once a ``cache_key`` the jitted ``body`` run with the
+        traced parameters and buffers swapped in."""
+        from ..core import autograd
+        from ..jit import _swapped
+        cache = self.__dict__.setdefault("_program_cache", {})
+        if (kind, cache_key) in cache:
+            return cache[kind, cache_key]
+        mbuffers = dict(self.named_buffers())
+        bnames = sorted(mbuffers)
+
+        def pure(p_list, b_list, *args):
+            with _swapped(params, dict(zip(pnames, p_list))), \
+                    _swapped(mbuffers, dict(zip(bnames, b_list))):
+                with autograd.no_grad():
+                    return body(*args)
+
+        fn = _jit_named(kind, pure, donate_argnums=donate)
+        if len(cache) >= 8:
+            cache.pop(next(iter(cache)))
+        cache[kind, cache_key] = (
+            self._compile_probe(kind, cache_key, fn), bnames, mbuffers)
+        return cache[kind, cache_key]
 
     def add_compile_listener(self, cb):
         """Register ``cb(kind, cache_key, wall_s)`` to fire right after

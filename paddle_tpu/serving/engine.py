@@ -597,6 +597,9 @@ class Engine:
         # all come from it (models/programs.py KVRowSpec)
         self._kvspec = sspec.kv
         self._serving_spec = sspec
+        # a step that is not one row and one token a lane
+        # (models/programs.py StepSpec); None for the models it is
+        self._step = sspec.step
         self._nh, self._hd = sspec.kv.num_heads, sspec.kv.head_dim
         self._kv_dtype = kv_dtype = sspec.kv.dtype
         # the dtype LABEL for compiled-program cache keys, /healthz,
@@ -748,6 +751,7 @@ class Engine:
                     f"tick_token_budget ({b}) must cover at least one "
                     f"prefill_chunk ({c}), or no tick could ever make "
                     "prefill progress")
+            self._check_aligned("prefill_chunk", c)
             self._chunk = c
             self._tick_budget = b
         elif tick_token_budget is not None:
@@ -802,6 +806,7 @@ class Engine:
                 raise ValueError(
                     f"kv_block_size must be >= 1 and divide max_seq_len"
                     f" ({self.max_seq_len}), got {bsz}")
+            self._check_aligned("kv_block_size", bsz)
             self._bs = bsz
             self._bps = self.max_seq_len // bsz  # blocks per full slot
             # per-shard footprint of ONE logical block: each mesh
@@ -1325,6 +1330,12 @@ class Engine:
         the contiguous layout."""
         return self._kvspec.geometry(self._bs) if self._paged else None
 
+    def step_report(self):
+        """What the served model says of a step that is not one row
+        and one token a lane (``StepSpec.report``; ``/healthz``
+        ``step``); None for a model whose step is."""
+        return dict(self._step.report) if self._step is not None else None
+
     # every feature a ``ServingSpec.unsupported`` may name, by the
     # option that asks for it (a key that is not here is a refusal
     # nothing reads: tests/test_mla_moe.py holds the models to it)
@@ -1338,7 +1349,19 @@ class Engine:
         "lora": "adapters / max_adapters (LoRA banks)",
         "offload": "kv_host_mb (host offload)",
         "migration": "KV migration (migrate_out / import)",
+        "sampling": "temperature / top_k / top_p (a sampled request)",
     }
+
+    def _check_aligned(self, option, value):
+        """A served model whose positions come in groups
+        (``StepSpec.align``) keeps chunk starts and block edges on
+        them."""
+        align = self._step.align if self._step is not None else 1
+        if value % align:
+            raise ValueError(
+                f"{option} ({value}) must be a multiple of "
+                f"{align}: {type(self.model).__name__} prefills, "
+                f"commits and adopts positions {align} at a time")
 
     def _refuse_unsupported(self, sspec, used):
         """Raise, naming the option and the missing piece, for every
@@ -1425,7 +1448,12 @@ class Engine:
         # device-resident cursors, re-uploaded only when an admission /
         # eviction / chunk dirties them (_push_state)
         self._pos = np.zeros(self.num_slots, np.int32)
-        self._cur_tok = np.zeros((self.num_slots, 1), np.int32)
+        self._cur_tok = np.zeros(
+            (self.num_slots, self._step.rows if self._step else 1),
+            np.int32)
+        # the step program's own word a lane (StepSpec.open): mirrored,
+        # never read; 0 = the lane does not step
+        self._flags = np.zeros(self.num_slots, np.int32)
         # per-slot sampling lanes: temperature 0 is the
         # greedy sentinel, seed words feed core/rng.request_key, and
         # _sctr tracks each request's emitted-token count — the rng
@@ -1509,6 +1537,8 @@ class Engine:
                       timeout=timeout, temperature=temperature,
                       top_k=top_k, top_p=top_p, seed=seed,
                       priority=priority, tenant=tenant, adapter=adapter)
+        self._refuse_unsupported(self._serving_spec,
+                                 dict(sampling=req.do_sample))
         total = len(req.prompt) + req.max_new_tokens
         margin = self._spec_k or 0
         if total + margin > self.max_seq_len:
@@ -1797,6 +1827,15 @@ class Engine:
         return uniq
 
     # -- priority preemption -------------------------------------------
+    @staticmethod
+    def _history(req):
+        """Prompt and every token sent so far: the ids whose rows a
+        slot holds (the keys of what enters the prefix cache when a
+        stream is preempted or ends)."""
+        return (np.concatenate([req.prompt,
+                                np.asarray(req.generated, np.int32)])
+                if req.generated else req.prompt)
+
     def _preempt(self, slot, tr):
         """Evict a RUNNING request mid-stream under priority pressure
         and requeue it with its emitted tokens preserved.  Paged mode
@@ -1812,9 +1851,7 @@ class Engine:
         otherwise raise the consume-side drift check."""
         req = slot.request
         i = slot.index
-        ctx = (np.concatenate([req.prompt,
-                               np.asarray(req.generated, np.int32)])
-               if req.generated else req.prompt)
+        ctx = self._history(req)
         if self._paged and self.prefix_cache is not None \
                 and not req._adapter_id:
             # slot.pos rows of K/V are computed (decoding slots: the
@@ -2736,7 +2773,7 @@ class Engine:
 
     # -- tracing / flight recorder / debug surface ---------------------
     def _dev_note(self, program, handle, batch=0, n=0, req=None,
-                  role=None, t_dispatch=None, stats=()):
+                  role=None, t_dispatch=None, stats=(), width=None):
         """Hand the device watcher one dispatch the engine thread just
         made.  ``program`` is the ``_compile_probe`` kind, ``handle``
         the program's smallest output (never a pool, never donated;
@@ -2762,6 +2799,8 @@ class Engine:
                 "batch": batch, "n": n}
         if req is not None:
             args["req"] = req
+        if width is not None:
+            args["width"] = width  # rows a lane (StepSpec.rows)
         if stats:
             # the program's counter vector: the watcher reads it once
             # the program is done and names the span's arguments
@@ -2934,6 +2973,7 @@ class Engine:
                 "kv_row_bytes": self._m_kv_row_bytes.value,
                 "kv_geometry": self.kv_geometry(),
                 "kernels": self._serving_spec.kernels,
+                "step": self.step_report(),
                 "async_depth": self.async_depth,
                 "tracing": bool(self.tracer.enabled),
                 "preemption": self._preemption,
@@ -3028,14 +3068,20 @@ class Engine:
         tokens = req.context
         shard = self._slot_shard(slot.index)
         s = len(tokens)
-        n_total = -(-(s + req.remaining + (self._spec_k or 0))
-                    // self._bs)
+        end = s + req.remaining + (self._spec_k or 0)
+        if self._step is not None:
+            # a step of several rows writes the whole of its last group
+            end += -end % self._step.align
+        n_total = -(-end // self._bs)
         ctx, m = ([], 0)
         if self.prefix_cache is not None and not req._adapter_id:
             # adapter lanes never share cached K/V: LoRA on out_proj
             # shifts the residual stream, so layers >= 1 K/V depend
-            # on the adapter — a base-lane prefix would be wrong
-            ctx, m = self.prefix_cache.match(tokens, shard=shard)
+            # on the adapter — a base-lane prefix would be wrong.
+            # (A prefill that yields no token needs no position left
+            # to run: every whole block may be adopted.)
+            ctx, m = self.prefix_cache.match(
+                tokens, shard=shard, keep=0 if self._step else 1)
         need = n_total - len(ctx)
         short = need - self.block_pool.free_count(shard)
         if short > 0 and self.prefix_cache is not None:
@@ -3206,7 +3252,8 @@ class Engine:
         corrected cursors — a mid-window eviction may have advanced
         the device cursor further than the host consumed."""
         self._pos[i] = 0
-        self._cur_tok[i, 0] = 0
+        self._cur_tok[i] = 0
+        self._flags[i] = 0
         self._temp[i] = 0.0
         self._topk[i] = 0
         self._topp[i] = 1.0
@@ -3284,6 +3331,8 @@ class Engine:
             rem=self._rem)
         if self.adapters is not None:
             mirrors["aid"] = self._aid
+        if self._step is not None:
+            mirrors["flags"] = self._flags
         if self._paged:
             mirrors["tables"] = self._block_tables
             # per-slot scratch block ids (constant per engine config,
@@ -3407,18 +3456,46 @@ class Engine:
             slot.prefilled = 0
         slot.pos = slot.prefilled
         self._pos[i] = slot.prefilled
-        self._cur_tok[i, 0] = 0
+        self._cur_tok[i] = 0
+        self._flags[i] = 0
+        if self._step is not None \
+                and slot.prefilled >= self._prefill_target(slot.request):
+            # every whole group of the context was adopted: no chunk
+            self._open_step(slot)
+
+    def _prefill_target(self, req):
+        """Context tokens a prefill covers: all of them, or the whole
+        groups of a model whose positions come in groups
+        (``StepSpec.align``; the rest opens its first step)."""
+        s = len(req.context)
+        return s - s % self._step.align if self._step is not None else s
+
+    def _open_step(self, slot):
+        """A lane whose prefill yields no token starts stepping: what
+        the prefill left of the context stands first in the lane's
+        rows (``StepSpec.open``), and the slot counts as prefilled, so
+        it is DECODING from the next snapshot on."""
+        req, i = slot.request, slot.index
+        target = self._prefill_target(req)
+        self._cur_tok[i], self._flags[i] = self._step.open(
+            req.context[target:])
+        slot.pos = target
+        slot.prefilled = len(req.context)
+        self._pos[i] = target
+        self._state_dirty = True
 
     def _run_chunk(self, slot, n):
         """One chunk dispatch: compute K/V (and, on the final chunk,
         the first-token logits) for prompt positions
-        ``[prefilled, prefilled + n)``.  Returns 1 when the final chunk
-        emitted the request's first token, else 0."""
+        ``[prefilled, prefilled + n)``.  Returns None while chunks are
+        left, else the tokens the final chunk emitted: the request's
+        first, or none where the first step makes it
+        (``StepSpec``)."""
         import jax.numpy as jnp
         req = slot.request
         i = slot.index
         tokens = req.context  # prompt, or the frozen resume snapshot
-        s = len(tokens)
+        s = self._prefill_target(req)
         p0 = slot.prefilled
         C = self._chunk
         ids = np.zeros((1, C), np.int32)  # right-padded final chunk
@@ -3471,7 +3548,7 @@ class Engine:
             # still PREFILLING: re-park the decode dispatch's garbage
             # write on the next chunk's start row
             self._pos[i] = slot.prefilled
-            return 0
+            return None
         # final chunk: the context's full blocks become adoptable and
         # the last real position's logits sample the first token (TTFT
         # on a fresh admission; the NEXT stream token on a resume)
@@ -3479,6 +3556,9 @@ class Engine:
                 and not req._adapter_id:
             self.prefix_cache.insert(tokens,
                                      self._slot_blocks[i][:s // self._bs])
+        if self._step is not None:
+            self._open_step(slot)
+            return 0
         self._pos[i] = s
         self._emit(slot, self._first_token(req, last0))
         return 1
@@ -3498,21 +3578,22 @@ class Engine:
         while queue and budget > 0:
             slot = queue.popleft()
             req = slot.request
-            n = min(self._chunk, len(req.context) - slot.prefilled)
+            n = min(self._chunk,
+                    self._prefill_target(req) - slot.prefilled)
             if n > budget:
                 break  # strict per-tick cap (budget >= chunk, so a
                 #        tick's FIRST chunk always fits: progress is
                 #        guaranteed, the cap only defers later chunks)
-            done_first = self._run_chunk(slot, n)
+            n_first = self._run_chunk(slot, n)
             budget -= n
-            if done_first:
-                emitted += 1
+            if n_first is None:
+                queue.append(slot)
+            else:
+                emitted += n_first
                 if slot.request is not None:
                     newly.append(slot)
                 else:
                     evicted += 1  # EOS / max_new_tokens on first token
-            else:
-                queue.append(slot)
         return emitted, newly, evicted
 
     def _first_token(self, req, last0):
@@ -3586,6 +3667,17 @@ class Engine:
                 self._m_tpot.observe(
                     (now - req.first_token_at) / n_after_first * 1e3)
             i = slot.index
+            if self._step is not None and self.prefix_cache is not None \
+                    and not req._adapter_id:
+                # rows below pos are final (StepSpec.align): the whole
+                # blocks of prompt + answer below it stay adoptable, so
+                # the next turn prefills what the client added and no
+                # more.  (The last block of a stream is never
+                # committed, so no row the client has not seen is
+                # keyed by ids it could send.)
+                self.prefix_cache.insert(
+                    self._history(req),
+                    self._slot_blocks[i][:slot.pos // self._bs])
             self.scheduler.evict(slot)
             self._evicted_in_tick += 1
             self._release_slot_kv(i)
@@ -3600,7 +3692,8 @@ class Engine:
                                 tokens=len(req.generated))
             return
         i = slot.index
-        self._cur_tok[i, 0] = int(tok)
+        if self._step is None:
+            self._cur_tok[i, 0] = int(tok)
         self._pos[i] = slot.pos
         self._sctr[i] = len(req.generated)  # rng fold counter mirror
         self._rem[i] = req.max_new_tokens - len(req.generated)
@@ -3727,45 +3820,51 @@ class Engine:
             {"pos": self._pos.tolist(), "rem": self._rem.tolist()},
             spec_lanes=[slot.spec_lanes for slot in active])
 
-    def _emit_window_lane(self, slot, picks_row, acc_i, n_emit_dev_i,
-                          done_i, tick):
-        """Shared per-slot emit loop of the windowed consume paths
-        (``_consume_spec`` and ``_consume_ragged``'s mode-0 lanes):
-        consume the device-accepted lanes plus the bonus token,
-        advancing pos/mirrors through ``_emit``.  Lane j's pick was
-        drawn on device from the same key/logits the one-token tick
-        would use for this prefix, and ``acc_i`` counts only REAL
-        draft lanes, so consuming lanes 0..acc_i reproduces the host
-        accept loop exactly; an accepted lane is counted even when
-        its token finishes the request (EOS drafted by a matched
-        lane), but only over lanes actually consumed.  Host-vs-device
-        stop-condition drift raises into step recovery — ONE
-        implementation, so the two consume paths' drift semantics
-        cannot desynchronize.  Returns (emitted, accepted)."""
+    def _emit_lane(self, slot, toks, n_emit_dev_i, done_i, tick,
+                   advance=1):
+        """The one emit loop of every consume path: ``toks`` are the
+        tokens the device says this lane yields this tick, in stream
+        order; each goes through ``_emit`` (the cursor first moving
+        ``advance`` rows: 1 where a token is a cached row, 0 where the
+        step program moves ``pos`` itself) until one finishes the
+        request.  Host-vs-device stop-condition drift raises into step
+        recovery — ONE implementation, so the paths' drift semantics
+        cannot desynchronize.  ``n_emit_dev_i`` is the device's count
+        (1 where the program yields exactly one token a live lane).
+        Returns the tokens emitted."""
         i = slot.index
-        n_cnt = 0
         n_em = 0
-        j = 0
-        while True:
-            tok = int(picks_row[j])
-            matched = j < acc_i
-            if matched:
-                n_cnt += 1
-            slot.pos += 1
+        for tok in toks:
+            slot.pos += advance
             self._pos[i] = slot.pos
-            self._emit(slot, tok)
+            self._emit(slot, int(tok))
             n_em += 1
-            if slot.request is None or not matched:
+            if slot.request is None:
                 break
-            j += 1
-        slot.spec_lanes = 0
         if n_em != n_emit_dev_i or done_i != (slot.request is None):
             raise RuntimeError(
                 f"async stop-condition drift: slot {i} host "
                 f"emitted {n_em} (finished={slot.request is None}) "
                 f"vs device n_emit={n_emit_dev_i} done={done_i} "
                 f"at tick {tick}")
-        return n_em, n_cnt
+        return n_em
+
+    def _emit_window_lane(self, slot, picks_row, acc_i, n_emit_dev_i,
+                          done_i, tick):
+        """Per-slot emit of the windowed consume paths
+        (``_consume_spec`` and ``_consume_ragged``'s mode-0 lanes):
+        consume the device-accepted lanes plus the bonus token
+        (``_emit_lane``).  Lane j's pick was drawn on device from the
+        same key/logits the one-token tick would use for this prefix,
+        and ``acc_i`` counts only REAL draft lanes, so consuming lanes
+        0..acc_i reproduces the host accept loop exactly; an accepted
+        lane is counted even when its token finishes the request (EOS
+        drafted by a matched lane), but only over lanes actually
+        consumed.  Returns (emitted, accepted)."""
+        n_em = self._emit_lane(slot, picks_row[:acc_i + 1], n_emit_dev_i,
+                               done_i, tick)
+        slot.spec_lanes = 0
+        return n_em, min(n_em, acc_i)
 
     def _consume_spec(self, inf, mats, done, tr):
         """Emit a materialized speculative tick: consume exactly the
@@ -3847,16 +3946,24 @@ class Engine:
         args += [st["tok"], st["pos"], st["temp"], st["topk"],
                  st["topp"], st["slo"], st["shi"], st["ctr"],
                  st["eos"], st["rem"], *self._lora_args_state(st)]
+        step = self._step
+        span_args = {}
+        if step is not None:
+            args.append(st["flags"])
+            span_args["width"] = step.rows
         layout = "paged" if self._paged else "contiguous"
         self._fault("dispatch")
         with tr.span("decode.dispatch", batch=len(active),
                      layout=layout, fused=True,
-                     rows=self._rows_walked()), \
+                     rows=self._rows_walked(step.rows if step else 1),
+                     **span_args), \
                 self._dequant_span(tr, len(active)):
             (ids, done, new_tok, new_pos, new_ctr, new_rem,
              self.k_pools, self.v_pools, *stats) = self._fused_fn(*args)
+        if step is not None:
+            st["flags"] = stats.pop()
         self._dev_note(self._fused_fn.kind, ids, batch=len(active),
-                       stats=stats)
+                       stats=stats, **span_args)
         st["tok"], st["pos"], st["ctr"], st["rem"] = \
             new_tok, new_pos, new_ctr, new_rem
         self._m_fused_ticks.inc()
@@ -3891,15 +3998,22 @@ class Engine:
                             f"was evicted on the host but tick "
                             f"{inf.tick}'s device lane is not done")
                     continue
-                slot.pos += 1
-                self._pos[i] = slot.pos
-                self._emit(slot, int(ids[i]))
-                emitted += 1
-                if bool(done[i]) != (slot.request is None):
-                    raise RuntimeError(
-                        f"async stop-condition drift: slot {i} host "
-                        f"finished={slot.request is None} vs device "
-                        f"done={bool(done[i])} at tick {inf.tick}")
+                if self._step is None:
+                    emitted += self._emit_lane(
+                        slot, ids[i:i + 1], 1, bool(done[i]), inf.tick)
+                    continue
+                # a lane's report (StepSpec): its rows after the step,
+                # then which of them to send and its new cursor and
+                # flags, which the mirrors follow
+                W = self._step.rows
+                first, count, pos, flags = (int(v) for v in ids[i, W:])
+                slot.pos = pos
+                emitted += self._emit_lane(
+                    slot, ids[i, first:first + count], count,
+                    bool(done[i]), inf.tick, advance=0)
+                if slot.request is req:
+                    self._cur_tok[i] = ids[i, :W]
+                    self._pos[i], self._flags[i] = pos, flags
             emit_sp.args["emitted"] = emitted
         return emitted
 
@@ -4386,6 +4500,7 @@ class Engine:
         occ, active, prefilling = self.scheduler.snapshot()
         ragged = self._ragged
         if active and self._ring and self._spec_k is None and \
+                self._step is None and \
                 not (ragged and prefilling) and \
                 all(self._rem[s.index] <= len(self._ring)
                     for s in active):

@@ -323,6 +323,10 @@ class _Handler(JsonHandler):
                 # backend it finds (ServingSpec.kernels)
                 "kernels": getattr(getattr(eng, "_serving_spec", None),
                                    "kernels", None),
+                # a step that is not one row and one token a lane
+                # (StepSpec.report), None where it is
+                "step": (eng.step_report()
+                         if hasattr(eng, "step_report") else None),
                 # async-loop signals, next to the router-tier load
                 # signals: pipeline depth plus the mean overlapped
                 # host time and mean blocking d2h wait per tick —

@@ -551,16 +551,18 @@ class PrefixCache:
             children = node.children
         return blocks
 
-    def match(self, tokens, shard=None):
+    def match(self, tokens, shard=None, keep=1):
         """Longest cached prefix of ``tokens`` in full blocks, capped
-        so at least ONE token is left for the adopter's own prefill
-        (admission still needs a last-position logit to sample from).
+        so at least ``keep`` tokens are left for the adopter's own
+        prefill: ONE where admission needs a last-position logit to
+        sample from, none where the served model's prefill yields no
+        token (``models/programs.py`` ``StepSpec``).
         Takes one pool reference per returned block on behalf of the
         caller — release with ``pool.decref`` at slot eviction.
         ``shard`` names the dp shard whose trie to walk (the adopting
         slot's); None probes every shard and adopts from the longest.
         Returns ``(block_ids, matched_token_count)``."""
-        limit = (len(tokens) - 1) // self.block_size
+        limit = (len(tokens) - keep) // self.block_size
         if shard is None:
             shard = 0
             if len(self._roots) > 1:

@@ -579,8 +579,11 @@ def test_one_engine_serves_both_models(build):
     tick, a prefix adopted from the cache: token-identical to each
     model's own uncached greedy decoding."""
     model = build()
-    eng = Engine(model, num_slots=3, max_seq_len=128, kv_block_size=8,
-                 kv_blocks=48, prefill_chunk=16)
+    # its own registry: whether the moe counters exist is asserted below,
+    # and the default one keeps what earlier engines of the process made
+    eng = Engine(model, registry=monitor.StatRegistry(), num_slots=3,
+                 max_seq_len=128, kv_block_size=8, kv_blocks=48,
+                 prefill_chunk=16)
     rng = np.random.default_rng(7)
     prompts = [rng.integers(1, 128, n).tolist() for n in (37, 5, 50, 20)]
     reqs = [eng.submit(p, max_new_tokens=8) for p in prompts]
@@ -652,10 +655,21 @@ def test_the_engine_refuses_by_name_what_the_model_cannot_honour(
     assert "MLAMoEModel" in str(err.value)
 
 
+def _sdar():
+    from paddle_tpu.models import SDARMoEModel
+    return SDARMoEModel(dict(
+        vocab_size=128, max_position_embeddings=64, hidden_size=32,
+        moe_intermediate_size=16, num_hidden_layers=1,
+        num_attention_heads=2, num_key_value_heads=1, head_dim=16,
+        num_experts=4, num_experts_per_tok=2, rms_norm_eps=1e-6,
+        rope_theta=1e6), mask_token_id=127)
+
+
 @pytest.mark.parametrize("build", [
     lambda: seeded(seed=7)[0],
     lambda: GPTModel.from_config("tiny", dropout=0.0),
-], ids=["MLAMoEModel", "GPTModel"])
+    _sdar,
+], ids=["MLAMoEModel", "GPTModel", "SDARMoEModel"])
 def test_every_refusal_a_served_model_declares_is_read(build):
     """Every key of a served model's ``ServingSpec.unsupported`` is a
     feature the engine asks about (its construction-time table, which

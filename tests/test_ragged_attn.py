@@ -360,6 +360,71 @@ def test_latent_chunk_rows_are_written_in_place_on_the_v5e():
     assert "input_output_alias" in lines[0]
 
 
+def test_block_rows_are_written_in_place_on_the_v5e():
+    """The block-diffusion model's grouped-query attention at the
+    published widths (32 query / 4 K/V heads of 128, hidden 2,048,
+    blocks of 4 positions; K and V pools of 10,241 blocks of 16 rows),
+    compiled for the compile-only ``TPU v5 lite`` device.  The step
+    over 32 slots writes each slot's 4 rows by ONE in-place update a
+    pool (64 in all) and the chunk program its 256 rows by 17 a pool;
+    the one loop of either is the walk; nothing comes from a scatter
+    and nothing copies or transposes a pool, whose ``[4, 128]`` rows
+    the runtime keeps unpadded.  The grouped product compiles at the
+    routed layer's two widths for a step's and a chunk's pairs under
+    the tiles ``_gmm_tiling`` gives them."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu import nn
+    from paddle_tpu.distributed.moe import grouped_matmul
+    from paddle_tpu.jit import _swapped
+    from paddle_tpu.models.sdar_moe import GQAttention
+    from paddle_tpu.serving.kvcache import KVRowSpec
+
+    shape, pool = (10241, 16, 4, 128), "bf16[10241,16,4,128]"
+    assert KVRowSpec.heads(7, 4, 128, "bfloat16").pool_shapes(
+        (10241, 16)) == [shape, shape]
+    with nn.LazyGuard():
+        attn = GQAttention(2048, 32, 4, 128, 1e6, 1e-6, 4)
+    attn.to(dtype="bfloat16")
+    params = dict(attn.named_parameters())
+    names = sorted(params)
+
+    def text(sds, program, *args):
+        def step(p_list, *args):
+            with _swapped(params, dict(zip(names, p_list))):
+                return program(attn, *args)
+        return jax.jit(step, donate_argnums=(2, 3)).lower(
+            [sds(params[n].shape) for n in names], *args
+        ).compile().as_text()
+
+    with _described_v5e() as sds:
+        i32 = jnp.int32
+        step = text(sds, GQAttention.step_slots_paged, sds((32, 4, 2048)),
+                    sds(shape), sds(shape), sds((32, 256), i32),
+                    sds((32,), i32), sds((32,), i32))
+        chunk = text(sds, GQAttention.prefill_chunk_paged,
+                     sds((1, 256, 2048)), sds(shape), sds(shape),
+                     sds((256,), i32), sds((), i32), sds((), i32),
+                     sds((), i32))
+        for m, k, n in ((1024, 2048, 1536), (1024, 768, 2048),
+                        (2048, 2048, 1536), (2048, 768, 2048)):
+            jax.jit(lambda x, w, g: grouped_matmul(x, w, g, "gmm")).lower(
+                sds((m, k)), sds((128, k, n)),
+                sds((128,), jnp.int32)).compile()
+    for program, updates in ((step, 64), (chunk, 34)):
+        lines = program.splitlines()
+        assert pool + "{3,2,1,0:T(4,128)(2,1)}" in program
+        assert sum(" while(" in ln for ln in lines) == 1
+        assert not [ln for ln in lines if re.search(
+            r" scatter\(|op_name=\"[^\"]*scatter", ln)]
+        assert not [ln for ln in lines if re.search(
+            r"= " + re.escape(pool) + r"\S* (copy|transpose)\(", ln)]
+        assert len(re.findall(re.escape(pool)
+                              + r"\S* dynamic-update-slice\(",
+                              program)) == updates
+        assert "input_output_alias" in lines[0]
+
+
 # -- knob validation --------------------------------------------------
 
 def test_attn_impl_validation(tiny_gpt):
