@@ -157,8 +157,7 @@ def write_chunk_rows(pool, rows, table, pos, true_len, scratch):
     ``decode_slots_paged``'s 32-trip twin, PR 30).
 
     pool [NB, bs, W]; rows [C, w], w <= W (or, a row of several axes,
-    pool [NB, bs, *W] and rows [C, *W]: the K and V rows ``[heads,
-    hd]`` of ``models/sdar_moe.py``); table int32 [L // bs]; pos,
+    pool [NB, bs, *W] and rows [C, *W]); table int32 [L // bs]; pos,
     true_len, scratch traced scalars.  Returns the pool."""
     import jax
     import jax.numpy as jnp

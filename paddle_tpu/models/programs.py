@@ -137,22 +137,35 @@ class KVRowSpec:
     of ``[H, hd]`` (``"k"``, ``"v"``) for full multi-head attention,
     one of ``[r + d_rope]`` (``"latent"``) for latent attention, whose
     row is the compressed KV and the shared rotary key and which keeps
-    no V.  ``dtype`` is the dtype the model computes attention in (the
-    pools hold it unless the engine quantizes them).  ``heads_axis``
-    says the first axis of every row is attention heads — what a
-    tensor-parallel mesh shards and what an int8 pool hangs its scales
-    on; a row without it can do neither.
+    no V, one of ``[2 K hd]`` (``"kv"``) for grouped-query attention,
+    whose row is K and V of its K heads flat, head-major
+    (``models/sdar_moe.py``).  ``dtype`` is the dtype the model
+    computes attention in (the pools hold it unless the engine
+    quantizes them).  ``heads_axis`` says the first axis of every row
+    is attention heads — what a tensor-parallel mesh shards and what
+    an int8 pool hangs its scales on; a row without it can do neither.
 
-    A pool stores a row's last axis padded up to whole tiles of
-    ``LANES`` when it is wider than one tile and no multiple of it
-    (576 -> 640).  A TPU keeps the minor axis in tiles of 128 lanes
-    whatever the program says, and given a wider minor axis that is no
-    multiple of 128 its runtime picks a layout with the BLOCK axis
-    minor instead, which every step then transposes there and back (a
-    264 MB copy a layer: PERF.md, PR 28); the padding costs the same
-    bytes and keeps the pool row-major and updated in place.  Block
-    and position bytes count what is stored, padding included: they
-    are what a budget buys and what the gauges report
+    THE RULE a row has to keep: **a pool's last two axes must fill
+    whole tiles of its dtype** — ``(8, 128)`` of 4 bytes, ``(16, 128)``
+    of bf16, stored ``T(8,128)(2,1)`` — or every walk pays for it.
+    The lane half: a TPU keeps the minor axis in tiles of ``LANES``
+    whatever the program says, and given a minor axis wider than one
+    tile that is no multiple of 128 its runtime picks a layout with
+    the BLOCK axis minor instead, which every step then transposes
+    there and back (a 264 MB copy a layer: PERF.md, PR 28); so a pool
+    stores such a row padded up to whole tiles (576 -> 640), which
+    costs the same bytes and keeps the pool row-major and updated in
+    place.  The sublane half: the axis before the minor one must be
+    the block's rows, not a small axis of the row — a ``[4, 128]``
+    bf16 row is tiled ``T(4,128)(2,1)``, a quarter of a register, and
+    the walk's gather of such blocks ran at 255 GB/s of the memory's
+    819, where the same bytes as one flat ``[1024]`` row are fetched
+    at 675 and the latent model's 640-wide row at 540 (chip runs,
+    PR 36, 35 and 30; ``[16, 128]``, GPT-3 1.3B's row, is exactly one
+    tile already).  So a model with few K/V heads declares its row
+    flat, and reads a head as a 128-lane slice of the fetched rows.
+    Block and position bytes count what is stored, padding included:
+    they are what a budget buys and what the gauges report
     (``geometry()["rows"]`` has the row as the model wrote it)."""
 
     LANES = 128

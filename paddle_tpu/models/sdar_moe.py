@@ -13,7 +13,8 @@ hidden, H query heads, K key/value heads, hd the head size):
   position (``rope``: dimension i pairs with i + hd/2).  Query head i
   reads K/V head ``i // (H / K)``; ``s_ij = q_i . k_j / sqrt(hd)``,
   softmax over the visible j, ``y = concat(o) W_o``.  **The cached row
-  of a position and layer is K and V of ``[K, hd]``.**
+  of a position and layer is K and V of its K heads, flat and head-major:
+  ``[K][k|v][hd]``, ``2 K hd`` numbers in ONE pool a layer** (below).
 * **visibility is block-causal**: with blocks of ``block_length`` = B
   positions aligned to absolute positions (the prompt included),
   position i sees j iff ``j // B <= i // B`` — bidirectional inside a
@@ -38,13 +39,27 @@ what stays cached: exactly what a prefill of the same tokens writes.
 Whether a position is masked is a flag the program keeps, not ``id ==
 mask_token_id`` (a prompt or a greedy pick may hold that id).
 
-Serving: ``serving_spec()`` declares K and V pools of ``[K, hd]`` rows
-and a ``StepSpec`` of B rows a lane; ``serving_program`` offers the
-fused step over all slots (denoise or commit a lane, by its flags; the
-choice of what to fix, the stream's rule, the budget and EOS on the
+Serving: ``serving_spec()`` declares one pool a layer of ``[2 K hd]``
+rows (``[NB, bs, 1024]`` at 4 heads of 128; no V pool, as the latent
+model) and a ``StepSpec`` of B rows a lane; ``serving_program`` offers
+the fused step over all slots (denoise or commit a lane, by its flags;
+the choice of what to fix, the stream's rule, the budget and EOS on the
 device) and the paged chunk prefill, both returning an int32 counter
 vector.  What is not here: sampled requests, the dynamic (threshold)
 schedule, sliding windows, rope scaling.
+
+Why the row is flat (``KVRowSpec`` states the rule): a pool's last two
+axes must fill whole tiles of its dtype, or the walk's block gather
+fetches part-filled tiles.  As ``[NB, bs, K, hd]`` the v5e tiles the
+``[4, 128]`` bf16 row ``T(4,128)(2,1)``, a quarter of a register, and K
+and V were two pools: two gathers a trip at 255 GB/s of the memory's
+819 (chip runs, PR 35 and 36).  ``[NB, bs, 2 K hd]`` is tiled
+``T(8,128)(2,1)`` with nothing padded, a trip fetches a block's K and V
+in one gather of 32 KB blocks at 675 GB/s, and head k's K and V are the
+128-lane slices ``2 k hd ..`` and ``(2 k + 1) hd ..`` of the row, which
+a product reads where they lie (a reshape of the fetched rows to
+``[.., K, hd]`` brings the small tiles back, with a copy a trip).
+Head-major, so a later split over K/V heads is a contiguous lane range.
 """
 from __future__ import annotations
 
@@ -117,16 +132,23 @@ class GQAttention(nn.Layer):
         return (rope(self.q_norm(q), at, self.theta),
                 rope(self.k_norm(k), at, self.theta), v)
 
+    def cache_rows(self, k, v):
+        """k, v [..., K, hd] -> the rows a pool keeps, [..., 2 K hd],
+        head-major: ``[K][k|v][hd]`` (module docstring)."""
+        import jax.numpy as jnp
+        return jnp.stack([k, v], axis=-2).reshape(*k.shape[:-2], -1)
+
     @_scoped("sdar.attend")
-    def attend(self, q, k_new, v_new, k_pool, v_pool, tables, pos):
+    def attend(self, q, new, pool, tables, pos):
         """Attention of each slot's S rows, which stand at positions
         ``pos[b] ..`` (a multiple of the block length), over the
         slot's cached rows BELOW ``pos[b]`` — all of them: they belong
         to earlier blocks — and over the S rows themselves under the
-        block-causal mask, their K/V taken from ``k_new`` / ``v_new``
-        and not from the pools.  One pass with a running maximum and
-        denominator, float32 accumulation, the pools read in their own
-        dtype ``walk_chunk`` rows at a time.
+        block-causal mask, their K/V taken from ``new`` and not from
+        the pool.  One pass with a running maximum and denominator,
+        float32 accumulation, the pool read in its own dtype
+        ``walk_chunk`` rows at a time: one gather of whole blocks a
+        trip, head k's K and V read as lane slices of the fetched rows.
 
         Several slots (the step program) are walked as ``walk_plan``'s
         work list of (slot, chunk) items, each slot to its OWN ``pos``,
@@ -135,13 +157,13 @@ class GQAttention(nn.Layer):
         (the chunk program) walks its own chunks in turn.  A table of
         at most one chunk is read whole, without a loop.
 
-        q [B, S, H, hd]; k_new, v_new [B, S, K, hd]; pools
-        [NB, bs, K, hd]; tables int32 [B, L // bs]; pos int32 [B].
-        Returns [B, S, H * hd]."""
+        q [B, S, H, hd]; new [B, S, 2 K hd] (``cache_rows``); pool
+        [NB, bs, W], W >= 2 K hd; tables int32 [B, L // bs]; pos int32
+        [B].  Returns [B, S, H * hd]."""
         import jax
         import jax.numpy as jnp
         B, S, H, hd = q.shape
-        K, bs = self.num_kv_heads, k_pool.shape[1]
+        K, bs = self.num_kv_heads, pool.shape[1]
         g = H // K
         table_rows = tables.shape[1] * bs
         chunk = walk_chunk(table_rows, bs)
@@ -149,17 +171,24 @@ class GQAttention(nn.Layer):
         highest = jax.lax.Precision.HIGHEST
         qg = q.reshape(B, S, K, g, hd)
 
-        def partial(qs, ks, vs, visible):
+        def partial(qs, rows, visible):
             """Masked scores [b, K, g, S, n] of queries ``qs``
-            [b, S, K, g, hd] over keys ``ks`` [b, n, K, hd], and
-            ``context(p)`` [b, S, K, g, hd] of weights p over
-            ``vs``; ``visible`` broadcasts to [b, S, n]."""
-            sc = jnp.einsum("bskgd,bnkd->bkgsn", qs, ks.astype(qs.dtype),
-                            preferred_element_type=jnp.float32)
+            [b, S, K, g, hd] over the keys of ``rows`` [b, n, W], and
+            ``context(p)`` [b, S, K, g, hd] of weights p over their
+            values; ``visible`` broadcasts to [b, S, n]."""
+            def lanes(k, half):          # head k's K (0) or V (1)
+                return rows[..., (2 * k + half) * hd:
+                            (2 * k + half + 1) * hd]
+            sc = jnp.stack([jnp.einsum(
+                "bsgd,bnd->bgsn", qs[:, :, k],
+                lanes(k, 0).astype(qs.dtype),
+                preferred_element_type=jnp.float32)
+                for k in range(K)], axis=1)
             return (jnp.where(visible[:, None, None], sc * scale, -1e30),
-                    lambda p: jnp.einsum(
-                        "bkgsn,bnkd->bskgd", p, vs.astype(jnp.float32),
-                        precision=highest))
+                    lambda p: jnp.stack([jnp.einsum(
+                        "bgsn,bnd->bsgd", p[:, k],
+                        lanes(k, 1).astype(jnp.float32),
+                        precision=highest) for k in range(K)], axis=2))
 
         def per_ctx(a):            # [b, K, g, S] -> [b, S, K, g, 1]
             return jnp.transpose(a, (0, 3, 1, 2))[..., None]
@@ -172,10 +201,10 @@ class GQAttention(nn.Layer):
             return (new_top, den * keep + jnp.sum(p, axis=-1),
                     acc * per_ctx(keep) + context(p))
 
-        def rows_of(pool, blocks):
-            """pool[blocks] [b, n // bs, bs, K, hd] -> [b, n, K, hd]."""
+        def rows_of(blocks):
+            """pool[blocks] [b, n // bs, bs, W] -> [b, n, W]."""
             got = pool[blocks]
-            return got.reshape(got.shape[0], -1, K, hd)
+            return got.reshape(got.shape[0], -1, got.shape[-1])
 
         def trip(c, carry):
             start = jnp.minimum(c * chunk, table_rows - chunk)
@@ -187,9 +216,8 @@ class GQAttention(nn.Layer):
             # starts early)
             sees = (at[None, :] < pos[:, None]) \
                 & (at >= c * chunk)[None, :]
-            return fold(carry, *partial(
-                qg, rows_of(k_pool, blocks), rows_of(v_pool, blocks),
-                sees[:, None, :]))
+            return fold(carry, *partial(qg, rows_of(blocks),
+                                        sees[:, None, :]))
 
         def walk_items(init):
             group = walk_group(B)
@@ -212,8 +240,8 @@ class GQAttention(nn.Layer):
                     return jax.lax.dynamic_slice_in_dim(
                         a, t * group, group, axis)
                 sc, context = partial(
-                    qg[cut(slot_of)], rows_of(k_pool, cut(cols)),
-                    rows_of(v_pool, cut(cols)), cut(sees)[:, None, :])
+                    qg[cut(slot_of)], rows_of(cut(cols)),
+                    cut(sees)[:, None, :])
                 # the items' own partials, folded into their slots'
                 # running state: item i weighs exp(m_i - new_top_b) in
                 # its slot b, 0 elsewhere (and exactly 0 where it saw
@@ -241,7 +269,7 @@ class GQAttention(nn.Layer):
         init = fold((jnp.full((B, K, g, S), -1e30, jnp.float32),
                      jnp.zeros((B, K, g, S), jnp.float32),
                      jnp.zeros((B, S, K, g, hd), jnp.float32)),
-                    *partial(qg, k_new, v_new,
+                    *partial(qg, new,
                              (blk[None, :] <= blk[:, None])[None]))
         trips = -(-table_rows // chunk)
         if trips == 1:
@@ -254,61 +282,56 @@ class GQAttention(nn.Layer):
         return (acc / per_ctx(den)).astype(q.dtype).reshape(B, S, H * hd)
 
     @_scoped("attention")
-    def step_slots_paged(self, h, k_pool, v_pool, tables, pos, walk_pos):
+    def step_slots_paged(self, h, pool, tables, pos, walk_pos):
         """One block a slot: its S rows' K/V go into the block that
-        holds ``pos[b] .. pos[b] + S`` — one in-place update a slot and
-        pool of the pool as it lies (a scatter is a loop of one trip a
-        row on the v5e, ``mla_moe.write_chunk_rows``) — and the rows
-        attend the cache below ``walk_pos[b]`` (``pos``, or 0 for a
-        lane that does not step: it walks nothing) and themselves.
-        The update is made by every pass of every lane: what a denoise
-        pass writes at ``pos ..`` is read by nobody (this block's
-        passes take their own rows' K/V from the pass itself; a later
-        block sees these rows only after the commit pass has written
-        the final ones over them; a parked lane's table is the scratch
+        holds ``pos[b] .. pos[b] + S`` — one in-place update a slot of
+        the pool as it lies (a scatter is a loop of one trip a row on
+        the v5e, ``mla_moe.write_chunk_rows``) — and the rows attend
+        the cache below ``walk_pos[b]`` (``pos``, or 0 for a lane that
+        does not step: it walks nothing) and themselves.  The update
+        is made by every pass of every lane: what a denoise pass
+        writes at ``pos ..`` is read by nobody (this block's passes
+        take their own rows' K/V from the pass itself; a later block
+        sees these rows only after the commit pass has written the
+        final ones over them; a parked lane's table is the scratch
         block; a prefilling lane stands at its next chunk's first row,
         which that chunk writes before any query sees it).  h
-        [B, S, D]; pools [NB, bs, K, hd]; tables [B, L // bs]; pos
-        [B], multiples of S with S dividing bs.  Returns (out
-        [B, S, D], k_pool, v_pool)."""
+        [B, S, D]; pool [NB, bs, W]; tables [B, L // bs]; pos [B],
+        multiples of S with S dividing bs.  Returns (out [B, S, D],
+        pool)."""
         import jax
         import jax.numpy as jnp
         B, S = h.shape[0], h.shape[1]
         q, k, v = self.project(h, pos[:, None] + jnp.arange(S)[None, :])
-        bs = k_pool.shape[1]
+        new = self.cache_rows(k, v)
+        bs = pool.shape[1]
         blocks = tables[jnp.arange(B), pos // bs]
-        offs = pos % bs
-
-        def put(pool, new):
-            new = new.astype(pool.dtype)
-            for b in range(B):
-                pool = jax.lax.dynamic_update_slice(
-                    pool, new[b:b + 1], (blocks[b], offs[b], 0, 0))
-            return pool
-        k_pool, v_pool = put(k_pool, k), put(v_pool, v)
-        out = self.attend(q, k, v, k_pool, v_pool, tables, walk_pos)
-        return _lin(self.o_proj, out), k_pool, v_pool
+        offs, stored = pos % bs, new.astype(pool.dtype)
+        for b in range(B):
+            pool = jax.lax.dynamic_update_slice(
+                pool, stored[b:b + 1], (blocks[b], offs[b], 0))
+        out = self.attend(q, new, pool, tables, walk_pos)
+        return _lin(self.o_proj, out), pool
 
     @_scoped("attention")
-    def prefill_chunk_paged(self, h, k_pool, v_pool, table, pos,
-                            true_len, scratch):
+    def prefill_chunk_paged(self, h, pool, table, pos, true_len,
+                            scratch):
         """C prompt tokens of ONE slot at ``pos .. pos + C`` (``pos``
-        and ``true_len`` multiples of the block length): the K/V of the
-        first ``true_len`` go into the slot's blocks by
+        and ``true_len`` multiples of the block length): the rows of
+        the first ``true_len`` go into the slot's blocks by
         ``write_chunk_rows``' in-place block updates, and the chunk
         attends the slot's rows below ``pos`` and itself under the
         block-causal mask.  h [1, C, D]; table [L // bs].  Returns
-        (out [1, C, D], k_pool, v_pool)."""
+        (out [1, C, D], pool)."""
         import jax.numpy as jnp
         q, k, v = self.project(
             h, (pos + jnp.arange(h.shape[1]))[None, :])
-        k_pool = write_chunk_rows(k_pool, k[0], table, pos, true_len,
-                                  scratch)
-        v_pool = write_chunk_rows(v_pool, v[0], table, pos, true_len,
-                                  scratch)
-        out = self.attend(q, k, v, k_pool, v_pool, table[None, :],
+        new = self.cache_rows(k, v)
+        pool = write_chunk_rows(pool, new[0], table, pos, true_len,
+                                scratch)
+        out = self.attend(q, new, pool, table[None, :],
                           jnp.reshape(pos, (1,)))
-        return _lin(self.o_proj, out), k_pool, v_pool
+        return _lin(self.o_proj, out), pool
 
     def forward(self, h):
         """Uncached block-causal attention over whole sequences, h
@@ -355,22 +378,21 @@ class SDARMoEBlock(nn.Layer):
         y, stats = self.ffn(h.reshape(-1, h.shape[-1]), live.reshape(-1))
         return x + y.reshape(x.shape), stats
 
-    def step_slots_paged(self, x, k_pool, v_pool, tables, pos, live):
+    def step_slots_paged(self, x, pool, tables, pos, live):
         import jax.numpy as jnp
-        a, k_pool, v_pool = self.attn.step_slots_paged(
-            self.input_norm(x), k_pool, v_pool, tables, pos,
+        a, pool = self.attn.step_slots_paged(
+            self.input_norm(x), pool, tables, pos,
             jnp.where(live, pos, 0))
         x, stats = self.feed_forward(
             x + a, jnp.broadcast_to(live[:, None], x.shape[:2]))
-        return x, k_pool, v_pool, stats
+        return x, pool, stats
 
-    def prefill_chunk_paged(self, x, k_pool, v_pool, table, pos,
-                            true_len, scratch, live):
-        a, k_pool, v_pool = self.attn.prefill_chunk_paged(
-            self.input_norm(x), k_pool, v_pool, table, pos, true_len,
-            scratch)
+    def prefill_chunk_paged(self, x, pool, table, pos, true_len,
+                            scratch, live):
+        a, pool = self.attn.prefill_chunk_paged(
+            self.input_norm(x), pool, table, pos, true_len, scratch)
         x, stats = self.feed_forward(x + a, live[None, :])
-        return x, k_pool, v_pool, stats
+        return x, pool, stats
 
     def forward(self, x):
         import jax.numpy as jnp
@@ -493,8 +515,8 @@ class SDARMoEModel(ServedModel, nn.Layer):
                              & jnp.any(left, axis=-1, keepdims=True))
         return jnp.where(fixed, x0, tok), masked & ~fixed, fixed
 
-    def _fused_step_slots(self, tok, k_pools, v_pools, tables, pos,
-                          ctr, eos, rem, flags):
+    def _fused_step_slots(self, tok, pools, no_v, tables, pos, ctr,
+                          eos, rem, flags):
         """One pass of every stepping lane's block (``StepSpec``; the
         module docstring).  A lane with a masked row denoises: the most
         confident masked rows are fixed, and the rows that are now
@@ -503,7 +525,11 @@ class SDARMoEModel(ServedModel, nn.Layer):
         the K/V this pass wrote at ``pos ..`` stay, ``pos`` moves a
         block on and the next block opens as masks.  Lanes that do not
         step (parked, prefilling, out of budget) hit no expert, walk
-        no cached row and keep their state.  tok int32 [B, W]."""
+        no cached row and keep their state.  tok int32 [B, W]; ``pools``
+        one a layer; ``no_v`` is the engine's empty list of V pools,
+        handed back as it came (the arguments keep the seam's order,
+        which ``tests/benchmarks/planted_fault_commit.py`` wraps by
+        position)."""
         import jax.numpy as jnp
         W = self.block_length
         masked = ((flags[:, None] >> jnp.arange(W)) & 1) > 0
@@ -511,12 +537,11 @@ class SDARMoEModel(ServedModel, nn.Layer):
         denoising = live & jnp.any(masked, axis=1)
         committing = live & ~jnp.any(masked, axis=1)
         x = self.embed._data[jnp.where(masked, self.mask_token_id, tok)]
-        new_k, new_v, stats = [], [], []
+        new_pools, stats = [], []
         for j, blk in enumerate(self.blocks):
-            x, kp, vp, st = blk.step_slots_paged(
-                x, k_pools[j], v_pools[j], tables, pos, live)
-            new_k.append(kp)
-            new_v.append(vp)
+            x, pool, st = blk.step_slots_paged(x, pools[j], tables, pos,
+                                               live)
+            new_pools.append(pool)
             stats.append(st)
         new_tok, new_masked, fixed = self._unmask(
             self._head(x), tok, masked, denoising)
@@ -534,7 +559,7 @@ class SDARMoEModel(ServedModel, nn.Layer):
         new_rem = jnp.where(denoising,
                             jnp.where(hit_eos, 0, rem - count), rem)
         # a commit opens the next block
-        L = tables.shape[1] * k_pools[0].shape[1]
+        L = tables.shape[1] * pools[0].shape[1]
         new_pos = jnp.where(committing, jnp.minimum(pos + W, L - W), pos)
         new_tok = jnp.where(committing[:, None], self.mask_token_id,
                             new_tok)
@@ -551,58 +576,56 @@ class SDARMoEModel(ServedModel, nn.Layer):
             jnp.sum(denoising), jnp.sum(committing), jnp.sum(fixed),
             jnp.sum(committing)))
         return (report, done, new_tok, new_pos, ctr + count, new_rem,
-                new_k, new_v, counters, new_flags)
+                new_pools, no_v, counters, new_flags)
 
-    def _chunk_prefill_tick_paged(self, toks, k_pools, v_pools, table,
-                                  pos, true_len, scratch):
+    def _chunk_prefill_tick_paged(self, toks, pools, table, pos,
+                                  true_len, scratch):
         """C prompt tokens of one slot through every block: their K/V
         are cached, nothing else is kept (no logit of a prefill is
         used: the head does not run).  Returns (a [1, 1] handle of the
-        last layer's output, k_pools, v_pools, counters)."""
+        last layer's output, pools, [], counters)."""
         import jax.numpy as jnp
         pos = jnp.asarray(pos, jnp.int32)
         live = jnp.arange(toks.shape[1]) < true_len
         x = self.embed._data[toks]
-        new_k, new_v, stats = [], [], []
+        new_pools, stats = [], []
         for j, blk in enumerate(self.blocks):
-            x, kp, vp, st = blk.prefill_chunk_paged(
-                x, k_pools[j], v_pools[j], table, pos, true_len, scratch,
-                live)
-            new_k.append(kp)
-            new_v.append(vp)
+            x, pool, st = blk.prefill_chunk_paged(
+                x, pools[j], table, pos, true_len, scratch, live)
+            new_pools.append(pool)
             stats.append(st)
         zero = jnp.int32(0)
-        return (x[:, -1, :1].astype(jnp.float32), new_k, new_v,
+        return (x[:, -1, :1].astype(jnp.float32), new_pools, [],
                 self._counter_vector(stats, (
                     zero, zero, zero, true_len // self.block_length)))
 
     def _compiled_fused_decode_fn(self, pnames, params, cache_key,
                                   paged=False):
-        """(p_list, b_list, k_pools, v_pools, block_tables, tok [B, W],
+        """(p_list, b_list, pools, [], block_tables, tok [B, W],
         pos, temp, top_k, top_p, seed_lo, seed_hi, ctr, eos, rem,
         flags) -> (report [B, W + 4], done, new_tok, new_pos, new_ctr,
-        new_rem, k_pools, v_pools, counters, new_flags): ``StepSpec``'s
+        new_rem, pools, [], counters, new_flags): ``StepSpec``'s
         contract.  Pools donated.  The sampling lanes are taken and
         not read (sampled requests are refused at ``submit``)."""
         if not paged:
             raise NotImplementedError(
                 "the K/V pools are paged: no contiguous step")
 
-        def body(k_pools, v_pools, tables, tok, pos, _temp, _top_k,
-                 _top_p, _slo, _shi, ctr, eos, rem, flags):
-            return self._fused_step_slots(tok, k_pools, v_pools, tables,
-                                          pos, ctr, eos, rem, flags)
+        def body(pools, no_v, tables, tok, pos, _temp, _top_k, _top_p,
+                 _slo, _shi, ctr, eos, rem, flags):
+            return self._fused_step_slots(tok, pools, no_v, tables, pos,
+                                          ctr, eos, rem, flags)
         return self._program("fused_decode", cache_key, params, pnames,
                              body)
 
     def _compiled_paged_chunk_prefill_fn(self, pnames, params,
                                          cache_key):
-        """(p_list, b_list, k_pools, v_pools, ids [1, C], block_table,
-        pos, true_len, scratch) -> (handle [1, 1], k_pools, v_pools,
-        counters).  Pools donated."""
-        def body(k_pools, v_pools, ids, table, pos, true_len, scratch):
+        """(p_list, b_list, pools, [], ids [1, C], block_table, pos,
+        true_len, scratch) -> (handle [1, 1], pools, [], counters).
+        Pools donated."""
+        def body(pools, _v, ids, table, pos, true_len, scratch):
             return self._chunk_prefill_tick_paged(
-                ids, k_pools, v_pools, table, pos, true_len, scratch)
+                ids, pools, table, pos, true_len, scratch)
         return self._program("paged_chunk_prefill", cache_key, params,
                              pnames, body)
 
@@ -615,9 +638,8 @@ class SDARMoEModel(ServedModel, nn.Layer):
             or k_proj.weight._data.dtype
         step = "the step carries a block of rows a lane: "
         return ServingSpec(
-            kv=KVRowSpec.heads(len(self.blocks),
-                               cfg["num_key_value_heads"],
-                               cfg["head_dim"], dtype),
+            kv=KVRowSpec(len(self.blocks), dtype, (("kv", (
+                2 * cfg["num_key_value_heads"] * cfg["head_dim"],)),)),
             max_positions=cfg["max_position_embeddings"],
             vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
             counters=MOE_COUNTERS + STEP_COUNTERS,

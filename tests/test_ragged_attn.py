@@ -275,6 +275,13 @@ def _latent_step_text(sds, program, *args):
         [sds(params[n].shape) for n in names], *args).compile().as_text()
 
 
+def _computation(text, name):
+    """The lines of the computation ``name`` of a compiled module's
+    text, its header to its closing brace."""
+    start = re.search(r"^%?" + re.escape(name) + r" \(", text, re.M).start()
+    return text[start:text.index("\n}", start)]
+
+
 def _pool_copies(text):
     """Instructions that copy or transpose an array of the pool's
     shape."""
@@ -363,29 +370,40 @@ def test_latent_chunk_rows_are_written_in_place_on_the_v5e():
 def test_block_rows_are_written_in_place_on_the_v5e():
     """The block-diffusion model's grouped-query attention at the
     published widths (32 query / 4 K/V heads of 128, hidden 2,048,
-    blocks of 4 positions; K and V pools of 10,241 blocks of 16 rows),
-    compiled for the compile-only ``TPU v5 lite`` device.  The step
-    over 32 slots writes each slot's 4 rows by ONE in-place update a
-    pool (64 in all) and the chunk program its 256 rows by 17 a pool;
-    the one loop of either is the walk; nothing comes from a scatter
-    and nothing copies or transposes a pool, whose ``[4, 128]`` rows
-    the runtime keeps unpadded.  The grouped product compiles at the
-    routed layer's two widths for a step's and a chunk's pairs under
-    the tiles ``_gmm_tiling`` gives them."""
+    blocks of 4 positions; ONE pool a layer of 10,241 blocks of 16
+    rows of K and V flat, 1,024 wide), compiled for the compile-only
+    ``TPU v5 lite`` device.  The pool lies in whole ``(8,128)(2,1)``
+    tiles with nothing padded (as ``[.., 4, 128]`` rows it lay in
+    quarter-filled ``T(4,128)`` tiles and its block gather ran at a
+    third of the memory's rate: chip runs, PR 35 and 36).  The step
+    over 32 slots writes each slot's 4 rows by ONE in-place update (32
+    in all) and the chunk program its 256 rows by 17; the one loop of
+    either is the walk, whose trip holds ONE gather of pool blocks (32
+    items x 16 blocks of ``[16, 1024]`` in the step) that no reshape,
+    copy or transpose relays out before the products read it; nothing
+    comes from a scatter and nothing copies or transposes the pool.
+    The grouped product compiles at the routed layer's two widths for
+    a step's and a chunk's pairs under the tiles ``_gmm_tiling`` gives
+    them."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu import nn
     from paddle_tpu.distributed.moe import grouped_matmul
     from paddle_tpu.jit import _swapped
-    from paddle_tpu.models.sdar_moe import GQAttention
-    from paddle_tpu.serving.kvcache import KVRowSpec
+    from paddle_tpu.models.sdar_moe import GQAttention, SDARMoEModel
 
-    shape, pool = (10241, 16, 4, 128), "bf16[10241,16,4,128]"
-    assert KVRowSpec.heads(7, 4, 128, "bfloat16").pool_shapes(
-        (10241, 16)) == [shape, shape]
+    shape, pool = (10241, 16, 1024), "bf16[10241,16,1024]"
     with nn.LazyGuard():
-        attn = GQAttention(2048, 32, 4, 128, 1e6, 1e-6, 4)
-    attn.to(dtype="bfloat16")
+        model = SDARMoEModel(dict(
+            vocab_size=512, max_position_embeddings=4096,
+            hidden_size=2048, moe_intermediate_size=768,
+            num_hidden_layers=1, num_attention_heads=32,
+            num_key_value_heads=4, head_dim=128, num_experts=128,
+            num_experts_per_tok=8, rms_norm_eps=1e-6, rope_theta=1e6),
+            mask_token_id=511)
+    model.to(dtype="bfloat16")
+    assert model.serving_spec().kv.pool_shapes((10241, 16)) == [shape]
+    attn = model.blocks[0].attn
     params = dict(attn.named_parameters())
     names = sorted(params)
 
@@ -393,27 +411,33 @@ def test_block_rows_are_written_in_place_on_the_v5e():
         def step(p_list, *args):
             with _swapped(params, dict(zip(names, p_list))):
                 return program(attn, *args)
-        return jax.jit(step, donate_argnums=(2, 3)).lower(
+        return jax.jit(step, donate_argnums=(2,)).lower(
             [sds(params[n].shape) for n in names], *args
         ).compile().as_text()
 
     with _described_v5e() as sds:
         i32 = jnp.int32
         step = text(sds, GQAttention.step_slots_paged, sds((32, 4, 2048)),
-                    sds(shape), sds(shape), sds((32, 256), i32),
-                    sds((32,), i32), sds((32,), i32))
+                    sds(shape), sds((32, 256), i32), sds((32,), i32),
+                    sds((32,), i32))
         chunk = text(sds, GQAttention.prefill_chunk_paged,
-                     sds((1, 256, 2048)), sds(shape), sds(shape),
-                     sds((256,), i32), sds((), i32), sds((), i32),
-                     sds((), i32))
+                     sds((1, 256, 2048)), sds(shape), sds((256,), i32),
+                     sds((), i32), sds((), i32), sds((), i32))
         for m, k, n in ((1024, 2048, 1536), (1024, 768, 2048),
                         (2048, 2048, 1536), (2048, 768, 2048)):
             jax.jit(lambda x, w, g: grouped_matmul(x, w, g, "gmm")).lower(
                 sds((m, k)), sds((128, k, n)),
                 sds((128,), jnp.int32)).compile()
-    for program, updates in ((step, 64), (chunk, 34)):
+    # (program, in-place updates, blocks one trip of the walk fetches)
+    for program, updates, fetched in ((step, 32, 32 * 16),
+                                      (chunk, 17, 16)):
         lines = program.splitlines()
-        assert pool + "{3,2,1,0:T(4,128)(2,1)}" in program
+        # the pool and the blocks fetched from it, wherever they
+        # appear: row-major in whole tiles (S(1): an array kept on chip)
+        assert pool + "{2,1,0:T(8,128)(2,1)}" in program
+        assert set(re.findall(
+            r"bf16\[(?:10241|%d),16,1024\]\{([^}]*)\}" % fetched,
+            program)) <= {"2,1,0:T(8,128)(2,1)", "2,1,0:T(8,128)(2,1)S(1)"}
         assert sum(" while(" in ln for ln in lines) == 1
         assert not [ln for ln in lines if re.search(
             r" scatter\(|op_name=\"[^\"]*scatter", ln)]
@@ -423,6 +447,21 @@ def test_block_rows_are_written_in_place_on_the_v5e():
                               + r"\S* dynamic-update-slice\(",
                               program)) == updates
         assert "input_output_alias" in lines[0]
+        # the walk's trip: the loop's body holds one gather of pool
+        # blocks, and the blocks it fetched reach the products as they
+        # lie (a reshape, copy or transpose of them is a relayout of
+        # 16.8 MB a trip: 11.3 ms a step against 3.7, chip run, PR 36)
+        body = _computation(program, re.search(
+            r" while\(.*body=%?([\w.\-]+)", program).group(1))
+        blocks = r"bf16\[%d,16,1024\]" % fetched
+        called = "\n".join(_computation(program, name) for name in
+                           re.findall(r"calls=%?([\w.\-]+)", body))
+        assert len(re.findall(
+            r" gather\(.*slice_sizes=\{1,16,1024\}", body + called)) == 1
+        assert len(re.findall(r"= " + blocks + r"\S* fusion\(",
+                              body)) == 1
+        assert not re.findall(
+            r"= bf16\[[\d,]+\]\S* (?:reshape|copy|transpose)\(", body)
 
 
 # -- knob validation --------------------------------------------------

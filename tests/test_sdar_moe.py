@@ -2,8 +2,8 @@
 against its plain reference (benchmarks/configs/sdar_moe_reference.py),
 at a tiny size on the CPU with seeded float32 weights: the step that
 carries a block of rows a lane through ``serving.Engine``
-(``models/programs.py`` ``StepSpec``), grouped-query K/V pools, the
-softmax gate."""
+(``models/programs.py`` ``StepSpec``), the grouped-query cache (K and V
+of a position in one flat row of one pool a layer), the softmax gate."""
 import importlib.util
 import os
 import sys
@@ -129,10 +129,9 @@ def paged_block_logits(model, prompt, block, masked, chunk=8, bs=8,
     ``prompt`` (whole blocks) went through the chunk program into paged
     pools: the step's blocks and head, without its unmasking."""
     L = 64
-    shape = (nb + 1, bs, model.config["num_key_value_heads"],
-             model.config["head_dim"])
-    k_pools = [jnp.zeros(shape, jnp.float32) for _ in model.blocks]
-    v_pools = [jnp.zeros(shape, jnp.float32) for _ in model.blocks]
+    shape = (nb + 1, bs, 2 * model.config["num_key_value_heads"]
+             * model.config["head_dim"])
+    pools = [jnp.zeros(shape, jnp.float32) for _ in model.blocks]
     table = np.zeros(L // bs, np.int32)
     table[:nb - 2] = 3 + np.arange(nb - 2)      # blocks 3.. are the slot's
     table = jnp.asarray(table)
@@ -140,16 +139,15 @@ def paged_block_logits(model, prompt, block, masked, chunk=8, bs=8,
         part = prompt[p0:p0 + chunk]
         ids = np.zeros((1, chunk), np.int32)
         ids[0, :len(part)] = part
-        _, k_pools, v_pools, _ = model._chunk_prefill_tick_paged(
-            jnp.asarray(ids), k_pools, v_pools, table, p0, len(part), 0)
+        _, pools, _, _ = model._chunk_prefill_tick_paged(
+            jnp.asarray(ids), pools, table, p0, len(part), 0)
     pos = jnp.asarray([len(prompt)], jnp.int32)
     x = model.embed._data[jnp.where(
         jnp.asarray(masked), model.mask_token_id,
         jnp.asarray(block, jnp.int32))][None]
     for j, blk in enumerate(model.blocks):
-        x, _, _, _ = blk.step_slots_paged(
-            x, k_pools[j], v_pools[j], table[None], pos,
-            jnp.ones((1,), bool))
+        x, _, _ = blk.step_slots_paged(
+            x, pools[j], table[None], pos, jnp.ones((1,), bool))
     return np.asarray(model._head(x)[0])
 
 
@@ -157,9 +155,9 @@ def paged_block_logits(model, prompt, block, masked, chunk=8, bs=8,
     (0, [True] * 4), (8, [True] * 4), (12, [False, True, False, True]),
     (20, [False] * 4), (28, [True, False, False, False])])
 def test_a_block_over_the_paged_cache_equals_the_reference(n, masked):
-    """The grouped-query attention over the 2-head pools (the walk, the
-    block's own rows, the block-causal chunk program before it) against
-    the reference's one softmax with K and V repeated."""
+    """The grouped-query attention over the pools of 2-head rows (the
+    walk, the block's own rows, the block-causal chunk program before
+    it) against the reference's one softmax with K and V repeated."""
     model, get = seeded(seed=2)
     prompt, block = tokens(n, seed=3), tokens(4, seed=4)
     got = paged_block_logits(model, prompt, block, masked)
@@ -177,6 +175,82 @@ def test_whole_forward_equals_the_reference():
                                    masked[None])._data[0])
     want = np.asarray(_reference().logits(get, DIMS, ids, masked))
     assert np.abs(got - want).max() < TOL
+
+
+def _flat_pool_case(seed, positions, bs=8, table_rows=512):
+    """A seeded ``GQAttention`` (4 query / 2 K/V heads of 16, blocks of
+    4), hidden states [T, D] a slot, and a pool of noise in which slot
+    b's rows below ``positions[b]`` are laid through its own table by
+    plain indexing (not by the programs under test).  Returns (attn,
+    hidden [B, T, D], pool, tables [B, table_rows // bs], every
+    position's row [B, T, 64])."""
+    from paddle_tpu.models.sdar_moe import GQAttention
+    attn = GQAttention(64, 4, 2, 16, 1e6, 1e-6, 4)
+    for i, (_, p) in enumerate(attn.named_parameters()):
+        v = jax.random.normal(jax.random.fold_in(
+            jax.random.PRNGKey(seed), i), tuple(p.shape), jnp.float32)
+        p.set_value(1.0 + 0.1 * v if len(p.shape) == 1 else 0.3 * v)
+    rng = np.random.default_rng(seed)
+    n, per = len(positions), table_rows // bs
+    T = max(positions) + 16
+    hidden = rng.normal(size=(n, T, 64)).astype(np.float32)
+    # block 0 is the scratch block; the last 5 belong to nobody
+    pool = rng.normal(size=(1 + n * per + 5, bs, 64)).astype(np.float32)
+    tables = 1 + rng.permutation(n * per).reshape(n, per).astype(np.int32)
+    at = np.broadcast_to(np.arange(T)[None, :], (n, T))
+    _, k, v = attn.project(jnp.asarray(hidden), jnp.asarray(at))
+    rows = np.asarray(attn.cache_rows(k, v))
+    for b, pos in enumerate(positions):
+        for i in range(pos):
+            pool[tables[b, i // bs], i % bs] = rows[b, i]
+    return attn, hidden, pool, tables, rows
+
+
+# a walk chunk is 256 rows here (``walk_chunk``: 32 blocks of 8)
+@pytest.mark.parametrize("program", ["step", "chunk"])
+@pytest.mark.parametrize("pos", [264, 268, 256, 0], ids=[
+    "at a block's edge", "inside a block", "at a chunk's edge",
+    "at 0 (a parked lane)"])
+def test_the_flat_pool_gives_what_the_uncached_attention_gives(pos,
+                                                               program):
+    """``step_slots_paged`` (three slots: the case's, one two chunks
+    deep, one parked on the scratch block) and ``prefill_chunk_paged``
+    (16 rows of which 12 are real) over the one flat pool against
+    ``GQAttention.forward`` on the same hidden states; every row of the
+    pool outside ``[pos, pos + rows written)`` of the writing slots,
+    other slots' blocks and nobody's blocks included, comes back bit
+    for bit, and the written rows are ``cache_rows`` of the projection
+    (to rounding: one product over all positions against the
+    program's over its own rows)."""
+    positions = [pos, 300, 0] if program == "step" else [pos]
+    attn, hidden, pool, tables, rows = _flat_pool_case(31, positions)
+    bs = pool.shape[1]
+    wrote = 4 if program == "step" else 12
+    if program == "step":
+        tables[2, :] = 0                     # a parked lane: scratch
+        at = np.asarray(positions, np.int32)
+        h = np.stack([hidden[b, p:p + 4] for b, p in enumerate(positions)])
+        out, new_pool = attn.step_slots_paged(
+            jnp.asarray(h), jnp.asarray(pool), jnp.asarray(tables),
+            jnp.asarray(at), jnp.asarray(at))
+    else:
+        out, new_pool = attn.prefill_chunk_paged(
+            jnp.asarray(hidden[:, pos:pos + 16]), jnp.asarray(pool),
+            jnp.asarray(tables[0]), jnp.int32(pos), jnp.int32(wrote),
+            jnp.int32(0))
+    out, new_pool = np.asarray(out), np.asarray(new_pool)
+    written = np.zeros(pool.shape[:2], bool)
+    for b, p in enumerate(positions):
+        want = np.asarray(attn.forward(
+            jnp.asarray(hidden[b:b + 1, :p + wrote])))[0, p:]
+        assert np.abs(out[b, :wrote] - want).max() < TOL
+        assert np.abs(want).max() > 0.05
+        for i in range(p, p + wrote):
+            where = tables[b, i // bs], i % bs
+            written[where] = True
+            assert np.abs(new_pool[where] - rows[b, i]).max() < TOL
+    assert written.sum() == len(positions) * wrote
+    assert np.array_equal(new_pool[~written], pool[~written])
 
 
 def test_the_mask_is_block_causal():
@@ -280,8 +354,12 @@ def test_the_step_s_counters_and_healthz_field():
     # 9 step passes x 4 rows + 8 prompt rows, top 2, over 2 layers
     assert value["moe_routed_pairs"] == (9 * 4 + 8) * 2 * 2
     assert eng.step_report() == {"rows": 4, "steps": 4}
-    assert eng.kv_geometry() == {"block_size": 8, "num_heads": 2,
-                                 "head_dim": 16, "n_layers": 2}
+    # K and V of 2 heads of 16 in one flat row of one pool a layer
+    assert eng.kv_geometry() == {"block_size": 8,
+                                 "rows": [["kv", [2 * 2 * 16]]],
+                                 "n_layers": 2}
+    assert len(eng.k_pools) == 2 and eng.v_pools == []
+    assert eng.k_pools[0].shape == (41, 8, 64)
     assert eng.registry.get("serving.kv_row_bytes").value \
         == 2 * 2 * 2 * 16 * 4
 
