@@ -289,9 +289,9 @@ def _gmm_tiling(m, k, n):
     grouped product: all of a short m in one tile (a decode step's
     pairs: every hit expert then costs one pass over its own weights),
     128 rows otherwise; the whole contraction in one k tile (up to
-    2,048); the whole of n in one tile where the contraction is at
-    most 1,024 and n at most 2,048, else the widest n tile of those
-    tried that divides n.  Chip run, PR 28, 40 experts hit, m 192:
+    2,048); the whole of n in one tile where the contraction's tile
+    is at most 1,024 and n at most 2,048, else the widest n tile of
+    those tried that divides n.  Chip run, PR 28, 40 experts hit, m 192:
     [2,048 -> 2,816] 0.65 ms at (192, 2048, 1408) against 0.72 at
     (192, 1024, 1408), [1,408 -> 2,048] 0.34 ms at (192, 1408, 1024)
     against 0.60 at (192, 128, 1024) and 0.42 at (192, 1408, 256): 87%
@@ -303,12 +303,22 @@ def _gmm_tiling(m, k, n):
     fast memory at (256, 2048, 1536); [768 -> 2,048] 0.619 / 0.672 ms
     at (128, 768, 2048) against 0.660 / 0.718 at n tile 1,024, 0.691 /
     0.756 at 512, 0.647 / 0.683 at (256, 768, 2048): 84% and 79% of the
-    weights' time; ``ragged_dot`` 2.74 and 1.65 ms."""
+    weights' time; ``ragged_dot`` 2.74 and 1.65 ms.  Chip run, PR 37
+    (``_chip/xing_bench.py``), 30 of 64 experts hit by 44 pairs in m
+    128 / all 64 by 1,024: [3,584 -> 2,048] (contraction tiles of 512:
+    3,584 = 7 x 512) 0.664 / 1.399 ms at (128, 512, 2048) against 0.727
+    / 1.539 at n tile 1,024, 0.662 / 1.400 at k tile 896, and no fit at
+    (128, 3584, 1024) or (128, 1792, 2048); [1,024 -> 3,584] 0.337 /
+    0.717 ms at (128, 1024, 1792) against 0.343 / 0.744 at n tile 512,
+    0.346 / 0.734 at (128, 512, 3584), no fit at (128, 1024, 3584): 81%
+    / 82% and 80% / 80% of the weights' time by the host's clock;
+    ``ragged_dot`` 0.907 / 3.279 and 0.469 / 1.706 ms."""
     tm = m if m <= 256 else 128
     tk = k if k <= 2048 else next(
         t for t in (2048, 1024, 512, 256, 128, k) if k % t == 0)
-    tn = n if k <= 1024 and n <= 2048 else next(
-        t for t in (1536, 1408, 1024, 512, 256, 128, n) if n % t == 0)
+    tn = n if tk <= 1024 and n <= 2048 else next(
+        t for t in (1792, 1536, 1408, 1024, 512, 256, 128, n)
+        if n % t == 0)
     return tm, tk, tn
 
 

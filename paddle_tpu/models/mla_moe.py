@@ -6,7 +6,9 @@ Layer equations (pre-norm residual blocks, RMSNorm, no position table,
 untied head; d hidden, H heads, d_n / d_r the no-position and rotary
 parts of a query/key head, d_v the value head, r the latent rank):
 
-* attention, ``h = RMSNorm(x)``: ``q = h W_q`` -> per head
+* attention, ``h = RMSNorm(x)``: ``q = h W_q`` (or, where the config
+  gives ``q_lora_rank``, ``q = RMSNorm(h W_qa) W_qb``: the query is
+  compressed too) -> per head
   ``[q_n (d_n); q_r (d_r)]``; ``[c; k_r] = h W_kva`` (r + d_r);
   ``c <- RMSNorm(c)``; ``k_r <- RoPE(k_r, pos)`` (ONE rotary key for
   all heads); ``q_r <- RoPE(q_r, pos)``.  **The cached row of a
@@ -29,10 +31,28 @@ parts of a query/key head, d_v the value head, r the latent rank):
   ``n_shared`` times that width over every token.  No token is dropped
   (``distributed/moe.py`` ``dropless_experts``).
 
+* the residual, where the config gives ``hc_mult`` n > 1
+  (manifold-constrained hyper-connections, arXiv:2512.24880): a
+  position's state is n streams ``X [n, d]``, every stream the
+  embedding after the lookup, their sum before the final norm.  Around
+  EACH sub-layer F (attention, feed-forward), with that sub-layer's
+  own leaves: ``x' = RMSNorm(vec(X))`` over all n d numbers;
+  ``[Hpre~; Hpost~; Hres~] = a * (Phi x') + b`` (``Phi`` [n (n + 2),
+  n d], ``a`` one scalar a mapping, all float32); ``H_pre =
+  sigmoid(Hpre~)``, ``H_post = 2 sigmoid(Hpost~)``, ``H_res`` the
+  Sinkhorn projection of ``exp(clip(Hres~))`` (``hc_sinkhorn_iters``
+  times columns, then rows, divided by their sums + ``hc_eps``: doubly
+  stochastic); ``u = H_pre X``, ``X <- H_res X + H_post^T F(u)``
+  (``HyperConnection``).
+
 RoPE pairs dimension i with i + d_r/2 in STORED order (the published
 code first de-interleaves; that is a fixed permutation of W_q's and
-W_kva's columns).  What is not here: a vision tower (positions are
-token positions), ``q_lora_rank``, grouped top-k (one group), YaRN.
+W_kva's columns); ``rope_scaling`` of type ``yarn`` interpolates the
+slow frequencies and scales the softmax (``yarn_inv_freq``,
+``yarn_mscale``).  What is not here: a vision tower (positions are
+token positions), grouped top-k (one group), the next-token prediction
+module (``num_nextn_predict_layers``: one more block that drafts token
+t + 2; the main model's logits do not depend on it).
 
 Serving: ``serving_spec()`` declares one pool a layer of ``[r + d_r]``
 rows; ``serving_program`` offers the fused decode tick (absorbed form,
@@ -195,16 +215,62 @@ def rms_norm(x, weight, eps):
     return xf.astype(x.dtype) * weight.astype(x.dtype)
 
 
-def rope(x, pos, theta):
+def yarn_mscale(factor, mscale):
+    """YaRN's attention scale ``0.1 mscale ln(factor) + 1`` (1 at a
+    factor of at most 1)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_inv_freq(dim, theta, scaling):
+    """The ``dim // 2`` rotary frequencies under ``rope_scaling`` of
+    type ``yarn`` (the published ``DeepseekV3YarnRotaryEmbedding``):
+    ``f_i = theta^(-2i/dim)``; a frequency that turns more than
+    ``beta_fast`` times over the original context is kept, one that
+    turns less than ``beta_slow`` times is divided by ``factor``, and
+    between them a linear ramp over the index:
+    ``corr(b) = dim ln(L0 / (2 pi b)) / (2 ln theta)``, ``low =
+    floor(corr(beta_fast))``, ``high = ceil(corr(beta_slow))``, both
+    clipped to ``[0, dim - 1]``, ``ramp_i = clip((i - low) / (high -
+    low), 0, 1)``, ``inv_freq_i = f_i (1 - ramp_i) + f_i / factor
+    ramp_i``.  Returns float32 numpy [dim // 2]."""
+    import numpy as np
+    if scaling.get("type", scaling.get("rope_type")) != "yarn":
+        raise ValueError(f"rope_scaling {scaling!r}: only type 'yarn' "
+                         "is written")
+    L0 = scaling["original_max_position_embeddings"]
+
+    def corr(turns):
+        return dim * math.log(L0 / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(corr(scaling.get("beta_fast", 32))), 0)
+    high = min(math.ceil(corr(scaling.get("beta_slow", 1))), dim - 1)
+    freq = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2) - low)
+                   / (high - low if high != low else 0.001), 0.0, 1.0)
+    return (freq * (1.0 - ramp)
+            + freq / scaling["factor"] * ramp).astype(np.float32)
+
+
+def rope(x, pos, theta, scaling=None):
     """Rotate ``x [..., d]`` to positions ``pos`` (broadcastable to
     ``x.shape[:-1]``): dimension i pairs with i + d/2, angle
-    ``pos * theta^(-2i/d)``, in float32."""
+    ``pos * theta^(-2i/d)`` (``scaling``: ``pos * yarn_inv_freq_i``,
+    cos and sin times ``yarn_mscale(factor, mscale) /
+    yarn_mscale(factor, mscale_all_dim)``), in float32."""
     import jax.numpy as jnp
     half = x.shape[-1] // 2
-    inv = jnp.exp(-math.log(theta) * jnp.arange(half, dtype=jnp.float32)
-                  / half)
+    if scaling is None:
+        inv, m = jnp.exp(-math.log(theta)
+                         * jnp.arange(half, dtype=jnp.float32) / half), 1.0
+    else:
+        inv = jnp.asarray(yarn_inv_freq(2 * half, theta, scaling))
+        m = (yarn_mscale(scaling["factor"], scaling.get("mscale", 1))
+             / yarn_mscale(scaling["factor"],
+                           scaling.get("mscale_all_dim", 0)))
     ang = jnp.asarray(pos, jnp.float32)[..., None] * inv
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if m != 1.0:
+        cos, sin = cos * m, sin * m
     x1 = x[..., :half].astype(jnp.float32)
     x2 = x[..., half:].astype(jnp.float32)
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
@@ -311,15 +377,31 @@ class RoutedFFN(nn.Layer):
 class MLAttention(nn.Layer):
     def __init__(self, hidden, num_heads, qk_nope_head_dim,
                  qk_rope_head_dim, v_head_dim, kv_lora_rank, rope_theta,
-                 eps):
+                 eps, q_lora_rank=None, rope_scaling=None):
         super().__init__()
         self.num_heads = num_heads
         self.d_n, self.d_r = qk_nope_head_dim, qk_rope_head_dim
         self.d_v, self.rank = v_head_dim, kv_lora_rank
         self.theta = float(rope_theta)
+        self.rope_scaling = dict(rope_scaling) if rope_scaling else None
+        # the scores' scale: 1 / sqrt(d_n + d_r), times YaRN's
+        # attention scale squared where ``mscale_all_dim`` is given
+        self.scale = 1.0 / math.sqrt(self.d_n + self.d_r)
+        if self.rope_scaling and self.rope_scaling.get("mscale_all_dim"):
+            self.scale *= yarn_mscale(
+                self.rope_scaling["factor"],
+                self.rope_scaling["mscale_all_dim"]) ** 2
         self.row = kv_lora_rank + qk_rope_head_dim
-        self.q_proj = nn.Linear(hidden, num_heads * (self.d_n + self.d_r),
-                                bias_attr=False)
+        q_width = num_heads * (self.d_n + self.d_r)
+        if q_lora_rank is None:
+            self.q_proj = nn.Linear(hidden, q_width, bias_attr=False)
+        else:
+            self.q_a_proj = nn.Linear(hidden, q_lora_rank,
+                                      bias_attr=False)
+            self.q_a_norm = RMSNorm(q_lora_rank, eps)
+            self.q_b_proj = nn.Linear(q_lora_rank, q_width,
+                                      bias_attr=False)
+        self.q_lora_rank = q_lora_rank
         self.kv_a_proj = nn.Linear(hidden, self.row, bias_attr=False)
         self.kv_norm = RMSNorm(kv_lora_rank, eps)
         # [r, H * (d_n + d_v)]: a raw parameter, because the absorbed
@@ -340,13 +422,19 @@ class MLAttention(nn.Layer):
         the normed latent and the rotated shared key."""
         import jax.numpy as jnp
         B, S = h.shape[0], h.shape[1]
-        q = _lin(self.q_proj, h).reshape(B, S, self.num_heads,
-                                         self.d_n + self.d_r)
+        if self.q_lora_rank is None:
+            q = _lin(self.q_proj, h)
+        else:
+            q = _lin(self.q_b_proj,
+                     self.q_a_norm(_lin(self.q_a_proj, h)))
+        q = q.reshape(B, S, self.num_heads, self.d_n + self.d_r)
         q_n = q[..., :self.d_n]
-        q_r = rope(q[..., self.d_n:], pos[:, :, None], self.theta)
+        q_r = rope(q[..., self.d_n:], pos[:, :, None], self.theta,
+                   self.rope_scaling)
         ckr = _lin(self.kv_a_proj, h)
         c = self.kv_norm(ckr[..., :self.rank])
-        k_r = rope(ckr[..., self.rank:], pos, self.theta)
+        k_r = rope(ckr[..., self.rank:], pos, self.theta,
+                   self.rope_scaling)
         return q_n, q_r, jnp.concatenate([c, k_r], axis=-1)
 
     def absorbed_wins(self, window):
@@ -356,11 +444,15 @@ class MLAttention(nn.Layer):
         the row, context over the latent); expanded costs
         ``2 H (d_n + d_r + d_v)`` and, once for each position a trip
         fetches, the expansion ``2 r H (d_n + d_v)``.  Absorbed wins
-        while ``window * (absorbed - expanded) < expansion``: at
-        H 16, d_n 128, d_r 64, d_v 128, r 512 that is 34.8 against
-        10.2 kFLOP a pair and 4.19 MFLOP a position, so up to 170
-        queries — a decode row (1) is absorbed, a 256-token chunk
-        expanded."""
+        while ``window * (absorbed - expanded) < expansion``, that is
+        while ``window < r (d_n + d_v) / (2 r - d_n - d_v)``: the
+        heads cancel, so the sizes that decide are the head's and the
+        latent's alone.  At d_n 128, d_r 64, d_v 128, r 512 (16 heads
+        of a 2,048 model: 34.8 against 10.2 kFLOP a pair and 4.19
+        MFLOP a position; 32 heads of a 3,584 one: twice each) up to
+        170 queries — a decode row (1) is absorbed, a 256-token chunk
+        expanded, at 64 trips over 16 k cached rows as at 32 over
+        8 k."""
         H = self.num_heads
         absorbed = 2 * H * (2 * self.rank + self.d_r)
         expanded = 2 * H * (self.d_n + self.d_r + self.d_v)
@@ -403,7 +495,7 @@ class MLAttention(nn.Layer):
         table_rows = tables.shape[1] * bs
         chunk = walk_chunk(table_rows, bs)
         w_kvb = self._w_kvb()
-        scale = 1.0 / math.sqrt(self.d_n + self.d_r)
+        scale = self.scale
         q_end = pos[:, None] + jnp.arange(S)[None, :]          # [B, S]
         highest = jax.lax.Precision.HIGHEST
         if absorbed:
@@ -616,7 +708,7 @@ class MLAttention(nn.Layer):
             kv = jnp.einsum("bkr,rhe->bkhe", c, w_kvb)
             sc = jnp.einsum("bshn,bkhn->bhsk", q_n, kv[..., :self.d_n])
         sc = (sc + jnp.einsum("bshr,bkr->bhsk", q_r, k_r)).astype(
-            jnp.float32) / math.sqrt(self.d_n + self.d_r)
+            jnp.float32) * self.scale
         causal = jnp.arange(S)[None, :] <= jnp.arange(S)[:, None]
         p = jax.nn.softmax(jnp.where(causal[None, None], sc, -1e30),
                            axis=-1).astype(h.dtype)
@@ -630,9 +722,138 @@ class MLAttention(nn.Layer):
                     ctx.reshape(B, S, self.num_heads * self.d_v))
 
 
+def sinkhorn(cells, n, iters, eps):
+    """``cells``: the n x n numbers of ``exp(Hres~)`` row-major, each
+    an array over the positions -> ``M[i][j]``: ``iters`` times every
+    column and then every row divided by its sum + ``eps``, so rows sum
+    to 1 and columns nearly.  A sum is n - 1 additions of arrays: the
+    whole chain is element-wise over the positions."""
+    m = [[cells[i * n + j] for j in range(n)] for i in range(n)]
+    for _ in range(iters):
+        inv = [1.0 / (sum(m[i][j] for i in range(n)) + eps)
+               for j in range(n)]
+        m = [[m[i][j] * inv[j] for j in range(n)] for i in range(n)]
+        inv = [1.0 / (sum(m[i]) + eps) for i in range(n)]
+        m = [[m[i][j] * inv[i] for j in range(n)] for i in range(n)]
+    return m
+
+
+def _mhc_maps_rows(rows, n, iters, eps, clamp):
+    """The three mappings from their raw values, ``rows``: n (n + 2)
+    arrays over the positions, pre (n), post (n), res (n x n) -> as
+    many arrays: ``sigmoid``, ``2 sigmoid``, and the Sinkhorn
+    projection of ``exp(clip(.))``."""
+    import jax
+    import jax.numpy as jnp
+    pre = [jax.nn.sigmoid(r) for r in rows[:n]]
+    post = [2.0 * jax.nn.sigmoid(r) for r in rows[n:2 * n]]
+    res = sinkhorn([jnp.exp(jnp.clip(r, *clamp)) for r in rows[2 * n:]],
+                   n, iters, eps)
+    return pre + post + [c for row in res for c in row]
+
+
+def mhc_maps(raw, n, iters, eps, clamp):
+    """``raw [n (n + 2), T]`` (float32: ``Hpre~``, ``Hpost~``,
+    ``Hres~`` of T positions, a position a lane) -> the mappings
+    ``[n (n + 2), T]``.
+
+    ONE Pallas kernel, named ``mhc_maps`` in a device trace, holds the
+    ``2 iters`` normalisations (1.1 us a call on the v5e); off the TPU
+    the same kernel runs interpreted, as every Pallas kernel of this
+    repo does on the ``cpu`` platform.  Measured with a sub-layer's
+    norm, projection, read and mix around it, 14 chained (chip run, PR
+    37, ``_chip/xing_bench.py``): 14.8 us a sub-layer at 32 positions
+    and 74.8 at 256, against 17.1 / 77.5 written in ``jax.numpy`` over
+    separate arrays (the v5e compiler makes ~30 fusions a sub-layer of
+    them, under names it shares with other operations), 16.9 / 77.2 as
+    reductions over ``[..., n, n]`` and 16.7 / 76.0 as a device loop,
+    which puts a ``while`` beside the walk's; read and mix alone are
+    ~15.  The forms differ by a seventh of 1% of a step; the kernel is
+    kept because it gives the trace one name for the mappings."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    def kernel(raw_ref, out_ref):
+        raw = raw_ref[...]
+        out_ref[...] = jnp.concatenate(_mhc_maps_rows(
+            [raw[k:k + 1, :] for k in range(raw.shape[0])], n, iters,
+            eps, clamp), axis=0)
+    return pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct(raw.shape, jnp.float32),
+        interpret=jax.default_backend() != "tpu", name="mhc_maps")(raw)
+
+
+class HyperConnection(nn.Layer):
+    """The three mappings of ONE sub-layer's residual over ``n``
+    streams (the module docstring; manifold-constrained
+    hyper-connections).  The streams are ``X [n, B, S, d]``: a stream
+    is a whole ``[B, S, d]`` slab, so no small axis sits before the
+    minor one.  Leaves: the stream norm's gain ``[n d]``; ``phi``
+    ``[n (n + 2), n d]``, rows in the order pre (n), post (n), res
+    (n x n, row-major), stored with the long axis minor (whole tiles);
+    ``alpha`` ``[3]`` (pre, post, res); ``beta`` ``[n (n + 2)]``."""
+
+    def __init__(self, hidden, n, iters, eps, norm_eps, clamp):
+        super().__init__()
+        self.n, self.iters, self.eps = int(n), int(iters), float(eps)
+        self.clamp = (float(clamp[0]), float(clamp[1]))
+        self.norm = RMSNorm(n * hidden, norm_eps)
+        self.phi = self.create_parameter(
+            [n * (n + 2), n * hidden],
+            default_initializer=I.Normal(0.0, 0.02))
+        self.alpha = self.create_parameter(
+            [3], default_initializer=I.Constant(1.0))
+        self.beta = self.create_parameter([n * (n + 2)], is_bias=True)
+
+    @_scoped("mhc.maps")
+    def maps(self, X):
+        """X [n, B, S, d] -> (h_pre n x [B, S, 1], h_post n x
+        [B, S, 1], h_res n x n x [B, S, 1]), float32."""
+        import jax.numpy as jnp
+        n = self.n
+        xf = self.norm(jnp.concatenate(
+            [X[i] for i in range(n)], axis=-1).astype(jnp.float32))
+        raw = jnp.einsum("mk,bsk->mbs",
+                         self.phi._data.astype(jnp.float32), xf,
+                         precision="highest")
+        a = jnp.repeat(self.alpha._data.astype(jnp.float32),
+                       jnp.array([n, n, n * n]),
+                       total_repeat_length=n * (n + 2))
+        raw = (raw * a[:, None, None]
+               + self.beta._data.astype(jnp.float32)[:, None, None])
+        h = mhc_maps(raw.reshape(raw.shape[0], -1), n, self.iters,
+                     self.eps, self.clamp).reshape(raw.shape)[..., None]
+        return (list(h[:n]), list(h[n:2 * n]),
+                [list(h[2 * n + i * n:2 * n + (i + 1) * n])
+                 for i in range(n)])
+
+    @_scoped("mhc.mix")
+    def read(self, X, h_pre):
+        """The sub-layer's input ``u = H_pre X`` [B, S, d]."""
+        import jax.numpy as jnp
+        u = sum(h_pre[i] * X[i].astype(jnp.float32)
+                for i in range(self.n))
+        return u.astype(X.dtype)
+
+    @_scoped("mhc.mix")
+    def mix(self, X, y, h_post, h_res):
+        """``H_res X + H_post^T y`` -> the streams [n, B, S, d]."""
+        import jax.numpy as jnp
+        n = self.n
+        xs = [X[j].astype(jnp.float32) for j in range(n)]
+        yf = y.astype(jnp.float32)
+        return jnp.stack([
+            (sum(h_res[i][j] * xs[j] for j in range(n))
+             + h_post[i] * yf).astype(X.dtype)
+            for i in range(n)])
+
+
 class MLAMoEBlock(nn.Layer):
     """``x += Attn(RMSNorm(x)); x += FFN(RMSNorm(x))``; ``ffn`` is a
-    ``GatedMLP`` (dense layer) or a ``RoutedFFN``."""
+    ``GatedMLP`` (dense layer) or a ``RoutedFFN``.  With ``hc_mult`` n
+    > 1 the state is n streams ``[n, B, S, d]`` and each ``+=`` is a
+    ``HyperConnection`` of its own around the sub-layer."""
 
     def __init__(self, cfg, routed):
         super().__init__()
@@ -641,8 +862,16 @@ class MLAMoEBlock(nn.Layer):
         self.attn = MLAttention(
             d, cfg["num_attention_heads"], cfg["qk_nope_head_dim"],
             cfg["qk_rope_head_dim"], cfg["v_head_dim"],
-            cfg["kv_lora_rank"], cfg["rope_theta"], eps)
+            cfg["kv_lora_rank"], cfg["rope_theta"], eps,
+            cfg.get("q_lora_rank"), cfg.get("rope_scaling"))
         self.post_norm = RMSNorm(d, eps)
+        self.attn_hc = self.ffn_hc = None
+        if cfg.get("hc_mult", 1) > 1:
+            self.attn_hc, self.ffn_hc = (HyperConnection(
+                d, cfg["hc_mult"], cfg["hc_sinkhorn_iters"],
+                cfg["hc_eps"], eps,
+                (cfg["mhc_h_res_clamp_min"], cfg["mhc_h_res_clamp_max"]))
+                for _ in range(2))
         self.routed = routed
         self.ffn = (RoutedFFN(d, cfg["moe_intermediate_size"],
                               cfg["n_routed_experts"],
@@ -654,32 +883,52 @@ class MLAMoEBlock(nn.Layer):
 
     @_scoped("mlp")
     def feed_forward(self, x, live):
-        """x [B, S, D], live [B, S] -> (x + FFN(RMSNorm(x)), stats or
+        """x [B, S, D], live [B, S] -> (FFN(RMSNorm(x)), stats or
         None)."""
         h = self.post_norm(x)
         if not self.routed:
-            return x + self.ffn(h), None
+            return self.ffn(h), None
         y, stats = self.ffn(h.reshape(-1, h.shape[-1]),
                             live.reshape(-1))
-        return x + y.reshape(x.shape), stats
+        return y.reshape(x.shape), stats
+
+    @staticmethod
+    def _residual(hc, x, sublayer):
+        """One sub-layer on the residual: ``x + F(x)``, or through the
+        streams' mappings.  ``sublayer(u) -> (F(u), aux)``; returns
+        (the new state, aux)."""
+        if hc is None:
+            y, aux = sublayer(x)
+            return x + y, aux
+        h_pre, h_post, h_res = hc.maps(x)
+        y, aux = sublayer(hc.read(x, h_pre))
+        return hc.mix(x, y, h_post, h_res), aux
 
     def decode_slots_paged(self, x, pool, tables, pos, live):
-        a, pool = self.attn.decode_slots_paged(self.input_norm(x), pool,
-                                               tables, pos)
-        x, stats = self.feed_forward(x + a, live[:, None])
+        x, pool = self._residual(
+            self.attn_hc, x, lambda u: self.attn.decode_slots_paged(
+                self.input_norm(u), pool, tables, pos))
+        x, stats = self._residual(
+            self.ffn_hc, x, lambda u: self.feed_forward(u, live[:, None]))
         return x, pool, stats
 
     def prefill_chunk_paged(self, x, pool, table, pos, true_len,
                             scratch, live):
-        a, pool = self.attn.prefill_chunk_paged(
-            self.input_norm(x), pool, table, pos, true_len, scratch)
-        x, stats = self.feed_forward(x + a, live[None, :])
+        x, pool = self._residual(
+            self.attn_hc, x, lambda u: self.attn.prefill_chunk_paged(
+                self.input_norm(u), pool, table, pos, true_len, scratch))
+        x, stats = self._residual(
+            self.ffn_hc, x, lambda u: self.feed_forward(u, live[None, :]))
         return x, pool, stats
 
     def forward(self, x, absorbed=False):
         import jax.numpy as jnp
-        x = x + self.attn(self.input_norm(x), absorbed)
-        return self.feed_forward(x, jnp.ones(x.shape[:2], bool))[0]
+        x, _ = self._residual(
+            self.attn_hc, x,
+            lambda u: (self.attn(self.input_norm(u), absorbed), None))
+        live = jnp.ones(x.shape[-3:-1], bool)
+        return self._residual(
+            self.ffn_hc, x, lambda u: self.feed_forward(u, live))[0]
 
 
 class MLAMoEModel(ServedModel, nn.Layer):
@@ -690,23 +939,22 @@ class MLAMoEModel(ServedModel, nn.Layer):
     ``n_routed_experts``, ``num_experts_per_tok``, ``n_shared_experts``,
     ``routed_scaling_factor``, ``first_k_dense_replace``,
     ``num_hidden_layers``, ``vocab_size``, ``max_position_embeddings``,
-    ``rms_norm_eps``, ``rope_theta``); build it under
-    ``nn.LazyGuard()`` to declare the parameters without values."""
+    ``rms_norm_eps``, ``rope_theta``; and where the model has them
+    ``q_lora_rank``, ``rope_scaling`` of type ``yarn``, ``hc_mult`` with
+    ``hc_sinkhorn_iters``, ``hc_eps``, ``mhc_h_res_clamp_min`` /
+    ``_max``); build it under ``nn.LazyGuard()`` to declare the
+    parameters without values."""
 
     def __init__(self, config):
         super().__init__()
         cfg = dict(config)
-        if cfg.get("q_lora_rank") is not None:
-            raise ValueError("q_lora_rank: the query is not compressed "
-                             "in this decoder")
         if cfg.get("n_group", 1) != 1 or cfg.get("topk_group", 1) != 1:
             raise ValueError("grouped top-k routing is not written: "
                              "n_group and topk_group have to be 1")
         if cfg.get("scoring_func", "sigmoid") != "sigmoid":
             raise ValueError("the gate scores with a sigmoid")
-        if cfg.get("rope_scaling") is not None:
-            raise ValueError("rope_scaling is not written")
         self.config = cfg
+        self.streams = int(cfg.get("hc_mult", 1))
         d = cfg["hidden_size"]
         self.embed = self.create_parameter(
             [cfg["vocab_size"], d],
@@ -732,16 +980,29 @@ class MLAMoEModel(ServedModel, nn.Layer):
         s = sum(stats)
         return jnp.stack([s[0], s[1], jnp.int32(slots), s[2]])
 
+    def _widen(self, x):
+        """The residual's first state from the embedding [B, S, d]:
+        itself, or with n streams every stream the embedding
+        [n, B, S, d]."""
+        import jax.numpy as jnp
+        if self.streams > 1:
+            x = jnp.broadcast_to(x[None], (self.streams,) + x.shape)
+        return x
+
     @_scoped("lm_head")
     def _head(self, x):
+        """The final norm and the head over the residual's last state
+        (n streams: over their sum)."""
         import jax.numpy as jnp
+        if self.streams > 1:
+            x = jnp.sum(x.astype(jnp.float32), axis=0).astype(x.dtype)
         return _lin(self.lm_head, self.norm(x)).astype(jnp.float32)
 
     def forward(self, input_ids, absorbed=False):
         """Uncached logits [B, S, V] (float32)."""
         ids = input_ids._data if isinstance(input_ids, Tensor) \
             else input_ids
-        x = self.embed._data[ids]
+        x = self._widen(self.embed._data[ids])
         for blk in self.blocks:
             x = blk(x, absorbed)
         return Tensor(self._head(x))
@@ -760,7 +1021,7 @@ class MLAMoEModel(ServedModel, nn.Layer):
         weights."""
         import jax.numpy as jnp
         live = rem > 0
-        x = self.embed._data[tok[:, 0]][:, None, :]
+        x = self._widen(self.embed._data[tok[:, 0]][:, None, :])
         new_pools, stats = [], []
         for j, blk in enumerate(self.blocks):
             x, pool, st = blk.decode_slots_paged(x, pools[j], tables,
@@ -791,7 +1052,7 @@ class MLAMoEModel(ServedModel, nn.Layer):
         pos = jnp.asarray(pos, jnp.int32)
         C = toks.shape[1]
         live = jnp.arange(C) < true_len
-        x = self.embed._data[toks]
+        x = self._widen(self.embed._data[toks])
         new_pools, stats = [], []
         for j, blk in enumerate(self.blocks):
             x, pool, st = blk.prefill_chunk_paged(
@@ -799,8 +1060,8 @@ class MLAMoEModel(ServedModel, nn.Layer):
             new_pools.append(pool)
             if st is not None:
                 stats.append(st)
-        last_h = jax.lax.dynamic_slice(
-            x, (0, true_len - 1, 0), (1, 1, x.shape[-1]))
+        last_h = jax.lax.dynamic_slice_in_dim(x, true_len - 1, 1,
+                                              axis=x.ndim - 2)
         return (self._head(last_h)[:, -1, :], new_pools, [],
                 self._counter_vector(stats))
 
@@ -846,6 +1107,9 @@ class MLAMoEModel(ServedModel, nn.Layer):
             counters=MOE_COUNTERS,
             kernels={"moe.experts": grouped_matmul_impl()},
             decode_rows=walk_rows,
+            residual=({"streams": self.streams,
+                       "sinkhorn_iters": cfg["hc_sinkhorn_iters"]}
+                      if self.streams > 1 else None),
             unsupported={
                 "contiguous": "a contiguous [slots, L] latent buffer "
                               "and its decode / prefill programs",

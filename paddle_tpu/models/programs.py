@@ -336,11 +336,17 @@ class ServingSpec:
                        ``GPTAttention._slot_attn``'s rule
     ``step``           ``StepSpec`` of a model whose step is not one
                        row and one token a lane; None for one that is
+    ``residual``       what ``/healthz`` says of a residual that is not
+                       one ``[hidden_size]`` row a position (``{"streams":
+                       n, "sinkhorn_iters": k}``: the streams live
+                       inside the step programs, the engine never sees
+                       them); None for one that is
     """
 
     def __init__(self, kv, max_positions, vocab_size, hidden_size,
                  tensor_parallel=False, counters=(), unsupported=None,
-                 kernels=None, decode_rows=None, step=None):
+                 kernels=None, decode_rows=None, step=None,
+                 residual=None):
         self.kv = kv
         self.max_positions = int(max_positions)
         self.vocab_size = int(vocab_size)
@@ -351,6 +357,7 @@ class ServingSpec:
         self.kernels = dict(kernels or {})
         self.decode_rows = decode_rows or _rows_to_the_longest
         self.step = step
+        self.residual = dict(residual) if residual else None
 
 
 class ServedModel:

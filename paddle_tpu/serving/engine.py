@@ -2974,6 +2974,7 @@ class Engine:
                 "kv_geometry": self.kv_geometry(),
                 "kernels": self._serving_spec.kernels,
                 "step": self.step_report(),
+                "residual": self._serving_spec.residual,
                 "async_depth": self.async_depth,
                 "tracing": bool(self.tracer.enabled),
                 "preemption": self._preemption,

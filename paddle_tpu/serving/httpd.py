@@ -327,6 +327,10 @@ class _Handler(JsonHandler):
                 # (StepSpec.report), None where it is
                 "step": (eng.step_report()
                          if hasattr(eng, "step_report") else None),
+                # a residual of several streams (ServingSpec.residual),
+                # None for the plain one
+                "residual": getattr(getattr(eng, "_serving_spec", None),
+                                    "residual", None),
                 # async-loop signals, next to the router-tier load
                 # signals: pipeline depth plus the mean overlapped
                 # host time and mean blocking d2h wait per tick —
