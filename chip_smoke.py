@@ -483,14 +483,28 @@ def training_phase(sz, cache):
         loss = float(step.step([x, y]).numpy())   # .numpy() waits
         return loss, time.perf_counter() - t0
 
+    # which attention the step is traced with (one count a call site)
+    from paddle_tpu import monitor
+    from paddle_tpu.nn.functional.attention import attention_path
+    sites = {p: monitor.counter("nn.attention." + p)
+             for p in ("blockwise", "dense")}
+    before = {p: c.value for p, c in sites.items()}
     mark = cache.mark()
     losses, walls = zip(*[one() for _ in range(3)])
+    paths = {p: int(c.value - before[p]) for p, c in sites.items()}
+    want = attention_path(jax.default_backend(), sz["train_seq"],
+                          sz["train_seq"], model.blocks[0].attn.head_dim,
+                          False, devices=step.mesh.size,
+                          dtype=sz["dtype"] or "float32")
     report = {"phase": "training", "losses": [round(l, 4) for l in losses],
+              "attention_call_sites": paths,
               "step_wall_s": [round(w, 2) for w in walls],
               "cold_build_s": round(walls[0] - walls[2], 2),
               "first_build_cache": cache.since(mark)}
     check(all(np.isfinite(l) for l in losses),
           f"training: loss not finite: {losses}")
+    check(0 < paths[want] == sum(paths.values()),
+          f"training: the rule says {want}, the step was traced {paths}")
     check(losses[2] < losses[0],
           f"training: loss did not fall over three steps: {losses}")
     # the same program, built again from the persistent cache

@@ -112,8 +112,9 @@ class _PerRankStep:
         sig = tuple((tuple(a.shape), str(a.dtype)) for a in ins + labs)
         if sig not in self._compiled:
             self._compiled[sig] = jax.jit(self._build())
-        loss, *new_states = self._compiled[sig](
-            *self._state_tuple(), lr, key, ins, labs)
+        with mesh_mod.compiling_for(self.mesh):
+            loss, *new_states = self._compiled[sig](
+                *self._state_tuple(), lr, key, ins, labs)
         self._set_state_tuple(new_states)
         self.optimizer._step_count += 1
         return Tensor(loss)

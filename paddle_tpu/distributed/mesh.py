@@ -8,8 +8,10 @@ mesh axes over ICI/DCN.  A reference ``ring_id`` maps to a mesh axis name
 """
 from __future__ import annotations
 
+import contextlib
 import math
 import os
+import threading
 
 import numpy as np
 import jax
@@ -90,6 +92,35 @@ def ensure_mesh() -> Mesh:
     if _global_mesh is None:
         _global_mesh = build_mesh()
     return _global_mesh
+
+
+_compiling = threading.local()
+
+
+@contextlib.contextmanager
+def compiling_for(mesh: Mesh):
+    """Publish ``mesh`` as the one the program traced inside this block
+    is compiled for.  A builder that jits over a mesh of its own
+    (``TrainStep(mesh=...)``, the per-rank steps) wraps the call that
+    traces in it, so that code which must know how many devices the
+    program spans while it is traced (``program_devices``) reads the
+    builder's mesh and not the process's."""
+    prev = getattr(_compiling, "mesh", None)
+    _compiling.mesh = mesh
+    try:
+        yield mesh
+    finally:
+        _compiling.mesh = prev
+
+
+def program_devices() -> int:
+    """How many devices the program now being traced spans: the mesh
+    its builder published (``compiling_for``); where nobody published
+    one, the process's mesh, if it has one (a program jitted over
+    arrays that ``fleet.init`` or ``init_parallel_env`` placed); else
+    one."""
+    mesh = getattr(_compiling, "mesh", None) or _global_mesh
+    return 1 if mesh is None else int(mesh.size)
 
 
 def axis_size(name: str) -> int:

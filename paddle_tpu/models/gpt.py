@@ -6,7 +6,10 @@ with Megatron TP via ``paddle.distributed.split``
 (``distributed/collective.py:492,526``).
 
 TPU-native design: pre-LN causal transformer whose attention goes through
-``F.scaled_dot_product_attention`` (Pallas flash kernel on TPU); tensor
+``F.scaled_dot_product_attention`` (on one TPU chip a Pallas blockwise
+kernel from the measured crossover up, 512 positions at heads of 64:
+``nn/functional/attention.py`` ``attention_path``; the XLA form below it,
+on a mesh and off the TPU); tensor
 parallelism via Column/RowParallelLinear specs consumed by pjit; the
 ``GPTPipe`` variant exposes the identical-block structure the SPMD pipeline
 engine needs (parallel/pipeline.py).  BASELINE configs 4/5 (GPT-2 345M

@@ -3,8 +3,10 @@
 Reference parity: ``python/paddle/nn/layer/transformer.py:85,576,1037``
 (MultiHeadAttention / TransformerEncoder(Layer) / TransformerDecoder(Layer) /
 Transformer).  TPU-native: attention dispatches through
-``F.scaled_dot_product_attention`` which uses the Pallas flash kernel on TPU
-(the reference materializes S×S scores; see SURVEY.md §5.7).
+``F.scaled_dot_product_attention``, which takes a Pallas blockwise kernel
+on the TPU where no mask is given and the sequences are long enough
+(``nn/functional/attention.py`` ``attention_path``; the reference
+materializes S×S scores; see SURVEY.md §5.7).
 """
 from __future__ import annotations
 

@@ -561,16 +561,18 @@ class TrainStep:
             else:
                 self._compiled[shapes_key] = self._build_flat(meta)
         fn = self._compiled[shapes_key]
-        if self.is_pipeline:
-            (loss, self.params, self.block_buffers, self.opt_state,
-             self.last_metric_outs) = fn(
-                self.params, self.block_buffers, self.opt_state, lr, key,
-                in_arrays, lab_arrays)
-        else:
-            (loss, self.params, self.buffers, self.opt_state,
-             self.last_metric_outs) = fn(
-                self.params, self.buffers, self.opt_state, lr, key,
-                in_arrays, lab_arrays)
+        # the first call traces: what is traced reads THIS step's mesh
+        with mesh_mod.compiling_for(self.mesh):
+            if self.is_pipeline:
+                (loss, self.params, self.block_buffers, self.opt_state,
+                 self.last_metric_outs) = fn(
+                    self.params, self.block_buffers, self.opt_state, lr,
+                    key, in_arrays, lab_arrays)
+            else:
+                (loss, self.params, self.buffers, self.opt_state,
+                 self.last_metric_outs) = fn(
+                    self.params, self.buffers, self.opt_state, lr, key,
+                    in_arrays, lab_arrays)
         self.optimizer._step_count += 1
         # dispatch-side step accounting (monitor registry; the step is
         # async, so the histogram measures host dispatch latency — a
@@ -602,8 +604,9 @@ class TrainStep:
         lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
         state = self.block_buffers if self.is_pipeline else self.buffers
         t0 = _time.perf_counter()
-        lowered = fn.lower(self.params, state, self.opt_state, lr, key,
-                           in_arrays, lab_arrays)
+        with mesh_mod.compiling_for(self.mesh):
+            lowered = fn.lower(self.params, state, self.opt_state, lr, key,
+                               in_arrays, lab_arrays)
         t_lower = _time.perf_counter() - t0
         t0 = _time.perf_counter()
         compiled = lowered.compile()

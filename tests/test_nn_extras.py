@@ -503,19 +503,243 @@ def test_hash_many_and_pad_like_validation():
             paddle.to_tensor(np.ones((3, 2), "float32")))
 
 
-def test_flash_default_block_sizes_clamp():
-    """Tuned pallas block defaults clamp to the sequence extent
-    (v5e measurement: 2.9x over kernel defaults at S=4096)."""
+@pytest.mark.parametrize("seq_q,seq_kv,want_q,want_kv", [
+    (512, 4096, 512, 512), (8192, 8192, 512, 512), (1024, 1024, 512, 512),
+    (256, 1024, 256, 512)])
+def test_flash_default_block_sizes_clamp(seq_q, seq_kv, want_q, want_kv):
+    """The blockwise kernel's blocks clamp to the sequence extent, 512
+    at most (v5e, PR 38: 2.05 ms a layer at 1,024 positions against
+    2.19 at blocks of 1,024, which skip nothing under the causal mask),
+    and the fused backward takes the forward's."""
     from paddle_tpu.nn.functional import attention as att
-    bs = att._default_block_sizes(512, 4096)
-    assert bs.block_q == 512 and bs.block_k == 1024
-    bs2 = att._default_block_sizes(8192, 8192)
-    assert bs2.block_q == 1024 and bs2.block_k_major == 1024
+    bs = att._default_block_sizes(seq_q, seq_kv)
+    assert (bs.block_q, bs.block_kv) == (want_q, want_kv)
+    assert bs.block_kv_compute == want_kv
+    assert (bs.block_q_dkv, bs.block_kv_dkv) == (want_q, want_kv)
+    assert bs.use_fused_bwd_kernel and bs.has_backward_blocks
 
 
-def test_flash_block_sizes_divide_sequence():
-    """Blocks must divide the sequence (pallas _verify_block); 2560 is
-    gate-admitted (divisible by 128) but not by 1024."""
+@pytest.mark.parametrize("seq,want", [
+    (2560, 512), (2176, 128), (3584, 512), (7680, 512), (1024, 512),
+    (1280, 256)])
+def test_flash_block_sizes_divide_sequence(seq, want):
+    """Blocks must divide the sequence (the kernel's mask tables are
+    cut in whole blocks); 2176 is admitted by the rule (divisible by
+    128) but by no larger block."""
     from paddle_tpu.nn.functional import attention as att
-    for seq, want in ((2560, 512), (2176, 128), (3584, 512), (7680, 512)):
-        assert att._default_block_sizes(seq, seq).block_q == want, seq
+    assert att._default_block_sizes(seq, seq).block_q == want
+    assert seq % want == 0
+
+
+@pytest.mark.parametrize("platform,seq_q,seq_kv,head_dim,masked,want", [
+    # the training cell: gpt2-medium, 8 x 1,024 tokens, heads of 64
+    ("tpu", 1024, 1024, 64, False, "blockwise"),
+    ("tpu", 2048, 2048, 64, False, "blockwise"),
+    ("tpu", 512, 512, 64, False, "blockwise"),
+    ("tpu", 1024, 1024, 128, False, "blockwise"),
+    ("tpu", 4096, 4096, 128, False, "blockwise"),
+    # no Mosaic off the TPU
+    ("cpu", 1024, 1024, 64, False, "dense"),
+    ("gpu", 4096, 4096, 128, False, "dense"),
+    # an arbitrary mask is the dense form's
+    ("tpu", 1024, 1024, 64, True, "dense"),
+    # whole 128-row tiles of both sequences only
+    ("tpu", 1000, 1000, 64, False, "dense"),
+    ("tpu", 1024, 1000, 64, False, "dense"),
+    # under the measured crossover (v5e, PR 38): 256 positions at heads
+    # of 64, 512 at heads of 128 (0.74 ms dense against 0.77)
+    ("tpu", 256, 256, 64, False, "dense"),
+    ("tpu", 512, 512, 128, False, "dense"),
+    ("tpu", 128, 4096, 64, False, "dense"),
+    # heads of 256 win from 2,048 positions only (1.05 against 1.14 ms
+    # at 1,024; 2.33 against 1.54 at 2,048)
+    ("tpu", 1024, 1024, 256, False, "dense"),
+    ("tpu", 2048, 2048, 256, False, "blockwise"),
+    # head sizes nobody measured (PR 38's tables: 64, 128 and 256)
+    ("tpu", 1024, 1024, 80, False, "dense"),
+    ("tpu", 2048, 2048, 192, False, "dense"),
+])
+def test_attention_path_rule(platform, seq_q, seq_kv, head_dim, masked,
+                             want):
+    """The dispatch is one pure function of what a call shows."""
+    from paddle_tpu.nn.functional import attention as att
+    assert att.attention_path(platform, seq_q, seq_kv, head_dim,
+                              masked) == want
+
+
+def test_attention_path_keeps_the_dense_form_on_a_mesh():
+    """GSPMD cannot partition a Mosaic kernel: a program compiled for
+    several devices keeps the dense form whatever the shapes."""
+    from paddle_tpu.nn.functional import attention as att
+    assert att.attention_path("tpu", 1024, 1024, 64, False,
+                              devices=4) == "dense"
+    assert att.attention_path("tpu", 1024, 1024, 64, False,
+                              devices=1) == "blockwise"
+
+
+@pytest.mark.parametrize("own,process,want", [
+    (4, None, 4),    # examples/train_gpt2.py: build_mesh + mesh=, no global
+    (1, 8, 1),       # a one-chip step after a fleet call left its mesh
+    (None, None, 8),  # no mesh given: TrainStep makes the process's
+])
+def test_train_step_traces_attention_for_its_own_mesh(monkeypatch, own,
+                                                      process, want):
+    """What ``_sdpa`` hands the rule as ``devices`` is the size of the
+    mesh the STEP is compiled for (``TrainStep.mesh``, published while
+    it traces), whatever the process-global mesh holds: a step over an
+    explicit mesh of four keeps the dense form though no global mesh is
+    set, and a global mesh left behind does not turn a one-device step
+    dense.  ``aot_compile`` lowers the same program."""
+    import jax
+    from paddle_tpu import distributed as dist, optimizer
+    from paddle_tpu.distributed import mesh as mesh_mod
+    from paddle_tpu.models import GPTModel
+    from paddle_tpu.nn.functional import attention as att
+    from paddle_tpu.parallel.train_step import TrainStep
+
+    def mesh_of(n):
+        return n and dist.build_mesh(dp=n, devices=jax.devices()[:n])
+    monkeypatch.setattr(mesh_mod, "_global_mesh", mesh_of(process))
+    seen = []
+    rule = att.attention_path
+
+    def spy(platform, seq_q, seq_kv, head_dim, masked, devices, dtype):
+        seen.append(devices)
+        return rule(platform, seq_q, seq_kv, head_dim, masked, devices,
+                    dtype)
+    monkeypatch.setattr(att, "attention_path", spy)
+    model = GPTModel.from_config("tiny", dropout=0.0, fused_loss=True,
+                                 max_position=64)
+    opt = optimizer.AdamW(learning_rate=1e-3, parameters=model.parameters())
+    step = TrainStep(model, opt, loss_fn=None, mesh=mesh_of(own))
+    ids = np.random.RandomState(0).randint(0, 128, (8, 33)).astype(np.int32)
+    batch = [ids[:, :-1], ids[:, 1:]]
+    step.aot_compile(batch)
+    traced = len(seen)
+    assert traced and set(seen) == {want}
+    assert np.isfinite(float(step.step(batch).numpy()))
+    assert len(seen) == 2 * traced and set(seen) == {want}
+    # nothing stays published once the step has been traced
+    assert mesh_mod.program_devices() == (
+        process or (8 if own is None else 1))
+
+
+@pytest.mark.parametrize("dtype,head_dim,seq,want", [
+    ("bfloat16", 64, 512, "blockwise"),
+    # float32 moves twice the bytes through the dense form: the kernel
+    # wins from 512 positions at heads of 128 too (2.57 against 1.25 ms)
+    ("float32", 128, 512, "blockwise"), ("float32", 64, 256, "dense"),
+    ("float32", 256, 4096, "dense"),    # not measured
+    ("float16", 64, 1024, "dense"),     # Mosaic refuses it on the v5e
+])
+def test_attention_path_by_dtype(dtype, head_dim, seq, want):
+    """The crossover is read by dtype and head size from what PR 38
+    measured; a pair that is not in the table keeps the dense form."""
+    import jax.numpy as jnp
+    from paddle_tpu.nn.functional import attention as att
+    for spelt in (dtype, jnp.dtype(dtype), getattr(jnp, dtype)):
+        assert att.attention_path("tpu", seq, seq, head_dim, False,
+                                  dtype=spelt) == want
+
+
+def test_attention_has_no_module_switches():
+    """One rule, no switch: the threshold and the block-size override
+    that PR 38 removed stay removed."""
+    from paddle_tpu.nn.functional import attention as att
+    for name in ("FLASH_MIN_SEQ", "FLASH_BLOCK_SIZES", "_flash_available"):
+        assert not hasattr(att, name), name
+
+
+def test_sdpa_counts_the_path_it_traced():
+    """``nn.attention.dense`` / ``.blockwise`` count call sites as they
+    are traced: on the CPU every one is dense."""
+    from paddle_tpu import monitor
+    dense = monitor.counter("nn.attention.dense")
+    blockwise = monitor.counter("nn.attention.blockwise")
+    d0, b0 = dense.value, blockwise.value
+    x = paddle.to_tensor(np.ones((1, 128, 2, 64), "float32"))
+    F.scaled_dot_product_attention(x, x, x, is_causal=True)
+    assert dense.value == d0 + 1 and blockwise.value == b0
+
+
+def _attention_operands(shape, seed=0):
+    import jax
+    import jax.numpy as jnp
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return [jax.random.normal(k, shape, jnp.float32) for k in ks]
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("case", ["causal", "full", "packed", "offset",
+                                  "three_blocks"])
+def test_blockwise_attention_matches_the_dense_form(case):
+    """Values and gradients of the blockwise path against
+    ``_reference_attention`` in float32 at ``[2, 256, 4, 64]``, the
+    kernel interpreted on the CPU.  256 positions are one block;
+    ``three_blocks`` (384 positions, blocks of 128) carries the running
+    maximum and sum across blocks and skips the blocks above the
+    diagonal."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.nn.functional import attention as att
+    q, k, v, do = _attention_operands(
+        (1, 384, 2, 64) if case == "three_blocks" else (2, 256, 4, 64))
+    causal, seg, mask = case != "full", None, None
+    if case == "packed":
+        seg = jnp.asarray(np.repeat(np.array([[0, 1], [0, 0]]), 128,
+                                    axis=1), jnp.int32)
+        mask = (seg[:, :, None] == seg[:, None, :])[:, None]
+    if case == "offset":
+        # 128 queries against 256 keys: the mask is bottom-right aligned
+        q, do = q[:, :128], do[:, :128]
+
+    def dense(q, k, v):
+        return att._reference_attention(q, k, v, mask, None, causal)
+
+    def blockwise(q, k, v):
+        return att._blockwise_attention(q, k, v, seg, 0.125, causal,
+                                        interpret=True)
+    want, pull = jax.vjp(dense, q, k, v)
+    got, pull_b = jax.vjp(blockwise, q, k, v)
+    np.testing.assert_allclose(got, want, atol=5e-6)
+    for g, w in zip(pull_b(do), pull(do)):
+        np.testing.assert_allclose(g, w, atol=2e-5)
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_blockwise_attention_at_heads_of_128(dtype):
+    """Heads of 128 have a scale that is no power of two (2 ** -3.5),
+    which the queries carry into the kernel.  In float32 the blockwise
+    path is the dense form to rounding; in bf16 it is as close to the
+    float32 reference as the dense bf16 form is (values closer: its
+    scores stay float32, where the dense form rounds them to bf16), so
+    the scale costs no precision.  ``[2, 256, 4, 128]``, causal,
+    interpreted on the CPU."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.nn.functional import attention as att
+    ops = [x.astype(dtype) for x in _attention_operands((2, 256, 4, 128))]
+    exact = [x.astype(jnp.float32) for x in ops]
+
+    def dense(q, k, v):
+        return att._reference_attention(q, k, v, None, None, True)
+
+    def blockwise(q, k, v):
+        return att._blockwise_attention(q, k, v, None, 128 ** -0.5, True,
+                                        interpret=True)
+
+    def run(f, q, k, v, do):
+        out, pull = jax.vjp(f, q, k, v)
+        return [np.asarray(x, np.float32) for x in (out,) + pull(do)]
+
+    want, plain, got = run(dense, *exact), run(dense, *ops), run(
+        blockwise, *ops)
+    if dtype == "float32":
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(g, w, atol=5e-6)
+        return
+    off = [np.abs(g - w).max() for g, w in zip(got, want)]
+    off_dense = [np.abs(p - w).max() for p, w in zip(plain, want)]
+    assert off[0] < off_dense[0]
+    assert all(o < 1.5 * d for o, d in zip(off, off_dense)), (off, off_dense)

@@ -247,6 +247,92 @@ def _described_v5e():
         compilation_cache.reset_cache()
 
 
+def _ends_in_the_square(text, seq):
+    """Instructions of a compiled module's text whose result, or one
+    member of its tuple, is an array that ends in ``seq, seq`` (what
+    stands between ``=`` and the operands)."""
+    square = re.compile(r"\[(?:\d+,)*%d,%d\]" % (seq, seq))
+    return [ln for ln in text.splitlines() if " = " in ln
+            and square.search(ln.split(" = ", 1)[1].split("(%", 1)[0])]
+
+
+def _forward_backward(attend):
+    import jax
+
+    def fwd_bwd(q, k, v, do):
+        out, pull = jax.vjp(attend, q, k, v)
+        return (out,) + pull(do)
+    return fwd_bwd
+
+
+def test_training_attention_writes_no_sequence_square_on_the_v5e():
+    """Forward plus backward of attention at the training cell's shape
+    (``[8, 1024, 16, 64]`` bf16, causal, no mask), on the path the rule
+    gives for platform ``tpu`` and compiled for the compile-only ``TPU
+    v5 lite`` device: the blockwise kernels (forward, fused backward)
+    and no instruction whose array ends in ``1024, 1024``.  The dense
+    form of the same call, compiled the same way, holds over fifty: the
+    guard reads what it claims to."""
+    import jax
+    from paddle_tpu.nn.functional import attention as att
+
+    def blockwise(q, k, v):
+        return att._blockwise_attention(q, k, v, None, 0.125, True)
+
+    def dense(q, k, v):
+        return att._reference_attention(q, k, v, None, None, True)
+
+    assert att.attention_path("tpu", 1024, 1024, 64, False) == "blockwise"
+    with _described_v5e() as sds:
+        x = sds((8, 1024, 16, 64))
+        text, dense_text = (
+            jax.jit(_forward_backward(f)).lower(x, x, x, x).compile()
+            .as_text() for f in (blockwise, dense))
+    assert not _ends_in_the_square(text, 1024)
+    assert len(_ends_in_the_square(dense_text, 1024)) > 50
+    kernels = re.findall(r"%(splash_\w+?)(?:\.\d+)* = ", text)
+    assert sorted(set(kernels)) == ["splash_mha_dkv_no_residuals",
+                                    "splash_mha_fwd_residuals"]
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+
+
+def test_attention_over_a_mesh_of_four_v5e_lowers_without_the_kernel(
+        monkeypatch):
+    """A program sharded over the four described ``TPU v5 lite`` chips
+    (the batch over ``dp``, as ``TrainStep`` shards it) whose builder
+    published its mesh: ``_sdpa`` on platform ``tpu`` keeps the dense
+    form, and the module lowers with no Mosaic call.  The same program
+    with nothing published and no process mesh takes the kernel and
+    cannot be lowered: GSPMD does not partition a Mosaic kernel, which
+    is why the rule counts devices."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec
+    from paddle_tpu.distributed import mesh as mesh_mod
+    from paddle_tpu.nn.functional import attention as att
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(mesh_mod, "_global_mesh", None)
+    mesh = mesh_mod.build_mesh(dp=4, devices=topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices)
+    x = jax.ShapeDtypeStruct((8, 1024, 16, 64), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, PartitionSpec(
+                                 mesh_mod.DATA_AXES)))
+
+    def lower():
+        # a fresh function each time: nothing of an earlier trace reused
+        return jax.jit(_forward_backward(
+            lambda q, k, v: att._sdpa.raw_fn(q, k, v, is_causal=True))
+        ).lower(x, x, x, x).as_text()
+
+    with mesh_mod.compiling_for(mesh):
+        text = lower()
+    assert "tpu_custom_call" not in text
+    assert "mhlo.num_partitions = 4" in text
+    with pytest.raises(NotImplementedError, match="Mosaic"):
+        lower()
+
+
 def _latent_step_text(sds, program, *args):
     """The compiled text of ``program(attn, *args)``, a method of the
     latent attention at the published widths (16 heads of 128 + 64 /
