@@ -15,6 +15,14 @@ Design points:
 - **Low overhead.**  A span is two ``time.perf_counter()`` calls and
   one deque append under a lock; a disabled tracer (or the
   ``NullTracer``) short-circuits to a shared no-op context manager.
+  A span opened with ``cpu=True`` also reads ``time.thread_time()``
+  (the calling thread's CPU clock, a system call) at both ends and
+  records ``cpu_ms``: its wall time less that is what the thread
+  waited, for the interpreter lock, a lock of the program's, the
+  scheduler or the device.  Spans that do not ask pay nothing for it.
+  The clock is as fine as the kernel's accounting of CPU time: where
+  that goes by the timer (steps of 10 ms), one span's ``cpu_ms`` is 0
+  or a whole step, and only sums over many spans mean anything.
   No jax import at module level — like the rest of ``monitor``, this
   is pure stdlib and safe in fork'd workers and HTTP handler threads.
 - **Thread-aware.**  Each thread appends into its own
@@ -102,12 +110,17 @@ class RecordEvent:
 
     Exactly two clock reads per span (enter + exit) — the elapsed
     seconds land on ``.elapsed`` and the complete-event is appended to
-    the tracer's ring buffer.  ``annotate=True`` additionally wraps
+    the tracer's ring buffer.  ``cpu=True`` reads the calling thread's
+    CPU clock (``time.thread_time``) inside those two: the CPU seconds
+    land on ``.cpu_elapsed`` and as ``cpu_ms`` (3 decimals) in the
+    event's args, so ``dur`` less ``cpu_ms`` is the time the thread
+    stood still.  Enter and exit must then run on one thread.
+    ``annotate=True`` additionally wraps
     the span in ``jax.profiler.TraceAnnotation`` so it shows up in
     XPlane captures (requires jax; lazily imported)."""
 
     def __init__(self, name, tracer=None, cat="serving", annotate=None,
-                 **args):
+                 cpu=False, **args):
         self.name = name
         self._tracer = tracer if tracer is not None else default_tracer()
         self.cat = cat
@@ -115,7 +128,9 @@ class RecordEvent:
         tr_ann = getattr(self._tracer, "annotate", False)
         self._annotate = tr_ann if annotate is None else annotate
         self._ann = None
+        self._cpu = cpu
         self.elapsed = 0.0
+        self.cpu_elapsed = 0.0
 
     def __enter__(self):
         if self._annotate:
@@ -123,11 +138,18 @@ class RecordEvent:
             self._ann = jax.profiler.TraceAnnotation(self.name)
             self._ann.__enter__()
         self._t0 = time.perf_counter()
+        if self._cpu:
+            self._c0 = time.thread_time()
         return self
 
     def __exit__(self, *exc):
+        if self._cpu:
+            c1 = time.thread_time()
         t1 = time.perf_counter()
         self.elapsed = t1 - self._t0
+        if self._cpu:
+            self.cpu_elapsed = c1 - self._c0
+            self.args["cpu_ms"] = round(self.cpu_elapsed * 1e3, 3)
         self._tracer._append(
             self.name, "X", self._t0 * 1e6, self.elapsed * 1e6,
             self.cat, self.args or None)
@@ -145,7 +167,7 @@ class RecordEvent:
             # across calls, and a shared mutable dict would leak one
             # call's annotations into the next event
             with RecordEvent(self.name, self._tracer, cat=self.cat,
-                             annotate=self._annotate,
+                             annotate=self._annotate, cpu=self._cpu,
                              **dict(self.args)):
                 return fn(*a, **kw)
         return wrapped
@@ -160,6 +182,7 @@ class _NullSpan:
     __slots__ = ()
     args = {}
     elapsed = 0.0
+    cpu_elapsed = 0.0
 
     def __enter__(self):
         return self
@@ -182,7 +205,8 @@ class NullTracer:
     enabled = False
     annotate = False
 
-    def span(self, name, cat="serving", annotate=None, **args):
+    def span(self, name, cat="serving", annotate=None, cpu=False,
+             **args):
         return _NULL_SPAN
 
     def instant(self, name, cat="serving", **args):
@@ -309,14 +333,17 @@ class Tracer:
             buf.append(TraceEvent(name, ph, ts_us, dur_us, tid, cat,
                                   dict(args) if args else None))
 
-    def span(self, name, cat="serving", annotate=None, **args):
+    def span(self, name, cat="serving", annotate=None, cpu=False,
+             **args):
         """Open a complete-event span (context manager / decorator).
         Keyword args become the event's chrome-trace ``args``; amend
-        ``sp.args`` inside the block for values only known at exit."""
+        ``sp.args`` inside the block for values only known at exit.
+        ``cpu=True`` adds the calling thread's CPU time as ``cpu_ms``
+        (``RecordEvent``)."""
         if not self.enabled:
             return _NULL_SPAN
         return RecordEvent(name, self, cat=cat, annotate=annotate,
-                           **args)
+                           cpu=cpu, **args)
 
     def instant(self, name, cat="serving", **args):
         """Record a point-in-time instant event (``ph="i"``) — the
@@ -391,6 +418,21 @@ def to_chrome_trace(events, thread_names=None, process_name=None,
         out.append(ev.to_json(pid=pid) if isinstance(ev, TraceEvent)
                    else dict(ev))
     return {"traceEvents": out, "displayTimeUnit": "ms"}
+
+
+def thread_clock_read_us(reads=16):
+    """What one ``time.thread_time()`` call costs here, in
+    microseconds: the least of a few timed reads (one preempted read
+    must not decide).  A system call on stock Linux (~0.3 us), a trap
+    into the sandbox's kernel where the process runs in one (5.5 us
+    on the benchmark's machine): callers that would read the clock
+    many times a tick ask first."""
+    best = float("inf")
+    for _ in range(reads):
+        t0 = time.perf_counter()
+        time.thread_time()
+        best = min(best, time.perf_counter() - t0)
+    return best * 1e6
 
 
 _default_tracer = Tracer()

@@ -46,7 +46,20 @@ bounded per-thread rings, phase spans + request lifecycle instants +
 compile events) with chrome-trace export (``Engine.chrome_trace()``,
 ``GET /debug/trace``), a live request view (``GET /debug/requests``),
 and an automatic flight-recorder dump on step failure
-(``Engine(flight_dir=...)``).  OVERLOAD PROTECTION:
+(``Engine(flight_dir=...)``).  THE HOST BY THREAD: the ``tick`` span
+splits its ``host_ms`` (the tick less its waits for the device) into
+``cpu_ms`` (Python its thread ran) and ``wait_ms`` (it stood still:
+the interpreter lock, a sink lock, the scheduler), its phases
+(``admit``, ``chunk.plan``, ``prefill.chunk``, ``prefill.d2h``,
+``state.push``, ``ring.drain``, ``dispatch``, ``decode.dispatch``,
+``consume``, ``decode.emit``) carry ``cpu_ms`` where a read of that
+clock is cheap or ``trace_annotations`` is on, the HTTP edge's
+``http.ingest`` and ``http.stream`` (one a streamed response) theirs,
+and counters keep the sums with tracing on or off:
+``serving.tick_host_ms`` / ``tick_cpu_ms`` / ``tick_wait_ms``,
+``serving.http_cpu_ms`` / ``http_frames`` / ``http_bytes_out``,
+``serving.dev_watch_cpu_ms`` and the gauge ``process.cpu_ms``
+(``tools/trace_view.py --wall`` prints both).  OVERLOAD PROTECTION:
 ``submit(priority=..., tenant=...)`` gives requests priority classes
 (higher preempts lower MID-STREAM under slot/KV pressure — the
 victim's blocks return to the prefix cache and its stream resumes

@@ -105,19 +105,57 @@ class _MigrateDemand:
         return self.result
 
 
-def _spanned(name):
+def _spanned(name, phase=False):
     """Run the decorated engine method under a tracer span: the
-    phases of a tick that have no narrower span of their own."""
+    phases of a tick that have no narrower span of their own
+    (``phase``: one of those that read the CPU clock where the engine
+    lets them, ``Engine._phase_cpu``)."""
     def deco(fn):
         @functools.wraps(fn)
         def wrapped(self, *args, **kwargs):
-            with self.tracer.span(name):
+            with self.tracer.span(name,
+                                  cpu=phase and self._phase_cpu):
                 return fn(self, *args, **kwargs)
         return wrapped
     return deco
 
 
-def _watch_device(q, tracer, busy_ms):
+# a read of the thread's CPU clock dearer than this (us) is kept off
+# the phases' spans unless the tracer annotates (Engine._phase_cpu)
+_PHASE_CPU_MAX_READ_US = 1.0
+
+
+class _DeviceWait:
+    """One wait of the tick's thread for the device, under the span
+    that names it: its wall time goes to ``Engine._blocked_s`` (what
+    the tick's ``host_ms`` leaves out) and the thread CPU it burned
+    (a runtime may spin while it waits) to ``Engine._blocked_cpu_s``
+    (what the tick's ``cpu_ms`` leaves out).  The clocks are the
+    engine's own reads, inside the span, so the tick's account is the
+    same with ``tracing=False``."""
+
+    __slots__ = ("_eng", "_span", "_w0", "_c0")
+
+    def __init__(self, eng, span):
+        self._eng = eng
+        self._span = span
+
+    def __enter__(self):
+        sp = self._span.__enter__()
+        self._w0 = time.perf_counter()
+        self._c0 = time.thread_time()
+        return sp
+
+    def __exit__(self, *exc):
+        c1 = time.thread_time()
+        w1 = time.perf_counter()
+        eng = self._eng
+        eng._blocked_s += w1 - self._w0
+        eng._blocked_cpu_s += c1 - self._c0
+        return self._span.__exit__(*exc)
+
+
+def _watch_device(q, tracer, busy_ms, cpu_ms):
     """Body of an engine's device watcher thread: turn each dispatch
     the engine thread hands over into a ``dev.*`` span on the tracer's
     shared ``device`` lane.  One device runs the engine's programs in
@@ -129,11 +167,21 @@ def _watch_device(q, tracer, busy_ms):
     device idle.  ``busy_ms`` (``serving.dev_busy_ms``) sums the same
     durations.  The completion is read after this thread wakes up, so
     it is late by the wake-up (and the program after it starts as
-    late, which keeps the sum right).  The thread holds the tracer and
-    the counter but never the engine; ``None`` ends it."""
+    late, which keeps the sum right).  The wake-up needs the
+    interpreter lock, which the tick's and the handlers' threads hold
+    in turn: its lateness grows with what the tick's thread itself
+    waits (``serving.tick_wait_ms``), and that, not the device, is why
+    ``dev.decode`` reads longer in a contended run of one program.
+    ``cpu_ms`` (``serving.dev_watch_cpu_ms``) takes this thread's own
+    CPU time, once a dispatch.  The thread holds the tracer and the
+    counters but never the engine; ``None`` ends it."""
     prev_done = 0.0
+    cpu_seen = time.thread_time()
     while True:
         item = q.get()
+        cpu_now = time.thread_time()
+        cpu_ms.inc((cpu_now - cpu_seen) * 1e3)
+        cpu_seen = cpu_now
         if item is None:
             return
         name, t_dispatch, handle, args = item
@@ -421,9 +469,37 @@ class Engine:
         durations (its rate is the device's utilisation).  Every phase
         of a tick runs under a span of its own, and the ``tick`` span
         carries ``host_ms``: its duration less the time blocked on the
-        device.  ``http.ingest`` / ``http.first_frame`` (httpd.py)
-        and the ``req.*`` instants share one ``requests`` lane that
-        outlives the handler threads.
+        device, split into ``cpu_ms`` (thread CPU time: the Python the
+        tick's thread ran) and ``wait_ms`` (the rest: the thread
+        runnable or blocked, but not by the device, so waiting for
+        the interpreter lock that the HTTP handlers and the watcher
+        share with it, a sink lock, or the scheduler);
+        ``blocked_cpu_ms`` where a wait for the device burned CPU.
+        (The thread CPU clock is as fine as the kernel's accounting:
+        nanoseconds on stock Linux, 10 ms steps on the benchmark's
+        machine, where one tick's split says little and only sums
+        over many ticks, the counters below, are to be read.)
+        The phases the idle tables name carry their own ``cpu_ms``
+        (``admit``, ``chunk.plan``, ``prefill.chunk``,
+        ``prefill.d2h``, ``state.push``, ``ring.drain``, ``dispatch``,
+        ``decode.dispatch``, ``consume``, ``decode.emit``) where a read
+        of the thread's CPU clock is cheap (under 1 us:
+        ``monitor.tracing.thread_clock_read_us``, asked once at
+        construction) or ``trace_annotations`` is on: in a sandbox
+        whose kernel is a trap away a read costs 5.5 us alone and far
+        more beside the runtime's busy threads (~0.7 ms a tick on the
+        benchmark's machine), and the default ring then keeps the
+        tick's own split and the counters only; the pure
+        waits and the per-token ``stream.emit`` never do.  The sums are
+        kept with tracing on or off: ``serving.tick_host_ms``,
+        ``serving.tick_cpu_ms``, ``serving.tick_wait_ms``, beside the
+        edge's ``serving.http_cpu_ms``, the watcher's
+        ``serving.dev_watch_cpu_ms`` and the gauge ``process.cpu_ms``
+        (every thread of the process), so four numbers a wall-second
+        say whether the interpreter is saturated or the machine is.
+        ``http.ingest`` / ``http.first_frame`` / ``http.stream``
+        (httpd.py) and the ``req.*`` instants share one ``requests``
+        lane that outlives the handler threads.
     trace_capacity : per-thread ring-buffer bound, in events.
     trace_annotations : also enter a ``jax.profiler.TraceAnnotation``
         per span so engine phases land in XPlane/TensorBoard captures
@@ -980,6 +1056,20 @@ class Engine:
         self._dev_thread = None
         self._blocked_s = 0.0   # time this tick spent waiting on the
         #   device (d2h syncs, collectives): tick less this = host_ms
+        self._blocked_cpu_s = 0.0  # thread CPU burned inside those
+        #   waits (_DeviceWait): left out of the tick's cpu_ms
+        self._cpu_carry_ms = 0.0   # CPU time a coarse clock charged to
+        #   a tick beyond its host_ms: the next ticks' (_step_inner)
+        # the phases' own cpu_ms: where a read of the thread's CPU
+        # clock is cheap (0.3 us on stock Linux), or in the detailed
+        # mode (annotations on).  Where it is a trap into a sandbox's
+        # kernel (5.5 us alone and far more beside the runtime's busy
+        # threads: ~0.7 ms a tick on the benchmark's machine) the
+        # default ring keeps the tick's own split and the counters
+        self._phase_cpu = bool(tracing) and (
+            bool(trace_annotations)
+            or monitor.tracing.thread_clock_read_us()
+            <= _PHASE_CPU_MAX_READ_US)
         self._flight_dir = flight_dir
         self.last_flight = None        # chrome-trace dict of the most
         self.last_flight_path = None   # recent step failure (+ file)
@@ -1177,6 +1267,42 @@ class Engine:
             "device watcher saw them complete — its rate is the "
             "device's utilisation, with no profiler attached (0 with "
             "tracing=False)")
+        # the host by thread: what the tick's thread, the HTTP
+        # handlers' and the device watcher's each spent on a CPU, and
+        # the whole process (tracing on or off).  Over a window the
+        # process less these three is the runtime's own threads
+        # (transfers, PJRT), which hold no interpreter lock
+        self._m_tick_host = reg.counter(
+            "serving.tick_host_ms", "summed host_ms of the ticks: a "
+            "tick's wall time less its waits for the device "
+            "(decode.d2h_wait / decode.d2h, prefill.d2h, "
+            "decode.allgather) (ms)")
+        self._m_tick_cpu = reg.counter(
+            "serving.tick_cpu_ms", "thread CPU time of the tick's "
+            "thread inside tick_host_ms: the Python the tick ran "
+            "(ms; what a coarse CPU clock charges a tick beyond its "
+            "host_ms goes to the next ticks, so read it over many)")
+        self._m_tick_wait = reg.counter(
+            "serving.tick_wait_ms", "tick_host_ms less tick_cpu_ms: "
+            "the tick's thread neither waiting for the device nor "
+            "running, so waiting for the interpreter lock, a sink "
+            "lock or the scheduler (ms)")
+        self._m_http_cpu = reg.counter(
+            "serving.http_cpu_ms", "thread CPU time of the HTTP "
+            "handler threads in http.ingest and http.stream, summed "
+            "over threads; a live stream adds its share at least "
+            "every 32 frames (ms)")
+        self._m_http_frames = reg.counter(
+            "serving.http_frames", "SSE frames written by streamed "
+            "responses (token, heartbeat and terminal frames)")
+        self._m_http_bytes = reg.counter(
+            "serving.http_bytes_out", "bytes of those frames")
+        self._m_dev_watch_cpu = reg.counter(
+            "serving.dev_watch_cpu_ms", "thread CPU time of the "
+            "device watcher thread (ms; 0 with tracing=False)")
+        self._m_proc_cpu = reg.gauge(
+            "process.cpu_ms", "time.process_time() at the last tick: "
+            "CPU time of every thread of the process (ms)")
         # overload-protection surface: preemption / shedding /
         # fairness / chaos (registered always; zero when idle)
         self._m_preempt = reg.counter(
@@ -2812,7 +2938,8 @@ class Engine:
         q = self._dev_q
         t = threading.Thread(
             target=_watch_device, daemon=True,
-            args=(q, self.tracer, self._m_dev_busy),
+            args=(q, self.tracer, self._m_dev_busy,
+                  self._m_dev_watch_cpu),
             name="paddle_tpu-serving-device")
         t.start()
         self._dev_thread = t
@@ -3342,7 +3469,7 @@ class Engine:
             # their OWN dp shard's scratch row
             mirrors["scratch"] = self._slot_scratch
         with self.tracer.span(
-                "state.push",
+                "state.push", cpu=self._phase_cpu,
                 bytes=sum(int(a.nbytes) for a in mirrors.values())), \
                 sync:
             self._dev_state = {k: put(a) for k, a in mirrors.items()}
@@ -3502,7 +3629,7 @@ class Engine:
         ids = np.zeros((1, C), np.int32)  # right-padded final chunk
         ids[0, :n] = tokens[p0:p0 + n]
         with self.tracer.span(
-                "prefill.chunk", req=req.id, pos=p0, n=n,
+                "prefill.chunk", cpu=self._phase_cpu, req=req.id, pos=p0, n=n,
                 layout="paged" if self._paged else "contiguous"):
             if self._paged:
                 fn, _, _ = self.model.serving_program(
@@ -3602,9 +3729,9 @@ class Engine:
         request's first token.  The download waits for the prefill
         program (and whatever was queued ahead of it on the device):
         ``prefill.d2h``, counted as time blocked on the device."""
-        with self.tracer.span("prefill.d2h", req=req.id) as sp:
+        with _DeviceWait(self, self.tracer.span(
+                "prefill.d2h", cpu=self._phase_cpu, req=req.id)):
             row = np.asarray(last0, np.float32)[0]
-        self._blocked_s += sp.elapsed
         return self._pick(req, row)
 
     def _pick(self, req, row):
@@ -3758,7 +3885,7 @@ class Engine:
             #   gauge with lanes never scored.)
         return toks
 
-    @_spanned("dispatch")
+    @_spanned("dispatch", phase=True)
     def _dispatch_spec(self, active, tr):
         """DISPATCH one fused speculative draft-and-verify tick
         without consuming it: the verify dispatch picks every window
@@ -3801,7 +3928,7 @@ class Engine:
                  st["shi"], st["ctr"], st["eos"], st["rem"],
                  *self._lora_args_state(st)]
         self._fault("dispatch")
-        with tr.span("decode.dispatch", batch=len(active),
+        with tr.span("decode.dispatch", cpu=self._phase_cpu, batch=len(active),
                      layout=layout, spec_w=W, fused=True,
                      rows=self._rows_walked(W)), \
                 self._dequant_span(tr, len(active)):
@@ -3882,7 +4009,7 @@ class Engine:
         total_acc = 0
         # `with`, not manual enter/exit: an _emit failure mid-loop must
         # still record the span for the flight-recorder dump
-        with tr.span("decode.emit", batch=inf.batch,
+        with tr.span("decode.emit", cpu=self._phase_cpu, batch=inf.batch,
                      layout=inf.layout) as emit_sp:
             for slot, req, lanes_i in zip(inf.slots, inf.reqs,
                                           inf.spec_lanes):
@@ -3915,7 +4042,7 @@ class Engine:
         inf = self._dispatch_spec(active, self.tracer)
         return self._consume(inf, self.tracer)
 
-    @_spanned("dispatch")
+    @_spanned("dispatch", phase=True)
     def _dispatch_decode(self, active, tr):
         """DISPATCH one fused decode+sample tick without
         consuming it: the step state lives on
@@ -3954,7 +4081,7 @@ class Engine:
             span_args["width"] = step.rows
         layout = "paged" if self._paged else "contiguous"
         self._fault("dispatch")
-        with tr.span("decode.dispatch", batch=len(active),
+        with tr.span("decode.dispatch", cpu=self._phase_cpu, batch=len(active),
                      layout=layout, fused=True,
                      rows=self._rows_walked(step.rows if step else 1),
                      **span_args), \
@@ -3988,7 +4115,7 @@ class Engine:
         failure."""
         ids = mats["ids"]
         emitted = 0
-        with tr.span("decode.emit", batch=inf.batch,
+        with tr.span("decode.emit", cpu=self._phase_cpu, batch=inf.batch,
                      layout=inf.layout) as emit_sp:
             for slot, req in zip(inf.slots, inf.reqs):
                 i = slot.index
@@ -4052,7 +4179,7 @@ class Engine:
                 break
         return plan
 
-    @_spanned("dispatch")
+    @_spanned("dispatch", phase=True)
     def _dispatch_ragged(self, active, plan, tr):
         """DISPATCH one unified RAGGED window tick without consuming
         it: decoding slots ride as mode-0 lanes (width 1, or the k+1
@@ -4136,7 +4263,7 @@ class Engine:
                     emit_w=spec_w,
                     sharded=self.mp * self.dp > 1)
         self._fault("dispatch")
-        with tr.span("decode.ragged_stream",
+        with tr.span("decode.ragged_stream", cpu=self._phase_cpu,
                      batch=len(active) + len(plan),
                      layout="paged", w=W, chunks=len(plan),
                      chunk_tokens=chunk_toks, fused=True,
@@ -4187,7 +4314,7 @@ class Engine:
         total_acc = 0
         emitted_spec = 0
         n_spec = 0
-        with tr.span("decode.emit", batch=inf.batch,
+        with tr.span("decode.emit", cpu=self._phase_cpu, batch=inf.batch,
                      layout=inf.layout) as emit_sp:
             for slot, req, (mode_i, width_i, lanes_i) in zip(
                     inf.slots, inf.reqs, inf.meta_lanes):
@@ -4247,7 +4374,7 @@ class Engine:
             self._m_spec_tpt.set(emitted_spec / n_spec)
         return emitted
 
-    @_spanned("consume")
+    @_spanned("consume", phase=True)
     def _consume(self, inf, tr):
         """Materialize and emit one in-flight tick.  The blocking
         ``np.asarray`` on the ids + done mask is the async loop's ONLY
@@ -4273,18 +4400,17 @@ class Engine:
             # own span so collective time is attributed to
             # decode.allgather, and the d2h span below measures the
             # (tiny, unchanged-contract) host copy alone.
-            with tr.span("decode.allgather", tick=inf.tick,
-                         shards=self.mp * self.dp, mp=self.mp,
-                         dp=self.dp) as ag_sp:
+            with _DeviceWait(self, tr.span(
+                    "decode.allgather", tick=inf.tick,
+                    shards=self.mp * self.dp, mp=self.mp, dp=self.dp)):
                 for v in inf.arrays.values():
                     v.block_until_ready()
-            self._blocked_s += ag_sp.elapsed
         t0 = time.monotonic()
-        with tr.span(wait_name, tick=inf.tick) as d2h_sp:
+        with _DeviceWait(self, tr.span(wait_name,
+                                       tick=inf.tick)) as d2h_sp:
             mats = {k: np.asarray(v) for k, v in inf.arrays.items()}
             nbytes = sum(int(a.nbytes) for a in mats.values())
             d2h_sp.args["bytes"] = nbytes
-        self._blocked_s += d2h_sp.elapsed
         self._m_d2h_wait.observe((time.monotonic() - t0) * 1e3)
         self._m_d2h.set(nbytes)
         done = np.unpackbits(mats["done"],
@@ -4303,6 +4429,13 @@ class Engine:
                 emitted = self._consume_decode(inf, mats, done, tr)
         if in_flight:
             self._overlap_acc += time.monotonic() - t1
+        # drop the consumed tick's device arrays HERE, under the span,
+        # not a statement later with the last reference to ``inf``:
+        # freeing them calls into the runtime, which gives the
+        # interpreter up, and the tick's thread then queues for it
+        # behind every handler thread that has a frame to write
+        # (1.8 ms of a 15 ms tick under 13 streams: PERF.md section 5)
+        inf.arrays = None
         return emitted
 
     def _count_stats(self, vector=None):
@@ -4331,7 +4464,8 @@ class Engine:
         pipeline) under a ``ring.drain`` span that says ``why``.
         Returns tokens emitted."""
         emitted = 0
-        with tr.span("ring.drain", why=why, ticks=len(self._ring)):
+        with tr.span("ring.drain", cpu=self._phase_cpu, why=why,
+                     ticks=len(self._ring)):
             while self._ring:
                 emitted += self._consume(self._ring.pop(0), tr)
         return emitted
@@ -4391,19 +4525,43 @@ class Engine:
         self._tick_started_at = time.monotonic()
         try:
             self._fault("host_slow")
-            self._blocked_s = 0.0
+            self._blocked_s = self._blocked_cpu_s = 0.0
             with tr.span("tick", cat="tick",
                          tick=self.tick_no) as tick_sp:
                 t0 = time.perf_counter()
+                c0 = time.thread_time()
                 if self.async_depth > 1:
                     emitted = self._tick_async(tr, tick_sp)
                 else:
                     emitted = self._tick(tr, tick_sp)
+                c1 = time.thread_time()
                 # the tick's host share: its duration less the time
-                # it was blocked on the device (d2h syncs, collectives)
-                tick_sp.args["host_ms"] = round(
-                    (time.perf_counter() - t0 - self._blocked_s) * 1e3,
-                    3)
+                # it was blocked on the device (d2h syncs, collectives);
+                # of that, what this thread spent on a CPU, and the
+                # rest: runnable or blocked, but not by the device.
+                # Where the kernel accounts CPU time by its timer
+                # (10 ms steps on the benchmark's machine), a whole
+                # period lands on the tick the timer found running:
+                # what a tick's host_ms cannot hold is carried to the
+                # next ticks, so cpu_ms <= host_ms in every tick and
+                # the sums over a window stay true
+                host_ms = round(max(
+                    time.perf_counter() - t0 - self._blocked_s, 0.0)
+                    * 1e3, 3)
+                cpu_seen = self._cpu_carry_ms + max(
+                    c1 - c0 - self._blocked_cpu_s, 0.0) * 1e3
+                cpu_ms = round(min(cpu_seen, host_ms), 3)
+                self._cpu_carry_ms = max(cpu_seen - cpu_ms, 0.0)
+                wait_ms = round(host_ms - cpu_ms, 3)
+                tick_sp.args.update(host_ms=host_ms, cpu_ms=cpu_ms,
+                                    wait_ms=wait_ms)
+                blocked_cpu_ms = round(self._blocked_cpu_s * 1e3, 3)
+                if blocked_cpu_ms:
+                    tick_sp.args["blocked_cpu_ms"] = blocked_cpu_ms
+            self._m_tick_host.inc(host_ms)
+            self._m_tick_cpu.inc(cpu_ms)
+            self._m_tick_wait.inc(wait_ms)
+            self._m_proc_cpu.set(round(time.process_time() * 1e3, 3))
         finally:
             self._tick_started_at = None
         if emitted:
@@ -4441,7 +4599,7 @@ class Engine:
         ov = (tr.span("host.overlap", phase="plan") if in_flight
               else nullcontext())
         with ov:
-            with tr.span("admit") as admit_sp:
+            with tr.span("admit", cpu=self._phase_cpu) as admit_sp:
                 timed_out = self.queue.expire(now)
                 admitted = []
                 if not self._draining and self.scheduler.admissible():
@@ -4470,7 +4628,7 @@ class Engine:
                     self._prefill(slot)
                 emitted += 1  # prefill samples the first token
         else:
-            with tr.span("chunk.plan") as plan_sp:
+            with tr.span("chunk.plan", cpu=self._phase_cpu) as plan_sp:
                 for slot in admitted:
                     self._begin_chunked(slot)
                 _, _, prefilling = self.scheduler.snapshot()
@@ -4517,7 +4675,8 @@ class Engine:
         n_before = self._evicted_in_tick
         plan = []
         if ragged and self._chunk is not None and prefilling:
-            with tr.span("chunk.plan", prefilling=len(prefilling)):
+            with tr.span("chunk.plan", cpu=self._phase_cpu,
+                         prefilling=len(prefilling)):
                 plan = self._plan_ragged_chunks(prefilling)
         # -- dispatch tick N+1 ---------------------------------------
         if active or plan:
@@ -4566,7 +4725,7 @@ class Engine:
         self._gate_declined = False
         # deadline sweep first: with a full pool nothing gets popped,
         # but queued requests must still time out on schedule
-        with tr.span("admit") as admit_sp:
+        with tr.span("admit", cpu=self._phase_cpu) as admit_sp:
             timed_out = self.queue.expire(now)
             admitted = []
             if not self._draining:
@@ -4594,7 +4753,7 @@ class Engine:
                 emitted += 1  # prefill samples the first token
             occ, active, prefilling = self.scheduler.snapshot()
         else:
-            with tr.span("chunk.plan") as plan_sp:
+            with tr.span("chunk.plan", cpu=self._phase_cpu) as plan_sp:
                 for slot in admitted:
                     self._begin_chunked(slot)
                 occ, active, prefilling = self.scheduler.snapshot()

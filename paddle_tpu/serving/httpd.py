@@ -45,7 +45,19 @@ full submit -> queue -> slot -> result path over a real socket):
                    "dying" from "finishing up" without parsing prose
   GET  /debug/trace     current trace ring as chrome-trace JSON
                         (open in chrome://tracing / Perfetto, or feed
-                        tools/trace_view.py)
+                        tools/trace_view.py).  The edge's own events,
+                        on the shared "requests" lane: span
+                        http.ingest (top of do_POST to the return of
+                        submit: req, bytes, prompt, cpu_ms), instant
+                        http.first_frame, and ONE span http.stream a
+                        streamed response (headers' end to the
+                        terminal frame: req, frames, bytes,
+                        done_bytes, write_ms, cpu_ms; no span a
+                        frame).  cpu_ms is the handler thread's CPU
+                        time, which it takes of the interpreter the
+                        tick's thread needs (the tick span's wait_ms);
+                        serving.http_cpu_ms / http_frames /
+                        http_bytes_out sum them over all handlers
   GET  /debug/requests  in-flight slot/request states (prefill
                         progress, spec lanes, KV blocks) + the queue
                         + the recent migration log; "engine" carries
@@ -188,6 +200,52 @@ class JsonHandler(BaseHTTPRequestHandler):
         if self.command != "HEAD" and code >= 200 \
                 and code not in (204, 304):
             self.wfile.write(body)
+
+
+class _FrameWriter:
+    """The frames of one streamed response on their way to the socket,
+    counted: how many, their bytes, the terminal frame's bytes, and
+    the wall time inside ``write`` + ``flush`` (a full socket buffer
+    or a slow client shows there).  ``settle`` adds what the engine's
+    counters have not seen yet (``serving.http_frames``,
+    ``serving.http_bytes_out``, and this thread's CPU time since the
+    last call, also kept as ``cpu_s``, to ``serving.http_cpu_ms``);
+    ``write`` calls it every 32 frames, so a window's delta of the
+    counters is true to a few frames of each live stream, and the
+    caller once at the end."""
+
+    __slots__ = ("_wfile", "_eng", "frames", "bytes", "done_bytes",
+                 "write_s", "cpu_s", "_told", "_cpu_seen")
+
+    def __init__(self, wfile, engine):
+        self._wfile, self._eng = wfile, engine
+        self.frames = self.bytes = self.done_bytes = 0
+        self.write_s = self.cpu_s = 0.0
+        self._told = (0, 0)
+        self._cpu_seen = time.thread_time()
+
+    def write(self, frame, terminal=False):
+        t0 = time.perf_counter()
+        self._wfile.write(frame)
+        self._wfile.flush()
+        self.write_s += time.perf_counter() - t0
+        self.frames += 1
+        self.bytes += len(frame)
+        if terminal:
+            self.done_bytes = len(frame)
+        if not self.frames % 32:
+            self.settle()
+
+    def settle(self):
+        eng = self._eng
+        cpu = time.thread_time()
+        eng._m_http_cpu.inc((cpu - self._cpu_seen) * 1e3)
+        self.cpu_s += cpu - self._cpu_seen
+        self._cpu_seen = cpu
+        frames, nbytes = self._told
+        eng._m_http_frames.inc(self.frames - frames)
+        eng._m_http_bytes.inc(self.bytes - nbytes)
+        self._told = (self.frames, self.bytes)
 
 
 class _Handler(JsonHandler):
@@ -456,9 +514,16 @@ class _Handler(JsonHandler):
         tracer = self.engine.tracer
         # the edge's share of the wait for a first token: body read,
         # JSON decode, validation and submit (the request's id is
-        # only known at exit)
+        # only known at exit); its thread CPU time is what it took of
+        # the interpreter, which the tick's thread needs too (read
+        # here and not by the span, so that the counter holds it with
+        # tracing off and the two agree to the digit)
+        c0 = time.thread_time()
         with tracer.span("http.ingest", cat="http") as sp:
             req, body = self._ingest(sp)
+            cpu_ms = (time.thread_time() - c0) * 1e3
+            sp.args["cpu_ms"] = round(cpu_ms, 3)
+        self.engine._m_http_cpu.inc(cpu_ms)
         if req is None:
             return  # _ingest answered
         if body.get("stream"):
@@ -587,8 +652,13 @@ class _Handler(JsonHandler):
         preempt-timeout mid-stream is an honest terminal frame, never
         a silently truncated body.  A SIGTERM-drain migration is
         SPLICED: the peer's relayed tokens beyond what was already
-        streamed continue the same SSE stream seamlessly."""
-        stream = TokenStream(req, heartbeat_s=0.25)
+        streamed continue the same SSE stream seamlessly.  The whole
+        response is ONE ``http.stream`` span and no span a frame, from
+        the headers' end to the terminal frame or the client's
+        hang-up: ``frames`` and ``bytes`` written, ``done_bytes`` (the
+        terminal frame alone), ``write_ms`` (wall time inside the
+        socket writes) and ``cpu_ms`` (what this thread took of the
+        interpreter while the tick's thread decoded beside it)."""
         self.close_connection = True  # the frame has no length; it
         #   ends when the connection does
         self.send_response(200)
@@ -597,51 +667,66 @@ class _Handler(JsonHandler):
         self.send_header("X-Accel-Buffering", "no")
         self.send_header("Connection", "close")
         self.end_headers()
+        out = _FrameWriter(self.wfile, self.engine)
+        with self.engine.tracer.span("http.stream", cat="http",
+                                     req=req.id) as sp:
+            try:
+                self._stream_frames(req, out)
+            except (BrokenPipeError, ConnectionResetError, OSError):
+                # the client vanished mid-stream: nothing to answer;
+                # the engine lands the request and this sink dies with
+                # the handler thread
+                pass
+            finally:
+                out.settle()
+                sp.args.update(frames=out.frames, bytes=out.bytes,
+                               done_bytes=out.done_bytes,
+                               write_ms=round(out.write_s * 1e3, 3),
+                               cpu_ms=round(out.cpu_s * 1e3, 3))
+
+    def _stream_frames(self, req, out):
+        """Write the frames of one streamed response through ``out``
+        (a ``_FrameWriter``) until the terminal one."""
+        stream = TokenStream(req, heartbeat_s=0.25)
         deadline = time.monotonic() + self.result_timeout
         sent = 0
-        try:
-            for ev in stream:
-                if ev.kind == "token":
-                    self.wfile.write(sse_format(
-                        {"token": int(ev.token),
-                         "index": int(ev.index)}, event="token"))
-                    sent += 1
-                elif ev.kind == "heartbeat":
-                    if time.monotonic() > deadline:
-                        self.wfile.write(sse_format(
-                            {"error": "no terminal event before "
-                             "result_timeout",
-                             "reason": "result_timeout",
-                             "retry_after": None}, event="error"))
-                        return
-                    self.wfile.write(sse_format(comment="hb"))
-                elif ev.kind == "done":
-                    ttft = None
-                    if req.first_token_at is not None:
-                        ttft = round((req.first_token_at
-                                      - req.submitted_at) * 1e3, 3)
-                    self.wfile.write(sse_format({
-                        "id": req.id,
-                        "ids": [int(t) for t in req.prompt]
-                        + [int(t) for t in req.generated],
-                        "generated": [int(t) for t in req.generated],
-                        "ttft_ms": ttft, "streamed": sent,
-                    }, event="done"))
-                    return
-                else:
-                    self._stream_error(req, ev.error, sent)
-                    return
-                self.wfile.flush()
-                if sent == 1 and ev.kind == "token":
+        for ev in stream:
+            if ev.kind == "token":
+                out.write(sse_format(
+                    {"token": int(ev.token),
+                     "index": int(ev.index)}, event="token"))
+                sent += 1
+                if sent == 1:
                     self.engine.tracer.instant(
                         "http.first_frame", cat="http", req=req.id)
-        except (BrokenPipeError, ConnectionResetError, OSError):
-            # the client vanished mid-stream: nothing to answer; the
-            # engine lands the request and this sink dies with the
-            # handler thread
-            pass
+            elif ev.kind == "heartbeat":
+                if time.monotonic() > deadline:
+                    out.write(sse_format(
+                        {"error": "no terminal event before "
+                         "result_timeout",
+                         "reason": "result_timeout",
+                         "retry_after": None}, event="error"),
+                        terminal=True)
+                    return
+                out.write(sse_format(comment="hb"))
+            elif ev.kind == "done":
+                ttft = None
+                if req.first_token_at is not None:
+                    ttft = round((req.first_token_at
+                                  - req.submitted_at) * 1e3, 3)
+                out.write(sse_format({
+                    "id": req.id,
+                    "ids": [int(t) for t in req.prompt]
+                    + [int(t) for t in req.generated],
+                    "generated": [int(t) for t in req.generated],
+                    "ttft_ms": ttft, "streamed": sent,
+                }, event="done"), terminal=True)
+                return
+            else:
+                self._stream_error(req, ev.error, sent, out)
+                return
 
-    def _stream_error(self, req, err, sent):
+    def _stream_error(self, req, err, sent, out):
         """Terminal frame for a stream that did not finish cleanly.
         Migrated + a draining EngineServer is the one recoverable
         case: await the drain relay and SPLICE the peer's completion
@@ -655,17 +740,18 @@ class _Handler(JsonHandler):
             if found and resp is not None:
                 gen = [int(t) for t in resp.get("generated", [])]
                 for j in range(sent, len(gen)):
-                    self.wfile.write(sse_format(
+                    out.write(sse_format(
                         {"token": gen[j], "index": j}, event="token"))
-                out = dict(resp)
-                out["migrated"] = True
-                out["streamed"] = sent + max(len(gen) - sent, 0)
-                self.wfile.write(sse_format(out, event="done"))
+                done = dict(resp)
+                done["migrated"] = True
+                done["streamed"] = sent + max(len(gen) - sent, 0)
+                out.write(sse_format(done, event="done"),
+                          terminal=True)
                 return
-            self.wfile.write(sse_format(
+            out.write(sse_format(
                 {"error": str(err),
                  "reason": "drain_failed" if found else "internal",
-                 "retry_after": None}, event="error"))
+                 "retry_after": None}, event="error"), terminal=True)
             return
         if isinstance(err, RequestTimeout):
             reason = "result_timeout"
@@ -674,10 +760,10 @@ class _Handler(JsonHandler):
                 getattr(self.engine, "_draining", False)))
         else:
             reason = "internal"
-        self.wfile.write(sse_format(
+        out.write(sse_format(
             {"error": str(err), "reason": reason,
              "retry_after": getattr(err, "retry_after", None)},
-            event="error"))
+            event="error"), terminal=True)
 
     def _read_body(self):
         n = int(self.headers.get("Content-Length", 0))

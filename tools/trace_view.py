@@ -7,6 +7,13 @@ a trace file (a ``/debug/trace`` download, a flight-recorder dump, or
 a ``stop_profiler(profile_path=...)`` export), so traces are
 inspectable over ssh with nothing but Python.
 
+``--wall`` adds the host by thread: the CPU time of the spans that
+carry ``cpu_ms`` (the tick, its phases, the HTTP edge) beside their
+wall time, and the CPU a wall-second of the tick's thread, the edge's,
+the device watcher's and the rest of the process.  The file may also
+be a benchmark run's ``--dump-sources`` dump, whose counters fill all
+four rows.
+
 Usage:
     python tools/trace_view.py trace.json [--cat serving] [--sort total]
 """
@@ -35,15 +42,28 @@ def _percentile(sorted_vals, q):
 def summarize(events, cat=None):
     """Aggregate complete-events (``ph == "X"``) by name.  Returns rows
     of dicts: name, count, total_ms, mean_ms, p50_ms, p99_ms — sorted
-    by total descending."""
-    groups = {}
+    by total descending — and, for a name whose spans carry ``cpu_ms``
+    (opened with ``cpu=True``; the engine's ``tick``), ``cpu_ms``: the
+    thread CPU time summed, and ``wait_ms``: the rest of their summed
+    wall time, in which the thread stood still (the ``tick`` span says
+    its own: what is left of ``host_ms``, its waits for the device
+    taken out).  Sums, because a coarse CPU clock (10 ms steps on some
+    kernels) makes one span's ``cpu_ms`` 0 or a whole step.  Both are
+    None where no span of the name has the arg."""
+    groups, cpu, wait = {}, {}, {}
     for ev in events:
         if ev.get("ph") != "X":
             continue
         if cat is not None and ev.get("cat") != cat:
             continue
-        groups.setdefault(ev["name"], []).append(
-            float(ev.get("dur", 0.0)) / 1e3)  # us -> ms
+        name = ev["name"]
+        dur = float(ev.get("dur", 0.0)) / 1e3  # us -> ms
+        groups.setdefault(name, []).append(dur)
+        args = ev.get("args") or {}
+        if "cpu_ms" in args:
+            cpu[name] = cpu.get(name, 0.0) + args["cpu_ms"]
+            wait[name] = wait.get(name, 0.0) + args.get(
+                "wait_ms", dur - args["cpu_ms"])
     rows = []
     for name, durs in groups.items():
         durs.sort()
@@ -53,19 +73,93 @@ def summarize(events, cat=None):
             "mean_ms": sum(durs) / len(durs),
             "p50_ms": _percentile(durs, 50),
             "p99_ms": _percentile(durs, 99),
+            "cpu_ms": cpu.get(name),
+            "wait_ms": max(wait[name], 0.0) if name in wait else None,
         })
     rows.sort(key=lambda r: -r["total_ms"])
     return rows
 
 
-def format_table(rows):
-    lines = [f"{'span':<28} {'count':>7} {'total(ms)':>11} "
-             f"{'mean(ms)':>10} {'p50(ms)':>10} {'p99(ms)':>10}"]
+def format_table(rows, cpu=False):
+    """The per-span table; ``cpu`` adds the ``cpu(ms)`` and
+    ``wait(ms)`` columns (``-`` for spans that read no CPU clock)."""
+    head = (f"{'span':<28} {'count':>7} {'total(ms)':>11} "
+            f"{'mean(ms)':>10} {'p50(ms)':>10} {'p99(ms)':>10}")
+    if cpu:
+        head += f" {'cpu(ms)':>11} {'wait(ms)':>11}"
+    lines = [head]
     for r in rows:
-        lines.append(
+        line = (
             f"{r['name']:<28} {r['count']:>7} {r['total_ms']:>11.3f} "
             f"{r['mean_ms']:>10.3f} {r['p50_ms']:>10.3f} "
             f"{r['p99_ms']:>10.3f}")
+        if cpu:
+            line += "".join(
+                f" {'-':>11}" if r.get(k) is None else f" {r[k]:>11.3f}"
+                for k in ("cpu_ms", "wait_ms"))
+        lines.append(line)
+    return "\n".join(lines)
+
+
+def cpu_by_group(events, counters=None, window_s=None):
+    """CPU time a wall-second by thread group: the tick's thread
+    (inside ``host_ms``), the HTTP edge's handler threads
+    (``http.ingest`` + ``http.stream``), the device watcher's, and the
+    rest of the process (the runtime's own threads, which hold no
+    interpreter lock, plus whatever the first three spend outside
+    their spans).  The first three share ONE interpreter: their sum
+    near 1,000 ms a second says it is saturated; a process total that
+    falls while the tick's ``wait_ms`` rises says the machine is.
+
+    From ``counters`` (a window's deltas of ``serving.tick_cpu_ms``,
+    ``serving.http_cpu_ms``, ``serving.dev_watch_cpu_ms`` and
+    ``process.cpu_ms``: a ``--dump-sources`` dump has them) over
+    ``window_s``; else from the spans' ``cpu_ms`` over the time the
+    ``tick`` spans cover, where the last two rows cannot be known.
+    Returns ``{"wall_s", "rows": [(group, ms a second or None)]}``, or
+    None where nothing carries a CPU time."""
+    if counters and window_s and "serving.tick_cpu_ms" in counters:
+        tick = counters["serving.tick_cpu_ms"]
+        edge = counters.get("serving.http_cpu_ms", 0.0)
+        watch = counters.get("serving.dev_watch_cpu_ms", 0.0)
+        proc = counters.get("process.cpu_ms")
+        rest = None if proc is None else proc - tick - edge - watch
+        wall_s = float(window_s)
+    else:
+        tick = edge = 0.0
+        t_lo, t_hi, seen = float("inf"), float("-inf"), False
+        for ev in events:
+            if ev.get("ph") != "X":
+                continue
+            name = ev.get("name")
+            if name == "tick":
+                t_lo = min(t_lo, float(ev["ts"]))
+                t_hi = max(t_hi, float(ev["ts"]) + float(ev.get("dur", 0)))
+            c = (ev.get("args") or {}).get("cpu_ms")
+            if c is None:
+                continue
+            if name == "tick":
+                tick, seen = tick + c, True
+            elif name in ("http.ingest", "http.stream"):
+                edge, seen = edge + c, True
+        if not seen or t_hi <= t_lo:
+            return None
+        watch = rest = None
+        wall_s = (t_hi - t_lo) / 1e6
+    return {"wall_s": wall_s, "rows": [
+        (group, None if ms is None else ms / wall_s)
+        for group, ms in (("tick", tick), ("edge", edge),
+                          ("watcher", watch), ("rest of process", rest))]}
+
+
+def format_cpu_groups(g):
+    lines = [f"CPU by thread group, ms a wall-second over "
+             f"{g['wall_s']:.3f} s (tick + edge + watcher share one "
+             "interpreter):"]
+    for group, ms in g["rows"]:
+        lines.append(f"  {group:<26} " + (
+            f"{'-':>11}   (needs the counters of a dump)" if ms is None
+            else f"{ms:>11.3f}"))
     return "\n".join(lines)
 
 
@@ -382,14 +476,23 @@ def format_lifecycle(rows):
     return "\n".join(lines)
 
 
-def load_events(path):
-    """Events from a trace file: Catapult object form or bare list."""
+def load_trace(path):
+    """``(events, counters, window_s)`` of a trace file: Catapult
+    object form or bare list (no counters), or a benchmark run's
+    ``--dump-sources`` dump (its ``spans``, the window's counter
+    deltas and its length)."""
     with open(path) as f:
         data = json.load(f)
-    events = data["traceEvents"] if isinstance(data, dict) else data
+    counters = window_s = None
+    if isinstance(data, dict) and "spans" in data:
+        events = data["spans"]
+        counters = (data.get("counters") or {}).get("delta")
+        window_s = (data.get("ctx") or {}).get("window_s")
+    else:
+        events = data["traceEvents"] if isinstance(data, dict) else data
     if not isinstance(events, list):
         raise ValueError(f"{path}: not a chrome trace")
-    return events
+    return events, counters, window_s
 
 
 def main(argv=None):
@@ -410,14 +513,18 @@ def main(argv=None):
                         "trace has the engine's device lane (dev.* "
                         "spans), the device's busy and idle time with "
                         "the idle summed by the host span open "
-                        "meanwhile")
+                        "meanwhile; also cpu(ms) and wait(ms) columns "
+                        "for the spans that carry cpu_ms, and the CPU "
+                        "a wall-second of the tick's, the edge's and "
+                        "the watcher's threads and the rest of the "
+                        "process")
     p.add_argument("--lifecycle", action="store_true",
                    help="append an instant-event count table (request "
                         "lifecycle incl. req.preempted / req.resumed "
                         "/ req.shed[reason], fault.injected, "
                         "engine.watchdog)")
     args = p.parse_args(argv)
-    events = load_events(args.trace)
+    events, counters, window_s = load_trace(args.trace)
     rows = summarize(events, cat=args.cat)
     key = {"total": "total_ms", "count": "count", "mean": "mean_ms",
            "p50": "p50_ms", "p99": "p99_ms"}[args.sort]
@@ -426,13 +533,16 @@ def main(argv=None):
         print("no complete-events matched", file=sys.stderr)
         return 1
     if rows:
-        print(format_table(rows))
+        print(format_table(rows, cpu=args.wall))
     if args.wall:
         print()
         print(format_wall(wall_summary(events)))
         dev = device_summary(events)
         if dev is not None:
             print(format_device(dev))
+        groups = cpu_by_group(events, counters, window_s)
+        if groups is not None:
+            print(format_cpu_groups(groups))
     if args.lifecycle:
         life = lifecycle_summary(events)
         print()
