@@ -156,6 +156,12 @@ def start_profile(directory):
     jax.profiler.start_trace(directory, profiler_options=opts)
 
 
+# what a traced run's result line says of the device beside JAX's own
+# account of it, out of ``xplane.reduce``: ``window_s`` is the larger of
+# ``host_window_s`` and ``extent_s``
+DEVICE_WINDOW = ("busy_s", "window_s", "host_window_s", "extent_s")
+
+
 def enable_cache():
     """JAX's persistent compile cache at the program's fixed place (the
     variable if set, else ``.jax_cache/`` in the checkout), keeping every

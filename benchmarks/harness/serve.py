@@ -207,6 +207,13 @@ def measure(url, cfg, mix_path, override, args, counters):
            "profile_dir": os.path.join(common.scratch_dir(), "profile")}
     last = [0.0]
 
+    # The capture starts inside ``start_profile`` and ends inside
+    # ``stop_trace``; the clock is read after the one and before the
+    # other, so the capture CONTAINS ``[p0, p1]`` and its device events
+    # may span a little more than ``p1 - p0`` (``xplane.reduce`` widens
+    # the window it reports to hold them).  The counters and
+    # ``profile_work`` take ``[p0, p1]``: a roofline share can only be
+    # understated by that, never overstated.
     def stop_profile():
         win["p_after"] = counters.snapshot()
         win["profile"][1] = time.monotonic()
@@ -381,8 +388,7 @@ def run(cell, cfg, mix_path, args, t_proc0):
         src = traced_sources(cfg, args, dev, win, trace, counters,
                              memory_peak)
         metrics = common.layer_metrics(cell, src, args.dump_sources)
-        device.update(busy_s=src["device"]["busy_s"],
-                      window_s=src["device"]["window_s"])
+        device.update({k: src["device"][k] for k in common.DEVICE_WINDOW})
         breakdown = {"device_ops": src["device"]["device_ops"],
                      "idle_gaps": src["device"]["idle_gaps"]}
     common.say("client: " + json.dumps({

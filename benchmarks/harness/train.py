@@ -168,7 +168,11 @@ def run(cell, cfg, mix_path, args, t_proc0):
                 args.seconds * 0.5)
     p_at = (args.seconds - p_len) / 2.0
     prof_dir = os.path.join(common.scratch_dir(), "profile")
-    prof = {"t": None, "steps": 0}    # t: [start, stop] of the profile
+    # t: [start, stop] of the profile, each read INSIDE the capture (after
+    # ``start_profile`` returns, before ``stop_trace`` is called): the
+    # capture contains it, and ``xplane.reduce`` widens the window it
+    # reports to hold every device event the capture recorded
+    prof = {"t": None, "steps": 0}
     c0 = compiles.count
     losses = []
     w0 = time.monotonic()
@@ -242,8 +246,7 @@ def run(cell, cfg, mix_path, args, t_proc0):
                     "memory_peak_bytes": memory_peak},
         }
         metrics = common.layer_metrics(cell, src, args.dump_sources)
-        device.update(busy_s=dev_trace["busy_s"],
-                      window_s=dev_trace["window_s"])
+        device.update({k: dev_trace[k] for k in common.DEVICE_WINDOW})
         breakdown = {"device_ops": dev_trace["device_ops"],
                      "idle_gaps": dev_trace["idle_gaps"]}
         common.say(f"traced run: train_tok_s={train_tok_s:.1f} outside "

@@ -101,14 +101,32 @@ def _by_name(raw, key, n):
     return {k: v * 1e-9 / n for k, v in out.items()}
 
 
+def capture_window(host_window_s, extent_s):
+    """{"window_s", "host_window_s", "extent_s"}: the window that
+    ``busy_s`` is compared with is the larger of what the caller timed
+    (None where it timed nothing) and the device events' extent."""
+    host = None if host_window_s is None else float(host_window_s)
+    return {"window_s": max(host or 0.0, extent_s), "host_window_s": host,
+            "extent_s": extent_s}
+
+
 def reduce(path, span_names=(), window_s=None, top=10):
-    """{"busy_s", "window_s", "device_ops", "idle_gaps", "devices",
-    "by_name"}.
+    """{"busy_s", "window_s", "host_window_s", "extent_s", "device_ops",
+    "idle_gaps", "devices", "by_name"}.
 
     ``busy_s`` is the union of the device's operation intervals,
-    averaged over the device planes that ran anything; ``window_s`` is
-    the traced window as the caller timed it (else the span from the
-    first device event to the last).  ``device_ops`` are the ``top``
+    averaged over the device planes that ran anything.  ``extent_s`` is
+    the span from the first device event's start to the last one's end
+    over those planes (0 where none ran anything), ``host_window_s`` the
+    caller's ``window_s`` as it timed it (None where it gave none), and
+    ``window_s`` the larger of the two.  A caller reads its clock after
+    ``start_trace`` returns and before it calls ``stop_trace``, so both
+    its interval and the events' extent lie inside the capture and the
+    larger is the better lower bound of it; the two are on different
+    clocks, so neither is clipped to the other, and ``busy_s <= extent_s
+    <= window_s`` whatever the host timed.  Where the device idles at
+    the capture's edges the host's interval is the larger and that idle
+    time still counts.  ``device_ops`` are the ``top``
     operations by summed time, ``idle_gaps`` the idle time summed by the
     host span that covers each gap.  ``by_name`` holds the summed
     seconds (averaged over the devices, as ``device_ops``) of every
@@ -151,14 +169,13 @@ def reduce(path, span_names=(), window_s=None, top=10):
                                      ev.start_ns + ev.duration_ns,
                                      ev.name))
     if not per_device:
-        return {"busy_s": 0.0, "window_s": window_s or 0.0,
+        return {"busy_s": 0.0, **capture_window(window_s, 0.0),
                 "device_ops": [], "idle_gaps": [], "devices": 0,
                 "by_name": {}}
     busy = sum(sum(e - s for s, e in m) for m in per_device) \
         / len(per_device) * 1e-9
-    if window_s is None:
-        window_s = (max(m[-1][1] for m in per_device)
-                    - min(m[0][0] for m in per_device)) * 1e-9
+    extent = (max(m[-1][1] for m in per_device)
+              - min(m[0][0] for m in per_device)) * 1e-9
     first = per_device[0]
     between = [(e0, s1) for (_, e0), (s1, _) in zip(first, first[1:])]
     gaps = {}
@@ -168,7 +185,7 @@ def reduce(path, span_names=(), window_s=None, top=10):
     ops = sorted(_by_name(raw[OPS_LINE], short_op, n).items(),
                  key=lambda kv: -kv[1])[:top]
     idle = sorted(gaps.items(), key=lambda kv: -kv[1])[:top]
-    return {"busy_s": busy, "window_s": float(window_s),
+    return {"busy_s": busy, **capture_window(window_s, extent),
             "device_ops": [[k, v] for k, v in ops],
             "idle_gaps": [[k, v] for k, v in idle],
             "devices": n,

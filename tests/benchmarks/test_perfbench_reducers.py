@@ -235,6 +235,37 @@ def test_device_trace_recorded_on_a_v5e():
     assert idle == pytest.approx(100 * (1 - got["busy_s"] / 0.03))
 
 
+# the events' extent: first copy-start's start to the last fusion's end
+EXTENT = 23_011_224e-9
+
+
+@pytest.mark.parametrize("host, busy, extent, window", [
+    # the caller timed more than the events span: its window stands, and
+    # the idle time at the capture's edges still counts
+    (0.03, None, None, 0.03),
+    # the caller timed LESS than the events span (its clock is read
+    # inside the capture): the window holds every event
+    (0.01, None, None, EXTENT),
+    # no caller's window: the extent, and nothing said of the host's
+    (None, None, None, EXTENT),
+    # PR 41's refused pair (sessions, seed 1085869964): the device busy
+    # for 0.48 ms more than the host timed; the events spanned the busy
+    # time and the 2.9 ms of gaps between them
+    (5.017475489, 5.017954717, 5.017954717 + 0.0029, 5.020854717),
+], ids=["host_wider", "host_shorter", "no_host_window", "pr41_pair"])
+def test_the_window_holds_every_device_event(host, busy, extent, window):
+    if busy is None:
+        got = xplane.reduce(TRACE, {"train.step"}, window_s=host)
+        assert got["extent_s"] == pytest.approx(EXTENT, rel=1e-9)
+    else:
+        got = dict(xplane.capture_window(host, extent), busy_s=busy)
+    assert got["window_s"] == pytest.approx(window, rel=1e-9)
+    assert got["host_window_s"] == host
+    assert got["window_s"] == max(host or 0.0, got["extent_s"])
+    assert 0 < got["busy_s"] <= got["extent_s"] <= got["window_s"]
+    assert 0.0 <= reducers.xplane_idle({"device": got}) <= 100.0
+
+
 def test_device_trace_by_name():
     # the same file by name.  ``XLA Modules``: three runs of the one
     # program, 11,900 + 11,901 + 11,901 ns; ``XLA Ops``: nine events

@@ -16,7 +16,12 @@ def test_traced_rehearsal(workload):
     res, wanted = check_line(lines, workload, "per_layer")
     missing = wanted - set(res["metrics"])
     assert all(n.startswith(NEEDS_DEVICE) for n in missing), missing
-    assert {"busy_s", "window_s"} <= set(res["device"])
+    dev = res["device"]
+    assert {"busy_s", "window_s", "host_window_s", "extent_s"} <= set(dev)
+    # (the CPU has no device plane: busy and extent read 0 here, and the
+    # window is the host's)
+    assert 0 <= dev["busy_s"] <= dev["extent_s"] <= dev["window_s"]
+    assert dev["window_s"] == max(dev["host_window_s"], dev["extent_s"])
     assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
     for name in res["metrics"]:
         if name.startswith("compiles_in_window"):
