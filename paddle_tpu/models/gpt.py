@@ -17,6 +17,7 @@ sharding stage2, GPT-3 1.3B hybrid) instantiate from ``GPT_CONFIGS``.
 """
 from __future__ import annotations
 
+import functools
 import math
 import threading
 from contextlib import contextmanager as _contextmanager
@@ -29,7 +30,8 @@ from ..nn.layer.scan import ScanLayers
 from ..ops import reshape, transpose, concat
 from .programs import (  # noqa: F401
     KVRowSpec, ServedModel, ServingSpec, _jit_named, _scoped,
-    filter_logits_lanes, sample_lanes, slot_sample_keys,
+    filter_logits_lanes, sample_lanes, slot_sample_keys, walk_chunk,
+    walk_group, walk_plan, walk_rows,
 )
 
 
@@ -44,53 +46,15 @@ def _is_quant_kv(pool):
     return hasattr(pool, "codes") and hasattr(pool, "scale")
 
 
-_SLOT_ATTN_CHUNK_ROWS = 256
-
-
-def slot_attn_chunk(block_size=None):
-    """Cache rows one trip of ``GPTAttention._slot_attn``'s blocked
-    walk fetches: a whole number of KV blocks, of the order of 256
-    rows (the contiguous layout has no blocks and takes 256)."""
-    if not block_size:
-        return _SLOT_ATTN_CHUNK_ROWS
-    return block_size * max(1, _SLOT_ATTN_CHUNK_ROWS // block_size)
-
-
-def slot_attn_rows(end, table_rows, chunk):
-    """Rows of each slot's ``table_rows``-row table that
-    ``_slot_attn`` walks when the longest window ends at row ``end``
-    (exclusive, ``max(pos) + S``): the host twin of the trip count the
-    program reads from its ``pos`` lanes on the device."""
-    if table_rows <= chunk:
-        return table_rows
-    trips = min(-(-table_rows // chunk), max(1, -(-int(end) // chunk)))
-    return min(table_rows, trips * chunk)
-
-
-def _fetch_rows(buf, start, size):
-    """``_slot_attn`` fetch for the contiguous layout: ``size`` rows of
-    every slot's ``[B, L, H, hd]`` buffer from row ``start``."""
-    import jax
-    return jax.lax.dynamic_slice_in_dim(buf, start, size, axis=1)
-
-
-def _fetch_blocks(block_tables):
-    """``_slot_attn`` fetch for the paged layout: whole blocks gathered
-    through ``size // bs`` columns of the per-slot tables from logical
-    row ``start`` (a multiple of the block size).  A ``QuantKV`` pool
-    dequantizes the fetched blocks only."""
-    import jax
-
-    def fetch(pool, start, size):
-        bs = pool.shape[1]
-        cols = jax.lax.dynamic_slice_in_dim(block_tables, start // bs,
-                                            size // bs, axis=1)
-        if _is_quant_kv(pool):
-            from ..serving.quant import paged_gather
-            return paged_gather(pool, cols)
-        blocks = pool[cols]                     # [B, nb, bs, H, hd]
-        return blocks.reshape(blocks.shape[0], size, *blocks.shape[3:])
-    return fetch
+def _gather_blocks(pool, cols):
+    """Rows of the blocks ``cols`` [n, k] name, block after block:
+    ``[n, k * bs, H, hd]`` of a ``[NB, bs, H, hd]`` pool.  A ``QuantKV``
+    pool dequantizes the fetched blocks only."""
+    if _is_quant_kv(pool):
+        from ..serving.quant import paged_gather
+        return paged_gather(pool, cols)
+    blocks = pool[cols]                         # [n, k, bs, H, hd]
+    return blocks.reshape(cols.shape[0], -1, *blocks.shape[3:])
 
 
 # Per-slot LoRA context (serving/lora.py).  Thread-local because jax
@@ -342,8 +306,7 @@ class GPTAttention(nn.Layer):
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         return q._data, k._data, v._data
 
-    def _slot_attn(self, qa, k_src, v_src, fetch, table_rows, chunk,
-                   pos):
+    def _slot_attn(self, qa, k_src, v_src, tables, pos):
         """Windowed attention over each slot's cache rows: f32 scores,
         per-row causal mask (the query at window offset q of slot b
         sees cache positions <= pos[b] + q), f32 softmax, value
@@ -354,84 +317,179 @@ class GPTAttention(nn.Layer):
         the speculative verify's greedy parity are structural, not
         by-convention.
 
-        The cache is read through ``fetch(src, start, size)`` ->
-        ``[B, size, H, hd]`` logical rows ``start..start+size-1`` of
-        every slot (``_fetch_rows`` / ``_fetch_blocks``), ``chunk``
-        rows a trip, and only as far as the longest live window:
-        ``ceil((max(pos) + S) / chunk)`` trips, read on the device
-        from ``pos`` (parked lanes hold 0), so rows past every live
-        context are never fetched and the program stays one.  Rows are
-        contracted in the dtype the cache holds them in with f32
-        accumulation — a product of two bf16 values is exact in f32,
-        so an f32 copy of the rows would carry nothing they do not;
-        the probabilities stay f32 through the value contraction
-        (``HIGHEST``: no single bf16 pass over them).  One pass: each
-        trip scores its chunk, folds it into a running f32 maximum and
-        denominator per query and rescales the f32 context it has so
-        far, so the softmax is over exactly the visible positions
-        (masked ones contribute exactly 0: row 0 is visible to every
-        query, so the maximum is finite from the first trip on).  A
-        table no longer than one chunk takes one trip with nothing to
-        skip and keeps the one-shot form.
+        The cache is read ``walk_chunk`` rows at a time, each slot as
+        far as its OWN window: several slots are walked as a WORK LIST
+        of (slot, chunk) items built on the device from ``pos``
+        (``walk_plan``, the routed models' rule): slot b has
+        ``ceil((pos[b] + S) / chunk)`` items and a parked lane (position
+        0) none, a trip takes ``walk_group`` items whatever slots they
+        belong to (the fewer the wider a position's K and V are), and
+        the trip count ``ceil(items / group)`` is data, so rows past a
+        slot's own context are never fetched and the program stays
+        one.  An item's masked scores give a partial
+        (maximum, denominator, context); a trip folds its items'
+        partials into their slots' running f32 state, so the softmax is
+        over exactly the visible positions (masked rows, the last
+        trip's padding items and slots without an item contribute
+        exactly 0; a slot without an item returns the projection of
+        zeros: that is where the engine parks a lane, and a lane that
+        decodes stands at 1 or later).  Rows are contracted in the
+        dtype the cache holds them in with f32 accumulation — a product
+        of two bf16 values is exact in f32, so an f32 copy of the rows
+        would carry nothing they do not; the probabilities stay f32
+        through the value contraction (``HIGHEST``: no single bf16 pass
+        over them).  One slot walks its own chunks in turn, and a table
+        no longer than one chunk is read whole, without a loop.  The
+        form is chosen from the shapes alone.
 
-        qa [B, S, H, hd]; k_src/v_src what ``fetch`` reads; pos int32
-        [B] (window start per slot).  Returns out Tensor [B, S, E]."""
+        qa [B, S, H, hd]; k_src/v_src ``[NB, bs, H, hd]`` pools (plain
+        or ``QuantKV``) read through ``tables`` int32 [B, L // bs], or
+        ``[B, L, H, hd]`` buffers where ``tables`` is None (the
+        contiguous layout); pos int32 [B] (window start per slot).
+        Returns out Tensor [B, S, E]."""
         import math as _math
         import jax
         import jax.numpy as jnp
+        import numpy as np
 
         B, S, H = qa.shape[0], qa.shape[1], qa.shape[2]
+        bs = None if tables is None else k_src.shape[1]
+        table_rows = k_src.shape[1] if tables is None \
+            else tables.shape[1] * bs
+        chunk = walk_chunk(table_rows, bs)
+        n_chunks = -(-table_rows // chunk)
         scale = 1.0 / _math.sqrt(self.head_dim)
         q_end = pos[:, None] + jnp.arange(S)[None, :]          # [B, S]
 
-        def scores_of(start, size, fresh_from=0):
-            # masked f32 scores [B, H, S, size] of rows start..; rows
-            # below fresh_from were scored by an earlier trip
-            kc = fetch(k_src, start, size)
-            dt = jnp.promote_types(qa.dtype, kc.dtype)
-            sc = jnp.einsum("bqhd,bkhd->bhqk", qa.astype(dt),
+        def partial(q, rows_of, visible):
+            """Masked f32 scores [n, H, S, size] of the queries ``q``
+            [n, S, H, hd] over ``rows_of(k_src)`` [n, size, H, hd], and
+            ``context(p)``: the f32 context [n, S, H, hd] of weights p
+            over ``rows_of(v_src)``."""
+            kc = rows_of(k_src)
+            dt = jnp.promote_types(q.dtype, kc.dtype)
+            sc = jnp.einsum("bqhd,bkhd->bhqk", q.astype(dt),
                             kc.astype(dt),
                             preferred_element_type=jnp.float32) * scale
-            rows = start + jnp.arange(size)
-            visible = ((rows[None, None, :] <= q_end[:, :, None])
-                       & (rows >= fresh_from)[None, None, :])
-            return jnp.where(visible[:, None, :, :], sc, -1e30)
+            return (jnp.where(visible[:, None, :, :], sc, -1e30),
+                    lambda p: jnp.einsum(
+                        "bhqk,bkhd->bqhd", p,
+                        rows_of(v_src).astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST))
 
-        def ctx_of(probs, start, size):
-            vc = fetch(v_src, start, size)
-            return jnp.einsum("bhqk,bkhd->bqhd", probs,
-                              vc.astype(jnp.float32),
-                              precision=jax.lax.Precision.HIGHEST)
+        def window(start, size):
+            # rows start..start+size-1 of every slot
+            if tables is None:
+                return lambda src: jax.lax.dynamic_slice_in_dim(
+                    src, start, size, axis=1)
+            cols = jax.lax.dynamic_slice_in_dim(
+                tables, start // bs, size // bs, axis=1)
+            return lambda src: _gather_blocks(src, cols)
 
-        trips = -(-table_rows // chunk)
-        if trips == 1:
-            probs = jax.nn.softmax(scores_of(0, table_rows), axis=-1)
-            ctx = ctx_of(probs, 0, table_rows)
-        else:
-            # the last trip of a table that is no whole number of
-            # chunks starts early and masks the rows it shares
-            last = table_rows - chunk
-            live = jnp.clip((jnp.max(pos) + S + chunk - 1) // chunk,
-                            1, trips)
+        def per_ctx(a):                  # [B, H, S] -> [B, S, H, 1]
+            return jnp.transpose(a, (0, 2, 1))[..., None]
 
-            def per_ctx(a):                  # [B, H, S] -> [B, S, H, 1]
-                return jnp.transpose(a, (0, 2, 1))[..., None]
+        # the last chunk of a table that is no whole number of chunks
+        # starts early and masks the rows it shares with the one before
+        last = table_rows - chunk
 
-            def trip(c, carry):
+        def trip(c, carry):
+            top, den, acc = carry
+            start = jnp.minimum(c * chunk, last)
+            at = start + jnp.arange(chunk)
+            sc, context = partial(
+                qa, window(start, chunk),
+                (at[None, None, :] <= q_end[:, :, None])
+                & (at >= c * chunk)[None, None, :])
+            new_top = jnp.maximum(top, jnp.max(sc, axis=-1))
+            keep = jnp.exp(top - new_top)
+            p = jnp.exp(sc - new_top[..., None])
+            return (new_top, den * keep + jnp.sum(p, axis=-1),
+                    acc * per_ctx(keep) + context(p))
+
+        def walk_items(init):
+            group = walk_group(B, 2 * H * qa.shape[3])
+            slot_of, chunk_of, valid, n_trips = walk_plan(
+                pos, S, table_rows, chunk, group)
+            # What a trip needs of its items and no carry enters is
+            # made for the whole list before the loop and cut a trip:
+            # it depends on ``pos`` and the tables alone, so a
+            # program's layers share one copy.
+            first = np.minimum(np.arange(n_chunks) * chunk, last)
+            start_of = jnp.asarray(first, jnp.int32)[chunk_of]
+            at = start_of[:, None] + jnp.arange(chunk)[None, :]
+            sees = ((at[:, None, :] <= q_end[slot_of][:, :, None])
+                    & ((at >= (chunk_of * chunk)[:, None])
+                       & valid[:, None])[:, None, :])      # [N, S, K]
+            whose = ((slot_of[None, :] == jnp.arange(B)[:, None])
+                     & valid[None, :])[..., None, None]  # [B, N, 1, 1]
+            if tables is None:
+                # an item's rows are one slice of its slot's buffer
+                where = jnp.stack([slot_of, start_of], axis=1)
+
+                def items(slices):
+                    return lambda src: jax.vmap(
+                        lambda w: jax.lax.dynamic_slice(
+                            src, (w[0], w[1], 0, 0),
+                            (1, chunk) + src.shape[2:])[0])(slices)
+            else:
+                # the block columns of every (slot, chunk) window, a
+                # row each, and each item's row of them
+                windows = tables[:, first[:, None] // bs
+                                 + np.arange(chunk // bs)[None, :]]
+                where = windows.reshape(B * n_chunks, chunk // bs)[
+                    slot_of * n_chunks + chunk_of]       # [N, K // bs]
+
+                def items(cols):
+                    return lambda src: _gather_blocks(src, cols)
+
+            def item_trip(t, carry):
                 top, den, acc = carry
-                start = jnp.minimum(c * chunk, last)
-                sc = scores_of(start, chunk, c * chunk)
-                new_top = jnp.maximum(top, jnp.max(sc, axis=-1))
-                keep = jnp.exp(top - new_top)
-                p = jnp.exp(sc - new_top[..., None])
-                return (new_top, den * keep + jnp.sum(p, axis=-1),
-                        acc * per_ctx(keep) + ctx_of(p, start, chunk))
 
-            _, den, acc = jax.lax.fori_loop(
-                0, live, trip,
-                (jnp.full((B, H, S), -1e30, jnp.float32),
-                 jnp.zeros((B, H, S), jnp.float32),
-                 jnp.zeros((B, S, H, qa.shape[3]), jnp.float32)))
+                def cut(a, axis=0):
+                    return jax.lax.dynamic_slice_in_dim(
+                        a, t * group, group, axis)
+                sc, context = partial(qa[cut(slot_of)],
+                                      items(cut(where)), cut(sees))
+                # the items' own partials ...
+                m = jnp.max(sc, axis=-1)                  # [G, H, S]
+                p = jnp.exp(sc - m[..., None])
+                # ... folded into their slots' running state: item g
+                # weighs exp(m_g - new_top_b) in its slot b, 0 elsewhere
+                # (and exactly 0 where it saw no row: m_g = -1e30)
+                mine = cut(whose, 1)
+                new_top = jnp.maximum(top, jnp.max(
+                    jnp.where(mine, m[None], -1e30), axis=1))
+                keep = jnp.exp(top - new_top)
+                w = jnp.where(mine, jnp.exp(jnp.minimum(
+                    m[None] - new_top[:, None], 0.0)), 0.0)  # [B,G,H,S]
+                return (new_top,
+                        den * keep + jnp.einsum(
+                            "bghs,ghs->bhs", w, jnp.sum(p, axis=-1)),
+                        acc * per_ctx(keep) + jnp.einsum(
+                            "bghs,gshd->bshd", w, context(p),
+                            precision=jax.lax.Precision.HIGHEST))
+
+            _, den, acc = jax.lax.fori_loop(0, n_trips, item_trip, init)
+            # a slot without an item: 0 / 1
+            return jnp.where(den > 0, den, 1.0), acc
+
+        if n_chunks == 1:
+            sc, context = partial(
+                qa, window(0, table_rows),
+                jnp.arange(table_rows)[None, None, :]
+                <= q_end[:, :, None])
+            ctx = context(jax.nn.softmax(sc, axis=-1))
+        else:
+            init = (jnp.full((B, H, S), -1e30, jnp.float32),
+                    jnp.zeros((B, H, S), jnp.float32),
+                    jnp.zeros((B, S, H, qa.shape[3]), jnp.float32))
+            if B > 1:
+                den, acc = walk_items(init)
+            else:
+                live = jnp.clip((jnp.max(pos) + S + chunk - 1) // chunk,
+                                1, n_chunks)
+                _, den, acc = jax.lax.fori_loop(0, live, trip, init)
             ctx = acc / per_ctx(den)
         out = Tensor(ctx.astype(qa.dtype))
         if self.use_mp:
@@ -468,8 +526,7 @@ class GPTAttention(nn.Layer):
         rows = jnp.arange(qa.shape[0])
         k_buf = k_buf.at[rows, pos].set(ka[:, 0].astype(k_buf.dtype))
         v_buf = v_buf.at[rows, pos].set(va[:, 0].astype(v_buf.dtype))
-        out = self._slot_attn(qa, k_buf, v_buf, _fetch_rows,
-                              k_buf.shape[1], slot_attn_chunk(), pos)
+        out = self._slot_attn(qa, k_buf, v_buf, None, pos)
         return out, k_buf, v_buf
 
     @_scoped("attention")
@@ -519,10 +576,7 @@ class GPTAttention(nn.Layer):
                 ka[:, 0].astype(flat_k.dtype)).reshape(k_pool.shape)
             v_pool = flat_v.at[widx].set(
                 va[:, 0].astype(flat_v.dtype)).reshape(v_pool.shape)
-        out = self._slot_attn(qa, k_pool, v_pool,
-                              _fetch_blocks(block_tables),
-                              block_tables.shape[1] * bs,
-                              slot_attn_chunk(bs), pos)
+        out = self._slot_attn(qa, k_pool, v_pool, block_tables, pos)
         return out, k_pool, v_pool
 
     @_scoped("attention")
@@ -552,8 +606,7 @@ class GPTAttention(nn.Layer):
         cols = pos[:, None] + jnp.arange(W)[None, :]        # [B, W]
         k_buf = k_buf.at[rows, cols].set(ka.astype(k_buf.dtype))
         v_buf = v_buf.at[rows, cols].set(va.astype(v_buf.dtype))
-        out = self._slot_attn(qa, k_buf, v_buf, _fetch_rows,
-                              k_buf.shape[1], slot_attn_chunk(), pos)
+        out = self._slot_attn(qa, k_buf, v_buf, None, pos)
         return out, k_buf, v_buf
 
     @_scoped("attention")
@@ -600,10 +653,7 @@ class GPTAttention(nn.Layer):
                 ka.astype(flat_k.dtype)).reshape(k_pool.shape)
             v_pool = flat_v.at[widx].set(
                 va.astype(flat_v.dtype)).reshape(v_pool.shape)
-        out = self._slot_attn(qa, k_pool, v_pool,
-                              _fetch_blocks(block_tables),
-                              block_tables.shape[1] * bs,
-                              slot_attn_chunk(bs), pos)
+        out = self._slot_attn(qa, k_pool, v_pool, block_tables, pos)
         return out, k_pool, v_pool
 
     @_scoped("attention")
@@ -2604,6 +2654,8 @@ class GPTModel(ServedModel, nn.Layer):
                 or attn0.qkv_proj.weight._data.dtype
         emb = self.embeddings
         return ServingSpec(
+            decode_rows=functools.partial(
+                walk_rows, row_width=2 * attn0.num_heads * attn0.head_dim),
             kv=KVRowSpec.heads(len(self.blocks), attn0.num_heads,
                                attn0.head_dim, dtype),
             max_positions=emb.position_embeddings.weight.shape[0],

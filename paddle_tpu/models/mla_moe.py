@@ -69,15 +69,7 @@ from ..core.tensor import Tensor
 from ..nn import initializer as I
 from .programs import (
     KVRowSpec, ServedModel, ServingSpec, _scoped, sample_lanes,
-    slot_sample_keys)
-
-# cache rows one item of the blocked walk fetches (a whole number of
-# blocks): at 256 the v5e keeps an item's rows on chip (PERF.md, PR 26)
-_WALK_ROWS = 256
-# (slot, chunk) items one trip of the decode walk takes, never more
-# than there are slots: 32 x 256 rows are what a trip over all 32 slots
-# fetched, and a trip costs the same whatever its items (PERF.md, PR 30)
-_WALK_GROUP = 32
+    slot_sample_keys, walk_chunk, walk_group, walk_plan)
 
 # counters of the routed layers, in the order of the vector the step
 # programs return; (registry name under "serving.", dev.* span arg)
@@ -85,68 +77,6 @@ MOE_COUNTERS = (("moe_routed_pairs", "pairs"),
                 ("moe_experts_hit", "experts_hit"),
                 ("moe_expert_slots", None),
                 ("moe_load_max", None))
-
-
-def walk_chunk(table_rows, block_size):
-    """Rows of one item of ``MLAttention.attend``'s walk: a whole
-    number of blocks, of the order of ``_WALK_ROWS``, at most the
-    table."""
-    return min(table_rows, block_size * max(1, _WALK_ROWS // block_size))
-
-
-def walk_group(slots):
-    """(slot, chunk) items one trip of the decode walk takes over
-    ``slots`` slots."""
-    return min(_WALK_GROUP, slots)
-
-
-def walk_plan(pos, window, table_rows, chunk, group):
-    """The decode walk's work list, built on the device from ``pos``
-    alone: slot b gets ``n_b = ceil((pos_b + window) / chunk)`` items,
-    one for each ``chunk`` rows its queries see (rows ``< pos_b +
-    window``), and none at position 0 (a parked lane); the items lie
-    slot by slot in chunk order, by a cumulative sum.
-
-    pos int32 [B]; the rest static.  Returns ``(slot_of, chunk_of,
-    valid, n_trips)``: three arrays of the static length ``B *
-    ceil(table_rows / chunk)`` rounded up to whole trips of ``group``
-    items (item i is chunk ``chunk_of[i]`` of slot ``slot_of[i]``;
-    items past the list's end are not ``valid``), and the data trip
-    count ``ceil(sum(n_b) / group)``.  One program for every list."""
-    import jax.numpy as jnp
-    n_chunks = -(-table_rows // chunk)
-    n = jnp.where(pos > 0, jnp.minimum(
-        (pos + window + chunk - 1) // chunk, n_chunks), 0).astype(jnp.int32)
-    ends = jnp.cumsum(n)                                          # [B]
-    size = pos.shape[0] * n_chunks
-    item = jnp.arange(size + -size % group, dtype=jnp.int32)
-    valid = item < ends[-1]
-    # the slot of item i is the first whose items end past i
-    slot_of = jnp.minimum(jnp.sum(item[:, None] >= ends[None, :], axis=1,
-                                  dtype=jnp.int32), pos.shape[0] - 1)
-    chunk_of = jnp.where(valid, item - (ends - n)[slot_of], 0)
-    return slot_of, chunk_of, valid, (ends[-1] + group - 1) // group
-
-
-def walk_rows(pos, ahead, table_rows, block_size):
-    """Host twin of ``walk_plan`` for the engine's counters
-    (``ServingSpec.decode_rows``): the cache rows one decode dispatch
-    fetches over all slots when slot b's window ends at ``pos[b] +
-    ahead`` — ``trips x group x chunk``, the last trip's padding items
-    included; a table of at most one chunk is read whole by every
-    slot."""
-    import numpy as np
-    pos = np.asarray(pos, np.int64)
-    chunk = walk_chunk(table_rows, block_size)
-    if table_rows <= chunk or len(pos) == 1:
-        # one trip over every slot; one slot walks as the chunk
-        # program does, to the end of its own window
-        end = max(1, min(int(pos.max()) + ahead, table_rows))
-        return len(pos) * min(table_rows, -(-end // chunk) * chunk)
-    group = walk_group(len(pos))
-    items = int((-(-np.minimum(pos[pos > 0] + ahead, table_rows)
-                   // chunk)).sum())
-    return -(-items // group) * group * chunk
 
 
 def write_chunk_rows(pool, rows, table, pos, true_len, scratch):
@@ -461,13 +391,13 @@ class MLAttention(nn.Layer):
 
     def attend(self, q_n, q_r, pool, tables, pos, absorbed=None):
         """Causal attention of a window of queries over each slot's
-        cached rows, read through its block table ``_WALK_ROWS`` rows
+        cached rows, read through its block table ``walk_chunk`` rows
         at a time in the pool's dtype with float32 accumulation, one
         pass with a running maximum and denominator.
 
         Several slots (the decode program) are walked as a WORK LIST
         of (slot, chunk) items, ``walk_plan``: each slot is read to its
-        OWN window's end, ``_WALK_GROUP`` items a trip whatever slots
+        OWN window's end, ``walk_group`` items a trip whatever slots
         they belong to, ``ceil(items / group)`` trips read on the
         device.  An item's masked scores give a partial (maximum,
         denominator, context); a trip folds its items' partials into
@@ -1106,7 +1036,6 @@ class MLAMoEModel(ServedModel, nn.Layer):
             vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
             counters=MOE_COUNTERS,
             kernels={"moe.experts": grouped_matmul_impl()},
-            decode_rows=walk_rows,
             residual=({"streams": self.streams,
                        "sinkhorn_iters": cfg["hc_sinkhorn_iters"]}
                       if self.streams > 1 else None),

@@ -256,12 +256,86 @@ class KVRowSpec:
         return geo
 
 
-def _rows_to_the_longest(pos, ahead, table_rows, block_size):
-    """``ServingSpec.decode_rows`` of a walk that reads every slot as
-    far as the longest live window."""
-    from .gpt import slot_attn_chunk, slot_attn_rows
-    return len(pos) * slot_attn_rows(int(max(pos)) + ahead, table_rows,
-                                     slot_attn_chunk(block_size))
+# cache rows one item of a slot walk fetches (a whole number of
+# blocks): at 256 the v5e keeps an item's rows on chip (PERF.md, PR 26)
+_WALK_ROWS = 256
+# (slot, chunk) items one trip of a decode walk takes, never more than
+# there are slots: 32 x 256 rows are what a trip over all 32 slots
+# fetched, and a trip of narrow rows costs the same whatever its items
+# (PERF.md, PR 30)
+_WALK_GROUP = 32
+# cached numbers the items of one trip hold at most: 32 items of 256
+# rows 1,024 wide.  Past that a trip's time is its rows' way through
+# the vector unit, item for item, so a model whose rows are wider takes
+# fewer items a trip and pads its last trip with less (PERF.md, PR 43)
+_WALK_TRIP_SIZE = 8 << 20
+
+
+def walk_chunk(table_rows, block_size=None):
+    """Rows of one item of a model's walk over a slot's cached rows: a
+    whole number of blocks, of the order of ``_WALK_ROWS``, at most the
+    table (``block_size`` None: the contiguous layout, which has no
+    blocks)."""
+    bs = block_size or 1
+    return min(table_rows, bs * max(1, _WALK_ROWS // bs))
+
+
+def walk_group(slots, row_width=None):
+    """(slot, chunk) items one trip of the decode walk takes over
+    ``slots`` slots whose cache keeps ``row_width`` numbers a position
+    (None: rows narrow enough for the whole group)."""
+    fit = _WALK_GROUP if row_width is None \
+        else max(1, _WALK_TRIP_SIZE // (_WALK_ROWS * row_width))
+    return min(_WALK_GROUP, fit, slots)
+
+
+def walk_plan(pos, window, table_rows, chunk, group):
+    """The decode walk's work list, built on the device from ``pos``
+    alone: slot b gets ``n_b = ceil((pos_b + window) / chunk)`` items,
+    one for each ``chunk`` rows its queries see (rows ``< pos_b +
+    window``), and none at position 0 (a parked lane); the items lie
+    slot by slot in chunk order, by a cumulative sum.
+
+    pos int32 [B]; the rest static.  Returns ``(slot_of, chunk_of,
+    valid, n_trips)``: three arrays of the static length ``B *
+    ceil(table_rows / chunk)`` rounded up to whole trips of ``group``
+    items (item i is chunk ``chunk_of[i]`` of slot ``slot_of[i]``;
+    items past the list's end are not ``valid``), and the data trip
+    count ``ceil(sum(n_b) / group)``.  One program for every list."""
+    import jax.numpy as jnp
+    n_chunks = -(-table_rows // chunk)
+    n = jnp.where(pos > 0, jnp.minimum(
+        (pos + window + chunk - 1) // chunk, n_chunks), 0).astype(jnp.int32)
+    ends = jnp.cumsum(n)                                          # [B]
+    size = pos.shape[0] * n_chunks
+    item = jnp.arange(size + -size % group, dtype=jnp.int32)
+    valid = item < ends[-1]
+    # the slot of item i is the first whose items end past i
+    slot_of = jnp.minimum(jnp.sum(item[:, None] >= ends[None, :], axis=1,
+                                  dtype=jnp.int32), pos.shape[0] - 1)
+    chunk_of = jnp.where(valid, item - (ends - n)[slot_of], 0)
+    return slot_of, chunk_of, valid, (ends[-1] + group - 1) // group
+
+
+def walk_rows(pos, ahead, table_rows, block_size, row_width=None):
+    """Host twin of ``walk_plan`` for the engine's counters
+    (``ServingSpec.decode_rows``): the cache rows one decode dispatch
+    fetches over all slots when slot b's window ends at ``pos[b] +
+    ahead`` — ``trips x group x chunk``, the last trip's padding items
+    included (``row_width`` as ``walk_group`` takes it); a table of at
+    most one chunk is read whole by every slot."""
+    import numpy as np
+    pos = np.asarray(pos, np.int64)
+    chunk = walk_chunk(table_rows, block_size)
+    if table_rows <= chunk or len(pos) == 1:
+        # one trip over every slot; one slot walks as the chunk
+        # program does, to the end of its own window
+        end = max(1, min(int(pos.max()) + ahead, table_rows))
+        return len(pos) * min(table_rows, -(-end // chunk) * chunk)
+    group = walk_group(len(pos), row_width)
+    items = int((-(-np.minimum(pos[pos > 0] + ahead, table_rows)
+                   // chunk)).sum())
+    return -(-items // group) * group * chunk
 
 
 class StepSpec:
@@ -332,8 +406,9 @@ class ServingSpec:
                        the contiguous layout) — the host twin of the
                        trip count the program reads on the device,
                        behind ``serving.decode_rows_walked``.  Left
-                       out: every slot as far as the longest window,
-                       ``GPTAttention._slot_attn``'s rule
+                       out: ``walk_rows``, the work list of (slot,
+                       chunk) items that reads each slot to its own
+                       window's end
     ``step``           ``StepSpec`` of a model whose step is not one
                        row and one token a lane; None for one that is
     ``residual``       what ``/healthz`` says of a residual that is not
@@ -355,7 +430,7 @@ class ServingSpec:
         self.counters = tuple(counters)
         self.unsupported = dict(unsupported or {})
         self.kernels = dict(kernels or {})
-        self.decode_rows = decode_rows or _rows_to_the_longest
+        self.decode_rows = decode_rows or walk_rows
         self.step = step
         self.residual = dict(residual) if residual else None
 

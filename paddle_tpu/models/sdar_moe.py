@@ -69,10 +69,10 @@ from .. import nn
 from ..core.tensor import Tensor
 from ..nn import initializer as I
 from .mla_moe import (
-    MOE_COUNTERS, RMSNorm, RoutedFFN, _lin, rope, walk_chunk, walk_group,
-    walk_plan, walk_rows, write_chunk_rows)
+    MOE_COUNTERS, RMSNorm, RoutedFFN, _lin, rope, write_chunk_rows)
 from .programs import (
-    KVRowSpec, ServedModel, ServingSpec, StepSpec, _scoped)
+    KVRowSpec, ServedModel, ServingSpec, StepSpec, _scoped, walk_chunk,
+    walk_group, walk_plan, walk_rows)
 
 # the step's own counters, after the routed layers' four, in the order
 # of the vector the programs return: lane-passes of each state, the

@@ -1200,9 +1200,10 @@ class Engine:
             "serving.decode_rows_walked", "cache rows the XLA decode / "
             "verify dispatches walked, summed over slots: how far the "
             "slot-window attention goes is the served model's rule "
-            "(ServingSpec.decode_rows: every slot to the longest live "
-            "window rounded up to its chunk, or each to its own), "
-            "applied to the host's position mirror; over "
+            "(ServingSpec.decode_rows; models/programs.py walk_rows: "
+            "each slot to its own window's end, a work list of (slot, "
+            "chunk) items taken a whole trip at a time), applied to "
+            "the host's position mirror; over "
             "serving.decode_rows_table it is the share of the table "
             "read, 1.0 = every row of every slot")
         self._m_rows_table = reg.counter(

@@ -15,7 +15,7 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu import monitor, nn
 from paddle_tpu.distributed import moe
-from paddle_tpu.models import GPTModel, mla_moe
+from paddle_tpu.models import GPTModel, mla_moe, programs
 from paddle_tpu.models.mla_moe import MLAMoEModel
 from paddle_tpu.serving import Engine
 from paddle_tpu.serving.kvcache import KVRowSpec, per_shard_block_bytes
@@ -290,8 +290,8 @@ def test_absorbed_equals_expanded_over_the_paged_cache(window):
 def short_walk(monkeypatch):
     """Items of 16 rows, 4 a trip: tables of 64 rows are then four
     items long, and a few slots make several trips."""
-    monkeypatch.setattr(mla_moe, "_WALK_ROWS", 16)
-    monkeypatch.setattr(mla_moe, "_WALK_GROUP", 4)
+    monkeypatch.setattr(programs, "_WALK_ROWS", 16)
+    monkeypatch.setattr(programs, "_WALK_GROUP", 4)
 
 
 def _one_shot(attn, q_n, q_r, pool, tables, pos):
@@ -391,7 +391,7 @@ def test_the_work_list_is_the_item_rule(pos, table_rows, short_walk):
     once and in order; the host twin counts the same rows."""
     chunk, group = 16, min(4, len(pos))
     slot_of, chunk_of, valid, n_trips = (np.asarray(a) for a in
-                                         mla_moe.walk_plan(
+                                         programs.walk_plan(
         jnp.asarray(pos, jnp.int32), 1, table_rows, chunk, group))
     n = [min(-(-(p + 1) // chunk), -(-table_rows // chunk)) if p else 0
          for p in pos]
@@ -400,7 +400,7 @@ def test_the_work_list_is_the_item_rule(pos, table_rows, short_walk):
     assert len(valid) % group == 0 and len(valid) >= sum(n)
     assert list(zip(slot_of[valid], chunk_of[valid])) == [
         (b, c) for b, k in enumerate(n) for c in range(k)]
-    assert mla_moe.walk_rows(np.asarray(pos), 1, table_rows, 8) \
+    assert programs.walk_rows(np.asarray(pos), 1, table_rows, 8) \
         == int(n_trips) * group * chunk
 
 
@@ -428,25 +428,31 @@ def test_the_rows_counters_add_up_to_the_rule(short_walk):
         eng.submit(tokens(k, seed=k)[0].tolist(), max_new_tokens=7)
     eng.run_until_idle()
     assert seen
-    walked = sum(mla_moe.walk_rows(p, a, 128, 8) for p, a in seen)
+    walked = sum(programs.walk_rows(p, a, 128, 8) for p, a in seen)
     live = sum(int(np.minimum(p[p > 0] + a, 128).sum()) for p, a in seen)
     assert _decode_rows(eng) == (walked, live, len(seen) * 4 * 128)
     assert live <= walked < len(seen) * 4 * 128
     rows = [e["args"]["rows"] for e in eng.chrome_trace()["traceEvents"]
             if e["name"] == "decode.dispatch"]
-    assert rows == [mla_moe.walk_rows(p, a, 128, 8) // 4 for p, a in seen]
+    assert rows == [programs.walk_rows(p, a, 128, 8) // 4 for p, a in seen]
 
 
-def test_gpt_counts_the_rows_it_counted():
-    """``GPTModel`` leaves ``decode_rows`` out: every slot as far as
-    the longest window, the numbers the parent's engine read for the
-    same requests (recorded there)."""
+def test_gpt_counts_by_the_same_rule():
+    """``GPTModel`` and the latent model both count by ``walk_rows``,
+    the one item rule; GPT hands it the width of a position's K and V,
+    by which its trips are sized (to the longest window these requests
+    read 23,808 rows of 46,080; the parent's run)."""
     paddle.seed(0)
     model = GPTModel.from_config("tiny", max_position=1024, dropout=0.0)
     model.eval()
     rule = model.serving_spec().decode_rows
-    assert rule(np.asarray([5, 0, 300]), 2, 2048, 16) == 3 * 512
-    assert rule(np.asarray([5, 0, 300]), 2, 2048, None) == 3 * 512
+    assert rule.func is programs.walk_rows
+    assert rule.keywords == {"row_width": 2 * 4 * 16}
+    assert MLAMoEModel(DIMS).serving_spec().decode_rows \
+        is programs.walk_rows
+    # 1 + 2 items of 3 a trip: one trip of three chunks
+    assert rule(np.asarray([5, 0, 300]), 2, 2048, 16) == 3 * 256
+    assert rule(np.asarray([5, 0, 300]), 2, 2048, None) == 3 * 256
     eng = Engine(model, num_slots=3, max_seq_len=1024, kv_block_size=8,
                  kv_blocks=160, prefill_chunk=64,
                  registry=monitor.StatRegistry())
@@ -455,8 +461,8 @@ def test_gpt_counts_the_rows_it_counted():
         eng.submit(rng.integers(1, 128, k).tolist(), max_new_tokens=6)
     eng.run_until_idle()
     walked, live, table = _decode_rows(eng)
-    assert (walked, table) == (23808, 46080)      # the parent's run
-    assert 0 < live < walked
+    assert table == 46080
+    assert 0 < live <= walked < 23808
 
 
 def test_the_form_is_chosen_from_the_shape():

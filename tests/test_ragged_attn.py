@@ -391,8 +391,8 @@ def test_latent_pool_is_updated_in_place_on_the_v5e():
     import jax
     import jax.numpy as jnp
     from paddle_tpu.distributed.moe import grouped_matmul
-    from paddle_tpu.models.mla_moe import (
-        MLAttention, walk_chunk, walk_group)
+    from paddle_tpu.models.mla_moe import MLAttention
+    from paddle_tpu.models.programs import walk_chunk, walk_group
 
     with _described_v5e() as sds:
         text = _latent_step_text(
@@ -548,6 +548,69 @@ def test_block_rows_are_written_in_place_on_the_v5e():
                               body)) == 1
         assert not re.findall(
             r"= bf16\[[\d,]+\]\S* (?:reshape|copy|transpose)\(", body)
+
+
+def test_gpt_decode_walks_a_work_list_on_the_v5e():
+    """GPT's paged decode attention at the widths ``gpt3-1.3b-serve``
+    runs (16 heads of 128, hidden 2,048, 32 slots, tables of 128 blocks
+    of 16 rows, K and V pools of 3,073 blocks), compiled for the
+    compile-only ``TPU v5 lite`` device: ONE loop a layer, the walk,
+    whose trip count the compiler does not know (it is read from
+    ``pos``: ``ceil(items / group)``); no gather fetches more cached
+    rows than one trip's group of (slot, chunk) items (8 of them here:
+    K and V are 4,096 numbers a position, four times the width a trip
+    of 32 items is sized for); no float32 value is as large as a pool,
+    the largest being one trip's rows; nothing copies or transposes a
+    pool.  (To the longest window the loop ran ``max`` and not ``sum /
+    group`` trips, 83-86% of both GPT cells' device time; ledger,
+    PR 42.)"""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu import nn
+    from paddle_tpu.jit import _swapped
+    from paddle_tpu.models.gpt import GPTAttention
+    from paddle_tpu.models.programs import walk_chunk, walk_group
+
+    with nn.LazyGuard():
+        attn = GPTAttention(2048, 16, dropout=0.0)
+    attn.to(dtype="bfloat16")
+    params = dict(attn.named_parameters())
+    names = sorted(params)
+    pool_shape = (3073, 16, 16, 128)
+
+    def step(p_list, x, k_pool, v_pool, tables, pos):
+        with _swapped(params, dict(zip(names, p_list))):
+            out, k_pool, v_pool = attn.decode_slots_paged(
+                paddle.Tensor(x), k_pool, v_pool, tables, pos)
+        return out._data, k_pool, v_pool
+
+    with _described_v5e() as sds:
+        text = jax.jit(step, donate_argnums=(2, 3)).lower(
+            [sds(params[n].shape) for n in names], sds((32, 1, 2048)),
+            sds(pool_shape), sds(pool_shape), sds((32, 128), jnp.int32),
+            sds((32,), jnp.int32)).compile().as_text()
+    loops = [ln for ln in text.splitlines() if " while(" in ln]
+    assert len(loops) == 1 and "known_trip_count" not in loops[0]
+    # a trip takes its items' queries by their slots: the work list
+    body = _computation(text, re.search(
+        r" while\(.*body=%?([\w.\-]+)", loops[0]).group(1))
+    called = "\n".join(_computation(text, name) for name in
+                       re.findall(r"calls=%?([\w.\-]+)", body))
+    group = walk_group(32, 2 * 16 * 128)
+    assert group == 8        # K and V are 4,096 numbers a position
+    assert re.findall(r"= bf16\[%d,16,128\]\S* gather\(" % group,
+                      body + called)
+
+    def sizes(pattern):
+        return [int(np.prod([int(d) for d in dims.split(",")]))
+                for dims in re.findall(pattern, text)]
+    rows = group * walk_chunk(2048, 16)
+    fetched = sizes(r"= bf16\[([\d,]+)\]\S* gather\(")
+    assert fetched and max(fetched) == rows * 16 * 128
+    f32 = sizes(r"= f32\[([\d,]+)\]")
+    assert f32 and max(f32) == rows * 16 * 128 < np.prod(pool_shape)
+    assert not re.findall(r"= bf16\[3073,16,16,128\]\S* (?:copy|transpose)\(",
+                          text)
 
 
 # -- knob validation --------------------------------------------------
