@@ -271,14 +271,21 @@ def softmax_topk_routing(logits, k, normalize=True):
     return choice.astype(jnp.int32), w
 
 
-def sort_pairs_by_expert(choice, live, num_experts):
+def sort_pairs_by_expert(choice, live, num_experts, first=None):
     """Sort the T x k (token, expert) pairs by expert.  ``live`` [T]
     marks the rows that are real tokens: the pairs of the others go to
-    the end and belong to no group, so they hit no expert.  Returns
-    (order [P] pair indices in sorted order, group_sizes [E] int32);
-    the token of sorted row i is ``order[i] // k``."""
+    the end and belong to no group, so they hit no expert.  ``first``
+    (None: every expert the router knows is here) says the groups are
+    the experts ``[first, first + num_experts)`` of a wider router:
+    a pair routed outside them goes to the end as a dead row's does.
+    Returns (order [P] pair indices in sorted order, group_sizes [E]
+    int32); the token of sorted row i is ``order[i] // k``."""
     t, k = choice.shape
-    flat = jnp.where(live[:, None], choice, num_experts).reshape(t * k)
+    here = live[:, None]
+    if first is not None:
+        choice = choice - first
+        here = here & (choice >= 0) & (choice < num_experts)
+    flat = jnp.where(here, choice, num_experts).reshape(t * k)
     order = jnp.argsort(flat, stable=True)
     sizes = jnp.zeros((num_experts + 1,), jnp.int32).at[flat].add(1)
     return order.astype(jnp.int32), sizes[:num_experts]
@@ -289,7 +296,8 @@ def _gmm_tiling(m, k, n):
     grouped product: all of a short m in one tile (a decode step's
     pairs: every hit expert then costs one pass over its own weights),
     128 rows otherwise; the whole contraction in one k tile (up to
-    2,048); the whole of n in one tile where the contraction's tile
+    2,048, and up to 3,072 beside an n tile of at most 768, PR 44);
+    the whole of n in one tile where the contraction's tile
     is at most 1,024 and n at most 2,048, else the widest n tile of
     those tried that divides n.  Chip run, PR 28, 40 experts hit, m 192:
     [2,048 -> 2,816] 0.65 ms at (192, 2048, 1408) against 0.72 at
@@ -312,8 +320,20 @@ def _gmm_tiling(m, k, n):
     0.717 ms at (128, 1024, 1792) against 0.343 / 0.744 at n tile 512,
     0.346 / 0.734 at (128, 512, 3584), no fit at (128, 1024, 3584): 81%
     / 82% and 80% / 80% of the weights' time by the host's clock;
-    ``ragged_dot`` 0.907 / 3.279 and 0.469 / 1.706 ms."""
+    ``ragged_dot`` 0.907 / 3.279 and 0.469 / 1.706 ms.  Chip run, PR 44
+    (``_chip/gmm_bench.py``), 32 held experts of a router of 256, a
+    contraction of 3,072, m 128 with 8 pairs on 7 experts / m 1,024
+    with 128 pairs on all 32: the whole contraction in one tile beside
+    an n tile of 768 fits and wins, [3,072 -> 6,144] 0.391 / 1.644 ms at
+    (m, 3072, 768) against 0.418 / 1.776 at the rule's (m, 1024, 1536),
+    0.403 / 1.710 at (m, 512 or 768, 3072); [3,072 -> 3,072] 0.223 /
+    0.839 against 0.235 / 0.905: 83% / 90% and 72% / 88% of the
+    weights' time; n tiles of 512 and 1,024 within 1% of 768;
+    ``ragged_dot`` 0.579 / 4.167 and 0.348 / 2.095 ms."""
     tm = m if m <= 256 else 128
+    if 2048 < k <= 3072:
+        return tm, k, next(t for t in (768, 512, 256, 128, n)
+                           if n % t == 0)
     tk = k if k <= 2048 else next(
         t for t in (2048, 1024, 512, 256, 128, k) if k % t == 0)
     tn = n if tk <= 1024 and n <= 2048 else next(
@@ -370,11 +390,14 @@ def grouped_matmul(lhs, rhs, group_sizes, impl=None):
     return jnp.where(in_group[:, None], out, 0.0)
 
 
-def dropless_experts(x, choice, weights, live, w_in, w_out):
+def dropless_experts(x, choice, weights, live, w_in, w_out, first=None):
     """Every (token, selected expert) pair through its expert's gated
     feed-forward, nothing dropped: ``y_t = sum_j weights[t, j] *
     E_choice[t, j](x_t)`` with ``E_e(x) = (silu(x W1_e) * (x W3_e))
-    W2_e``.
+    W2_e``.  With ``first`` the stacks hold the experts ``[first,
+    first + E)`` of a wider router (this chip's share under expert
+    parallelism): the pairs that fall on them are computed, the others
+    add nothing, and nothing stands in for the chips that hold them.
 
     x [T, D]; choice / weights [T, k]; live [T] bool (rows that are
     tokens); w_in [E, D, 2F] holds W1 | W3 side by side, w_out
@@ -382,7 +405,7 @@ def dropless_experts(x, choice, weights, live, w_in, w_out):
     computed, experts hit, the busiest expert's pairs)."""
     t, k = choice.shape
     e, f = w_in.shape[0], w_out.shape[1]
-    order, sizes = sort_pairs_by_expert(choice, live, e)
+    order, sizes = sort_pairs_by_expert(choice, live, e, first)
     rows = x[order // k]                                   # [P, D]
     a = grouped_matmul(rows, w_in, sizes)                  # [P, 2F]
     act = (jax.nn.silu(a[:, :f]) * a[:, f:]).astype(x.dtype)

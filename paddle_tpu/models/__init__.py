@@ -1,12 +1,13 @@
 """Model zoo: language models (GPT, a latent-attention / routed-experts
-decoder, a block-diffusion routed-experts decoder, BERT) + vision
-re-exports."""
+decoder, a block-diffusion routed-experts decoder, a decoder of
+sliding-window and full-attention layers, BERT) + vision re-exports."""
 from .gpt import (  # noqa: F401
     GPTModel, GPTBlock, GPTEmbeddings, GPTLMHead, GPTPretrainingCriterion,
     GPT_CONFIGS, gpt_pipe_model,
 )
 from .mla_moe import MLAMoEModel  # noqa: F401
 from .sdar_moe import SDARMoEModel  # noqa: F401
+from .afmoe import AfmoeModel  # noqa: F401
 from .programs import (  # noqa: F401
     KVRowSpec, ServedModel, ServingSpec, StepSpec)
 from .bert import (  # noqa: F401

@@ -246,13 +246,28 @@ class RoutedFFN(nn.Layer):
     ``n_shared`` 0 — the softmax top-k layer that has neither
     (``models/sdar_moe.py``).  ``forward`` returns ``(y, stats)``,
     ``stats`` int32 [3]: pairs computed, experts hit, the busiest
-    expert's pairs."""
+    expert's pairs.
+
+    ``held = (first, count)`` is one chip's share of a layer that
+    several chips hold by expert parallelism (``models/afmoe.py``):
+    the router keeps its ``num_experts`` columns, the stacks hold the
+    ``count`` experts from ``first``, the pairs that fall on them are
+    computed here and the others add nothing (their chips add them),
+    the shared expert is whole; ``stats`` gets a fourth number, the
+    live pairs that fell elsewhere.  None: every expert is held."""
 
     def __init__(self, hidden, width, num_experts, top_k, n_shared,
-                 scale, normalize=True, gate="sigmoid"):
+                 scale, normalize=True, gate="sigmoid", held=None):
         super().__init__()
         if gate not in ("sigmoid", "softmax"):
             raise ValueError(f"gate {gate!r}: 'sigmoid' or 'softmax'")
+        if held is not None and not (
+                0 <= held[0] and 0 < held[1]
+                and held[0] + held[1] <= num_experts):
+            raise ValueError(f"held {held!r}: (first, count) inside "
+                             f"the router's {num_experts} experts")
+        self.held = None if held is None else (int(held[0]), int(held[1]))
+        stacked = num_experts if held is None else self.held[1]
         self.num_experts, self.top_k = num_experts, top_k
         self.scale, self.normalize = float(scale), bool(normalize)
         self.gate = gate
@@ -263,9 +278,9 @@ class RoutedFFN(nn.Layer):
             self.gate_bias = self.create_parameter(
                 [num_experts], is_bias=True)
         self.experts_in = self.create_parameter(
-            [num_experts, hidden, 2 * width], default_initializer=init)
+            [stacked, hidden, 2 * width], default_initializer=init)
         self.experts_out = self.create_parameter(
-            [num_experts, width, hidden], default_initializer=init)
+            [stacked, width, hidden], default_initializer=init)
         self.shared = (GatedMLP(hidden, n_shared * width) if n_shared
                        else None)
 
@@ -288,7 +303,16 @@ class RoutedFFN(nn.Layer):
         from ..distributed.moe import dropless_experts
         return dropless_experts(x, choice, weights, live,
                                 self.experts_in._data,
-                                self.experts_out._data)
+                                self.experts_out._data,
+                                None if self.held is None else self.held[0])
+
+    @_scoped("moe.held")
+    def elsewhere(self, choice, live):
+        """Live pairs whose expert another chip holds, int32 []."""
+        import jax.numpy as jnp
+        first, count = self.held
+        away = (choice < first) | (choice >= first + count)
+        return jnp.sum(away & live[:, None], dtype=jnp.int32)
 
     @_scoped("moe.shared")
     def shared_expert(self, x):
@@ -298,6 +322,9 @@ class RoutedFFN(nn.Layer):
         """x [T, D]; live [T] bool, the rows that are tokens."""
         choice, weights = self.route(x)
         y, stats = self.experts(x, choice, weights, live)
+        if self.held is not None:
+            import jax.numpy as jnp
+            stats = jnp.append(stats, self.elsewhere(choice, live))
         y = y.astype(x.dtype)
         if self.shared is not None:
             y = y + self.shared_expert(x)

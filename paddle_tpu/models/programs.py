@@ -289,23 +289,41 @@ def walk_group(slots, row_width=None):
     return min(_WALK_GROUP, fit, slots)
 
 
-def walk_plan(pos, window, table_rows, chunk, group):
+def walk_first(pos, reach, chunk):
+    """The first chunk a slot's walk fetches where a query sees only
+    the ``reach`` rows that end with its own: the earliest query of the
+    window stands at ``pos`` and sees rows ``> pos - reach``.  Works on
+    traced and on host integers alike."""
+    return (pos + 1 - reach).clip(0) // chunk
+
+
+def walk_plan(pos, window, table_rows, chunk, group, reach=None):
     """The decode walk's work list, built on the device from ``pos``
     alone: slot b gets ``n_b = ceil((pos_b + window) / chunk)`` items,
     one for each ``chunk`` rows its queries see (rows ``< pos_b +
     window``), and none at position 0 (a parked lane); the items lie
-    slot by slot in chunk order, by a cumulative sum.
+    slot by slot in chunk order, by a cumulative sum.  With a
+    ``reach`` (a sliding-window layer: a query sees the ``reach`` rows
+    that end with its own) slot b's items start at chunk
+    ``walk_first(pos_b)`` and not at 0, and the list is as much
+    shorter.
 
     pos int32 [B]; the rest static.  Returns ``(slot_of, chunk_of,
     valid, n_trips)``: three arrays of the static length ``B *
-    ceil(table_rows / chunk)`` rounded up to whole trips of ``group``
-    items (item i is chunk ``chunk_of[i]`` of slot ``slot_of[i]``;
-    items past the list's end are not ``valid``), and the data trip
-    count ``ceil(sum(n_b) / group)``.  One program for every list."""
+    ceil(table_rows / chunk)`` (with a ``reach``: ``B`` times the most
+    chunks ``reach + window - 1`` rows can touch) rounded up to whole
+    trips of ``group`` items (item i is chunk ``chunk_of[i]`` of slot
+    ``slot_of[i]``; items past the list's end are not ``valid``), and
+    the data trip count ``ceil(sum(n_b) / group)``.  One program for
+    every list."""
     import jax.numpy as jnp
     n_chunks = -(-table_rows // chunk)
     n = jnp.where(pos > 0, jnp.minimum(
         (pos + window + chunk - 1) // chunk, n_chunks), 0).astype(jnp.int32)
+    if reach is not None:
+        first = jnp.minimum(walk_first(pos, reach, chunk), n)
+        n = n - first
+        n_chunks = min(n_chunks, (reach + window - 2) // chunk + 2)
     ends = jnp.cumsum(n)                                          # [B]
     size = pos.shape[0] * n_chunks
     item = jnp.arange(size + -size % group, dtype=jnp.int32)
@@ -314,16 +332,20 @@ def walk_plan(pos, window, table_rows, chunk, group):
     slot_of = jnp.minimum(jnp.sum(item[:, None] >= ends[None, :], axis=1,
                                   dtype=jnp.int32), pos.shape[0] - 1)
     chunk_of = jnp.where(valid, item - (ends - n)[slot_of], 0)
+    if reach is not None:
+        chunk_of = jnp.where(valid, chunk_of + first[slot_of], 0)
     return slot_of, chunk_of, valid, (ends[-1] + group - 1) // group
 
 
-def walk_rows(pos, ahead, table_rows, block_size, row_width=None):
+def walk_rows(pos, ahead, table_rows, block_size, row_width=None,
+              reach=None):
     """Host twin of ``walk_plan`` for the engine's counters
     (``ServingSpec.decode_rows``): the cache rows one decode dispatch
     fetches over all slots when slot b's window ends at ``pos[b] +
     ahead`` — ``trips x group x chunk``, the last trip's padding items
-    included (``row_width`` as ``walk_group`` takes it); a table of at
-    most one chunk is read whole by every slot."""
+    included (``row_width`` as ``walk_group`` takes it; ``reach`` as
+    ``walk_plan`` does); a table of at most one chunk is read whole by
+    every slot."""
     import numpy as np
     pos = np.asarray(pos, np.int64)
     chunk = walk_chunk(table_rows, block_size)
@@ -331,10 +353,16 @@ def walk_rows(pos, ahead, table_rows, block_size, row_width=None):
         # one trip over every slot; one slot walks as the chunk
         # program does, to the end of its own window
         end = max(1, min(int(pos.max()) + ahead, table_rows))
-        return len(pos) * min(table_rows, -(-end // chunk) * chunk)
+        last = -(-end // chunk)
+        if reach is not None and table_rows > chunk:
+            last -= min(int(walk_first(pos.max(), reach, chunk)), last)
+        return len(pos) * min(table_rows, last * chunk)
     group = walk_group(len(pos), row_width)
-    items = int((-(-np.minimum(pos[pos > 0] + ahead, table_rows)
-                   // chunk)).sum())
+    live = pos[pos > 0]
+    n = -(-np.minimum(live + ahead, table_rows) // chunk)
+    if reach is not None:
+        n = n - np.minimum(walk_first(live, reach, chunk), n)
+    items = int(n.sum())
     return -(-items // group) * group * chunk
 
 
@@ -416,12 +444,23 @@ class ServingSpec:
                        n, "sinkhorn_iters": k}``: the streams live
                        inside the step programs, the engine never sees
                        them); None for one that is
+    ``attention``      what ``/healthz`` says of a model whose layers
+                       do not all see the whole context (``{"window":
+                       w, "layers": {"sliding": n, "full": m}}``: the
+                       window lives in the step programs' walks and
+                       masks, the engine keeps every row of every
+                       layer in ONE block table a slot); None for one
+                       whose layers are of one kind
+    ``experts``        what ``/healthz`` says of a model that holds a
+                       share of its routed experts (``{"held": [first,
+                       count], "of": the router's width}``); None for
+                       one that holds them all or has none
     """
 
     def __init__(self, kv, max_positions, vocab_size, hidden_size,
                  tensor_parallel=False, counters=(), unsupported=None,
                  kernels=None, decode_rows=None, step=None,
-                 residual=None):
+                 residual=None, attention=None, experts=None):
         self.kv = kv
         self.max_positions = int(max_positions)
         self.vocab_size = int(vocab_size)
@@ -433,6 +472,8 @@ class ServingSpec:
         self.decode_rows = decode_rows or walk_rows
         self.step = step
         self.residual = dict(residual) if residual else None
+        self.attention = dict(attention) if attention else None
+        self.experts = dict(experts) if experts else None
 
 
 class ServedModel:

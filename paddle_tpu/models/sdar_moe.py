@@ -72,7 +72,7 @@ from .mla_moe import (
     MOE_COUNTERS, RMSNorm, RoutedFFN, _lin, rope, write_chunk_rows)
 from .programs import (
     KVRowSpec, ServedModel, ServingSpec, StepSpec, _scoped, walk_chunk,
-    walk_group, walk_plan, walk_rows)
+    walk_first, walk_group, walk_plan, walk_rows)
 
 # the step's own counters, after the routed layers' four, in the order
 # of the vector the programs return: lane-passes of each state, the
@@ -95,10 +95,15 @@ def _first_masked(masked):
 
 class GQAttention(nn.Layer):
     """Grouped-query attention with per-head q/k norms under the
-    block-causal mask (module docstring)."""
+    block-causal mask (module docstring).  What another family's layer
+    sets (``models/afmoe.py``): ``reach``, a sliding window (position i
+    sees j only where ``i - j < reach``; None: back to row 0), under
+    which every walk starts at the first chunk a query can see;
+    ``rotary`` False for a layer without positions; a ``block_length``
+    of 1 is the causal mask."""
 
     def __init__(self, hidden, num_heads, num_kv_heads, head_dim,
-                 rope_theta, eps, block_length):
+                 rope_theta, eps, block_length, reach=None, rotary=True):
         super().__init__()
         if num_heads % num_kv_heads:
             raise ValueError(
@@ -107,6 +112,7 @@ class GQAttention(nn.Layer):
         self.num_heads, self.num_kv_heads = num_heads, num_kv_heads
         self.head_dim, self.theta = head_dim, float(rope_theta)
         self.block_length = block_length
+        self.reach, self.rotary = reach, bool(rotary)
         self.q_proj = nn.Linear(hidden, num_heads * head_dim,
                                 bias_attr=False)
         self.k_proj = nn.Linear(hidden, num_kv_heads * head_dim,
@@ -128,9 +134,41 @@ class GQAttention(nn.Layer):
                                          self.head_dim)
         v = _lin(self.v_proj, h).reshape(B, S, self.num_kv_heads,
                                          self.head_dim)
+        if not self.rotary:
+            return self.q_norm(q), self.k_norm(k), v
         at = pos[:, :, None]
         return (rope(self.q_norm(q), at, self.theta),
                 rope(self.k_norm(k), at, self.theta), v)
+
+    def output(self, ctx, h):
+        """The layer's output from the heads' contexts ``ctx``
+        [B, S, H hd] (``h`` is the layer's input, for a subclass whose
+        output is gated by it)."""
+        return _lin(self.o_proj, ctx)
+
+    def one_slot_span(self, pos, chunk, trips):
+        """(first, end) chunks of ONE slot's walk over the rows below
+        ``pos`` [1]: to the chunk that holds row ``pos - 1``, from
+        chunk 0 or, under a ``reach``, from ``walk_first``."""
+        import jax.numpy as jnp
+        end = jnp.clip((jnp.max(pos) + chunk - 1) // chunk, 0, trips)
+        if self.reach is None:
+            return 0, end
+        return jnp.minimum(walk_first(jnp.max(pos), self.reach, chunk),
+                           end), end
+
+    def mask(self, blk):
+        """Which of a step's or a chunk's own S rows see which, from
+        their blocks ``blk = arange(S) // block_length`` (the first
+        row's position is a multiple of the block): row s sees row t
+        iff ``blk[t] <= blk[s]`` and, under a ``reach``, ``s - t <
+        reach``.  bool [S, S]."""
+        import jax.numpy as jnp
+        sees = blk[None, :] <= blk[:, None]
+        if self.reach is not None:
+            at = jnp.arange(blk.shape[0])
+            sees = sees & (at[:, None] - at[None, :] < self.reach)
+        return sees
 
     def cache_rows(self, k, v):
         """k, v [..., K, hd] -> the rows a pool keeps, [..., 2 K hd],
@@ -155,7 +193,11 @@ class GQAttention(nn.Layer):
         ``walk_group`` items a trip; a slot at position 0 (a parked
         lane, or a prompt shorter than a block) has no item.  One slot
         (the chunk program) walks its own chunks in turn.  A table of
-        at most one chunk is read whole, without a loop.
+        at most one chunk is read whole, without a loop.  Under a
+        ``reach`` row s sees the cached rows ``> pos + s - reach``
+        only: the work list and the one-slot walk start at the first
+        chunk the slot's first row sees (``walk_first``), and the mask
+        is exact inside the first and the last chunk.
 
         q [B, S, H, hd]; new [B, S, 2 K hd] (``cache_rows``); pool
         [NB, bs, W], W >= 2 K hd; tables int32 [B, L // bs]; pos int32
@@ -217,19 +259,33 @@ class GQAttention(nn.Layer):
             sees = (at[None, :] < pos[:, None]) \
                 & (at >= c * chunk)[None, :]
             return fold(carry, *partial(qg, rows_of(blocks),
-                                        sees[:, None, :]))
+                                        per_row(sees, at, pos)))
+
+        def per_row(sees, at, start, cut=lambda a: a):
+            """``sees`` [b, n] (which of the fetched rows, at rows
+            ``at`` [n] or [b, n], lie below a slot's first row
+            ``start`` [b]) for each of the slot's S rows, [b, 1 or S,
+            n]: row s reaches back to ``start + s - reach``.  ``cut``
+            takes a trip's items out of ``at`` and ``start``."""
+            if self.reach is None:
+                return sees[:, None, :]
+            back = cut(start)[:, None] + jnp.arange(S)[None, :] \
+                - self.reach
+            return sees[:, None, :] & (cut(at)[..., None, :]
+                                       > back[:, :, None])
 
         def walk_items(init):
-            group = walk_group(B)
+            group = walk_group(B, pool.shape[2])
             slot_of, chunk_of, valid, n_trips = walk_plan(
-                pos, 0, table_rows, chunk, group)
+                pos, 0, table_rows, chunk, group, self.reach)
             n_chunks = -(-table_rows // chunk)
             whole = jnp.pad(tables, ((0, 0), (
                 0, n_chunks * chunk // bs - tables.shape[1])))
             cols = whole.reshape(B * n_chunks, chunk // bs)[
                 slot_of * n_chunks + chunk_of]           # [N, chunk//bs]
             at = (chunk_of * chunk)[:, None] + jnp.arange(chunk)[None, :]
-            sees = (at < pos[slot_of][:, None]) & valid[:, None]  # [N, n]
+            start = pos[slot_of]
+            sees = (at < start[:, None]) & valid[:, None]        # [N, n]
             whose = ((slot_of[None, :] == jnp.arange(B)[:, None])
                      & valid[None, :])[..., None, None, None]
 
@@ -241,7 +297,7 @@ class GQAttention(nn.Layer):
                         a, t * group, group, axis)
                 sc, context = partial(
                     qg[cut(slot_of)], rows_of(cut(cols)),
-                    cut(sees)[:, None, :])
+                    per_row(cut(sees), at, start, cut))
                 # the items' own partials, folded into their slots'
                 # running state: item i weighs exp(m_i - new_top_b) in
                 # its slot b, 0 elsewhere (and exactly 0 where it saw
@@ -269,16 +325,15 @@ class GQAttention(nn.Layer):
         init = fold((jnp.full((B, K, g, S), -1e30, jnp.float32),
                      jnp.zeros((B, K, g, S), jnp.float32),
                      jnp.zeros((B, S, K, g, hd), jnp.float32)),
-                    *partial(qg, new,
-                             (blk[None, :] <= blk[:, None])[None]))
+                    *partial(qg, new, self.mask(blk)[None]))
         trips = -(-table_rows // chunk)
         if trips == 1:
             _, den, acc = trip(0, init)
         elif B > 1:
             _, den, acc = walk_items(init)
         else:
-            live = jnp.clip((jnp.max(pos) + chunk - 1) // chunk, 0, trips)
-            _, den, acc = jax.lax.fori_loop(0, live, trip, init)
+            _, den, acc = jax.lax.fori_loop(
+                *self.one_slot_span(pos, chunk, trips), trip, init)
         return (acc / per_ctx(den)).astype(q.dtype).reshape(B, S, H * hd)
 
     @_scoped("attention")
@@ -311,7 +366,7 @@ class GQAttention(nn.Layer):
             pool = jax.lax.dynamic_update_slice(
                 pool, stored[b:b + 1], (blocks[b], offs[b], 0))
         out = self.attend(q, new, pool, tables, walk_pos)
-        return _lin(self.o_proj, out), pool
+        return self.output(out, h), pool
 
     @_scoped("attention")
     def prefill_chunk_paged(self, h, pool, table, pos, true_len,
@@ -331,7 +386,7 @@ class GQAttention(nn.Layer):
                                 scratch)
         out = self.attend(q, new, pool, table[None, :],
                           jnp.reshape(pos, (1,)))
-        return _lin(self.o_proj, out), pool
+        return self.output(out, h), pool
 
     def forward(self, h):
         """Uncached block-causal attention over whole sequences, h
@@ -348,11 +403,11 @@ class GQAttention(nn.Layer):
             k).astype(jnp.float32) / math.sqrt(self.head_dim)
         blk = jnp.arange(S) // self.block_length
         p = jax.nn.softmax(jnp.where(
-            (blk[None, :] <= blk[:, None])[None, None, None], sc, -1e30),
+            self.mask(blk)[None, None, None], sc, -1e30),
             axis=-1).astype(h.dtype)
         ctx = jnp.einsum("bkgsn,bnkd->bskgd", p, v)
-        return _lin(self.o_proj,
-                    ctx.reshape(B, S, self.num_heads * self.head_dim))
+        return self.output(
+            ctx.reshape(B, S, self.num_heads * self.head_dim), h)
 
 
 class SDARMoEBlock(nn.Layer):

@@ -3103,6 +3103,8 @@ class Engine:
                 "kernels": self._serving_spec.kernels,
                 "step": self.step_report(),
                 "residual": self._serving_spec.residual,
+                "attention": self._serving_spec.attention,
+                "experts": self._serving_spec.experts,
                 "async_depth": self.async_depth,
                 "tracing": bool(self.tracer.enabled),
                 "preemption": self._preemption,

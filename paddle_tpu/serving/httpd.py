@@ -389,6 +389,13 @@ class _Handler(JsonHandler):
                 # None for the plain one
                 "residual": getattr(getattr(eng, "_serving_spec", None),
                                     "residual", None),
+                # layers that do not all see the whole context, and a
+                # share of the routed experts (ServingSpec.attention,
+                # .experts), None for a model with neither
+                "attention": getattr(getattr(eng, "_serving_spec", None),
+                                     "attention", None),
+                "experts": getattr(getattr(eng, "_serving_spec", None),
+                                   "experts", None),
                 # async-loop signals, next to the router-tier load
                 # signals: pipeline depth plus the mean overlapped
                 # host time and mean blocking d2h wait per tick —
