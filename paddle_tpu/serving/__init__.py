@@ -51,7 +51,8 @@ splits its ``host_ms`` (the tick less its waits for the device) into
 ``cpu_ms`` (Python its thread ran) and ``wait_ms`` (it stood still:
 the interpreter lock, a sink lock, the scheduler), its phases
 (``admit``, ``chunk.plan``, ``prefill.chunk``, ``prefill.d2h``,
-``state.push``, ``ring.drain``, ``dispatch``, ``decode.dispatch``,
+``state.push``, ``state.patch``, ``first_token``, ``ring.drain``,
+``dispatch``, ``decode.dispatch``,
 ``consume``, ``decode.emit``) carry ``cpu_ms`` where a read of that
 clock is cheap or ``trace_annotations`` is on, the HTTP edge's
 ``http.ingest`` and ``http.stream`` (one a streamed response) theirs,

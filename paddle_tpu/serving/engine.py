@@ -120,6 +120,24 @@ def _spanned(name, phase=False):
     return deco
 
 
+# why the async loop still consumes its ring to empty (``ring.drain``'s
+# ``why``, ``serving.ring_drains.<why>``): each is a true sync, where
+# the host needs the tokens in flight before it can go on.  A dirty
+# slot is none of them: its lanes are patched in dispatch order
+# (``Engine._patch_state``)
+_RING_DRAINS = {
+    "spec": "drafting reads the accepted tokens",
+    "tail": "every lane ends inside the ticks in flight",
+    "idle": "every slot was freed under the newest dispatch",
+    "preempt": "a victim's emitted tokens are requeued with it",
+    "migrate": "an export gathers the rows the host has consumed",
+    "adapter": "a bank lane flips under no dispatched tick",
+}
+
+# slots one patch program rewrites (shorter lists repeat their first
+# row, longer ones take more calls): a tick seldom dirties more
+_PATCH_ROWS = 4
+
 # a read of the thread's CPU clock dearer than this (us) is kept off
 # the phases' spans unless the tracer annotates (Engine._phase_cpu)
 _PHASE_CPU_MAX_READ_US = 1.0
@@ -217,7 +235,7 @@ class _InflightTick:
 
     __slots__ = ("tick", "kind", "slots", "reqs", "arrays", "batch",
                  "layout", "dispatched_at", "cursors", "spec_lanes",
-                 "meta_lanes")
+                 "meta_lanes", "dropped")
 
     def __init__(self, tick, kind, slots, arrays, batch, layout,
                  cursors, spec_lanes=None, meta_lanes=None):
@@ -237,6 +255,10 @@ class _InflightTick:
         self.meta_lanes = meta_lanes  # ragged dispatch: per listed
         #   slot (mode, width, lanes) as of dispatch — same
         #   must-not-re-read rule as spec_lanes
+        self.dropped = set()          # slots the HOST freed after this
+        #   dispatch was queued (a first token that ended its request):
+        #   their lanes are live on the device, and consume drops what
+        #   they computed instead of calling it drift
 
     def meta(self):
         """JSON-able metadata for the flight recorder / debug
@@ -431,13 +453,22 @@ class Engine:
         downloads ids + done-mask bytes and never forces an early
         sync.  The device cursor state is double-buffered: the
         in-flight tick holds the buffer it chained from while
-        ``_dev_state`` tracks the newest handles; admissions /
-        evictions / chunks dirty only the HOST mirrors (the next
-        buffer), and a dirty event drains the pipeline before the
-        mirrors are re-uploaded — recovery and parity semantics are
-        unchanged, and greedy streams are token-identical to
-        ``async_depth=1`` (which keeps today's synchronous tick
-        bit-for-bit).  Speculative mode consumes before drafting
+        ``_dev_state`` tracks the newest handles; an admission, an
+        eviction, a chunk's progress or a final chunk's first token
+        writes the HOST mirrors (the engine's truth of consumed ticks)
+        and marks its slot dirty, and the slot's lanes reach the
+        device as a small patch program queued behind the decodes in
+        flight (``state.patch``; ``serving.state_patches``): no dirty
+        event consumes the ring, the mirrors are uploaded whole once
+        (``state.push``; ``serving.state_pushes`` 1 in a healthy run),
+        and because one device runs its queue in order the streams
+        are token-identical to ``async_depth=1`` (the synchronous
+        tick, which queues the same patches over nothing in flight).
+        The first token of a prefill is picked on the device and read
+        (4 bytes) behind the decode that follows it.  What still
+        empties the ring are the true syncs (``ring.drain`` says
+        ``why``: ``spec``, ``tail``, ``idle``, ``preempt``,
+        ``migrate``, ``adapter``).  Speculative mode consumes before drafting
         (draft windows are data-dependent on the previous window's
         accepted tokens), so its overlap is limited to planning.
         Watch ``serving.tick_overlap_ms``
@@ -481,7 +512,8 @@ class Engine:
         over many ticks, the counters below, are to be read.)
         The phases the idle tables name carry their own ``cpu_ms``
         (``admit``, ``chunk.plan``, ``prefill.chunk``,
-        ``prefill.d2h``, ``state.push``, ``ring.drain``, ``dispatch``,
+        ``prefill.d2h``, ``state.push``, ``state.patch``,
+        ``first_token``, ``ring.drain``, ``dispatch``,
         ``decode.dispatch``, ``consume``, ``decode.emit``) where a read
         of the thread's CPU clock is cheap (under 1 us:
         ``monitor.tracing.thread_clock_read_us``, asked once at
@@ -1161,6 +1193,24 @@ class Engine:
         # prefill_chunk is off)
         self._m_chunks = reg.counter(
             "serving.prefill_chunks", "chunked-prefill dispatches")
+        # how the device-resident step state follows the host: whole
+        # uploads (1 after warm-up in a healthy run: the first tick's,
+        # then one a step-failure rebuild) beside the per-slot patch
+        # programs queued behind the decodes in flight, and what still
+        # empties the ring, by reason
+        self._m_state_pushes = reg.counter(
+            "serving.state_pushes", "whole-state uploads of the step "
+            "state (the first tick, and the rebuild after a failed "
+            "step)")
+        self._m_state_patches = reg.counter(
+            "serving.state_patches", "per-slot state patch programs "
+            "queued in dispatch order (admission, chunk progress, a "
+            "first token, eviction)")
+        self._m_ring_drains = {
+            why: reg.counter(
+                f"serving.ring_drains.{why}", "times the in-flight "
+                f"ring was consumed to empty: {what}")
+            for why, what in _RING_DRAINS.items()}
         self._m_stall = reg.histogram(
             "serving.decode_stall_ms", "gap between consecutive decode "
             "dispatches while slots were decoding — the time decoders "
@@ -1384,6 +1434,8 @@ class Engine:
         #   tick reads DELTAS to keep the occupancy gauge exact without
         #   re-locking the scheduler after the decode dispatch
         self._insert_fn = None
+        self._state_fns = None  # (unpack, patch, {sampled: first}):
+        #   _state_programs
         self._fused_fn = None   # resolved fused decode+sample handle
         self._fused_spec_fn = None  # fused verify+sample/accept handle
         self._p_arrays = None   # lazy snapshots of param/buffer handles
@@ -1572,8 +1624,10 @@ class Engine:
                           "device_kind": devs[0].device_kind,
                           "device_ids": [d.id for d in devs]}
         # host-side per-slot step state: MIRRORS of the
-        # device-resident cursors, re-uploaded only when an admission /
-        # eviction / chunk dirties them (_push_state)
+        # device-resident cursors, the engine's truth of CONSUMED
+        # ticks.  An admission / eviction / chunk marks its slot dirty
+        # and the slot's lanes are patched on the device in dispatch
+        # order (_patch_state); the whole is uploaded once (_push_state)
         self._pos = np.zeros(self.num_slots, np.int32)
         self._cur_tok = np.zeros(
             (self.num_slots, self._step.rows if self._step else 1),
@@ -1600,9 +1654,17 @@ class Engine:
         # per-slot LoRA lane (0 = base model); mirrors like the rest
         self._aid = np.zeros(self.num_slots, np.int32)
         self._dev_state = None   # device handles of the step state
-        self._state_dirty = True  # device copies stale vs the mirrors
-        self._stats_pending = []  # chunk programs' counter vectors,
-        #   not yet downloaded (they ride the next tick's download)
+        #   (None: the next dispatch uploads the mirrors whole)
+        self._dirty_slots = set()  # slots whose device lanes are stale
+        #   vs the mirrors: patched before the next dispatch
+        self._first_pending = []  # prefills' first tokens not yet
+        #   picked: (slot, request, logits handle); the next state sync
+        #   queues their pick on the device (_pick_first_tokens)...
+        self._first_picked = []   # ...and these wait to be read, once
+        #   the decode behind them is queued: (slot, request, id handle)
+        self._stats_pending = []  # (tick, handle): chunk programs'
+        #   counter vectors, read with the first consumed tick that was
+        #   dispatched after them (ready by then: one device, in order)
         self._ring = []  # dispatched-but-unconsumed ticks, oldest
         #   first (async_depth > 1); recovery and shutdown clear it —
         #   the dropped handles die with the rebuilt pools
@@ -3142,7 +3204,7 @@ class Engine:
                     # BEFORE recovery evicts and rebuilds
                     "async": {
                         "async_depth": self.async_depth,
-                        "state_dirty": bool(self._state_dirty),
+                        "dirty_slots": sorted(self._dirty_slots),
                         "in_flight": [inf.meta()
                                       for inf in list(self._ring)],
                         "next_buffer": {
@@ -3240,7 +3302,17 @@ class Engine:
     def _release_slot_kv(self, i):
         """Return slot i's block references (eviction path): cached
         prefix blocks fall back to the cache's reference and stay
-        resident; decode-span blocks free."""
+        resident; decode-span blocks free.
+
+        Decodes already queued may still write one row each into these
+        blocks (the slot's lane is parked by a patch queued BEHIND
+        them, ``_park_state``), and the pool may hand the blocks to
+        this very tick's admission.  That is sound only because one
+        device runs its queue in order: the new owner's chunk program
+        is queued after those decodes, rewrites every row it reads and
+        masks by its own ``pos`` all that lies past it, and a block
+        that entered the prefix cache holds whole blocks below ``pos``
+        only, which no frozen or dropped lane writes."""
         if not self._paged:
             return
         self.block_pool.decref(self._slot_blocks[i])
@@ -3333,8 +3405,9 @@ class Engine:
         mirrors (admission): temperature 0 marks a greedy lane, the
         seed words feed the on-device key derivation, and the rng
         counter restarts at 0 — so two engines given the same seed
-        emit the same sampled tokens.  Dirtying the mirrors makes the
-        next tick re-upload them.
+        emit the same sampled tokens.  The slot is marked dirty: its
+        lanes (and the table row ``_bind_kv_plan`` installs) reach the
+        device as a patch queued before the next dispatch.
 
         A GREEDY request's lane binds CONSTANT zero seed words, not
         its id-derived default seed: its draw is discarded (argmax),
@@ -3373,15 +3446,18 @@ class Engine:
         # LoRA lane: which adapter this slot decodes through (0 =
         # base).  Data like everything else here — never a retrace.
         self._aid[i] = req._adapter_id
-        self._state_dirty = True
+        self._dirty_slots.add(i)
 
     def _park_state(self, i):
         """Park slot i's step + sampling lanes (eviction): frozen
         zeros keep the inactive row's (discarded) compute in-bounds
-        and greedy-cheap until the next admission overwrites them; the
-        dirty flag makes the next tick re-upload the
-        corrected cursors — a mid-window eviction may have advanced
-        the device cursor further than the host consumed."""
+        and greedy-cheap until the next admission overwrites them.
+        The slot is marked dirty, so the zeros (and the scratch row
+        ``_release_slot_kv`` left in its table) are patched in behind
+        the decodes in flight — a mid-window eviction may have
+        advanced the device cursor further than the host consumed;
+        what those decodes computed for the lane is dropped at
+        consume."""
         self._pos[i] = 0
         self._cur_tok[i] = 0
         self._flags[i] = 0
@@ -3394,7 +3470,7 @@ class Engine:
         self._eos[i] = -1
         self._rem[i] = 0  # rem 0 = the device freezes this lane
         self._aid[i] = 0  # parked compute runs the base lane (zeros)
-        self._state_dirty = True
+        self._dirty_slots.add(i)
 
     def _rows_walked(self, width=1):
         """Rows of a slot's table the XLA slot-window attention walks
@@ -3415,68 +3491,187 @@ class Engine:
         self._m_rows_table.inc(self.max_seq_len * self.num_slots)
         return walked // self.num_slots
 
-    def _push_state(self):
-        """Upload the state mirrors as the device-resident step
-        state: runs only when an admission / eviction / chunk
-        dirtied them — a steady-state tick reuses the handles the last
-        dispatch returned and uploads NOTHING.  The pipeline must be
-        drained first: the mirrors only reflect CONSUMED ticks, so
-        uploading them under an un-consumed dispatch would rewind
-        every other slot's device cursor by a tick."""
-        assert not self._ring, \
-            "_push_state with ticks in flight — drain the ring first"
-        import jax.numpy as jnp
-        # transfer from PRIVATE COPIES: the PJRT CPU client may run
-        # the host->device copy asynchronously, so handing it the live
-        # mirror races any mirror write that lands before the enqueued
-        # dispatch executes — concretely, the ragged chunk lanes
-        # advance self._pos right after dispatch, and the in-flight
-        # transfer would intermittently capture the POST-chunk cursor
-        # as the pre-state (observed as nondeterministic corruption)
-        if self._repl_sharding is not None:
-            # mesh-sharded engine: every [num_slots]-leading cursor
-            # row-shards over 'dp' (each dp shard owns ITS slots'
-            # cursors and block-table rows; at dp == 1 the spec
-            # degenerates to replication over 'mp') — an uncommitted
-            # single-device upload would make the first dispatch
-            # re-shard them.  The placement is a cross-shard barrier,
-            # traced as shard.sync so its cost is visible in
-            # trace_view --wall
-            import jax
-            state_sh = self._state_sharding or self._repl_sharding
-
-            def put(a):
-                return jax.device_put(a.copy(), state_sh)
-            sync = (self.tracer.span("shard.sync",
-                                     shards=self.mp * self.dp,
-                                     mp=self.mp, dp=self.dp)
-                    if self.mp * self.dp > 1 else nullcontext())
-        else:
-            def put(a):
-                return jnp.asarray(a.copy())
-            sync = nullcontext()
-        mirrors = dict(
-            tok=self._cur_tok, pos=self._pos, ctr=self._sctr,
-            temp=self._temp, topk=self._topk, topp=self._topp,
-            slo=self._seed_lo, shi=self._seed_hi, eos=self._eos,
-            rem=self._rem)
+    def _state_mirrors(self):
+        """``(key, mirror)`` of every lane of the step state, in the
+        order ``_state_rows`` packs them and the device programs
+        unpack them."""
+        lanes = [("tok", self._cur_tok), ("pos", self._pos),
+                 ("ctr", self._sctr), ("temp", self._temp),
+                 ("topk", self._topk), ("topp", self._topp),
+                 ("slo", self._seed_lo), ("shi", self._seed_hi),
+                 ("eos", self._eos), ("rem", self._rem)]
         if self.adapters is not None:
-            mirrors["aid"] = self._aid
+            lanes.append(("aid", self._aid))
         if self._step is not None:
-            mirrors["flags"] = self._flags
+            lanes.append(("flags", self._flags))
         if self._paged:
-            mirrors["tables"] = self._block_tables
             # per-slot scratch block ids (constant per engine config,
             # but rides the state dict so the ragged dispatch
             # signature stays uniform): masked/parked lanes park in
             # their OWN dp shard's scratch row
-            mirrors["scratch"] = self._slot_scratch
-        with self.tracer.span(
-                "state.push", cpu=self._phase_cpu,
-                bytes=sum(int(a.nbytes) for a in mirrors.values())), \
-                sync:
-            self._dev_state = {k: put(a) for k, a in mirrors.items()}
-        self._state_dirty = False
+            lanes += [("tables", self._block_tables),
+                      ("scratch", self._slot_scratch)]
+        return lanes
+
+    def _state_rows(self, slots):
+        """Slots' lanes packed from the mirrors as ONE int32 matrix,
+        a row a slot: the slot index, then every lane of
+        ``_state_mirrors`` (float32 and uint32 lanes as their bit
+        patterns).  A private copy, so a mirror write that lands
+        before the (asynchronous) transfer has run cannot reach the
+        device."""
+        idx = np.asarray(slots, np.int32)
+        return np.concatenate(
+            [idx[:, None]]
+            + [m[idx].view(np.int32).reshape(len(idx), -1)
+               for _, m in self._state_mirrors()], axis=1)
+
+    def _state_programs(self):
+        """The small programs that keep the device-resident step
+        state, built once an engine: ``unpack(rows) -> state`` (the
+        whole upload, split on the device), ``patch(state, rows) ->
+        state`` (``_PATCH_ROWS`` slots' lanes replaced where they lie;
+        the state donated) and ``first[sampled](state, logits, slot)
+        -> (state, id)`` (a prefill's first token picked from its
+        last-position logits and written into the slot's ``tok``
+        lane).  Slots and values are data, never a retrace.  The pick
+        is two programs, chosen by the request: the sampling tail
+        (two sorts over the vocabulary) takes the TPU's compiler
+        ~20 s, which an engine that serves greedy requests never
+        pays; like ``sample_rows`` before it, the sampled one is
+        compiled by the first sampled request.  Under a mesh all
+        return the state's own shardings, so no dispatch re-shards."""
+        if self._state_fns is not None:
+            return self._state_fns
+        import jax
+        import jax.numpy as jnp
+        from ..models.gpt import sample_rows
+        fields = [(key, m[0].size, m.dtype, m.ndim)
+                  for key, m in self._state_mirrors()]
+
+        def lanes(rows):
+            out, o = {}, 1
+            for key, width, dtype, ndim in fields:
+                col = rows[:, o:o + width]
+                o += width
+                if dtype != np.int32:
+                    col = jax.lax.bitcast_convert_type(col, dtype)
+                out[key] = col if ndim == 2 else col[:, 0]
+            return out
+
+        def patch(state, rows):
+            idx = rows[:, 0]
+            return {k: state[k].at[idx].set(v)
+                    for k, v in lanes(rows).items()}
+
+        kw_state, kw_first = {}, {}
+        if self._repl_sharding is not None:
+            repl = self._repl_sharding
+            sh = {f[0]: self._state_sharding or repl for f in fields}
+            kw_state = dict(out_shardings=sh)
+            kw_first = dict(out_shardings=(sh, repl))
+
+        def pick(sampled):
+            def first(state, logits, slot):
+                # argmax for a greedy request (the first maximum, as
+                # np.argmax), else the draw every other path makes:
+                # the lane's filters and fold(request_key, ctr) with
+                # the counter of the token BEING picked (the patch
+                # ahead of this program already counts it)
+                def lane(k):
+                    return jax.lax.dynamic_slice(state[k], (slot,), (1,))
+                row = logits.astype(jnp.float32).reshape(1, -1)
+                ids = (sample_rows(row, lane("temp"), lane("topk"),
+                                   lane("topp"), lane("slo"),
+                                   lane("shi"), lane("ctr") - 1)
+                       if sampled else
+                       jnp.argmax(row, axis=-1).astype(jnp.int32))
+                tok = jax.lax.dynamic_update_slice(
+                    state["tok"], ids[:, None], (slot, 0))
+                return dict(state, tok=tok), ids
+            first.__name__ = first.__qualname__ = (
+                "first_token_sampled" if sampled else "first_token")
+            return jax.jit(first, donate_argnums=(0,), **kw_first)
+
+        for fn, name in ((lanes, "state_unpack"), (patch, "state_patch")):
+            fn.__name__ = fn.__qualname__ = name
+        self._state_fns = (
+            jax.jit(lanes, **kw_state),
+            jax.jit(patch, donate_argnums=(0,), **kw_state),
+            {sampled: pick(sampled) for sampled in (False, True)})
+        return self._state_fns
+
+    def _push_state(self):
+        """Upload the state mirrors whole as the device-resident step
+        state: the first dispatch of an engine, and the rebuild after
+        ``_reset_pools`` (a failed step) — nothing else.  ONE packed
+        transfer (``_state_rows`` of every slot), split into the
+        state's arrays on the device.  A steady-state tick reuses the
+        handles the last dispatch returned and uploads nothing; a
+        dirty slot is patched (``_patch_state``).  The ring is empty
+        in both cases, and has to be: the mirrors only reflect
+        CONSUMED ticks, so uploading them under an un-consumed
+        dispatch would rewind every live slot's device cursor by a
+        tick."""
+        assert not self._ring, \
+            "_push_state with ticks in flight: patch the dirty slots"
+        import jax
+        unpack, _, _ = self._state_programs()
+        rows = self._state_rows(range(self.num_slots))
+        # mesh-sharded engine: every [num_slots]-leading cursor
+        # row-shards over 'dp' (each dp shard owns ITS slots' cursors
+        # and block-table rows; at dp == 1 the spec degenerates to
+        # replication over 'mp'), which the unpack program's output
+        # shardings say.  The placement is a cross-shard barrier,
+        # traced as shard.sync so its cost is visible in
+        # trace_view --wall
+        sync = (self.tracer.span("shard.sync",
+                                 shards=self.mp * self.dp,
+                                 mp=self.mp, dp=self.dp)
+                if self.mp * self.dp > 1 else nullcontext())
+        with self.tracer.span("state.push", cpu=self._phase_cpu,
+                              bytes=int(rows.nbytes)), sync:
+            if self._repl_sharding is not None:
+                rows = jax.device_put(rows, self._repl_sharding)
+            self._dev_state = unpack(rows)
+        self._dirty_slots.clear()
+        self._m_state_pushes.inc()
+
+    def _patch_state(self):
+        """Queue the dirty slots' lanes as patch programs behind
+        whatever is in flight: each rewrites ``_PATCH_ROWS`` slots'
+        entries of the (donated) state from the mirrors and touches
+        no other slot, so a live lane's cursor is never rewound and
+        nothing has to be consumed first.  One device runs its queue
+        in order: a patch queued after decode N and before decode N+1
+        is seen by N+1 and not by N, which is what consuming the ring
+        and uploading the mirrors gave."""
+        _, patch, _ = self._state_programs()
+        slots = sorted(self._dirty_slots)
+        self._dirty_slots.clear()
+        with self.tracer.span("state.patch", cpu=self._phase_cpu,
+                              slots=len(slots)) as sp:
+            nbytes = 0
+            for o in range(0, len(slots), _PATCH_ROWS):
+                part = slots[o:o + _PATCH_ROWS]
+                part += part[:1] * (_PATCH_ROWS - len(part))
+                rows = self._state_rows(part)
+                nbytes += int(rows.nbytes)
+                self._dev_state = patch(self._dev_state, rows)
+                self._m_state_patches.inc()
+            sp.args["bytes"] = nbytes
+
+    def _sync_state(self):
+        """Bring the device's step state up to the mirrors before a
+        dispatch reads it: the whole upload where there is none yet,
+        else a patch of the dirty slots; then the first tokens that
+        wait to be picked (their lanes patched just now)."""
+        if self._dev_state is None:
+            self._push_state()
+        elif self._dirty_slots:
+            self._patch_state()
+        if self._first_pending:
+            self._pick_first_tokens()
 
     def _prefill_paged(self, slot):
         """Paged admission prefill: ONE jitted dispatch gathers the
@@ -3488,7 +3683,6 @@ class Engine:
         import jax.numpy as jnp
         req = slot.request
         ctx, fresh, m = self._bind_kv_plan(slot)
-        i = slot.index
         blocks = ctx + fresh
         tokens = req.context  # prompt, or the frozen resume snapshot
         s = len(tokens)
@@ -3514,8 +3708,7 @@ class Engine:
         self._m_prefill_tokens.inc(s_tail)
         slot.pos = s
         slot.prefilled = s
-        self._pos[i] = s
-        self._emit(slot, self._first_token(req, last0))
+        self._queue_first_token(slot, last0)
 
     def _prefill(self, slot):
         """Admission prefill: one jitted whole-prompt forward (shared
@@ -3563,8 +3756,7 @@ class Engine:
         self._m_prefill_tokens.inc(s)
         slot.pos = s
         slot.prefilled = s
-        self._pos[i] = s
-        self._emit(slot, self._first_token(req, last0))
+        self._queue_first_token(slot, last0)
 
     # -- budgeted chunked prefill (prefill_chunk=...) ------------------
     def _begin_chunked(self, slot):
@@ -3613,15 +3805,15 @@ class Engine:
         slot.pos = target
         slot.prefilled = len(req.context)
         self._pos[i] = target
-        self._state_dirty = True
+        self._dirty_slots.add(i)
 
     def _run_chunk(self, slot, n):
         """One chunk dispatch: compute K/V (and, on the final chunk,
         the first-token logits) for prompt positions
-        ``[prefilled, prefilled + n)``.  Returns None while chunks are
-        left, else the tokens the final chunk emitted: the request's
-        first, or none where the first step makes it
-        (``StepSpec``)."""
+        ``[prefilled, prefilled + n)``.  Returns True while chunks are
+        left.  The final chunk's first token is queued, not read
+        (``_queue_first_token``); where the first step makes it
+        (``StepSpec``) the lane opens its first step."""
         import jax.numpy as jnp
         req = slot.request
         i = slot.index
@@ -3665,21 +3857,22 @@ class Engine:
                     jnp.asarray(n, jnp.int32),
                     *self._lora_args_slot(req))
                 stats = []
-            # a chunk's counters wait for the next download (the
-            # device runs in order: they are ready by then)
-            self._stats_pending += stats
+            # a chunk's counters wait for the download of the decode
+            # queued behind it (the device runs in order: they are
+            # ready by then)
+            self._stats_pending += [(self.tick_no, h) for h in stats]
             self._dev_note(fn.kind, last0, n=n, req=req.id, stats=stats)
         slot.prefilled = p0 + n
         slot.pos = slot.prefilled
         self._m_chunks.inc()
         self._m_prefill_tokens.inc(n)
-        self._state_dirty = True  # the device cursors must re-park on
-        #   the chunk's new start row before the next fused tick
+        self._dirty_slots.add(i)  # a patch moves the lane's cursor
+        #   before the next fused tick reads it
         if slot.prefilled < s:
             # still PREFILLING: re-park the decode dispatch's garbage
             # write on the next chunk's start row
             self._pos[i] = slot.prefilled
-            return None
+            return True
         # final chunk: the context's full blocks become adoptable and
         # the last real position's logits sample the first token (TTFT
         # on a fresh admission; the NEXT stream token on a resume)
@@ -3689,25 +3882,24 @@ class Engine:
                                      self._slot_blocks[i][:s // self._bs])
         if self._step is not None:
             self._open_step(slot)
-            return 0
-        self._pos[i] = s
-        self._emit(slot, self._first_token(req, last0))
-        return 1
+        else:
+            self._queue_first_token(slot, last0)
+        return False
 
     def _prefill_chunked(self, prefilling):
         """Spend at most ``tick_token_budget`` prompt tokens on prefill
         chunks: round-robin over the PREFILLING slots (admission order,
         so partially-prefilled prompts resume before fresh ones start),
-        one chunk per slot per pass.  Returns (tokens_emitted,
-        newly_decoding_slots, evicted_count) — newly-decoding slots
-        join this same tick's decode dispatch, exactly like monolithic
-        prefill's emit-then-decode."""
+        one chunk per slot per pass.  A slot whose final chunk ran is
+        DECODING from the next snapshot on and joins this same tick's
+        decode dispatch, exactly like monolithic prefill's
+        emit-then-decode; its first token is read behind that
+        dispatch (``_emit_first_tokens``)."""
         from collections import deque
         budget = self._tick_budget
-        emitted, newly, evicted = 0, [], 0
         queue = deque(prefilling)
         while queue and budget > 0:
-            slot = queue.popleft()
+            slot = queue[0]
             req = slot.request
             n = min(self._chunk,
                     self._prefill_target(req) - slot.prefilled)
@@ -3715,50 +3907,75 @@ class Engine:
                 break  # strict per-tick cap (budget >= chunk, so a
                 #        tick's FIRST chunk always fits: progress is
                 #        guaranteed, the cap only defers later chunks)
-            n_first = self._run_chunk(slot, n)
+            queue.popleft()
             budget -= n
-            if n_first is None:
+            if self._run_chunk(slot, n):
                 queue.append(slot)
-            else:
-                emitted += n_first
-                if slot.request is not None:
-                    newly.append(slot)
-                else:
-                    evicted += 1  # EOS / max_new_tokens on first token
-        return emitted, newly, evicted
+        # a prompt the budget left waiting keeps its lane where the
+        # last chunk parked it: every decode moves a live lane on by a
+        # row, and the garbage row it writes belongs on the NEXT
+        # chunk's start row, which that chunk rewrites
+        self._dirty_slots.update(s.index for s in queue)
 
-    def _first_token(self, req, last0):
-        """Download the prefill's last-position logits and pick the
-        request's first token.  The download waits for the prefill
-        program (and whatever was queued ahead of it on the device):
-        ``prefill.d2h``, counted as time blocked on the device."""
-        with _DeviceWait(self, self.tracer.span(
-                "prefill.d2h", cpu=self._phase_cpu, req=req.id)):
-            row = np.asarray(last0, np.float32)[0]
-        return self._pick(req, row)
+    def _queue_first_token(self, slot, last0):
+        """A prefill's (or the final chunk's) last-position logits are
+        on their way: queue the request's first token instead of
+        waiting for them.  The mirrors take the lane as it stands once
+        that token is emitted (cursor on the context's end, the
+        counter and the budget moved by one), so the patch before the
+        next dispatch carries it, ``_pick_first_tokens`` writes the id
+        the device picks into the lane's ``tok``, and the decode that
+        follows is queued at once; ``_emit_first_tokens`` reads the id
+        (4 bytes, not a ``[V]`` row) behind that dispatch."""
+        req, i = slot.request, slot.index
+        self._pos[i] = slot.pos
+        self._sctr[i] = len(req.generated) + 1
+        self._rem[i] = max(req.remaining - 1, 0)
+        self._dirty_slots.add(i)
+        self._first_pending.append((slot, req, last0))
 
-    def _pick(self, req, row):
-        """First-token pick from the one [V] logits row that prefill
-        (or the final chunk) returned: argmax for a greedy request,
-        else the SAME lane filters and key derivation as the fused
-        dispatches (``models.gpt.sample_rows`` — one process-wide
-        compile) — so token
-        i of a request draws from fold(request_key, i) whether
-        prefill, a one-token tick, or a verify-window lane emitted it,
-        and a seed reproduces across engine restarts."""
-        if not req.do_sample:
-            return int(np.argmax(row))
-        import jax.numpy as jnp
-        from ..models.gpt import sample_rows
-        lo, hi = req.seed_words()
-        ids = sample_rows(
-            jnp.asarray(row, jnp.float32)[None, :],
-            jnp.asarray([req.temperature], jnp.float32),
-            jnp.asarray([req.top_k], jnp.int32),
-            jnp.asarray([req.top_p], jnp.float32),
-            jnp.asarray([lo], jnp.uint32), jnp.asarray([hi], jnp.uint32),
-            jnp.asarray([len(req.generated)], jnp.int32))
-        return int(np.asarray(ids)[0])
+    def _pick_first_tokens(self):
+        """Queue the ``first_token`` program for every first token
+        that waits for its pick: argmax for a greedy request, else the
+        SAME lane filters and key derivation as the fused dispatches
+        (``models.gpt.sample_rows``), read from the slot's own lanes —
+        so token i of a request draws from fold(request_key, i)
+        whether prefill, a one-token tick, or a verify-window lane
+        emitted it, and a seed reproduces across engine restarts."""
+        _, _, first = self._state_programs()
+        pending, self._first_pending = self._first_pending, []
+        for slot, req, last0 in pending:
+            with self.tracer.span("state.patch", cpu=self._phase_cpu,
+                                  first_token=slot.index):
+                self._dev_state, ids = first[req.do_sample](
+                    self._dev_state, last0, np.int32(slot.index))
+            self._first_picked.append((slot, req, ids))
+
+    def _emit_first_tokens(self):
+        """Read and emit the first tokens queued this tick (called with
+        the decode behind them already dispatched, or with nothing in
+        flight on the synchronous paths, where they are picked here).
+        The read waits for the prefill program and whatever was queued
+        ahead of it, not for the decode behind it: ``prefill.d2h``,
+        counted as time blocked on the device.  A first token that
+        ends its request (EOS, ``max_new_tokens`` 1) parks the slot
+        like any eviction; the decode lane it cost is dropped at
+        consume.  Returns the tokens emitted."""
+        if not (self._first_pending or self._first_picked):
+            return 0
+        with self.tracer.span("first_token", cpu=self._phase_cpu):
+            if self._first_pending:
+                self._sync_state()
+            picked, self._first_picked = self._first_picked, []
+            for slot, req, ids in picked:
+                with _DeviceWait(self, self.tracer.span(
+                        "prefill.d2h", cpu=self._phase_cpu, req=req.id)):
+                    tok = int(np.asarray(ids)[0])
+                self._emit(slot, tok)
+                if slot.request is None:
+                    for inf in self._ring:
+                        inf.dropped.add(slot.index)
+        return len(picked)
 
     def _emit(self, slot, tok):
         """Record one generated token; finish + evict on EOS or
@@ -3908,8 +4125,7 @@ class Engine:
         lanes = np.zeros(self.num_slots, np.int32)
         for slot in active:
             lanes[slot.index] = slot.spec_lanes
-        if self._state_dirty or self._dev_state is None:
-            self._push_state()
+        self._sync_state()
         st = self._dev_state
         if self._fused_spec_fn is None:
             self._fused_spec_fn, _, _ = \
@@ -4018,7 +4234,7 @@ class Engine:
                                           inf.spec_lanes):
                 i = slot.index
                 if slot.request is not req:
-                    if not done[i]:
+                    if not done[i] and i not in inf.dropped:
                         raise RuntimeError(
                             f"async stop-condition drift: slot {i} "
                             f"was evicted on the host but tick "
@@ -4049,17 +4265,16 @@ class Engine:
     def _dispatch_decode(self, active, tr):
         """DISPATCH one fused decode+sample tick without
         consuming it: the step state lives on
-        device between ticks (re-uploaded only when admissions /
-        evictions / chunks dirtied the mirrors — which requires an
-        empty pipeline, see ``_push_state``), sampling AND the stop
+        device between ticks (admissions / evictions / chunks patch
+        their own slot's lanes in behind the ticks in flight, see
+        ``_sync_state``), sampling AND the stop
         condition run inside the dispatch, and the returned
         ``_InflightTick`` holds the un-materialized [B] ids + packed
         done-mask handles — jax async dispatch means this returns as
         soon as the program is enqueued, so the host can plan the
         next tick (or emit the previous one) while the device
         computes."""
-        if self._state_dirty or self._dev_state is None:
-            self._push_state()
+        self._sync_state()
         st = self._dev_state
         if self._fused_fn is None:
             self._fused_fn, _, _ = self.model.serving_program(
@@ -4123,7 +4338,7 @@ class Engine:
             for slot, req in zip(inf.slots, inf.reqs):
                 i = slot.index
                 if slot.request is not req:
-                    if not done[i]:
+                    if not done[i] and i not in inf.dropped:
                         raise RuntimeError(
                             f"async stop-condition drift: slot {i} "
                             f"was evicted on the host but tick "
@@ -4193,9 +4408,9 @@ class Engine:
         tokens are known up front — unlike spec drafts there is no
         data dependence on the in-flight window), so a depth-2 blind
         dispatch can plan the next chunk, and a final chunk's first
-        token rides home in the device picks: chunked prefill
-        pipelines instead of forcing a drain per chunk like the XLA
-        path's per-chunk programs."""
+        token rides home in the device picks, where the XLA path
+        queues a program a chunk and reads a first token of its
+        own."""
         import jax.numpy as jnp
         W = self._wmax
         B = self.num_slots
@@ -4221,11 +4436,10 @@ class Engine:
             width[i] = n
             mode[i] = 2 if final else 1
             chunk_toks += n
-        # push BEFORE the chunk lanes' mirror advance below: a dirty
-        # upload must carry the PRE-dispatch cursors (the program
+        # BEFORE the chunk lanes' mirror advance below: an upload or a
+        # patch must carry the PRE-dispatch cursors (the program
         # itself advances them by width)
-        if self._state_dirty or self._dev_state is None:
-            self._push_state()
+        self._sync_state()
         # kv blocks the kernel walks this tick (computed on the
         # PRE-dispatch cursors, before the chunk lanes' mirror
         # advance): the streaming loop stops at each lane's causal
@@ -4242,8 +4456,8 @@ class Engine:
             i = slot.index
             # dispatch-time bookkeeping (kept consistent with the
             # device cursor the program advances; the mirrors equal
-            # the post-consume state, so a drain-then-push re-upload
-            # stays exact)
+            # the post-consume state, so a rebuild's upload stays
+            # exact)
             slot.prefilled += n
             slot.pos = slot.prefilled
             self._pos[i] = slot.prefilled
@@ -4323,7 +4537,7 @@ class Engine:
                     inf.slots, inf.reqs, inf.meta_lanes):
                 i = slot.index
                 if slot.request is not req:
-                    if not done[i]:
+                    if not done[i] and i not in inf.dropped:
                         raise RuntimeError(
                             f"async stop-condition drift: slot {i} "
                             f"was evicted on the host but tick "
@@ -4418,7 +4632,7 @@ class Engine:
         self._m_d2h.set(nbytes)
         done = np.unpackbits(mats["done"],
                              count=self.num_slots).astype(bool)
-        self._count_stats(mats.get("stats"))
+        self._count_stats(inf.tick, mats.get("stats"))
         in_flight = bool(self._ring)
         t1 = time.monotonic()
         ov = (tr.span("host.overlap", tick=inf.tick) if in_flight
@@ -4441,13 +4655,18 @@ class Engine:
         inf.arrays = None
         return emitted
 
-    def _count_stats(self, vector=None):
-        """Add a step program's counter vector, and those of the chunk
-        programs dispatched before it (complete by now: one device,
-        in order), into the model's counters."""
-        pending, self._stats_pending = self._stats_pending, []
+    def _count_stats(self, tick, vector=None):
+        """Add the counter vector of the step program dispatched in
+        ``tick``, and those of the chunk programs dispatched before it
+        (complete by now: one device, in order), into the model's
+        counters.  A chunk program queued BEHIND that step keeps its
+        vector for a later tick's: reading it here would stand for a
+        program the consumed tick never waited for."""
+        ready = [h for t, h in self._stats_pending if t <= tick]
+        self._stats_pending = [
+            (t, h) for t, h in self._stats_pending if t > tick]
         for v in ([vector] if vector is not None else []) \
-                + [np.asarray(h) for h in pending]:
+                + [np.asarray(h) for h in ready]:
             for m, n in zip(self._m_program, v):
                 m.inc(int(n))
 
@@ -4462,11 +4681,14 @@ class Engine:
         self._m_decode_batch.set(n_active)
 
     def _drain_ring(self, tr, why):
-        """Consume every in-flight tick, oldest first (the dirty-event
-        barrier: mirrors may only be re-uploaded over an empty
-        pipeline) under a ``ring.drain`` span that says ``why``.
-        Returns tokens emitted."""
+        """Consume every in-flight tick, oldest first, under a
+        ``ring.drain`` span that says ``why`` (``_RING_DRAINS``: the
+        true syncs, where the host needs the tokens in flight before
+        it can go on; a dirty slot is none, ``_patch_state``).
+        Counted into ``serving.ring_drains.<why>``.  Returns tokens
+        emitted."""
         emitted = 0
+        self._m_ring_drains[why].inc()
         with tr.span("ring.drain", cpu=self._phase_cpu, why=why,
                      ticks=len(self._ring)):
             while self._ring:
@@ -4582,9 +4804,12 @@ class Engine:
         then consume tick N's already-materializing ids — so the
         inter-tick host work (admission, chunk planning, the emit
         loop) hides behind device compute instead of serializing with
-        it.  Structural events (admission, eviction, chunk) dirty the
-        host mirrors; the pipeline is drained before the mirrors are
-        re-uploaded, so parity with the synchronous tick is exact."""
+        it.  Structural events (admission, eviction, chunk progress,
+        a final chunk's first token) mark their slot dirty and reach
+        the device as per-slot patches queued in dispatch order
+        (``_patch_state``): nothing in flight is consumed for them,
+        and parity with the synchronous tick is exact because the
+        device runs its queue in order."""
         self._overlap_acc = 0.0
         now = time.monotonic()
         emitted = 0
@@ -4622,14 +4847,13 @@ class Engine:
         admitted = self._post_admit(admitted + p_admitted,
                                     timed_out + p_timed, tr)
         # -- prefill / chunk planning (mutates only the admitted
-        #    slots' lanes; the dirty flag defers the re-upload) ------
+        #    slots' lanes; their patch is queued at the dispatch) ----
         if self._chunk is None:
             for slot in admitted:
                 rid = slot.request.id
                 with tr.span("prefill", req=rid,
                              prompt=int(len(slot.request.prompt))):
                     self._prefill(slot)
-                emitted += 1  # prefill samples the first token
         else:
             with tr.span("chunk.plan", cpu=self._phase_cpu) as plan_sp:
                 for slot in admitted:
@@ -4638,27 +4862,24 @@ class Engine:
                 plan_sp.args["prefilling"] = len(prefilling)
                 if prefilling and not self._ragged:
                     # ragged mode: chunks ride as lanes of the unified
-                    # dispatch below — and because their tokens are
-                    # known up front (no data dependence on the
-                    # in-flight window), chunk progress needs NO
-                    # pipeline drain, unlike the XLA per-chunk
-                    # programs whose cursor updates dirty the mirrors
-                    # every chunk
-                    n_emit, _, _ = self._prefill_chunked(prefilling)
-                    emitted += n_emit
+                    # dispatch below; the XLA path's per-chunk
+                    # programs are queued here, behind the decode in
+                    # flight
+                    self._prefill_chunked(prefilling)
         # -- spec barrier: drafting is data-dependent on the previous
         #    window's accepted tokens, so spec mode always consumes
         #    before the dispatch snapshot — but only HERE, after the
         #    planning/prefill phase above ran in the gap, so spec
         #    ticks still overlap their plan work with the in-flight
         #    verify's device compute --------------------------------
-        if self._spec_k is not None and self._ring:
-            emitted += self._drain_ring(tr, "spec")
-        # -- dirty barrier: consumed evictions must not leave freed
-        #    slots in the dispatch set, and _push_state may only run
-        #    over an empty pipeline ---------------------------------
-        if self._ring and (self._state_dirty or self._dev_state is None):
-            emitted += self._drain_ring(tr, "dirty")
+        if self._spec_k is not None:
+            if self._ring:
+                emitted += self._drain_ring(tr, "spec")
+            # ...and on the first token of this tick's prefills
+            emitted += self._emit_first_tokens()
+        # (no dirty barrier: a slot an admission, an eviction or a
+        # chunk touched is patched on the device, behind the ring,
+        # when the dispatch below syncs the state)
         occ, active, prefilling = self.scheduler.snapshot()
         ragged = self._ragged
         if active and self._ring and self._spec_k is None and \
@@ -4700,6 +4921,10 @@ class Engine:
         keep = (self.async_depth - 1) if (active or plan) else 0
         while len(self._ring) > keep:
             emitted += self._consume(self._ring.pop(0), tr)
+        # -- ...then the first tokens of this tick's prefills: picked
+        #    on the device ahead of the decode just queued, read once
+        #    their program ends, not once that decode does ----------
+        emitted += self._emit_first_tokens()
         occ -= self._evicted_in_tick - n_before
         if self._ring and occ == 0:
             # every slot freed while the newest dispatch was in
@@ -4753,26 +4978,28 @@ class Engine:
                 with tr.span("prefill", req=rid,
                              prompt=int(len(slot.request.prompt))):
                     self._prefill(slot)
-                emitted += 1  # prefill samples the first token
-            occ, active, prefilling = self.scheduler.snapshot()
         else:
             with tr.span("chunk.plan", cpu=self._phase_cpu) as plan_sp:
                 for slot in admitted:
                     self._begin_chunked(slot)
-                occ, active, prefilling = self.scheduler.snapshot()
+                _, _, prefilling = self.scheduler.snapshot()
                 plan_sp.args["prefilling"] = len(prefilling)
-                if self._ragged:
-                    # chunks ride as window lanes of the unified
-                    # dispatch, not through the per-chunk loop
-                    plan = self._plan_ragged_chunks(prefilling)
-                elif prefilling:
-                    n_emit, newly, n_evicted = \
-                        self._prefill_chunked(prefilling)
-                    emitted += n_emit
-                    occ -= n_evicted
-                    active = active + newly  # final-chunk slots decode
-                    #   in this same tick, like monolithic
-                    #   emit-then-decode
+                if prefilling and not self._ragged:
+                    self._prefill_chunked(prefilling)
+        # nothing is in flight to read the first tokens behind: the
+        # same patches and picks as the pipelined tick's, consumed at
+        # once, so a first token that ends its request frees the slot
+        # before the snapshot
+        emitted += self._emit_first_tokens()
+        # final-chunk slots decode in this same tick, like monolithic
+        # emit-then-decode
+        occ, active, prefilling = self.scheduler.snapshot()
+        if self._ragged and self._chunk is not None:
+            # chunks ride as window lanes of the unified dispatch, not
+            # through the per-chunk loop
+            with tr.span("chunk.plan", cpu=self._phase_cpu,
+                         prefilling=len(prefilling)):
+                plan = self._plan_ragged_chunks(prefilling)
         if self._ragged:
             if active or plan:
                 self._note_dispatch_gap(len(active))
@@ -4889,10 +5116,12 @@ class Engine:
         """Fail every queued and in-flight request (shutdown path)."""
         self._flush_offload()  # land pending demotes — the host tier
         #   outlives this loop and warms the next start()
-        # drop un-consumed dispatches: their requests fail below, and
-        # the next start() re-uploads clean cursors (every eviction
-        # parks its lanes and dirties the mirrors)
+        # drop un-consumed dispatches and unread first tokens: their
+        # requests fail below, and the next start() serves with clean
+        # cursors (every eviction parks its lanes and marks its slot
+        # for a patch)
         self._ring = []
+        self._first_pending, self._first_picked = [], []
         with self._mig_lock:
             demands, self._migrate_demands = self._migrate_demands, []
         for d in demands:

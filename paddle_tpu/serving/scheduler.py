@@ -147,9 +147,9 @@ class Scheduler:
     def admissible(self):
         """True when an admission attempt could make progress: at
         least one queued request AND at least one free slot.  The
-        async engine tick's cheap planning probe — admission is a
-        structural (pipeline-draining) event, so the pipelined loop
-        only pays ``admit()`` when this says it could bind."""
+        async engine tick's cheap planning probe: the pipelined loop
+        only pays ``admit()`` (the queue's lock, the paged gate) when
+        this says it could bind."""
         if self.queue.depth() == 0:
             return False
         with self._lock:

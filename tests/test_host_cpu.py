@@ -31,7 +31,8 @@ TOOLS = os.path.join(os.path.dirname(os.path.dirname(
 # the phases of a tick that read the CPU clock, and the waits for the
 # device, which do not (the engine times those itself)
 CPU_PHASES = {"admit", "chunk.plan", "prefill.chunk", "prefill.d2h",
-              "state.push", "ring.drain", "dispatch", "decode.dispatch",
+              "state.push", "state.patch", "first_token", "ring.drain",
+              "dispatch", "decode.dispatch",
               "decode.ragged_stream", "consume", "decode.emit"}
 DEVICE_WAITS = {"decode.d2h_wait", "decode.d2h", "prefill.d2h",
                 "decode.allgather"}
@@ -256,8 +257,10 @@ def test_tick_splits_host_time_into_cpu_and_wait(tiny_gpt, kw):
         for k in sums:
             sums[k] += a[k]
     assert loose <= len(ticks) // 10
+    # one whole upload (the first tick's), then per-slot patches
     assert {"admit", "dispatch", "consume", "decode.emit",
-            "state.push"} <= names
+            "state.push", "state.patch"} <= names
+    assert sum(e["name"] == "state.push" for e in inner) == 1
     assert "stream.emit" not in CPU_PHASES
     for name, k in zip(TICK_COUNTERS, sums):
         assert eng.registry.get(name).value == pytest.approx(

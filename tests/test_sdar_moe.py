@@ -123,6 +123,29 @@ def test_the_engine_serves_what_the_published_loop_generates(case, depth):
     assert eng.registry.get("serving.compiles_total").value <= 2
 
 
+def test_a_lane_opens_its_first_step_behind_two_steps_in_flight():
+    """``_open_step`` reaches the device as a patch of the lane's rows,
+    cursor and flags queued behind the steps in flight: five requests
+    through two slots of a pool that fits two, at ``async_depth=3``,
+    are served what the published loop generates, with one whole
+    upload of the state and no drain for a dirty slot."""
+    model, get = seeded(seed=1)
+    requests = [(tokens(n, seed=n), m)
+                for n, m in ((17, 6), (8, 5), (30, 9), (5, 4), (19, 7))]
+    got, eng = served(model, requests, async_depth=3, num_slots=2,
+                      kv_blocks=12, trace_capacity=1 << 16)
+    for (prompt, m), out in zip(requests, got):
+        assert out == _reference().generate(get, DIMS, prompt, m)
+    reg = eng.registry
+    assert reg.get("serving.state_pushes").value == 1
+    assert reg.get("serving.state_patches").value >= len(requests)
+    whys = {e["args"]["why"]
+            for e in eng.chrome_trace()["traceEvents"]
+            if e.get("ph") == "X" and e["name"] == "ring.drain"}
+    assert whys <= {"tail", "idle"}
+    assert [fn._cache_size() for fn in eng._state_fns[:2]] == [1, 1]
+
+
 def paged_block_logits(model, prompt, block, masked, chunk=8, bs=8,
                        nb=10):
     """Logits [W, V] of one block's rows (``masked`` [W] bool) after

@@ -866,7 +866,7 @@ def test_device_step_failure_recovers(tiny_gpt):
     reqs = [eng.submit(p, max_new_tokens=8) for p in prompts]
     eng.step()
     eng.step()                           # device state now resident
-    assert not eng._state_dirty
+    assert eng._dev_state is not None and not eng._dirty_slots
 
     def boom(*a, **kw):
         raise RuntimeError("synthetic fused dispatch failure")
@@ -878,7 +878,9 @@ def test_device_step_failure_recovers(tiny_gpt):
         with pytest.raises(RuntimeError, match="engine step failed"):
             r.result(timeout=1)
     assert eng.scheduler.occupancy() == 0
-    assert eng._state_dirty              # cursors rebuilt on next tick
+    assert eng._dev_state is None        # cursors rebuilt on next tick:
+    #   the one case besides the first tick of a whole upload
+    assert not eng._dirty_slots and not eng._first_pending
     assert eng.block_pool.in_use() == 0
     assert all(eng.block_pool.refcount(b) == 0
                for b in range(eng.block_pool.num_blocks))
@@ -887,6 +889,7 @@ def test_device_step_failure_recovers(tiny_gpt):
     eng.run_until_idle()
     assert r2.result(timeout=1).tolist() == _gen_ref(tiny_gpt,
                                                      prompts[0], 6)
+    assert reg.get("serving.state_pushes").value == 2
 
 
 def test_sampling_metrics(tiny_gpt):
@@ -2388,16 +2391,21 @@ def test_tick_host_spans_cover_host_ms(tiny_gpt, kw):
                 end = e["ts"] + e["dur"]
         host_ms += t["args"]["host_ms"]
         uncovered_ms += (t["dur"] - direct) * 1e-3
-    assert {"admit", "preempt", "post_admit", "state.push"} <= seen
+    # warm since the first request: the whole state is never uploaded
+    # again, every admission, chunk and eviction patches its own slot
+    assert {"admit", "preempt", "post_admit", "state.patch"} <= seen
+    assert "state.push" not in seen
     if "prefill_chunk" in kw:
         assert "chunk.plan" in seen
+    if kw.get("attn_impl") != "ragged":
+        assert {"first_token", "prefill.d2h"} <= seen
     if kw.get("async_depth") != 1:
         assert "ring.drain" in seen
         whys = {e["args"]["why"] for e in _spans(eng, name="ring.drain")}
-        assert whys <= {"dirty", "spec", "tail", "idle", "preempt",
+        assert whys <= {"spec", "tail", "idle", "preempt",
                         "migrate", "adapter"}
-    assert all(e["args"]["bytes"] > 0
-               for e in _spans(eng, name="state.push"))
+    assert all(e["args"].get("bytes", 1) > 0
+               for e in _spans(eng, name="state.patch"))
     assert uncovered_ms <= 0.1 * host_ms, (uncovered_ms, host_ms)
 
 
@@ -2499,3 +2507,290 @@ def test_http_events_and_requests_lane(tiny_gpt):
     assert {e["tid"] for n in by for e in by[n].values()} == \
         {lanes["requests"]}
     assert len(lanes) < 10      # handler threads burned no lanes
+
+
+# ---------------------------------------------------------------------------
+# NO DIRTY EVENT DRAINS THE RING: an admission, a chunk's progress, a
+# final chunk's first token and an eviction reach the device as per-slot
+# patches queued in dispatch order behind the ticks in flight; the whole
+# state is uploaded once.  Token-for-token parity with the synchronous
+# engine (which queues the same patches over nothing in flight), the
+# counters that say how often each engages, and the compile-once rule of
+# the patch and first-token programs.
+# ---------------------------------------------------------------------------
+
+def _eventful_run(eng, max_new=7):
+    """More requests than slots over a pool that fits the running ones
+    and little more, so every eviction's blocks are adopted at once by
+    the admission behind it; prompts of one to four chunks; one request
+    whose first token is its EOS, one whose budget is one token, and
+    one that times out in the queue while the slots are busy."""
+    lens = (5, 19, 11, 27, 8, 14, 21, 6)
+    rng = np.random.RandomState(3)
+    prompts = [rng.randint(0, 128, (n,)).astype(np.int32) for n in lens]
+    firsts = [int(_ref(eng.model, p, 1)[-1]) for p in prompts[:2]]
+    reqs = [eng.submit(prompts[0], max_new_tokens=max_new,
+                       eos_token_id=firsts[0]),     # EOS at token one
+            eng.submit(prompts[1], max_new_tokens=1)]
+    reqs += [eng.submit(p, max_new_tokens=max_new) for p in prompts[2:5]]
+    for _ in range(3):
+        eng.step()
+    late = eng.submit(prompts[5], max_new_tokens=max_new, timeout=0.0)
+    reqs += [eng.submit(p, max_new_tokens=max_new) for p in prompts[5:]]
+    eng.run_until_idle()
+    with pytest.raises(RequestTimeout):
+        late.result(timeout=1)
+    return [r.result(timeout=2).tolist() for r in reqs]
+
+
+PATCHED_LAYOUTS = [
+    pytest.param(dict(), id="contiguous"),
+    pytest.param(dict(prefill_chunk=8), id="contiguous-chunked"),
+    pytest.param(dict(kv_block_size=8, kv_blocks=14), id="paged"),
+    pytest.param(dict(kv_block_size=8, kv_blocks=14, prefill_chunk=8),
+                 id="paged-chunked"),
+    pytest.param(dict(kv_block_size=8, kv_blocks=14, prefill_chunk=8,
+                      tick_token_budget=8), id="paged-chunked-budget"),
+    pytest.param(dict(kv_block_size=8, kv_blocks=14, prefill_chunk=8,
+                      prefix_cache=False), id="paged-chunked-nocache"),
+    pytest.param(dict(kv_block_size=8, kv_blocks=14, prefill_chunk=8,
+                      kv_dtype="int8"), id="paged-chunked-int8"),
+    pytest.param(dict(kv_block_size=8, kv_blocks=14, prefill_chunk=8,
+                      attn_impl="ragged"), id="ragged"),
+    pytest.param(dict(kv_block_size=8, kv_blocks=14, prefill_chunk=8,
+                      spec_k=2), id="paged-chunked-spec"),
+]
+
+
+def _eventful_engine(model, **kw):
+    return _engine(model, num_slots=3, max_seq_len=64,
+                   shed_deadlines=False, **kw)
+
+
+@pytest.mark.parametrize("cfg", PATCHED_LAYOUTS)
+def test_patched_engine_matches_the_synchronous_one(tiny_gpt, cfg):
+    """With one and with two decodes in flight, admissions, chunk
+    progress, first tokens (one an EOS, one a budget of one) and
+    evictions whose blocks the next admission adopts at once give every
+    request the tokens the synchronous engine gives it, and per-request
+    ``generate()``'s where the pool keeps the compute dtype."""
+    want = _eventful_run(_eventful_engine(tiny_gpt, async_depth=1, **cfg))
+    assert len(want[0]) == 5 + 1 and len(want[1]) == 19 + 1
+    if "kv_dtype" not in cfg:
+        for n, ids in zip((11, 27, 8), want[2:5]):
+            assert ids == _ref(tiny_gpt, np.asarray(ids[:n], np.int32),
+                               7).tolist()
+    for depth in (2, 3):
+        eng = _eventful_engine(tiny_gpt, async_depth=depth, **cfg)
+        assert _eventful_run(eng) == want, depth
+        reg = eng.registry
+        assert reg.get("serving.state_pushes").value == 1
+        assert reg.get("serving.state_patches").value > 0
+        whys = {e["args"]["why"] for e in _spans(eng, name="ring.drain")}
+        assert whys <= {"spec", "tail", "idle"}, depth
+        assert eng.scheduler.occupancy() == 0 and not eng._ring
+        if eng._paged:
+            if eng.prefix_cache is not None:
+                eng.prefix_cache.clear()
+            assert eng.block_pool.in_use() == 0
+
+
+def test_bf16_pools_patched_parity():
+    """The same run over bf16 pools (a model that computes in bf16):
+    pipelined and synchronous engines agree token for token."""
+    paddle.seed(0)
+    m = GPTModel.from_config("tiny", dropout=0.0)
+    m.to(dtype="bfloat16")
+    m.eval()
+    cfg = dict(kv_block_size=8, kv_blocks=14, prefill_chunk=8)
+    want = _eventful_run(_eventful_engine(m, async_depth=1, **cfg))
+    eng = _eventful_engine(m, async_depth=3, **cfg)
+    assert _eventful_run(eng) == want
+    assert "bfloat16" in str(eng.k_pools[0].dtype)
+    assert eng.registry.get("serving.state_pushes").value == 1
+
+
+def test_eviction_under_two_decodes_blocks_adopted_at_once(tiny_gpt):
+    """THE HAZARD the drain used to hide.  ``async_depth=3``: a stream
+    ends at consume of tick N with N+1 and N+2 queued, its blocks are
+    released under them (each still writes one row of the frozen lane)
+    and the pool, which holds nothing else, hands those very blocks to
+    the request waiting at the gate in the next tick.  Sound because
+    the device runs in order: the new owner's chunk program is queued
+    behind both decodes and rewrites what it reads.  Tokens equal the
+    synchronous engine's and ``generate()``'s."""
+    rng = np.random.RandomState(5)
+    a, b = (rng.randint(0, 128, (n,)).astype(np.int32) for n in (13, 17))
+
+    def run(depth):
+        eng = _engine(tiny_gpt, num_slots=2, max_seq_len=32,
+                      kv_block_size=8, kv_blocks=4, prefill_chunk=8,
+                      prefix_cache=False, async_depth=depth)
+        ra = eng.submit(a, max_new_tokens=6)   # 3 of the 4 blocks
+        rb = eng.submit(b, max_new_tokens=6)   # needs 3: waits for a's
+        adopted = None
+        for _ in range(200):
+            if eng.scheduler.idle():
+                break
+            before = list(eng._slot_blocks[0])
+            in_flight = len(eng._ring)
+            eng.step()
+            if ra.done() and adopted is None:
+                # the tick that consumed a's last token released its
+                # blocks with this many decodes still queued
+                adopted = (in_flight, before)
+        assert eng.scheduler.idle()
+        owned_by_b = adopted[1]
+        return (ra.result(timeout=1).tolist(),
+                rb.result(timeout=1).tolist(), adopted[0], owned_by_b,
+                eng)
+
+    ta, tb, _, _, _ = run(1)
+    ga, gb, in_flight, blocks, eng = run(3)
+    assert (ga, gb) == (ta, tb)
+    assert ga == _ref(tiny_gpt, a, 6).tolist()
+    assert gb == _ref(tiny_gpt, b, 6).tolist()
+    assert in_flight == 2              # two decodes were in flight
+    assert len(blocks) == 3            # ...over a's three blocks
+    assert "dirty" not in {e["args"]["why"]
+                           for e in _spans(eng, name="ring.drain")}
+    assert eng.block_pool.in_use() == 0
+
+
+def test_first_token_read_behind_the_decode_it_feeds(tiny_gpt):
+    """The final chunk's first token is picked on the device: the
+    decode that follows is dispatched BEFORE the host reads the id
+    (``first_token`` > ``prefill.d2h`` lies after ``dispatch`` in the
+    tick), the read is 4 bytes behind the chunk program, and a first
+    token that ends its request frees the slot while that decode's
+    lane for it is dropped at consume, not called drift."""
+    eng = _engine(tiny_gpt, kv_block_size=8, prefill_chunk=8)
+    long_run = eng.submit(_prompts(1)[0], max_new_tokens=20)
+    for _ in range(3):
+        eng.step()                      # a stream is decoding
+    p = _prompts(4)[3]
+    eos = int(_ref(tiny_gpt, p, 1)[-1])
+    eng.tracer.clear()
+    r = eng.submit(p, max_new_tokens=8, eos_token_id=eos)
+    for _ in range(2):      # 9 tokens: two chunks, the second final
+        eng.step()
+    assert r.done() and r.result(timeout=1).tolist() == p.tolist() + [eos]
+    tick = _spans(eng, name="tick")[-1]
+    t0, t1 = tick["ts"], tick["ts"] + tick["dur"]
+    inside = {e["name"]: e for e in _spans(eng)
+              if t0 <= e["ts"] < t1 and e["name"] != "tick"}
+    assert inside["dispatch"]["ts"] < inside["first_token"]["ts"]
+    assert inside["first_token"]["ts"] <= inside["prefill.d2h"]["ts"]
+    # the decode queued behind the chunk carried r's lane, live
+    inf = eng._ring[-1]
+    assert inf.dropped == {inf.slots[inf.reqs.index(r)].index}
+    eng.run_until_idle()                # consume drops it: no drift
+    assert long_run.result(timeout=1).tolist() == \
+        _ref(tiny_gpt, _prompts(1)[0], 20).tolist()
+
+
+def test_seeded_first_token_is_the_one_it_was(tiny_gpt):
+    """A seeded sampled request whose prompt takes three chunks: the
+    first token picked by the ``first_token`` program (the lane's own
+    filters and fold(request_key, 0)) and the tokens after it are the
+    ones the host-side pick gave before this change (recorded at the
+    parent commit), on every layout and depth."""
+    p = np.concatenate(_prompts(4))      # 24 tokens
+    want = [86, 87, 20, 17, 82, 13, 105, 43]
+    for kw in (dict(), dict(kv_block_size=8),
+               dict(kv_block_size=8, prefill_chunk=8),
+               dict(prefill_chunk=8, async_depth=3),
+               dict(kv_block_size=8, prefill_chunk=8, async_depth=1)):
+        eng = _engine(tiny_gpt, **kw)
+        r = eng.submit(p, max_new_tokens=8, temperature=0.9, top_p=0.9,
+                       top_k=20, seed=1234)
+        eng.run_until_idle()
+        assert r.result(timeout=2).tolist()[len(p):] == want, kw
+
+
+def test_many_admissions_one_push_and_no_dirty_drain(tiny_gpt):
+    """Forty requests through four slots: the state is uploaded whole
+    ONCE, everything after it is a patch, the ring is never consumed
+    for a dirty slot, and ``serving.ring_drains.<why>`` counts what
+    the ``ring.drain`` spans say."""
+    eng = _engine(tiny_gpt, kv_block_size=8, prefill_chunk=8,
+                  trace_capacity=1 << 16)
+    reqs = []
+    for i, p in enumerate(_prompts(40)):
+        reqs.append(eng.submit(p, max_new_tokens=3 + i % 5))
+        if i % 3 == 0:
+            eng.step()
+    eng.run_until_idle()
+    for p, r in zip(_prompts(40), reqs):
+        assert r.result(timeout=1).tolist() == \
+            _ref(tiny_gpt, p, r.max_new_tokens).tolist()
+    reg = eng.registry
+    assert reg.get("serving.state_pushes").value == 1
+    assert len(_spans(eng, name="state.push")) == 1
+    patches = [e for e in _spans(eng, name="state.patch")
+               if "first_token" not in e["args"]]
+    assert reg.get("serving.state_patches").value >= len(patches) >= 40
+    picks = [e for e in _spans(eng, name="state.patch")
+             if "first_token" in e["args"]]
+    assert len(picks) == 40
+    drains = _spans(eng, name="ring.drain")
+    assert all(e["args"]["why"] != "dirty" for e in drains)
+    for why in ("spec", "tail", "idle", "preempt", "migrate", "adapter"):
+        assert reg.get(f"serving.ring_drains.{why}").value == \
+            sum(e["args"]["why"] == why for e in drains), why
+    assert reg.get("serving.ring_drains.dirty") is None
+    # greedy requests only: the sampled pick (its sorts over the
+    # vocabulary are the dear part to compile) was never built
+    first = eng._state_fns[2]
+    assert (first[False]._cache_size(), first[True]._cache_size()) == (1, 0)
+    text = monitor.render_prometheus(reg)
+    for name in ("serving_state_pushes", "serving_state_patches",
+                 "serving_ring_drains_tail"):
+        assert name in text
+
+
+class backend_compiles:
+    """Counts JAX's own backend compilations while it is open."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __enter__(self):
+        import jax.monitoring
+        self.count = 0
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+        return self
+
+    def _on(self, event, duration, **_):
+        self.count += event == self.EVENT
+
+    def __exit__(self, *exc):
+        from jax._src import monitoring
+        monitoring.unregister_event_duration_listener(self._on)
+
+
+def test_state_programs_compile_once(tiny_gpt):
+    """The patch, first-token and unpack programs are compiled once an
+    engine, in its first requests: after those, slots and values
+    (greedy and sampled lanes, every slot, one to four dirty slots a
+    tick) are data, and nothing compiles."""
+    eng = _engine(tiny_gpt, kv_block_size=8, prefill_chunk=8)
+
+    def wave(n0):
+        for i, p in enumerate(_prompts(n0 + 12)[n0:]):
+            kw = (dict(temperature=0.8, top_k=5, seed=i) if i % 3 == 0
+                  else {})
+            eng.submit(p, max_new_tokens=2 + i % 4, **kw)
+            if i % 2:
+                eng.step()
+        eng.run_until_idle()
+
+    wave(0)         # every program of this configuration, warm
+    before = eng.registry.get("serving.state_patches").value
+    with backend_compiles() as seen:
+        wave(5)
+    assert seen.count == 0
+    assert eng.registry.get("serving.state_patches").value >= before + 6
+    assert eng.registry.get("serving.state_pushes").value == 1
+    unpack, patch, first = eng._state_fns
+    assert [fn._cache_size() for fn in
+            (unpack, patch, first[False], first[True])] == [1, 1, 1, 1]

@@ -317,6 +317,37 @@ def test_dp_parity_matrix(dense_gpt, tp_gpt, kw):
                 == mesh[0] * mesh[1]
 
 
+@pytest.mark.parametrize("mesh", [(2, 1), (1, 2)], ids=["mp2", "dp2"])
+def test_patched_state_keeps_its_shardings(dense_gpt, tp_gpt, mesh):
+    """Under a mesh the state's one upload and every per-slot patch
+    land on the state's own shardings (cursors and table rows over
+    'dp'), so no dispatch re-shards: chunked streams over a tight pool
+    with two decodes in flight are token-identical to the unsharded
+    synchronous engine, ``shard.sync`` happens once, and nothing
+    retraces by slot or value."""
+    kw = dict(kv_block_size=8, prefill_chunk=8, kv_blocks=12)
+    prompts = _prompts(7)
+    base = _drive(_engine(dense_gpt, async_depth=1, **kw), prompts)
+    eng = _engine(_dp_model(dense_gpt, tp_gpt, mesh), mesh=mesh,
+                  async_depth=3, **kw)
+    assert _drive(eng, prompts) == base
+    from test_serving import backend_compiles
+    with backend_compiles() as seen:     # warm: slots and values are
+        assert _drive(eng, prompts[::-1]) == base[::-1]       # data
+    assert seen.count == 0
+    reg = eng.registry
+    assert reg.get("serving.state_pushes").value == 1
+    assert reg.get("serving.state_patches").value >= len(prompts)
+    spans = [e for e in eng.chrome_trace()["traceEvents"]
+             if e.get("ph") == "X"]
+    assert sum(e["name"] == "shard.sync" for e in spans) == 1
+    assert not any(e["name"] == "ring.drain"
+                   and e["args"]["why"] == "dirty" for e in spans)
+    want = eng._state_sharding
+    for k, v in eng._dev_state.items():
+        assert v.sharding.is_equivalent_to(want, v.ndim), k
+
+
 def test_dp_parity_depth1(dense_gpt, tp_gpt):
     """async_depth=1 keeps the synchronous tick under the dp mesh
     too — batch sharding and pipelining are orthogonal."""

@@ -551,6 +551,49 @@ def test_trace_view_wall_reads_the_device_lane(tmp_path, capsys):
     assert "state.push" in out.split("device idle")[1]
 
 
+def test_trace_view_wall_knows_the_state_patch(tmp_path, capsys):
+    """--wall names how the device-resident step state followed the
+    host: the per-slot patch programs and the first-token picks queued
+    with them (``state.patch``), the whole uploads (``state.push``) and
+    what still consumed the ring to empty, by ``why``."""
+    tv = _load_tool("trace_view")
+    events = [
+        {"name": "tick", "ph": "X", "ts": 0.0, "dur": 10000.0,
+         "cat": "tick"},
+        {"name": "state.push", "ph": "X", "ts": 100.0, "dur": 900.0,
+         "cat": "serving", "args": {"bytes": 4096}},
+        {"name": "state.patch", "ph": "X", "ts": 2000.0, "dur": 400.0,
+         "cat": "serving", "args": {"slots": 2, "bytes": 512}},
+        {"name": "state.patch", "ph": "X", "ts": 2400.0, "dur": 100.0,
+         "cat": "serving", "args": {"first_token": 1}},
+        {"name": "state.patch", "ph": "X", "ts": 5000.0, "dur": 300.0,
+         "cat": "serving", "args": {"slots": 1, "bytes": 256}},
+        {"name": "ring.drain", "ph": "X", "ts": 7000.0, "dur": 500.0,
+         "cat": "serving", "args": {"why": "tail", "ticks": 1}},
+        {"name": "ring.drain", "ph": "X", "ts": 8000.0, "dur": 500.0,
+         "cat": "serving", "args": {"why": "tail", "ticks": 1}},
+        {"name": "ring.drain", "ph": "X", "ts": 9000.0, "dur": 500.0,
+         "cat": "serving", "args": {"why": "spec", "ticks": 1}},
+    ]
+    w = tv.wall_summary(events)
+    assert w["state_patches"] == 2 and w["first_token_picks"] == 1
+    assert w["state_patch_ms"] == pytest.approx(0.8)
+    assert w["state_pushes"] == 1
+    assert w["state_push_ms"] == pytest.approx(0.9)
+    assert w["ring_drains"] == {"tail": 2, "spec": 1}
+    path = tmp_path / "t.json"
+    path.write_text(json.dumps({"traceEvents": events}))
+    assert tv.main([str(path), "--wall"]) == 0
+    out = capsys.readouterr().out
+    assert ("state.patch 0.800 ms over 2 slot patch(es) and 1 "
+            "first-token pick(s)") in out
+    assert "state.push 0.900 ms over 1 whole upload(s)" in out
+    assert "ring.drain by why: spec 1, tail 2" in out
+    # a trace with none of them prints no such line
+    assert "state.patch" not in tv.format_wall(
+        tv.wall_summary(events[:1]))
+
+
 def test_dev_lane_check_aligns_clocks_and_pairs_programs(monkeypatch):
     """tools/dev_lane_check.py: the profiler's clock is aligned to the
     engine's on the tick spans both hold, each ``dev.*`` span is paired

@@ -187,6 +187,9 @@ def wall_summary(events):
     n_restarts = n_drain_migs = n_dequants = 0
     n_lora_swaps = n_stream_emits = 0
     n_off_demotes = n_off_promotes = 0
+    state_patch = state_push = 0.0
+    n_patches = n_first_picks = n_pushes = 0
+    ring_drains = {}
     for ev in events:
         if ev.get("ph") != "X" or ev.get("cat") == "device":
             continue  # the device lane is not a host phase: see
@@ -274,6 +277,25 @@ def wall_summary(events):
             elif name == "offload.promote":
                 off_promote += dur
                 n_off_promotes += 1
+            elif name == "state.patch":
+                # how the device-resident step state follows the
+                # host: per-slot patch programs queued behind the
+                # decodes in flight (admission, chunk progress,
+                # eviction) and the first-token picks queued with
+                # them, beside the whole uploads (state.push: one a
+                # healthy run) and what still consumed the ring to
+                # empty, by its reason
+                state_patch += dur
+                if "first_token" in ev.get("args", {}):
+                    n_first_picks += 1
+                else:
+                    n_patches += 1
+            elif name == "state.push":
+                state_push += dur
+                n_pushes += 1
+            elif name == "ring.drain":
+                why = ev.get("args", {}).get("why", "?")
+                ring_drains[why] = ring_drains.get(why, 0) + 1
             elif name == "decode.dequant":
                 # int8-KV engines (Engine(kv_dtype="int8")): the
                 # host-side attribution span of a QUANTIZED dispatch
@@ -313,6 +335,12 @@ def wall_summary(events):
         "offload_demotes": n_off_demotes,
         "offload_promote_ms": off_promote,
         "offload_promotes": n_off_promotes,
+        "state_patch_ms": state_patch,
+        "state_patches": n_patches,
+        "first_token_picks": n_first_picks,
+        "state_push_ms": state_push,
+        "state_pushes": n_pushes,
+        "ring_drains": ring_drains,
     }
 
 
@@ -384,6 +412,17 @@ def format_wall(w):
         f"host.overlap {w['overlap_ms']:.3f} ms   "
         f"decode.d2h_wait {w['d2h_wait_ms']:.3f} ms",
     ]
+    if w.get("state_patches") or w.get("state_pushes") \
+            or w.get("first_token_picks") or w.get("ring_drains"):
+        drains = ", ".join(f"{why} {n}" for why, n in
+                           sorted(w.get("ring_drains", {}).items()))
+        lines.append(
+            f"state.patch {w.get('state_patch_ms', 0.0):.3f} ms over "
+            f"{w.get('state_patches', 0)} slot patch(es) and "
+            f"{w.get('first_token_picks', 0)} first-token pick(s)   "
+            f"state.push {w.get('state_push_ms', 0.0):.3f} ms over "
+            f"{w.get('state_pushes', 0)} whole upload(s)   "
+            f"ring.drain by why: {drains or 'none'}")
     if w.get("ragged_stream_dispatches"):
         per = (w["kv_blocks_walked"] / w["ragged_stream_dispatches"]
                if w["ragged_stream_dispatches"] else 0.0)
