@@ -241,19 +241,21 @@ def collect_moe_aux_loss(layer: Layer):
 # rows, so a step reads the weights of the experts that were hit and of
 # no other.
 
-def sigmoid_topk_routing(logits, bias, k, scale=1.0, normalize=True):
+def sigmoid_topk_routing(logits, bias, k, scale=1.0, normalize=True,
+                         norm_eps=1e-20):
     """Sigmoid-scored top-k with a selection-only correction bias (the
     ``noaux_tc`` gate of DeepSeek-V3's published code, one group):
     ``s = sigmoid(logits)`` in float32; the ``k`` experts are the top
     ``k`` of ``s + bias``; the weights are the UNBIASED scores of the
-    selected, normalised to sum to one (``normalize``) and multiplied
-    by ``scale``.  logits [T, E] -> (choice [T, k] int32, weights
-    [T, k] float32)."""
+    selected, normalised to sum to one (``normalize``: over their sum
+    ``+ norm_eps``, 1e-20 in DeepSeek-V3's code and 1e-6 in LFM2's) and
+    multiplied by ``scale``.  logits [T, E] -> (choice [T, k] int32,
+    weights [T, k] float32)."""
     s = jax.nn.sigmoid(logits.astype(jnp.float32))
     _, choice = jax.lax.top_k(s + bias.astype(jnp.float32)[None, :], k)
     w = jnp.take_along_axis(s, choice, axis=-1)
     if normalize and k > 1:
-        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + norm_eps)
     return choice.astype(jnp.int32), w * scale
 
 
@@ -291,15 +293,24 @@ def sort_pairs_by_expert(choice, live, num_experts, first=None):
     return order.astype(jnp.int32), sizes[:num_experts]
 
 
+# numbers of one weight tile [tk, tn] of the kernel at most: twice a
+# bf16 tile of 2,048 x 1,536 (6.3 MB) is what fits fast memory beside
+# the rows and the accumulator; 2,048 x 1,792 (7.3 MB) does not at any
+# row tile (PR 35 on the chip; PR 46 by the compile for the described
+# v5e, which refuses it)
+_GMM_WEIGHT_TILE = 2048 * 1536
+
+
 def _gmm_tiling(m, k, n):
     """Tile sizes of the megablox kernel for an [m, k] x [E, k, n]
-    grouped product: all of a short m in one tile (a decode step's
-    pairs: every hit expert then costs one pass over its own weights),
-    128 rows otherwise; the whole contraction in one k tile (up to
-    2,048, and up to 3,072 beside an n tile of at most 768, PR 44);
-    the whole of n in one tile where the contraction's tile
+    grouped product: all of a short m (under 256 rows) in one tile (a
+    decode step's pairs: every hit expert then costs one pass over its
+    own weights), 128 rows otherwise; the whole contraction in one k
+    tile (up to 2,048, and up to 3,072 beside an n tile of at most 768,
+    PR 44); the whole of n in one tile where the contraction's tile
     is at most 1,024 and n at most 2,048, else the widest n tile of
-    those tried that divides n.  Chip run, PR 28, 40 experts hit, m 192:
+    those tried that divides n and keeps the weight tile inside
+    ``_GMM_WEIGHT_TILE``.  Chip run, PR 28, 40 experts hit, m 192:
     [2,048 -> 2,816] 0.65 ms at (192, 2048, 1408) against 0.72 at
     (192, 1024, 1408), [1,408 -> 2,048] 0.34 ms at (192, 1408, 1024)
     against 0.60 at (192, 128, 1024) and 0.42 at (192, 1408, 256): 87%
@@ -329,16 +340,28 @@ def _gmm_tiling(m, k, n):
     0.403 / 1.710 at (m, 512 or 768, 3072); [3,072 -> 3,072] 0.223 /
     0.839 against 0.235 / 0.905: 83% / 90% and 72% / 88% of the
     weights' time; n tiles of 512 and 1,024 within 1% of 768;
-    ``ragged_dot`` 0.579 / 4.167 and 0.348 / 2.095 ms."""
-    tm = m if m <= 256 else 128
+    ``ragged_dot`` 0.579 / 4.167 and 0.348 / 2.095 ms.  Chip run, PR 46
+    (``_chip/gmm_bench.py``), 32 experts, m 256 (64 slots x 4) with 16
+    pairs on 14 experts / 112 on 31, and m 1,024 with all 32 hit:
+    [2,048 -> 3,584], whose rule-made (m, 2048, 1792) fits no fast
+    memory (``_GMM_WEIGHT_TILE``): 0.318 / 0.648 / 0.711 ms at (128,
+    2048, 896) against 0.328 / 0.687 at (256, 2048, 896), 0.321 / 0.658
+    / 0.726 at n tile 512, 0.336 / 0.696 / 0.730 at (128, 1024, 1792);
+    [1,792 -> 2,048] 0.244 / 0.346 / 0.383 ms at (128, 1792, 1024)
+    against 0.255 / 0.359 at (256, 1792, 1024) and 0.234 / 0.343 /
+    0.383 at n tile 512: a row tile of 128 wins at m 256 (two row
+    tiles of a hit expert cost less than the wider one), 79% / 86% /
+    81% and 51% / 80% / 75% of the weights' time; ``ragged_dot`` 0.562
+    / 1.129 / 1.673 and 0.373 / 0.730 / 1.011 ms."""
+    tm = m if m < 256 else 128
     if 2048 < k <= 3072:
         return tm, k, next(t for t in (768, 512, 256, 128, n)
                            if n % t == 0)
     tk = k if k <= 2048 else next(
         t for t in (2048, 1024, 512, 256, 128, k) if k % t == 0)
     tn = n if tk <= 1024 and n <= 2048 else next(
-        t for t in (1792, 1536, 1408, 1024, 512, 256, 128, n)
-        if n % t == 0)
+        t for t in (1792, 1536, 1408, 1024, 896, 512, 256, 128, n)
+        if n % t == 0 and (tk * t <= _GMM_WEIGHT_TILE or t <= 128))
     return tm, tk, tn
 
 

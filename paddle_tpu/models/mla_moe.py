@@ -242,11 +242,12 @@ class GatedMLP(nn.Layer):
 
 class RoutedFFN(nn.Layer):
     """Routed experts: sigmoid-scored with a selection bias and a
-    shared expert (module docstring), or — ``gate="softmax"``,
-    ``n_shared`` 0 — the softmax top-k layer that has neither
-    (``models/sdar_moe.py``).  ``forward`` returns ``(y, stats)``,
-    ``stats`` int32 [3]: pairs computed, experts hit, the busiest
-    expert's pairs.
+    shared expert (module docstring; ``n_shared`` 0: without one, and
+    ``norm_eps`` what the family adds to the selected scores' sum,
+    ``models/lfm2_moe.py``), or — ``gate="softmax"``, ``n_shared`` 0 —
+    the softmax top-k layer that has neither (``models/sdar_moe.py``).
+    ``forward`` returns ``(y, stats)``, ``stats`` int32 [3]: pairs
+    computed, experts hit, the busiest expert's pairs.
 
     ``held = (first, count)`` is one chip's share of a layer that
     several chips hold by expert parallelism (``models/afmoe.py``):
@@ -257,7 +258,8 @@ class RoutedFFN(nn.Layer):
     live pairs that fell elsewhere.  None: every expert is held."""
 
     def __init__(self, hidden, width, num_experts, top_k, n_shared,
-                 scale, normalize=True, gate="sigmoid", held=None):
+                 scale, normalize=True, gate="sigmoid", held=None,
+                 norm_eps=1e-20):
         super().__init__()
         if gate not in ("sigmoid", "softmax"):
             raise ValueError(f"gate {gate!r}: 'sigmoid' or 'softmax'")
@@ -270,7 +272,7 @@ class RoutedFFN(nn.Layer):
         stacked = num_experts if held is None else self.held[1]
         self.num_experts, self.top_k = num_experts, top_k
         self.scale, self.normalize = float(scale), bool(normalize)
-        self.gate = gate
+        self.gate, self.norm_eps = gate, float(norm_eps)
         init = I.Normal(0.0, 0.02)
         self.gate_weight = self.create_parameter(
             [hidden, num_experts], default_initializer=init)
@@ -296,7 +298,7 @@ class RoutedFFN(nn.Layer):
                                             self.normalize)
         return moe.sigmoid_topk_routing(logits, self.gate_bias._data,
                                         self.top_k, self.scale,
-                                        self.normalize)
+                                        self.normalize, self.norm_eps)
 
     @_scoped("moe.experts")
     def experts(self, x, choice, weights, live):

@@ -166,11 +166,31 @@ class KVRowSpec:
     flat, and reads a head as a 128-lane slice of the fetched rows.
     Block and position bytes count what is stored, padding included:
     they are what a budget buys and what the gauges report
-    (``geometry()["rows"]`` has the row as the model wrote it)."""
+    (``geometry()["rows"]`` has the row as the model wrote it).
+
+    ``block_rows``: ``((name, width), ...)``, what a model keeps once a
+    BLOCK and not once a position: ONE pool ``[num_blocks, width]`` for
+    each, indexed by the same layer-invariant block id as the cached
+    rows (``n_layers`` then counts only the layers that keep ``rows``;
+    layers that share a pool lay their parts side by side in its
+    width).  The state of a layer whose memory does not grow with the
+    context lives there (``models/lfm2_moe.py``: a short convolution's
+    last ``L - 1`` inputs, kept as **the tail row of the block that
+    holds the position before** — a full block's tail is final and is
+    what a prefix hit continues from; a partial block's is its slot's
+    own), as an int8 pool's scale row does: it travels with its block
+    id through admission, adoption, eviction, preemption and resume,
+    and nothing a slot is added.  Such a row is ONE flat axis, so that
+    the pool's last two axes are ``(blocks, width)`` and fill whole
+    tiles by the rule above; the engine passes the pools as the second
+    list every step program takes and returns (where full attention
+    has its V pools), donated as the first; a block's bytes count
+    them."""
 
     LANES = 128
 
-    def __init__(self, n_layers, dtype, rows, heads_axis=False):
+    def __init__(self, n_layers, dtype, rows, heads_axis=False,
+                 block_rows=()):
         import numpy as np
         self.n_layers = int(n_layers)
         self.dtype = dtype
@@ -181,6 +201,15 @@ class KVRowSpec:
             raise ValueError("a KVRowSpec keeps at least one row")
         self._row_elems = sum(int(np.prod(self._stored(shape)))
                               for _, shape in self.rows)
+        self.block_rows = tuple((str(n), int(width))
+                                for n, width in block_rows)
+        if self.block_rows and (len(self.rows) != 1 or self.heads_axis):
+            raise ValueError(
+                "per-block rows take the pools' second list: beside "
+                "them a layer keeps ONE flat row a position, not "
+                f"{self.rows}")
+        self._block_elems = sum(self._stored((width,))[0]
+                                for _, width in self.block_rows)
 
     @classmethod
     def heads(cls, n_layers, num_heads, head_dim, dtype):
@@ -212,6 +241,12 @@ class KVRowSpec:
         return [tuple(leading) + self._stored(shape)
                 for _, shape in self.rows]
 
+    def block_pool_shapes(self, num_blocks):
+        """Shape of every per-block pool, in the order of
+        ``block_rows``."""
+        return [(int(num_blocks),) + self._stored((width,))
+                for _, width in self.block_rows]
+
     def position_bytes(self, dtype=None):
         """Bytes one cached position takes over all layers, as
         stored."""
@@ -238,6 +273,9 @@ class KVRowSpec:
                     f"num_heads ({self.num_heads}) must divide by mp "
                     f"({mp})")
         total = (int(block_size) * self.position_bytes(dtype)) // mp
+        # (per-block rows stay in the model's dtype, as a scale row
+        # stays float32)
+        total += self._block_elems * np.dtype(self.dtype).itemsize
         if scale_dtype is not None:
             total += (self.n_layers * len(self.rows)
                       * (self.num_heads // mp)
@@ -253,6 +291,9 @@ class KVRowSpec:
         else:
             geo["rows"] = [[n, list(shape)] for n, shape in self.rows]
         geo["n_layers"] = self.n_layers
+        if self.block_rows:
+            geo["block_rows"] = [[n, width]
+                                 for n, width in self.block_rows]
         return geo
 
 
@@ -455,12 +496,22 @@ class ServingSpec:
                        share of its routed experts (``{"held": [first,
                        count], "of": the router's width}``); None for
                        one that holds them all or has none
+    ``state``          what ``/healthz`` says (``layer_state``: ``state``
+                       is the replica's own, "ok" / "draining") of a
+                       model some of whose layers keep a state and no
+                       cached row (``{"conv":
+                       [L - 1, d], "layers": {"conv": n, "attention":
+                       m}, "per": "block"}``: the state lives in
+                       ``kv.block_rows``' pools, the engine moves block
+                       ids and never sees it); None for one whose
+                       layers all keep rows
     """
 
     def __init__(self, kv, max_positions, vocab_size, hidden_size,
                  tensor_parallel=False, counters=(), unsupported=None,
                  kernels=None, decode_rows=None, step=None,
-                 residual=None, attention=None, experts=None):
+                 residual=None, attention=None, experts=None,
+                 state=None):
         self.kv = kv
         self.max_positions = int(max_positions)
         self.vocab_size = int(vocab_size)
@@ -474,6 +525,7 @@ class ServingSpec:
         self.residual = dict(residual) if residual else None
         self.attention = dict(attention) if attention else None
         self.experts = dict(experts) if experts else None
+        self.state = dict(state) if state else None
 
 
 class ServedModel:

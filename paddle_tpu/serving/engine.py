@@ -656,6 +656,15 @@ class Engine:
             mp=mesh is not None,
             lora=adapters is not None or max_adapters is not None,
             offload=kv_host_mb is not None))
+        lacking = [f for f in self._ROWS_ONLY
+                   if sspec.kv.block_rows and f not in sspec.unsupported]
+        if lacking:
+            raise ValueError(
+                f"{type(model).__name__} keeps per-block rows "
+                f"(KVRowSpec.block_rows) in the pools' second list, "
+                f"which {lacking} pair with the first a layer at a "
+                "time as K with V: its ServingSpec.unsupported has to "
+                "name them")
         # -- overload protection: tenants, priorities, shedding ---------
         self._tenant_policies = {}
         self._buckets = {}
@@ -915,6 +924,20 @@ class Engine:
                     f"kv_block_size must be >= 1 and divide max_seq_len"
                     f" ({self.max_seq_len}), got {bsz}")
             self._check_aligned("kv_block_size", bsz)
+            if self._kvspec.block_rows and self._chunk \
+                    and self._chunk % bsz:
+                # a lane that is still prefilling takes a discarded
+                # decode step at its next chunk's first row, which
+                # writes the tail of the block that holds that row: the
+                # chunk rewrites it only if that block is not the one
+                # its own state comes from
+                raise ValueError(
+                    f"prefill_chunk ({self._chunk}) must be a multiple "
+                    f"of kv_block_size ({bsz}): "
+                    f"{type(self.model).__name__} keeps a state in "
+                    "every block's tail, and a chunk that starts "
+                    "inside a block would continue from a tail the "
+                    "lane's discarded decode step has overwritten")
             self._bs = bsz
             self._bps = self.max_seq_len // bsz  # blocks per full slot
             # per-shard footprint of ONE logical block: each mesh
@@ -1531,6 +1554,12 @@ class Engine:
         "sampling": "temperature / top_k / top_p (a sampled request)",
     }
 
+    # the features whose code takes ``v_pools`` to be a V pool a layer
+    # (``zip(k_pools, v_pools)``: the contiguous insert, int8 scales,
+    # the mesh's pool sharding, the host tier, the migration wire): a
+    # model whose second list holds per-block rows has to refuse them
+    _ROWS_ONLY = ("contiguous", "kv_int8", "mp", "offload", "migration")
+
     def _check_aligned(self, option, value):
         """A served model whose positions come in groups
         (``StepSpec.align``) keeps chunk starts and block edges on
@@ -1612,8 +1641,18 @@ class Engine:
         shapes = self._kvspec.pool_shapes(leading)
         layers = range(self._kvspec.n_layers)
         self.k_pools = [self._alloc_pool(shapes[0]) for _ in layers]
-        self.v_pools = ([self._alloc_pool(shapes[1]) for _ in layers]
-                        if len(shapes) > 1 else [])
+        if self._kvspec.block_rows:
+            # what a model keeps once a block (KVRowSpec.block_rows: a
+            # layer's state in its blocks' tails) takes the second
+            # list: [num_blocks, width] pools under the same block ids,
+            # donated and returned by the step programs as the row
+            # pools are
+            self.v_pools = [
+                self._alloc_pool(shape) for shape in
+                self._kvspec.block_pool_shapes(leading[0])]
+        else:
+            self.v_pools = ([self._alloc_pool(shapes[1]) for _ in layers]
+                            if len(shapes) > 1 else [])
         # where this engine runs, read off the pools themselves (fixed
         # per config): /healthz and /debug/requests report it, so a
         # caller asserts the chip through the server's own surface
@@ -3167,6 +3206,7 @@ class Engine:
                 "residual": self._serving_spec.residual,
                 "attention": self._serving_spec.attention,
                 "experts": self._serving_spec.experts,
+                "layer_state": self._serving_spec.state,
                 "async_depth": self.async_depth,
                 "tracing": bool(self.tracer.enabled),
                 "preemption": self._preemption,

@@ -396,6 +396,10 @@ class _Handler(JsonHandler):
                                      "attention", None),
                 "experts": getattr(getattr(eng, "_serving_spec", None),
                                    "experts", None),
+                # layers that keep a state in their blocks' tails and
+                # no cached row (ServingSpec.state), None without
+                "layer_state": getattr(getattr(eng, "_serving_spec",
+                                               None), "state", None),
                 # async-loop signals, next to the router-tier load
                 # signals: pipeline depth plus the mean overlapped
                 # host time and mean blocking d2h wait per tick —

@@ -97,6 +97,19 @@ scale pool obeys three invariants on top of the protocol above:
   refcount is shared — adopters always read exactly the scale the
   producer wrote.
 
+Per-block rows (PR 46, ``models/programs.py`` ``KVRowSpec.block_rows``):
+a served model some of whose layers keep a state and no cached row
+(``models/lfm2_moe.py``: a short convolution's last inputs) keeps it the
+same way, as block metadata: ``[num_blocks, width]`` pools in the
+engine's second list, ONE tail row a physical block a layer under the
+same layer-invariant block id.  The state before position p is the tail
+of the block that holds p - 1; a full block's tail is final and immutable
+while shared (what an adopter continues from), a partial block is its
+slot's own.  BlockPool and PrefixCache are unchanged: they track ids.
+``export_blocks`` / ``import_blocks`` and the host tier move rows only,
+so such a model refuses migration and offload by name; a caller of
+``BlockPool.cow`` that copies a block's rows copies its tail with them.
+
 ``import_blocks`` raises ``KVDtypeMismatch`` when the payload and the
 destination pools disagree about quantization (codes into fp pools,
 fp rows into quantized pools) BEFORE any geometry check — a
@@ -443,8 +456,9 @@ class BlockPool:
         """Copy-on-write: make the caller's reference to ``block``
         privately writable.  Sole owner -> the block itself (no copy).
         Shared -> the caller's ref moves to a fresh block and the
-        caller must copy the device rows; returns ``(writable_block,
-        needs_copy)``.  Raises NoFreeBlocks with the original ref
+        caller must copy the device rows (and the block's scale row or
+        tail, where the pools have one: module docstring); returns
+        ``(writable_block, needs_copy)``.  Raises NoFreeBlocks with the original ref
         intact if the pool is empty (evict, then retry).
 
         The serving engine adopts cached prefixes at FULL-block

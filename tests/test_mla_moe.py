@@ -741,6 +741,57 @@ def test_block_bytes_come_from_the_row_spec(spec, block, want):
             spec.block_bytes(block, mp=2)
 
 
+LFM2_SPEC = dict(n_layers=3, dtype="bfloat16", rows=(("kv", (1024,)),),
+                 block_rows=(("conv", 40960),))
+
+
+@pytest.mark.parametrize("tails", [(("conv", 40960),),
+                                   (("conv", 32768), ("gate", 8192))],
+                         ids=["one_pool", "two_pools"])
+@pytest.mark.parametrize("block, want", [(16, 180_224), (32, 278_528),
+                                         (64, 475_136)])
+def test_block_bytes_with_a_row_a_block(block, want, tails):
+    """3 layers keep a row of 1,024 a position, 10 keep a tail of 4,096
+    a BLOCK, side by side in one pool's width as
+    ``models/lfm2_moe.py`` declares them (``KVRowSpec.block_rows``: a
+    pool a name): a position is 3 x 1,024 x 2 B, a block its rows and
+    10 x 4,096 x 2 B of tails, whatever its size."""
+    spec = KVRowSpec(**dict(LFM2_SPEC, block_rows=tails))
+    assert spec.position_bytes() == 6_144
+    assert spec.block_bytes(block) == block * 6_144 + 81_920 == want
+    # what the bytes count is what the pools hold
+    held = (3 * int(np.prod(spec.pool_shapes((1, block))[0]))
+            + sum(int(np.prod(s)) for s in spec.block_pool_shapes(1)))
+    assert held * 2 == want
+    assert spec.geometry(block) == {
+        "block_size": block, "rows": [["kv", [1024]]], "n_layers": 3,
+        "block_rows": [list(t) for t in tails]}
+    with pytest.raises(ValueError, match="no head axis"):
+        spec.block_bytes(block, mp=2)
+
+
+def test_a_row_a_block_is_one_flat_axis_of_whole_tiles():
+    """The tile rule for ``[blocks, width]``: a per-block pool's last
+    two axes are (blocks, width), the width padded to whole 128-lane
+    tiles where it is wider than one and no multiple; and it takes
+    the pools' second list, so the rows are one flat pool a layer."""
+    spec = KVRowSpec(**LFM2_SPEC)
+    assert spec.block_pool_shapes(7) == [(7, 40960)]
+    padded = KVRowSpec(1, "float32", (("kv", (64,)),),
+                       block_rows=(("conv", 4100), ("gate", 4100)))
+    assert padded.block_pool_shapes(5) == [(5, 4224)] * 2
+    assert padded.block_bytes(8) == (8 * 64 + 2 * 4224) * 4
+    assert padded.geometry(8)["block_rows"] == [["conv", 4100],
+                                                ["gate", 4100]]
+    with pytest.raises(ValueError, match="second list"):
+        KVRowSpec(2, "float32", (("k", (4, 16)), ("v", (4, 16))),
+                  heads_axis=True, block_rows=(("conv", 128),))
+    # a spec without them reports none and allocates none
+    plain = KVRowSpec(2, "float32", (("latent", (40,)),))
+    assert plain.block_rows == () and plain.block_pool_shapes(9) == []
+    assert "block_rows" not in plain.geometry(8)
+
+
 def test_a_budget_buys_the_blocks_the_pools_hold():
     """A latent row wider than one tile of 128 lanes and no multiple
     of it (160 + 8 -> 256 stored): ``kv_budget_mb`` is spent on the
