@@ -7,8 +7,10 @@
 One process drives the main path through the entry points a user calls
 and fails on the first phase that is wrong:
 
-1. serving, XLA attention: ``GPTModel.from_config("gpt3-1.3b")`` in bf16
-   inside ``Engine(num_slots=8, max_seq_len=2048, kv_block_size=16,
+1. serving, the default path (``attn_impl="xla"``: XLA programs whose
+   decode attention core is, on one TPU, the Pallas kernel behind
+   ``_slot_attn``; ``/healthz`` ``attn_core`` says which form and why):
+   ``GPTModel.from_config("gpt3-1.3b")`` in bf16 inside ``Engine(num_slots=8, max_seq_len=2048, kv_block_size=16,
    prefill_chunk=128)`` inside ``EngineServer(port=0)``; two waves of
    eight concurrent ``POST /generate`` over the real socket (one of them
    streamed), then a third wave after ``jax.clear_caches()`` so the same
@@ -255,7 +257,16 @@ def serving_phase(name, model, sz, prompts, cache, *, attn_impl=None,
               f"{dev0.device_kind}")
         report["healthz"] = {k: hz[k] for k in (
             "platform", "device_kind", "device_ids", "attn_impl",
-            "mesh_shape")}
+            "attn_core", "mesh_shape")}
+        if attn_impl is None and hz["attn_core"] is not None:
+            # the decode attention's core: the kernel on one TPU at
+            # heads of whole lane tiles, the walk everywhere else
+            want = ("kernel" if dev0.platform == "tpu" and mesh is None
+                    and hz["attn_core"]["head_dim"] % 128 == 0
+                    else "walk")
+            check(hz["attn_core"]["form"] == want,
+                  f"/healthz attn_core is {hz['attn_core']}, wanted "
+                  f"the {want} on {dev0.platform}")
         n_dev = mesh[0] * mesh[1] if mesh else 1
         check(len(hz["device_ids"]) == n_dev,
               f"KV pools sit on devices {hz['device_ids']}, wanted "

@@ -57,6 +57,49 @@ def _gather_blocks(pool, cols):
     return blocks.reshape(cols.shape[0], -1, *blocks.shape[3:])
 
 
+def _backend():
+    """The platform the step programs are traced for (the process's
+    default backend)."""
+    import jax
+    return jax.default_backend()
+
+
+def _over_a_mesh(use_mp):
+    """Whether the program now being traced spans several devices: the
+    einsum form whose weights carry ``'mp'`` specs, or a mesh its
+    builder or the process published (``Engine(mesh=...)``)."""
+    from ..distributed import mesh as mesh_mod
+    return bool(use_mp) or mesh_mod.program_devices() > 1
+
+
+def slot_attn_core(platform, *, paged, quant, head_dim, mesh, table_rows,
+                   block_size):
+    """Which form the attention core of ``GPTAttention._slot_attn``
+    takes, from what the code can see, and why: ``("kernel", reason)``,
+    the Pallas kernel that streams each live slot's pages through VMEM
+    (``ops/ragged_paged_attn.py``), or ``("walk", reason)``, the XLA
+    work list.  ONE algorithm, an online softmax over each slot's own
+    rows, whose implementation follows the platform and the shapes:
+    the kernel is compiled by Mosaic, so it needs a TPU, paged pools of
+    plain floating point, heads of whole 128-lane tiles and a program
+    on one device (GSPMD cannot partition a Mosaic call); a table of at
+    most one chunk is read whole by either."""
+    if not paged:
+        return "walk", "contiguous cache: no pages to stream"
+    if quant:
+        return "walk", "int8 pools: the walk dequantizes at the gather"
+    if head_dim % 128:
+        return "walk", f"head size {head_dim}: not whole 128-lane tiles"
+    if platform != "tpu":
+        return "walk", f"platform {platform}: Mosaic compiles for a TPU"
+    if mesh:
+        return "walk", "a program over a mesh: GSPMD cannot partition " \
+            "a Mosaic call"
+    if table_rows <= walk_chunk(table_rows, block_size):
+        return "walk", "a table of one chunk is read whole"
+    return "kernel", "paged floating-point pools on one TPU"
+
+
 # Per-slot LoRA context (serving/lora.py).  Thread-local because jax
 # traces on the calling thread while sibling engines over ONE model
 # may trace concurrently — a plain module global would leak one
@@ -281,16 +324,7 @@ class GPTAttention(nn.Layer):
         probs = jax.nn.softmax(scores, axis=-1)
         ctx = jnp.einsum("bhqk,bkhd->bqhd", probs,
                          v_buf.astype(jnp.float32)).astype(qa.dtype)
-        out = Tensor(ctx)
-        if self.use_mp:
-            from ..ops import einsum
-            out = einsum("bshd,hde->bse", out, self.out_weight) + \
-                self.out_bias
-        else:
-            b = x.shape[0]
-            out = reshape(out, [b, S, self.num_heads * self.head_dim])
-            out = self._lora_out(out)
-        return out, k_buf, v_buf
+        return self._project(ctx), k_buf, v_buf
 
     def _qkv_step(self, x):
         """Fused QKV for a slot-pool window: Tensor [B, S, E] ->
@@ -342,6 +376,14 @@ class GPTAttention(nn.Layer):
         no longer than one chunk is read whole, without a loop.  The
         form is chosen from the shapes alone.
 
+        On one TPU, for paged floating-point pools at heads of whole
+        128-lane tiles, the same online softmax over the same rows runs
+        as ONE Pallas kernel instead (``slot_attn_core`` is the rule,
+        ``_stream_attn`` the call): each live slot's pages stream
+        through VMEM, the next step's copies in flight under the
+        arithmetic, rows contracted as stored with f32 sums and f32
+        probabilities, a parked lane a grid step and nothing else.
+
         qa [B, S, H, hd]; k_src/v_src ``[NB, bs, H, hd]`` pools (plain
         or ``QuantKV``) read through ``tables`` int32 [B, L // bs], or
         ``[B, L, H, hd]`` buffers where ``tables`` is None (the
@@ -356,6 +398,14 @@ class GPTAttention(nn.Layer):
         bs = None if tables is None else k_src.shape[1]
         table_rows = k_src.shape[1] if tables is None \
             else tables.shape[1] * bs
+        core, _ = slot_attn_core(
+            _backend(), paged=tables is not None,
+            quant=_is_quant_kv(k_src), head_dim=qa.shape[3],
+            mesh=_over_a_mesh(self.use_mp), table_rows=table_rows,
+            block_size=bs)
+        if core == "kernel":
+            return self._project(self._stream_attn(
+                qa, k_src, v_src, tables, pos))
         chunk = walk_chunk(table_rows, bs)
         n_chunks = -(-table_rows // chunk)
         scale = 1.0 / _math.sqrt(self.head_dim)
@@ -491,15 +541,35 @@ class GPTAttention(nn.Layer):
                                 1, n_chunks)
                 _, den, acc = jax.lax.fori_loop(0, live, trip, init)
             ctx = acc / per_ctx(den)
-        out = Tensor(ctx.astype(qa.dtype))
+        return self._project(ctx.astype(qa.dtype))
+
+    def _project(self, ctx):
+        """Output projection of the context [B, S, H, hd]: Tensor
+        [B, S, E]."""
+        out = Tensor(ctx)
         if self.use_mp:
             from ..ops import einsum
-            out = einsum("bshd,hde->bse", out, self.out_weight) + \
+            return einsum("bshd,hde->bse", out, self.out_weight) + \
                 self.out_bias
-        else:
-            out = reshape(out, [B, S, self.num_heads * self.head_dim])
-            out = self._lora_out(out)
-        return out
+        out = reshape(out, [ctx.shape[0], ctx.shape[1],
+                            self.num_heads * self.head_dim])
+        return self._lora_out(out)
+
+    @staticmethod
+    def _stream_attn(qa, k_pool, v_pool, tables, pos):
+        """``_slot_attn``'s core as the Pallas kernel: the same online
+        softmax over each slot's own rows (a slot at position 0 is
+        parked: no step, a context of zeros), the pools read in place
+        as ``[NB * bs, H, hd]`` rows."""
+        import jax.numpy as jnp
+        from ..ops.ragged_paged_attn import ragged_paged_attention
+
+        NB, bs, H, hd = k_pool.shape
+        width = jnp.where(pos > 0, qa.shape[1], 0).astype(jnp.int32)
+        return ragged_paged_attention(
+            qa, k_pool.reshape(NB * bs, H, hd),
+            v_pool.reshape(NB * bs, H, hd), tables, pos, width,
+            block_size=bs)
 
     @_scoped("attention")
     def decode_slots(self, x, k_buf, v_buf, pos):
@@ -749,15 +819,7 @@ class GPTAttention(nn.Layer):
                        block_size=bs)
             new_k = flat_k.reshape(k_pool.shape)
             new_v = flat_v.reshape(v_pool.shape)
-        out = Tensor(ctx)
-        if self.use_mp:
-            from ..ops import einsum
-            out = einsum("bshd,hde->bse", out, self.out_weight) + \
-                self.out_bias
-        else:
-            out = reshape(out, [B, W, self.num_heads * self.head_dim])
-            out = self._lora_out(out)
-        return out, new_k, new_v
+        return self._project(ctx), new_k, new_v
 
     @_scoped("attention")
     def prefill_chunk_paged(self, x, k_pool, v_pool, block_table, pos,
@@ -843,15 +905,7 @@ class GPTAttention(nn.Layer):
         probs = jax.nn.softmax(scores, axis=-1)
         ctx = jnp.einsum("bhqk,bkhd->bqhd", probs,
                          v_rows.astype(jnp.float32)).astype(qa.dtype)
-        out = Tensor(ctx)
-        if self.use_mp:
-            from ..ops import einsum
-            out = einsum("bshd,hde->bse", out, self.out_weight) + \
-                self.out_bias
-        else:
-            out = reshape(out, [1, C, self.num_heads * self.head_dim])
-            out = self._lora_out(out)
-        return out, new_k, new_v
+        return self._project(ctx), new_k, new_v
 
     @_scoped("attention")
     def forward(self, x, cache=None, doc_segments=None):
@@ -2656,12 +2710,25 @@ class GPTModel(ServedModel, nn.Layer):
         return ServingSpec(
             decode_rows=functools.partial(
                 walk_rows, row_width=2 * attn0.num_heads * attn0.head_dim),
+            attn_core=self._attn_core,
             kv=KVRowSpec.heads(len(self.blocks), attn0.num_heads,
                                attn0.head_dim, dtype),
             max_positions=emb.position_embeddings.weight.shape[0],
             vocab_size=emb.word_embeddings.weight.shape[0],
             hidden_size=emb.word_embeddings.weight.shape[1],
             tensor_parallel=attn0.use_mp)
+
+    def _attn_core(self, *, paged, quant, table_rows, block_size):
+        """``ServingSpec.attn_core``: the form ``_slot_attn`` takes
+        when the decode and verify programs are traced for such pools,
+        by the rule it applies itself (``slot_attn_core``)."""
+        attn0 = self.blocks[0].attn
+        form, why = slot_attn_core(
+            _backend(), paged=paged, quant=quant,
+            head_dim=attn0.head_dim, mesh=_over_a_mesh(attn0.use_mp),
+            table_rows=table_rows, block_size=block_size)
+        return {"form": form, "why": why, "platform": _backend(),
+                "head_dim": attn0.head_dim}
 
     def serving_linear_stacks(self):
         """The layers whose ``nn.Linear`` children weight-only int8

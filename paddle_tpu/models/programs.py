@@ -478,6 +478,15 @@ class ServingSpec:
                        out: ``walk_rows``, the work list of (slot,
                        chunk) items that reads each slot to its own
                        window's end
+    ``attn_core``      ``(paged, quant, table_rows, block_size) -> dict``
+                       of a model whose decode attention has two forms
+                       and picks one when it traces (``{"form":
+                       "kernel" | "walk", "why": ..., "platform": ...,
+                       "head_dim": ...}``): the engine asks it at
+                       construction for ``/healthz`` ``attn_core``,
+                       ``serving.attn_kernel_dispatches`` and the
+                       kernel's compile check; None for one that
+                       always walks
     ``step``           ``StepSpec`` of a model whose step is not one
                        row and one token a lane; None for one that is
     ``residual``       what ``/healthz`` says of a residual that is not
@@ -511,7 +520,7 @@ class ServingSpec:
                  tensor_parallel=False, counters=(), unsupported=None,
                  kernels=None, decode_rows=None, step=None,
                  residual=None, attention=None, experts=None,
-                 state=None):
+                 state=None, attn_core=None):
         self.kv = kv
         self.max_positions = int(max_positions)
         self.vocab_size = int(vocab_size)
@@ -521,6 +530,7 @@ class ServingSpec:
         self.unsupported = dict(unsupported or {})
         self.kernels = dict(kernels or {})
         self.decode_rows = decode_rows or walk_rows
+        self.attn_core = attn_core
         self.step = step
         self.residual = dict(residual) if residual else None
         self.attention = dict(attention) if attention else None

@@ -375,10 +375,18 @@ class Engine:
     attn_impl : which attention implementation serves the paged
         window dispatches.  ``None`` (default) inherits the model's
         ``GPTModel(attn_impl=...)`` knob (itself defaulting to
-        ``"xla"``).  ``"xla"`` keeps the pure-XLA gather/scatter
-        programs — one compiled executable per (layout, chunk shape,
-        spec_k) window SHAPE — and remains the CPU tier-1 parity
-        oracle.  ``"ragged"`` (requires the paged layout) routes the
+        ``"xla"``).  ``"xla"`` keeps one compiled executable per
+        (layout, chunk shape, spec_k) window SHAPE — and remains the
+        CPU tier-1 parity oracle.  Its decode and verify programs'
+        attention core is the model's to choose when it traces them
+        (``ServingSpec.attn_core``; GPT: the XLA walk, or on one TPU
+        with paged floating-point pools at heads of 128 the same
+        Pallas kernel as below, streaming each live slot's pages:
+        ``/healthz`` ``attn_core`` names the form and why,
+        ``serving.attn_kernel_dispatches`` counts it, and
+        construction compiles the kernel through Mosaic at the decode
+        and verify windows, raising the compiler's message if it is
+        refused).  ``"ragged"`` (requires the paged layout) routes the
         decode, spec-verify, and chunked-prefill
         attention core through the Pallas RAGGED PAGED ATTENTION
         kernel (ops/ragged_paged_attn.py; interpret mode on the cpu
@@ -395,9 +403,9 @@ class Engine:
         ``serving.compiles_total`` and the ``decode.ragged_stream``
         trace span (plus ``serving.kv_blocks_walked_per_tick``).
         The kernel body is the flash-style ONLINE-SOFTMAX streaming
-        loop: K/V are consumed one paged block at a time up to each
+        loop: K/V are consumed a step of pages at a time up to each
         lane's causal horizon, so the per-slot working set is
-        O(block_size x window) — independent of context length — and
+        O(step rows x window) — independent of context length — and
         long contexts are first-class.  Numerics: allclose to the XLA
         oracle (online softmax reorders float summation); GREEDY
         streams are token-identical to the XLA path end-to-end across
@@ -1068,6 +1076,44 @@ class Engine:
                         f"attn_impl={attn_impl!r} does not compile "
                         f"for {dev.device_kind}: {e}") from e
         self._ragged_fn = None  # resolved jitted ragged-window handle
+        # -- the decode attention's core: kernel or walk ----------------
+        # the model chooses from what it can see (platform, layout,
+        # pool dtype, head size, mesh: models/gpt.py slot_attn_core)
+        # when it traces the decode / verify programs; the engine asks
+        # the same rule here for its counters and /healthz, and has
+        # Mosaic take the kernel NOW at the shapes those programs
+        # will compile
+        self._attn_core = None
+        if sspec.attn_core is not None and not self._ragged:
+            self._attn_core = dict(sspec.attn_core(
+                paged=self._paged, quant=self._kv_quant,
+                table_rows=self.max_seq_len,
+                block_size=self._bs if self._paged else None),
+                pool_dtype=self._kv_dtype_str)
+        self._attn_kernel = (self._attn_core or {}).get("form") == "kernel"
+        # the host twin of what the kernel fetches (_rows_walked); None:
+        # the served model's own rule, ServingSpec.decode_rows
+        self._kernel_rows = None
+        if self._attn_kernel:
+            import jax
+            from ..ops.ragged_paged_attn import compile_check, stream_rows
+            self._kernel_rows = stream_rows
+            dev = jax.devices()[0]
+            if dev.platform != "cpu":
+                for window in sorted({1, (self._spec_k or 0) + 1}):
+                    try:
+                        compile_check(
+                            num_slots=self.num_slots, window=window,
+                            num_heads=self._nh, head_dim=self._hd,
+                            block_size=self._bs,
+                            blocks_per_slot=self._bps,
+                            num_blocks=self._kv_managed + 1,
+                            dtype=self._kv_dtype, device=dev)
+                    except Exception as e:
+                        raise ValueError(
+                            "the decode attention's kernel does not "
+                            f"compile for {dev.device_kind} at a window "
+                            f"of {window}: {e}") from e
         self._zero_scale_fn = None  # jitted fresh-block scale zeroer
         #   (kv_dtype='int8'; compiled once per config — see
         #   _zero_fresh_scales)
@@ -1270,15 +1316,27 @@ class Engine:
             "serving.fused_sample_ticks", "decode dispatches that "
             "sampled on device")
         self._m_rows_walked = reg.counter(
-            "serving.decode_rows_walked", "cache rows the XLA decode / "
-            "verify dispatches walked, summed over slots: how far the "
-            "slot-window attention goes is the served model's rule "
-            "(ServingSpec.decode_rows; models/programs.py walk_rows: "
-            "each slot to its own window's end, a work list of (slot, "
-            "chunk) items taken a whole trip at a time), applied to "
-            "the host's position mirror; over "
+            "serving.decode_rows_walked", "cache rows the decode / "
+            "verify dispatches fetched, summed over slots, from the "
+            "host's position mirror.  Where the attention core is the "
+            "kernel (serving.attn_kernel_dispatches; "
+            "ops/ragged_paged_attn.py stream_rows): every live slot to "
+            "its own window's end in whole steps of 256 rows' pages, "
+            "each step copied into one of two VMEM buffers a pool "
+            "while the other is contracted, rows as stored, sums and "
+            "weights float32; nothing for a parked slot.  Where it is "
+            "the XLA walk, the served model's rule "
+            "(ServingSpec.decode_rows; models/programs.py walk_rows: a "
+            "work list of (slot, chunk) items taken a whole trip at a "
+            "time, the last trip's padding items included).  Over "
             "serving.decode_rows_table it is the share of the table "
             "read, 1.0 = every row of every slot")
+        self._m_attn_kernel = reg.counter(
+            "serving.attn_kernel_dispatches", "decode and verify "
+            "dispatches whose attention core is the Pallas kernel that "
+            "streams each live slot's pages (the host knows it from "
+            "the program it built: /healthz attn_core); the others "
+            "of serving.fused_sample_ticks walked in XLA")
         self._m_rows_table = reg.counter(
             "serving.decode_rows_table", "cache rows of every slot's "
             "whole table, summed over the same dispatches")
@@ -3189,6 +3247,7 @@ class Engine:
                 "prefill_chunk": self._chunk,
                 "spec_k": self._spec_k,
                 "attn_impl": self.attn_impl,
+                "attn_core": self._attn_core,
                 "max_context_len": self._max_context_len,
                 "mesh_shape": self.mesh_axes,
                 "mp": self.mp,
@@ -3518,13 +3577,17 @@ class Engine:
         the position mirror: the mirror trails the device by the ticks
         in flight, each of which moved a lane by at most ``width``
         rows.  How far the walk goes is the served model's to say
-        (``ServingSpec.decode_rows``).  Counted into
+        (``ServingSpec.decode_rows``), or the kernel's where the
+        attention core is the kernel (``stream_rows``; the dispatch
+        is then one of ``serving.attn_kernel_dispatches``).  Counted into
         ``serving.decode_rows_walked`` / ``_live`` / ``_table``;
         returned for the ``decode.dispatch`` span."""
         ahead = width * (1 + len(self._ring))
-        walked = self._serving_spec.decode_rows(
+        walked = (self._kernel_rows or self._serving_spec.decode_rows)(
             self._pos, ahead, self.max_seq_len,
             self._bs if self._paged else None)
+        if self._attn_kernel:
+            self._m_attn_kernel.inc()
         self._m_rows_walked.inc(walked)
         self._m_rows_live.inc(int(np.minimum(
             self._pos[self._pos > 0] + ahead, self.max_seq_len).sum()))

@@ -337,6 +337,12 @@ class _Handler(JsonHandler):
                 # programs (the CPU parity oracle); the router copies
                 # this into its registry signals like kv_dtype
                 "attn_impl": getattr(eng, "attn_impl", "xla"),
+                # the form the decode / verify programs' attention core
+                # took and why: {"form": "kernel" | "walk", "why",
+                # "platform", "head_dim", "pool_dtype"} (the model's
+                # rule, models/gpt.py slot_attn_core); None for a model
+                # that always walks and under attn_impl="ragged"
+                "attn_core": getattr(eng, "_attn_core", None),
                 # long-context exposure: max context length (prompt +
                 # decoded) any request has reached on this replica
                 "max_context_len": getattr(

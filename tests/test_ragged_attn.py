@@ -613,6 +613,377 @@ def test_gpt_decode_walks_a_work_list_on_the_v5e():
                           text)
 
 
+# -- the decode attention's core: the kernel behind _slot_attn --------
+
+@pytest.fixture
+def one_device(monkeypatch):
+    """No process-wide mesh while the test runs: an earlier test of the
+    same worker may have left one (``Engine(mesh=...)``, ``fleet.init``
+    publish theirs), and a program over a mesh keeps the walk."""
+    from paddle_tpu.distributed import mesh as mesh_mod
+    monkeypatch.setattr(mesh_mod, "_global_mesh", None)
+
+
+def _core_attn(heads=2):
+    """A ``GPTAttention`` at heads of 128 (the kernel's tile) with
+    seeded float32 weights."""
+    from paddle_tpu.models.gpt import GPTAttention
+    paddle.seed(3)
+    return GPTAttention(heads * 128, heads, dropout=0.0)
+
+
+def _core_case(pages, window, tail, bs=8, slots=8, rng=None):
+    """Pools, tables and positions whose windows end at every edge a
+    step of ``pages`` pages has: inside a step, at a step's last row
+    and at the next step's first, inside the first block, a slot two
+    steps and a half long; parked lanes between the live ones; the
+    last slot live (``tail`` "live": its step has no successor to
+    prefetch for) or parked."""
+    rng = rng or np.random.RandomState(pages * 10 + window)
+    R = pages * bs
+    # a table of no whole steps, longer than the one chunk (256 rows)
+    # that either form reads whole
+    nb = max(3 * pages + 2, 34)
+    NB = slots * nb + 1
+    ends = [0, R - window, 0, R - window + 1, 5 - min(window, 4),
+            R + R // 2, 0, 2 * R + R // 2]
+    if tail == "parked":
+        ends[-1], ends[2] = 0, 2 * R + R // 2
+    pos = np.asarray(ends, np.int32)
+    assert pos.max() + window <= nb * bs and pos.min() >= 0
+    tables = np.zeros((slots, nb), np.int32)
+    order = rng.permutation(np.arange(1, NB))
+    for b in range(slots):
+        if pos[b]:
+            n = -(-(pos[b] + window) // bs)
+            tables[b, :n] = order[b * nb:b * nb + n]
+    pool = (NB, bs, 2, 128)
+    return (rng.randn(slots, window, 256).astype(np.float32),
+            rng.randn(*pool).astype(np.float32),
+            rng.randn(*pool).astype(np.float32), tables, pos)
+
+
+def _slot_attn_as(monkeypatch, platform, attn, x, k_pool, v_pool,
+                  tables, pos):
+    """``_slot_attn``'s output when the step programs are traced for
+    ``platform`` (the kernel itself runs interpreted on this CPU)."""
+    import jax.numpy as jnp
+    from paddle_tpu.models import gpt
+    monkeypatch.setattr(gpt, "_backend", lambda: platform)
+    qa, _, _ = attn._qkv_step(paddle.Tensor(jnp.asarray(x)))
+    return np.asarray(attn._slot_attn(
+        qa, jnp.asarray(k_pool), jnp.asarray(v_pool),
+        None if tables is None else jnp.asarray(tables),
+        jnp.asarray(pos))._data)
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("tail", ["live", "parked"])
+@pytest.mark.parametrize("window", [1, 3])
+@pytest.mark.parametrize("pages", [8, 16, 32])
+def test_slot_attn_kernel_matches_the_walk(one_device, monkeypatch, pages, window,
+                                           tail):
+    """The kernel behind ``_slot_attn`` against the walk it replaces on
+    a TPU, on the edges its body adds: a slot whose rows end inside a
+    step of pages, at a step's last row and at the next step's first,
+    a slot of one block, parked lanes between live ones, a last live
+    slot whose prefetch has no successor, the decode window (1) and
+    the verify window (k + 1), 8 / 16 / 32 pages a step.  Both are an
+    online softmax over the same rows; a parked lane (position 0)
+    gives the projection of zeros in both."""
+    from paddle_tpu.ops import ragged_paged_attn as rpa
+    attn = _core_attn()
+    case = _core_case(pages, window, tail)
+    monkeypatch.setattr(rpa, "_STEP_ROWS", pages * 8)
+    assert _traces_the_kernel(monkeypatch, "tpu", attn, *case)
+    walk = _slot_attn_as(monkeypatch, "cpu", attn, *case)
+    kernel = _slot_attn_as(monkeypatch, "tpu", attn, *case)
+    np.testing.assert_allclose(kernel, walk, rtol=2e-5, atol=2e-5)
+    parked = case[4] == 0
+    assert parked.any() and not parked.all()
+    assert np.array_equal(kernel[parked], walk[parked])
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("W", [1, 2])
+def test_slot_attn_kernel_contracts_bfloat16_rows_as_stored(W):
+    """bf16 queries over bf16 pools, a decode row (W 1) and a window
+    (W 2), never upcast outside VMEM: the rows go to the matrix unit
+    as they are (two heads a 32-bit word, cut apart by a shift and a
+    mask), the sums and the weights stay float32 (the weights as three
+    bf16 terms over ONE pass of the rows), so the kernel stands as
+    close to the float32 oracle over the same bf16 values as float32
+    arithmetic does: far inside one bf16 step (2 ** -8) of the
+    context."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.ragged_paged_attn import ragged_paged_attention
+
+    rng = np.random.RandomState(5)
+    B, H, hd, bs, nb, NB = 3, 4, 128, 8, 9, 40
+
+    def bf16(*shape):
+        return jnp.asarray(rng.randn(*shape).astype(np.float32)
+                           ).astype(jnp.bfloat16)
+    q, k, v = bf16(B, W, H, hd), bf16(NB * bs, H, hd), bf16(NB * bs, H, hd)
+    tables = jnp.asarray(rng.randint(0, NB, (B, nb)).astype(np.int32))
+    pos = jnp.asarray(np.array([70, 0, 33], np.int32))
+    width = jnp.asarray(np.array([W, 0, 1], np.int32))
+    out = ragged_paged_attention(q, k, v, tables, pos, width,
+                                 block_size=bs, pages=4)
+    assert out.dtype == jnp.bfloat16
+    ctx = _kernel_oracle(q, k, v, tables, pos, width, bs)
+    for b in (0, 2):
+        w = int(width[b])
+        # the output is rounded to bf16 once: half a step of 2 ** -8
+        np.testing.assert_allclose(
+            np.asarray(out[b, :w].astype(jnp.float32)), ctx[b, :w],
+            rtol=2 ** -8, atol=2 ** -9)
+    assert not np.asarray(out[1].astype(jnp.float32)).any()
+
+
+_CORE = dict(paged=True, quant=False, head_dim=128, mesh=False,
+             table_rows=2048, block_size=16)
+
+
+@pytest.mark.parametrize("platform,change,form", [
+    ("tpu", {}, "kernel"),
+    ("cpu", {}, "walk"),
+    ("gpu", {}, "walk"),
+    ("tpu", {"quant": True}, "walk"),
+    ("tpu", {"head_dim": 64}, "walk"),
+    ("tpu", {"paged": False, "block_size": None}, "walk"),
+    ("tpu", {"mesh": True}, "walk"),
+    ("tpu", {"table_rows": 256}, "walk"),
+    ("tpu", {"head_dim": 256, "block_size": 32}, "kernel"),
+])
+def test_slot_attn_core_rule(platform, change, form):
+    """The ONE place that chooses between the kernel and the walk, from
+    platform, layout, pool dtype, head size, mesh and table length,
+    with a reason ``/healthz`` can show."""
+    from paddle_tpu.models.gpt import slot_attn_core
+    got, why = slot_attn_core(platform, **{**_CORE, **change})
+    assert got == form and why
+
+
+def _traces_the_kernel(monkeypatch, platform, attn, *case):
+    """Whether ``_slot_attn``, traced for ``platform``, holds a Pallas
+    call."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models import gpt
+    monkeypatch.setattr(gpt, "_backend", lambda: platform)
+    x, k_pool, v_pool, tables, pos = case
+    if tables is not None:
+        tables = jnp.asarray(tables)
+
+    def run(x, k_pool, v_pool, pos):
+        qa, _, _ = attn._qkv_step(paddle.Tensor(x))
+        return attn._slot_attn(qa, k_pool, v_pool, tables, pos)._data
+    return "pallas_call" in str(jax.make_jaxpr(run)(
+        jnp.asarray(x), k_pool, v_pool, jnp.asarray(pos)))
+
+
+@pytest.mark.pallas
+def test_slot_attn_takes_the_form_the_rule_names(one_device, monkeypatch):
+    """``_slot_attn`` itself, traced and not run: the kernel for paged
+    float pools at heads of 128 on a TPU platform string; the walk on
+    the CPU, for ``QuantKV`` pools, at heads of 64, for a contiguous
+    cache, for the einsum form whose weights are sharded, under a
+    process-wide mesh, and for a table of one chunk."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.distributed import mesh as mesh_mod
+    from paddle_tpu.models.gpt import GPTAttention
+    from paddle_tpu.serving.quant import QuantKV
+
+    attn = _core_attn()
+    x, k_pool, v_pool, tables, pos = _core_case(8, 1, "live")
+    k_pool, v_pool = jnp.asarray(k_pool), jnp.asarray(v_pool)
+    case = (x, k_pool, v_pool, tables, pos)
+    assert _traces_the_kernel(monkeypatch, "tpu", attn, *case)
+    assert not _traces_the_kernel(monkeypatch, "cpu", attn, *case)
+    # int8 pools: codes and one scale a block and head
+    quant = [QuantKV(jnp.zeros(p.shape, jnp.int8),
+                     jnp.ones(p.shape[:1] + p.shape[2:3], jnp.float32))
+             for p in (k_pool, v_pool)]
+    assert not _traces_the_kernel(monkeypatch, "tpu", attn, x, *quant,
+                                  tables, pos)
+    # heads of 64: the same pools' numbers as four heads
+    paddle.seed(3)
+    narrow = GPTAttention(256, 4, dropout=0.0)
+    assert not _traces_the_kernel(
+        monkeypatch, "tpu", narrow, x,
+        k_pool.reshape(k_pool.shape[:2] + (4, 64)),
+        v_pool.reshape(v_pool.shape[:2] + (4, 64)), tables, pos)
+    # a contiguous cache: [B, L, H, hd] buffers and no tables
+    bufs = [jnp.zeros((8, 512, 2, 128), jnp.float32)] * 2
+    assert not _traces_the_kernel(monkeypatch, "tpu", attn, x, *bufs,
+                                  None, pos)
+    # a table of one chunk (256 rows)
+    assert not _traces_the_kernel(monkeypatch, "tpu", attn, x, k_pool,
+                                  v_pool, tables[:, :32],
+                                  np.minimum(pos, 200))
+    # a process-wide mesh (``Engine(mesh=...)`` publishes one)
+    devices = np.asarray(jax.devices()[:2]).reshape(2, 1)
+    monkeypatch.setattr(mesh_mod, "_global_mesh",
+                        jax.sharding.Mesh(devices, ("mp", "dp")))
+    assert not _traces_the_kernel(monkeypatch, "tpu", attn, *case)
+    monkeypatch.setattr(mesh_mod, "_global_mesh", None)
+    # the einsum form whose weights carry 'mp' specs
+    paddle.seed(3)
+    sharded = GPTAttention(256, 2, dropout=0.0, use_mp=True)
+    assert not _traces_the_kernel(monkeypatch, "tpu", sharded, *case)
+
+
+@pytest.mark.parametrize("ahead", [1, 4])
+def test_stream_rows_counts_what_the_kernel_fetches(ahead):
+    """``stream_rows``, the host's count behind
+    ``serving.decode_rows_walked`` where the core is the kernel,
+    against a brute-force count of the kernel's copies: for every live
+    slot, page after page until the one that holds the window's last
+    row, nothing for a parked slot, no page past a slot's last and no
+    padding items (``walk_rows`` counts whole trips of 8 items of 256
+    rows)."""
+    from paddle_tpu.models.programs import walk_rows
+    from paddle_tpu.ops.ragged_paged_attn import stream_rows
+    bs, table_rows = 16, 2048
+    rng = np.random.RandomState(11)
+    for _ in range(20):
+        pos = rng.randint(1, table_rows - ahead, 32)
+        pos[rng.rand(32) < 0.7] = 0
+        brute = 0
+        for p in pos:
+            page = 0
+            while p and page * bs <= p + ahead - 1:
+                brute += bs
+                page += 1
+        got = stream_rows(pos, ahead, table_rows, bs)
+        assert got == brute
+        live = int(np.minimum(pos[pos > 0] + ahead, table_rows).sum())
+        assert live <= got < live + bs * int((pos > 0).sum())
+        assert got <= walk_rows(pos, ahead, table_rows, bs,
+                                row_width=2 * 16 * 128)
+    assert stream_rows(np.zeros(32, int), ahead, table_rows, bs) == 0
+
+
+@pytest.fixture
+def core_gpt():
+    """Two layers at heads of 128 and a 512-entry position table: the
+    smallest model whose decode attention can take the kernel.  A new
+    model a test: the model keeps its step programs by shape."""
+    paddle.seed(0)
+    m = GPTModel(num_layers=2, hidden_size=256, num_heads=2,
+                 vocab_size=128, max_position=512, dropout=0.0)
+    m.eval()
+    return m
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("spec_k", [None, 2])
+def test_engine_decodes_through_the_kernel(one_device, monkeypatch, core_gpt, spec_k):
+    """An engine whose model traces for a TPU platform (the kernel runs
+    interpreted here): greedy streams token-identical to
+    ``generate()`` through decode (S = 1) and speculative verify
+    (S = k + 1), prompts on both sides of one 256-row step; every
+    decode dispatch counted in ``serving.attn_kernel_dispatches``, the
+    rows by the kernel's rule, and ``/healthz`` names the form and
+    why."""
+    from paddle_tpu.models import gpt
+    rng = np.random.RandomState(7)
+    prompts = [rng.randint(0, 128, (n,)).astype(np.int32)
+               for n in (5, 300, 253)]
+    refs = [_ref(core_gpt, p, 5).tolist() for p in prompts]
+    monkeypatch.setattr(gpt, "_backend", lambda: "tpu")
+    kw = {"spec_k": spec_k} if spec_k else {}
+    eng = _engine(core_gpt, num_slots=4, max_seq_len=512, **kw)
+    core = eng.debug_requests()["engine"]["attn_core"]
+    assert core == {"form": "kernel", "platform": "tpu", "head_dim": 128,
+                    "pool_dtype": "float32", "why": core["why"]}
+    reqs = [eng.submit(p, max_new_tokens=5) for p in prompts]
+    eng.run_until_idle()
+    assert [r.result(timeout=2).tolist() for r in reqs] == refs
+    reg = eng.registry
+    ticks = reg.get("serving.fused_sample_ticks").value
+    assert ticks > 0
+    assert reg.get("serving.attn_kernel_dispatches").value == ticks
+    walked = reg.get("serving.decode_rows_walked").value
+    live = reg.get("serving.decode_rows_live").value
+    # whole blocks of 8 rows, at most one a live slot past its rows
+    assert walked % 8 == 0 and live <= walked < live + 8 * 3 * ticks
+
+
+def test_engine_names_the_walk_and_why(tiny_gpt, core_gpt):
+    """On the CPU, at heads of 16 and for int8 pools the engine keeps
+    the walk, says so in ``/healthz`` and counts no kernel dispatch."""
+    for model, kw, word in ((tiny_gpt, {}, "head size 16"),
+                            (core_gpt, {"max_seq_len": 512}, "platform cpu"),
+                            (core_gpt, {"max_seq_len": 512,
+                                        "kv_dtype": "int8"}, "int8")):
+        eng = _engine(model, **kw)
+        core = eng.debug_requests()["engine"]["attn_core"]
+        assert core["form"] == "walk" and word in core["why"]
+        eng.submit(_prompts(1)[0], max_new_tokens=3)
+        eng.run_until_idle()
+        assert eng.registry.get(
+            "serving.attn_kernel_dispatches").value == 0
+        assert eng.registry.get("serving.fused_sample_ticks").value > 0
+    ragged = _engine(core_gpt, max_seq_len=512, attn_impl="ragged")
+    assert ragged.debug_requests()["engine"]["attn_core"] is None
+
+
+@pytest.mark.pallas
+def test_gpt_decode_streams_pages_through_one_kernel_on_the_v5e(
+        one_device, monkeypatch):
+    """GPT's paged decode attention at the widths ``gpt3-1.3b-serve``
+    runs, traced for a TPU and compiled for the compile-only ``TPU v5
+    lite`` device: no loop and no gather of cached rows is left where
+    the walk stood, ONE Mosaic call a layer reads both pools where
+    they lie (nothing copies, transposes or converts a pool), and the
+    engine's compile check takes the decode and the verify window."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu import nn
+    from paddle_tpu.jit import _swapped
+    from paddle_tpu.models.gpt import GPTAttention
+    from paddle_tpu.ops.ragged_paged_attn import compile_check
+
+    with nn.LazyGuard():
+        attn = GPTAttention(2048, 16, dropout=0.0)
+    attn.to(dtype="bfloat16")
+    params = dict(attn.named_parameters())
+    names = sorted(params)
+    pool_shape = (3073, 16, 16, 128)
+
+    def step(p_list, x, k_pool, v_pool, tables, pos):
+        with _swapped(params, dict(zip(names, p_list))):
+            out, k_pool, v_pool = attn.decode_slots_paged(
+                paddle.Tensor(x), k_pool, v_pool, tables, pos)
+        return out._data, k_pool, v_pool
+
+    with _described_v5e() as sds:
+        # the described device first: looking it up asks for the
+        # process's real backend
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        text = jax.jit(step, donate_argnums=(2, 3)).lower(
+            [sds(params[n].shape) for n in names], sds((32, 1, 2048)),
+            sds(pool_shape), sds(pool_shape), sds((32, 128), jnp.int32),
+            sds((32,), jnp.int32)).compile().as_text()
+        dev = sds((1,)).sharding._device
+        for window in (1, 5):
+            compile_check(num_slots=32, window=window, num_heads=16,
+                          head_dim=128, block_size=16,
+                          blocks_per_slot=128, num_blocks=3073,
+                          dtype=jnp.bfloat16, device=dev)
+    assert " while(" not in text
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 1
+    assert "ragged_paged_attn_stream" in text
+    assert not re.findall(r"= bf16\[[\d,]*16,128\]\S* gather\(", text)
+    assert not re.findall(
+        r"= (?:bf16|f32)\[(?:3073,16|49168),16,128\]\S* "
+        r"(?:copy|transpose|convert)\(", text)
+
+
 # -- knob validation --------------------------------------------------
 
 def test_attn_impl_validation(tiny_gpt):
