@@ -58,6 +58,7 @@ than 1 are refused by name), rope scaling, the load-balance loss
 """
 from __future__ import annotations
 
+import functools
 import math
 
 from .. import nn
@@ -66,8 +67,8 @@ from ..nn import initializer as I
 from .mla_moe import MOE_COUNTERS, GatedMLP, RMSNorm, RoutedFFN, _lin
 from .programs import (
     KVRowSpec, ServedModel, ServingSpec, _scoped, sample_lanes,
-    slot_sample_keys, walk_chunk, walk_group, walk_plan, walk_rows)
-from .sdar_moe import GQAttention
+    slot_sample_keys, walk_chunk, walk_group, walk_plan)
+from .sdar_moe import GQAttention, walk_kernel_check
 
 KINDS = ("sliding_attention", "full_attention")
 
@@ -111,19 +112,25 @@ class GatedGQAttention(GQAttention):
         return _lin(self.o_proj, ctx * jax.nn.sigmoid(
             _lin(self.gate_proj, h)))
 
-    def rows_walked(self, pos, slots, table_rows, bs, width):
-        """Device twin of ``walk_rows``: the cache rows ``attend``
-        fetches for ``slots`` slots whose rows start at ``pos`` [slots]
-        (each kind of walk as ``attend`` picks it), int32 []."""
+    def rows_walked(self, pos, slots, pool, table_rows):
+        """Device twin of ``GQAttention.decode_rows``: the cache rows
+        ``attend`` fetches from ``pool`` for ``slots`` slots whose rows
+        start at ``pos`` [slots] (each kind of walk as ``attend`` picks
+        it, a trip of the work list in the form ``core`` names: whole
+        trips in XLA, the items alone in the kernel), int32 []."""
         import jax.numpy as jnp
+        bs = pool.shape[1]
         chunk = walk_chunk(table_rows, bs)
         trips = -(-table_rows // chunk)
         if trips == 1:
             return jnp.int32(slots * table_rows)
         if slots > 1:
-            group = walk_group(slots, width)
-            return walk_plan(pos, 0, table_rows, chunk, group,
-                             self.reach)[3] * (group * chunk)
+            group = walk_group(slots, pool.shape[2])
+            _, _, valid, n_trips = walk_plan(pos, 0, table_rows, chunk,
+                                             group, self.reach)
+            if self.core(slots, pool, table_rows) == "kernel":
+                return jnp.sum(valid, dtype=jnp.int32) * chunk
+            return n_trips * (group * chunk)
         first, end = self.one_slot_span(pos, chunk, trips)
         return (end - first) * chunk
 
@@ -279,11 +286,11 @@ class AfmoeModel(ServedModel, nn.Layer):
         """{kind: rows the walks of the layers of that kind fetch} for
         rows that start at ``pos`` [slots]."""
         import jax.numpy as jnp
-        bs, width = pools[0].shape[1], pools[0].shape[2]
+        table_rows = tables.shape[-1] * pools[0].shape[1]
         walked = {k: jnp.int32(0) for k in KINDS}
-        for blk in self.blocks:
+        for blk, pool in zip(self.blocks, pools):
             walked[blk.kind] = walked[blk.kind] + blk.attn.rows_walked(
-                pos, slots, tables.shape[-1] * bs, bs, width)
+                pos, slots, pool, table_rows)
         return walked
 
     def _rows_seen(self, end):
@@ -381,17 +388,17 @@ class AfmoeModel(ServedModel, nn.Layer):
                              pnames, body)
 
     # -- the serving seam ----------------------------------------------
-    def decode_rows(self, pos, ahead, table_rows, block_size):
-        """``ServingSpec.decode_rows``: the rows one decode dispatch
+    def decode_rows(self, pos, ahead, table_rows, block_size,
+                    padded=True):
+        """``ServingSpec.decode_rows`` (``attn_kernel_rows`` where not
+        ``padded``): the rows one decode dispatch
         fetches in a layer, the mean over the layers of both kinds (so
         that over ``serving.decode_rows_table`` it stays a share of one
         layer's table; ``serving.attn_rows_walked_*`` have each kind's
         own, read on the device).  The step's own row comes from the
         step itself: the walk reads rows below ``pos``."""
-        width = 2 * self.config["num_key_value_heads"] \
-            * self.config["head_dim"]
-        return sum(walk_rows(pos, ahead - 1, table_rows, block_size,
-                             width, blk.attn.reach)
+        return sum(blk.attn.decode_rows(pos, ahead - 1, table_rows,
+                                        block_size, padded)
                    for blk in self.blocks) // len(self.blocks)
 
     def serving_spec(self):
@@ -409,6 +416,11 @@ class AfmoeModel(ServedModel, nn.Layer):
             counters=MOE_COUNTERS + AFMOE_COUNTERS,
             kernels={"moe.experts": grouped_matmul_impl()},
             decode_rows=self.decode_rows,
+            attn_kernel_rows=functools.partial(self.decode_rows,
+                                               padded=False),
+            attn_core=self.blocks[0].attn.serving_core,
+            attn_kernel_check=functools.partial(
+                walk_kernel_check, [b.attn for b in self.blocks], 1),
             attention={"window": cfg["sliding_window"], "layers": {
                 "sliding": self.layers_of(KINDS[0]),
                 "full": self.layers_of(KINDS[1])}},

@@ -29,9 +29,10 @@ from ..core.tensor import Tensor
 from ..nn.layer.scan import ScanLayers
 from ..ops import reshape, transpose, concat
 from .programs import (  # noqa: F401
-    KVRowSpec, ServedModel, ServingSpec, _jit_named, _scoped,
-    filter_logits_lanes, sample_lanes, slot_sample_keys, walk_chunk,
-    walk_group, walk_plan, walk_rows,
+    KVRowSpec, ServedModel, ServingSpec, _backend, _jit_named,
+    _over_a_mesh, _scoped, filter_logits_lanes, sample_lanes,
+    slot_attn_core, slot_sample_keys, walk_chunk, walk_group, walk_plan,
+    walk_rows,
 )
 
 
@@ -55,49 +56,6 @@ def _gather_blocks(pool, cols):
         return paged_gather(pool, cols)
     blocks = pool[cols]                         # [n, k, bs, H, hd]
     return blocks.reshape(cols.shape[0], -1, *blocks.shape[3:])
-
-
-def _backend():
-    """The platform the step programs are traced for (the process's
-    default backend)."""
-    import jax
-    return jax.default_backend()
-
-
-def _over_a_mesh(use_mp):
-    """Whether the program now being traced spans several devices: the
-    einsum form whose weights carry ``'mp'`` specs, or a mesh its
-    builder or the process published (``Engine(mesh=...)``)."""
-    from ..distributed import mesh as mesh_mod
-    return bool(use_mp) or mesh_mod.program_devices() > 1
-
-
-def slot_attn_core(platform, *, paged, quant, head_dim, mesh, table_rows,
-                   block_size):
-    """Which form the attention core of ``GPTAttention._slot_attn``
-    takes, from what the code can see, and why: ``("kernel", reason)``,
-    the Pallas kernel that streams each live slot's pages through VMEM
-    (``ops/ragged_paged_attn.py``), or ``("walk", reason)``, the XLA
-    work list.  ONE algorithm, an online softmax over each slot's own
-    rows, whose implementation follows the platform and the shapes:
-    the kernel is compiled by Mosaic, so it needs a TPU, paged pools of
-    plain floating point, heads of whole 128-lane tiles and a program
-    on one device (GSPMD cannot partition a Mosaic call); a table of at
-    most one chunk is read whole by either."""
-    if not paged:
-        return "walk", "contiguous cache: no pages to stream"
-    if quant:
-        return "walk", "int8 pools: the walk dequantizes at the gather"
-    if head_dim % 128:
-        return "walk", f"head size {head_dim}: not whole 128-lane tiles"
-    if platform != "tpu":
-        return "walk", f"platform {platform}: Mosaic compiles for a TPU"
-    if mesh:
-        return "walk", "a program over a mesh: GSPMD cannot partition " \
-            "a Mosaic call"
-    if table_rows <= walk_chunk(table_rows, block_size):
-        return "walk", "a table of one chunk is read whole"
-    return "kernel", "paged floating-point pools on one TPU"
 
 
 # Per-slot LoRA context (serving/lora.py).  Thread-local because jax
@@ -2698,6 +2656,7 @@ class GPTModel(ServedModel, nn.Layer):
         compute in, the position table's length, and nothing it
         cannot honour (every engine option was written against this
         model)."""
+        from ..ops.ragged_paged_attn import stream_rows
         attn0 = self.blocks[0].attn
         if attn0.use_mp:
             dtype = attn0.qkv_weight._data.dtype
@@ -2711,6 +2670,8 @@ class GPTModel(ServedModel, nn.Layer):
             decode_rows=functools.partial(
                 walk_rows, row_width=2 * attn0.num_heads * attn0.head_dim),
             attn_core=self._attn_core,
+            attn_kernel_check=self._attn_kernel_check,
+            attn_kernel_rows=stream_rows,
             kv=KVRowSpec.heads(len(self.blocks), attn0.num_heads,
                                attn0.head_dim, dtype),
             max_positions=emb.position_embeddings.weight.shape[0],
@@ -2718,10 +2679,12 @@ class GPTModel(ServedModel, nn.Layer):
             hidden_size=emb.word_embeddings.weight.shape[1],
             tensor_parallel=attn0.use_mp)
 
-    def _attn_core(self, *, paged, quant, table_rows, block_size):
+    def _attn_core(self, *, paged, quant, table_rows, block_size,
+                   slots=None):
         """``ServingSpec.attn_core``: the form ``_slot_attn`` takes
         when the decode and verify programs are traced for such pools,
-        by the rule it applies itself (``slot_attn_core``)."""
+        by the rule it applies itself (``slot_attn_core``; the kernel's
+        grid runs over any number of slots)."""
         attn0 = self.blocks[0].attn
         form, why = slot_attn_core(
             _backend(), paged=paged, quant=quant,
@@ -2729,6 +2692,24 @@ class GPTModel(ServedModel, nn.Layer):
             table_rows=table_rows, block_size=block_size)
         return {"form": form, "why": why, "platform": _backend(),
                 "head_dim": attn0.head_dim}
+
+    def _attn_kernel_check(self, *, num_slots, block_size,
+                           blocks_per_slot, num_blocks, dtype, spec_k,
+                           device):
+        """``ServingSpec.attn_kernel_check``: the streaming kernel at
+        the decode window and the verify window."""
+        from ..ops.ragged_paged_attn import compile_check
+        attn0 = self.blocks[0].attn
+        for window in sorted({1, (spec_k or 0) + 1}):
+            try:
+                compile_check(
+                    num_slots=num_slots, window=window,
+                    num_heads=attn0.num_heads, head_dim=attn0.head_dim,
+                    block_size=block_size,
+                    blocks_per_slot=blocks_per_slot,
+                    num_blocks=num_blocks, dtype=dtype, device=device)
+            except Exception as e:
+                raise ValueError(f"at a window of {window}: {e}") from e
 
     def serving_linear_stacks(self):
         """The layers whose ``nn.Linear`` children weight-only int8

@@ -71,14 +71,16 @@ load-balance loss.
 """
 from __future__ import annotations
 
+import functools
+
 from .. import nn
 from ..core.tensor import Tensor
 from ..nn import initializer as I
 from .mla_moe import MOE_COUNTERS, GatedMLP, RMSNorm, RoutedFFN, _lin
 from .programs import (
     KVRowSpec, ServedModel, ServingSpec, _scoped, sample_lanes,
-    slot_sample_keys, walk_rows)
-from .sdar_moe import GQAttention
+    slot_sample_keys)
+from .sdar_moe import GQAttention, walk_kernel_check
 
 KINDS = ("conv", "full_attention")
 
@@ -497,23 +499,27 @@ class Lfm2MoeModel(ServedModel, nn.Layer):
                              pnames, body)
 
     # -- the serving seam ----------------------------------------------
-    def decode_rows(self, pos, ahead, table_rows, block_size):
-        """``ServingSpec.decode_rows``: the rows one decode dispatch
+    def decode_rows(self, pos, ahead, table_rows, block_size,
+                    padded=True):
+        """``ServingSpec.decode_rows`` (``attn_kernel_rows`` where not
+        ``padded``): the rows one decode dispatch
         fetches in an ATTENTION layer (a conv layer walks none).  The
         step's own row comes from the step itself: the walk reads rows
         below ``pos``."""
-        cfg = self.config
-        width = 2 * cfg["num_key_value_heads"] \
-            * (cfg["hidden_size"] // cfg["num_attention_heads"])
-        return walk_rows(pos, ahead - 1, table_rows, block_size, width)
+        return self._attention().decode_rows(pos, ahead - 1, table_rows,
+                                             block_size, padded)
+
+    def _attention(self):
+        """The first attention layer's ``GQAttention`` (they are all
+        alike)."""
+        return next(b for b in self.blocks if b.kind == KINDS[1]).attn
 
     def serving_spec(self):
         from ..distributed.moe import grouped_matmul_impl
         cfg = self.config
         d, taps = cfg["hidden_size"], cfg["conv_L_cache"]
         n_conv, n_attn = (self.layers_of(k) for k in KINDS)
-        k_proj = next(b for b in self.blocks
-                      if b.kind == KINDS[1]).attn.k_proj
+        k_proj = self._attention().k_proj
         dtype = getattr(k_proj, "compute_dtype", None) \
             or k_proj.weight._data.dtype
         tails = "the conv layers' state lies in their blocks' tails: "
@@ -527,6 +533,12 @@ class Lfm2MoeModel(ServedModel, nn.Layer):
             counters=MOE_COUNTERS + LFM2_COUNTERS,
             kernels={"moe.experts": grouped_matmul_impl()},
             decode_rows=self.decode_rows,
+            attn_kernel_rows=functools.partial(self.decode_rows,
+                                               padded=False),
+            attn_core=self._attention().serving_core,
+            attn_kernel_check=functools.partial(
+                walk_kernel_check,
+                [b.attn for b in self.blocks if b.kind == KINDS[1]], 1),
             state={"conv": [taps - 1, d],
                    "layers": {"conv": n_conv, "attention": n_attn},
                    "per": "block"},

@@ -164,6 +164,13 @@ class KVRowSpec:
     PR 36, 35 and 30; ``[16, 128]``, GPT-3 1.3B's row, is exactly one
     tile already).  So a model with few K/V heads declares its row
     flat, and reads a head as a 128-lane slice of the fetched rows.
+    WHO READS THE ROWS, by ``slot_attn_core``: on one TPU, for paged
+    floating-point pools at heads of 128, a Pallas kernel copies the
+    pages where they lie (``"k"``/``"v"`` rows: the whole decode core,
+    ``ops/ragged_paged_attn.py``; a flat ``"kv"`` row: a trip of the
+    work list, ``ops/gq_walk_trip.py``, a head a static 128-lane slice
+    of the VMEM buffer); everywhere else, and for the ``"latent"`` row
+    on every platform, XLA's walk gathers whole blocks.
     Block and position bytes count what is stored, padding included:
     they are what a budget buys and what the gauges report
     (``geometry()["rows"]`` has the row as the model wrote it).
@@ -330,6 +337,55 @@ def walk_group(slots, row_width=None):
     return min(_WALK_GROUP, fit, slots)
 
 
+def _backend():
+    """The platform the step programs are traced for (the process's
+    default backend)."""
+    import jax
+    return jax.default_backend()
+
+
+def _over_a_mesh(use_mp=False):
+    """Whether the program now being traced spans several devices: the
+    einsum form whose weights carry ``'mp'`` specs, or a mesh its
+    builder or the process published (``Engine(mesh=...)``)."""
+    from ..distributed import mesh as mesh_mod
+    return bool(use_mp) or mesh_mod.program_devices() > 1
+
+
+def slot_attn_core(platform, *, paged, quant, head_dim, mesh, table_rows,
+                   block_size, slots=None):
+    """Which form the core of a decode attention's walk takes, from
+    what the code can see, and why: ``("kernel", reason)``, a Pallas
+    kernel that streams pages through VMEM under its arithmetic, or
+    ``("walk", reason)``, the XLA work list.  ONE algorithm, an online
+    softmax over each slot's own rows, whose implementation follows
+    the platform and the shapes.  Two walks ask: ``GPTAttention
+    ._slot_attn`` (the whole core as ``ops/ragged_paged_attn.py``) and
+    ``GQAttention.attend`` (a trip of its work list as
+    ``ops/gq_walk_trip.py``; it gives its ``slots``: one slot, the
+    chunk program, walks its own chunks in turn).  A kernel is compiled
+    by Mosaic, so it needs a TPU, paged pools of plain floating point,
+    heads of whole 128-lane tiles and a program on one device (GSPMD
+    cannot partition a Mosaic call); a table of at most one chunk is
+    read whole by either."""
+    if not paged:
+        return "walk", "contiguous cache: no pages to stream"
+    if quant:
+        return "walk", "int8 pools: the walk dequantizes at the gather"
+    if head_dim % 128:
+        return "walk", f"head size {head_dim}: not whole 128-lane tiles"
+    if platform != "tpu":
+        return "walk", f"platform {platform}: Mosaic compiles for a TPU"
+    if mesh:
+        return "walk", "a program over a mesh: GSPMD cannot partition " \
+            "a Mosaic call"
+    if table_rows <= walk_chunk(table_rows, block_size):
+        return "walk", "a table of one chunk is read whole"
+    if slots is not None and slots < 2:
+        return "walk", "one slot walks its own chunks in turn"
+    return "kernel", "paged floating-point pools on one TPU"
+
+
 def walk_first(pos, reach, chunk):
     """The first chunk a slot's walk fetches where a query sees only
     the ``reach`` rows that end with its own: the earliest query of the
@@ -379,14 +435,16 @@ def walk_plan(pos, window, table_rows, chunk, group, reach=None):
 
 
 def walk_rows(pos, ahead, table_rows, block_size, row_width=None,
-              reach=None):
+              reach=None, padded=True):
     """Host twin of ``walk_plan`` for the engine's counters
     (``ServingSpec.decode_rows``): the cache rows one decode dispatch
     fetches over all slots when slot b's window ends at ``pos[b] +
     ahead`` — ``trips x group x chunk``, the last trip's padding items
     included (``row_width`` as ``walk_group`` takes it; ``reach`` as
-    ``walk_plan`` does); a table of at most one chunk is read whole by
-    every slot."""
+    ``walk_plan`` does), or ``items x chunk`` where not ``padded`` (a
+    trip that is a kernel copies nothing for a padding item,
+    ``ops/gq_walk_trip.py``); a table of at most one chunk is read
+    whole by every slot."""
     import numpy as np
     pos = np.asarray(pos, np.int64)
     chunk = walk_chunk(table_rows, block_size)
@@ -404,7 +462,7 @@ def walk_rows(pos, ahead, table_rows, block_size, row_width=None,
     if reach is not None:
         n = n - np.minimum(walk_first(live, reach, chunk), n)
     items = int(n.sum())
-    return -(-items // group) * group * chunk
+    return (-(-items // group) * group if padded else items) * chunk
 
 
 class StepSpec:
@@ -478,15 +536,27 @@ class ServingSpec:
                        out: ``walk_rows``, the work list of (slot,
                        chunk) items that reads each slot to its own
                        window's end
-    ``attn_core``      ``(paged, quant, table_rows, block_size) -> dict``
-                       of a model whose decode attention has two forms
-                       and picks one when it traces (``{"form":
-                       "kernel" | "walk", "why": ..., "platform": ...,
-                       "head_dim": ...}``): the engine asks it at
-                       construction for ``/healthz`` ``attn_core``,
-                       ``serving.attn_kernel_dispatches`` and the
-                       kernel's compile check; None for one that
-                       always walks
+    ``attn_core``      ``(paged, quant, table_rows, block_size, slots)
+                       -> dict`` of a model whose decode attention has
+                       two forms and picks one when it traces
+                       (``{"form": "kernel" | "walk", "why": ...,
+                       "platform": ..., "head_dim": ...}``): the engine
+                       asks it at construction for ``/healthz``
+                       ``attn_core`` and
+                       ``serving.attn_kernel_dispatches``; None for one
+                       that always walks
+    ``attn_kernel_check``  ``(num_slots, block_size, blocks_per_slot,
+                       num_blocks, dtype, spec_k, device)``: has Mosaic
+                       compile the model's kernel for ``device`` at the
+                       shapes its decode (and verify) programs will
+                       use, running nothing, and raises the compiler's
+                       words where it refuses; the engine calls it at
+                       construction where ``attn_core`` says "kernel"
+    ``attn_kernel_rows``   ``decode_rows``' twin for the dispatches
+                       whose core is the kernel, which fetches less
+                       than the XLA walk (GPT: ``stream_rows``, a live
+                       slot's own pages; a grouped-query model: the
+                       work list's items and no padding item)
     ``step``           ``StepSpec`` of a model whose step is not one
                        row and one token a lane; None for one that is
     ``residual``       what ``/healthz`` says of a residual that is not
@@ -520,7 +590,8 @@ class ServingSpec:
                  tensor_parallel=False, counters=(), unsupported=None,
                  kernels=None, decode_rows=None, step=None,
                  residual=None, attention=None, experts=None,
-                 state=None, attn_core=None):
+                 state=None, attn_core=None, attn_kernel_check=None,
+                 attn_kernel_rows=None):
         self.kv = kv
         self.max_positions = int(max_positions)
         self.vocab_size = int(vocab_size)
@@ -531,6 +602,8 @@ class ServingSpec:
         self.kernels = dict(kernels or {})
         self.decode_rows = decode_rows or walk_rows
         self.attn_core = attn_core
+        self.attn_kernel_check = attn_kernel_check
+        self.attn_kernel_rows = attn_kernel_rows
         self.step = step
         self.residual = dict(residual) if residual else None
         self.attention = dict(attention) if attention else None

@@ -63,6 +63,7 @@ Head-major, so a later split over K/V heads is a contiguous lane range.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 from .. import nn
@@ -71,8 +72,9 @@ from ..nn import initializer as I
 from .mla_moe import (
     MOE_COUNTERS, RMSNorm, RoutedFFN, _lin, rope, write_chunk_rows)
 from .programs import (
-    KVRowSpec, ServedModel, ServingSpec, StepSpec, _scoped, walk_chunk,
-    walk_first, walk_group, walk_plan, walk_rows)
+    KVRowSpec, ServedModel, ServingSpec, StepSpec, _backend, _over_a_mesh,
+    _scoped, slot_attn_core, walk_chunk, walk_first, walk_group, walk_plan,
+    walk_rows)
 
 # the step's own counters, after the routed layers' four, in the order
 # of the vector the programs return: lane-passes of each state, the
@@ -190,10 +192,26 @@ class GQAttention(nn.Layer):
 
         Several slots (the step program) are walked as ``walk_plan``'s
         work list of (slot, chunk) items, each slot to its OWN ``pos``,
-        ``walk_group`` items a trip; a slot at position 0 (a parked
-        lane, or a prompt shorter than a block) has no item.  One slot
-        (the chunk program) walks its own chunks in turn.  A table of
-        at most one chunk is read whole, without a loop.  Under a
+        ``walk_group`` items a trip of ONE device loop; a slot at
+        position 0 (a parked lane, or a prompt shorter than a block)
+        has no item.  One slot (the chunk program) walks its own chunks
+        in turn.  A table of at most one chunk is read whole, without
+        a loop.
+
+        WHICH FORM A TRIP TAKES (``core``: ``models/programs.py``
+        ``slot_attn_core``, from what the code can see).  On one TPU,
+        over a paged floating-point pool at heads of whole 128-lane
+        tiles, a trip of the work list is ONE Pallas kernel
+        (``ops/gq_walk_trip.py``, ``_kernel_trips``): the next item's
+        pages in flight under the present item's products, rows
+        contracted as stored, the items folded into their slots'
+        running state inside the kernel, a padding item neither copied
+        nor computed.  Everywhere else (the CPU, where tier-1 runs;
+        heads of 64, ``lfm2_moe``; a program over a mesh; the one-slot
+        walk and the one-chunk table on every platform) a trip is
+        XLA's: one gather of the items' blocks, then the products and
+        the fold.  One algorithm, an online softmax over a work list,
+        whose trip has two implementations by shape.  Under a
         ``reach`` row s sees the cached rows ``> pos + s - reach``
         only: the work list and the one-slot walk start at the first
         chunk the slot's first row sees (``walk_first``), and the mask
@@ -283,6 +301,12 @@ class GQAttention(nn.Layer):
                 0, n_chunks * chunk // bs - tables.shape[1])))
             cols = whole.reshape(B * n_chunks, chunk // bs)[
                 slot_of * n_chunks + chunk_of]           # [N, chunk//bs]
+            if self.core(B, pool, table_rows) == "kernel":
+                from ..ops.gq_walk_trip import trip_meta
+                return self._kernel_trips(
+                    init, qg, pool, trip_meta(
+                        cols, slot_of, chunk_of, valid, pos, chunk),
+                    group, n_trips)
             at = (chunk_of * chunk)[:, None] + jnp.arange(chunk)[None, :]
             start = pos[slot_of]
             sees = (at < start[:, None]) & valid[:, None]        # [N, n]
@@ -335,6 +359,63 @@ class GQAttention(nn.Layer):
             _, den, acc = jax.lax.fori_loop(
                 *self.one_slot_span(pos, chunk, trips), trip, init)
         return (acc / per_ctx(den)).astype(q.dtype).reshape(B, S, H * hd)
+
+    def serving_core(self, *, paged, quant, table_rows, block_size,
+                     slots):
+        """``ServingSpec.attn_core`` of a model whose decode attention
+        is ``attend``: the form a trip of the step program's walk
+        takes, by the rule ``attend`` applies itself
+        (``slot_attn_core``)."""
+        form, why = slot_attn_core(
+            _backend(), paged=paged, quant=quant, head_dim=self.head_dim,
+            mesh=_over_a_mesh(), table_rows=table_rows,
+            block_size=block_size, slots=slots)
+        return {"form": form, "why": why, "platform": _backend(),
+                "head_dim": self.head_dim}
+
+    def core(self, slots, pool, table_rows):
+        """The form (``"kernel"`` / ``"walk"``) of a trip of
+        ``attend``'s walk over ``slots`` slots of ``pool`` [NB, bs, W],
+        traced now."""
+        import jax.numpy as jnp
+        return self.serving_core(
+            paged=True, quant=not jnp.issubdtype(pool.dtype, jnp.floating),
+            table_rows=table_rows, block_size=pool.shape[1],
+            slots=slots)["form"]
+
+    def decode_rows(self, pos, ahead, table_rows, block_size,
+                    padded=True):
+        """Host twin of what ``attend``'s walk fetches for slots whose
+        queries see the rows below ``pos[b] + ahead``
+        (``ServingSpec.decode_rows``): ``walk_rows``' whole trips where
+        XLA's trip runs; not ``padded``, the items alone
+        (``ServingSpec.attn_kernel_rows``: the kernel copies nothing
+        for a padding item)."""
+        return walk_rows(pos, ahead, table_rows, block_size,
+                         2 * self.num_kv_heads * self.head_dim,
+                         self.reach, padded=padded)
+
+    def _kernel_trips(self, init, qg, pool, meta, group, n_trips):
+        """``walk_items``' loop with ``ops/gq_walk_trip.py`` as a
+        trip's body: the running state packed as the kernel keeps it
+        and updated where it lies, the trip's items ``group`` rows of
+        ``meta``.  Returns (None, den, acc) as the XLA trips do."""
+        import jax
+        from ..ops import gq_walk_trip as kernel
+        S, K, g = qg.shape[1], qg.shape[2], qg.shape[3]
+        rows = kernel.state_rows(g * S, qg.dtype)
+        queries = kernel.pack_queries(qg, rows)
+        flat = pool.reshape(-1, pool.shape[-1])
+
+        def trip(t, state):
+            return kernel.gq_walk_trip(
+                state, queries, flat,
+                jax.lax.dynamic_slice_in_dim(meta, t * group, group),
+                heads=K, steps=S, reach=self.reach,
+                block_size=pool.shape[1])
+        state = jax.lax.fori_loop(
+            0, n_trips, trip, kernel.pack_state(*init, rows))
+        return (None, *kernel.unpack_state(state, K, g, S))
 
     @_scoped("attention")
     def step_slots_paged(self, h, pool, tables, pos, walk_pos):
@@ -408,6 +489,34 @@ class GQAttention(nn.Layer):
         ctx = jnp.einsum("bkgsn,bnkd->bskgd", p, v)
         return self.output(
             ctx.reshape(B, S, self.num_heads * self.head_dim), h)
+
+
+def walk_kernel_check(layers, steps, *, num_slots, block_size,
+                      blocks_per_slot, num_blocks, dtype, device,
+                      spec_k=None):
+    """``ServingSpec.attn_kernel_check`` of a model whose attention
+    ``layers`` are ``GQAttention`` carrying ``steps`` rows a slot:
+    Mosaic compiles ``ops/gq_walk_trip.py`` for ``device`` once a
+    distinct ``reach``, at the trip the step program will take over
+    ``num_slots`` slots.  (No such model has a verify program:
+    ``spec_k`` is taken and not read.)"""
+    from ..ops.gq_walk_trip import compile_check
+    attn = layers[0]
+    width = 2 * attn.num_kv_heads * attn.head_dim
+    chunk = walk_chunk(blocks_per_slot * block_size, block_size)
+    for reach in sorted({a.reach for a in layers},
+                        key=lambda r: (r is None, r)):
+        try:
+            compile_check(
+                num_slots=num_slots, kv_heads=attn.num_kv_heads,
+                groups=attn.num_heads // attn.num_kv_heads, steps=steps,
+                head_dim=attn.head_dim, row_width=width,
+                block_size=block_size, pages=chunk // block_size,
+                group=walk_group(num_slots, width),
+                num_blocks=num_blocks, dtype=dtype, reach=reach,
+                device=device)
+        except Exception as e:
+            raise ValueError(f"at a reach of {reach}: {e}") from e
 
 
 class SDARMoEBlock(nn.Layer):
@@ -685,10 +794,20 @@ class SDARMoEModel(ServedModel, nn.Layer):
                              pnames, body)
 
     # -- the serving seam ----------------------------------------------
+    def decode_rows(self, pos, ahead, table_rows, block_size,
+                    padded=True):
+        """``ServingSpec.decode_rows`` (``attn_kernel_rows`` where not
+        ``padded``).  The walk reads rows BELOW pos: the block itself
+        (the ``ahead`` of the dispatch about to be issued) is not
+        read."""
+        return self.blocks[0].attn.decode_rows(
+            pos, ahead - self.block_length, table_rows, block_size, padded)
+
     def serving_spec(self):
         from ..distributed.moe import grouped_matmul_impl
         cfg, W = self.config, self.block_length
-        k_proj = self.blocks[0].attn.k_proj
+        attn = self.blocks[0].attn
+        k_proj = attn.k_proj
         dtype = getattr(k_proj, "compute_dtype", None) \
             or k_proj.weight._data.dtype
         step = "the step carries a block of rows a lane: "
@@ -699,10 +818,12 @@ class SDARMoEModel(ServedModel, nn.Layer):
             vocab_size=cfg["vocab_size"], hidden_size=cfg["hidden_size"],
             counters=MOE_COUNTERS + STEP_COUNTERS,
             kernels={"moe.experts": grouped_matmul_impl()},
-            # the walk reads rows BELOW pos: the block itself (the
-            # ``ahead`` of the dispatch about to be issued) is not read
-            decode_rows=lambda pos, ahead, table_rows, block_size:
-            walk_rows(pos, ahead - W, table_rows, block_size),
+            decode_rows=self.decode_rows,
+            attn_kernel_rows=functools.partial(self.decode_rows,
+                                               padded=False),
+            attn_core=attn.serving_core,
+            attn_kernel_check=functools.partial(
+                walk_kernel_check, [b.attn for b in self.blocks], W),
             step=StepSpec(rows=W, align=W, open=self.open_block,
                           report={"rows": W,
                                   "steps": self.denoising_steps}),

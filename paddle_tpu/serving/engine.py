@@ -379,14 +379,17 @@ class Engine:
         (layout, chunk shape, spec_k) window SHAPE — and remains the
         CPU tier-1 parity oracle.  Its decode and verify programs'
         attention core is the model's to choose when it traces them
-        (``ServingSpec.attn_core``; GPT: the XLA walk, or on one TPU
-        with paged floating-point pools at heads of 128 the same
-        Pallas kernel as below, streaming each live slot's pages:
+        (``ServingSpec.attn_core``; the XLA walk, or on one TPU
+        with paged floating-point pools at heads of 128 a Pallas
+        kernel: GPT's whole core, the same kernel as below, streaming
+        each live slot's pages; a grouped-query model's trip of its
+        work list, ``ops/gq_walk_trip.py``:
         ``/healthz`` ``attn_core`` names the form and why,
         ``serving.attn_kernel_dispatches`` counts it, and
-        construction compiles the kernel through Mosaic at the decode
-        and verify windows, raising the compiler's message if it is
-        refused).  ``"ragged"`` (requires the paged layout) routes the
+        construction compiles the model's kernel through Mosaic at
+        the shapes its programs will use
+        (``ServingSpec.attn_kernel_check``), raising the compiler's
+        message if it is refused).  ``"ragged"`` (requires the paged layout) routes the
         decode, spec-verify, and chunked-prefill
         attention core through the Pallas RAGGED PAGED ATTENTION
         kernel (ops/ragged_paged_attn.py; interpret mode on the cpu
@@ -1078,17 +1081,19 @@ class Engine:
         self._ragged_fn = None  # resolved jitted ragged-window handle
         # -- the decode attention's core: kernel or walk ----------------
         # the model chooses from what it can see (platform, layout,
-        # pool dtype, head size, mesh: models/gpt.py slot_attn_core)
-        # when it traces the decode / verify programs; the engine asks
-        # the same rule here for its counters and /healthz, and has
-        # Mosaic take the kernel NOW at the shapes those programs
-        # will compile
+        # pool dtype, head size, mesh, slots: models/programs.py
+        # slot_attn_core) when it traces the decode / verify programs;
+        # the engine asks the same rule here for its counters and
+        # /healthz, and has Mosaic take the model's kernel NOW at the
+        # shapes those programs will compile
+        # (ServingSpec.attn_kernel_check)
         self._attn_core = None
         if sspec.attn_core is not None and not self._ragged:
             self._attn_core = dict(sspec.attn_core(
                 paged=self._paged, quant=self._kv_quant,
                 table_rows=self.max_seq_len,
-                block_size=self._bs if self._paged else None),
+                block_size=self._bs if self._paged else None,
+                slots=self.num_slots),
                 pool_dtype=self._kv_dtype_str)
         self._attn_kernel = (self._attn_core or {}).get("form") == "kernel"
         # the host twin of what the kernel fetches (_rows_walked); None:
@@ -1096,24 +1101,20 @@ class Engine:
         self._kernel_rows = None
         if self._attn_kernel:
             import jax
-            from ..ops.ragged_paged_attn import compile_check, stream_rows
-            self._kernel_rows = stream_rows
+            self._kernel_rows = sspec.attn_kernel_rows
             dev = jax.devices()[0]
             if dev.platform != "cpu":
-                for window in sorted({1, (self._spec_k or 0) + 1}):
-                    try:
-                        compile_check(
-                            num_slots=self.num_slots, window=window,
-                            num_heads=self._nh, head_dim=self._hd,
-                            block_size=self._bs,
-                            blocks_per_slot=self._bps,
-                            num_blocks=self._kv_managed + 1,
-                            dtype=self._kv_dtype, device=dev)
-                    except Exception as e:
-                        raise ValueError(
-                            "the decode attention's kernel does not "
-                            f"compile for {dev.device_kind} at a window "
-                            f"of {window}: {e}") from e
+                try:
+                    sspec.attn_kernel_check(
+                        num_slots=self.num_slots, block_size=self._bs,
+                        blocks_per_slot=self._bps,
+                        num_blocks=self._kv_managed + 1,
+                        dtype=self._kv_dtype, spec_k=self._spec_k,
+                        device=dev)
+                except Exception as e:
+                    raise ValueError(
+                        "the decode attention's kernel does not compile "
+                        f"for {dev.device_kind}: {e}") from e
         self._zero_scale_fn = None  # jitted fresh-block scale zeroer
         #   (kv_dtype='int8'; compiled once per config — see
         #   _zero_fresh_scales)
@@ -1319,12 +1320,13 @@ class Engine:
             "serving.decode_rows_walked", "cache rows the decode / "
             "verify dispatches fetched, summed over slots, from the "
             "host's position mirror.  Where the attention core is the "
-            "kernel (serving.attn_kernel_dispatches; "
-            "ops/ragged_paged_attn.py stream_rows): every live slot to "
-            "its own window's end in whole steps of 256 rows' pages, "
-            "each step copied into one of two VMEM buffers a pool "
-            "while the other is contracted, rows as stored, sums and "
-            "weights float32; nothing for a parked slot.  Where it is "
+            "kernel (serving.attn_kernel_dispatches): what the kernel "
+            "copies into VMEM, rows as stored, sums and weights "
+            "float32, nothing for a parked slot (GPT, "
+            "ops/ragged_paged_attn.py stream_rows: every live slot's "
+            "pages to its own window's end; a grouped-query model, "
+            "ops/gq_walk_trip.py: the work list's items and no "
+            "padding item, walk_rows(padded=False)).  Where it is "
             "the XLA walk, the served model's rule "
             "(ServingSpec.decode_rows; models/programs.py walk_rows: a "
             "work list of (slot, chunk) items taken a whole trip at a "
@@ -1333,8 +1335,8 @@ class Engine:
             "read, 1.0 = every row of every slot")
         self._m_attn_kernel = reg.counter(
             "serving.attn_kernel_dispatches", "decode and verify "
-            "dispatches whose attention core is the Pallas kernel that "
-            "streams each live slot's pages (the host knows it from "
+            "dispatches whose attention core is a Pallas kernel that "
+            "streams pages under its arithmetic (the host knows it from "
             "the program it built: /healthz attn_core); the others "
             "of serving.fused_sample_ticks walked in XLA")
         self._m_rows_table = reg.counter(
@@ -3578,7 +3580,8 @@ class Engine:
         in flight, each of which moved a lane by at most ``width``
         rows.  How far the walk goes is the served model's to say
         (``ServingSpec.decode_rows``), or the kernel's where the
-        attention core is the kernel (``stream_rows``; the dispatch
+        attention core is a kernel (``ServingSpec.attn_kernel_rows``
+        where the model gives one; the dispatch
         is then one of ``serving.attn_kernel_dispatches``).  Counted into
         ``serving.decode_rows_walked`` / ``_live`` / ``_table``;
         returned for the ``decode.dispatch`` span."""

@@ -984,6 +984,430 @@ def test_gpt_decode_streams_pages_through_one_kernel_on_the_v5e(
         r"(?:copy|transpose|convert)\(", text)
 
 
+# -- the grouped-query walk's trip as a kernel (ops/gq_walk_trip.py) --------
+
+def _gq_case(S, reach, K, g, positions, dtype="float32", bs=8, blocks=96,
+             seed=3):
+    """``GQAttention.attend``'s arguments over a pool of noise: one
+    slot a position (0: a parked lane), each with its own blocks in a
+    shuffled order.  Items are 256 rows (32 blocks of 8) and a trip
+    takes one item a slot at most (``walk_group``)."""
+    import jax.numpy as jnp
+    from paddle_tpu.models.sdar_moe import GQAttention
+    rng = np.random.RandomState(seed)
+    B, hd, W = len(positions), 128, 2 * K * 128
+    attn = GQAttention(64, K * g, K, hd, 1e4, 1e-6, S, reach=reach)
+    order = 1 + rng.permutation(B * blocks)
+
+    def noise(*shape):
+        return jnp.asarray(rng.randn(*shape).astype(np.float32)
+                           ).astype(dtype)
+    return (attn, noise(B, S, K * g, hd), noise(B, S, W),
+            noise(1 + B * blocks, bs, W),
+            jnp.asarray(order.reshape(B, blocks).astype(np.int32)),
+            jnp.asarray(np.asarray(positions, np.int32)))
+
+
+def _attend_as(monkeypatch, platform, attn, *args):
+    """``attend``'s output and whether it holds a Pallas call, traced
+    for ``platform`` (the kernel runs interpreted on this CPU)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.models import sdar_moe
+    monkeypatch.setattr(sdar_moe, "_backend", lambda: platform)
+    text = str(jax.make_jaxpr(lambda *a: attn.attend(*a))(*args))
+    out = jax.jit(lambda *a: attn.attend(*a))(*args)
+    return np.asarray(out.astype(jnp.float32)), "pallas_call" in text
+
+
+# what each case holds beside a parked slot (position 0) and a last
+# trip with padding items (the items are no whole number of trips):
+# ``pos`` on an item's edge (256, 512), on a block's edge (264), inside
+# a block (700, 513) and a slot whose rows end in the first item
+_GQ_CASES = {
+    # a reach that straddles an item: rows 401..700 of slot 0 lie in
+    # items 1 and 2, rows 214..513 of slot 3 in items 0, 1 and 2
+    "S=1, a reach": dict(S=1, reach=300, K=2, g=3,
+                         positions=[700, 0, 256, 513, 40, 264]),
+    "S=1, no reach": dict(S=1, reach=None, K=2, g=3,
+                          positions=[700, 0, 256, 513, 767, 264]),
+    "S=4": dict(S=4, reach=None, K=4, g=8,
+                positions=[700, 0, 256, 512, 44, 764, 264]),
+    "S=4, a reach": dict(S=4, reach=258, K=2, g=2,
+                         positions=[700, 0, 256, 512, 44]),
+    "every slot parked": dict(S=1, reach=None, K=2, g=2,
+                              positions=[0, 0, 0]),
+}
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", sorted(_GQ_CASES))
+def test_gq_trip_kernel_matches_the_walk(one_device, monkeypatch, case,
+                                         dtype):
+    """A trip of ``GQAttention.attend``'s work list as the kernel
+    against the XLA trip it replaces on a TPU, over ragged work lists
+    (``_GQ_CASES``).  Both are an online softmax over the same rows;
+    float32 pools agree to rounding, bfloat16 rows are contracted as
+    stored with float32 sums and weights (three bf16 terms) where the
+    walk's products are ``Precision.HIGHEST``: inside half a bf16 step
+    of the output."""
+    args = _gq_case(dtype=dtype, **_GQ_CASES[case])
+    walk, is_kernel = _attend_as(monkeypatch, "cpu", *args)
+    assert not is_kernel
+    kernel, is_kernel = _attend_as(monkeypatch, "tpu", *args)
+    assert is_kernel
+    tol = 2e-5 if dtype == "float32" else 2 ** -8
+    np.testing.assert_allclose(kernel, walk, rtol=tol, atol=tol)
+    parked = np.asarray(args[-1]) == 0
+    assert parked.any()
+    assert np.array_equal(kernel[parked], walk[parked])
+
+
+@pytest.mark.pallas
+def test_gq_trip_folds_a_run_into_the_state_where_it_lies(one_device):
+    """The kernel alone on one trip of four items, two runs (slot 2:
+    items of chunks 0 and 1; slot 0: chunk 0) and a padding item: the
+    state of the slots the trip names moves as a float64 fold of the
+    same rows says, every other slot's state comes back bit for bit,
+    and the padding item's pages (ids past the pool: a copy would
+    fault) are never asked for."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import gq_walk_trip as trip
+    rng = np.random.RandomState(9)
+    B, K, g, S, hd, bs, P = 4, 2, 2, 1, 128, 8, 4
+    R, chunk, W = trip.state_rows(g * S, np.float32), P * bs, 2 * K * hd
+    pool = rng.randn(40 * bs, W).astype(np.float32)
+    qg = rng.randn(B, S, K, g, hd).astype(np.float32)
+    top = rng.randn(B, K, g, S).astype(np.float32)
+    den = (1 + rng.rand(B, K, g, S)).astype(np.float32)
+    acc = rng.randn(B, S, K, g, hd).astype(np.float32)
+    pos = np.array([20, 0, 50, 0], np.int32)
+    cols = np.array([[3, 9, 1, 30], [7, 2, 11, 5], [4, 6, 8, 10],
+                     [10 ** 6] * 4], np.int32)
+    slot_of = np.array([2, 2, 0, 3], np.int32)
+    chunk_of = np.array([0, 1, 0, 0], np.int32)
+    valid = np.array([True, True, True, False])
+    meta = trip.trip_meta(jnp.asarray(cols), jnp.asarray(slot_of),
+                          jnp.asarray(chunk_of), jnp.asarray(valid),
+                          jnp.asarray(pos), chunk)
+    state = trip.pack_state(jnp.asarray(top), jnp.asarray(den),
+                            jnp.asarray(acc), R)
+    out = trip.gq_walk_trip(
+        state, trip.pack_queries(jnp.asarray(qg), R), jnp.asarray(pool),
+        meta, heads=K, steps=S, reach=None, block_size=bs)
+    got_den, got_acc = trip.unpack_state(out, K, g, S)
+    out = np.asarray(out)
+    for b in (1, 3):
+        assert np.array_equal(out[b], np.asarray(state)[b])
+    for b, items in ((2, (0, 1)), (0, (2,))):
+        rows = np.concatenate([pool[c * bs:(c + 1) * bs]
+                               for i in items for c in cols[i]])
+        at = np.concatenate([chunk_of[i] * chunk + np.arange(chunk)
+                             for i in items])
+        rows = rows[at < pos[b]].astype(np.float64)
+        for k in range(K):
+            keys = rows[:, 2 * k * hd:(2 * k + 1) * hd]
+            vals = rows[:, (2 * k + 1) * hd:(2 * k + 2) * hd]
+            for gi in range(g):
+                sc = keys @ qg[b, 0, k, gi].astype(np.float64) \
+                    / math.sqrt(hd)
+                m = max(top[b, k, gi, 0], sc.max())
+                keep = math.exp(top[b, k, gi, 0] - m)
+                p = np.exp(sc - m)
+                np.testing.assert_allclose(
+                    got_den[b, k, gi, 0], den[b, k, gi, 0] * keep
+                    + p.sum(), rtol=1e-5)
+                np.testing.assert_allclose(
+                    got_acc[b, 0, k, gi], acc[b, 0, k, gi] * keep
+                    + p @ vals, rtol=1e-4, atol=1e-4)
+
+
+_GQ_CORE = dict(paged=True, quant=False, head_dim=128, mesh=False,
+                table_rows=4096, block_size=16, slots=32)
+
+
+@pytest.mark.parametrize("platform,change,form,word", [
+    ("tpu", {}, "kernel", "one TPU"),
+    ("cpu", {}, "walk", "platform cpu"),
+    ("tpu", {"head_dim": 64}, "walk", "head size 64"),
+    ("tpu", {"quant": True}, "walk", "int8 pools"),
+    ("tpu", {"mesh": True}, "walk", "a mesh"),
+    ("tpu", {"table_rows": 256}, "walk", "one chunk"),
+    ("tpu", {"slots": 1}, "walk", "one slot"),
+    ("tpu", {"slots": 2, "table_rows": 32768}, "kernel", "one TPU"),
+])
+def test_gq_walk_core_rule(platform, change, form, word):
+    """The rule the grouped-query walk shares with ``_slot_attn``
+    (``models/programs.py`` ``slot_attn_core``), asked with the slots
+    of the program: each reason for the XLA trip and the one for the
+    kernel, in words ``/healthz`` can show."""
+    from paddle_tpu.models.programs import slot_attn_core
+    got, why = slot_attn_core(platform, **{**_GQ_CORE, **change})
+    assert got == form and word in why
+
+
+@pytest.mark.pallas
+def test_gq_attend_takes_the_form_the_rule_names(one_device, monkeypatch):
+    """``attend`` itself, traced and not run: the kernel for several
+    slots over a float pool longer than one chunk at heads of 128 on a
+    TPU platform string; the XLA trip on the CPU, for one slot (the
+    chunk program), for a table of one chunk, at heads of 64, for an
+    int8 pool and under a process-wide mesh."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.distributed import mesh as mesh_mod
+    from paddle_tpu.models.sdar_moe import GQAttention
+
+    attn, q, new, pool, tables, pos = _gq_case(
+        1, None, 2, 2, [700, 0, 300])
+
+    def traced(platform, attn, *args):
+        return _attend_as(monkeypatch, platform, attn, *args)[1]
+    assert traced("tpu", attn, q, new, pool, tables, pos)
+    assert not traced("cpu", attn, q, new, pool, tables, pos)
+    assert not traced("tpu", attn, q[:1], new[:1], pool, tables[:1],
+                      pos[:1])
+    assert not traced("tpu", attn, q, new, pool, tables[:, :32],
+                      jnp.minimum(pos, 200))
+    narrow = GQAttention(64, 8, 4, 64, 1e4, 1e-6, 1)
+    assert not traced("tpu", narrow, q.reshape(3, 1, 8, 64), new, pool,
+                      tables, pos)
+    assert not traced("tpu", attn, q, new, pool.astype(jnp.int8), tables,
+                      pos)
+    devices = np.asarray(jax.devices()[:2]).reshape(2, 1)
+    monkeypatch.setattr(mesh_mod, "_global_mesh",
+                        jax.sharding.Mesh(devices, ("mp", "dp")))
+    assert not traced("tpu", attn, q, new, pool, tables, pos)
+
+
+@pytest.mark.parametrize("reach", [None, 300])
+def test_walk_rows_counts_what_the_trip_kernel_copies(reach):
+    """``walk_rows(padded=False)``, the host's count behind
+    ``serving.decode_rows_walked`` where a trip is the kernel: the
+    work list's items and no padding item, against a brute-force count
+    and against the XLA trips' whole groups."""
+    from paddle_tpu.models.programs import walk_rows
+    bs, table_rows, chunk = 16, 4096, 256
+    rng = np.random.RandomState(13)
+    for _ in range(20):
+        pos = rng.randint(1, table_rows - 4, 32)
+        pos[rng.rand(32) < 0.5] = 0
+        brute = 0
+        for p in pos[pos > 0]:
+            first = 0 if reach is None else max(p + 1 - reach, 0) // chunk
+            brute += (-(-p // chunk) - first) * chunk
+        got = walk_rows(pos, 0, table_rows, bs, 1024, reach, padded=False)
+        whole = walk_rows(pos, 0, table_rows, bs, 1024, reach)
+        assert got == brute
+        assert got <= whole < got + 32 * chunk and whole % (32 * chunk) == 0
+
+
+def _seed_leaves(model, seed=0):
+    """Every leaf of ``model`` drawn from ``seed`` (matrices normal
+    0.08, gains 1 + normal 0.1, a router's bias normal 0.1)."""
+    import jax
+    import jax.numpy as jnp
+    model.eval()
+    for i, (name, p) in enumerate(model.named_parameters()):
+        v = jax.random.normal(jax.random.fold_in(
+            jax.random.PRNGKey(seed), i), tuple(p.shape), jnp.float32)
+        if name.endswith("_bias"):
+            v = 0.1 * v
+        else:
+            v = 1.0 + 0.1 * v if len(p.shape) == 1 else 0.08 * v
+        p.set_value(v)
+    return model
+
+
+_GQ_ROUTED = dict(
+    vocab_size=128, max_position_embeddings=512, hidden_size=64,
+    intermediate_size=96, moe_intermediate_size=32, num_experts=8,
+    num_experts_per_tok=2, num_attention_heads=4, num_key_value_heads=2,
+    rope_theta=10000)
+
+
+def _gq_family(family, head_dim=128):
+    """A new tiny model of one of the three families whose decode
+    attention is ``GQAttention.attend`` (a new one a test: a model
+    keeps its step programs by shape), heads of ``head_dim``."""
+    from paddle_tpu.models import AfmoeModel, Lfm2MoeModel, SDARMoEModel
+    if family == "sdar_moe":
+        return _seed_leaves(SDARMoEModel(dict(
+            _GQ_ROUTED, head_dim=head_dim, num_hidden_layers=2,
+            norm_topk_prob=True, decoder_sparse_step=1, mlp_only_layers=[],
+            rms_norm_eps=1e-6, rope_scaling=None), block_length=4,
+            denoising_steps=4, mask_token_id=127))
+    if family == "afmoe":
+        return _seed_leaves(AfmoeModel(dict(
+            _GQ_ROUTED, head_dim=head_dim, num_hidden_layers=3,
+            num_dense_layers=1, num_shared_experts=1, route_norm=True,
+            route_scale=2.448, score_func="sigmoid", n_group=1,
+            topk_group=1, mup_enabled=True, sliding_window=270,
+            rms_norm_eps=1e-5, rope_scaling=None,
+            layer_types=["sliding_attention", "full_attention",
+                         "sliding_attention"])))
+    return _seed_leaves(Lfm2MoeModel(dict(
+        _GQ_ROUTED, hidden_size=4 * head_dim, num_hidden_layers=3,
+        num_dense_layers=1, conv_L_cache=3, conv_bias=False,
+        norm_topk_prob=True, routed_scaling_factor=1, use_expert_bias=True,
+        norm_eps=1e-5, layer_types=["conv", "full_attention", "conv"])))
+
+
+def _gq_served(monkeypatch, family, platform, requests, **kw):
+    """``requests`` [(prompt, max_new)], one after the other's first
+    token at most, through an engine over a new model of ``family``
+    whose programs are traced for ``platform``."""
+    from paddle_tpu.models import sdar_moe
+    monkeypatch.setattr(sdar_moe, "_backend", lambda: platform)
+    eng = Engine(_gq_family(family, **kw), num_slots=3, max_seq_len=512,
+                 kv_block_size=8, kv_blocks=200, prefill_chunk=64,
+                 registry=monitor.StatRegistry())
+    reqs = [eng.submit(p, max_new_tokens=m) for p, m in requests[:2]]
+    eng.run_until_idle()
+    # the same prompt again: its whole blocks are adopted
+    reqs += [eng.submit(p, max_new_tokens=m) for p, m in requests[2:]]
+    eng.run_until_idle()
+    return [list(r.generated) for r in reqs], eng
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("family", ["sdar_moe", "afmoe"])
+def test_engine_walks_grouped_query_rows_through_the_trip_kernel(
+        one_device, monkeypatch, family):
+    """Tiny engines of the two families whose heads take the kernel,
+    their programs traced for a TPU (the kernel interpreted here):
+    greedy streams token-identical to the XLA walk's through chunked
+    prefill (prompts on both sides of one 256-row item, a window of
+    270 in ``afmoe``), a prefix hit and decode; ``/healthz`` names the
+    form, every decode dispatch is one of
+    ``serving.attn_kernel_dispatches``, and the rows are the items'
+    alone (no padding item)."""
+    rng = np.random.RandomState(5)
+    prompts = [rng.randint(1, 127, (n,)).astype(np.int32)
+               for n in (300, 40)]
+    requests = [(prompts[0], 9), (prompts[1], 6), (prompts[0], 7)]
+    walk, eng_w = _gq_served(monkeypatch, family, "cpu", requests)
+    kernel, eng = _gq_served(monkeypatch, family, "tpu", requests)
+    assert kernel == walk and all(kernel)
+    core = eng.debug_requests()["engine"]["attn_core"]
+    assert core == {"form": "kernel", "platform": "tpu", "head_dim": 128,
+                    "pool_dtype": "float32", "why": core["why"]}
+    assert eng_w.debug_requests()["engine"]["attn_core"]["form"] == "walk"
+    reg = eng.registry
+    assert reg.get("serving.prefix_hit_tokens").value > 0
+    ticks = reg.get("serving.fused_sample_ticks").value
+    assert ticks > 0
+    assert reg.get("serving.attn_kernel_dispatches").value == ticks
+    assert eng_w.registry.get("serving.attn_kernel_dispatches").value == 0
+    # items of 256 rows and nothing else; the XLA trips pad to whole
+    # groups of three
+    walked = reg.get("serving.decode_rows_walked").value
+    padded = eng_w.registry.get("serving.decode_rows_walked").value
+    assert walked % 256 == 0 and 0 < walked < padded
+
+
+@pytest.mark.parametrize("family,head_dim,word", [
+    ("sdar_moe", 128, "platform cpu"),
+    ("afmoe", 128, "platform cpu"),
+    ("lfm2_moe", 64, "head size 64"),
+])
+def test_grouped_query_engines_name_the_walk_and_why(family, head_dim,
+                                                     word, monkeypatch):
+    """On the CPU every family keeps the XLA trip and says why; at
+    heads of 64 (``lfm2_moe``) it keeps it on a TPU platform string
+    too: the rule adapts by what it sees, not by the model's name."""
+    from paddle_tpu.models import sdar_moe
+    prompt = np.arange(1, 41, dtype=np.int32)
+    for platform in ("cpu", "tpu") if head_dim % 128 else ("cpu",):
+        monkeypatch.setattr(sdar_moe, "_backend", lambda: platform)
+        got, eng = _gq_served(monkeypatch, family, platform,
+                              [(prompt, 4)], head_dim=head_dim)
+        core = eng.debug_requests()["engine"]["attn_core"]
+        assert core["form"] == "walk" and word in core["why"]
+        assert core["head_dim"] == head_dim and len(got[0]) == 4
+        assert eng.registry.get(
+            "serving.attn_kernel_dispatches").value == 0
+        assert eng.registry.get("serving.fused_sample_ticks").value > 0
+
+
+_GQ_CELLS = {
+    # trinity-large-preview-serve: K=8, g=6, rows of 2,048, 16 items
+    "trinity": dict(kv_heads=8, groups=6, steps=1, row_width=2048,
+                    num_slots=32, group=16, num_blocks=15361),
+    # sdar-30b-a3b-serve: K=4, g=8, S=4, rows of 1,024, 32 items
+    "sdar": dict(kv_heads=4, groups=8, steps=4, row_width=1024,
+                 num_slots=32, group=32, num_blocks=10241),
+}
+
+
+@pytest.mark.pallas
+@pytest.mark.parametrize("cell,reach", [
+    ("trinity", 4096), ("trinity", None), ("sdar", None)])
+def test_gq_trip_kernel_compiles_on_the_v5e(cell, reach):
+    """Mosaic takes the kernel at both cells' shapes (blocks of 16,
+    bf16 pools, items of 16 pages) for the compile-only ``TPU v5
+    lite`` device, as ``Engine`` asks at construction
+    (``walk_kernel_check``); heads of 64 are refused by name."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops.gq_walk_trip import compile_check
+    with _described_v5e() as sds:
+        dev = sds((1,)).sharding._device
+        shape = dict(_GQ_CELLS[cell], head_dim=128, block_size=16,
+                     pages=16, dtype=jnp.bfloat16, reach=reach, device=dev)
+        compile_check(**shape)
+        if cell == "sdar":
+            with pytest.raises(Exception, match="aligned to tiling"):
+                compile_check(**{**shape, "head_dim": 64, "kv_heads": 8})
+
+
+@pytest.mark.pallas
+def test_sdar_decode_keeps_one_loop_a_layer_around_the_trip_kernel(
+        one_device, monkeypatch):
+    """SDAR's step over 32 slots at the cell's widths (two layers),
+    traced for a TPU and compiled for the compile-only ``TPU v5 lite``
+    device: ONE device loop a layer's walk, whose body is the trip's
+    slice of the work list and ONE Mosaic call that reads the pool
+    where it lies and updates the running state in place (no gather
+    of cached rows, no copy or convert of a pool, no float32 fusion
+    in the loop)."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu import nn
+    from paddle_tpu.jit import _swapped
+    from paddle_tpu.models.sdar_moe import GQAttention
+
+    with nn.LazyGuard():
+        attn = GQAttention(2048, 32, 4, 128, 1e6, 1e-6, 4)
+    attn.to(dtype="bfloat16")
+    params = dict(attn.named_parameters())
+    names = sorted(params)
+
+    def step(p_list, h, pool, tables, pos):
+        with _swapped(params, dict(zip(names, p_list))):
+            return attn.step_slots_paged(h, pool, tables, pos, pos)
+
+    with _described_v5e() as sds:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        text = jax.jit(step, donate_argnums=(2,)).lower(
+            [sds(params[n].shape) for n in names], sds((32, 4, 2048)),
+            sds((10241, 16, 1024)), sds((32, 256), jnp.int32),
+            sds((32,), jnp.int32)).compile().as_text()
+    assert len(re.findall(r" while\(", text)) == 1
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"",
+                          text)) == 1
+    assert "gq_walk_trip" in text
+    assert not re.findall(r"= bf16\[[\d,]*256,1024\]\S* gather\(", text)
+    assert not re.findall(
+        r"= (?:bf16|f32)\[(?:10241,16|163856),1024\]\S* "
+        r"(?:copy|transpose|convert)\(", text)
+    body = re.search(r"body=%?([\w.\-]+)", text).group(1)
+    start = text.index("%" + body + " ")
+    loop = text[start:text.index("\n}\n", start)]
+    assert "gq_walk_trip" in loop and " f32[" not in loop.replace(
+        "f32[32,128,256]", "")
+
+
 # -- knob validation --------------------------------------------------
 
 def test_attn_impl_validation(tiny_gpt):
